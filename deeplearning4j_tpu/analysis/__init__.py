@@ -11,7 +11,7 @@ machine-enforced invariant.
 
 Entry points:
 
-- CLI: ``python tools/graftlint.py deeplearning4j_tpu tools bench.py``
+- CLI: ``python tools/graftlint.py deeplearning4j_tpu tools bench.py chip_smoke.py``
   (human, ``--json``, ``--baseline`` burn-down; exit 2 on unsuppressed
   findings) — wired into tier-1 via tests/test_lint.py.
 - Library: `run(paths)` -> RunResult; `ALL_RULES`;

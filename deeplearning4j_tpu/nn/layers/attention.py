@@ -227,18 +227,15 @@ class MultiHeadAttention(LayerConf):
             k = rope(k, pos)
         drop = self.attention_dropout if train else 0.0
         # fused-kernel eligibility, shared by the context-parallel and
-        # single-device dispatches (the Pallas interpreter off-TPU would
-        # be far slower than XLA; the kernel has no dropout RNG)
+        # single-device dispatches, decided from what the code can see:
+        # the platform (the Pallas interpreter off-TPU would be far
+        # slower than XLA) and dropout (the kernel has no dropout RNG).
         # "blockwise" is the algorithm; on TPU the fused flash kernel IS
         # its fastest realization, so both impls ride it when eligible.
-        # DL4J_TPU_FLASH=0 is the first-contact kill switch: if the Pallas
-        # kernel miscompiles on real hardware, everything falls back to
-        # the lax online-softmax paths without a code edit.
-        from deeplearning4j_tpu.util.env import env_flag
+        # On a TPU an eligible layer runs the kernel or the call raises.
         use_flash = (self.attention_impl in ("flash", "blockwise")
                      and drop == 0.0
-                     and is_tpu_backend()
-                     and env_flag("DL4J_TPU_FLASH"))
+                     and is_tpu_backend())
         if _CONTEXT_PARALLEL_AXIS is not None:
             if use_flash:
                 from deeplearning4j_tpu.parallel.ring import (
@@ -263,7 +260,7 @@ class MultiHeadAttention(LayerConf):
                                   block_k=self.block_size)
         elif self.attention_impl in ("flash", "blockwise"):
             # off-TPU (the Pallas interpreter would be orders of magnitude
-            # slower than XLA), dropout on, or DL4J_TPU_FLASH=0: blockwise
+            # slower than XLA) or dropout on: blockwise
             # recomputation, clamped + padded to the block size like the
             # flash wrapper pads — a sequence shorter than / not divisible
             # by block_size must work, not raise

@@ -18,8 +18,8 @@ Semantics match dot_product_attention exactly (tested):
   the saved per-row log-sum-exp, so the score matrix never materializes
   in either direction; cross-attention shapes (tq != tk) included.
 
-On CPU the kernel runs under `interpret=True` (numerically identical,
-slow) — callers gate on backend; tests run interpret mode.
+Off-TPU the kernel runs under `interpret=True` (numerically identical,
+slow); on a TPU it compiles or the call raises.
 """
 from __future__ import annotations
 
@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deeplearning4j_tpu.util.env import env_int
 from deeplearning4j_tpu.util.platform import is_tpu_backend
 
 NEG = -1e30
@@ -100,7 +99,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr,
         m = m_scr[...]
         l = l_scr[...]
         out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        out = jnp.where((m <= NEG / 2)[:, None], 0.0, out)
+        # broadcast the f32 row max, THEN compare: Mosaic has no layout
+        # for the (block_q,) -> (block_q, 1) reshape of an i1 vector
+        out = jnp.where(m[:, None] <= NEG / 2, 0.0, out)
         o_ref[0] = out.astype(o_ref.dtype)
         # log-sum-exp per q row, the backward residual; +NEG-> +inf for
         # fully-masked rows so exp(s - lse) vanishes there in the bwd
@@ -153,6 +154,7 @@ def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qh, kh, vh, mask)
     return (out.reshape(b, h, tq, d).transpose(0, 2, 1, 3),
             lse.reshape(b * h, tq))
@@ -302,6 +304,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qh, kh, vh, gh, lse3, dh, m_in)
 
     dk, dv = pl.pallas_call(
@@ -328,6 +331,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qh, kh, vh, gh, lse3, dh, m_in)
 
     reshape = lambda a, t: a.reshape(b, h, t, d).transpose(0, 2, 1, 3)
@@ -360,16 +364,8 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
     tk = k.shape[1]
     if interpret is None:
         interpret = not is_tpu_backend()
-    # block sizes: DL4J_TPU_FLASH_BLOCK_Q/K take PRECEDENCE over caller
-    # arguments — they are the first-contact VMEM/tiling recovery knobs
-    # (PERF.md) and must work even for layers that pass explicit sizes
-    # (MultiHeadAttention forwards its block_size config here)
-    bq_env = env_int("DL4J_TPU_FLASH_BLOCK_Q")
-    bk_env = env_int("DL4J_TPU_FLASH_BLOCK_K")
-    block_q = bq_env if bq_env else (block_q or 128)
-    block_k = bk_env if bk_env else (block_k or 128)
-    block_q = min(block_q, max(tq, 1))
-    block_k = min(block_k, max(tk, 1))
+    block_q = min(block_q or 128, max(tq, 1))
+    block_k = min(block_k or 128, max(tk, 1))
     pq = (-tq) % block_q
     pk = (-tk) % block_k
     if mask is None and pk:

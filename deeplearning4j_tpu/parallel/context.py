@@ -34,7 +34,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.nn.layers.attention import context_parallel
 from deeplearning4j_tpu.parallel.mesh import (
-    DATA_AXIS, SEQ_AXIS, build_mesh, compat_shard_map, MeshConfig,
+    DATA_AXIS, SEQ_AXIS, build_mesh, MeshConfig,
 )
 
 log = logging.getLogger("deeplearning4j_tpu")
@@ -162,19 +162,20 @@ class ContextParallelTrainer:
         repl = P()
         xspec = P(DATA_AXIS, SEQ_AXIS)          # (B, T, ...) batch+seq sharded
         out_specs = (repl, repl, repl, repl)
-        # shard_map can't take None specs for None args uniformly across
-        # jax versions; close over the absent masks instead
+        # absent masks are closed over, not passed as None args
+        def shard(f, *in_specs):
+            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)
+
         if with_fmask and with_lmask:
-            sm = compat_shard_map(local_step, mesh,
-                                  (repl, repl, repl, xspec, xspec, xspec,
-                                   xspec, repl), out_specs)
+            sm = shard(local_step, repl, repl, repl, xspec, xspec, xspec,
+                       xspec, repl)
         elif with_fmask:
             def fm_step(params, opt_state, state, x, y, fmask, rng):
                 return local_step(params, opt_state, state, x, y, fmask,
                                   None, rng)
-            inner = compat_shard_map(
-                fm_step, mesh,
-                (repl, repl, repl, xspec, xspec, xspec, repl), out_specs)
+            inner = shard(fm_step, repl, repl, repl, xspec, xspec, xspec,
+                          repl)
 
             def sm(params, opt_state, state, x, y, fmask, lmask, rng):
                 return inner(params, opt_state, state, x, y, fmask, rng)
@@ -182,9 +183,8 @@ class ContextParallelTrainer:
             def lm_step(params, opt_state, state, x, y, lmask, rng):
                 return local_step(params, opt_state, state, x, y, None,
                                   lmask, rng)
-            inner = compat_shard_map(
-                lm_step, mesh,
-                (repl, repl, repl, xspec, xspec, xspec, repl), out_specs)
+            inner = shard(lm_step, repl, repl, repl, xspec, xspec, xspec,
+                          repl)
 
             def sm(params, opt_state, state, x, y, fmask, lmask, rng):
                 return inner(params, opt_state, state, x, y, lmask, rng)
@@ -192,9 +192,7 @@ class ContextParallelTrainer:
             def bare_step(params, opt_state, state, x, y, rng):
                 return local_step(params, opt_state, state, x, y, None,
                                   None, rng)
-            inner = compat_shard_map(
-                bare_step, mesh,
-                (repl, repl, repl, xspec, xspec, repl), out_specs)
+            inner = shard(bare_step, repl, repl, repl, xspec, xspec, repl)
 
             def sm(params, opt_state, state, x, y, fmask, lmask, rng):
                 return inner(params, opt_state, state, x, y, rng)

@@ -84,18 +84,3 @@ def stacked_sharding(mesh: Mesh) -> NamedSharding:
     """Leading-axis sharding for per-replica stacked pytrees (AVERAGING
     mode keeps one parameter copy per data-parallel worker)."""
     return NamedSharding(mesh, P(DATA_AXIS))
-
-
-def compat_shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map across JAX versions (jax.shard_map with check_vma vs the
-    older jax.experimental API with check_rep)."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:      # pragma: no cover - old JAX
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except TypeError:        # pragma: no cover - old JAX
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)

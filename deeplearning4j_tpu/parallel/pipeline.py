@@ -42,7 +42,7 @@ import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import (
-    DATA_AXIS, STAGE_AXIS, MeshConfig, build_mesh, compat_shard_map,
+    DATA_AXIS, STAGE_AXIS, MeshConfig, build_mesh,
 )
 
 log = logging.getLogger("deeplearning4j_tpu")
@@ -188,13 +188,15 @@ class PipelineParallelTrainer:
                     STAGE_AXIS)
 
             if with_mask:
-                return compat_shard_map(
-                    torso, mesh,
-                    (P(STAGE_AXIS), P(None, DATA_AXIS), P(None, DATA_AXIS)),
-                    P(None, DATA_AXIS))
-            inner = compat_shard_map(
-                lambda stacked, hm: torso(stacked, hm, None), mesh,
-                (P(STAGE_AXIS), P(None, DATA_AXIS)), P(None, DATA_AXIS))
+                return jax.shard_map(
+                    torso, mesh=mesh,
+                    in_specs=(P(STAGE_AXIS), P(None, DATA_AXIS),
+                              P(None, DATA_AXIS)),
+                    out_specs=P(None, DATA_AXIS), check_vma=False)
+            inner = jax.shard_map(
+                lambda stacked, hm: torso(stacked, hm, None), mesh=mesh,
+                in_specs=(P(STAGE_AXIS), P(None, DATA_AXIS)),
+                out_specs=P(None, DATA_AXIS), check_vma=False)
             return lambda stacked, hm, fm: inner(stacked, hm)
 
         from deeplearning4j_tpu.nn.regularization import (

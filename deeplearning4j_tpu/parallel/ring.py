@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning4j_tpu.parallel.mesh import SEQ_AXIS, compat_shard_map
+from deeplearning4j_tpu.parallel.mesh import SEQ_AXIS
 
 
 def _online_block(q, k, v, o, m, l, *, causal, q_start, k_start, scale,
@@ -127,8 +127,13 @@ def make_ring_attention(mesh: Mesh, *, causal: bool = True,
         return ring_self_attention(q, k, v, axis_name=axis_name,
                                    causal=causal, mask=None)
 
-    f_masked = compat_shard_map(masked, mesh, (spec_qkv, spec_qkv, spec_qkv, spec_mask), spec_qkv)
-    f_unmasked = compat_shard_map(unmasked, mesh, (spec_qkv, spec_qkv, spec_qkv), spec_qkv)
+    f_masked = jax.shard_map(
+        masked, mesh=mesh,
+        in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_mask),
+        out_specs=spec_qkv, check_vma=False)
+    f_unmasked = jax.shard_map(
+        unmasked, mesh=mesh, in_specs=(spec_qkv, spec_qkv, spec_qkv),
+        out_specs=spec_qkv, check_vma=False)
     size = int(mesh.shape[axis_name])
 
     def attend(q, k, v, mask=None):

@@ -368,9 +368,13 @@ class SubprocessReplica(Replica):
         return argv
 
     def launch(self):
+        # stderr is inherited, not discarded: a child that cannot start
+        # (e.g. a chip another process already holds — N subprocess
+        # replicas cannot share one chip; pinning them to devices is not
+        # built yet) must say why before launch() times out
         self.proc = subprocess.Popen(
             self._argv(), stdout=subprocess.PIPE,
-            stderr=subprocess.DEVNULL, env=self.env, text=True)
+            env=self.env, text=True)
         # a silent hung child must not hang launch(): readline() has no
         # deadline of its own, so a reader thread feeds a queue and the
         # timeout lives on the queue get. The thread exits on the EOF

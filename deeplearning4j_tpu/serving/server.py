@@ -69,6 +69,7 @@ from deeplearning4j_tpu.serving.batcher import (
 from deeplearning4j_tpu.serving import kvfabric
 from deeplearning4j_tpu.serving.registry import ModelLoadError, ModelRegistry
 from deeplearning4j_tpu.util import faults as fault_util
+from deeplearning4j_tpu.util.platform import device_info
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -220,6 +221,10 @@ class _Handler(BaseHTTPRequestHandler):
             if self._srv.ready():
                 self._json({"status": "ready",
                             "models": self._srv.registry.names(),
+                            # the device this process serves from, as
+                            # JAX reports it: a probe can tell a chip
+                            # from a CPU that took its place
+                            "device": device_info(),
                             "role": self._srv.role,
                             "rollout_generation":
                                 self._srv.rollout_generation,

@@ -1,12 +1,11 @@
 """Unit tests for the bench orchestration (driver contract pieces that
-need no device): config ordering, mode-label canonicalization, cache
-path, and the headline-aggregation rule.
+need no device): config ordering, the shared compile-cache location,
+the no-CPU-fallback rule, and the headline-aggregation rule.
 
 bench.py's module level imports no jax, so these are instant.
 """
 import os
 import subprocess
-import sys
 
 import pytest
 
@@ -29,12 +28,11 @@ class TestConfigs:
     def test_tpu_order_banks_decisive_trio_first(self, clean_knobs):
         cfgs = bench._configs(True)
         kinds = [(c.get("kind"), c.get("mode", "")) for c in cfgs]
-        # the per-call/scan/fit trio at batch 128 must precede the Pallas
-        # attention micro (first-contact wedge risk) and batch 256
+        # the per-call/scan/fit trio at batch 128 comes first
         assert kinds[:3] == [("resnet", "per-call"), ("resnet", "scan"),
                              ("resnet", "fit")]
         # the cheap h2d bandwidth micro (attributes the fit number) rides
-        # right behind the trio, before the wedge-risky attention micro
+        # right behind the trio
         assert kinds[3] == ("h2d", "")
         assert kinds[4] == ("attention", "")
         assert {c["batch"] for c in cfgs[:3]} == {128}
@@ -61,39 +59,69 @@ class TestConfigs:
         assert kinds == {"resnet"}
 
 
-class TestCanonMode:
-    def test_scan_and_fit_get_k_suffix(self):
-        assert bench._canon_mode(
-            {"kind": "resnet", "mode": "scan"}, 10)["mode"] == "scan10"
-        assert bench._canon_mode(
-            {"kind": "resnet", "mode": "fit"}, 2)["mode"] == "fit-pipelined2"
-
-    def test_other_configs_untouched(self):
-        for cfg in ({"kind": "resnet", "mode": "per-call"},
-                    {"kind": "attention"}, {"kind": "char-lstm"}):
-            assert bench._canon_mode(dict(cfg), 10) == cfg
-
-
 class TestCacheDir:
-    def test_repo_local_path(self):
-        # repo-local so the cached TPU programs survive /tmp wipes
-        # between builder sessions (PERF.md round-5 hardware status)
-        d = bench.cache_dir()
+    """util/platform.enable_compile_cache: ONE cache location for every
+    entry point, placeable from outside."""
+
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        """Record jax.config.update calls instead of applying them: the
+        suite's own cache (set by conftest) stays where it is."""
+        import deeplearning4j_tpu.util.platform as plat
+        seen = {}
+        monkeypatch.setattr(plat.jax.config, "update",
+                            lambda k, v: seen.__setitem__(k, v))
+        return seen
+
+    def test_default_is_checkout_local(self, monkeypatch, updates):
+        import deeplearning4j_tpu.util.platform as plat
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        d = plat.enable_compile_cache()
         assert d == os.path.join(repo, ".jaxcache")
         assert os.path.isdir(d)
+        assert updates["jax_compilation_cache_dir"] == d
 
-    def test_shared_with_graft_entry_and_conftest(self):
-        # conftest imports the same symbol; __graft_entry__ falls back to
-        # it too — one definition, so just assert it is importable from
-        # the repo root the way both callers do it
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        r = subprocess.run(
-            [sys.executable, "-c",
-             "from bench import cache_dir; print(cache_dir())"],
-            capture_output=True, text=True, cwd=repo, timeout=60)
-        assert r.returncode == 0
-        assert r.stdout.strip() == bench.cache_dir()
+    def test_env_places_it_and_code_sets_no_directory(
+            self, monkeypatch, updates, tmp_path):
+        import deeplearning4j_tpu.util.platform as plat
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert plat.enable_compile_cache() == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+
+    def test_unwritable_checkout_is_an_error(self, monkeypatch, updates,
+                                             tmp_path):
+        import deeplearning4j_tpu.util.platform as plat
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        blocker = tmp_path / "not-a-dir"
+        blocker.write_text("")
+        monkeypatch.setattr(plat, "CHECKOUT", str(blocker))
+        with pytest.raises(OSError):
+            plat.enable_compile_cache()
+        assert "jax_compilation_cache_dir" not in updates
+
+
+class TestNoFallback:
+    def test_child_without_tpu_exits_unless_cpu_was_asked_for(
+            self, monkeypatch):
+        # the suite's jax is on the CPU; with JAX_PLATFORMS not naming it
+        # the child must refuse before running anything
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        monkeypatch.setitem(bench._KIND_RUNNERS, "h2d", lambda cfg: 1 / 0)
+        with pytest.raises(SystemExit, match="no TPU"):
+            bench.run_one({"kind": "h2d"})
+
+    def test_failed_config_fails_the_run(self, monkeypatch, capsys):
+        calls = []
+
+        def fake_run(argv, **kw):
+            calls.append(argv)
+            return subprocess.CompletedProcess(argv, 7, stdout="")
+
+        monkeypatch.setattr(bench.subprocess, "run", fake_run)
+        assert bench.main() == 7
+        assert len(calls) == 1          # fail-fast: no later config ran
+        assert capsys.readouterr().out == ""    # and no result printed
 
 
 class TestHeadlineAggregation:
@@ -103,62 +131,7 @@ class TestHeadlineAggregation:
             {"batch": 128, "mode": "scan10", "imgs_sec": 3300.0},
             {"mode": "lenet-mnist", "lenet_imgs_sec": 99999.0},
             {"mode": "char-lstm", "chars_sec": 1e9},
-            {"batch": 256, "mode": "per-call",
-             "error": "watchdog: config exceeded 1800s"},
         ]
         best = bench._headline(results)
         assert best["mode"] == "scan10"   # micro benches ride along only
-        assert bench._headline([{"mode": "x", "error": "e"}]) is None
-
-    @pytest.mark.slow
-    @pytest.mark.distributed
-    def test_sigterm_kills_inflight_child(self, tmp_path):
-        # orchestration-level contract: the --one child dies with the
-        # orchestrator (no orphan contending for the chip)
-        import signal
-        import time as _t
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   DL4J_TPU_BENCH_PARTIAL=str(tmp_path / "partial.jsonl"))
-        p = subprocess.Popen([sys.executable, "bench.py"], cwd=repo,
-                             env=env, stdout=subprocess.DEVNULL,
-                             stderr=subprocess.DEVNULL)
-        try:
-            child_pid = None
-            for _ in range(120):     # wait for the first --one child
-                _t.sleep(1)
-                r = subprocess.run(
-                    ["pgrep", "-f", "bench.py --one"],
-                    capture_output=True, text=True)
-                pids = [int(x) for x in r.stdout.split()
-                        if x.strip().isdigit() and int(x) != p.pid]
-                live = []
-                for pid in pids:
-                    try:
-                        with open(f"/proc/{pid}/stat") as f:
-                            ppid = int(f.read().split()[3])
-                        if ppid == p.pid:
-                            live.append(pid)
-                    except OSError:
-                        pass
-                if live:
-                    child_pid = live[0]
-                    break
-            assert child_pid is not None, "no --one child appeared"
-            p.send_signal(signal.SIGTERM)
-            p.wait(timeout=30)
-            for _ in range(20):
-                if not os.path.exists(f"/proc/{child_pid}"):
-                    break
-                _t.sleep(0.5)
-            # a zombie (not yet reaped) also counts as dead
-            alive = os.path.exists(f"/proc/{child_pid}")
-            if alive:
-                with open(f"/proc/{child_pid}/stat") as f:
-                    alive = f.read().split()[2] != "Z"
-            assert not alive, "config child survived orchestrator SIGTERM"
-        finally:
-            try:
-                p.kill()
-            except OSError:
-                pass
+        assert bench._headline([{"mode": "h2d-micro"}]) is None

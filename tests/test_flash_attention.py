@@ -203,9 +203,7 @@ def test_ring_flash_matches_ring_online():
     """ring_flash_self_attention (fused kernel per shard + LSE merge)
     must match the lax online-softmax ring bit-for-tolerance on the
     8-device CPU mesh, causal and masked."""
-    from deeplearning4j_tpu.parallel.mesh import (
-        MeshConfig, build_mesh, compat_shard_map,
-    )
+    from deeplearning4j_tpu.parallel.mesh import MeshConfig, build_mesh
     from deeplearning4j_tpu.parallel.ring import (
         ring_flash_self_attention, ring_self_attention,
     )
@@ -220,17 +218,19 @@ def test_ring_flash_matches_ring_online():
     mask = jnp.asarray((rs.rand(2, T) > 0.2).astype("float32"))
     spec = P(None, "seq", None, None)
     mspec = P(None, "seq")
+    sm = dict(mesh=mesh, in_specs=(spec, spec, spec, mspec), out_specs=spec,
+              check_vma=False)
 
     for causal in (True, False):
-        ref_f = compat_shard_map(
+        ref_f = jax.shard_map(
             lambda q, k, v, m, c=causal: ring_self_attention(
                 q, k, v, axis_name="seq", causal=c, mask=m),
-            mesh, (spec, spec, spec, mspec), spec)
-        new_f = compat_shard_map(
+            **sm)
+        new_f = jax.shard_map(
             lambda q, k, v, m, c=causal: ring_flash_self_attention(
                 q, k, v, axis_name="seq", causal=c, mask=m,
                 block_q=8, block_k=8),
-            mesh, (spec, spec, spec, mspec), spec)
+            **sm)
         ref = np.asarray(ref_f(q, k, v, mask))
         new = np.asarray(new_f(q, k, v, mask))
         np.testing.assert_allclose(new, ref, atol=3e-5, rtol=3e-5,
@@ -242,15 +242,15 @@ def test_ring_flash_matches_ring_online():
             return jnp.sum(fn(q, k, v, mask) ** 2)
         return go
 
-    ref_f = compat_shard_map(
+    ref_f = jax.shard_map(
         lambda q, k, v, m: ring_self_attention(
             q, k, v, axis_name="seq", causal=True, mask=m),
-        mesh, (spec, spec, spec, mspec), spec)
-    new_f = compat_shard_map(
+        **sm)
+    new_f = jax.shard_map(
         lambda q, k, v, m: ring_flash_self_attention(
             q, k, v, axis_name="seq", causal=True, mask=m,
             block_q=8, block_k=8),
-        mesh, (spec, spec, spec, mspec), spec)
+        **sm)
     gr = jax.grad(loss(ref_f), argnums=(0, 1, 2))(q, k, v)
     gn = jax.grad(loss(new_f), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gn, gr):
@@ -265,10 +265,8 @@ def test_ring_flash_matches_ring_online():
     ((1, 8, 1, 32), 128, 128),      # T far below the block size
 ])
 def test_flash_block_size_shape_matrix(shape, bq, bk):
-    """First-contact de-risking: the kernel must be exact across the
-    block-size x head-dim x ragged-T matrix that real models hit (the
-    same configs the DL4J_TPU_FLASH_BLOCK_Q/K knobs select on
-    hardware)."""
+    """The kernel must be exact across the block-size x head-dim x
+    ragged-T matrix that real models hit."""
     from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
     from deeplearning4j_tpu.ops import flash_attention
 
@@ -282,24 +280,3 @@ def test_flash_block_size_shape_matrix(shape, bq, bk):
         ref = dot_product_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
-
-
-def test_flash_env_block_override(monkeypatch):
-    """The env knobs must actually reach the kernel — including overriding
-    EXPLICIT caller block sizes (they are the no-code-edit recovery path
-    on hardware, and layers pass their configured block_size)."""
-    from deeplearning4j_tpu.ops import flash_attention
-
-    rs = np.random.RandomState(0)
-    q, k, v = [jnp.asarray(rs.randn(1, 64, 1, 32).astype("float32"))
-               for _ in range(3)]
-    base = np.asarray(flash_attention(q, k, v, interpret=True))
-    monkeypatch.setenv("DL4J_TPU_FLASH_BLOCK_Q", "16")
-    monkeypatch.setenv("DL4J_TPU_FLASH_BLOCK_K", "32")
-    tuned = np.asarray(flash_attention(q, k, v, block_q=128, block_k=128,
-                                       interpret=True))
-    np.testing.assert_allclose(tuned, base, rtol=1e-5, atol=1e-5)
-    # the override is observably live: garbage must raise, not be ignored
-    monkeypatch.setenv("DL4J_TPU_FLASH_BLOCK_Q", "not-a-number")
-    with pytest.raises(ValueError):
-        flash_attention(q, k, v, interpret=True)

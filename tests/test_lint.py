@@ -3,8 +3,8 @@
 Fixture convention (tests/fixtures/graftlint/): every rule has a
 `*_pos.py` with `# EXPECT` markers on each line that must be flagged,
 and a `*_neg.py` of near-misses that must stay clean. The live-tree
-test IS the CI gate: `deeplearning4j_tpu/ + tools/ + bench.py` must
-have zero unsuppressed findings, so every future PR (including the
+test IS the CI gate: `deeplearning4j_tpu/ + tools/ + bench.py +
+chip_smoke.py` must have zero unsuppressed findings, so every future PR (including the
 GSPMD-mesh refactor) walks through the analyzer.
 """
 import json
@@ -268,7 +268,8 @@ def test_live_tree_is_clean():
         assert required in active
     res = analysis.run([os.path.join(REPO, "deeplearning4j_tpu"),
                         os.path.join(REPO, "tools"),
-                        os.path.join(REPO, "bench.py")])
+                        os.path.join(REPO, "bench.py"),
+                        os.path.join(REPO, "chip_smoke.py")])
     rendered = "\n".join(f.render(REPO) for f in res.all_unsuppressed)
     assert not res.all_unsuppressed, f"graftlint findings:\n{rendered}"
     # the suite actually ran over the tree (not an empty glob) and the

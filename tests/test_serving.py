@@ -768,30 +768,3 @@ def test_accum_partial_group_warns(caplog):
             if "accumulation group" in r.getMessage()]
     assert len(hits) == 1
     assert "shape changed" in hits[0].getMessage()
-
-
-def test_bench_cache_dir_write_probe(tmp_path, monkeypatch):
-    """cache_dir probes with a real create/remove — os.access(W_OK)
-    answers yes to root even on a read-only mount, so only an actual
-    failing open may engage the tempdir fallback."""
-    import builtins
-    import bench
-    real_open = builtins.open
-    # point the repo-local cache at tmp_path and make ITS opens fail the
-    # way a read-only mount does for root (EROFS despite W_OK bits)
-    monkeypatch.setattr(bench, "__file__",
-                        str(tmp_path / "bench.py"), raising=True)
-    denied = str(tmp_path / ".jaxcache")
-
-    def deny(path, *a, **kw):
-        if str(path).startswith(denied):
-            raise OSError(30, "Read-only file system", str(path))
-        return real_open(path, *a, **kw)
-
-    monkeypatch.setattr(builtins, "open", deny)
-    d = bench.cache_dir()
-    assert not d.startswith(denied)
-    assert "dl4jtpu-jax-cache" in d
-    # and with writable opens the repo-local dir is chosen
-    monkeypatch.setattr(builtins, "open", real_open)
-    assert bench.cache_dir() == denied

@@ -505,14 +505,12 @@ def test_cli_fleet_one_request_one_trace_merged(tmp_path):
     + 2 subprocess replicas) yields ONE trace_id present in router,
     replica-server, and batcher spans, and trace_report merges the
     per-process segments into one valid Perfetto trace."""
-    from bench import cache_dir
     from deeplearning4j_tpu.util.serialization import save_model
     model_zip = str(tmp_path / "model.zip")
     save_model(_net(), model_zip)
     trace_out = str(tmp_path / "fleet.json")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir())
     proc = subprocess.Popen(
         [sys.executable, "-m", "deeplearning4j_tpu.serving",
          "--model", f"m={model_zip}", "--replicas", "2",

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """graftlint CLI — the project-native static-analysis suite.
 
-    python tools/graftlint.py deeplearning4j_tpu tools bench.py
+    python tools/graftlint.py deeplearning4j_tpu tools bench.py chip_smoke.py
     python tools/graftlint.py --json ... | jq .
     python tools/graftlint.py --list-rules
     python tools/graftlint.py --changed-only            # git-diff scope
@@ -116,7 +116,8 @@ def _parser() -> argparse.ArgumentParser:
     p.add_argument("paths", nargs="*",
                    default=[os.path.join(ROOT, "deeplearning4j_tpu"),
                             os.path.join(ROOT, "tools"),
-                            os.path.join(ROOT, "bench.py")],
+                            os.path.join(ROOT, "bench.py"),
+                            os.path.join(ROOT, "chip_smoke.py")],
                    help="files/dirs to lint (default: the shipped tree)")
     p.add_argument("--json", action="store_true",
                    help="machine-readable findings on stdout")

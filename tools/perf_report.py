@@ -3,9 +3,8 @@
 
 Merges two artifact streams:
 
-- the banked bench trajectory (``BENCH_r*.json`` /
-  ``BENCH_TPU_MEASURED_*.json``): every throughput series that appears in
-  more than one round — per-mode/batch ResNet imgs/sec, char-LSTM
+- the banked bench trajectory (``BENCH_r*.json``): every throughput
+  series that appears in more than one round — per-mode/batch ResNet imgs/sec, char-LSTM
   chars/sec, Word2Vec pairs/sec, LeNet imgs/sec, h2d MB/s, and the
   headline — is compared LATEST vs. BEST-EARLIER within its own device
   class (CPU rows never gate TPU rows and vice versa). Artifacts that
@@ -113,13 +112,10 @@ def _round_of(name: str) -> int:
 def load_rounds(directory: str):
     """Parse every banked bench artifact into (round, on_tpu, payload)
     entries. Artifacts wrap the bench JSON under "parsed" (driver capture)
-    or are the bare JSON (watcher-banked TPU measurements); unparseable or
-    payload-less rounds are skipped, not fatal — a wedged round must not
-    break the gate."""
+    or are the bare JSON; unparseable or payload-less rounds are skipped,
+    not fatal."""
     entries = []
     names = (sorted(glob.glob(os.path.join(directory, "BENCH_r*.json")))
-             + sorted(glob.glob(os.path.join(directory,
-                                             "BENCH_TPU_MEASURED_*.json")))
              + sorted(glob.glob(os.path.join(directory, "CHAOS_r*.json")))
              # GSPMD-plan scaling sweeps; pre-r06 MULTICHIP artifacts
              # are driver dryrun stamps without a sweep and skip below

@@ -172,7 +172,6 @@ def main(argv=None) -> int:
 
     import numpy as np
 
-    from bench import cache_dir
     from deeplearning4j_tpu.monitor import flight
     from deeplearning4j_tpu.serving import (
         AutoscaleConfig, InProcessReplica, ReplicaSpec, ReplicaSupervisor,
@@ -242,8 +241,7 @@ def main(argv=None) -> int:
     # ---------------- phase 2: fleet + canary -> promote -----------------
     pm_dir = os.path.join(tmp, "postmortems")
     flight.enable_flight(capacity=512, dump_dir=pm_dir)
-    env = dict(os.environ)
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir())
+    env = dict(os.environ)      # the replicas' CLI finds the shared cache
     spec = ReplicaSpec([("m", v1_zip)], buckets=(1, 8), max_delay_ms=2.0,
                        queue_limit=64, default_deadline_s=30.0,
                        postmortem_dir=pm_dir,

@@ -257,7 +257,6 @@ def main(argv=None) -> int:
 
     import numpy as np
 
-    from bench import cache_dir
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--bank-postmortem", default=None, metavar="PATH",
                     help="copy the fault-window flight postmortem here "
@@ -300,8 +299,7 @@ def main(argv=None) -> int:
     pm_dir = os.path.join(tmp, "postmortems")
     flight.enable_flight(capacity=512, dump_dir=pm_dir)
 
-    env = dict(os.environ)
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir())
+    env = dict(os.environ)      # the replicas' CLI finds the shared cache
     spec = ReplicaSpec([("m", model_zip)], buckets=(1, 8),
                        max_delay_ms=2.0, queue_limit=64,
                        default_deadline_s=30.0, enable_faults=True,

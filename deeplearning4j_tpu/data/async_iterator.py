@@ -97,8 +97,10 @@ def prefetch_iterable(source, transform=None, queue_size: Optional[int] = None):
     Telemetry (monitor/): `etl_queue_depth` tracks the prefetch buffer
     fill, `etl_fetch_wait_seconds` how long the consumer (the train
     loop) blocked on it — a consistently empty queue + large waits means
-    the fit is ETL-bound, not compute-bound. Worker-side staging shows
-    up as `etl/stage` spans on the prefetch thread's trace track."""
+    the fit is ETL-bound, not compute-bound. With tracing on, each batch
+    leaves `etl/source_next`, `etl/stage` and `etl/queue_put` spans on
+    the prefetch thread's track and an `etl/queue_wait` span on the
+    consumer's, all carrying the batch's `seq`."""
     if queue_size is None:
         queue_size = prefetch_depth()
     if int(queue_size) <= 0:
@@ -134,19 +136,36 @@ def _prefetch_pump(source, transform, queue_size: int):
                 continue
         return False
 
+    # Spans of one batch share its `seq` (its number since the pump
+    # started): `etl/source_next`, `etl/stage` and `etl/queue_put` on the
+    # worker thread, `etl/queue_wait` on the consumer's. A worker blocked
+    # in `etl/queue_put` is the healthy state (the queue is full), so
+    # that span stays out of the profiler's host plane, where it is about
+    # as long as a chunk and would be taken for what the device waited
+    # on; a consumer blocked in `etl/queue_wait` is work waiting for the
+    # feed.
     def worker():
         try:
-            for item in source:
+            it = iter(source)
+            seq = 0
+            while True:
                 if stop.is_set():
                     return
+                with monitor.span("etl/source_next", seq=seq):
+                    try:
+                        item = next(it)
+                    except StopIteration:
+                        break
                 if transform is not None:
                     t0 = time.perf_counter()
-                    with monitor.span("etl/stage"):
+                    with monitor.span("etl/stage", seq=seq):
                         item = transform(item)
                     m_stage.observe(time.perf_counter() - t0)
                 m_batches.inc()
-                if not put(item):
-                    return
+                with monitor.span("etl/queue_put", annotate=False, seq=seq):
+                    if not put(item):
+                        return
+                seq += 1
         except BaseException as e:    # surface worker errors to the consumer
             put(e)
             return
@@ -156,15 +175,18 @@ def _prefetch_pump(source, transform, queue_size: int):
                          name=f"etl-prefetch-{next(_prefetch_seq)}")
     t.start()
     try:
+        seq = 0
         while True:
             t0 = time.perf_counter()
-            item = q.get()
+            with monitor.span("etl/queue_wait", seq=seq):
+                item = q.get()
             m_wait.observe(time.perf_counter() - t0)
             m_depth.set(q.qsize())
             if item is _SENTINEL:
                 break
             if isinstance(item, BaseException):
                 raise item
+            seq += 1
             yield item
     finally:
         stop.set()

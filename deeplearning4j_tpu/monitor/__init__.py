@@ -49,8 +49,8 @@ from deeplearning4j_tpu.monitor.metrics import (
 from deeplearning4j_tpu.monitor.trace import (
     TRACEPARENT_HEADER, TraceContext, add_span, bind_context, clear_trace,
     current_context, disable_tracing, enable_tracing, instant,
-    mint_context, parse_traceparent, save_trace, span, trace_events,
-    tracing_enabled,
+    mint_context, parse_traceparent, save_trace, span, thread_names,
+    trace_events, tracing_enabled,
 )
 # the compiled-program ledger (xla_* families, MFU gauges, perf ledger
 # JSON) — namespaced as monitor.xla; see docs/OBSERVABILITY.md
@@ -77,6 +77,6 @@ __all__ = [
     "TRACEPARENT_HEADER", "TraceContext", "add_span", "bind_context",
     "clear_trace", "current_context", "disable_tracing", "enable_tracing",
     "instant", "mint_context", "parse_traceparent", "save_trace", "span",
-    "trace_events", "tracing_enabled",
+    "thread_names", "trace_events", "tracing_enabled",
     "xla", "flight", "timeseries", "slo", "goodput",
 ]

@@ -3,16 +3,19 @@
 `examples_per_sec` answers "how fast"; this module answers "where did
 the time go". A `GoodputLedger` consumes the span stream the fit loops
 already emit (`train/etl`, `train/host_sync`, `xla/compile`, the
-resilience checkpoint spans, plus the emission points this module
-added: `train/device_wait`, `train/resume_replay`,
-`resilience/eval_gate`) and attributes every wall-clock second of a
-`fit()` to exactly ONE of a closed category set:
+resilience checkpoint spans, the chunked path's `train/stage` /
+`train/launch` / `train/loss_fetch`, the prefetch pump's
+`etl/queue_wait`, plus the emission points this module added:
+`train/device_wait`, `train/resume_replay`, `resilience/eval_gate`) and
+attributes every wall-clock second of a `fit()` to exactly ONE of a
+closed category set:
 
 ==============  ======================================================
 category        meaning
 ==============  ======================================================
 step_compute    device executing the compiled step (the goodput)
-data_wait       blocked on the ETL/input pipeline (`train/etl`)
+data_wait       blocked on the ETL/input pipeline (`etl/queue_wait`
+                and the rest of `train/etl`)
 host_sync       the deliberate loss fetch's D2H transfer + Python
 compile         XLA compilation (`xla/compile`)
 checkpoint      checkpoint save/restore IO
@@ -25,7 +28,11 @@ other           everything unattributed (framework overhead, listener
 Exclusivity is the contract: the categories of a finished session sum
 to its measured wall-clock exactly (`other` is defined as the
 remainder), which `tools/telemetry_smoke.py` enforces in CI against an
-externally measured wall-clock.
+externally measured wall-clock. Only LEAF spans carry a category: a
+parent (`train/chunk`, `train/dispatch`, `train/chunk_sync`) is never
+summed with its children, and a span around a pull from the iterator
+(`train/etl`, `train/resume_replay`) counts only what its
+`etl/queue_wait` children left over.
 
 Zero-cost-when-disabled follows `span()`/flight: while disabled the fit
 loops' `add_span()` calls keep their original single-flag fast path and
@@ -39,9 +46,11 @@ Extras carried by the ledger:
   counters, and a per-session summary in `FitReport`
   (`goodput_pct`, `time_by_category`);
 - a per-step anomaly detector — rolling median/MAD over the
-  step-to-step wall spacing; a spike fires
-  `flight.trip("step_time_anomaly")` with a postmortem naming the
-  dominant category, step index and trace id (plus the all-thread
+  step-to-step wall spacing (`train/step` ends on the per-call paths,
+  `train/chunk` ends on the chunked ones); a spike logs one WARNING
+  and fires `flight.trip("step_time_anomaly")` with a postmortem, both
+  naming the dominant category, the leaf span and thread that hold
+  most of the excess, step index and trace id (plus the all-thread
   stack snapshot trip() attaches);
 - per-step barrier wait under multi-device ShardingPlan fits: the
   spread between the first and last shard finishing banks as
@@ -53,6 +62,7 @@ Extras carried by the ledger:
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import deque
@@ -60,18 +70,25 @@ from typing import Dict, Optional
 
 from deeplearning4j_tpu.monitor import metrics, trace
 
+log = logging.getLogger("deeplearning4j_tpu")
+
 #: the closed partition every attributed second falls into
 CATEGORIES = ("step_compute", "data_wait", "host_sync", "compile",
               "checkpoint", "eval_gate", "resume_replay", "other")
 
-#: span name -> category: the consumed stream. `train/step` is handled
-#: specially (its residual after contained child spans is step_compute)
-#: and `train/barrier_wait` banks outside the partition.
+#: leaf span name -> category: the consumed stream. `train/step` and
+#: `train/chunk` close an iteration (a step's residual after contained
+#: child spans is step_compute; a chunk's is host bookkeeping and stays
+#: unattributed), and `train/barrier_wait` banks outside the partition.
+#: Listener time is host work and goes where unattributed host time goes.
 SPAN_CATEGORY = {
+    "etl/queue_wait": "data_wait",
     "train/etl": "data_wait",
     "train/device_wait": "step_compute",
-    "train/dispatch": "step_compute",
-    "train/chunk_sync": "step_compute",
+    "train/stage": "step_compute",
+    "train/launch": "step_compute",
+    "train/loss_fetch": "step_compute",
+    "train/listeners": "other",
     "train/host_sync": "host_sync",
     "xla/compile": "compile",
     "resilience/checkpoint_save": "checkpoint",
@@ -79,6 +96,15 @@ SPAN_CATEGORY = {
     "resilience/eval_gate": "eval_gate",
     "train/resume_replay": "resume_replay",
 }
+#: spans around a pull from the iterator: they count only what the
+#: `etl/queue_wait` spans inside them (the prefetch pump) left over
+_PULL_SPANS = ("train/etl", "train/resume_replay")
+#: feed-thread spans a stall is traced into when the fit() thread spent
+#: it in `etl/queue_wait`. `etl/queue_put` is left out: a feed blocked
+#: on a full queue is the healthy state.
+_FEED_SPANS = ("etl/source_next", "etl/stage")
+#: a stalled interval's time on the fit() thread under no leaf span
+NO_SPAN = "_no_span_"
 
 _TIME_HELP = ("Attributed fit() wall-clock seconds per goodput "
               "category (docs/OBSERVABILITY.md 'Goodput accounting')")
@@ -97,19 +123,23 @@ def _cat_counter():
 class _Session:
     """One fit()'s accounting state. Touched only from the fit thread
     (the sink filters on `tid`), except the swap in/out under the
-    ledger lock."""
+    ledger lock and `leaf_now`, which the feed threads add to under
+    `leaf_lock`."""
 
-    __slots__ = ("kind", "tid", "t0", "categories", "buffer",
-                 "barrier_wait_s", "steps", "anomalies", "prev_step_end",
-                 "iter_walls", "cat_mark", "last_anomaly_step", "ctx",
-                 "_binder")
+    __slots__ = ("kind", "tid", "thread", "t0", "categories", "buffer",
+                 "waits", "barrier_wait_s", "steps", "anomalies",
+                 "prev_step_end", "iter_walls", "cat_mark",
+                 "last_anomaly_step", "ctx", "_binder", "leaf_lock",
+                 "leaf_now", "leaf_hist")
 
     def __init__(self, kind: str, clock_now: float, window: int):
         self.kind = kind
         self.tid = threading.get_ident()
+        self.thread = threading.current_thread().name
         self.t0 = clock_now
         self.categories: Dict[str, float] = {c: 0.0 for c in CATEGORIES}
         self.buffer = []              # (t0, t1, dur) since last step
+        self.waits = []               # etl/queue_wait since the last pull
         self.barrier_wait_s = 0.0
         self.steps = 0
         self.anomalies = 0
@@ -119,6 +149,14 @@ class _Session:
         self.last_anomaly_step = -10**9
         self.ctx = None
         self._binder = None
+        # what names a stall: seconds by (leaf span, thread) in the
+        # iteration under way (feed threads write here too, hence the
+        # lock) and by leaf span in each of the last `window` ones (by
+        # name alone: every epoch's pump is a new thread, and its
+        # batches are measured against the pumps before it)
+        self.leaf_lock = threading.Lock()
+        self.leaf_now: Dict[tuple, float] = {}
+        self.leaf_hist: Dict[str, deque] = {}
 
 
 def _median(values) -> float:
@@ -126,6 +164,27 @@ def _median(values) -> float:
     n = len(s)
     mid = n // 2
     return s[mid] if n % 2 else 0.5 * (s[mid - 1] + s[mid])
+
+
+def blame(leaves: Dict[tuple, float], usual: Dict[str, float],
+          thread) -> tuple:
+    """(leaf span, thread, seconds over that leaf's usual) of the leaf
+    that holds most of a slow iteration's excess. `leaves` is the
+    iteration's seconds by (leaf span, thread), `usual` a sound
+    iteration's seconds by leaf span, `thread` the one fit() runs on.
+    It is fit()'s worst leaf or, when that is the wait for the feed
+    (`etl/queue_wait`), the worst leaf of a feed thread, if one ran
+    over. The one rule for the detector below and for the benchmark's
+    readers of the same spans (`benchmark/lib/spans.py`)."""
+    def excess(key):
+        return leaves[key] - usual.get(key[0], 0.0)
+
+    worst = max((k for k in leaves if k[1] == thread), key=excess)
+    if worst[0] == "etl/queue_wait":
+        feed = [k for k in leaves if k[1] != thread]
+        if feed and excess(max(feed, key=excess)) > 0.0:
+            worst = max(feed, key=excess)
+    return worst[0], worst[1], excess(worst)
 
 
 class GoodputLedger:
@@ -216,13 +275,31 @@ class GoodputLedger:
     # ------------------------------------------------------ span sink
     def on_span(self, name: str, t0: float, t1: float, attrs: dict):
         s = self._session
-        if s is None or threading.get_ident() != s.tid:
+        if s is None:
             return
         dur = t1 - t0
         if dur < 0.0:
             return
+        if threading.get_ident() != s.tid:
+            if name in _FEED_SPANS:
+                self._note_leaf(s, name, threading.current_thread().name,
+                                dur)
+            return
         if name == "train/step":
-            self._on_step(s, t0, t1, dur, attrs)
+            # residual: the step extent minus the child spans it contains
+            # (device_wait/host_sync...) is device execution the loop
+            # didn't bracket separately -> step_compute
+            residual = max(dur - self._contained(s.buffer, t0, t1), 0.0)
+            if residual > 0.0:
+                self._bank(s, name, "step_compute", residual)
+            self._end_iteration(s, t1, dur, 1, attrs)
+            return
+        if name == "train/chunk":
+            # one turn of the chunked pipeline closes an iteration of
+            # `steps` optimizer steps; what its leaves did not cover is
+            # host bookkeeping and stays unattributed
+            self._end_iteration(s, t1, dur, int(attrs.get("steps", 0)),
+                                attrs)
             return
         if name == "train/barrier_wait":
             s.barrier_wait_s += dur
@@ -236,26 +313,38 @@ class GoodputLedger:
         cat = SPAN_CATEGORY.get(name)
         if cat is None:
             return
-        s.categories[cat] += dur
+        if name == "etl/queue_wait":
+            s.waits.append((t0, t1, dur))
+        elif name in _PULL_SPANS:
+            dur = max(dur - self._contained(s.waits, t0, t1), 0.0)
+            s.waits.clear()
         s.buffer.append((t0, t1, dur))
-        _cat_counter().inc(dur, category=cat)
+        self._bank(s, name, cat, dur)
 
-    def _on_step(self, s: _Session, t0: float, t1: float, dur: float,
-                 attrs: dict):
-        # residual: the step extent minus the child spans it contains
-        # (device_wait/host_sync/dispatch...) is device execution the
-        # loop didn't bracket separately -> step_compute
+    @staticmethod
+    def _contained(spans, t0: float, t1: float) -> float:
         eps = 1e-9
-        contained = sum(d for (c0, c1, d) in s.buffer
-                        if c0 >= t0 - eps and c1 <= t1 + eps)
+        return sum(d for (c0, c1, d) in spans
+                   if c0 >= t0 - eps and c1 <= t1 + eps)
+
+    def _bank(self, s: _Session, name: str, cat: str, dur: float):
+        s.categories[cat] += dur
+        _cat_counter().inc(dur, category=cat)
+        self._note_leaf(s, name, s.thread, dur)
+
+    @staticmethod
+    def _note_leaf(s: _Session, name: str, thread: str, dur: float):
+        key = (name, thread)
+        with s.leaf_lock:
+            s.leaf_now[key] = s.leaf_now.get(key, 0.0) + dur
+
+    def _end_iteration(self, s: _Session, t1: float, dur: float,
+                       steps: int, attrs: dict):
         s.buffer.clear()
-        residual = max(dur - contained, 0.0)
-        if residual > 0.0:
-            s.categories["step_compute"] += residual
-            _cat_counter().inc(residual, category="step_compute")
-        s.steps += 1
-        # iteration wall: spacing between consecutive step ENDS — it
-        # covers the inter-step gap (ETL, checkpoints), so a stall
+        s.waits.clear()         # a pump driven under no pull span
+        s.steps += steps
+        # iteration wall: spacing between consecutive step/chunk ENDS —
+        # it covers the inter-step gap (ETL, checkpoints), so a stall
         # anywhere in the loop surfaces, not just a slow step
         iter_wall = (t1 - s.prev_step_end
                      if s.prev_step_end is not None else dur)
@@ -263,16 +352,31 @@ class GoodputLedger:
         deltas = {k: s.categories[k] - s.cat_mark[k]
                   for k in s.categories}
         s.cat_mark = dict(s.categories)
+        with s.leaf_lock:
+            leaves, s.leaf_now = s.leaf_now, {}
         wall = t1 - s.t0
         if wall > 0:
             metrics.gauge("train_goodput_pct", _PCT_HELP).set(
                 round(100.0 * s.categories["step_compute"] / wall, 3))
-        self._check_anomaly(s, iter_wall, deltas, attrs)
-        s.iter_walls.append(iter_wall)   # after the check: a spike must
-        #                                  not raise its own baseline
+        if steps <= 0:
+            # a turn that reported no step (the chunked pipeline's fill,
+            # an empty last pull) is no sample of the iteration wall
+            return
+        busy = sum(v for (_, th), v in leaves.items() if th == s.thread)
+        leaves[(NO_SPAN, s.thread)] = max(iter_wall - busy, 0.0)
+        self._check_anomaly(s, iter_wall, deltas, leaves, attrs)
+        # after the check: a spike must not raise its own baseline
+        s.iter_walls.append(iter_wall)
+        by_leaf: Dict[str, float] = {}
+        for (name, _), v in leaves.items():
+            by_leaf[name] = by_leaf.get(name, 0.0) + v
+        for name in set(by_leaf) | set(s.leaf_hist):
+            s.leaf_hist.setdefault(name, deque(maxlen=self.window)).append(
+                by_leaf.get(name, 0.0))
 
     def _check_anomaly(self, s: _Session, iter_wall: float,
-                       deltas: Dict[str, float], attrs: dict):
+                       deltas: Dict[str, float],
+                       leaves: Dict[tuple, float], attrs: dict):
         hist = s.iter_walls
         if len(hist) < self.warmup_steps:
             return
@@ -290,25 +394,39 @@ class GoodputLedger:
         metrics.counter(
             "train_step_anomalies_total",
             "Step-time spikes caught by the rolling median/MAD "
-            "detector (each fires a step_time_anomaly postmortem when "
-            "the flight recorder is on)").inc()
-        # the interval's dominant category names the suspect; when the
-        # unattributed remainder dominates, say "other" honestly
+            "detector (each logs a WARNING, and fires a "
+            "step_time_anomaly postmortem when the flight recorder is "
+            "on)").inc()
+        # the interval's dominant category names the suspect; the
+        # unattributed remainder is `other`, so when it dominates the
+        # trip says "other" honestly
+        deltas["other"] += max(iter_wall - sum(deltas.values()), 0.0)
         dominant = max(deltas, key=deltas.get)
-        unattributed = iter_wall - sum(deltas.values())
-        if unattributed > deltas[dominant]:
-            dominant, dom_s = "other", unattributed
-        else:
-            dom_s = deltas[dominant]
+        dom_s = deltas[dominant]
+        leaf, thread, leaf_s = blame(
+            leaves, {n: _median(h) for n, h in s.leaf_hist.items()},
+            s.thread)
+        step = attrs.get("iteration", attrs.get(
+            "step", attrs.get("chunk", s.steps)))
+        # the operator's line: the library never turns the flight
+        # recorder on, so the log is what a plain fit() user gets
+        log.warning(
+            "fit() stalled at %s %s: %.3f s against a median of %.3f s; "
+            "%.3f s in %s, most of the excess (%.3f s) in %s on thread "
+            "%s", "chunk" if "chunk" in attrs else "step", step,
+            iter_wall, med, dom_s, dominant, leaf_s, leaf, thread)
         from deeplearning4j_tpu.monitor import flight
         flight.trip(
             "step_time_anomaly",
-            step=attrs.get("iteration", attrs.get("step", s.steps)),
+            step=step,
             iteration_wall_s=round(iter_wall, 6),
             median_s=round(med, 6),
             threshold_s=round(threshold, 6),
             dominant_category=dominant,
             dominant_seconds=round(dom_s, 6),
+            leaf_span=leaf,
+            leaf_thread=thread,
+            leaf_excess_s=round(leaf_s, 6),
             trace_id=s.ctx.trace_id if s.ctx else None)
 
     # ------------------------------------------------------ live view

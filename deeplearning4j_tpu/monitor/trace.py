@@ -160,21 +160,25 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs):
+        pass
+
 
 _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "t0", "_ann", "_ctx")
+    __slots__ = ("name", "args", "t0", "_ann", "_ctx", "_annotate")
 
-    def __init__(self, name: str, args: dict, ctx=None):
+    def __init__(self, name: str, args: dict, ctx=None, annotate=True):
         self.name = name
         self.args = args
         self._ann = None
         self._ctx = ctx
+        self._annotate = annotate
 
     def __enter__(self):
-        if _jax_annotations:
+        if _jax_annotations and self._annotate:
             try:
                 import jax
                 self._ann = jax.profiler.TraceAnnotation(self.name)
@@ -183,6 +187,12 @@ class _Span:
                 self._ann = None
         self.t0 = _now_us()
         return self
+
+    def set(self, **attrs):
+        """Attributes known only inside the extent (a chunk's batch
+        count once it has been pulled). They go into the event buffer
+        and to the sink; the profiler annotation keeps the bare name."""
+        self.args.update(attrs)
 
     def __exit__(self, *exc):
         t1 = _now_us()
@@ -213,6 +223,9 @@ class _SinkSpan:
     def __enter__(self):
         self.t0 = time.perf_counter()
         return self
+
+    def set(self, **attrs):
+        self.args.update(attrs)
 
     def __exit__(self, *exc):
         sink = _span_sink
@@ -255,16 +268,22 @@ def _jsonable(v):
     return str(v)
 
 
-def span(name: str, ctx: Optional[TraceContext] = None, **attrs):
+def span(name: str, ctx: Optional[TraceContext] = None,
+         annotate: bool = True, **attrs):
     """Context manager timing one dynamic extent. No-op (shared null
     object) while tracing is disabled. `ctx` overrides the thread-bound
     trace context (for recording on behalf of another thread's
-    request); by default the bound context, if any, is attached."""
+    request); by default the bound context, if any, is attached.
+    `annotate=False` keeps the span out of the profiler's host plane
+    even with `jax_annotations` on: for a wait that is its thread's
+    healthy state (a feed blocked on a full queue), which a reader of
+    the device profile must not take for what the device waited on. It
+    is still in the event buffer and goes to the sink."""
     if not _enabled:
         if _span_sink is not None:
             return _SinkSpan(name, attrs)
         return _NULL
-    return _Span(name, attrs, ctx)
+    return _Span(name, attrs, ctx, annotate)
 
 
 def add_span(name: str, start_s: float, end_s: float,
@@ -300,13 +319,52 @@ def instant(name: str, ctx: Optional[TraceContext] = None, **attrs):
             _thread_names[tid] = threading.current_thread().name
 
 
+#: jax.monitoring duration event -> span name: the time JAX itself
+#: measured around tracing a function to a jaxpr, lowering the jaxpr to
+#: an MLIR module, and the backend compile (a persistent compile-cache
+#: read shows as a short `xla/backend_compile`)
+_COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "xla/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "xla/lower",
+    "/jax/core/compile/backend_compile_duration": "xla/backend_compile",
+}
+_compile_listener_on = False
+
+
+def _on_compile_event(event: str, duration_secs: float, **kwargs):
+    """The one jax.monitoring duration listener: JAX reports an event as
+    it ends, so the span ends now and started a duration earlier."""
+    if not _enabled:
+        return
+    name = _COMPILE_EVENTS.get(event)
+    if name is None:
+        return
+    t1 = _now_us()
+    _record(name, t1 - duration_secs * 1e6, t1,
+            {"fun_name": kwargs.get("fun_name")})
+
+
 def enable_tracing(jax_annotations: bool = False):
     """Start recording spans (idempotent). `jax_annotations=True`
     additionally mirrors every span into jax.profiler.TraceAnnotation so
-    device profiles captured alongside carry the same names."""
-    global _enabled, _jax_annotations
+    device profiles captured alongside carry the same names.
+
+    The first call of a process also registers one `jax.monitoring`
+    duration listener, which records what JAX spends tracing, lowering
+    and compiling as `xla/trace` / `xla/lower` / `xla/backend_compile`
+    spans (`fun_name=...`); it returns at once while tracing is off. A
+    process that never enables tracing registers nothing."""
+    global _enabled, _jax_annotations, _compile_listener_on
     _jax_annotations = bool(jax_annotations)
     _enabled = True
+    if not _compile_listener_on:
+        try:
+            from jax import monitoring
+        except ImportError:      # span recording works without jax
+            return
+        monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        _compile_listener_on = True
 
 
 def disable_tracing():
@@ -338,6 +396,13 @@ def trace_events() -> List[dict]:
     """Copy of the recorded events (Chrome trace-event dicts)."""
     with _lock:
         return list(_events)
+
+
+def thread_names() -> dict:
+    """{thread id: thread name} of every thread that recorded an event
+    (what `save_trace` writes as thread-name metadata)."""
+    with _lock:
+        return dict(_thread_names)
 
 
 def save_trace(path: str, clear: bool = True) -> int:

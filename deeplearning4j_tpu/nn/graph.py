@@ -37,6 +37,11 @@ from deeplearning4j_tpu.util import params as param_util
 log = logging.getLogger("deeplearning4j_tpu")
 
 
+def _mds_examples(mds) -> int:
+    """Rows of one MultiDataSet batch (the `examples=` of a train/chunk)."""
+    return int(np.shape(mds.features[0])[0])
+
+
 class ComputationGraph:
     def __init__(self, conf: ComputationGraphConfiguration):
         self.conf = conf
@@ -461,6 +466,7 @@ class ComputationGraph:
                 copy_marked = mark_copy_for_stacking(data)
             from deeplearning4j_tpu.monitor import goodput
             gp_session = goodput.fit_begin("graph/fit")
+            self._fit_chunk = 0     # train/chunk numbers run over epochs
             try:
                 from deeplearning4j_tpu import monitor
                 for _ in range(epochs):
@@ -656,6 +662,14 @@ class ComputationGraph:
                 None if mds.labels_masks is None else tuple(
                     _as_jnp(m) for m in mds.labels_masks))
 
+    def _stage_stacked(self, group):
+        """K same-shape MultiDataSets -> (inputs, labels, fmasks, lmasks)
+        stacked on a new leading axis, on the device, per the active
+        plan: the ONE staging rule of the scan and accumulation chunks."""
+        stacked = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs), *[self._mds_to_dev(m) for m in group])
+        return tuple(self._shard_tuple(t, stacked=True) for t in stacked)
+
     @staticmethod
     def _mds_sig(mds):
         shapes = lambda t: None if t is None else tuple(
@@ -721,9 +735,12 @@ class ComputationGraph:
         from deeplearning4j_tpu.monitor import xla as xla_ledger
         last_sync = [None]
 
-        def process(p):
-            loss, bs, etl_ms, rec = p
-            self._score = float(loss)
+        def fetch(p):
+            return float(p[0])      # the chunk's one blocking fetch
+
+        def notify(p, score):
+            _, bs, etl_ms, rec = p
+            self._score = score
             if xla_ledger.enabled():
                 now = time.perf_counter()
                 if rec is not None and last_sync[0] is not None:
@@ -735,30 +752,29 @@ class ComputationGraph:
                                    self.epoch_count, self._score, etl_ms,
                                    bs)
             self.iteration_count += 1
+            return 1
 
-        def dispatch(group, etl_ms):
+        def stage(group):
             nonlocal rng
             subs = []
             for _ in group:
                 rng, sub = jax.random.split(rng)
                 subs.append(sub)
-            items = [self._mds_to_dev(m) for m in group]
-            inputs, labels, fmasks, lmasks = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *items)
-            inputs = self._shard_tuple(inputs, stacked=True)
-            labels = self._shard_tuple(labels, stacked=True)
-            fmasks = self._shard_tuple(fmasks, stacked=True)
-            lmasks = self._shard_tuple(lmasks, stacked=True)
+            inputs, labels, fmasks, lmasks = self._stage_stacked(group)
+            bs = _mds_examples(group[0]) * len(group)
+            return (inputs, labels, fmasks, lmasks, jnp.stack(subs), bs,
+                    len(group))
+
+        def launch(staged, etl_ms):
+            inputs, labels, fmasks, lmasks, subs_d, bs, n = staged
             sig = ("accum", fmasks is not None, lmasks is not None)
             if sig not in self._scan_step:
                 self._scan_step[sig] = self._make_accum_step()
             kstep = self._scan_step[sig]
-            subs_d = jnp.stack(subs)
             (self.params, self.opt_state, self.state,
              loss) = kstep(
                 self.params, self.opt_state, self.state, inputs, labels,
                 fmasks, lmasks, subs_d)
-            bs = int(np.shape(group[0].features[0])[0]) * len(group)
             rec = None
             if xla_ledger.enabled():
                 key = (id(kstep), xla_ledger.shape_key(
@@ -769,20 +785,21 @@ class ComputationGraph:
                     "graph/accum_step", kstep,
                     (self.params, self.opt_state, self.state, inputs,
                      labels, fmasks, lmasks, subs_d),
-                    examples_per_call=bs,
-                    steps_per_call=len(group))
+                    examples_per_call=bs, steps_per_call=n)
                 if fresh:
                     last_sync[0] = None   # exclude the AOT compile interval
             return (loss, bs, etl_ms, rec)
 
-        # _iter_data, not _mds_stream: dispatch stacks K host batches
+        # _iter_data, not _mds_stream: stage() stacks K host batches
         # into ONE transfer; the prefetch stream's per-batch device_put
         # would round-trip each micro-batch through the host (same rule
         # as _fit_epoch_scan)
-        _run_scan_pipeline(self._iter_data(data), self._mds_sig, dispatch,
-                           process, K,
-                           defer=not _scan_incompatible_listeners(
-                               self.listeners))
+        self._fit_chunk = _run_scan_pipeline(
+            self._iter_data(data), K, sig_of=self._mds_sig,
+            examples_of=_mds_examples, stage=stage, launch=launch,
+            fetch=fetch, notify=notify,
+            defer=not _scan_incompatible_listeners(self.listeners),
+            first_chunk=self._fit_chunk)
         return rng
 
     def _fit_epoch_scan(self, data, rng, K):
@@ -795,9 +812,11 @@ class ComputationGraph:
         from deeplearning4j_tpu.monitor import xla as xla_ledger
         last_sync = [None]
 
-        def process(p):
-            losses, bs, etl_ms, rec = p
-            arr = np.asarray(losses)
+        def fetch(p):
+            return np.asarray(p[0])             # single blocking fetch/chunk
+
+        def notify(p, arr):
+            _, bs, etl_ms, rec = p
             if xla_ledger.enabled():
                 # steady-state chunk wall = spacing between chunk syncs;
                 # the stamp advances on EVERY chunk so a ragged tail
@@ -808,7 +827,7 @@ class ComputationGraph:
                     xla_ledger.observe_step(rec, now - last_sync[0])
                 last_sync[0] = now
             for loss in arr:
-                # graftlint: disable=host-sync-in-hot-path -- chunk losses are already host-resident (np.asarray above IS the deferred chunk sync); this is per-iteration bookkeeping
+                # graftlint: disable=host-sync-in-hot-path -- chunk losses are already host-resident (fetch() above IS the deferred chunk sync); this is per-iteration bookkeeping
                 self._score = float(loss)
                 _record_iteration(self._score, bs)
                 for lst in self.listeners:
@@ -817,54 +836,46 @@ class ComputationGraph:
                                        etl_ms, bs)
                 self.iteration_count += 1
                 etl_ms = 0.0
+            return len(arr)
 
-        def to_dev(mds):
-            return (tuple(self._stage_x(f) for f in mds.features),
-                    tuple(_as_jnp(l, self._compute_dtype) for l in mds.labels),
-                    None if mds.features_masks is None else tuple(
-                        _as_jnp(m) for m in mds.features_masks),
-                    None if mds.labels_masks is None else tuple(
-                        _as_jnp(m) for m in mds.labels_masks))
-
-        def dispatch(group, etl_ms):
+        def stage(group):
             nonlocal rng
             subs = []
             for _ in group:
                 rng, sub = jax.random.split(rng)
                 subs.append(sub)
-            bs = int(np.shape(group[0].features[0])[0])
+            bs = _mds_examples(group[0])
             if len(group) < K:
-                # ragged tail / shape-change remainder: reuse the compiled
-                # per-call step instead of a one-off scan-of-len(group)
+                # ragged tail / shape-change remainder: staged batch by
+                # batch for the compiled per-call step instead of a
+                # one-off scan-of-len(group)
+                return ([tuple(self._shard_tuple(t)
+                               for t in self._mds_to_dev(m))
+                         for m in group], subs, bs, True)
+            return self._stage_stacked(group), jnp.stack(subs), bs, False
+
+        def launch(staged, etl_ms):
+            parts, subs, bs, tail = staged
+            if tail:
                 losses = []
-                for mds, sub in zip(group, subs):
-                    inputs, labels, fmasks, lmasks = to_dev(mds)
-                    inputs = self._shard_tuple(inputs)
-                    labels = self._shard_tuple(labels)
-                    fmasks = self._shard_tuple(fmasks)
-                    lmasks = self._shard_tuple(lmasks)
+                for (inputs, labels, fmasks, lmasks), sub in zip(parts,
+                                                                 subs):
                     (self.params, self.opt_state, self.state, loss,
                      _) = self._train_step(
                         self.params, self.opt_state, self.state, inputs,
                         labels, fmasks, lmasks, sub, None)
                     losses.append(loss)
                 return (jnp.stack(losses), bs, etl_ms, None)
-            items = [to_dev(m) for m in group]
-            inputs, labels, fmasks, lmasks = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *items)
-            inputs = self._shard_tuple(inputs, stacked=True)
-            labels = self._shard_tuple(labels, stacked=True)
-            fmasks = self._shard_tuple(fmasks, stacked=True)
-            lmasks = self._shard_tuple(lmasks, stacked=True)
-            sig = (len(group), fmasks is not None, lmasks is not None)
+            inputs, labels, fmasks, lmasks = parts
+            n = int(subs.shape[0])
+            sig = (n, fmasks is not None, lmasks is not None)
             if sig not in self._scan_step:
                 self._scan_step[sig] = self._make_scan_step()
             kstep = self._scan_step[sig]
-            subs_d = jnp.stack(subs)
             (self.params, self.opt_state, self.state,
              losses) = kstep(
                 self.params, self.opt_state, self.state, inputs, labels,
-                fmasks, lmasks, subs_d)
+                fmasks, lmasks, subs)
             rec = None
             if xla_ledger.enabled():
                 key = (id(kstep), xla_ledger.shape_key(
@@ -874,21 +885,16 @@ class ComputationGraph:
                     self._ledger_cache, key,
                     "graph/scan_step", kstep,
                     (self.params, self.opt_state, self.state, inputs,
-                     labels, fmasks, lmasks, subs_d),
-                    examples_per_call=bs * len(group),
-                    steps_per_call=len(group))
+                     labels, fmasks, lmasks, subs),
+                    examples_per_call=bs * n, steps_per_call=n)
                 if fresh:
                     last_sync[0] = None   # exclude the AOT compile interval
             return (losses, bs, etl_ms, rec)
 
-        def sig_of(mds):
-            shapes = lambda t: None if t is None else tuple(
-                np.shape(a) for a in t)
-            return (shapes(mds.features), shapes(mds.labels),
-                    shapes(mds.features_masks), shapes(mds.labels_masks))
-
-        _run_scan_pipeline(self._iter_data(data), sig_of, dispatch, process,
-                           K)
+        self._fit_chunk = _run_scan_pipeline(
+            self._iter_data(data), K, sig_of=self._mds_sig,
+            examples_of=_mds_examples, stage=stage, launch=launch,
+            fetch=fetch, notify=notify, first_chunk=self._fit_chunk)
         return rng
 
     def _fit_tbptt_batch(self, inputs, labels, fmasks, lmasks, rng, etl_ms,

@@ -114,56 +114,99 @@ def _record_iteration(score: float, batch_size: int,
                           ).observe(sync_seconds)
 
 
-def _run_scan_pipeline(batches, sig_of, dispatch, process, K, defer=True):
+def _ds_examples(ds) -> int:
+    """Rows of one DataSet batch (the `examples=` of a train/chunk)."""
+    return int(np.shape(ds.features)[0])
+
+
+def _run_scan_pipeline(batches, K, *, sig_of, examples_of, stage, launch,
+                       fetch, notify, defer=True, first_chunk=0):
     """Shared chunking/deferral loop of the input-pipelined fit paths
-    (MultiLayerNetwork._fit_epoch_scan/_fit_epoch_accum,
-    ComputationGraph._fit_epoch_scan).
+    (the `_fit_epoch_scan` / `_fit_epoch_accum` of both containers).
 
-    Groups consecutive batches with identical shape signature `sig_of(b)`
-    into chunks of at most K, calls `dispatch(group, etl_ms)` for each
-    chunk (returning an opaque pending record whose device values are still
-    futures), and calls `process(pending)` for chunk i only AFTER chunk
-    i+1 has been dispatched — so the host-side stacking and dispatch of the
-    next chunk overlaps the device compute of the current one, and the one
-    blocking loss fetch per chunk happens while the device is busy.
-    defer=False processes each chunk in lockstep instead (model-reading
-    listeners must observe the params as of the step they're told about)."""
+    One turn pulls consecutive batches with identical shape signature
+    `sig_of(b)` into a chunk of at most K, stages and launches it
+    (`stage(group)` -> staged device inputs, `launch(staged, etl_ms)` ->
+    an opaque pending record whose device values are still futures), and
+    only then syncs the chunk launched one turn EARLIER
+    (`fetch(pending)` blocks on its losses, `notify(pending, fetched)`
+    runs the per-step bookkeeping and listeners and returns the number
+    of optimizer steps it reported) — so staging and launching chunk i
+    overlap the device compute of chunk i-1, and the one blocking loss
+    fetch per chunk happens while the device is busy (on a TPU the
+    staging's own enqueues can block first: PERF.md section 5). The last turn
+    pulls nothing and drains. defer=False syncs each chunk in the turn
+    that launched it (model-reading listeners must observe the params
+    as of the step they're told about).
+
+    Every phase is an ENTERED span, so with
+    `enable_tracing(jax_annotations=True)` the whole tree is on the
+    profiler's host plane (docs/OBSERVABILITY.md "Tracing"):
+
+        train/chunk                 chunk=i batches= examples= steps=
+          train/etl                 batches=    (etl/queue_wait inside)
+          train/dispatch            chunk=i
+            train/stage / train/launch
+          train/chunk_sync          chunk=i-1
+            train/loss_fetch / train/listeners steps=
+
+    `chunk` counts from `first_chunk` (the container keeps it running
+    over the epochs of one fit()); returns the next chunk's number."""
     from deeplearning4j_tpu import monitor
-    pending = None
-    group, gsig = [], None
-    etl_start = time.perf_counter()
-
-    def flush():
-        nonlocal pending, group, etl_start
-        etl_end = time.perf_counter()
-        etl_ms = (etl_end - etl_start) * 1e3
-        monitor.add_span("train/etl", etl_start, etl_end,
-                         batches=len(group))
-        monitor.counter("train_chunks_dispatched_total",
-                        "Scan/accum chunks dispatched to the device").inc()
-        with monitor.span("train/dispatch", batches=len(group)):
-            fresh = dispatch(group, etl_ms)
-        if not defer:
-            with monitor.span("train/chunk_sync"):
-                process(fresh)
-        else:
-            if pending is not None:
-                with monitor.span("train/chunk_sync"):
-                    process(pending)
-            pending = fresh
-        group, etl_start = [], time.perf_counter()
-
-    for b in batches:
-        s = sig_of(b)
-        if group and (s != gsig or len(group) == K):
-            flush()
-        group.append(b)
-        gsig = s
-    if group:
-        flush()
-    if pending is not None:
-        with monitor.span("train/chunk_sync"):
-            process(pending)
+    span = monitor.span
+    it = iter(batches)
+    chunk = first_chunk
+    held = None          # the batch whose shape change closed the last group
+    pending = None       # (chunk, record) launched and not yet synced
+    exhausted = False
+    while not (exhausted and held is None and pending is None):
+        with span("train/chunk", chunk=chunk) as turn:
+            etl_start = time.perf_counter()
+            with span("train/etl") as etl:
+                group, held = ([] if held is None else [held]), None
+                gsig = sig_of(group[0]) if group else None
+                while len(group) < K and not exhausted:
+                    try:
+                        b = next(it)
+                    except StopIteration:
+                        exhausted = True
+                        break
+                    s = sig_of(b)
+                    if group and s != gsig:
+                        held = b
+                        break
+                    group.append(b)
+                    gsig = s
+                etl.set(batches=len(group))
+            etl_ms = (time.perf_counter() - etl_start) * 1e3
+            fresh = None
+            if group:
+                with span("train/dispatch", chunk=chunk):
+                    with span("train/stage"):
+                        staged = stage(group)
+                    with span("train/launch"):
+                        fresh = (chunk, launch(staged, etl_ms))
+                    # the launched program alone keeps its inputs from
+                    # here: a reference held into the next turn's stage()
+                    # adds a whole chunk of device memory to the peak
+                    # (PERF.md section 6, PR 24)
+                    del staged
+            due, pending = (pending, fresh) if defer else (fresh, None)
+            steps = 0
+            if due is not None:
+                with span("train/chunk_sync", chunk=due[0]):
+                    with span("train/loss_fetch"):
+                        fetched = fetch(due[1])
+                    with span("train/listeners") as told:
+                        steps = notify(due[1], fetched)
+                        told.set(steps=steps)
+            turn.set(batches=len(group), steps=steps,
+                     examples=len(group) * examples_of(group[0])
+                     if group else 0)
+        if not group:
+            break
+        chunk += 1
+    return chunk
 
 
 def _required_kind(layer: LayerConf) -> Optional[Kind]:
@@ -366,6 +409,21 @@ class MultiLayerNetwork:
         if plan is None:
             return arrs
         return tuple(plan.shard_batch(a, stacked=stacked) for a in arrs)
+
+    def _stage_stacked(self, group):
+        """K same-shape host batches -> (xs, ys, fms, lms) stacked on a
+        new leading axis, on the device, per the active plan: the ONE
+        staging rule of the scan and accumulation chunks."""
+        ds0 = group[0]
+        stack = lambda get, dt=None: (
+            None if get(ds0) is None else
+            _as_jnp(np.stack([np.asarray(get(d)) for d in group]), dt))
+        xs = None if ds0.features is None else self._stage_x(
+            np.stack([np.asarray(d.features) for d in group]))
+        return self._shard_batch(
+            xs, stack(lambda d: d.labels, self._compute_dtype),
+            stack(lambda d: d.features_mask),
+            stack(lambda d: d.labels_mask), stacked=True)
 
     def set_listeners(self, *listeners):
         self.listeners = list(listeners)
@@ -747,6 +805,7 @@ class MultiLayerNetwork:
                     cast_features=self._input_affine is None)
             from deeplearning4j_tpu.monitor import goodput
             gp_session = goodput.fit_begin("mln/fit")
+            self._fit_chunk = 0     # train/chunk numbers run over epochs
             try:
                 from deeplearning4j_tpu import monitor
                 for _ in range(epochs):
@@ -1027,9 +1086,12 @@ class MultiLayerNetwork:
         warned_partial = [False]
         last_sync = [None]
 
-        def process(p):
-            loss, bs, etl_ms, capture, grads, updates, rec = p
-            self._score = float(loss)
+        def fetch(p):
+            return float(p[0])      # the chunk's one blocking fetch
+
+        def notify(p, score):
+            _, bs, etl_ms, capture, grads, updates, rec = p
+            self._score = score
             if xla_ledger.enabled():
                 now = time.perf_counter()
                 if rec is not None and last_sync[0] is not None:
@@ -1044,8 +1106,9 @@ class MultiLayerNetwork:
                                    self.epoch_count, self._score, etl_ms,
                                    bs)
             self.iteration_count += 1
+            return 1
 
-        def dispatch(group, etl_ms):
+        def stage(group):
             nonlocal rng
             if len(group) < K and not warned_partial[0]:
                 # _run_scan_pipeline only groups CONSECUTIVE same-shape
@@ -1069,21 +1132,15 @@ class MultiLayerNetwork:
             for _ in group:
                 rng, sub = jax.random.split(rng)
                 subs.append(sub)
-            ds0 = group[0]
-            stack = lambda get, dt=None: (
-                None if get(ds0) is None else
-                _as_jnp(np.stack([np.asarray(get(d)) for d in group]), dt))
-            xs = None if ds0.features is None else self._stage_x(
-                np.stack([np.asarray(d.features) for d in group]))
-            ys = stack(lambda d: d.labels, self._compute_dtype)
-            fms = stack(lambda d: d.features_mask)
-            lms = stack(lambda d: d.labels_mask)
-            xs, ys, fms, lms = self._shard_batch(xs, ys, fms, lms,
-                                                 stacked=True)
+            xs, ys, fms, lms = self._stage_stacked(group)
+            bs = _ds_examples(group[0]) * len(group)
+            return xs, ys, fms, lms, jnp.stack(subs), bs, len(group)
+
+        def launch(staged, etl_ms):
+            xs, ys, fms, lms, subs_d, bs, n = staged
             capture = [lst for lst in grad_listeners
                        if lst.should_capture(self.iteration_count)]
             kstep = self._get_accum_step(with_stats=bool(capture))
-            subs_d = jnp.stack(subs)
             out = kstep(self.params, self.opt_state, self.state, xs, ys,
                         fms, lms, subs_d)
             grads = updates = None
@@ -1092,7 +1149,6 @@ class MultiLayerNetwork:
                  updates) = out
             else:
                 self.params, self.opt_state, self.state, loss = out
-            bs = int(np.shape(ds0.features)[0]) * len(group)
             rec = None
             if xla_ledger.enabled():
                 key = (id(kstep), xla_ledger.shape_key((xs, ys, fms, lms)))
@@ -1102,7 +1158,7 @@ class MultiLayerNetwork:
                     "mln/accum_step", kstep,
                     (self.params, self.opt_state, self.state, xs, ys, fms,
                      lms, subs_d), examples_per_call=bs,
-                    steps_per_call=len(group))
+                    steps_per_call=n)
                 if fresh:
                     last_sync[0] = None   # exclude the AOT compile interval
             return loss, bs, etl_ms, capture, grads, updates, rec
@@ -1120,9 +1176,11 @@ class MultiLayerNetwork:
         # model-reading listeners (that would change the optimization) —
         # it drops the one-chunk deferral instead so each callback sees
         # the params of the step it reports
-        _run_scan_pipeline(iterator, sig_of, dispatch, process, K,
-                           defer=not _scan_incompatible_listeners(
-                               self.listeners))
+        self._fit_chunk = _run_scan_pipeline(
+            iterator, K, sig_of=sig_of, examples_of=_ds_examples,
+            stage=stage, launch=launch, fetch=fetch, notify=notify,
+            defer=not _scan_incompatible_listeners(self.listeners),
+            first_chunk=self._fit_chunk)
 
     def _get_scan_step(self, fmask, lmask, K):
         sig = (fmask is not None, lmask is not None, K)
@@ -1142,9 +1200,11 @@ class MultiLayerNetwork:
         rng = jax.random.PRNGKey(self.conf.seed + 7919 * (self.epoch_count + 1))
         last_sync = [None]   # previous chunk-sync stamp: chunk wall clock
 
-        def process(p):
-            losses, bs, etl_ms, rec = p
-            arr = np.asarray(losses)            # single blocking fetch/chunk
+        def fetch(p):
+            return np.asarray(p[0])             # single blocking fetch/chunk
+
+        def notify(p, arr):
+            _, bs, etl_ms, rec = p
             if xla_ledger.enabled():
                 # steady-state chunk wall time = spacing between chunk
                 # syncs (the pipelined path has no un-overlapped "this
@@ -1157,7 +1217,7 @@ class MultiLayerNetwork:
                     xla_ledger.observe_step(rec, now - last_sync[0])
                 last_sync[0] = now
             for loss in arr:
-                # graftlint: disable=host-sync-in-hot-path -- chunk losses are already host-resident (np.asarray above IS the deferred chunk sync); this is per-iteration bookkeeping
+                # graftlint: disable=host-sync-in-hot-path -- chunk losses are already host-resident (fetch() above IS the deferred chunk sync); this is per-iteration bookkeeping
                 self._score = float(loss)
                 _record_iteration(self._score, bs)
                 for lst in self.listeners:
@@ -1166,68 +1226,63 @@ class MultiLayerNetwork:
                                        etl_ms, bs)
                 self.iteration_count += 1
                 etl_ms = 0.0
+            return len(arr)
 
-        def dispatch(group, etl_ms):
+        def stage(group):
             nonlocal rng
             subs = []
             for _ in group:
                 rng, sub = jax.random.split(rng)
                 subs.append(sub)
             ds0 = group[0]
-            rec = None
+            bs = _ds_examples(ds0)
             if len(group) < K:
                 # ragged tail / shape-change remainder: reuse the already
                 # compiled per-call step rather than compiling a one-off
                 # scan-of-len(group) program
-                step = self._get_train_step(ds0.features_mask,
-                                            ds0.labels_mask, None)
+                tail = [self._shard_batch(
+                    self._stage_x(ds.features),
+                    _as_jnp(ds.labels, self._compute_dtype),
+                    _as_jnp(ds.features_mask),
+                    _as_jnp(ds.labels_mask)) for ds in group]
+                return tail, subs, bs, ds0
+            return self._stage_stacked(group), jnp.stack(subs), bs, None
+
+        def launch(staged, etl_ms):
+            inputs, subs, bs, tail_of = staged
+            if tail_of is not None:
+                step = self._get_train_step(tail_of.features_mask,
+                                            tail_of.labels_mask, None)
                 losses = []
-                for ds, sub in zip(group, subs):
-                    txs, tys, tfm, tlm = self._shard_batch(
-                        self._stage_x(ds.features),
-                        _as_jnp(ds.labels, self._compute_dtype),
-                        _as_jnp(ds.features_mask),
-                        _as_jnp(ds.labels_mask))
+                for (txs, tys, tfm, tlm), sub in zip(inputs, subs):
                     out = step(self.params, self.opt_state, self.state,
                                txs, tys, tfm, tlm, sub, None)
                     self.params, self.opt_state, self.state, loss, _ = out
                     losses.append(loss)
-                losses = jnp.stack(losses)
-            else:
-                stack = lambda get, dt=None: (
-                    None if get(ds0) is None else
-                    _as_jnp(np.stack([np.asarray(get(d)) for d in group]),
-                            dt))
-                xs = None if ds0.features is None else self._stage_x(
-                    np.stack([np.asarray(d.features) for d in group]))
-                ys = stack(lambda d: d.labels, self._compute_dtype)
-                fms = stack(lambda d: d.features_mask)
-                lms = stack(lambda d: d.labels_mask)
-                xs, ys, fms, lms = self._shard_batch(xs, ys, fms, lms,
-                                                     stacked=True)
-                kstep = self._get_scan_step(fms, lms, len(group))
-                subs_d = jnp.stack(subs)
-                (self.params, self.opt_state, self.state,
-                 losses) = kstep(self.params, self.opt_state, self.state,
-                                 xs, ys, fms, lms, subs_d)
-                if xla_ledger.enabled():
-                    key = (id(kstep),
-                           xla_ledger.shape_key((xs, ys, fms, lms)))
-                    fresh = key not in self._ledger_cache
-                    rec = xla_ledger.capture_cached(
-                        self._ledger_cache, key,
-                        "mln/scan_step", kstep,
-                        (self.params, self.opt_state, self.state, xs, ys,
-                         fms, lms, subs_d),
-                        examples_per_call=(
-                            int(np.shape(ds0.features)[0]) * len(group)),
-                        steps_per_call=len(group))
-                    if fresh:
-                        # the capture's AOT compile sat inside this
-                        # inter-chunk interval — restart the MFU clock so
-                        # it can't read as a slow chunk
-                        last_sync[0] = None
-            return losses, int(np.shape(ds0.features)[0]), etl_ms, rec
+                return jnp.stack(losses), bs, etl_ms, None
+            xs, ys, fms, lms = inputs
+            n = int(subs.shape[0])
+            kstep = self._get_scan_step(fms, lms, n)
+            (self.params, self.opt_state, self.state,
+             losses) = kstep(self.params, self.opt_state, self.state,
+                             xs, ys, fms, lms, subs)
+            rec = None
+            if xla_ledger.enabled():
+                key = (id(kstep),
+                       xla_ledger.shape_key((xs, ys, fms, lms)))
+                fresh = key not in self._ledger_cache
+                rec = xla_ledger.capture_cached(
+                    self._ledger_cache, key,
+                    "mln/scan_step", kstep,
+                    (self.params, self.opt_state, self.state, xs, ys,
+                     fms, lms, subs),
+                    examples_per_call=bs * n, steps_per_call=n)
+                if fresh:
+                    # the capture's AOT compile sat inside this
+                    # inter-chunk interval — restart the MFU clock so
+                    # it can't read as a slow chunk
+                    last_sync[0] = None
+            return losses, bs, etl_ms, rec
 
         def sig_of(ds):
             return (np.shape(ds.features), np.shape(ds.labels),
@@ -1236,7 +1291,10 @@ class MultiLayerNetwork:
                     None if ds.labels_mask is None
                     else np.shape(ds.labels_mask))
 
-        _run_scan_pipeline(iterator, sig_of, dispatch, process, K)
+        self._fit_chunk = _run_scan_pipeline(
+            iterator, K, sig_of=sig_of, examples_of=_ds_examples,
+            stage=stage, launch=launch, fetch=fetch, notify=notify,
+            first_chunk=self._fit_chunk)
 
     def _fit_epoch_tbptt(self, iterator):
         """Truncated BPTT: chunk the time axis, carry RNN state across chunks,

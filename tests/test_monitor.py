@@ -285,7 +285,6 @@ def test_fit_scan_path_records_iterations():
     net.fit((X, Y), epochs=1, batch_size=16, scan_steps=3)
     reg = monitor.REGISTRY
     assert reg.collect("train_iterations_total").value() == 3
-    assert reg.collect("train_chunks_dispatched_total").value() >= 1
 
 
 def test_performance_listener_consistent_and_feeds_registry():

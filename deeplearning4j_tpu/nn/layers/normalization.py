@@ -2,9 +2,18 @@
 
 - BatchNormalization <- DL4J nn/conf/layers/BatchNormalization.java; impl
   nn/layers/normalization/BatchNormalization.java (cuDNN helper
-  CudnnBatchNormalizationHelper). XLA fuses the normalize+scale+shift chain;
-  running statistics live in the layer *state* pytree (the analog of DL4J's
-  global mean/var params updated with `decay`).
+  CudnnBatchNormalizationHelper). Running statistics live in the layer
+  *state* pytree (the analog of DL4J's global mean/var params updated with
+  `decay`). What a training step costs in passes over the activation, as
+  XLA:TPU compiles it (PERF.md, PR 25): forward ONE read for both batch
+  statistics (`_batch_moments`; a pass of its own, because its shift is a
+  sample of the producer's output and so cannot ride in the producer's
+  fusion), the normalize+scale+shift chain fused into the consumer (the
+  next convolution reads `x` and the two vectors);
+  backward the sums over `dy` and `x` as siblings on one level, fused with
+  their neighbours, and a `dx` that waits on those vectors alone. No
+  reduction over the activation waits on another of its direction.
+  Inference reads the running state and reduces nothing.
 - LocalResponseNormalization <- nn/conf/layers/LocalResponseNormalization.java
   (cuDNN helper CudnnLocalResponseNormalizationHelper) — AlexNet-era
   cross-channel LRN.
@@ -12,11 +21,40 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.nn.conf.base import InputType, LayerConf, register_layer
+
+
+# jitted as `jnp.mean` and `jnp.var` are: a network's batch norms then share
+# one traced and lowered body a shape. Inline, the 53 of ResNet-50 made the
+# step's lowering 20 % longer (3.4 s of the benchmark's set-up, PR 25).
+@functools.partial(jax.jit, static_argnames=("axes", "stat_t"))
+def _batch_moments(x, axes, stat_t):
+    """Per-channel mean and biased variance from ONE traversal of `x`.
+
+    `sum(x - k)` and `sum((x - k)**2)`, accumulated in `stat_t`, are sibling
+    reductions over the same operand, and the gradient of both reaches `x`
+    through per-channel vectors alone, so in neither direction does a
+    reduction over the activation wait on another. `k` is the batch's first
+    position: a sample of the data costs no pass and keeps
+    `E[d*d] - E[d]**2` from cancelling when |mean| >> std (the running mean
+    would not: it is zero at step one). The variance does not depend on `k`,
+    so stopping its gradient is exact.
+    """
+    # sliced BEFORE the conversion: sliced after it, XLA:TPU has the
+    # producing convolution write a `stat_t` copy of the whole activation
+    k = lax.stop_gradient(x[(0,) * len(axes)].astype(stat_t))
+    d = x.astype(stat_t) - k
+    n = math.prod(x.shape[a] for a in axes)
+    m1 = jnp.sum(d, axis=axes) / n
+    m2 = jnp.sum(d * d, axis=axes) / n
+    return k + m1, jnp.maximum(m2 - m1 * m1, 0.0)
 
 
 @register_layer
@@ -45,8 +83,7 @@ class BatchNormalization(LayerConf):
         axes = tuple(range(x.ndim - 1))    # all but channel/feature dim
         stat_t = jnp.promote_types(jnp.float32, x.dtype)
         if train:
-            mean = jnp.mean(x.astype(stat_t), axis=axes)
-            var = jnp.var(x.astype(stat_t), axis=axes)
+            mean, var = _batch_moments(x, axes, stat_t)
             new_state = {
                 "mean": self.decay * state["mean"] + (1.0 - self.decay) * mean,
                 "var": self.decay * state["var"] + (1.0 - self.decay) * var,

@@ -192,6 +192,30 @@ def test_gc_batchnorm():
     _check(net, X, Y)
 
 
+@pytest.mark.parametrize("rank,lock", [("dense", False), ("dense", True),
+                                       ("cnn", True)],
+                         ids=["dense", "dense-locked", "cnn-locked"])
+def test_gc_batchnorm_ranks_and_lock(rank, lock):
+    # beside test_gc_batchnorm (cnn, gamma and beta trained): the
+    # statistics reduce over every axis but the last, whatever the rank;
+    # locked gamma/beta leaves the layer without parameters
+    if rank == "dense":
+        X, Y = _ff_data()
+        X = X * 3.0 + 5.0          # un-centred features
+        first = DenseLayer(n_out=4, activation="identity")
+        itype = InputType.feed_forward(5)
+    else:
+        X, Y = _cnn_data()
+        first = ConvolutionLayer(n_out=3, kernel=(3, 3),
+                                 convolution_mode="same",
+                                 activation="identity")
+        itype = InputType.convolutional(6, 6, 2)
+    net = _net([first, BatchNormalization(lock_gamma_beta=lock),
+                OutputLayer(n_out=3, activation="softmax", loss="mcxent")],
+               itype)
+    _check(net, X, Y)
+
+
 def test_gc_lrn():
     X, Y = _cnn_data(ch=4)
     net = _net([ConvolutionLayer(n_out=4, kernel=(3, 3),

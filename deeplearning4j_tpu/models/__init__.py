@@ -23,11 +23,13 @@ from deeplearning4j_tpu.models.zoo import (
     model_by_name,
     zoo_models,
 )
-from deeplearning4j_tpu.models.transformer import TransformerLM, TransformerLMMoE
+from deeplearning4j_tpu.models.transformer import (
+    KimiLinearLM, TransformerLM, TransformerLMMoE,
+)
 
 __all__ = [
     "ZooModel", "LeNet", "SimpleCNN", "AlexNet", "VGG16", "VGG19",
     "ResNet50", "GoogLeNet", "Darknet19", "TinyYOLO", "YOLO2",
     "TextGenerationLSTM", "InceptionResNetV1", "FaceNetNN4Small2", "UNet",
-    "TransformerLM", "TransformerLMMoE", "model_by_name", "zoo_models",
+    "TransformerLM", "TransformerLMMoE", "KimiLinearLM", "model_by_name", "zoo_models",
 ]

@@ -45,6 +45,7 @@ import hashlib
 import json
 import logging
 import os
+import re
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -95,7 +96,7 @@ class ProgramRecord:
     __slots__ = ("fingerprint", "name", "domain", "arg_shapes", "hlo_hash",
                  "compile_seconds", "compiles", "flops", "bytes_accessed",
                  "hbm", "examples_per_call", "steps_per_call",
-                 "first_captured_unix", "arg_shardings")
+                 "first_captured_unix", "arg_shardings", "op_scopes")
 
     def __init__(self, fingerprint, name, domain, arg_shapes, hlo_hash,
                  compile_seconds, flops, bytes_accessed, hbm,
@@ -117,6 +118,12 @@ class ProgramRecord:
         #: and the MFU accountant tell a GSPMD-plan-sharded program from
         #: a replicated one
         self.arg_shardings = tuple(arg_shardings or ())
+        #: compiled instruction name -> the `op_name` XLA kept for it
+        #: (the jax.named_scope path of the op, or of a fusion's root):
+        #: a device trace names ops by instruction, this maps them back
+        #: to the program's scopes ("moe/experts", "opt/update", ...).
+        #: In memory only, not in the saved ledger.
+        self.op_scopes: Dict[str, str] = {}
         self.first_captured_unix = time.time()
 
     @property
@@ -391,6 +398,21 @@ def hbm_stats(ma) -> Dict[str, int]:
     }
 
 
+_OP_NAME = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*\bop_name="([^"]*)"', re.M)
+
+
+def compiled_op_scopes(compiled) -> Dict[str, str]:
+    """{instruction name: op_name metadata} of every instruction of a
+    jax.stages.Compiled that carries one. Empty when the backend gives no
+    text."""
+    try:
+        text = compiled.as_text()
+    except Exception:  # noqa: BLE001 — no HLO text: no map, readers get None
+        return {}
+    return dict(_OP_NAME.findall(text or ""))
+
+
 def analyze_compiled(compiled):
     """(flops, bytes_accessed, hbm dict) from a jax.stages.Compiled —
     None for whatever the backend cannot answer. The ONE place the XLA
@@ -469,6 +491,7 @@ def capture(name: str, fn, args, domain: str = "train",
                                 hlo_hash, t1 - t0, flops, bytes_accessed,
                                 hbm, examples_per_call, steps_per_call,
                                 arg_shardings=arg_shardings)
+            rec.op_scopes = compiled_op_scopes(compiled)
             _records[fingerprint] = rec
         else:
             rec.compiles += 1

@@ -31,7 +31,7 @@ from deeplearning4j_tpu.nn.multilayer import (
     _as_jnp, _default_scan_steps, _record_iteration, _required_kind,
     _run_scan_pipeline, _scan_incompatible_listeners,
 )
-from deeplearning4j_tpu.nn.updaters import NoOp, build_optimizer
+from deeplearning4j_tpu.nn.updaters import NoOp, apply_update, build_optimizer
 from deeplearning4j_tpu.util import params as param_util
 
 log = logging.getLogger("deeplearning4j_tpu")
@@ -379,10 +379,8 @@ class ComputationGraph:
                 # hint from which XLA derives reduce-scatter -> sharded
                 # update -> all-gather (parallel/plan.py)
                 grads = plan.constrain_grads(grads)
-            updates, new_opt = tx.update(grads, opt_state, params)
-            if plan is not None:
-                updates = plan.constrain_grads(updates)
-            new_params = optax.apply_updates(params, updates)
+            new_params, new_opt, _ = apply_update(
+                tx, grads, opt_state, params, plan)
             if constrained:     # post-update projection (DL4J applyConstraints)
                 new_params = apply_constraints(layer_map, new_params)
             if plan is not None:
@@ -633,10 +631,8 @@ class ComputationGraph:
                     loss_fn, has_aux=True)(params)
                 if plan is not None:
                     grads = plan.constrain_grads(grads)
-                updates, new_opt = tx.update(grads, opt_state, params)
-                if plan is not None:
-                    updates = plan.constrain_grads(updates)
-                new_params = optax.apply_updates(params, updates)
+                new_params, new_opt, _ = apply_update(
+                    tx, grads, opt_state, params, plan)
                 if constrained:
                     new_params = apply_constraints(layer_map, new_params)
                 if plan is not None:
@@ -714,10 +710,8 @@ class ComputationGraph:
                 body, (zeros, state), (inputs, labels, fmasks, lmasks,
                                        subs))
             grads = jax.tree_util.tree_map(lambda g: g / k, gsum)
-            updates, new_opt = tx.update(grads, opt_state, params)
-            if plan is not None:
-                updates = plan.constrain_grads(updates)
-            new_params = optax.apply_updates(params, updates)
+            new_params, new_opt, _ = apply_update(
+                tx, grads, opt_state, params, plan)
             if constrained:
                 new_params = apply_constraints(layer_map, new_params)
             if plan is not None:

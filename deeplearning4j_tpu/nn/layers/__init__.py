@@ -23,8 +23,12 @@ from deeplearning4j_tpu.nn.layers.variational import VariationalAutoencoder
 from deeplearning4j_tpu.nn.layers.samediff import SameDiffLayer, FrozenLayerWrapper
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2OutputLayer
 from deeplearning4j_tpu.nn.layers.attention import (
-    EmbeddingSequenceLayer, LayerNormLayer, MoEFeedForward,
-    MultiHeadAttention, PositionalEmbeddingLayer, TransformerBlock,
+    EmbeddingSequenceLayer, GatedMLP, LayerNormLayer, MoEFeedForward,
+    RMSNormLayer, MultiHeadAttention, PositionalEmbeddingLayer,
+    TransformerBlock,
+)
+from deeplearning4j_tpu.nn.layers.linear_attention import (
+    KimiDeltaAttention, MultiHeadLatentAttention,
 )
 
 __all__ = [
@@ -45,5 +49,7 @@ __all__ = [
     "MaskZeroLayer", "VariationalAutoencoder", "SameDiffLayer",
     "FrozenLayerWrapper", "Yolo2OutputLayer",
     "MultiHeadAttention", "TransformerBlock", "MoEFeedForward",
+    "RMSNormLayer", "GatedMLP", "KimiDeltaAttention",
+    "MultiHeadLatentAttention",
     "LayerNormLayer", "PositionalEmbeddingLayer", "EmbeddingSequenceLayer",
 ]

@@ -12,15 +12,24 @@ foundation the sequence-parallel / ring-attention machinery
   (B, T) masks with DL4J mask semantics (0 = padded step);
 - sharding rules: "model"-axis tensor parallelism shards head projections
   column-wise and output row-wise (Megatron pattern), "seq"-axis sequence
-  parallelism is handled by ring attention at the network level.
+  parallelism is handled by ring attention at the network level;
+- ONE expert layer, `MoEFeedForward`: it routes every token over all the
+  experts of the layer, is told which of them it holds, sorts the
+  (token, slot) pairs by expert into grouped matrix products over the
+  held ones (cost follows the rows routed, not the expert count), drops
+  nothing, and adds a shared expert where the model has one. The linear
+  and latent attentions of the hybrid LMs live beside this file in
+  `linear_attention.py` and ride `TransformerBlock` through its ``attn``
+  field.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from deeplearning4j_tpu.nn.conf.base import (
     InputType, Kind, LayerConf, register_layer,
@@ -83,6 +92,37 @@ class LayerNormLayer(LayerConf):
         return y * params["gamma"] + params["beta"], state
 
 
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class RMSNormLayer(LayerConf):
+    """Root-mean-square normalization over the feature axis (no mean, no
+    bias): ``x / sqrt(mean(x^2) + eps) * gamma``, the statistics in
+    float32 whatever the compute dtype."""
+    epsilon: float = 1e-6
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        return {"gamma": jnp.ones((input_type.features,), dtype)}, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        acc_t = jnp.promote_types(jnp.float32, x.dtype)
+        xf = x.astype(acc_t)
+        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        y = xf * jax.lax.rsqrt(ms + self.epsilon)
+        return (y * params["gamma"].astype(acc_t)).astype(x.dtype), state
+
+
+def _norm_layer(kind: str, epsilon: Optional[float]):
+    """The normalization a block names: "layer" (LayerNormLayer) or "rms"
+    (RMSNormLayer); ``epsilon`` None keeps the layer's own default."""
+    cls = {"layer": LayerNormLayer, "rms": RMSNormLayer}.get(kind)
+    if cls is None:
+        raise ValueError(f"unknown norm {kind!r}: 'layer' or 'rms'")
+    return cls() if epsilon is None else cls(epsilon=epsilon)
+
+
 def _split_heads(x, n_heads):
     b, t, f = x.shape
     return x.reshape(b, t, n_heads, f // n_heads)
@@ -118,7 +158,9 @@ def dot_product_attention(q, k, v, *, mask=None, causal=False,
 
     mask: (B, Tk) 0/1 key-validity mask (DL4J mask semantics).
     q_offset/k_offset: global position offsets (used by ring attention to
-    apply causal masking across sequence shards)."""
+    apply causal masking across sequence shards). v's head width may
+    differ from q's and k's (latent attention: 192-wide q.k, 128-wide v);
+    the scale is that of q's width."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
     # accumulate scores in >= f32 (bf16 inputs -> f32 on the MXU; f64 stays
@@ -294,13 +336,364 @@ class MultiHeadAttention(LayerConf):
         return y, state
 
 
+# --- expert dispatch -------------------------------------------------------
+# Rows move between token order and expert order by GATHERS in both
+# directions: each of the two moves below is a permutation (or a k-fold
+# copy) whose transpose is again a gather through the inverse permutation.
+# Autodiff of `x[idx]` would emit a scatter-add instead.
+
+@jax.custom_vjp
+def _rows_to_experts(h, order, inverse):
+    """(N, F) token rows -> (N*k, F) rows in expert order: sorted row r is
+    token ``order[r] // k``."""
+    return h[order // (order.shape[0] // h.shape[0])]
+
+
+def _rows_to_experts_fwd(h, order, inverse):
+    return _rows_to_experts(h, order, inverse), (inverse, h.shape[0])
+
+
+def _rows_to_experts_bwd(res, g):
+    inverse, n = res
+    acc_t = jnp.promote_types(jnp.float32, g.dtype)
+    return g[inverse].reshape(n, -1, g.shape[-1]).astype(acc_t).sum(1) \
+        .astype(g.dtype), None, None
+
+
+_rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_tokens(y, order, inverse):
+    """(N*k, F) rows in expert order -> the same rows in (token, slot)
+    order."""
+    return y[inverse]
+
+
+def _rows_to_tokens_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _rows_to_tokens_bwd(order, g):
+    return g[order], None, None
+
+
+_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
+#: the worst-case rows of one dispatch (tokens x top_k, each an input, a
+#: hidden and an output row) may take this much; more tokens go in blocks
+_DISPATCH_LIVE_BYTES = 512 << 20
+
+
+def _grouped_matmul(x, w, sizes):
+    """Rows of ``x`` (M, K), sorted by group, times their group's matrix
+    of ``w`` (G, K, N): row r of group g gives ``x[r] @ w[g]``. Only the
+    first ``sum(sizes)`` rows are products; the rest is undefined (zeros
+    on the CPU, whatever the buffer held on the TPU), here and in the
+    transposed products of the backward pass, and the caller masks it.
+    On the v5e ``jax.lax.ragged_dot`` walks ALL M static rows, tile by
+    tile, each with the one or two matrices it needs: the cost follows
+    the static row count (not M x G), whatever share of the rows is in a
+    group (measured: PERF.md section 5)."""
+    return jax.lax.ragged_dot(x, w, sizes.astype(jnp.int32))
+
+
+def _gated_mlp(x, wgate, wup, wdown, activation):
+    """``(act(x Wgate) * (x Wup)) Wdown``."""
+    from deeplearning4j_tpu.nn.activations import get_activation
+    return (get_activation(activation)(x @ wgate) * (x @ wup)) @ wdown
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class MoEFeedForward(LayerConf):
+    """Mixture-of-experts FFN with real top-k dispatch — the
+    expert-parallel (EP) building block.
+
+    Every token is routed over ALL ``n_experts`` by the router ``Wr``.
+    ``router="softmax"``: the ``top_k`` largest logits kept, a softmax over
+    the kept ones. ``router="sigmoid"``: scores ``s = sigmoid(logits)``,
+    the ``top_k`` largest of ``s + b`` kept (``b``: a NON-trained
+    correction vector kept in the layer's state as ``route_bias``, zero
+    at init), weights ``s_i / sum_kept(s) * routed_scale``. The layer
+    computes the part of the result that the experts it HOLDS give:
+    ``experts_held=(lo, hi)`` names them (None: all), the weights of a
+    token's other experts are left as routed, and their part is left out
+    — on one chip of an expert-parallel layout that partial sum is what
+    goes on (the exchange that would add the other chips' parts is not
+    here). Expert weights stack on a leading axis sized by the experts
+    held; sharding rule P("model") on that axis = expert parallelism.
+
+    Dispatch has static shapes and drops nothing: the (token, slot) pairs
+    are sorted by expert (pairs of experts not held last), the token rows
+    gathered in that order into N*top_k rows — the worst case, every
+    token's every expert held here — and the experts' matrices applied as
+    grouped matrix products over the rows that ARE in a group
+    (`_grouped_matmul`); the rows behind them cost the gather and the
+    combine their bytes and no matrix product. Experts are
+    ``act(x W1 + b1) W2 + b2`` or, ``gated``, ``(act(x Wgate) * (x Wup))
+    Wdown`` (ReGLU with ``activation="relu"``, SwiGLU with ``"swish"``).
+    Where the worst-case rows of all N tokens (an input, a hidden and an
+    output row each) would pass `_DISPATCH_LIVE_BYTES`, the tokens are
+    dispatched in the fewest equal blocks that stay under it, one block
+    after another and each rematerialised in the backward pass.
+    ``n_shared > 0`` adds ONE gated expert of width ``n_shared * hidden``
+    that every token passes (scope ``moe/shared``); a chip that holds a
+    share of the experts holds the shared expert whole.
+
+    The layer's state keeps the tokens each of the ``n_experts`` experts
+    drew in the last step (``tokens_routed``) and in all steps so far
+    (``tokens_routed_total``, uint32: it wraps, so a reader takes
+    differences modulo 2**32); ``train.listeners.ExpertLoadListener``
+    turns them into counters, on every fit path of both containers."""
+    n_out: int = 0
+    n_experts: int = 8
+    top_k: int = 2
+    mlp_ratio: int = 4
+    hidden: Optional[int] = None        # expert width (None: mlp_ratio*n_out)
+    activation: str = "gelu"
+    gated: bool = False
+    has_bias: bool = True
+    experts_held: Optional[Tuple[int, int]] = None
+    router: str = "softmax"             # | "sigmoid"
+    routed_scale: float = 1.0           # sigmoid router only
+    n_shared: int = 0                   # gated layers only
+    weight_init: str = "xavier"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        t = input_type.shape[0]
+        return InputType(Kind.RNN, (t, self.n_out))
+
+    def _held(self):
+        lo, hi = self.experts_held or (0, self.n_experts)
+        if not 0 <= lo < hi <= self.n_experts:
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of the {self.n_experts} experts")
+        return int(lo), int(hi)
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        f_in = input_type.features
+        if f_in != self.n_out:
+            raise ValueError("MoEFeedForward requires input width == n_out")
+        if not 0 < self.top_k <= self.n_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.n_experts}")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r}: 'softmax' "
+                             "or 'sigmoid'")
+        if self.n_shared and not self.gated:
+            raise ValueError("a shared expert needs gated=True")
+        hidden = self.hidden or self.mlp_ratio * self.n_out
+        w_init = get_initializer(self.weight_init)
+        ks = jax.random.split(key, 3)
+        lo, hi = self._held()
+
+        def ew(k, shape, fi, fo):
+            # one key per expert of the WHOLE layer: a share holds the
+            # same matrices the uncut layer has for its experts
+            keys = jax.random.split(k, self.n_experts)
+            return jnp.stack([w_init(keys[i], shape, fi, fo, dtype)
+                              for i in range(lo, hi)])
+
+        p = {"Wr": w_init(ks[0], (f_in, self.n_experts), f_in,
+                          self.n_experts, dtype)}
+        if self.gated:
+            p["Wgate"] = ew(ks[1], (f_in, hidden), f_in, hidden)
+            p["Wup"] = ew(jax.random.fold_in(ks[1], 1), (f_in, hidden), f_in,
+                          hidden)
+            p["Wdown"] = ew(ks[2], (hidden, self.n_out), hidden, self.n_out)
+        else:
+            p["W1"] = ew(ks[1], (f_in, hidden), f_in, hidden)
+            p["W2"] = ew(ks[2], (hidden, self.n_out), hidden, self.n_out)
+        if self.has_bias:
+            p["b1"] = jnp.zeros((hi - lo, hidden), dtype)
+            p["b2"] = jnp.zeros((hi - lo, self.n_out), dtype)
+        if self.n_shared:
+            wide = self.n_shared * hidden
+            kg, ku, kd = jax.random.split(jax.random.fold_in(key, 7), 3)
+            p["Wgate_s"] = w_init(kg, (f_in, wide), f_in, wide, dtype)
+            p["Wup_s"] = w_init(ku, (f_in, wide), f_in, wide, dtype)
+            p["Wdown_s"] = w_init(kd, (wide, self.n_out), wide, self.n_out,
+                                  dtype)
+        state = {"tokens_routed": jnp.zeros((self.n_experts,), jnp.int32),
+                 "tokens_routed_total": jnp.zeros((self.n_experts,),
+                                                  jnp.uint32)}
+        if self.router == "sigmoid":
+            state["route_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
+        return p, state
+
+    # ------------------------------------------------------------ routing
+    def route(self, params, state, x):
+        """(..., F) -> the ``top_k`` experts of every token and their
+        weights, ``(N, k)`` each, in float32 (float64 under gradient
+        checking)."""
+        with jax.named_scope("moe/route"):
+            acc_t = jnp.promote_types(jnp.float32, x.dtype)
+            r = jnp.dot(x.reshape(-1, x.shape[-1]), params["Wr"],
+                        preferred_element_type=acc_t)
+            if self.router == "softmax":
+                top, idx = jax.lax.top_k(r, self.top_k)
+                # a softmax over all experts renormalised over the kept
+                # ones is a softmax over the kept logits
+                return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+            s = jax.nn.sigmoid(r)
+            _, idx = jax.lax.top_k(
+                jax.lax.stop_gradient(s) + state["route_bias"].astype(acc_t),
+                self.top_k)
+            kept = jnp.take_along_axis(s, idx, axis=-1)
+            w = kept / jnp.sum(kept, axis=-1, keepdims=True)
+            return idx.astype(jnp.int32), w * self.routed_scale
+
+    # ------------------------------------------------------------ experts
+    def experts(self, params, h, idx, w):
+        """The held experts' part of the layer's result for the rows of
+        ``h`` (..., F) under the routing ``(idx, w)``; also the tokens
+        each of the ``n_experts`` experts drew."""
+        shape = h.shape
+        h = h.reshape(-1, shape[-1])
+        n = h.shape[0]
+        blk = n // self._dispatch_blocks(params, h)
+        if blk == n:
+            out, routed = self._dispatch(params, h, idx, w)
+            return out.reshape(shape), routed
+        weights = {k: v for k, v in params.items()
+                   if k != "Wr" and not k.endswith("_s")}
+        one = jax.checkpoint(self._dispatch)
+        out, routed = jax.lax.map(
+            lambda a: one(weights, *a),
+            (h.reshape(-1, blk, shape[-1]), idx.reshape(-1, blk, self.top_k),
+             w.reshape(-1, blk, self.top_k)))
+        # a block rematerialised under the containers' gradient
+        # checkpointing keeps this result: its second forward pass does
+        # not dispatch again
+        out = checkpoint_name(out.reshape(shape), "remat_keep")
+        return out, routed.sum(0)
+
+    def _dispatch_blocks(self, params, h):
+        """Blocks the (N, F) token rows are dispatched in: the fewest that
+        divide N and keep one block's worst-case rows under
+        `_DISPATCH_LIVE_BYTES`."""
+        n, f = h.shape
+        hidden = params["Wdown" if self.gated else "W2"].shape[1]
+        row = (2 * f + (2 if self.gated else 1) * hidden) * h.dtype.itemsize
+        most = max(_DISPATCH_LIVE_BYTES // (row * self.top_k), 1)
+        return next(g for g in range(-(-n // most), n + 1) if n % g == 0)
+
+    def _dispatch(self, params, h, idx, w):
+        """`experts` for the (N, F) token rows of one dispatch."""
+        from deeplearning4j_tpu.nn.activations import get_activation
+        lo, hi = self._held()
+        e = hi - lo
+        k = self.top_k
+        n = h.shape[0]
+        with jax.named_scope("moe/dispatch"):
+            flat = idx.reshape(-1)
+            here = (flat >= lo) & (flat < hi)
+            local = jnp.where(here, flat - lo, e)   # not held: behind all
+            order = jnp.argsort(local, stable=True).astype(jnp.int32)
+            inverse = jnp.argsort(order).astype(jnp.int32)
+            sizes = jnp.bincount(local, length=e + 1)[:e]
+            routed = jnp.bincount(flat, length=self.n_experts)
+            in_group = (jnp.arange(n * k) < sizes.sum())[:, None]
+            if self.has_bias and not self.gated:
+                of_row = jnp.minimum(local[order], e - 1)
+            # the rows behind the last group belong to no expert held
+            # here. A grouped product leaves such rows of its result
+            # UNDEFINED (the TPU's kernel never writes them), forward and
+            # in its transposes, so every tensor of that many rows is
+            # selected to zero where it enters and where it leaves a
+            # product: no undefined row reaches a sum, in either direction
+            live = lambda a: jnp.where(in_group, a, 0).astype(h.dtype)
+            xs = live(_rows_to_experts(h, order, inverse))  # (n*k, F)
+        with jax.named_scope("moe/experts"):
+            act = get_activation(self.activation)
+            if self.gated:
+                mid = act(live(_grouped_matmul(xs, params["Wgate"], sizes))) \
+                    * live(_grouped_matmul(xs, params["Wup"], sizes))
+            else:
+                mid = _grouped_matmul(xs, params["W1"], sizes)
+                if self.has_bias:
+                    mid = mid + params["b1"][of_row]
+                mid = act(live(mid))
+            ys = _grouped_matmul(
+                live(mid), params["Wdown" if self.gated else "W2"], sizes)
+            if self.has_bias and not self.gated:
+                ys = ys + params["b2"][of_row]
+            ys = live(ys)
+        with jax.named_scope("moe/combine"):
+            per_slot = _rows_to_tokens(ys, order, inverse).reshape(n, k, -1)
+            wk = jnp.where(here.reshape(n, k), w, 0)
+            out = jnp.einsum("nkf,nk->nf", per_slot, wk.astype(w.dtype),
+                             preferred_element_type=w.dtype)
+        return out.astype(h.dtype), routed.astype(jnp.int32)
+
+    def shared(self, params, h):
+        """The shared expert's part: every token, no routing."""
+        with jax.named_scope("moe/shared"):
+            return _gated_mlp(h, params["Wgate_s"], params["Wup_s"],
+                              params["Wdown_s"], self.activation)
+
+    def counted(self, state, routed):
+        """``state`` with the tokens a step routed counted in."""
+        return {**state, "tokens_routed": routed,
+                "tokens_routed_total": state["tokens_routed_total"]
+                + routed.astype(jnp.uint32)}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        out, routed = self.experts(params, x,
+                                   *self.route(params, state, x))
+        if self.n_shared:
+            out = out + self.shared(params, x)
+        if mask is not None:
+            out = out * mask[..., None].astype(out.dtype)
+        return out, self.counted(state, routed)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class GatedMLP(LayerConf):
+    """Bias-free gated feed-forward ``(act(x Wgate) * (x Wup)) Wdown`` of
+    width ``hidden`` (SwiGLU with ``activation="swish"``)."""
+    n_out: int = 0
+    hidden: int = 0
+    activation: str = "swish"
+    weight_init: str = "xavier"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType(Kind.RNN, (input_type.shape[0], self.n_out))
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        f_in = input_type.features
+        w_init = get_initializer(self.weight_init)
+        kg, ku, kd = jax.random.split(key, 3)
+        return {"Wgate": w_init(kg, (f_in, self.hidden), f_in, self.hidden,
+                                dtype),
+                "Wup": w_init(ku, (f_in, self.hidden), f_in, self.hidden,
+                              dtype),
+                "Wdown": w_init(kd, (self.hidden, self.n_out), self.hidden,
+                                self.n_out, dtype)}, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        with jax.named_scope("mlp/gated"):
+            return _gated_mlp(x, params["Wgate"], params["Wup"],
+                              params["Wdown"], self.activation), state
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class TransformerBlock(LayerConf):
-    """Pre-norm transformer block: LN -> MHA -> +res -> LN -> MLP -> +res.
+    """Pre-norm transformer block: norm -> MHA -> +res -> norm -> FFN ->
+    +res.
 
     One declarative unit so deep stacks stay compact in configs (the zoo's
-    TransformerLM stacks these). mlp_ratio*n_out is the hidden width."""
+    TransformerLM stacks these). The FFN is a dense MLP of hidden width
+    mlp_ratio*n_out (``has_bias`` False: no biases) or, with ``ffn`` set,
+    that layer (`MoEFeedForward`, `GatedMLP`) on the normed stream. The
+    attention is a `MultiHeadAttention` built from the block's own fields
+    or, with ``attn`` set, that layer
+    (`linear_attention.KimiDeltaAttention`,
+    `linear_attention.MultiHeadLatentAttention`). ``norm``: "layer"
+    (LayerNorm) or "rms" (RMSNorm)."""
     n_out: int = 0
     n_heads: int = 8
     mlp_ratio: int = 4
@@ -312,19 +705,23 @@ class TransformerBlock(LayerConf):
     weight_init: str = "xavier"
     attention_impl: str = "dense"       # forwarded to MultiHeadAttention
     block_size: int = 512
+    norm: str = "layer"
+    norm_epsilon: Optional[float] = None
+    has_bias: bool = True
+    ffn: Optional[LayerConf] = None
+    attn: Optional[LayerConf] = None
 
     def output_type(self, input_type: InputType) -> InputType:
         t = input_type.shape[0]
         return InputType(Kind.RNN, (t, self.n_out))
 
     def _sub(self):
-        attn = MultiHeadAttention(
+        attn = self.attn or MultiHeadAttention(
             n_out=self.n_out, n_heads=self.n_heads, causal=self.causal,
             use_rope=self.use_rope, attention_dropout=self.attention_dropout,
             weight_init=self.weight_init, attention_impl=self.attention_impl,
             block_size=self.block_size)
-        ln = LayerNormLayer()
-        return ln, attn
+        return _norm_layer(self.norm, self.norm_epsilon), attn
 
     def init(self, key, input_type: InputType, dtype=jnp.float32):
         f_in = input_type.features
@@ -336,20 +733,21 @@ class TransformerBlock(LayerConf):
         ks = jax.random.split(key, 4)
         ln_p, _ = ln.init(ks[0], input_type, dtype)
         attn_p, _ = attn.init(ks[1], input_type, dtype)
+        p = {"ln1": ln_p, "attn": attn_p,
+             "ln2": ln.init(ks[0], input_type, dtype)[0]}
+        if self.ffn is not None:
+            p["ffn"], ffn_state = self.ffn.init(ks[2], input_type, dtype)
+            return p, ({"ffn": ffn_state} if ffn_state else {})
         hidden = self.mlp_ratio * self.n_out
         w_init = get_initializer(self.weight_init)
-        return {
-            "ln1": ln_p,
-            "attn": attn_p,
-            "ln2": {"gamma": jnp.ones((self.n_out,), dtype),
-                    "beta": jnp.zeros((self.n_out,), dtype)},
-            "W1": w_init(ks[2], (self.n_out, hidden), self.n_out, hidden,
-                         dtype),
-            "b1": jnp.zeros((hidden,), dtype),
-            "W2": w_init(ks[3], (hidden, self.n_out), hidden, self.n_out,
-                         dtype),
-            "b2": jnp.zeros((self.n_out,), dtype),
-        }, {}
+        p["W1"] = w_init(ks[2], (self.n_out, hidden), self.n_out, hidden,
+                         dtype)
+        p["W2"] = w_init(ks[3], (hidden, self.n_out), hidden, self.n_out,
+                         dtype)
+        if self.has_bias:
+            p["b1"] = jnp.zeros((hidden,), dtype)
+            p["b2"] = jnp.zeros((self.n_out,), dtype)
+        return p, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         from deeplearning4j_tpu.nn.activations import get_activation
@@ -365,77 +763,22 @@ class TransformerBlock(LayerConf):
             a = a * jax.random.bernoulli(r2, keep, a.shape) / keep
         x = x + a
         h, _ = ln.apply(params["ln2"], {}, x)
-        h = get_activation(self.activation)(h @ params["W1"] + params["b1"])
-        h = h @ params["W2"] + params["b2"]
+        if self.ffn is not None:
+            h, ffn_state = self.ffn.apply(params["ffn"],
+                                          state.get("ffn", {}), h)
+            if ffn_state:
+                state = {**state, "ffn": ffn_state}
+        else:
+            h = h @ params["W1"]
+            if self.has_bias:
+                h = h + params["b1"]
+            h = get_activation(self.activation)(h) @ params["W2"]
+            if self.has_bias:
+                h = h + params["b2"]
         y = x + h
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
         return y, state
-
-
-@register_layer
-@dataclasses.dataclass(frozen=True)
-class MoEFeedForward(LayerConf):
-    """Mixture-of-experts FFN with top-2 soft routing — the expert-parallel
-    (EP) building block. Experts stack on a leading axis sized n_experts;
-    sharding rule P("model") on that axis = expert parallelism (each model-
-    axis group holds a subset of experts; the einsum dispatch becomes an
-    all-to-all under the partitioner).
-
-    Capacity-less dense routing (every token scores every expert, weighted
-    by the top-2 normalized gates): simpler than Switch-style dispatch and
-    XLA-friendly (no dynamic shapes); fine up to ~16 experts."""
-    n_out: int = 0
-    n_experts: int = 8
-    top_k: int = 2
-    mlp_ratio: int = 4
-    activation: str = "gelu"
-    weight_init: str = "xavier"
-
-    def output_type(self, input_type: InputType) -> InputType:
-        t = input_type.shape[0]
-        return InputType(Kind.RNN, (t, self.n_out))
-
-    def init(self, key, input_type: InputType, dtype=jnp.float32):
-        f_in = input_type.features
-        if f_in != self.n_out:
-            raise ValueError("MoEFeedForward requires input width == n_out")
-        hidden = self.mlp_ratio * self.n_out
-        w_init = get_initializer(self.weight_init)
-        ks = jax.random.split(key, 3)
-        e = self.n_experts
-
-        def ew(k, shape, fi, fo):
-            keys = jax.random.split(k, e)
-            return jnp.stack([w_init(keys[i], shape, fi, fo, dtype)
-                              for i in range(e)])
-
-        return {
-            "Wg": w_init(ks[0], (f_in, e), f_in, e, dtype),
-            "W1": ew(ks[1], (f_in, hidden), f_in, hidden),
-            "b1": jnp.zeros((e, hidden), dtype),
-            "W2": ew(ks[2], (hidden, self.n_out), hidden, self.n_out),
-            "b2": jnp.zeros((e, self.n_out), dtype),
-        }, {}
-
-    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        from deeplearning4j_tpu.nn.activations import get_activation
-        gates = jax.nn.softmax(x @ params["Wg"], axis=-1)   # (B, T, E)
-        if self.top_k < self.n_experts:
-            top_vals, _ = jax.lax.top_k(gates, self.top_k)
-            thresh = top_vals[..., -1:]
-            gates = jnp.where(gates >= thresh, gates, 0.0)
-            gates = gates / jnp.maximum(
-                jnp.sum(gates, -1, keepdims=True), 1e-9)
-        act = get_activation(self.activation)
-        h = jnp.einsum("btf,efh->bteh", x, params["W1"]) + params["b1"]
-        h = act(h)
-        y = jnp.einsum("bteh,eho->bteo", h, params["W2"]) + params["b2"]
-        out = jnp.einsum("bteo,bte->bto", y, gates)
-        if mask is not None:
-            out = out * mask[..., None].astype(out.dtype)
-        return out, state
-
 
 @register_layer
 @dataclasses.dataclass(frozen=True)

@@ -1,0 +1,401 @@
+"""Linear and latent attention for hybrid LMs (Kimi-Linear's two kinds).
+
+`KimiDeltaAttention` (KDA) is a gated delta rule with a per-channel decay.
+Per head, with state ``S`` (d_k x d_v)::
+
+    S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T
+    o_t = S_t^T q_t
+
+`kda_chunked` computes it in chunks of ``chunk`` positions (the WY form).
+With ``g_t`` the running sum of ``log a`` inside a chunk and ``S`` the state
+the chunk receives::
+
+    A[t,i]   = b_t sum_c k_tc k_ic exp(g_tc - g_ic)        (i <  t)
+    Aqk[t,i] =     sum_c q_tc k_ic exp(g_tc - g_ic)        (i <= t)
+    (I + A) [W | U0] = [b k exp(g) | b v]          one triangular solve
+    U = U0 - W S;  O = (q exp(g)) S + Aqk U
+    S' = Diag(exp(g_C)) S + (k exp(g_C - g))^T U          the hand-over
+
+Everything but the last two lines is independent of ``S`` and is made for
+all chunks at once; a `lax.scan` over the chunks carries ``S`` in float32.
+No exponent is ever positive: the pairs inside a block of 16 positions
+take ``exp(g_t - g_i)`` pair by pair in one fused pass, every other pair
+splits it at a position ``r`` between i and t (``g_t - r <= 0`` and
+``r - g_i <= 0``) into two matrix operands, block by doubling block
+(`_decayed_products`). The backward pass is autodiff through the solve and
+the scan; the (sequence, head) pairs go through in groups, each
+rematerialised, so that only one group's temporaries are alive.
+
+`MultiHeadLatentAttention` (MLA) is DeepSeek's latent attention in the
+expanded form used for training, with no positional rotation
+(``mla_use_nope``): q of 128 + 64 a head, a 512 + 64 latent whose first
+part is normed and expanded to 128-wide k and v a head, the last 64 shared
+by all heads as the rest of k.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from deeplearning4j_tpu.nn.conf.base import (
+    InputType, Kind, LayerConf, register_layer,
+)
+from deeplearning4j_tpu.nn.initializers import get_initializer
+from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
+from deeplearning4j_tpu.util.platform import is_tpu_backend
+
+
+def _rms(x, gamma, eps):
+    acc_t = jnp.promote_types(jnp.float32, x.dtype)
+    xf = x.astype(acc_t)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return y * gamma.astype(acc_t)
+
+
+def causal_conv(x, taps):
+    """Depth-wise causal convolution over time: x (B, T, C), taps (K, C),
+    ``y_t = sum_j taps[j] x_{t-(K-1)+j}`` (positions before 0 are zero)."""
+    k = taps.shape[0]
+    t = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(xp[:, j:j + t] * taps[j] for j in range(k))
+
+
+# ------------------------------------------------------------- KDA core
+@jax.checkpoint
+def _pairs_in_blocks(q, k, g):
+    """The products of `_decayed_products` for the pairs inside blocks of
+    up to 16 positions, pair by pair in float32: q, k, g (M, N, P, b, d)
+    -> two (M, N, P, b, b). ``exp(g_t - g_i)`` is taken only where i <= t.
+    Rematerialised: nothing b x b x d is kept for the backward pass."""
+    b = k.shape[3]
+    seen = jnp.tril(jnp.ones((b, b), bool))[..., None]
+    decay = jnp.exp(jnp.where(seen, g[..., :, None, :] - g[..., None, :, :],
+                              -jnp.inf)) * k[..., None, :, :]
+    kk = jnp.sum(k[..., :, None, :] * decay, axis=-1)
+    qk = jnp.sum(q[..., :, None, :] * decay, axis=-1)
+    return jnp.where(jnp.tril(jnp.ones((b, b), bool), -1), kk, 0.0), qk
+
+
+def _decayed_products(q, k, g, dot):
+    """``sum_c x_tc k_ic exp(g_tc - g_ic)`` inside every chunk for x = k
+    (pairs i < t) and x = q (pairs i <= t): q, k, g (M, N, C, d) -> two
+    (M, N, C, C) lower-triangular matrices.
+
+    Built from blocks of 16 positions (`_pairs_in_blocks`) by doubling:
+    two sibling blocks of b positions become one of 2b whose new quarter,
+    the rows of the second against the columns of the first, is ONE
+    matrix product of ``x_t exp(g_t - r)`` and ``k_i exp(r - g_i)`` with
+    ``r`` = g at the first block's end, so that both exponents are <= 0
+    whatever the decay."""
+    c = k.shape[2]
+    if c & (c - 1):
+        raise ValueError(f"chunk {c} is not a power of two")
+    b = min(c, 16)
+    blocks = lambda x: x.reshape(x.shape[:2] + (c // b, b) + x.shape[3:])
+    kk, qk = _pairs_in_blocks(blocks(q), blocks(k), blocks(g))
+    while b < c:
+        halves = lambda x: x.reshape(x.shape[:2] + (c // (2 * b), 2, b)
+                                     + x.shape[3:])
+        gh, kh, qh = halves(g), halves(k), halves(q)
+        ref = gh[:, :, :, 0, -1:]                        # (M,N,c/2b,1,d)
+        later = jnp.exp(gh[:, :, :, 1] - ref)
+        rows = jnp.concatenate([kh[:, :, :, 1] * later,
+                                qh[:, :, :, 1] * later], axis=3)
+        cols = kh[:, :, :, 0] * jnp.exp(ref - gh[:, :, :, 0])
+        cross = dot(rows, cols, "mnptc,mnpic->mnpti")
+
+        def merged(diag, quarter):   # (M,N,c/b,b,b) -> (M,N,c/2b,2b,2b)
+            d = diag.reshape(diag.shape[:2] + (-1, 2, b, b))
+            top = jnp.concatenate([d[:, :, :, 0],
+                                   jnp.zeros_like(quarter)], axis=-1)
+            return jnp.concatenate(
+                [top, jnp.concatenate([quarter, d[:, :, :, 1]], axis=-1)],
+                axis=-2)
+
+        kk = merged(kk, cross[:, :, :, :b])
+        qk = merged(qk, cross[:, :, :, b:])
+        b *= 2
+    return kk[:, :, 0], qk[:, :, 0]
+
+
+def _kda_core(q, k, v, log_a, beta, s0, *, mm):
+    """`kda_chunked` for M (sequence, head) pairs: q, k, log_a
+    (M, N, C, d_k), v (M, N, C, d_v), beta (M, N, C, 1), all float32, in N
+    chunks of C positions; s0 (M, d_k, d_v). Returns (o (M, N, C, d_v),
+    the final state)."""
+    f32 = q.dtype
+    chunk, dk = q.shape[2], q.shape[3]
+    g = jnp.cumsum(log_a, axis=2)                        # (M,N,C,dk)
+
+    def dot(x, y, spec):
+        return jnp.einsum(spec, x.astype(mm), y.astype(mm),
+                          preferred_element_type=f32)
+
+    a_kk, a_qk = _decayed_products(q, k, g, dot)
+    a_kk = beta * a_kk
+
+    # ---- the solve: (I + A) [W | U0] = [b k e^g | b v]
+    decay = jnp.exp(g)
+    rhs = jnp.concatenate([beta * k * decay, beta * v], axis=-1)
+    sol = jax.scipy.linalg.solve_triangular(
+        a_kk + jnp.eye(chunk, dtype=f32), rhs, lower=True,
+        unit_diagonal=True)
+    w, u0 = sol[..., :dk], sol[..., dk:]
+    q_in = q * decay
+    g_end = g[:, :, -1:, :]                              # (M,N,1,dk)
+    k_out = k * jnp.exp(g_end - g)
+
+    # ---- the hand-over between chunks
+    def step(s, xs):
+        w_n, u0_n, q_n, aqk_n, k_n, end_n = xs
+        u = u0_n - dot(w_n, s, "mck,mkv->mcv")
+        o = dot(q_n, s, "mck,mkv->mcv") + dot(aqk_n, u, "mci,miv->mcv")
+        s = jnp.swapaxes(jnp.exp(end_n), -1, -2) * s + dot(
+            k_n, u, "mck,mcv->mkv")
+        return s, o
+
+    front = lambda x: jnp.moveaxis(x, 1, 0)
+    s, o = jax.lax.scan(step, s0, tuple(
+        front(x) for x in (w, u0, q_in, a_qk, k_out, g_end)))
+    return jnp.moveaxis(o, 0, 1), s
+
+
+#: the temporaries of the (sequence, head) pairs that go through the
+#: recurrence together may take this much; more pairs go in groups
+_SCAN_LIVE_BYTES = 2 << 30
+
+
+def _in_groups(fn, arrays, t, d):
+    """``fn`` over equal slices of the arrays' leading axis (the
+    independent (sequence, head) pairs), one slice after another and each
+    rematerialised in the backward pass: only one group's temporaries
+    (some forty tensors of T x d_k floats a pair) are alive at a time. The
+    groups are the fewest that divide the pairs and keep those
+    temporaries under `_SCAN_LIVE_BYTES`."""
+    m = arrays[0].shape[0]
+    most = max(_SCAN_LIVE_BYTES // (40 * t * d * 4), 1)
+    groups = next(g for g in range(-(-m // most), m + 1) if m % g == 0)
+    split = lambda x: x.reshape((groups, m // groups) + x.shape[1:])
+    out = jax.lax.map(lambda xs: jax.checkpoint(fn)(*xs),
+                      tuple(split(x) for x in arrays))
+    return tuple(x.reshape((m,) + x.shape[2:]) for x in out)
+
+
+def _chunked(x, chunk):
+    """(M, T, ...) -> (M, N, C, ...), the tail padded with zeros."""
+    pad = (-x.shape[1]) % chunk
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+    return x.reshape((x.shape[0], -1, chunk) + x.shape[2:])
+
+
+def _pairs(x):
+    """(B, T, H, ...) -> (B*H, T, ...): one row a (sequence, head) pair."""
+    x = jnp.moveaxis(x, 2, 1)
+    return x.reshape((-1,) + x.shape[2:])
+
+
+def kda_chunked(q, k, v, log_a, beta, *, chunk=64, initial_state=None,
+                mm_dtype=None):
+    """The gated delta rule of the module docstring, in chunks.
+
+    q, k: (B, T, H, d_k) (normalised and scaled by the caller), v:
+    (B, T, H, d_v), log_a: (B, T, H, d_k) <= 0, beta: (B, T, H). Returns
+    ``(o (B, T, H, d_v), final state (B, H, d_k, d_v))``, both float32.
+    ``T`` need not divide by ``chunk`` (a power of two): the tail is
+    padded with positions that leave the state as it is. ``mm_dtype``: the
+    dtype the matrix products take their operands in (None: float32);
+    sums, decays, the solve and the state stay float32. The B x H
+    (sequence, head) pairs are taken in groups (`_in_groups`)."""
+    b, t, h, dk = k.shape
+    dv = v.shape[-1]
+    f32 = jnp.promote_types(jnp.float32, q.dtype)
+    prep = lambda x: _chunked(_pairs(x.astype(f32)), chunk)
+    s0 = jnp.zeros((b * h, dk, dv), f32) if initial_state is None \
+        else initial_state.astype(f32).reshape(b * h, dk, dv)
+    o, s = _in_groups(
+        functools.partial(_kda_core, mm=mm_dtype or f32),
+        (prep(q), prep(k), prep(v), prep(log_a), prep(beta[..., None]), s0),
+        t, dk)
+    o = jnp.moveaxis(o.reshape(b, h, -1, dv), 1, 2)[:, :t]
+    return o, s.reshape(b, h, dk, dv)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class KimiDeltaAttention(LayerConf):
+    """Kimi Delta Attention over (B, T, F), causal by construction.
+
+    ``q, k, v = SiLU(conv(W x))`` (causal depth-wise convolution of
+    ``conv_kernel`` taps), q and k L2-normalised per head, q scaled by
+    ``head_dim^-1/2``; per-channel decay ``log a = -exp(A_log) *
+    softplus(Wa_up Wa_down x + dt_bias)``; ``b = sigmoid(Wb x)`` per head;
+    the recurrence of the module docstring (`kda_chunked`, the
+    (sequence, head) pairs in groups: `_in_groups`); output
+    ``Wo (RMSNorm_head(o) * sigmoid(Wg_up Wg_down x))``. ``low_rank`` is
+    the rank of the decay and gate projections (0: ``head_dim``)."""
+    n_out: int = 0
+    n_heads: int = 8
+    head_dim: int = 128
+    conv_kernel: int = 4
+    low_rank: int = 0
+    chunk: int = 64
+    norm_epsilon: float = 1e-5
+    weight_init: str = "xavier"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType(Kind.RNN, (input_type.shape[0], self.n_out))
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        f, hd = input_type.features, self.n_heads * self.head_dim
+        r = self.low_rank or self.head_dim
+        w_init = get_initializer(self.weight_init)
+        ks = jax.random.split(key, 14)
+        mat = lambda i, fi, fo: w_init(ks[i], (fi, fo), fi, fo, dtype)
+        # the family's convention (fla's KimiDeltaAttention): a depth-wise
+        # Conv1d's default taps U(-K^-1/2, K^-1/2), A uniform in [1, 16],
+        # dt log-uniform in [1e-3, 1e-1] with dt_bias its inverse softplus
+        bound = self.conv_kernel ** -0.5
+        taps = lambda i: jax.random.uniform(
+            ks[i], (self.conv_kernel, hd), dtype, -bound, bound)
+        dt = jnp.exp(jax.random.uniform(ks[12], (hd,), jnp.float32)
+                     * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+        return {
+            "Wq": mat(0, f, hd), "Wk": mat(1, f, hd), "Wv": mat(2, f, hd),
+            "conv_q": taps(3), "conv_k": taps(4), "conv_v": taps(5),
+            "Wa_down": mat(6, f, r), "Wa_up": mat(7, r, hd),
+            "A_log": jnp.log(jax.random.uniform(
+                ks[13], (self.n_heads,), jnp.float32, 1.0, 16.0)
+            ).astype(dtype),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "Wb": mat(8, f, self.n_heads),
+            "Wg_down": mat(9, f, r), "Wg_up": mat(10, r, hd),
+            "o_norm": jnp.ones((self.head_dim,), dtype),
+            "Wo": mat(11, hd, self.n_out),
+        }, {}
+
+    def _recurrence(self, q, k, v, z, b, conv_q, conv_k, conv_v, a_log,
+                    dt_bias):
+        """From the raw projections of M (sequence, head) pairs, (M, T, d)
+        each in the compute dtype (b: (M, T)), and the pairs' own taps
+        (M, K, d), A_log (M,) and dt_bias (M, d), to o (M, T, d) in the
+        compute dtype: convolutions, SiLU, normalisation, decay and the
+        chunked recurrence, all in float32 inside."""
+        f32 = jnp.promote_types(jnp.float32, q.dtype)
+        t, d = q.shape[1], q.shape[2]
+        branch = lambda a, taps: jax.nn.silu(jax.vmap(causal_conv)(
+            a.astype(f32)[:, None], taps.astype(f32))[:, 0])
+        unit = lambda a: a * jax.lax.rsqrt(
+            jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+        log_a = -jnp.exp(a_log.astype(f32))[:, None, None] * jax.nn.softplus(
+            z.astype(f32) + dt_bias.astype(f32)[:, None, :])
+        beta = jax.nn.sigmoid(b.astype(f32))[..., None]
+        chunks = lambda a: _chunked(a, self.chunk)
+        o, _ = _kda_core(
+            chunks(unit(branch(q, conv_q)) * d ** -0.5),
+            chunks(unit(branch(k, conv_k))), chunks(branch(v, conv_v)),
+            chunks(log_a), chunks(beta),
+            jnp.zeros((q.shape[0], d, d), f32), mm=q.dtype)
+        return (o.reshape(o.shape[0], -1, d)[:, :t].astype(q.dtype),)
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        if mask is not None:
+            raise NotImplementedError(
+                "KimiDeltaAttention takes whole sequences (no mask)")
+        b, t, _ = x.shape
+        h, d = self.n_heads, self.head_dim
+        f32 = jnp.promote_types(jnp.float32, x.dtype)
+        heads = lambda a: a.reshape(b, t, h, d)
+        with jax.named_scope("kda/proj"):
+            raw = [_pairs(heads(x @ params[w])) for w in ("Wq", "Wk", "Wv")]
+            raw.append(_pairs(heads(
+                (x @ params["Wa_down"]) @ params["Wa_up"])))
+            raw.append(_pairs((x @ params["Wb"])[..., None])[..., 0])
+            # each pair's own taps, A_log and dt_bias (its head's)
+            own = lambda a: jnp.tile(a, (b,) + (1,) * (a.ndim - 1))
+            for taps in ("conv_q", "conv_k", "conv_v"):
+                raw.append(own(jnp.moveaxis(
+                    params[taps].reshape(-1, h, d), 1, 0)))
+            raw.append(own(params["A_log"]))
+            raw.append(own(params["dt_bias"].reshape(h, d)))
+            gate = jax.nn.sigmoid(
+                ((x @ params["Wg_down"]) @ params["Wg_up"]).astype(f32))
+        with jax.named_scope("kda/scan"):
+            (o,) = _in_groups(self._recurrence, raw, t, d)
+            o = jnp.moveaxis(o.reshape(b, h, t, d), 1, 2)
+            # a block rematerialised under the containers' gradient
+            # checkpointing keeps the recurrence's result, so that its
+            # second forward pass does not run the recurrence again
+            o = checkpoint_name(o, "remat_keep")
+        with jax.named_scope("kda/out"):
+            o = _rms(o, params["o_norm"], self.norm_epsilon) * heads(gate)
+            y = o.reshape(b, t, h * d).astype(x.dtype) @ params["Wo"]
+        return y, state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class MultiHeadLatentAttention(LayerConf):
+    """Latent attention (MLA) over (B, T, F), expanded form, causal, with
+    no positions: ``q = Wq x`` split ``nope_dim + rope_dim`` a head;
+    ``[c; k_r] = Wkva x`` (``kv_rank + rope_dim``), ``c <- RMSNorm(c)``,
+    ``[k_nope; v] = Wkvb c`` (``nope_dim + v_dim`` a head), ``k = [k_nope;
+    k_r]`` with ``k_r`` shared by the heads; ``softmax(q k^T / sqrt(nope_dim
+    + rope_dim)) v``; ``Wo``. The ``rope_dim`` part is carried and NOT
+    rotated. On a TPU the fused flash kernel runs it (two head sizes)."""
+    n_out: int = 0
+    n_heads: int = 8
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    kv_rank: int = 512
+    norm_epsilon: float = 1e-5
+    block_size: int = 512
+    weight_init: str = "xavier"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType(Kind.RNN, (input_type.shape[0], self.n_out))
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        f, h = input_type.features, self.n_heads
+        w_init = get_initializer(self.weight_init)
+        ks = jax.random.split(key, 4)
+        mat = lambda i, fi, fo: w_init(ks[i], (fi, fo), fi, fo, dtype)
+        return {
+            "Wq": mat(0, f, h * (self.nope_dim + self.rope_dim)),
+            "Wkva": mat(1, f, self.kv_rank + self.rope_dim),
+            "kv_norm": jnp.ones((self.kv_rank,), dtype),
+            "Wkvb": mat(2, self.kv_rank, h * (self.nope_dim + self.v_dim)),
+            "Wo": mat(3, h * self.v_dim, self.n_out),
+        }, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        b, t, _ = x.shape
+        h = self.n_heads
+        with jax.named_scope("mla/proj"):
+            q = (x @ params["Wq"]).reshape(b, t, h, -1)
+            ckr = x @ params["Wkva"]
+            c = _rms(ckr[..., :self.kv_rank], params["kv_norm"],
+                     self.norm_epsilon).astype(x.dtype)
+            kv = (c @ params["Wkvb"]).reshape(b, t, h, -1)
+            k_r = jnp.broadcast_to(ckr[:, :, None, self.kv_rank:],
+                                   (b, t, h, self.rope_dim))
+            k = jnp.concatenate([kv[..., :self.nope_dim], k_r], axis=-1)
+            v = kv[..., self.nope_dim:]
+        with jax.named_scope("mla/attn"):
+            if is_tpu_backend():
+                from deeplearning4j_tpu.ops import flash_attention
+                out = flash_attention(q, k, v, mask=mask, causal=True,
+                                      block_q=self.block_size,
+                                      block_k=self.block_size)
+            else:
+                out = dot_product_attention(q, k, v, mask=mask, causal=True)
+        y = out.reshape(b, t, h * self.v_dim) @ params["Wo"]
+        if mask is not None:
+            y = y * mask[..., None].astype(y.dtype)
+        return y, state

@@ -386,11 +386,25 @@ class GravesBidirectionalLSTM(Bidirectional):
                                GravesLSTM(n_out=self.n_out, n_in=self.n_in))
 
 
+#: the float32 logits of an LM head and their gradient may take this much;
+#: more positions are scored in blocks (`RnnOutputLayer.score`)
+_LOSS_LIVE_BYTES = 512 << 20
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class RnnOutputLayer(LayerConf):
     """Time-distributed dense + loss (DL4J RnnOutputLayer): applies the same
-    (F_in -> n_out) projection at every step; loss averaged over unmasked steps."""
+    (F_in -> n_out) projection at every step; loss averaged over unmasked steps.
+
+    With ``loss="sparse_mcxent"`` and a softmax head whose float32
+    logits and their gradient (positions x vocabulary x 4 bytes, twice)
+    would pass `_LOSS_LIVE_BYTES`, the score is taken over blocks of
+    positions, the largest power of two that stays under it: each block's
+    logits are made in float32, reduced to its summed loss and made again
+    in the backward pass, so the logits of all positions never exist at
+    once (an LM head over a long sequence). Same number as the whole
+    score up to float32 summation order."""
     n_out: int = 0
     n_in: Optional[int] = None
     activation: str = "softmax"
@@ -421,8 +435,52 @@ class RnnOutputLayer(LayerConf):
         return get_activation(self.activation)(self.preout(params, x, train, rng)), state
 
     def score(self, params, x, labels, *, train=False, rng=None, mask=None):
+        if self.loss == "sparse_mcxent" and self.activation == "softmax":
+            # positions a block: the largest power of two whose logits
+            # and their gradient stay under the budget
+            acc = jnp.promote_types(jnp.float32, x.dtype).itemsize
+            most = max(_LOSS_LIVE_BYTES // (2 * acc * self.n_out), 1)
+            if most < x.size // x.shape[-1]:
+                return self._blocked_score(
+                    params, x, labels, train, rng, mask,
+                    1 << (most.bit_length() - 1))
         z = self.preout(params, x, train, rng)
         return get_loss(self.loss)(labels, z, self.activation, mask=mask)
+
+    def _blocked_score(self, params, x, labels, train, rng, mask, blk):
+        with jax.named_scope("head/loss"):
+            x = self.maybe_dropout_input(x, train, rng)
+            f = x.shape[-1]
+            x = x.reshape(-1, f)
+            n = x.shape[0]
+            labels = labels.reshape(n).astype(jnp.int32)
+            keep = jnp.ones((n,), jnp.float32) if mask is None \
+                else mask.reshape(n).astype(jnp.float32)
+            pad = (-n) % blk
+            if pad:
+                x = jnp.pad(x, ((0, pad), (0, 0)))
+                labels, keep = jnp.pad(labels, (0, pad)), jnp.pad(keep,
+                                                                  (0, pad))
+            acc_t = jnp.promote_types(jnp.float32, x.dtype)
+
+            @jax.checkpoint
+            def block_loss(w, b, xb, yb, kb):
+                z = jnp.dot(xb, w, preferred_element_type=acc_t)
+                if b is not None:
+                    z = z + b.astype(acc_t)
+                nll = jax.nn.logsumexp(z, axis=-1) - jnp.take_along_axis(
+                    z, yb[:, None], axis=-1)[:, 0]
+                return jnp.sum(nll * kb.astype(acc_t))
+
+            def body(total, blk_in):
+                return total + block_loss(params["W"], params.get("b"),
+                                          *blk_in), None
+
+            total, _ = jax.lax.scan(
+                body, jnp.zeros((), acc_t),
+                (x.reshape(-1, blk, f), labels.reshape(-1, blk),
+                 keep.reshape(-1, blk)))
+            return total / jnp.maximum(jnp.sum(keep), 1.0).astype(acc_t)
 
 
 @register_layer

@@ -37,7 +37,7 @@ from deeplearning4j_tpu.nn.conf.base import (
     InputType, Kind, LayerConf, preprocess_forward, preprocessed_type,
 )
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
-from deeplearning4j_tpu.nn.updaters import build_optimizer, NoOp
+from deeplearning4j_tpu.nn.updaters import NoOp, apply_update, build_optimizer
 from deeplearning4j_tpu.util import params as param_util
 from deeplearning4j_tpu.util.env import env_int
 from deeplearning4j_tpu.util.platform import is_tpu_backend
@@ -217,22 +217,31 @@ def _required_kind(layer: LayerConf) -> Optional[Kind]:
 
 
 def _layer_call(layer, *, seq, train, remat, params, x, state=None,
-                carry=None, rng=None, mask=None):
+                carry=None, rng=None, mask=None, cast=None):
     """Invoke layer.apply (seq=False) or layer.apply_seq (seq=True), with
     jax.checkpoint rematerialization when remat is on: every traced value
     (params/state/carry/input/rng/mask) is a checkpoint ARGUMENT, only the
-    static layer conf and train flag are closed over. Shared by both
-    containers so the two forward passes can't drift."""
+    static layer conf and train flag are closed over. ``cast`` (params ->
+    params in the compute dtype) is applied INSIDE the rematerialised
+    region, so that the cast copy of a layer's weights lives only while
+    the layer runs and is not held from the forward pass to the backward.
+    Shared by both containers so the two forward passes can't drift."""
+    cast = cast or (lambda lp: lp)
     if seq:
         def fn(lp, xx, cc, rr, mm, _l=layer):
-            return _l.apply_seq(lp, xx, cc, train=train, rng=rr, mask=mm)
+            return _l.apply_seq(cast(lp), xx, cc, train=train, rng=rr,
+                                mask=mm)
         args = (params, x, carry, rng, mask)
     else:
         def fn(lp, st, xx, rr, mm, _l=layer):
-            return _l.apply(lp, st, xx, train=train, rng=rr, mask=mm)
+            return _l.apply(cast(lp), st, xx, train=train, rng=rr, mask=mm)
         args = (params, state, x, rng, mask)
     if remat:
-        fn = jax.checkpoint(fn)
+        # nothing is kept but what a layer names "remat_keep" (a result
+        # far dearer to make again than to hold: a recurrence's output)
+        fn = jax.checkpoint(
+            fn, policy=jax.checkpoint_policies.save_only_these_names(
+                "remat_keep"))
     return fn(*args)
 
 
@@ -521,7 +530,12 @@ class MultiLayerNetwork:
         activation, new_state, new_carries)."""
         if self._input_types is None:
             self._input_types = self._resolve_types()
-        params = self._cast_params(params)
+        # under gradient checkpointing a layer casts its own weights
+        # inside its rematerialised region (`_layer_call`)
+        remat = train and self.conf.gradient_checkpointing
+        late_cast = self._cast_params if remat else None
+        if not remat:
+            params = self._cast_params(params)
         x = _as_jnp(x, self._compute_dtype)
         cur_type = self.conf.input_type
         n = len(self.layers) if upto is None else upto
@@ -539,6 +553,8 @@ class MultiLayerNetwork:
             mask = fmask if cur_type.kind == Kind.RNN else None
             key = str(i)
             layer_params = params[key]
+            if remat and layer.weight_noise is not None:
+                layer_params = self._cast_params(layer_params)
             if train and sub_rng is not None and layer.weight_noise is not None:
                 from deeplearning4j_tpu.nn.regularization import (
                     apply_weight_noise,
@@ -550,19 +566,18 @@ class MultiLayerNetwork:
             # activations in the backward pass instead of storing them —
             # HBM for recompute FLOPs (jax.checkpoint). Only the training
             # forward pays for a backward, so inference is untouched.
-            remat = train and self.conf.gradient_checkpointing
             if carries is not None and _is_stateful_recurrent(layer):
                 y, carry = _layer_call(
                     layer, seq=True, train=train, remat=remat,
                     params=layer_params, x=x, carry=carries.get(key),
-                    rng=sub_rng, mask=mask)
+                    rng=sub_rng, mask=mask, cast=late_cast)
                 new_carries[key] = carry
                 new_state[key] = state[key]
             else:
                 y, s = _layer_call(
                     layer, seq=False, train=train, remat=remat,
                     params=layer_params, x=x, state=state[key],
-                    rng=sub_rng, mask=mask)
+                    rng=sub_rng, mask=mask, cast=late_cast)
                 new_state[key] = s
             x = y
             cur_type = layer.output_type(cur_type)
@@ -583,10 +598,11 @@ class MultiLayerNetwork:
             raise ValueError("Last layer must be an output/loss layer with a "
                              "score() method to compute training loss")
         params_c = self._cast_params(params)
-        # forward up to (but excluding) the output layer
+        # forward up to (but excluding) the output layer; it casts the
+        # weights itself, a layer at a time under gradient checkpointing
         head = self.layers[-1]
         feat, new_state, new_carries = self._forward(
-            params_c, state, x, train, rng, fmask, carries,
+            params, state, x, train, rng, fmask, carries,
             upto=len(self.layers) - 1)
         out_mask = lmask if lmask is not None else (
             fmask if _required_kind(head) == Kind.RNN else None)
@@ -648,10 +664,8 @@ class MultiLayerNetwork:
                 # hint makes XLA derive reduce-scatter -> sharded update
                 # -> all-gather (parallel/plan.py)
                 grads = plan.constrain_grads(grads)
-            updates, new_opt = tx.update(grads, opt_state, params)
-            if plan is not None:
-                updates = plan.constrain_grads(updates)
-            new_params = optax.apply_updates(params, updates)
+            new_params, new_opt, updates = apply_update(
+                tx, grads, opt_state, params, plan)
             if constrained:     # post-update projection (DL4J applyConstraints)
                 new_params = apply_constraints(layer_map, new_params)
             if plan is not None:
@@ -986,10 +1000,8 @@ class MultiLayerNetwork:
                     loss_fn, has_aux=True)(params)
                 if plan is not None:
                     grads = plan.constrain_grads(grads)
-                updates, new_opt = tx.update(grads, opt_state, params)
-                if plan is not None:
-                    updates = plan.constrain_grads(updates)
-                new_params = optax.apply_updates(params, updates)
+                new_params, new_opt, _ = apply_update(
+                    tx, grads, opt_state, params, plan)
                 if constrained:
                     new_params = apply_constraints(layer_map, new_params)
                 if plan is not None:
@@ -1046,10 +1058,8 @@ class MultiLayerNetwork:
                 body, (zeros, state), (xs, ys, fms, lms, subs))
             grads = jax.tree_util.tree_map(
                 lambda g: g / subs.shape[0], gsum)
-            updates, new_opt = tx.update(grads, opt_state, params)
-            if plan is not None:
-                updates = plan.constrain_grads(updates)
-            new_params = optax.apply_updates(params, updates)
+            new_params, new_opt, updates = apply_update(
+                tx, grads, opt_state, params, plan)
             if constrained:
                 new_params = apply_constraints(layer_map, new_params)
             if plan is not None:

@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Optional
 
+import jax
 import optax
 
 
@@ -166,10 +167,18 @@ class AdamW(Updater):
     beta2: float = 0.999
     epsilon: float = 1e-8
     weight_decay: float = 1e-2
+    #: decay only the leaves of two or more dimensions (the LM recipe:
+    #: matrices, not gains, biases or per-head scalars)
+    decay_matrices_only: bool = False
 
     def to_optax(self):
+        mask = None
+        if self.decay_matrices_only:
+            mask = lambda params: jax.tree_util.tree_map(
+                lambda a: a.ndim >= 2, params)
         return optax.adamw(self._lr(), b1=self.beta1, b2=self.beta2,
-                           eps=self.epsilon, weight_decay=self.weight_decay)
+                           eps=self.epsilon, weight_decay=self.weight_decay,
+                           mask=mask)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -301,3 +310,16 @@ def build_optimizer(updater: Any, grad_clip_norm: Optional[float] = None,
         chain.append(optax.clip_by_global_norm(grad_clip_norm))
     chain.append(tx)
     return optax.chain(*chain) if len(chain) > 1 else tx
+
+
+def apply_update(tx, grads, opt_state, params, plan=None):
+    """One optimizer update, ``(new_params, new_opt_state, updates)``:
+    what every compiled train step of both containers does with its
+    gradients, under the scope ``opt/update`` (a trace tells the
+    optimizer's device ops by it). ``plan`` pins the updates to the
+    gradients' layout."""
+    with jax.named_scope("opt/update"):
+        updates, new_opt = tx.update(grads, opt_state, params)
+        if plan is not None:
+            updates = plan.constrain_grads(updates)
+        return optax.apply_updates(params, updates), new_opt, updates

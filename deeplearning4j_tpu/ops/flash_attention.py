@@ -10,8 +10,11 @@ into a single kernel (no per-block XLA op dispatch, scores stay in
 registers/VMEM, MXU does the two matmuls back to back).
 
 Semantics match dot_product_attention exactly (tested):
-- (B, T, H, D) layout, f32 accumulation, 1/sqrt(D) scaling;
-- optional causal masking;
+- (B, T, H, D) layout, f32 accumulation, 1/sqrt(D) scaling; v (and the
+  output) may have another head width than q and k (latent attention:
+  192-wide q.k, 128-wide v);
+- optional causal masking; key blocks wholly above the diagonal are
+  neither fetched nor computed, forward and backward;
 - optional (B, Tk) 0/1 key-validity mask, fully-masked query rows emit 0;
 - backward pass: true flash backward — two Pallas passes (dq over key
   blocks; dk/dv over query blocks) recomputing the probabilities from
@@ -36,34 +39,58 @@ from deeplearning4j_tpu.util.platform import is_tpu_backend
 NEG = -1e30
 
 
-def _masked_scores(q, k, kmask, qi, kj, *, causal, block_q, block_k,
-                   scale):
+class _Geometry:
+    """Which (q block, k block) tiles hold a visible score, as integer
+    arithmetic on traced block indices: in a BlockSpec index map (so that
+    a tile above the causal diagonal is never fetched) and inside a kernel
+    (so that it is never computed).
+
+    Query i sees key j iff ``j <= i`` (causal). For q block ``qi`` the
+    live k blocks are ``0 .. k_hi(qi)``; for k block ``kj`` the live q
+    blocks are ``q_lo(kj) .. nq - 1``. The third grid axis counts STEPS
+    from the low end of the live range; a step past the high end repeats
+    the last live block index (no new DMA) and computes nothing."""
+
+    def __init__(self, causal, block_q, block_k, nq, nk):
+        self.causal = causal
+        self.bq, self.bk, self.nq, self.nk = block_q, block_k, nq, nk
+
+    def k_hi(self, qi):
+        if not self.causal:
+            return qi * 0 + self.nk - 1
+        return jnp.minimum(((qi + 1) * self.bq - 1) // self.bk, self.nk - 1)
+
+    def q_lo(self, kj):
+        if not self.causal:
+            return kj * 0
+        return jnp.minimum((kj * self.bk) // self.bq, self.nq - 1)
+
+
+def _masked_scores(q, k, kmask, qi, kj, *, geom, scale):
     """Scaled masked scores for one (q block, k block) tile — the ONE
     copy of the masking semantics, shared by the forward kernel and the
-    backward recomputation."""
+    backward recomputation. Operands keep their dtype (bf16 operands run
+    the MXU at its bf16 rate); the product accumulates in float32."""
     s = scale * jax.lax.dot_general(
-        q.astype(jnp.float32), k.astype(jnp.float32),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     s = jnp.where(kmask[None, :] > 0, s, NEG)
-    if causal:
-        qpos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        kpos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+    if geom.causal:
+        qpos = qi * geom.bq + jax.lax.broadcasted_iota(
+            jnp.int32, (geom.bq, geom.bk), 0)
+        kpos = kj * geom.bk + jax.lax.broadcasted_iota(
+            jnp.int32, (geom.bq, geom.bk), 1)
         s = jnp.where(qpos >= kpos, s, NEG)
     return s
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr,
-                 l_scr, acc_scr, *, causal: bool, block_q: int,
-                 block_k: int, scale: float):
+                 l_scr, acc_scr, *, geom, scale: float):
     """Grid (B*H, q_blocks, k_blocks), k innermost: each step folds ONE
     (block_k, D) K/V tile into the running (m, l, acc) scratch — only one
     K and one V tile are VMEM-resident at a time, so sequence length is
     not bounded by VMEM."""
     qi = pl.program_id(1)
     kj = pl.program_id(2)
-    nkb = pl.num_programs(2)
 
     @pl.when(kj == 0)
     def _init():
@@ -71,16 +98,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: skip key blocks entirely above the diagonal (their whole
-    # tile is masked) — no MXU work for ~half the grid
-    live = (kj * block_k <= (qi + 1) * block_q - 1) if causal else True
-
-    @pl.when(live)
+    # tiles wholly above the diagonal are neither fetched (the index map
+    # repeats the last live tile) nor computed
+    @pl.when(kj <= geom.k_hi(qi))
     def _step():
+        v = v_ref[0]
         s = _masked_scores(q_ref[0], k_ref[0], mask_ref[0, 0], qi, kj,
-                           causal=causal, block_q=block_q,
-                           block_k=block_k, scale=scale)
-        v = v_ref[0].astype(jnp.float32)
+                           geom=geom, scale=scale)
         m = m_scr[...]
         m_new = jnp.maximum(m, s.max(-1))
         # exp(NEG - NEG) == 1 for all-masked rows: zero those terms
@@ -91,10 +115,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr,
         m_scr[...] = m_new
         l_scr[...] = l_scr[...] * alpha + p.sum(-1)
         acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(kj == nkb - 1)
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
         m = m_scr[...]
         l = l_scr[...]
@@ -109,15 +133,18 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr,
         lse_ref[0, 0] = jnp.where(m <= NEG / 2, -NEG, lse)
 
 
+def _head_rows(x):
+    """(B, T, H, D) -> (B*H, T, D): one grid row per (batch, head)."""
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
 def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
                 interpret: bool):
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[3]
     scale = 1.0 / float(d) ** 0.5
-    # (B, T, H, D) -> (B*H, T, D): one grid row per (batch, head)
-    qh = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    kh = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    vh = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
+    geom = _Geometry(causal, block_q, block_k, tq // block_q, tk // block_k)
     if mask is None:
         mask = jnp.ones((b, tk), jnp.float32)
     # rank-2 operands carry a singleton MIDDLE dim: the Mosaic lowering
@@ -126,37 +153,38 @@ def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
     # (second-to-last = 1 != b); as (b, 1, t) with (1, 1, block) blocks
     # the trailing pair is (1==1, block%128==0) — valid, same bytes
     mask = mask.astype(jnp.float32).reshape(b, 1, tk)
+    kidx = lambda qi, kj: jnp.minimum(kj, geom.k_hi(qi))
 
-    kernel = functools.partial(_attn_kernel, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               scale=scale)
     out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, tq // block_q, tk // block_k),
+        functools.partial(_attn_kernel, geom=geom, scale=scale),
+        grid=(b * h, geom.nq, geom.nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (bh, kj, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda bh, qi, kj: (bh, kidx(qi, kj), 0)),
+            pl.BlockSpec((1, block_k, dv),
+                         lambda bh, qi, kj: (bh, kidx(qi, kj), 0)),
             pl.BlockSpec((1, 1, block_k),
-                         lambda bh, qi, kj, _h=h: (bh // _h, 0, kj)),
+                         lambda bh, qi, kj, _h=h: (bh // _h, 0,
+                                                   kidx(qi, kj))),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, qi, kj: (bh, qi, 0)),
             pl.BlockSpec((1, 1, block_q), lambda bh, qi, kj: (bh, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(qh, kh, vh, mask)
-    return (out.reshape(b, h, tq, d).transpose(0, 2, 1, 3),
+    )(_head_rows(q), _head_rows(k), _head_rows(v), mask)
+    return (out.reshape(b, h, tq, dv).transpose(0, 2, 1, 3),
             lse.reshape(b * h, tq))
 
 
@@ -171,83 +199,70 @@ def _flash_fwd(q, k, v, mask, causal, block_q, block_k, interpret):
     return (out, lse), (q, k, v, mask, out, lse)
 
 
-def _bwd_scores(q_ref, k_ref, mask_ref, lse_row, qi, kj, *, causal,
-                block_q, block_k, scale):
+def _bwd_scores(q, k, kmask, lse_row, qi, kj, *, geom, scale):
     """Recompute the softmax probabilities p = exp(s - lse) for one
     (q block, k block) tile via the shared masked-scores helper."""
-    s = _masked_scores(q_ref[0], k_ref[0], mask_ref[0, 0], qi, kj,
-                       causal=causal, block_q=block_q, block_k=block_k,
-                       scale=scale)
+    s = _masked_scores(q, k, kmask, qi, kj, geom=geom, scale=scale)
     p = jnp.exp(s - lse_row[:, None])
     return jnp.where(s > NEG / 2, p, 0.0)
 
 
+def _bwd_ds(p, do, v, delta_row):
+    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    return p * (dp - delta_row[:, None])
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   mask_ref, dq_ref, dq_scr, *, causal, block_q, block_k,
-                   scale):
+                   mask_ref, dq_ref, dq_scr, *, geom, scale):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
-    nkb = pl.num_programs(2)
 
     @pl.when(kj == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    live = (kj * block_k <= (qi + 1) * block_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(kj <= geom.k_hi(qi))
     def _step():
-        p = _bwd_scores(q_ref, k_ref, mask_ref, lse_ref[0, 0], qi, kj,
-                        causal=causal, block_q=block_q, block_k=block_k,
-                        scale=scale)
-        do = do_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        k = k_ref[0].astype(jnp.float32)
+        k = k_ref[0]
+        p = _bwd_scores(q_ref[0], k, mask_ref[0, 0], lse_ref[0, 0], qi, kj,
+                        geom=geom, scale=scale)
+        ds = _bwd_ds(p, do_ref[0], v_ref[0], delta_ref[0, 0])
         dq_scr[...] += scale * jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(kj == nkb - 1)
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    mask_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, causal,
-                    block_q, block_k, scale):
+                    mask_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, geom,
+                    scale):
     kj = pl.program_id(1)
-    qi = pl.program_id(2)
-    nqb = pl.num_programs(2)
+    step = pl.program_id(2)
+    qi = geom.q_lo(kj) + step
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = (kj * block_k <= (qi + 1) * block_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(qi <= geom.nq - 1)
     def _step():
-        p = _bwd_scores(q_ref, k_ref, mask_ref, lse_ref[0, 0], qi, kj,
-                        causal=causal, block_q=block_q, block_k=block_k,
-                        scale=scale)
-        do = do_ref[0].astype(jnp.float32)
+        q, do = q_ref[0], do_ref[0]
+        p = _bwd_scores(q, k_ref[0], mask_ref[0, 0], lse_ref[0, 0], qi, kj,
+                        geom=geom, scale=scale)
         dv_scr[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        q = q_ref[0].astype(jnp.float32)
+        ds = _bwd_ds(p, do, v_ref[0], delta_ref[0, 0])
         dk_scr[...] += scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nqb - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -256,26 +271,26 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     """True flash backward: two Pallas passes (dq over k blocks; dk/dv
     over q blocks) recomputing p from the saved LSE — the score matrix
-    never materializes, matching the forward's memory shape."""
+    never materializes, matching the forward's memory shape, and a tile
+    above the causal diagonal is skipped as in the forward."""
     q, k, v, mask, out, lse = res
     g, g_lse = g                  # cotangents of (out, lse)
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[3]
     scale = 1.0 / float(d) ** 0.5
-    g = g.astype(jnp.float32)
+    geom = _Geometry(causal, block_q, block_k, tq // block_q, tk // block_k)
     # delta_i = rowsum(dO * O) (the softmax-jacobian diagonal term).
     # The LSE output is differentiable too: d lse_i / d s_ij = p_ij, so
     # its cotangent folds in as ds = p * (dp - (delta - g_lse)) — no
     # kernel change, just an effective delta.
-    delta = jnp.sum(g * out.astype(jnp.float32), axis=-1)   # (B, T, H)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)                                # (B, T, H)
     # (g_lse is always instantiated — zeros when lse was unused; XLA
     # folds the subtraction away in that case)
-    g_lse_bth = g_lse.astype(jnp.float32)                   # (bh, tq)
-    delta = delta - g_lse_bth.reshape(b, h, tq).transpose(0, 2, 1)
-    gh = g.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    qh = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-    kh = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    vh = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
+    delta = delta - g_lse.astype(jnp.float32).reshape(b, h, tq) \
+        .transpose(0, 2, 1)
+    gh = _head_rows(g.astype(q.dtype))
+    qh, kh, vh = _head_rows(q), _head_rows(k), _head_rows(v)
     # singleton middle dims on the rank-2 operands (lse/delta/mask) — see
     # the forward call: (1, 1, block) trailing pairs satisfy the Mosaic
     # (8, 128)-or-equal block constraint where (1, block) cannot
@@ -284,58 +299,60 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     m_in = (jnp.ones((b, tk), jnp.float32) if mask is None
             else mask.astype(jnp.float32)).reshape(b, 1, tk)
 
-    common = dict(causal=causal, block_q=block_q, block_k=block_k,
-                  scale=scale)
+    common = dict(geom=geom, scale=scale)
+    kidx = lambda qi, kj: jnp.minimum(kj, geom.k_hi(qi))
+    qidx = lambda kj, st: jnp.minimum(geom.q_lo(kj) + st, geom.nq - 1)
+    q_spec = lambda ix: pl.BlockSpec((1, block_q, d), ix)
+    do_spec = lambda ix: pl.BlockSpec((1, block_q, dv), ix)
+    row_spec = lambda ix: pl.BlockSpec((1, 1, block_q), ix)
+    k_spec = lambda ix: pl.BlockSpec((1, block_k, d), ix)
+    v_spec = lambda ix: pl.BlockSpec((1, block_k, dv), ix)
+
+    at_q = lambda bh, qi, kj: (bh, qi, 0)
+    at_row = lambda bh, qi, kj: (bh, 0, qi)
+    at_k = lambda bh, qi, kj: (bh, kidx(qi, kj), 0)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
-        grid=(b * h, tq // block_q, tk // block_k),
+        grid=(b * h, geom.nq, geom.nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi, kj: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi, kj: (bh, 0, qi)),
+            q_spec(at_q), k_spec(at_k), v_spec(at_k), do_spec(at_q),
+            row_spec(at_row), row_spec(at_row),
             pl.BlockSpec((1, 1, block_k),
-                         lambda bh, qi, kj, _h=h: (bh // _h, 0, kj)),
+                         lambda bh, qi, kj, _h=h: (bh // _h, 0,
+                                                   kidx(qi, kj))),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda bh, qi, kj: (bh, qi, 0)),
+        out_specs=q_spec(at_q),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(qh, kh, vh, gh, lse3, dh, m_in)
 
-    dk, dv = pl.pallas_call(
+    at_q = lambda bh, kj, st: (bh, qidx(kj, st), 0)
+    at_row = lambda bh, kj, st: (bh, 0, qidx(kj, st))
+    at_k = lambda bh, kj, st: (bh, kj, 0)
+    dk, dv_ = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
-        grid=(b * h, tk // block_k, tq // block_q),
+        grid=(b * h, geom.nk, geom.nq),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, kj, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, kj, qi: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, kj, qi: (bh, kj, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, kj, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, kj, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, kj, qi: (bh, 0, qi)),
+            q_spec(at_q), k_spec(at_k), v_spec(at_k), do_spec(at_q),
+            row_spec(at_row), row_spec(at_row),
             pl.BlockSpec((1, 1, block_k),
-                         lambda bh, kj, qi, _h=h: (bh // _h, 0, kj)),
+                         lambda bh, kj, st, _h=h: (bh // _h, 0, kj)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, kj, qi: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, kj, qi: (bh, kj, 0)),
-        ],
+        out_specs=[k_spec(at_k), v_spec(at_k)],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, tk, dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qh, kh, vh, gh, lse3, dh, m_in)
 
-    reshape = lambda a, t: a.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-    return reshape(dq, tq), reshape(dk, tk), reshape(dv, tk), None
+    back = lambda a: a.reshape(b, h, a.shape[1], -1).transpose(0, 2, 1, 3)
+    return back(dq), back(dk), back(dv_), None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -351,6 +368,10 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
     Sequence lengths are padded to the block size internally (padded keys
     are mask-excluded; padded query rows are sliced off).
 
+    ``v`` (and so the output) may have another head width than ``q`` and
+    ``k``. With ``causal``, key tiles wholly above the diagonal are neither
+    fetched nor computed, forward and backward.
+
     return_lse=True additionally returns the per-row log-sum-exp
     ((B, T, H), the softmax normalizer in log space) so partial results
     over DIFFERENT key shards can be merged exactly:
@@ -362,6 +383,9 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
     plain autodiff of the merge arithmetic."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
+    if k.shape[2:] != (h, d) or v.shape[:3] != k.shape[:3]:
+        raise ValueError(f"q {q.shape}, k {k.shape}, v {v.shape}: k needs "
+                         "q's heads and head width, v k's length and heads")
     if interpret is None:
         interpret = not is_tpu_backend()
     block_q = min(block_q or 128, max(tq, 1))

@@ -305,6 +305,24 @@ class DecodeEngine:
                         f"decode[{name}]: layer {i} is a non-causal "
                         "TransformerBlock; autoregressive decode needs "
                         "causal attention")
+                unserved = [what for what, on in (
+                    (f"a {type(layer.attn).__name__} attention",
+                     layer.attn is not None),
+                    (f"a {type(layer.ffn).__name__} feed-forward"
+                     + (f" holding experts {layer.ffn.experts_held}"
+                        if getattr(layer.ffn, "experts_held", None) else ""),
+                     layer.ffn is not None),
+                    (f"norm={layer.norm!r}", layer.norm != "layer"),
+                    ("a bias-free MLP", not layer.has_bias),
+                ) if on]
+                if unserved:
+                    # the KV pool has no latent cache and no recurrent
+                    # state, the block programs one norm and a biased dense
+                    # MLP: refuse by name rather than serve it wrong
+                    raise ModelLoadError(
+                        f"decode[{name}]: layer {i} is a TransformerBlock "
+                        f"with {'; '.join(unserved)}, which this runtime "
+                        "cannot serve yet (it trains through fit())")
                 h = layer.n_heads
                 d = layer.n_out // layer.n_heads
                 if self.n_heads not in (None, h) or \

@@ -2,7 +2,7 @@ from deeplearning4j_tpu.train.listeners import (
     TrainingListener, ScoreIterationListener, PerformanceListener,
     CollectScoresIterationListener, TimeIterationListener,
     EvaluativeListener, CheckpointListener, ProfilerListener,
-    DivergenceListener, TrainingDivergedError,
+    DivergenceListener, ExpertLoadListener, TrainingDivergedError,
 )
 from deeplearning4j_tpu.train.resilience import (
     CheckpointManager, FaultPolicy, FitReport, PreemptionGuard,
@@ -16,7 +16,7 @@ __all__ = [
     "TrainingListener", "ScoreIterationListener", "PerformanceListener",
     "CollectScoresIterationListener", "TimeIterationListener",
     "EvaluativeListener", "CheckpointListener", "ProfilerListener",
-    "DivergenceListener", "TrainingDivergedError",
+    "DivergenceListener", "ExpertLoadListener", "TrainingDivergedError",
     "CheckpointManager", "FaultPolicy", "FitReport", "PreemptionGuard",
     "ResilientTrainer",
     "BackTrackLineSearch", "LineGradientDescent", "ConjugateGradient",

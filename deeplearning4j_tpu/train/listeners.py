@@ -10,6 +10,7 @@ Parity with DL4J's TrainingListener/IterationListener framework
 - EvaluativeListener              (periodic held-out evaluation)
 - CheckpointListener              (periodic checkpoints w/ keepLast(n);
                                    checkpoint/CheckpointListener.java:72-144)
+- ExpertLoadListener              (expert layers' routed tokens as counters)
 """
 from __future__ import annotations
 
@@ -379,6 +380,63 @@ class ProfilerListener(TrainingListener):
             jax.profiler.stop_trace()
             self._active = False
             self.trace_dir = self.log_dir
+
+
+class ExpertLoadListener(TrainingListener):
+    """What a model's expert layers (`MoEFeedForward`, alone or as a
+    `TransformerBlock`'s FFN) routed, as counters. The layers count in
+    their own STATE, so every fit path of both containers counts alike;
+    this listener reads the state where ``fit()`` holds a finished one,
+    at the start and the end of an epoch (inside an epoch the pipelined
+    paths have the next chunk in flight, and reading its state would wait
+    for it), and publishes the difference:
+    ``moe_tokens_routed_total{layer,held}`` ((token, expert) pairs, by
+    whether the expert is held here) and
+    ``moe_expert_load_max_over_mean{layer}`` (the busiest expert's tokens
+    over the mean of all, last step). The state's totals are uint32 and
+    wrap; the difference is taken modulo 2**32."""
+
+    def __init__(self):
+        self._at_start = {}
+
+    @staticmethod
+    def _layers(model):
+        """(state key, expert layer, its state) of every expert layer."""
+        from deeplearning4j_tpu.nn.regularization import constraint_map
+        for key, layer in constraint_map(model).items():
+            state = model.state.get(key) or {}
+            if getattr(layer, "ffn", None) is not None:
+                layer, state = layer.ffn, state.get("ffn", {})
+            if "tokens_routed_total" in state:
+                yield key, layer, state
+
+    def on_epoch_start(self, model, epoch):
+        import numpy as np
+        self._at_start = {
+            key: np.asarray(state["tokens_routed_total"], np.int64)
+            for key, _, state in self._layers(model)}
+
+    def on_epoch_end(self, model, epoch):
+        import numpy as np
+        from deeplearning4j_tpu import monitor
+        routed = monitor.counter(
+            "moe_tokens_routed_total",
+            "(token, expert) pairs routed by an expert layer, by whether "
+            "the expert is held here", labels=("layer", "held"))
+        load = monitor.gauge(
+            "moe_expert_load_max_over_mean",
+            "tokens of the busiest expert over the mean of all experts, "
+            "last step", labels=("layer",))
+        for key, layer, state in self._layers(model):
+            total = np.asarray(state["tokens_routed_total"], np.int64)
+            drew = (total - self._at_start.get(key, 0)) % 2 ** 32
+            lo, hi = layer.experts_held or (0, layer.n_experts)
+            held = int(drew[lo:hi].sum())
+            routed.inc(held, layer=key, held="yes")
+            routed.inc(int(drew.sum()) - held, layer=key, held="no")
+            last = np.asarray(state["tokens_routed"], np.float64)
+            load.set(float(last.max() / max(last.mean(), 1e-9)), layer=key)
+            self._at_start[key] = total
 
 
 class DivergenceListener(TrainingListener):

@@ -159,6 +159,61 @@ class TestFlashKernelCompiles:
         for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
             assert kernel in hlo
 
+    @pytest.mark.parametrize("backward", [True, False])
+    def test_two_head_sizes_at_the_lm_cells_widths(self, v5e, backward):
+        # latent attention's expanded form: 32 heads, q.k 192 wide and v
+        # 128, 8,192 positions, block 512
+        from deeplearning4j_tpu.ops.flash_attention import flash_attention
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, causal=True, block_q=512, block_k=512,
+                interpret=False).astype(jnp.float32) ** 2)
+
+        qk = ((1, 8192, 32, 192), jnp.bfloat16)
+        v = ((1, 8192, 32, 128), jnp.bfloat16)
+        hlo = _compile_v5e(
+            jax.grad(loss, argnums=(0, 1, 2)) if backward else loss,
+            self._one(v5e), qk, qk, v)
+        for kernel in (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+                       if backward else ("flash_fwd",)):
+            assert kernel in hlo
+
+    def test_expert_grouped_products_compile_as_ragged_dot(self, v5e):
+        # the expert layer's grouped product at the cell's widths (one
+        # dispatch block's worst-case rows): XLA's own tiled kernel over
+        # the rows that are in a group
+        from deeplearning4j_tpu.nn.layers.attention import _grouped_matmul
+
+        def loss(x, w, sizes):
+            return jnp.sum(_grouped_matmul(x, w, sizes).astype(
+                jnp.float32) ** 2)
+
+        args = [jax.ShapeDtypeStruct(shape, dtype,
+                                     sharding=self._one(v5e))
+                for shape, dtype in (((32768, 2304), jnp.bfloat16),
+                                     ((8, 2304, 1024), jnp.bfloat16),
+                                     ((8,), jnp.int32))]
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            *args).compile().as_text()
+        assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
+
+    def test_kda_chunked_scan_compiles_with_its_backward(self, v5e):
+        # one group of the cell's (sequence, head) pairs: 8 pairs, 8,192
+        # positions in chunks of 64, d_k = d_v = 128, bf16 products
+        from deeplearning4j_tpu.nn.layers.linear_attention import kda_chunked
+
+        def loss(q, k, v, log_a, beta):
+            o, s = kda_chunked(q, k, v, log_a, beta, chunk=64,
+                               mm_dtype=jnp.bfloat16)
+            return jnp.sum(o ** 2) + jnp.sum(s ** 2)
+
+        wide = ((1, 8192, 8, 128), jnp.float32)
+        hlo = _compile_v5e(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                           self._one(v5e), wide, wide, wide, wide,
+                           ((1, 8192, 8), jnp.float32))
+        assert "triangular" in hlo.lower() or "while" in hlo
+
     def test_masked_padded_f32_with_lse(self, v5e):
         # t=200: the pad path; masked non-causal with the lse output and
         # its cotangent, in f32

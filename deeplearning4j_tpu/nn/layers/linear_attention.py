@@ -16,15 +16,14 @@ the chunk receives::
     U = U0 - W S;  O = (q exp(g)) S + Aqk U
     S' = Diag(exp(g_C)) S + (k exp(g_C - g))^T U          the hand-over
 
-Everything but the last two lines is independent of ``S`` and is made for
-all chunks at once; a `lax.scan` over the chunks carries ``S`` in float32.
-No exponent is ever positive: the pairs inside a block of 16 positions
-take ``exp(g_t - g_i)`` pair by pair in one fused pass, every other pair
-splits it at a position ``r`` between i and t (``g_t - r <= 0`` and
-``r - g_i <= 0``) into two matrix operands, block by doubling block
-(`_decayed_products`). The backward pass is autodiff through the solve and
-the scan; the (sequence, head) pairs go through in groups, each
-rematerialised, so that only one group's temporaries are alive.
+Everything but the last two lines is independent of ``S``: a map over the
+(pair, chunk) tiles, `ops/kda_chunk.py` (one tile function; on a TPU at
+widths of 128 one Pallas kernel forward and one backward with the tile in
+VMEM, elsewhere the same function vmapped under XLA). No exponent it
+takes is positive, whatever the decay. A `lax.scan` over the chunks
+carries ``S`` in float32; its backward pass is autodiff. The (sequence,
+head) pairs go through in groups, each rematerialised, so that only one
+group's temporaries are alive.
 
 `MultiHeadLatentAttention` (MLA) is DeepSeek's latent attention in the
 expanded form used for training, with no positional rotation
@@ -46,6 +45,7 @@ from deeplearning4j_tpu.nn.conf.base import (
 )
 from deeplearning4j_tpu.nn.initializers import get_initializer
 from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
+from deeplearning4j_tpu.ops.kda_chunk import chunk_algebra
 from deeplearning4j_tpu.util.platform import is_tpu_backend
 
 
@@ -66,89 +66,19 @@ def causal_conv(x, taps):
 
 
 # ------------------------------------------------------------- KDA core
-@jax.checkpoint
-def _pairs_in_blocks(q, k, g):
-    """The products of `_decayed_products` for the pairs inside blocks of
-    up to 16 positions, pair by pair in float32: q, k, g (M, N, P, b, d)
-    -> two (M, N, P, b, b). ``exp(g_t - g_i)`` is taken only where i <= t.
-    Rematerialised: nothing b x b x d is kept for the backward pass."""
-    b = k.shape[3]
-    seen = jnp.tril(jnp.ones((b, b), bool))[..., None]
-    decay = jnp.exp(jnp.where(seen, g[..., :, None, :] - g[..., None, :, :],
-                              -jnp.inf)) * k[..., None, :, :]
-    kk = jnp.sum(k[..., :, None, :] * decay, axis=-1)
-    qk = jnp.sum(q[..., :, None, :] * decay, axis=-1)
-    return jnp.where(jnp.tril(jnp.ones((b, b), bool), -1), kk, 0.0), qk
-
-
-def _decayed_products(q, k, g, dot):
-    """``sum_c x_tc k_ic exp(g_tc - g_ic)`` inside every chunk for x = k
-    (pairs i < t) and x = q (pairs i <= t): q, k, g (M, N, C, d) -> two
-    (M, N, C, C) lower-triangular matrices.
-
-    Built from blocks of 16 positions (`_pairs_in_blocks`) by doubling:
-    two sibling blocks of b positions become one of 2b whose new quarter,
-    the rows of the second against the columns of the first, is ONE
-    matrix product of ``x_t exp(g_t - r)`` and ``k_i exp(r - g_i)`` with
-    ``r`` = g at the first block's end, so that both exponents are <= 0
-    whatever the decay."""
-    c = k.shape[2]
-    if c & (c - 1):
-        raise ValueError(f"chunk {c} is not a power of two")
-    b = min(c, 16)
-    blocks = lambda x: x.reshape(x.shape[:2] + (c // b, b) + x.shape[3:])
-    kk, qk = _pairs_in_blocks(blocks(q), blocks(k), blocks(g))
-    while b < c:
-        halves = lambda x: x.reshape(x.shape[:2] + (c // (2 * b), 2, b)
-                                     + x.shape[3:])
-        gh, kh, qh = halves(g), halves(k), halves(q)
-        ref = gh[:, :, :, 0, -1:]                        # (M,N,c/2b,1,d)
-        later = jnp.exp(gh[:, :, :, 1] - ref)
-        rows = jnp.concatenate([kh[:, :, :, 1] * later,
-                                qh[:, :, :, 1] * later], axis=3)
-        cols = kh[:, :, :, 0] * jnp.exp(ref - gh[:, :, :, 0])
-        cross = dot(rows, cols, "mnptc,mnpic->mnpti")
-
-        def merged(diag, quarter):   # (M,N,c/b,b,b) -> (M,N,c/2b,2b,2b)
-            d = diag.reshape(diag.shape[:2] + (-1, 2, b, b))
-            top = jnp.concatenate([d[:, :, :, 0],
-                                   jnp.zeros_like(quarter)], axis=-1)
-            return jnp.concatenate(
-                [top, jnp.concatenate([quarter, d[:, :, :, 1]], axis=-1)],
-                axis=-2)
-
-        kk = merged(kk, cross[:, :, :, :b])
-        qk = merged(qk, cross[:, :, :, b:])
-        b *= 2
-    return kk[:, :, 0], qk[:, :, 0]
-
-
 def _kda_core(q, k, v, log_a, beta, s0, *, mm):
     """`kda_chunked` for M (sequence, head) pairs: q, k, log_a
     (M, N, C, d_k), v (M, N, C, d_v), beta (M, N, C, 1), all float32, in N
     chunks of C positions; s0 (M, d_k, d_v). Returns (o (M, N, C, d_v),
     the final state)."""
     f32 = q.dtype
-    chunk, dk = q.shape[2], q.shape[3]
     g = jnp.cumsum(log_a, axis=2)                        # (M,N,C,dk)
+    w, u0, q_in, k_out, a_qk = chunk_algebra(q, k, v, g, beta, mm=mm)
+    g_end = g[:, :, -1:, :]                              # (M,N,1,dk)
 
     def dot(x, y, spec):
         return jnp.einsum(spec, x.astype(mm), y.astype(mm),
                           preferred_element_type=f32)
-
-    a_kk, a_qk = _decayed_products(q, k, g, dot)
-    a_kk = beta * a_kk
-
-    # ---- the solve: (I + A) [W | U0] = [b k e^g | b v]
-    decay = jnp.exp(g)
-    rhs = jnp.concatenate([beta * k * decay, beta * v], axis=-1)
-    sol = jax.scipy.linalg.solve_triangular(
-        a_kk + jnp.eye(chunk, dtype=f32), rhs, lower=True,
-        unit_diagonal=True)
-    w, u0 = sol[..., :dk], sol[..., dk:]
-    q_in = q * decay
-    g_end = g[:, :, -1:, :]                              # (M,N,1,dk)
-    k_out = k * jnp.exp(g_end - g)
 
     # ---- the hand-over between chunks
     def step(s, xs):
@@ -166,19 +96,22 @@ def _kda_core(q, k, v, log_a, beta, s0, *, mm):
 
 
 #: the temporaries of the (sequence, head) pairs that go through the
-#: recurrence together may take this much; more pairs go in groups
-_SCAN_LIVE_BYTES = 2 << 30
+#: recurrence together may take this much; more pairs go in groups (on the
+#: v5e 8 groups of 8 pairs of 8,192 positions are 2 % faster a step than 4
+#: of 16, and reserve 0.35 GB less)
+_SCAN_LIVE_BYTES = 1 << 30
 
 
 def _in_groups(fn, arrays, t, d):
     """``fn`` over equal slices of the arrays' leading axis (the
     independent (sequence, head) pairs), one slice after another and each
     rematerialised in the backward pass: only one group's temporaries
-    (some forty tensors of T x d_k floats a pair) are alive at a time. The
-    groups are the fewest that divide the pairs and keep those
-    temporaries under `_SCAN_LIVE_BYTES`."""
+    (some twenty tensors of T x d_k floats a pair: the v5e compile of one
+    group's backward pass holds 13 to 16 beside its arguments and results
+    of 2 each) are alive at a time. The groups are the fewest that divide
+    the pairs and keep those temporaries under `_SCAN_LIVE_BYTES`."""
     m = arrays[0].shape[0]
-    most = max(_SCAN_LIVE_BYTES // (40 * t * d * 4), 1)
+    most = max(_SCAN_LIVE_BYTES // (20 * t * d * 4), 1)
     groups = next(g for g in range(-(-m // most), m + 1) if m % g == 0)
     split = lambda x: x.reshape((groups, m // groups) + x.shape[1:])
     out = jax.lax.map(lambda xs: jax.checkpoint(fn)(*xs),

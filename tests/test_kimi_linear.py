@@ -70,7 +70,7 @@ def _budgets_at_the_tests_sizes(monkeypatch):
         attention, linear_attention, recurrent,
     )
     monkeypatch.setattr(linear_attention, "_SCAN_LIVE_BYTES",
-                        4 * 40 * 128 * 16 * 4)
+                        4 * 20 * 128 * 16 * 4)
     monkeypatch.setattr(attention, "_DISPATCH_LIVE_BYTES",
                         64 * 2 * (2 * 32 + 2 * 24) * 4)
     monkeypatch.setattr(recurrent, "_LOSS_LIVE_BYTES", 64 * 96 * 8)

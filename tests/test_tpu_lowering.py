@@ -198,21 +198,32 @@ class TestFlashKernelCompiles:
             *args).compile().as_text()
         assert "ragged-dot" in hlo and "tpu_custom_call" in hlo
 
-    def test_kda_chunked_scan_compiles_with_its_backward(self, v5e):
+    @pytest.mark.parametrize("t,d,kernels", [(8192, 128, True),
+                                             (512, 8, False)])
+    def test_kda_chunked_scan_compiles_with_its_backward(self, v5e, t, d,
+                                                         kernels,
+                                                         monkeypatch):
         # one group of the cell's (sequence, head) pairs: 8 pairs, 8,192
-        # positions in chunks of 64, d_k = d_v = 128, bf16 products
+        # positions in chunks of 64, d_k = d_v = 128, bf16 products: the
+        # chunk algebra is the two Pallas kernels, the scan over chunks a
+        # loop. At a width the tiling does not take (8), neither kernel.
         from deeplearning4j_tpu.nn.layers.linear_attention import kda_chunked
+        from deeplearning4j_tpu.ops import kda_chunk
+        monkeypatch.setattr(kda_chunk, "is_tpu_backend", lambda: True)
 
         def loss(q, k, v, log_a, beta):
             o, s = kda_chunked(q, k, v, log_a, beta, chunk=64,
                                mm_dtype=jnp.bfloat16)
             return jnp.sum(o ** 2) + jnp.sum(s ** 2)
 
-        wide = ((1, 8192, 8, 128), jnp.float32)
+        wide = ((1, t, 8, d), jnp.float32)
         hlo = _compile_v5e(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
                            self._one(v5e), wide, wide, wide, wide,
-                           ((1, 8192, 8), jnp.float32))
-        assert "triangular" in hlo.lower() or "while" in hlo
+                           ((1, t, 8), jnp.float32))
+        assert "while" in hlo
+        assert ("tpu_custom_call" in hlo) == kernels
+        for kernel in ("kda_chunk_fwd", "kda_chunk_bwd"):
+            assert (kernel in hlo) == kernels
 
     def test_masked_padded_f32_with_lse(self, v5e):
         # t=200: the pad path; masked non-causal with the lse output and

@@ -70,7 +70,7 @@ def host_cast(a, dtype):
     """Cast a float32 host array to a 16-bit compute dtype BEFORE the
     device transfer: ml_dtypes' round-to-nearest-even matches XLA's device
     cast bit-for-bit, and the H2D copy ships half the bytes (the single
-    shared implementation of the rule — nn/multilayer._as_jnp and the
+    shared implementation of the rule — nn/fit_loop._as_jnp and the
     prefetch workers both route through here). DL4J_TPU_HOST_CAST=0
     restores the transfer-then-cast path."""
     if (dtype is not None and isinstance(a, np.ndarray)

@@ -27,19 +27,15 @@ from deeplearning4j_tpu.nn.conf.base import (
 )
 from deeplearning4j_tpu.nn.conf.graph_vertices import GraphVertexConf
 from deeplearning4j_tpu.nn.conf.network import ComputationGraphConfiguration
-from deeplearning4j_tpu.nn.multilayer import (
-    _as_jnp, _default_scan_steps, _record_iteration, _required_kind,
-    _run_scan_pipeline, _scan_incompatible_listeners,
+from deeplearning4j_tpu.nn import fit_loop
+from deeplearning4j_tpu.nn.fit_loop import (
+    _as_jnp, _fit_tbptt_batch, _stage_with_affine,
 )
-from deeplearning4j_tpu.nn.updaters import NoOp, apply_update, build_optimizer
+from deeplearning4j_tpu.nn.multilayer import _required_kind
+from deeplearning4j_tpu.nn.updaters import NoOp, build_optimizer
 from deeplearning4j_tpu.util import params as param_util
 
 log = logging.getLogger("deeplearning4j_tpu")
-
-
-def _mds_examples(mds) -> int:
-    """Rows of one MultiDataSet batch (the `examples=` of a train/chunk)."""
-    return int(np.shape(mds.features[0])[0])
 
 
 class ComputationGraph:
@@ -57,36 +53,16 @@ class ComputationGraph:
         self._topo = conf.topological_order()
         self._vertex_types: Optional[Dict[str, InputType]] = None
         self._tx = None
-        self._train_step = None
-        self._scan_step: Dict[Any, Any] = {}
+        self._steps: Dict[Any, Any] = {}   # compiled train steps (nn/fit_loop)
         self._output_fn = None
         self._input_affine = None   # (shift, scale) during device-norm fit
         self._affine_fn = None
         self._ledger_cache: Dict[Any, Any] = {}   # monitor.xla programs
         self._plan = None           # active GSPMD ShardingPlan (parallel/plan)
 
-    def _engage_plan(self, plan):
-        """Activate a GSPMD ShardingPlan for this graph's compiled steps
-        (the shared MultiLayerNetwork._engage_plan_impl contract)."""
-        from deeplearning4j_tpu.nn.multilayer import _engage_plan_impl
-        _engage_plan_impl(self, plan)
-
-    def _shard_tuple(self, t, stacked: bool = False):
-        """Place one tuple of staged batch operands (graph inputs/labels/
-        masks) per the active plan; identity without one."""
-        plan = self._plan
-        if plan is None or t is None:
-            return t
-        return tuple(None if a is None else plan.shard_batch(a, stacked=stacked)
-                     for a in t)
-
     def set_listeners(self, *listeners):
         self.listeners = list(listeners)
         return self
-
-    def _stage_x(self, a):
-        from deeplearning4j_tpu.nn.multilayer import _stage_with_affine
-        return _stage_with_affine(self, a)
 
     # ----------------------------------------------------------- init/types
     def _resolve_types(self) -> Dict[str, InputType]:
@@ -172,8 +148,7 @@ class ComputationGraph:
         else:
             self._tx = transforms["__global__"]
         self.opt_state = self._tx.init(self.params)
-        self._train_step = None
-        self._scan_step = {}
+        self._steps = {}
 
     # -------------------------------------------------------------- forward
     def _cast_params(self, params):
@@ -358,38 +333,9 @@ class ComputationGraph:
                 total = total + vd.vertex.regularization_score(p)
         return total, (new_state, new_carries)
 
-    def _make_train_step(self):
-        from deeplearning4j_tpu.nn.regularization import (
-            apply_constraints, constraint_map, has_constraints,
-        )
-        tx = self._tx
-        layer_map = constraint_map(self)
-        constrained = has_constraints(layer_map.values())
-        plan = self._plan   # GSPMD plan: sharding constraints in-jit
-
-        def step(params, opt_state, state, inputs, labels, fmasks, lmasks,
-                 rng, carries):
-            def loss_fn(p):
-                return self._score_fn(p, state, inputs, labels, fmasks,
-                                      lmasks, True, rng, carries=carries)
-            (loss, (new_state, new_carries)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            if plan is not None:
-                # pin grads to the ZeRO/TP compute layout: the single
-                # hint from which XLA derives reduce-scatter -> sharded
-                # update -> all-gather (parallel/plan.py)
-                grads = plan.constrain_grads(grads)
-            new_params, new_opt, _ = apply_update(
-                tx, grads, opt_state, params, plan)
-            if constrained:     # post-update projection (DL4J applyConstraints)
-                new_params = apply_constraints(layer_map, new_params)
-            if plan is not None:
-                new_params = plan.constrain_params(new_params)
-                new_opt = plan.constrain_opt(new_opt, new_params)
-                new_state = plan.constrain_replicated(new_state)
-            return new_params, new_opt, new_state, loss, new_carries
-
-        return jax.jit(step, donate_argnums=(0, 1, 2))
+    def _make_scan_step(self):
+        """The scan-of-K compiled step fit() runs (nn/fit_loop.py)."""
+        return fit_loop.build_step(self, "kstep")
 
     def fit(self, data, epochs: int = 1, scan_steps: Optional[int] = None,
             accumulate_steps: int = 1, plan=None):
@@ -399,97 +345,31 @@ class ComputationGraph:
         scan_steps > 1 fuses that many optimizer steps into one jit via
         lax.scan with a one-chunk-deferred loss fetch (input-pipelined fit;
         see MultiLayerNetwork.fit) — bit-identical math/RNG to the per-call
-        path. Default: 10 on TPU, 1 on CPU (measured, PERF.md);
+        path. Default: 10 on TPU, 1 on CPU (see MultiLayerNetwork.fit);
         $DL4J_TPU_SCAN_STEPS overrides.
 
         accumulate_steps > 1: gradient accumulation — K micro-batch
         gradients averaged into ONE optimizer step inside one jit (see
         MultiLayerNetwork.fit; mutually exclusive with scan_steps > 1,
         not applicable to tbptt)."""
-        if self.params is None:
-            self.init()
-        # donated-buffer safety: see util/params.owned_leaf (params from a
-        # checkpoint or import may alias numpy memory the donating step
-        # would otherwise free); under a GSPMD plan the laundered copies
-        # additionally land on the plan placements (docs/PARALLELISM.md)
-        from deeplearning4j_tpu.parallel.plan import active_plan
-        if plan is None:
-            plan = active_plan()
-        if plan is None and self._plan is None:
-            # deliberately inlined (mirrors _engage_plan_impl's no-plan
-            # branch): the donated-aliasing lint contract requires the
-            # own_tree laundering to live IN the module that builds the
-            # donating steps, not only behind the shared impl — keep in
-            # sync with nn/multilayer._engage_plan_impl
-            self.params = param_util.own_tree(self.params)
-            self.state = param_util.own_tree(self.state)
-            self.opt_state = param_util.own_tree(self.opt_state)
-        else:
-            self._engage_plan(plan)
-        if self._train_step is None:
-            self._train_step = self._make_train_step()
-        if accumulate_steps > 1:
-            if self.conf.backprop_type == "tbptt":
-                raise ValueError("accumulate_steps does not apply to "
-                                 "tbptt (chunked-time) training")
-            if scan_steps is not None and scan_steps > 1:
-                raise ValueError("accumulate_steps and scan_steps are "
-                                 "mutually exclusive (one fuses K "
-                                 "optimizer steps, the other folds K "
-                                 "micro-batches into one step)")
-            scan_steps = 1
-        if scan_steps is None:
-            scan_steps = _default_scan_steps()
-        rng = jax.random.PRNGKey(self.conf.seed + 331 * (self.epoch_count + 1))
-        tbptt = self.conf.backprop_type == "tbptt"
-        # device-side normalization (data/normalization.py
-        # engaged_device_affine; see MultiLayerNetwork.fit): the affine
-        # pre-processor is applied on device, raw (uint8) features ship
-        # over the link
-        from deeplearning4j_tpu.data.normalization import (
-            engaged_device_affine)
-        with engaged_device_affine(data, self.listeners) as aff:
-            if aff is not None:
-                self._input_affine = (jnp.asarray(aff[0]),
-                                      jnp.asarray(aff[1]))
-            copy_marked = []
-            if not tbptt and (accumulate_steps > 1 or (
-                    scan_steps > 1
-                    and not _scan_incompatible_listeners(self.listeners))):
-                # the stacking fits hold K live batches before one
-                # transfer — shared-memory ring sources must yield copies
-                # (data/pipeline.mark_copy_for_stacking)
-                from deeplearning4j_tpu.data.pipeline import (
-                    mark_copy_for_stacking)
-                copy_marked = mark_copy_for_stacking(data)
-            from deeplearning4j_tpu.monitor import goodput
-            gp_session = goodput.fit_begin("graph/fit")
-            self._fit_chunk = 0     # train/chunk numbers run over epochs
-            try:
-                from deeplearning4j_tpu import monitor
-                for _ in range(epochs):
-                    for lst in self.listeners:
-                        lst.on_epoch_start(self, self.epoch_count)
-                    with monitor.span("train/epoch",
-                                      epoch=self.epoch_count):
-                        if not tbptt and accumulate_steps > 1:
-                            rng = self._fit_epoch_accum(data, rng,
-                                                        accumulate_steps)
-                        elif not tbptt and scan_steps > 1:
-                            rng = self._fit_epoch_scan(data, rng, scan_steps)
-                        else:
-                            rng = self._fit_epoch_per_call(data, rng, tbptt)
-                    for lst in self.listeners:
-                        lst.on_epoch_end(self, self.epoch_count)
-                    self.epoch_count += 1
-                    if hasattr(data, "reset"):
-                        data.reset()
-            finally:
-                goodput.fit_end(gp_session)
-                self._input_affine = None
-                for it_ in copy_marked:
-                    it_._copy = False
-        return self
+        return fit_loop.fit(self, data, epochs, scan_steps,
+                            accumulate_steps, plan)
+
+    # ---------------------------------------- what nn/fit_loop.py asks for
+    _LEDGER_PREFIX = "graph"
+    # the RNG stream: keyed once a fit() and carried over its epochs
+    _RNG_MULT, _RNG_MULT_TBPTT, _RNG_PER_EPOCH = 331, 331, False
+
+    def _fit_source(self, data, stacking):
+        return data
+
+    def _epoch_batches(self, data, stacking):
+        """The source policy of fit(): what it is given, batch by batch;
+        behind the prefetch thread on the per-call path only (stage()
+        of a chunk stacks K host batches into ONE transfer; the prefetch
+        stream's per-batch device_put would round-trip each through the
+        host)."""
+        return self._iter_data(data) if stacking else self._mds_stream(data)
 
     def _mds_stream(self, data):
         """MultiDataSet stream for one epoch: a prefetch worker thread
@@ -537,126 +417,29 @@ class ComputationGraph:
 
         return prefetch_iterable(self._iter_data(data), stage)
 
-    def _fit_epoch_per_call(self, data, rng, tbptt):
-        from deeplearning4j_tpu import monitor
-        from deeplearning4j_tpu.monitor import goodput
-        from deeplearning4j_tpu.monitor import xla as xla_ledger
-        etl_start = time.perf_counter()
-        for mds in self._mds_stream(data):
-            step_start = time.perf_counter()
-            etl_ms = (step_start - etl_start) * 1e3
-            monitor.add_span("train/etl", etl_start, step_start,
-                             iteration=self.iteration_count)
-            inputs = self._shard_tuple(
-                tuple(self._stage_x(f) for f in mds.features))
-            labels = self._shard_tuple(
-                tuple(_as_jnp(l, self._compute_dtype) for l in mds.labels))
-            fmasks = self._shard_tuple(
-                None if mds.features_masks is None else tuple(
-                    _as_jnp(m) for m in mds.features_masks))
-            lmasks = self._shard_tuple(
-                None if mds.labels_masks is None else tuple(
-                    _as_jnp(m) for m in mds.labels_masks))
-            bs = int(np.shape(mds.features[0])[0])
-            if tbptt:
-                rng = self._fit_tbptt_batch(inputs, labels, fmasks,
-                                            lmasks, rng, etl_ms, bs)
-            else:
-                rng, sub = jax.random.split(rng)
-                (self.params, self.opt_state, self.state, loss,
-                 _) = self._train_step(
-                    self.params, self.opt_state, self.state, inputs,
-                    labels, fmasks, lmasks, sub, None)
-                sync_start = time.perf_counter()
-                # block for device completion FIRST (goodput:
-                # step_compute; banks per-shard barrier wait under a
-                # plan), so the host_sync span below covers only the
-                # narrow D2H fetch
-                goodput.device_wait(loss)
-                fetch_start = time.perf_counter()
-                monitor.add_span("train/device_wait", sync_start,
-                                 fetch_start)
-                # graftlint: disable=host-sync-in-hot-path -- the step's ONE budgeted loss fetch (the deliberate per-iteration sync; PERF.md) — bracketed by the train/host_sync span
-                self._score = float(loss)
-                step_end = time.perf_counter()
-                monitor.add_span("train/host_sync", fetch_start, step_end)
-                monitor.add_span("train/step", step_start, step_end,
-                                 iteration=self.iteration_count,
-                                 score=self._score, batch_size=bs)
-                if xla_ledger.enabled():
-                    key = (id(self._train_step), xla_ledger.shape_key(
-                        (inputs, labels, fmasks, lmasks)))
-                    fresh = key not in self._ledger_cache
-                    rec = xla_ledger.capture_cached(
-                        self._ledger_cache, key,
-                        "graph/train_step", self._train_step,
-                        (self.params, self.opt_state, self.state, inputs,
-                         labels, fmasks, lmasks, sub, None),
-                        examples_per_call=bs)
-                    if not fresh:
-                        # debut wall time includes the jit compile —
-                        # only steady-state steps feed the MFU gauge
-                        xla_ledger.observe_step(rec,
-                                                step_end - step_start)
-                _record_iteration(self._score, bs,
-                                  step_seconds=step_end - step_start,
-                                  sync_seconds=step_end - fetch_start)
-                for lst in self.listeners:
-                    lst.iteration_done(self, self.iteration_count,
-                                       self.epoch_count, self._score,
-                                       etl_ms, bs)
-                self.iteration_count += 1
-            etl_start = time.perf_counter()
-        return rng
-
-    def _make_scan_step(self):
-        from deeplearning4j_tpu.nn.regularization import (
-            apply_constraints, constraint_map, has_constraints,
-        )
-        tx = self._tx
-        layer_map = constraint_map(self)
-        constrained = has_constraints(layer_map.values())
-
-        plan = self._plan   # GSPMD plan: sharding constraints in-jit
-
-        def kstep(params, opt_state, state, inputs, labels, fmasks, lmasks,
-                  subs):
-            def body(carry, batch):
-                params, opt_state, state = carry
-                cin, clab, cfm, clm, sub = batch
-                def loss_fn(p):
-                    return self._score_fn(p, state, cin, clab, cfm, clm,
-                                          True, sub, carries=None)
-                (loss, (new_state, _)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params)
-                if plan is not None:
-                    grads = plan.constrain_grads(grads)
-                new_params, new_opt, _ = apply_update(
-                    tx, grads, opt_state, params, plan)
-                if constrained:
-                    new_params = apply_constraints(layer_map, new_params)
-                if plan is not None:
-                    new_params = plan.constrain_params(new_params)
-                    new_opt = plan.constrain_opt(new_opt, new_params)
-                    new_state = plan.constrain_replicated(new_state)
-                return (new_params, new_opt, new_state), loss
-
-            (params, opt_state, state), losses = jax.lax.scan(
-                body, (params, opt_state, state),
-                (inputs, labels, fmasks, lmasks, subs))
-            return params, opt_state, state, losses
-
-        return jax.jit(kstep, donate_argnums=(0, 1, 2))
+    def _shard_tuple(self, t, stacked: bool = False):
+        """Place one tuple of staged batch operands (graph inputs/labels/
+        masks) per the active plan; identity without one."""
+        plan = self._plan
+        if plan is None or t is None:
+            return t
+        return tuple(None if a is None else plan.shard_batch(a, stacked=stacked)
+                     for a in t)
 
     def _mds_to_dev(self, mds):
         """MultiDataSet -> device operand tuples; the ONE staging rule
         the per-call, scan and accumulation fit paths share."""
-        return (tuple(self._stage_x(f) for f in mds.features),
+        return (tuple(_stage_with_affine(self, f) for f in mds.features),
                 tuple(_as_jnp(l, self._compute_dtype) for l in mds.labels),
                 None if mds.features_masks is None else tuple(
                     _as_jnp(m) for m in mds.features_masks),
                 None if mds.labels_masks is None else tuple(
                     _as_jnp(m) for m in mds.labels_masks))
+
+    def _operands(self, mds):
+        """One MultiDataSet -> (inputs, labels, fmasks, lmasks) on the
+        device, per the active plan."""
+        return tuple(self._shard_tuple(t) for t in self._mds_to_dev(mds))
 
     def _stage_stacked(self, group):
         """K same-shape MultiDataSets -> (inputs, labels, fmasks, lmasks)
@@ -667,236 +450,36 @@ class ComputationGraph:
         return tuple(self._shard_tuple(t, stacked=True) for t in stacked)
 
     @staticmethod
-    def _mds_sig(mds):
+    def _batch_sig(mds):
         shapes = lambda t: None if t is None else tuple(
             np.shape(a) for a in t)
         return (shapes(mds.features), shapes(mds.labels),
                 shapes(mds.features_masks), shapes(mds.labels_masks))
 
-    def _make_accum_step(self):
-        """K micro-batch gradients averaged into ONE optimizer step (see
-        MultiLayerNetwork._make_accum_step)."""
-        from deeplearning4j_tpu.nn.regularization import (
-            apply_constraints, constraint_map, has_constraints,
-        )
-        tx = self._tx
-        layer_map = constraint_map(self)
-        constrained = has_constraints(layer_map.values())
+    @staticmethod
+    def _batch_examples(mds) -> int:
+        """Rows of one MultiDataSet batch (the `examples=` of a
+        train/chunk)."""
+        return int(np.shape(mds.features[0])[0])
 
-        plan = self._plan   # GSPMD plan: sharding constraints in-jit
-
-        def kaccum(params, opt_state, state, inputs, labels, fmasks,
-                   lmasks, subs):
-            k = subs.shape[0]
-
-            def body(carry, batch):
-                gsum, state = carry
-                cin, clab, cfm, clm, sub = batch
-                def loss_fn(p):
-                    return self._score_fn(p, state, cin, clab, cfm, clm,
-                                          True, sub, carries=None)
-                (loss, (new_state, _)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params)
-                gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
-                if plan is not None:
-                    # the accumulator carries in the ZeRO layout: micro-
-                    # batch grads reduce-scatter into it instead of ever
-                    # materializing whole per chip
-                    gsum = plan.constrain_grads(gsum)
-                return (gsum, new_state), loss
-
-            zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-            (gsum, state), losses = jax.lax.scan(
-                body, (zeros, state), (inputs, labels, fmasks, lmasks,
-                                       subs))
-            grads = jax.tree_util.tree_map(lambda g: g / k, gsum)
-            new_params, new_opt, _ = apply_update(
-                tx, grads, opt_state, params, plan)
-            if constrained:
-                new_params = apply_constraints(layer_map, new_params)
-            if plan is not None:
-                new_params = plan.constrain_params(new_params)
-                new_opt = plan.constrain_opt(new_opt, new_params)
-                state = plan.constrain_replicated(state)
-            return new_params, new_opt, state, jnp.mean(losses)
-
-        return jax.jit(kaccum, donate_argnums=(0, 1, 2))
-
-    def _fit_epoch_accum(self, data, rng, K):
-        """One optimizer step per K stacked micro-batches; chunking and
-        ragged-tail handling as in _fit_epoch_scan, lockstep listener
-        callbacks when a model-reading listener is attached."""
-        from deeplearning4j_tpu.monitor import xla as xla_ledger
-        last_sync = [None]
-
-        def fetch(p):
-            return float(p[0])      # the chunk's one blocking fetch
-
-        def notify(p, score):
-            _, bs, etl_ms, rec = p
-            self._score = score
-            if xla_ledger.enabled():
-                now = time.perf_counter()
-                if rec is not None and last_sync[0] is not None:
-                    xla_ledger.observe_step(rec, now - last_sync[0])
-                last_sync[0] = now
-            _record_iteration(self._score, bs)
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration_count,
-                                   self.epoch_count, self._score, etl_ms,
-                                   bs)
-            self.iteration_count += 1
-            return 1
-
-        def stage(group):
-            nonlocal rng
-            subs = []
-            for _ in group:
-                rng, sub = jax.random.split(rng)
-                subs.append(sub)
-            inputs, labels, fmasks, lmasks = self._stage_stacked(group)
-            bs = _mds_examples(group[0]) * len(group)
-            return (inputs, labels, fmasks, lmasks, jnp.stack(subs), bs,
-                    len(group))
-
-        def launch(staged, etl_ms):
-            inputs, labels, fmasks, lmasks, subs_d, bs, n = staged
-            sig = ("accum", fmasks is not None, lmasks is not None)
-            if sig not in self._scan_step:
-                self._scan_step[sig] = self._make_accum_step()
-            kstep = self._scan_step[sig]
-            (self.params, self.opt_state, self.state,
-             loss) = kstep(
-                self.params, self.opt_state, self.state, inputs, labels,
-                fmasks, lmasks, subs_d)
-            rec = None
-            if xla_ledger.enabled():
-                key = (id(kstep), xla_ledger.shape_key(
-                    (inputs, labels, fmasks, lmasks)))
-                fresh = key not in self._ledger_cache
-                rec = xla_ledger.capture_cached(
-                    self._ledger_cache, key,
-                    "graph/accum_step", kstep,
-                    (self.params, self.opt_state, self.state, inputs,
-                     labels, fmasks, lmasks, subs_d),
-                    examples_per_call=bs, steps_per_call=n)
-                if fresh:
-                    last_sync[0] = None   # exclude the AOT compile interval
-            return (loss, bs, etl_ms, rec)
-
-        # _iter_data, not _mds_stream: stage() stacks K host batches
-        # into ONE transfer; the prefetch stream's per-batch device_put
-        # would round-trip each micro-batch through the host (same rule
-        # as _fit_epoch_scan)
-        self._fit_chunk = _run_scan_pipeline(
-            self._iter_data(data), K, sig_of=self._mds_sig,
-            examples_of=_mds_examples, stage=stage, launch=launch,
-            fetch=fetch, notify=notify,
-            defer=not _scan_incompatible_listeners(self.listeners),
-            first_chunk=self._fit_chunk)
+    def _fit_epoch_tbptt(self, batches, rng):
+        """Truncated BPTT: chunk the time axis of every staged sequence
+        input/label/mask of a batch (ComputationGraph.java:2894
+        doTruncatedBPTT); the chunk loop is nn/fit_loop's."""
+        from deeplearning4j_tpu import monitor
+        etl_start = time.perf_counter()
+        for mds in batches:
+            step_start = time.perf_counter()
+            monitor.add_span("train/etl", etl_start, step_start,
+                             iteration=self.iteration_count)
+            rng = _fit_tbptt_batch(
+                self, self._tbptt_chunks(*self._operands(mds)), rng,
+                (step_start - etl_start) * 1e3, self._batch_examples(mds))
+            etl_start = time.perf_counter()
         return rng
 
-    def _fit_epoch_scan(self, data, rng, K):
-        """Input-pipelined epoch over MultiDataSets: consecutive same-shape
-        batches are stacked and run as one scan-of-K jit; the loss fetch is
-        deferred one chunk so host stacking overlaps device compute. Ragged
-        tails fall back to the per-call step."""
-        if _scan_incompatible_listeners(self.listeners):
-            return self._fit_epoch_per_call(data, rng, False)
-        from deeplearning4j_tpu.monitor import xla as xla_ledger
-        last_sync = [None]
-
-        def fetch(p):
-            return np.asarray(p[0])             # single blocking fetch/chunk
-
-        def notify(p, arr):
-            _, bs, etl_ms, rec = p
-            if xla_ledger.enabled():
-                # steady-state chunk wall = spacing between chunk syncs;
-                # the stamp advances on EVERY chunk so a ragged tail
-                # can't leak into the next interval (see
-                # MultiLayerNetwork._fit_epoch_scan)
-                now = time.perf_counter()
-                if rec is not None and last_sync[0] is not None:
-                    xla_ledger.observe_step(rec, now - last_sync[0])
-                last_sync[0] = now
-            for loss in arr:
-                # graftlint: disable=host-sync-in-hot-path -- chunk losses are already host-resident (fetch() above IS the deferred chunk sync); this is per-iteration bookkeeping
-                self._score = float(loss)
-                _record_iteration(self._score, bs)
-                for lst in self.listeners:
-                    lst.iteration_done(self, self.iteration_count,
-                                       self.epoch_count, self._score,
-                                       etl_ms, bs)
-                self.iteration_count += 1
-                etl_ms = 0.0
-            return len(arr)
-
-        def stage(group):
-            nonlocal rng
-            subs = []
-            for _ in group:
-                rng, sub = jax.random.split(rng)
-                subs.append(sub)
-            bs = _mds_examples(group[0])
-            if len(group) < K:
-                # ragged tail / shape-change remainder: staged batch by
-                # batch for the compiled per-call step instead of a
-                # one-off scan-of-len(group)
-                return ([tuple(self._shard_tuple(t)
-                               for t in self._mds_to_dev(m))
-                         for m in group], subs, bs, True)
-            return self._stage_stacked(group), jnp.stack(subs), bs, False
-
-        def launch(staged, etl_ms):
-            parts, subs, bs, tail = staged
-            if tail:
-                losses = []
-                for (inputs, labels, fmasks, lmasks), sub in zip(parts,
-                                                                 subs):
-                    (self.params, self.opt_state, self.state, loss,
-                     _) = self._train_step(
-                        self.params, self.opt_state, self.state, inputs,
-                        labels, fmasks, lmasks, sub, None)
-                    losses.append(loss)
-                return (jnp.stack(losses), bs, etl_ms, None)
-            inputs, labels, fmasks, lmasks = parts
-            n = int(subs.shape[0])
-            sig = (n, fmasks is not None, lmasks is not None)
-            if sig not in self._scan_step:
-                self._scan_step[sig] = self._make_scan_step()
-            kstep = self._scan_step[sig]
-            (self.params, self.opt_state, self.state,
-             losses) = kstep(
-                self.params, self.opt_state, self.state, inputs, labels,
-                fmasks, lmasks, subs)
-            rec = None
-            if xla_ledger.enabled():
-                key = (id(kstep), xla_ledger.shape_key(
-                    (inputs, labels, fmasks, lmasks)))
-                fresh = key not in self._ledger_cache
-                rec = xla_ledger.capture_cached(
-                    self._ledger_cache, key,
-                    "graph/scan_step", kstep,
-                    (self.params, self.opt_state, self.state, inputs,
-                     labels, fmasks, lmasks, subs),
-                    examples_per_call=bs * n, steps_per_call=n)
-                if fresh:
-                    last_sync[0] = None   # exclude the AOT compile interval
-            return (losses, bs, etl_ms, rec)
-
-        self._fit_chunk = _run_scan_pipeline(
-            self._iter_data(data), K, sig_of=self._mds_sig,
-            examples_of=_mds_examples, stage=stage, launch=launch,
-            fetch=fetch, notify=notify, first_chunk=self._fit_chunk)
-        return rng
-
-    def _fit_tbptt_batch(self, inputs, labels, fmasks, lmasks, rng, etl_ms,
-                         bs):
-        """Truncated BPTT over one batch: chunk the time axis of every
-        sequence input/label/mask, carry RNN state across chunks with
-        stop_gradient at the boundaries (ComputationGraph.java:2894
-        doTruncatedBPTT)."""
+    def _tbptt_chunks(self, inputs, labels, fmasks, lmasks):
+        """The time slices of one staged batch, tbptt_fwd_length long."""
         fwd = self.conf.tbptt_fwd_length
         in_types = [self._vertex_types[n] for n in self.conf.network_inputs]
         seq_lengths = [f.shape[1] for t, f in zip(in_types, inputs)
@@ -923,31 +506,15 @@ class ComputationGraph:
                 return arr[:, t0:t1]
             return arr
 
-        carries = {}
         for t0 in range(0, T, fwd):
             t1 = min(t0 + fwd, T)
-            cin = tuple(slice_t(f, t0, t1) for f in inputs)
-            clab = tuple(slice_t(l, t0, t1) for l in labels)
-            cfm = None if fmasks is None else tuple(
-                slice_t(m, t0, t1, is_mask=True) for m in fmasks)
-            clm = None if lmasks is None else tuple(
-                slice_t(m, t0, t1, is_mask=True) for m in lmasks)
-            rng, sub = jax.random.split(rng)
-            (self.params, self.opt_state, self.state, loss,
-             new_carries) = self._train_step(
-                self.params, self.opt_state, self.state, cin, clab, cfm,
-                clm, sub, carries)
-            carries = jax.tree_util.tree_map(jax.lax.stop_gradient,
-                                             new_carries)
-            # graftlint: disable=host-sync-in-hot-path -- the tbptt chunk's one budgeted loss fetch
-            self._score = float(loss)
-            _record_iteration(self._score, bs)
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration_count,
-                                   self.epoch_count, self._score, etl_ms, bs)
-            self.iteration_count += 1
-            etl_ms = 0.0
-        return rng
+            yield (tuple(slice_t(f, t0, t1) for f in inputs),
+                   tuple(slice_t(l, t0, t1) for l in labels),
+                   None if fmasks is None else tuple(
+                       slice_t(m, t0, t1, is_mask=True) for m in fmasks),
+                   None if lmasks is None else tuple(
+                       slice_t(m, t0, t1, is_mask=True) for m in lmasks))
+
 
     def _iter_data(self, data):
         if isinstance(data, (tuple, list)) and len(data) == 2 \

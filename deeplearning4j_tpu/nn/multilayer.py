@@ -20,7 +20,6 @@ DL4J's flattenedParams single buffer (:114,603-627).
 from __future__ import annotations
 
 import logging
-import time
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -28,19 +27,19 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from deeplearning4j_tpu.data.async_iterator import (
-    AsyncDataSetIterator, host_cast,
-)
+from deeplearning4j_tpu.data.async_iterator import AsyncDataSetIterator
 from deeplearning4j_tpu.data.dataset import DataSet
 from deeplearning4j_tpu.data.iterator import ArrayDataSetIterator, DataSetIterator
 from deeplearning4j_tpu.nn.conf.base import (
     InputType, Kind, LayerConf, preprocess_forward, preprocessed_type,
 )
+from deeplearning4j_tpu.nn import fit_loop
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
-from deeplearning4j_tpu.nn.updaters import NoOp, apply_update, build_optimizer
+from deeplearning4j_tpu.nn.fit_loop import (
+    _as_jnp, _fit_tbptt_batch, _stage_with_affine,
+)
+from deeplearning4j_tpu.nn.updaters import NoOp, build_optimizer
 from deeplearning4j_tpu.util import params as param_util
-from deeplearning4j_tpu.util.env import env_int
-from deeplearning4j_tpu.util.platform import is_tpu_backend
 
 log = logging.getLogger("deeplearning4j_tpu")
 
@@ -81,134 +80,6 @@ def _is_stateful_recurrent(layer) -> bool:
     return name in _RECURRENT_CLASSES
 
 
-def _scan_incompatible_listeners(listeners) -> bool:
-    """Listeners that inspect the model (params/opt state) or capture
-    gradients need iteration_done in lockstep with the params — the
-    pipelined scan fit delivers it up to 2K-1 steps late, so their
-    presence forces the per-call path."""
-    return any(getattr(lst, "wants_gradients", False)
-               or getattr(lst, "reads_model", False)
-               for lst in listeners)
-
-
-def _record_iteration(score: float, batch_size: int,
-                      step_seconds: Optional[float] = None,
-                      sync_seconds: Optional[float] = None):
-    """One optimizer step's worth of telemetry (monitor/metrics.py) —
-    shared by every fit path of both containers and the resilient
-    trainer, so `train_*` series mean the same thing everywhere. Only
-    host scalars are touched: no device sync is introduced."""
-    from deeplearning4j_tpu import monitor
-    monitor.counter("train_iterations_total",
-                    "Optimizer steps applied").inc()
-    monitor.counter("train_examples_total",
-                    "Training examples consumed").inc(batch_size)
-    monitor.gauge("train_score", "Last training loss/score").set(score)
-    if step_seconds is not None:
-        monitor.histogram("train_step_seconds",
-                          "Train step wall time (dispatch + host sync)"
-                          ).observe(step_seconds)
-    if sync_seconds is not None:
-        monitor.histogram("train_host_sync_seconds",
-                          "Blocking device->host loss fetch per step"
-                          ).observe(sync_seconds)
-
-
-def _ds_examples(ds) -> int:
-    """Rows of one DataSet batch (the `examples=` of a train/chunk)."""
-    return int(np.shape(ds.features)[0])
-
-
-def _run_scan_pipeline(batches, K, *, sig_of, examples_of, stage, launch,
-                       fetch, notify, defer=True, first_chunk=0):
-    """Shared chunking/deferral loop of the input-pipelined fit paths
-    (the `_fit_epoch_scan` / `_fit_epoch_accum` of both containers).
-
-    One turn pulls consecutive batches with identical shape signature
-    `sig_of(b)` into a chunk of at most K, stages and launches it
-    (`stage(group)` -> staged device inputs, `launch(staged, etl_ms)` ->
-    an opaque pending record whose device values are still futures), and
-    only then syncs the chunk launched one turn EARLIER
-    (`fetch(pending)` blocks on its losses, `notify(pending, fetched)`
-    runs the per-step bookkeeping and listeners and returns the number
-    of optimizer steps it reported) — so staging and launching chunk i
-    overlap the device compute of chunk i-1, and the one blocking loss
-    fetch per chunk happens while the device is busy (on a TPU the
-    staging's own enqueues can block first: PERF.md section 5). The last turn
-    pulls nothing and drains. defer=False syncs each chunk in the turn
-    that launched it (model-reading listeners must observe the params
-    as of the step they're told about).
-
-    Every phase is an ENTERED span, so with
-    `enable_tracing(jax_annotations=True)` the whole tree is on the
-    profiler's host plane (docs/OBSERVABILITY.md "Tracing"):
-
-        train/chunk                 chunk=i batches= examples= steps=
-          train/etl                 batches=    (etl/queue_wait inside)
-          train/dispatch            chunk=i
-            train/stage / train/launch
-          train/chunk_sync          chunk=i-1
-            train/loss_fetch / train/listeners steps=
-
-    `chunk` counts from `first_chunk` (the container keeps it running
-    over the epochs of one fit()); returns the next chunk's number."""
-    from deeplearning4j_tpu import monitor
-    span = monitor.span
-    it = iter(batches)
-    chunk = first_chunk
-    held = None          # the batch whose shape change closed the last group
-    pending = None       # (chunk, record) launched and not yet synced
-    exhausted = False
-    while not (exhausted and held is None and pending is None):
-        with span("train/chunk", chunk=chunk) as turn:
-            etl_start = time.perf_counter()
-            with span("train/etl") as etl:
-                group, held = ([] if held is None else [held]), None
-                gsig = sig_of(group[0]) if group else None
-                while len(group) < K and not exhausted:
-                    try:
-                        b = next(it)
-                    except StopIteration:
-                        exhausted = True
-                        break
-                    s = sig_of(b)
-                    if group and s != gsig:
-                        held = b
-                        break
-                    group.append(b)
-                    gsig = s
-                etl.set(batches=len(group))
-            etl_ms = (time.perf_counter() - etl_start) * 1e3
-            fresh = None
-            if group:
-                with span("train/dispatch", chunk=chunk):
-                    with span("train/stage"):
-                        staged = stage(group)
-                    with span("train/launch"):
-                        fresh = (chunk, launch(staged, etl_ms))
-                    # the launched program alone keeps its inputs from
-                    # here: a reference held into the next turn's stage()
-                    # adds a whole chunk of device memory to the peak
-                    # (PERF.md section 6, PR 24)
-                    del staged
-            due, pending = (pending, fresh) if defer else (fresh, None)
-            steps = 0
-            if due is not None:
-                with span("train/chunk_sync", chunk=due[0]):
-                    with span("train/loss_fetch"):
-                        fetched = fetch(due[1])
-                    with span("train/listeners") as told:
-                        steps = notify(due[1], fetched)
-                        told.set(steps=steps)
-            turn.set(batches=len(group), steps=steps,
-                     examples=len(group) * examples_of(group[0])
-                     if group else 0)
-        if not group:
-            break
-        chunk += 1
-    return chunk
-
-
 def _required_kind(layer: LayerConf) -> Optional[Kind]:
     name = type(layer).__name__
     if name == "FrozenLayerWrapper":
@@ -243,103 +114,6 @@ def _layer_call(layer, *, seq, train, remat, params, x, state=None,
             fn, policy=jax.checkpoint_policies.save_only_these_names(
                 "remat_keep"))
     return fn(*args)
-
-
-def _default_scan_steps() -> int:
-    """Production fit() pipelining default, decided from the round-5
-    hardware measurement (PERF.md): on the TPU v5e the scan-of-10 fused
-    step measured +6.5% over per-call (2377 vs 2231 imgs/s, ResNet-50
-    bf16 batch 128) and removes all per-step dispatch; on CPU XLA
-    pessimizes convolutions inside scan (10.9x slower, PERF.md
-    "mechanism check"), so per-call stays the CPU default.
-    DL4J_TPU_SCAN_STEPS overrides either way."""
-    env = env_int("DL4J_TPU_SCAN_STEPS")
-    if env is not None:
-        return env
-    # TPU only — GPU/other backends are unmeasured, and the CPU
-    # mechanism check shows conv-in-scan can regress badly off-TPU
-    return 10 if is_tpu_backend() else 1
-
-
-def _engage_plan_impl(net, plan):
-    """Shared by MultiLayerNetwork/ComputationGraph (and the resilience
-    drivers): activate a GSPMD ShardingPlan for a net's compiled steps —
-    or plain single-device training when None. Either way
-    params/opt/state are laundered into XLA-owned buffers
-    (donated-buffer safety, util/params.owned_leaf); under a plan the
-    laundered copies additionally land on the plan's placements
-    (sharding-aware own_tree), and a plan CHANGE drops the compiled-step
-    caches so the next step re-lowers against the new layout instead of
-    silently running the old one."""
-    prior = net._plan
-    if plan != prior:
-        net._plan = plan
-        net._train_step = None
-        net._scan_step = {}
-        net._output_fn = None
-        # the ledger cache keys on id(step_fn): with the old jitted fns
-        # dropped above, CPython may reuse their ids for the NEW steps —
-        # a stale hit would misattribute the re-compiled (sharded)
-        # program's timings to the old record
-        net._ledger_cache = {}
-    if plan is None:
-        if prior is not None:
-            # leaving a plan: gather mesh-committed leaves back to the
-            # default device FIRST — the owned copy below preserves
-            # committed shardings, and a plain fit stages its batches
-            # single-device (incompatible-devices error otherwise)
-            dev = jax.local_devices()[0]
-            gather = lambda t: jax.tree_util.tree_map(
-                lambda a: jax.device_put(a, dev), t)
-            net.params = gather(net.params)
-            net.state = gather(net.state)
-            net.opt_state = gather(net.opt_state)
-        net.params = param_util.own_tree(net.params)
-        net.state = param_util.own_tree(net.state)
-        net.opt_state = param_util.own_tree(net.opt_state)
-    else:
-        net.params = param_util.own_tree(
-            net.params, plan.param_shardings(net.params))
-        net.state = param_util.own_tree(
-            net.state, plan.replicated_shardings(net.state))
-        net.opt_state = param_util.own_tree(
-            net.opt_state, plan.opt_shardings(net.opt_state, net.params))
-
-
-def _stage_with_affine(net, a):
-    """Features -> device, shared by MultiLayerNetwork._stage_x and
-    ComputationGraph._stage_x. With a device affine engaged (fit through
-    a `device_affine()` pre-processor), RAW features ship over the
-    host->HBM link (uint8 pixels stay uint8: 4x fewer bytes than
-    float32, 2x fewer than the bf16 host cast) and the normalization
-    runs on device in one fused jit; otherwise plain _as_jnp."""
-    if net._input_affine is None:
-        return _as_jnp(a, net._compute_dtype)
-    if net._affine_fn is None:
-        from deeplearning4j_tpu.data.normalization import make_affine_fn
-        net._affine_fn = make_affine_fn(net._compute_dtype)
-    shift, scale = net._input_affine
-    return net._affine_fn(jnp.asarray(a), shift, scale)
-
-
-def _as_jnp(a, dtype=None):
-    if a is None:
-        return None
-    # 16-bit compute dtypes (bfloat16 training): cast float32 host arrays
-    # BEFORE the device transfer (bit-identical to the device cast; f64 is
-    # excluded — its old path double-rounds via f32 with x64 disabled).
-    # Shared rule: data/async_iterator.host_cast (DL4J_TPU_HOST_CAST=0
-    # restores transfer-then-cast).
-    a = host_cast(a, dtype)
-    arr = jnp.asarray(a)
-    # floats cast to the compute dtype; so do raw uint8 image bytes
-    # (ImageRecordReader reference parity) used WITHOUT a normalizer.
-    # Wider int dtypes stay integer — they are embedding/sparse-label
-    # token ids, not pixels.
-    if dtype is not None and (jnp.issubdtype(arr.dtype, jnp.floating)
-                              or arr.dtype == jnp.uint8):
-        arr = arr.astype(dtype)
-    return arr
 
 
 def _masked_eval_pair(labels, preds, labels_mask):
@@ -391,24 +165,17 @@ class MultiLayerNetwork:
         self._compute_dtype = jnp.dtype(conf.compute_dtype or conf.dtype)
         self._input_types: Optional[List[InputType]] = None
         self._tx = None
-        self._train_step = None
-        self._scan_step: Dict[Any, Any] = {}
+        self._steps: Dict[Any, Any] = {}   # compiled train steps (nn/fit_loop)
         self._output_fn = None
         self._input_affine = None   # (shift, scale) during device-norm fit
         self._affine_fn = None
         self._ledger_cache: Dict[Any, Any] = {}   # monitor.xla programs
         self._plan = None           # active GSPMD ShardingPlan (parallel/plan)
 
-    # ------------------------------------------------------------ plumbing
-    def _stage_x(self, a):
-        return _stage_with_affine(self, a)
-
-    def _engage_plan(self, plan):
-        """Activate a GSPMD ShardingPlan (parallel/plan.py) for this
-        net's compiled steps — or plain single-device training when
-        None (the shared `_engage_plan_impl`; also used by
-        ComputationGraph and the ResilientTrainer drivers)."""
-        _engage_plan_impl(self, plan)
+    # ---------------------------------------- what nn/fit_loop.py asks for
+    _LEDGER_PREFIX = "mln"
+    # the RNG stream: re-keyed every epoch, its own multiplier under tBPTT
+    _RNG_MULT, _RNG_MULT_TBPTT, _RNG_PER_EPOCH = 7919, 104729, True
 
     def _shard_batch(self, *arrs, stacked: bool = False):
         """Place staged batch operands per the active plan — dim 0 (dim
@@ -419,6 +186,14 @@ class MultiLayerNetwork:
             return arrs
         return tuple(plan.shard_batch(a, stacked=stacked) for a in arrs)
 
+    def _operands(self, ds):
+        """One DataSet -> (x, y, fmask, lmask) on the device, per the
+        active plan: the ONE staging rule of the per-call steps."""
+        return self._shard_batch(
+            _stage_with_affine(self, ds.features),
+            _as_jnp(ds.labels, self._compute_dtype),
+            _as_jnp(ds.features_mask), _as_jnp(ds.labels_mask))
+
     def _stage_stacked(self, group):
         """K same-shape host batches -> (xs, ys, fms, lms) stacked on a
         new leading axis, on the device, per the active plan: the ONE
@@ -427,12 +202,23 @@ class MultiLayerNetwork:
         stack = lambda get, dt=None: (
             None if get(ds0) is None else
             _as_jnp(np.stack([np.asarray(get(d)) for d in group]), dt))
-        xs = None if ds0.features is None else self._stage_x(
-            np.stack([np.asarray(d.features) for d in group]))
+        xs = None if ds0.features is None else _stage_with_affine(
+            self, np.stack([np.asarray(d.features) for d in group]))
         return self._shard_batch(
             xs, stack(lambda d: d.labels, self._compute_dtype),
             stack(lambda d: d.features_mask),
             stack(lambda d: d.labels_mask), stacked=True)
+
+    @staticmethod
+    def _batch_sig(ds):
+        shape = lambda a: None if a is None else np.shape(a)
+        return (np.shape(ds.features), np.shape(ds.labels),
+                shape(ds.features_mask), shape(ds.labels_mask))
+
+    @staticmethod
+    def _batch_examples(ds) -> int:
+        """Rows of one DataSet batch (the `examples=` of a train/chunk)."""
+        return int(np.shape(ds.features)[0])
 
     def set_listeners(self, *listeners):
         self.listeners = list(listeners)
@@ -506,8 +292,7 @@ class MultiLayerNetwork:
         else:
             self._tx = transforms["__global__"]
         self.opt_state = self._tx.init(self.params)
-        self._train_step = None     # force re-trace
-        self._scan_step = {}
+        self._steps = {}     # force re-trace
 
     # ------------------------------------------------------------- forward
     def _cast_params(self, params):
@@ -643,54 +428,9 @@ class MultiLayerNetwork:
         return acts
 
     # ----------------------------------------------------------------- fit
-    def _make_train_step(self, with_fmask, with_lmask, with_carries,
-                         with_stats=False):
-        from deeplearning4j_tpu.nn.regularization import (
-            apply_constraints, constraint_map, has_constraints,
-        )
-        tx = self._tx
-        constrained = has_constraints(self.layers)
-        layer_map = constraint_map(self)
-        plan = self._plan   # GSPMD plan: sharding constraints in-jit
-
-        def step(params, opt_state, state, x, y, fmask, lmask, rng, carries):
-            def loss_fn(p):
-                return self._score_fn(p, state, x, y, fmask, lmask, True, rng,
-                                      carries=carries)
-            (loss, (new_state, new_carries)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            if plan is not None:
-                # pin grads to the ZeRO/TP compute layout: this single
-                # hint makes XLA derive reduce-scatter -> sharded update
-                # -> all-gather (parallel/plan.py)
-                grads = plan.constrain_grads(grads)
-            new_params, new_opt, updates = apply_update(
-                tx, grads, opt_state, params, plan)
-            if constrained:     # post-update projection (DL4J applyConstraints)
-                new_params = apply_constraints(layer_map, new_params)
-            if plan is not None:
-                new_params = plan.constrain_params(new_params)
-                new_opt = plan.constrain_opt(new_opt, new_params)
-                new_state = plan.constrain_replicated(new_state)
-            if with_stats:
-                # StatsListener capture iterations also return the raw
-                # gradient and update pytrees (DL4J onGradientCalculation /
-                # onBackwardPass hooks); a separate jit variant so the fast
-                # path transfers nothing extra
-                return (new_params, new_opt, new_state, loss, new_carries,
-                        grads, updates)
-            return new_params, new_opt, new_state, loss, new_carries
-
-        return jax.jit(step, donate_argnums=(0, 1, 2))
-
-    def _get_train_step(self, fmask, lmask, carries, with_stats=False):
-        sig = (fmask is not None, lmask is not None, carries is not None,
-               with_stats)
-        if self._train_step is None:
-            self._train_step = {}
-        if sig not in self._train_step:
-            self._train_step[sig] = self._make_train_step(*sig)
-        return self._train_step[sig]
+    def _make_scan_step(self):
+        """The scan-of-K compiled step fit() runs (nn/fit_loop.py)."""
+        return fit_loop.build_step(self, "kstep")
 
     def fit(self, data, epochs: int = 1, batch_size: int = 32,
             scan_steps: Optional[int] = None,
@@ -703,7 +443,7 @@ class MultiLayerNetwork:
         accumulate_steps > 1: gradient accumulation — K micro-batch
         gradients averaged into ONE optimizer step inside one jit, for
         effective batch sizes beyond what HBM fits in a single forward
-        (see _make_accum_step; mutually exclusive with scan_steps > 1,
+        (see nn/fit_loop.build_step; mutually exclusive with scan_steps > 1,
         not applicable to tbptt). Accumulation groups only CONSECUTIVE
         same-shape micro-batches: a shape change (e.g. a non-drop-last
         partial tail) cuts the group short, and the short group takes one
@@ -717,12 +457,14 @@ class MultiLayerNetwork:
         deferred one chunk, so the dispatch pipeline never blocks on a
         device→host sync. The RNG stream, update math and listener calls are
         identical to the per-call path (bit-for-bit, tested) — only the
-        host/device overlap changes. Default: 10 on TPU (measured +6.5%
-        over per-call, PERF.md), 1 on CPU; $DL4J_TPU_SCAN_STEPS overrides.
+        host/device overlap changes. Default: 10 on TPU (the path both
+        cells of the benchmark run: fit_window_rate_ratio 99.5 % and
+        99.7 %, ledger PR 28; scan against per-call has no cell yet,
+        ROADMAP W5), 1 on CPU; $DL4J_TPU_SCAN_STEPS overrides.
 
-        Intended for dispatch-bound TPU loops. Caveat (PERF.md "mechanism
-        check"): XLA:CPU pessimizes convolutions inside scan, so conv nets
-        on CPU should keep scan_steps=1.
+        Intended for dispatch-bound TPU loops. Caveat: XLA:CPU pessimizes
+        convolutions inside scan, so conv nets on CPU should keep
+        scan_steps=1.
 
         `prefetch` (default on, kill switches DL4J_TPU_FIT_PREFETCH=0 /
         DL4J_TPU_PREFETCH_DEPTH=0): wrap plain sources in
@@ -743,108 +485,9 @@ class MultiLayerNetwork:
         runs SPMD over the plan's ("data", "model") mesh with DP
         all-reduce, tensor-parallel matmuls, and ZeRO reduce-scatter/
         all-gather as jit-inserted collectives. See docs/PARALLELISM.md."""
-        if self.params is None:
-            self.init()
-        # donated-buffer safety: params from ANY host source (checkpoint,
-        # keras/dl4j import, set_params_flat) may alias numpy memory that
-        # the donating train step must not free (util/params.owned_leaf);
-        # under a plan the laundered copies land on the plan placements
-        from deeplearning4j_tpu.parallel.plan import active_plan
-        if plan is None:
-            plan = active_plan()
-        self._engage_plan(plan)
-        if accumulate_steps > 1:
-            if self.conf.backprop_type == "tbptt":
-                raise ValueError("accumulate_steps does not apply to "
-                                 "tbptt (chunked-time) training")
-            if scan_steps is not None and scan_steps > 1:
-                raise ValueError("accumulate_steps and scan_steps are "
-                                 "mutually exclusive (one fuses K "
-                                 "optimizer steps, the other folds K "
-                                 "micro-batches into one step)")
-            scan_steps = 1
-        if scan_steps is None:
-            scan_steps = _default_scan_steps()
-        iterator = self._as_iterator(data, batch_size)
-        if prefetch is None:
-            from deeplearning4j_tpu.data.async_iterator import (
-                fit_prefetch_enabled,
-            )
-            prefetch = fit_prefetch_enabled()
-        # device-side normalization (data/normalization.py
-        # engaged_device_affine — env gate, listener gate, detach/restore,
-        # feature-cast pause): an affine-representable pre-processor is
-        # applied on device instead of host (_stage_x), so raw uint8
-        # pixels ship over the link. Engaged BEFORE the async wrap so
-        # the wrap skips the 16-bit FEATURE host cast — normalize-then-
-        # cast preserves the f32 signal a premature bf16 cast would
-        # quantize away (labels still ship 16-bit).
-        from deeplearning4j_tpu.data.normalization import (
-            engaged_device_affine)
-        with engaged_device_affine(iterator, self.listeners) as aff:
-            if aff is not None:
-                self._input_affine = (jnp.asarray(aff[0]),
-                                      jnp.asarray(aff[1]))
-            # scan-fit and accumulation STACK K host batches before one
-            # transfer — the wrap must not device_put per batch there (a
-            # device array would round-trip back through the host). The
-            # scan path falls back to per-call under model-reading
-            # listeners and tbptt never scans, so match the path that
-            # will actually run.
-            stacking = accumulate_steps > 1 or (
-                scan_steps > 1
-                and self.conf.backprop_type != "tbptt"
-                and not _scan_incompatible_listeners(self.listeners))
-            copy_marked = []
-            if stacking:
-                # stacking holds K live batches before ONE transfer —
-                # shared-memory ring iterators must yield copies for it
-                # (their normal view batches are recycled on the next
-                # pull; data/pipeline.mark_copy_for_stacking)
-                from deeplearning4j_tpu.data.pipeline import (
-                    mark_copy_for_stacking)
-                copy_marked = mark_copy_for_stacking(iterator)
-            if prefetch and not isinstance(iterator, AsyncDataSetIterator) \
-                    and getattr(iterator, "async_supported", True):
-                iterator = AsyncDataSetIterator(
-                    iterator, device_put=not stacking,
-                    # under a plan the worker thread stages straight onto
-                    # the mesh (device arg accepts a Sharding), so the
-                    # double-buffered H2D lands already batch-sharded
-                    device=(self._plan.batch_sharding()
-                            if self._plan is not None else None),
-                    cast_dtype=self._compute_dtype
-                    if np.dtype(self._compute_dtype).itemsize == 2
-                    else None,
-                    cast_features=self._input_affine is None)
-            from deeplearning4j_tpu.monitor import goodput
-            gp_session = goodput.fit_begin("mln/fit")
-            self._fit_chunk = 0     # train/chunk numbers run over epochs
-            try:
-                from deeplearning4j_tpu import monitor
-                for _ in range(epochs):
-                    for lst in self.listeners:
-                        lst.on_epoch_start(self, self.epoch_count)
-                    with monitor.span("train/epoch",
-                                      epoch=self.epoch_count):
-                        if self.conf.backprop_type == "tbptt":
-                            self._fit_epoch_tbptt(iterator)
-                        elif accumulate_steps > 1:
-                            self._fit_epoch_accum(iterator, accumulate_steps)
-                        elif scan_steps > 1:
-                            self._fit_epoch_scan(iterator, scan_steps)
-                        else:
-                            self._fit_epoch(iterator)
-                    for lst in self.listeners:
-                        lst.on_epoch_end(self, self.epoch_count)
-                    self.epoch_count += 1
-                    iterator.reset()
-            finally:
-                goodput.fit_end(gp_session)
-                self._input_affine = None
-                for it_ in copy_marked:
-                    it_._copy = False
-        return self
+        return fit_loop.fit(self, self._as_iterator(data, batch_size),
+                            epochs, scan_steps, accumulate_steps, plan,
+                            prefetch=prefetch)
 
     def fit_pretrain(self, data, epochs: int = 1, batch_size: int = 32):
         """Greedy layerwise unsupervised pretraining (the `pretrain` branch
@@ -908,436 +551,57 @@ class MultiLayerNetwork:
             return ArrayDataSetIterator(data[0], data[1], batch_size=batch_size)
         raise ValueError(f"Cannot interpret training data: {type(data)}")
 
-    def _fit_epoch(self, iterator):
-        from deeplearning4j_tpu import monitor
-        from deeplearning4j_tpu.monitor import goodput
-        from deeplearning4j_tpu.monitor import xla as xla_ledger
-        etl_start = time.perf_counter()
-        rng = jax.random.PRNGKey(self.conf.seed + 7919 * (self.epoch_count + 1))
-        grad_listeners = [lst for lst in self.listeners
-                          if getattr(lst, "wants_gradients", False)]
-        for ds in iterator:
-            step_start = time.perf_counter()
-            etl_ms = (step_start - etl_start) * 1e3
-            monitor.add_span("train/etl", etl_start, step_start,
-                             iteration=self.iteration_count)
-            rng, sub = jax.random.split(rng)
-            capture = [lst for lst in grad_listeners
-                       if lst.should_capture(self.iteration_count)]
-            step = self._get_train_step(ds.features_mask, ds.labels_mask,
-                                        None, with_stats=bool(capture))
-            xs = self._stage_x(ds.features)
-            ys = _as_jnp(ds.labels, self._compute_dtype)
-            fm = _as_jnp(ds.features_mask)
-            lm = _as_jnp(ds.labels_mask)
-            xs, ys, fm, lm = self._shard_batch(xs, ys, fm, lm)
-            out = step(self.params, self.opt_state, self.state,
-                       xs, ys, fm, lm, sub, None)
-            grads = updates = None
-            if capture:
-                (self.params, self.opt_state, self.state, loss, _,
-                 grads, updates) = out
-            else:
-                self.params, self.opt_state, self.state, loss, _ = out
-            sync_start = time.perf_counter()
-            # block for device completion FIRST (goodput: step_compute;
-            # banks per-shard barrier wait under a plan), so the
-            # host_sync span below covers only the narrow D2H fetch
-            goodput.device_wait(loss)
-            fetch_start = time.perf_counter()
-            monitor.add_span("train/device_wait", sync_start, fetch_start)
-            # graftlint: disable=host-sync-in-hot-path -- the step's ONE budgeted loss fetch (the deliberate per-iteration sync; PERF.md) — bracketed by the train/host_sync span
-            self._score = float(loss)     # the step's one blocking fetch
-            step_end = time.perf_counter()
-            bs = int(np.shape(ds.features)[0])
-            monitor.add_span("train/host_sync", fetch_start, step_end)
-            monitor.add_span("train/step", step_start, step_end,
-                             iteration=self.iteration_count,
-                             score=self._score, batch_size=bs)
-            if xla_ledger.enabled():
-                key = (id(step), xla_ledger.shape_key((xs, ys, fm, lm)))
-                fresh = key not in self._ledger_cache
-                rec = xla_ledger.capture_cached(
-                    self._ledger_cache, key, "mln/train_step", step,
-                    (self.params, self.opt_state, self.state, xs, ys, fm,
-                     lm, sub, None), examples_per_call=bs)
-                if not fresh:
-                    # the debut execution's wall time includes the jit
-                    # compile — only steady-state steps feed the MFU gauge
-                    xla_ledger.observe_step(rec, step_end - step_start)
-            _record_iteration(self._score, bs,
-                              step_seconds=step_end - step_start,
-                              sync_seconds=step_end - fetch_start)
-            for lst in capture:
-                lst.on_gradients(self, self.iteration_count, self.epoch_count,
-                                 grads, updates)
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration_count,
-                                   self.epoch_count, self._score, etl_ms, bs)
-            self.iteration_count += 1
-            etl_start = time.perf_counter()
+    def _fit_source(self, iterator, stacking, prefetch=None):
+        """The source policy of fit(): a plain source goes behind an
+        AsyncDataSetIterator, like the reference wraps every fit in an
+        async iterator by default (MultiLayerNetwork.java:1272-1274);
+        already-async and async_supported=False sources pass through.
+        Scan-fit and accumulation STACK K host batches before one
+        transfer — the wrap must not device_put per batch there (a
+        device array would round-trip back through the host)."""
+        if prefetch is None:
+            from deeplearning4j_tpu.data.async_iterator import (
+                fit_prefetch_enabled,
+            )
+            prefetch = fit_prefetch_enabled()
+        if not prefetch or isinstance(iterator, AsyncDataSetIterator) \
+                or not getattr(iterator, "async_supported", True):
+            return iterator
+        return AsyncDataSetIterator(
+            iterator, device_put=not stacking,
+            # under a plan the worker thread stages straight onto
+            # the mesh (device arg accepts a Sharding), so the
+            # double-buffered H2D lands already batch-sharded
+            device=(self._plan.batch_sharding()
+                    if self._plan is not None else None),
+            cast_dtype=self._compute_dtype
+            if np.dtype(self._compute_dtype).itemsize == 2
+            else None,
+            cast_features=self._input_affine is None)
 
-    def _make_scan_step(self, with_fmask, with_lmask, K):
-        """K optimizer steps fused into one jit via lax.scan. Same math as
-        _make_train_step applied K times; returns the K per-step losses as a
-        device array so the host never syncs inside the chunk."""
-        from deeplearning4j_tpu.nn.regularization import (
-            apply_constraints, constraint_map, has_constraints,
-        )
-        tx = self._tx
-        constrained = has_constraints(self.layers)
-        layer_map = constraint_map(self)
-        plan = self._plan   # GSPMD plan: sharding constraints in-jit
+    def _epoch_batches(self, source, stacking):
+        return source
 
-        def kstep(params, opt_state, state, xs, ys, fms, lms, subs):
-            def body(carry, batch):
-                params, opt_state, state = carry
-                x, y, fm, lm, sub = batch
-                def loss_fn(p):
-                    return self._score_fn(p, state, x, y, fm, lm, True, sub,
-                                          carries=None)
-                (loss, (new_state, _)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params)
-                if plan is not None:
-                    grads = plan.constrain_grads(grads)
-                new_params, new_opt, _ = apply_update(
-                    tx, grads, opt_state, params, plan)
-                if constrained:
-                    new_params = apply_constraints(layer_map, new_params)
-                if plan is not None:
-                    new_params = plan.constrain_params(new_params)
-                    new_opt = plan.constrain_opt(new_opt, new_params)
-                    new_state = plan.constrain_replicated(new_state)
-                return (new_params, new_opt, new_state), loss
-
-            (params, opt_state, state), losses = jax.lax.scan(
-                body, (params, opt_state, state), (xs, ys, fms, lms, subs))
-            return params, opt_state, state, losses
-
-        return jax.jit(kstep, donate_argnums=(0, 1, 2))
-
-    def _make_accum_step(self, with_stats):
-        """Gradient accumulation: K micro-batch gradients averaged into
-        ONE optimizer step, all inside one jit (TPU-native big-effective-
-        batch training — the HBM cost is one extra gradient-sized
-        accumulator, not a K-times batch). For equal micro-batch sizes
-        and batch-independent layers the result is bit-comparable to one
-        big-batch step (mean of equal-size micro means == full-batch
-        mean; tested); BatchNormalization statistics remain per
-        micro-batch, the same semantics every framework's accumulation
-        has. with_stats additionally returns the averaged (grads,
-        updates) for on_gradients listeners. One jit serves every
-        chunk/mask shape (jax retraces per pytree structure)."""
-        from deeplearning4j_tpu.nn.regularization import (
-            apply_constraints, constraint_map, has_constraints,
-        )
-        tx = self._tx
-        constrained = has_constraints(self.layers)
-        layer_map = constraint_map(self)
-        plan = self._plan   # GSPMD plan: sharding constraints in-jit
-
-        def kaccum(params, opt_state, state, xs, ys, fms, lms, subs):
-            def body(carry, batch):
-                gsum, state = carry
-                x, y, fm, lm, sub = batch
-                def loss_fn(p):
-                    return self._score_fn(p, state, x, y, fm, lm, True,
-                                          sub, carries=None)
-                (loss, (new_state, _)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params)
-                gsum = jax.tree_util.tree_map(jnp.add, gsum, grads)
-                if plan is not None:
-                    # the accumulator carries in the ZeRO layout: micro-
-                    # batch grads reduce-scatter into it instead of ever
-                    # materializing whole per chip
-                    gsum = plan.constrain_grads(gsum)
-                return (gsum, new_state), loss
-
-            zeros = jax.tree_util.tree_map(jnp.zeros_like, params)
-            (gsum, state), losses = jax.lax.scan(
-                body, (zeros, state), (xs, ys, fms, lms, subs))
-            grads = jax.tree_util.tree_map(
-                lambda g: g / subs.shape[0], gsum)
-            new_params, new_opt, updates = apply_update(
-                tx, grads, opt_state, params, plan)
-            if constrained:
-                new_params = apply_constraints(layer_map, new_params)
-            if plan is not None:
-                new_params = plan.constrain_params(new_params)
-                new_opt = plan.constrain_opt(new_opt, new_params)
-                state = plan.constrain_replicated(state)
-            if with_stats:
-                return (new_params, new_opt, state, jnp.mean(losses),
-                        grads, updates)
-            return new_params, new_opt, state, jnp.mean(losses)
-
-        return jax.jit(kaccum, donate_argnums=(0, 1, 2))
-
-    def _get_accum_step(self, with_stats=False):
-        sig = ("accum", with_stats)
-        if sig not in self._scan_step:
-            self._scan_step[sig] = self._make_accum_step(with_stats)
-        return self._scan_step[sig]
-
-    def _fit_epoch_accum(self, iterator, K):
-        """One optimizer step per K micro-batches (gradient accumulation).
-        Iteration counting follows DL4J's meaning (one iteration = one
-        optimizer step); a ragged tail (< K same-shape batches) still
-        accumulates into one step with the correct 1/len mean. Gradient
-        listeners receive the AVERAGED per-step grads/updates (lockstep
-        — wants_gradients forces defer=False below, so iteration_count
-        at dispatch is the step being reported)."""
-        from deeplearning4j_tpu.monitor import xla as xla_ledger
-        rng = jax.random.PRNGKey(self.conf.seed
-                                 + 7919 * (self.epoch_count + 1))
-        grad_listeners = [lst for lst in self.listeners
-                          if getattr(lst, "wants_gradients", False)]
-        sigs_seen = set()
-        warned_partial = [False]
-        last_sync = [None]
-
-        def fetch(p):
-            return float(p[0])      # the chunk's one blocking fetch
-
-        def notify(p, score):
-            _, bs, etl_ms, capture, grads, updates, rec = p
-            self._score = score
-            if xla_ledger.enabled():
-                now = time.perf_counter()
-                if rec is not None and last_sync[0] is not None:
-                    xla_ledger.observe_step(rec, now - last_sync[0])
-                last_sync[0] = now
-            _record_iteration(self._score, bs)
-            for lst in capture:
-                lst.on_gradients(self, self.iteration_count,
-                                 self.epoch_count, grads, updates)
-            for lst in self.listeners:
-                lst.iteration_done(self, self.iteration_count,
-                                   self.epoch_count, self._score, etl_ms,
-                                   bs)
-            self.iteration_count += 1
-            return 1
-
-        def stage(group):
-            nonlocal rng
-            if len(group) < K and not warned_partial[0]:
-                # _run_scan_pipeline only groups CONSECUTIVE same-shape
-                # batches: a shape change (e.g. a non-drop-last partial
-                # tail) cuts the accumulation group short, and the short
-                # group still takes ONE full-learning-rate optimizer step
-                # with the mean of len(group) gradients — K is silently
-                # not honored for it. Surface that once.
-                warned_partial[0] = True
-                cause = ("the micro-batch shape changed mid-epoch (use "
-                         "drop_last or padded iterators for uniform "
-                         "shapes)" if len(sigs_seen) > 1
-                         else "the epoch ended mid-group")
-                log.warning(
-                    "fit(accumulate_steps=%d): dispatching an accumulation "
-                    "group of only %d micro-batch(es) because %s; the "
-                    "partial group takes one full-learning-rate step with "
-                    "the 1/%d gradient mean", K, len(group), cause,
-                    len(group))
-            subs = []
-            for _ in group:
-                rng, sub = jax.random.split(rng)
-                subs.append(sub)
-            xs, ys, fms, lms = self._stage_stacked(group)
-            bs = _ds_examples(group[0]) * len(group)
-            return xs, ys, fms, lms, jnp.stack(subs), bs, len(group)
-
-        def launch(staged, etl_ms):
-            xs, ys, fms, lms, subs_d, bs, n = staged
-            capture = [lst for lst in grad_listeners
-                       if lst.should_capture(self.iteration_count)]
-            kstep = self._get_accum_step(with_stats=bool(capture))
-            out = kstep(self.params, self.opt_state, self.state, xs, ys,
-                        fms, lms, subs_d)
-            grads = updates = None
-            if capture:
-                (self.params, self.opt_state, self.state, loss, grads,
-                 updates) = out
-            else:
-                self.params, self.opt_state, self.state, loss = out
-            rec = None
-            if xla_ledger.enabled():
-                key = (id(kstep), xla_ledger.shape_key((xs, ys, fms, lms)))
-                fresh = key not in self._ledger_cache
-                rec = xla_ledger.capture_cached(
-                    self._ledger_cache, key,
-                    "mln/accum_step", kstep,
-                    (self.params, self.opt_state, self.state, xs, ys, fms,
-                     lms, subs_d), examples_per_call=bs,
-                    steps_per_call=n)
-                if fresh:
-                    last_sync[0] = None   # exclude the AOT compile interval
-            return loss, bs, etl_ms, capture, grads, updates, rec
-
-        def sig_of(ds):
-            s = (np.shape(ds.features), np.shape(ds.labels),
-                 None if ds.features_mask is None
-                 else np.shape(ds.features_mask),
-                 None if ds.labels_mask is None
-                 else np.shape(ds.labels_mask))
-            sigs_seen.add(s)
-            return s
-
-        # unlike scan-fit, accumulation cannot fall back to per-call for
-        # model-reading listeners (that would change the optimization) —
-        # it drops the one-chunk deferral instead so each callback sees
-        # the params of the step it reports
-        self._fit_chunk = _run_scan_pipeline(
-            iterator, K, sig_of=sig_of, examples_of=_ds_examples,
-            stage=stage, launch=launch, fetch=fetch, notify=notify,
-            defer=not _scan_incompatible_listeners(self.listeners),
-            first_chunk=self._fit_chunk)
-
-    def _get_scan_step(self, fmask, lmask, K):
-        sig = (fmask is not None, lmask is not None, K)
-        if sig not in self._scan_step:
-            self._scan_step[sig] = self._make_scan_step(*sig)
-        return self._scan_step[sig]
-
-    def _fit_epoch_scan(self, iterator, K):
-        """Input-pipelined epoch: group consecutive same-shape batches into
-        chunks of K, stack host-side, run one scan-of-K jit per chunk, and
-        defer the loss fetch by one chunk so stacking/dispatch of chunk i+1
-        overlaps chunk i's device compute. Ragged tails (or a shape change
-        mid-epoch) fall back to per-call steps for those batches."""
-        if _scan_incompatible_listeners(self.listeners):
-            return self._fit_epoch(iterator)
-        from deeplearning4j_tpu.monitor import xla as xla_ledger
-        rng = jax.random.PRNGKey(self.conf.seed + 7919 * (self.epoch_count + 1))
-        last_sync = [None]   # previous chunk-sync stamp: chunk wall clock
-
-        def fetch(p):
-            return np.asarray(p[0])             # single blocking fetch/chunk
-
-        def notify(p, arr):
-            _, bs, etl_ms, rec = p
-            if xla_ledger.enabled():
-                # steady-state chunk wall time = spacing between chunk
-                # syncs (the pipelined path has no un-overlapped "this
-                # chunk only" interval to time; the first chunk is
-                # skipped). The stamp advances on EVERY chunk — a ragged
-                # tail (rec None) must not leak its wall time into the
-                # next scan chunk's interval.
-                now = time.perf_counter()
-                if rec is not None and last_sync[0] is not None:
-                    xla_ledger.observe_step(rec, now - last_sync[0])
-                last_sync[0] = now
-            for loss in arr:
-                # graftlint: disable=host-sync-in-hot-path -- chunk losses are already host-resident (fetch() above IS the deferred chunk sync); this is per-iteration bookkeeping
-                self._score = float(loss)
-                _record_iteration(self._score, bs)
-                for lst in self.listeners:
-                    lst.iteration_done(self, self.iteration_count,
-                                       self.epoch_count, self._score,
-                                       etl_ms, bs)
-                self.iteration_count += 1
-                etl_ms = 0.0
-            return len(arr)
-
-        def stage(group):
-            nonlocal rng
-            subs = []
-            for _ in group:
-                rng, sub = jax.random.split(rng)
-                subs.append(sub)
-            ds0 = group[0]
-            bs = _ds_examples(ds0)
-            if len(group) < K:
-                # ragged tail / shape-change remainder: reuse the already
-                # compiled per-call step rather than compiling a one-off
-                # scan-of-len(group) program
-                tail = [self._shard_batch(
-                    self._stage_x(ds.features),
-                    _as_jnp(ds.labels, self._compute_dtype),
-                    _as_jnp(ds.features_mask),
-                    _as_jnp(ds.labels_mask)) for ds in group]
-                return tail, subs, bs, ds0
-            return self._stage_stacked(group), jnp.stack(subs), bs, None
-
-        def launch(staged, etl_ms):
-            inputs, subs, bs, tail_of = staged
-            if tail_of is not None:
-                step = self._get_train_step(tail_of.features_mask,
-                                            tail_of.labels_mask, None)
-                losses = []
-                for (txs, tys, tfm, tlm), sub in zip(inputs, subs):
-                    out = step(self.params, self.opt_state, self.state,
-                               txs, tys, tfm, tlm, sub, None)
-                    self.params, self.opt_state, self.state, loss, _ = out
-                    losses.append(loss)
-                return jnp.stack(losses), bs, etl_ms, None
-            xs, ys, fms, lms = inputs
-            n = int(subs.shape[0])
-            kstep = self._get_scan_step(fms, lms, n)
-            (self.params, self.opt_state, self.state,
-             losses) = kstep(self.params, self.opt_state, self.state,
-                             xs, ys, fms, lms, subs)
-            rec = None
-            if xla_ledger.enabled():
-                key = (id(kstep),
-                       xla_ledger.shape_key((xs, ys, fms, lms)))
-                fresh = key not in self._ledger_cache
-                rec = xla_ledger.capture_cached(
-                    self._ledger_cache, key,
-                    "mln/scan_step", kstep,
-                    (self.params, self.opt_state, self.state, xs, ys,
-                     fms, lms, subs),
-                    examples_per_call=bs * n, steps_per_call=n)
-                if fresh:
-                    # the capture's AOT compile sat inside this
-                    # inter-chunk interval — restart the MFU clock so
-                    # it can't read as a slow chunk
-                    last_sync[0] = None
-            return losses, bs, etl_ms, rec
-
-        def sig_of(ds):
-            return (np.shape(ds.features), np.shape(ds.labels),
-                    None if ds.features_mask is None
-                    else np.shape(ds.features_mask),
-                    None if ds.labels_mask is None
-                    else np.shape(ds.labels_mask))
-
-        self._fit_chunk = _run_scan_pipeline(
-            iterator, K, sig_of=sig_of, examples_of=_ds_examples,
-            stage=stage, launch=launch, fetch=fetch, notify=notify,
-            first_chunk=self._fit_chunk)
-
-    def _fit_epoch_tbptt(self, iterator):
-        """Truncated BPTT: chunk the time axis, carry RNN state across chunks,
-        stop gradients at chunk boundaries (doTruncatedBPTT, :1315-1317)."""
+    def _fit_epoch_tbptt(self, batches, rng):
+        """Truncated BPTT: chunk the time axis (sliced BEFORE staging),
+        carry RNN state across chunks, stop gradients at chunk boundaries
+        (doTruncatedBPTT, :1315-1317)."""
         fwd = self.conf.tbptt_fwd_length
-        rng = jax.random.PRNGKey(self.conf.seed + 104729 * (self.epoch_count + 1))
-        for ds in iterator:
+        for ds in batches:
             T = ds.features.shape[1]
-            carries = {}
-            for t0 in range(0, T, fwd):
-                t1 = min(t0 + fwd, T)
-                x = ds.features[:, t0:t1]
-                y = ds.labels[:, t0:t1] if ds.labels is not None and ds.labels.ndim >= 3 else ds.labels
-                fm = ds.features_mask[:, t0:t1] if ds.features_mask is not None else None
-                lm = ds.labels_mask[:, t0:t1] if ds.labels_mask is not None else None
-                rng, sub = jax.random.split(rng)
-                step = self._get_train_step(fm, lm, carries)
-                txs, tys, tfm, tlm = self._shard_batch(
-                    self._stage_x(x), _as_jnp(y, self._compute_dtype),
-                    _as_jnp(fm), _as_jnp(lm))
-                self.params, self.opt_state, self.state, loss, new_carries = step(
-                    self.params, self.opt_state, self.state,
-                    txs, tys, tfm, tlm, sub, carries)
-                # stop gradient across chunk boundary
-                carries = jax.tree_util.tree_map(jax.lax.stop_gradient, new_carries)
-                # graftlint: disable=host-sync-in-hot-path -- the tbptt chunk's one budgeted loss fetch
-                self._score = float(loss)
-                _record_iteration(self._score, int(np.shape(x)[0]))
-                for lst in self.listeners:
-                    lst.iteration_done(self, self.iteration_count,
-                                       self.epoch_count, self._score, 0.0,
-                                       int(np.shape(x)[0]))
-                self.iteration_count += 1
+
+            def chunks(ds=ds, T=T):
+                cut_labels = ds.labels is not None and ds.labels.ndim >= 3
+                for t0 in range(0, T, fwd):
+                    cut = lambda a: None if a is None else a[:, t0:t0 + fwd]
+                    yield self._operands(DataSet(
+                        cut(ds.features),
+                        cut(ds.labels) if cut_labels else ds.labels,
+                        cut(ds.features_mask), cut(ds.labels_mask)))
+
+            rng = _fit_tbptt_batch(self, chunks(), rng, 0.0,
+                                   self._batch_examples(ds))
+        return rng
 
     # ------------------------------------------------------------- scoring
     def score(self, dataset: Optional[DataSet] = None) -> float:

@@ -239,7 +239,7 @@ class ContextParallelTrainer:
         net.state = param_util.own_tree(net.state)
         net.opt_state = param_util.own_tree(net.opt_state)
         # vary by epoch_count so repeated fit() calls draw fresh dropout
-        # masks (matches MultiLayerNetwork._fit_epoch keying)
+        # masks (as fit()'s own keying, nn/fit_loop.py)
         rng = jax.random.fold_in(
             jax.random.PRNGKey(net.conf.seed + 524287), net.epoch_count)
         for _ in range(epochs):
@@ -266,7 +266,7 @@ class ContextParallelTrainer:
             for lst in net.listeners:
                 lst.on_epoch_end(net, net.epoch_count)
             net.epoch_count += 1
-        net._train_step = None
+        net._steps = {}
         net._output_fn = None
         return net
 

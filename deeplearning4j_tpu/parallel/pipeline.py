@@ -299,6 +299,6 @@ class PipelineParallelTrainer:
                 lst.on_epoch_end(net, net.epoch_count)
             net.epoch_count += 1
             source.reset()
-        net._train_step = None
+        net._steps = {}
         net._output_fn = None
         return net

@@ -403,7 +403,8 @@ def _host_copy(tree):
 
 class _NetDriver:
     """Per-call step execution for MultiLayerNetwork — the same compiled
-    step, staging, and RNG stream as MultiLayerNetwork._fit_epoch."""
+    step, staging, and RNG stream as fit()'s per-call path
+    (nn/fit_loop.py)."""
 
     rng_mult = 7919
 
@@ -448,15 +449,10 @@ class _NetDriver:
         # plan placements and the net's compiled step compiles the
         # plan's collectives — the same zero-code-change pickup fit()
         # has.
-        plan = None
         if self._uses_plan:
+            from deeplearning4j_tpu.nn.fit_loop import _engage_plan_impl
             from deeplearning4j_tpu.parallel.plan import active_plan
-            plan = active_plan()
-        if self._uses_plan and (plan is not None
-                                or getattr(self.net, "_plan", None)
-                                is not None):
-            from deeplearning4j_tpu.nn.multilayer import _engage_plan_impl
-            _engage_plan_impl(self.net, plan)
+            _engage_plan_impl(self.net, active_plan())
         else:
             self.net.params = param_util.own_tree(self.net.params)
             self.net.state = param_util.own_tree(self.net.state)
@@ -482,7 +478,7 @@ class _NetDriver:
         PR-3 own_tree contract, now sharding-aware — so a resumed step
         never donates misplaced (or heap-aliased) restored leaves."""
         if self._uses_plan and getattr(self.net, "_plan", None) is not None:
-            from deeplearning4j_tpu.nn.multilayer import _engage_plan_impl
+            from deeplearning4j_tpu.nn.fit_loop import _engage_plan_impl
             _engage_plan_impl(self.net, self.net._plan)
 
     def make_source(self, data, batch_size):
@@ -514,34 +510,29 @@ class _NetDriver:
         n.opt_state = own_tree(snap[1])
         n.state = own_tree(snap[2])
 
-    def step(self, ds, sub):
-        from deeplearning4j_tpu.nn.multilayer import _as_jnp
+    def step(self, batch, sub):
         from deeplearning4j_tpu.monitor import xla as xla_ledger
+        from deeplearning4j_tpu.nn.fit_loop import compiled_step
         n = self.net
-        fn = n._get_train_step(ds.features_mask, ds.labels_mask, None)
-        xs = n._stage_x(ds.features)
-        ys = _as_jnp(ds.labels, n._compute_dtype)
-        fm = _as_jnp(ds.features_mask)
-        lm = _as_jnp(ds.labels_mask)
-        # under a GSPMD plan the batch shards over the mesh "data" axis
-        # exactly like MultiLayerNetwork._fit_epoch (no-op without one)
-        xs, ys, fm, lm = n._shard_batch(xs, ys, fm, lm)
+        fn = compiled_step(n, "step")
+        # staged and, under a GSPMD plan, sharded over the mesh "data"
+        # axis exactly like fit()'s per-call path
+        operands = n._operands(batch)
         n.params, n.opt_state, n.state, loss, _ = fn(
-            n.params, n.opt_state, n.state, xs, ys, fm, lm, sub, None)
-        bs = int(np.shape(ds.features)[0])
+            n.params, n.opt_state, n.state, *operands, sub, None)
+        bs = n._batch_examples(batch)
         if xla_ledger.enabled():
             self._ledger_pending = (
                 n._ledger_cache,
-                (id(fn), xla_ledger.shape_key((xs, ys, fm, lm))),
+                (id(fn), xla_ledger.shape_key(operands)),
                 self.ledger_program, fn,
-                (n.params, n.opt_state, n.state, xs, ys, fm, lm, sub,
-                 None), bs)
+                (n.params, n.opt_state, n.state, *operands, sub, None), bs)
         return loss, bs
 
 
 class _GraphDriver(_NetDriver):
-    """ComputationGraph per-call step (ComputationGraph._fit_epoch_per_call
-    math; per-epoch RNG reseed for resumability)."""
+    """ComputationGraph per-call step (the same step; per-epoch RNG
+    reseed for resumability)."""
 
     rng_mult = 331
 
@@ -552,35 +543,6 @@ class _GraphDriver(_NetDriver):
 
     def batches(self, source):
         return self.net._iter_data(source)
-
-    def step(self, mds, sub):
-        from deeplearning4j_tpu.nn.multilayer import _as_jnp
-        from deeplearning4j_tpu.monitor import xla as xla_ledger
-        n = self.net
-        if n._train_step is None:
-            n._train_step = n._make_train_step()
-        inputs = n._shard_tuple(tuple(n._stage_x(f) for f in mds.features))
-        labels = n._shard_tuple(tuple(_as_jnp(l, n._compute_dtype)
-                                      for l in mds.labels))
-        fmasks = n._shard_tuple(
-            None if mds.features_masks is None else tuple(
-                _as_jnp(m) for m in mds.features_masks))
-        lmasks = n._shard_tuple(
-            None if mds.labels_masks is None else tuple(
-                _as_jnp(m) for m in mds.labels_masks))
-        n.params, n.opt_state, n.state, loss, _ = n._train_step(
-            n.params, n.opt_state, n.state, inputs, labels, fmasks,
-            lmasks, sub, None)
-        bs = int(np.shape(mds.features[0])[0])
-        if xla_ledger.enabled():
-            self._ledger_pending = (
-                n._ledger_cache,
-                (id(n._train_step), xla_ledger.shape_key(
-                    (inputs, labels, fmasks, lmasks))),
-                self.ledger_program, n._train_step,
-                (n.params, n.opt_state, n.state, inputs, labels, fmasks,
-                 lmasks, sub, None), bs)
-        return loss, bs
 
 
 class _WrapperDriver(_NetDriver):
@@ -900,7 +862,7 @@ class ResilientTrainer:
                     f"at step {step_idx}")
             return "skipped", loss_f, bs
         self._consecutive_skips = 0
-        from deeplearning4j_tpu.nn.multilayer import _record_iteration
+        from deeplearning4j_tpu.nn.fit_loop import _record_iteration
         from deeplearning4j_tpu.monitor import xla as xla_ledger
         _record_iteration(loss_f, bs, step_seconds=step_secs)
         if xla_ledger.enabled() and not self._driver._ledger_fresh:

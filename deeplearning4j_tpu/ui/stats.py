@@ -7,7 +7,7 @@ the static-info record (session start, model info, hardware).
 
 TPU-native design: gradients/updates come from a dedicated jit variant of
 the train step that returns the raw pytrees only on capture iterations
-(MultiLayerNetwork._make_train_step with_stats=True) — the fast path
+(nn/fit_loop.build_step with_stats=True) — the fast path
 transfers nothing extra. Histograms/norms are computed host-side from the
 fetched arrays; device memory comes from jax's per-device memory_stats().
 """

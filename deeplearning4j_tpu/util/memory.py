@@ -244,20 +244,16 @@ def _compiled_step_memory(net, batch_size, is_graph) -> Optional[Dict[str, int]]
             y.append(jnp.zeros((batch_size,) + t.shape,
                                net._compute_dtype))
         y = tuple(y)
-        if net._train_step is None:
-            net._train_step = net._make_train_step()
-        lowered = net._train_step.lower(
-            net.params, net.opt_state, net.state, x, y, None, None,
-            jax.random.PRNGKey(0), None)
     else:
         types = net._resolve_types()
         out_t = net.layers[-1].output_type(types[-1])
         x = jnp.zeros((batch_size,) + net.conf.input_type.shape,
                       net._compute_dtype)
         y = jnp.zeros((batch_size,) + out_t.shape, net._compute_dtype)
-        step = net._get_train_step(None, None, None)
-        lowered = step.lower(net.params, net.opt_state, net.state, x, y,
-                             None, None, jax.random.PRNGKey(0), None)
+    from deeplearning4j_tpu.nn.fit_loop import compiled_step
+    lowered = compiled_step(net, "step").lower(
+        net.params, net.opt_state, net.state, x, y, None, None,
+        jax.random.PRNGKey(0), None)
     try:
         ma = _read_memory_analysis(lowered.compile())
     except Exception as e:      # backend without memory_analysis support

@@ -2,7 +2,7 @@
 fit the TPU's tiling (widths of 128, chunks of 64): the Pallas forward and
 backward, interpreted on the CPU, against the ONE tile function vmapped
 under XLA with plain autodiff, and that function against the definition
-it is an arrangement of. `tests/test_kimi_linear.py` holds the whole
+it is an arrangement of. `tests/test_kimi_attention.py` holds the whole
 chunked recurrence (at small widths, the vmapped executor) to the token
 recurrence; `tests/test_tpu_lowering.py` compiles the kernels for the v5e.
 """

@@ -372,19 +372,19 @@ class TestScanFit:
 
 class TestScanStepsDefault:
     def test_cpu_default_is_per_call(self, monkeypatch):
-        from deeplearning4j_tpu.nn.multilayer import _default_scan_steps
+        from deeplearning4j_tpu.nn.fit_loop import _default_scan_steps
         monkeypatch.delenv("DL4J_TPU_SCAN_STEPS", raising=False)
         # conftest pins the cpu backend; per-call is the measured CPU
         # winner (PERF.md: conv-in-scan 10.9x slower on XLA:CPU)
         assert _default_scan_steps() == 1
 
     def test_env_override_wins(self, monkeypatch):
-        from deeplearning4j_tpu.nn.multilayer import _default_scan_steps
+        from deeplearning4j_tpu.nn.fit_loop import _default_scan_steps
         monkeypatch.setenv("DL4J_TPU_SCAN_STEPS", "7")
         assert _default_scan_steps() == 7
 
     def test_tpu_default_is_scan10(self, monkeypatch):
-        import deeplearning4j_tpu.nn.multilayer as ml
+        import deeplearning4j_tpu.nn.fit_loop as ml
         monkeypatch.delenv("DL4J_TPU_SCAN_STEPS", raising=False)
         monkeypatch.setattr(ml.jax, "default_backend", lambda: "tpu")
         assert ml._default_scan_steps() == 10
@@ -478,26 +478,27 @@ class TestGradientAccumulation:
         assert net.iteration_count == 3           # one step per chunk
         assert len(lst.scores) == 3
 
-    def test_graph_accumulation_equals_big_batch(self):
+    @staticmethod
+    def _graph():
         from deeplearning4j_tpu.nn.conf import (
             InputType, NeuralNetConfiguration)
         from deeplearning4j_tpu.nn.conf.network import GraphBuilder
         from deeplearning4j_tpu.nn.graph import ComputationGraph
         from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
         from deeplearning4j_tpu.nn.updaters import Sgd
+        g = (GraphBuilder(NeuralNetConfiguration.Builder().seed(9)
+                          .updater(Sgd(1e-1)))
+             .add_inputs("in")
+             .set_input_types(InputType.feed_forward(5)))
+        g.add_layer("d", DenseLayer(n_out=16, activation="tanh"), "in")
+        g.add_layer("out", OutputLayer(n_out=3, activation="softmax",
+                                       loss="mcxent"), "d")
+        g.set_outputs("out")
+        return ComputationGraph(g.build()).init()
+
+    def test_graph_accumulation_equals_big_batch(self):
         X, Y = self._data(64)
-
-        def net():
-            g = (GraphBuilder(NeuralNetConfiguration.Builder().seed(9)
-                              .updater(Sgd(1e-1)))
-                 .add_inputs("in")
-                 .set_input_types(InputType.feed_forward(5)))
-            g.add_layer("d", DenseLayer(n_out=16, activation="tanh"), "in")
-            g.add_layer("out", OutputLayer(n_out=3, activation="softmax",
-                                           loss="mcxent"), "d")
-            g.set_outputs("out")
-            return ComputationGraph(g.build()).init()
-
+        net = self._graph
         from deeplearning4j_tpu.data.iterator import ArrayDataSetIterator
         a = net()
         a.fit(ArrayDataSetIterator(X, Y, batch_size=16),
@@ -511,29 +512,102 @@ class TestGradientAccumulation:
             np.testing.assert_allclose(np.asarray(la), np.asarray(lb),
                                        rtol=1e-5, atol=1e-6)
 
+    class GradSpy:
+        wants_gradients = True
+        reads_model = True
+
+        def __init__(self):
+            self.calls = []
+            self.trees = []
+
+        def should_capture(self, it):
+            return True
+
+        def on_gradients(self, model, it, ep, grads, updates):
+            self.calls.append(
+                (it, grads is not None and updates is not None))
+            self.trees.append((grads, updates))
+
+        def __getattr__(self, name):
+            return lambda *a, **k: None
+
     def test_gradient_listener_gets_averaged_grads(self):
         # wants_gradients listeners receive the AVERAGED per-step grads
         # (lockstep callbacks — no one-chunk deferral on this path)
-        class GradSpy:
-            wants_gradients = True
-            reads_model = True
-
-            def __init__(self):
-                self.calls = []
-
-            def should_capture(self, it):
-                return True
-
-            def on_gradients(self, model, it, ep, grads, updates):
-                self.calls.append(
-                    (it, grads is not None and updates is not None))
-
-            def __getattr__(self, name):
-                return lambda *a, **k: None
-
         X, Y = self._data(64)
         net = self._net()
-        spy = GradSpy()
+        spy = self.GradSpy()
         net.set_listeners(spy)
         net.fit((X, Y), batch_size=16, accumulate_steps=4, epochs=2)
         assert spy.calls == [(0, True), (1, True)]
+
+    @pytest.mark.parametrize("how", [
+        {"batch_size": 64}, {"batch_size": 16, "accumulate_steps": 4}],
+        ids=["per_call", "accumulation"])
+    def test_graph_serves_gradient_listeners(self, how):
+        # one loop under both containers (nn/fit_loop.py): a graph's
+        # per-call and accumulation fits call on_gradients too, with the
+        # gradient of the whole effective batch (the mean of the
+        # micro-batch gradients) and the update the optimizer made of it
+        X, Y = self._data(64)
+        net = self._graph()
+        want = jax.grad(lambda p: net._score_fn(
+            p, net.state, (X,), (Y,), None, None, True, None)[0])(net.params)
+        spy = self.GradSpy()
+        net.set_listeners(spy)
+        net.fit(ArrayDataSetIterator(X, Y, batch_size=how["batch_size"]),
+                accumulate_steps=how.get("accumulate_steps", 1), epochs=2)
+        assert spy.calls == [(0, True), (1, True)]
+        grads, updates = spy.trees[0]
+        for g, u, w in zip(*map(jax.tree_util.tree_leaves,
+                                (grads, updates, want))):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-5, atol=1e-6)
+            np.testing.assert_allclose(np.asarray(u), -1e-1 * np.asarray(w),
+                                       rtol=1e-5, atol=1e-7)
+
+
+class TestOneLoop:
+    """The fork between the containers stays closed (nn/fit_loop.py)."""
+
+    @pytest.mark.parametrize("module", ["multilayer", "graph"])
+    def test_containers_build_no_train_step_and_hold_no_chunk_loop(
+            self, module):
+        # a container says how a batch becomes operands and how its
+        # forward scores them; the gradient, the scan over optimizer
+        # steps, the update and the chunk pipeline are fit_loop's.
+        # fit_pretrain's layerwise step is not a train step of fit().
+        import ast
+        import deeplearning4j_tpu.nn as nn_pkg
+        path = os.path.join(os.path.dirname(nn_pkg.__file__), module + ".py")
+        banned = {"value_and_grad", "grad", "scan", "apply_update",
+                  "_run_scan_pipeline", "jit"}
+        own = ("fit_pretrain", "output", "rnn_time_step")
+        found = []
+
+        def visit(node):
+            for child in ast.iter_child_nodes(node):
+                if isinstance(child, ast.FunctionDef) and child.name in own:
+                    continue
+                if isinstance(child, ast.Call):
+                    f = child.func
+                    name = f.attr if isinstance(f, ast.Attribute) else \
+                        getattr(f, "id", None)
+                    if name in banned:
+                        found.append(f"{module}.py:{child.lineno} {name}")
+                visit(child)
+
+        visit(ast.parse(open(path).read()))
+        assert not found, found
+
+    def test_one_jit_a_kind_retraces_by_mask_presence(self):
+        # the step cache has one entry a (kind, with_stats): jax.jit
+        # retraces by pytree structure, so batches with and then without
+        # a labels mask are two programs of ONE entry (the parent kept
+        # two entries of one program each)
+        X, Y = TestGradientAccumulation()._data(32)
+        net = TestGradientAccumulation()._net()
+        net.fit(DataSet(X, Y, None, np.ones((32,), "float32")), scan_steps=1)
+        net.fit(DataSet(X, Y), scan_steps=1)
+        assert list(net._steps) == [("step", False)]
+        assert net._steps[("step", False)]._cache_size() == 2

@@ -170,7 +170,7 @@ class TestHostCast:
     def _spy_transfer_dtype(monkeypatch):
         """Record the dtype of whatever _as_jnp hands to jnp.asarray —
         the observable that distinguishes host-cast from device-cast."""
-        import deeplearning4j_tpu.nn.multilayer as ml
+        import deeplearning4j_tpu.nn.fit_loop as ml
         seen = {}
         real = ml.jnp.asarray
 
@@ -182,7 +182,7 @@ class TestHostCast:
         return seen
 
     def test_bf16_host_cast_bitwise_matches_device_cast(self, monkeypatch):
-        from deeplearning4j_tpu.nn.multilayer import _as_jnp
+        from deeplearning4j_tpu.nn.fit_loop import _as_jnp
         monkeypatch.setenv("DL4J_TPU_HOST_CAST", "1")
         seen = self._spy_transfer_dtype(monkeypatch)
         rs = np.random.RandomState(0)
@@ -196,7 +196,7 @@ class TestHostCast:
             np.asarray(dev).view(np.uint16))
 
     def test_kill_switch_and_non_16bit_paths(self, monkeypatch):
-        from deeplearning4j_tpu.nn.multilayer import _as_jnp
+        from deeplearning4j_tpu.nn.fit_loop import _as_jnp
         monkeypatch.setenv("DL4J_TPU_HOST_CAST", "1")
         seen = self._spy_transfer_dtype(monkeypatch)
         a = np.ones((3, 3), np.float32)

@@ -16,8 +16,9 @@ foundation the sequence-parallel / ring-attention machinery
 - ONE expert layer, `MoEFeedForward`: it routes every token over all the
   experts of the layer, is told which of them it holds, sorts the
   (token, slot) pairs by expert into grouped matrix products over the
-  held ones (cost follows the rows routed, not the expert count), drops
-  nothing, and adds a shared expert where the model has one. The linear
+  held ones (cost follows the row tier that holds the pairs routed here,
+  not the expert count and not the worst case), drops nothing, and adds
+  a shared expert where the model has one. The linear
   and latent attentions of the hybrid LMs live beside this file in
   `linear_attention.py` and ride `TransformerBlock` through its ``attn``
   field.
@@ -25,6 +26,7 @@ foundation the sequence-parallel / ring-attention machinery
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -381,9 +383,85 @@ def _rows_to_tokens_bwd(order, g):
 _rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
 
 
-#: the worst-case rows of one dispatch (tokens x top_k, each an input, a
-#: hidden and an output row) may take this much; more tokens go in blocks
+# A dispatch that walks M of its N*k pairs moves rows by gathers too.
+# ``pairs`` (M,) names the (token, slot) pair of each row and ``row_of``
+# (N*k,) the row of each pair, M for a pair that has none: that one reads
+# zeros. A sum over the rows of a token is k gathers a token from those M
+# rows; a scatter-add of the M rows took 1.2 to 2.2 times as long on the
+# v5e (PERF.md section 6, PR 30).
+
+def _rows_by_pair(y, row_of, k):
+    """(M, F) rows -> (N, k, F): every (token, slot) pair's row, zeros for
+    a pair that has none."""
+    return y.at[row_of].get(mode="fill", fill_value=0) \
+        .reshape(-1, k, y.shape[-1])
+
+
+@jax.custom_vjp
+def _rows_of_pairs(h, pairs, row_of):
+    """(N, F) token rows -> (M, F): row r is the token of pair
+    ``pairs[r]``."""
+    return h[pairs // (row_of.shape[0] // h.shape[0])]
+
+
+def _rows_of_pairs_fwd(h, pairs, row_of):
+    return _rows_of_pairs(h, pairs, row_of), (row_of, h.shape[0])
+
+
+def _rows_of_pairs_bwd(res, g):
+    row_of, n = res
+    acc_t = jnp.promote_types(jnp.float32, g.dtype)
+    return _rows_by_pair(g, row_of, row_of.shape[0] // n).astype(acc_t) \
+        .sum(1).astype(g.dtype), None, None
+
+
+_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+
+
+@jax.custom_vjp
+def _weighed_to_tokens(y, w, pairs, row_of):
+    """(M, F) rows and the (N, k) weights of all pairs -> (N, F): every
+    token's sum of its pairs' rows under their weights, in ``w``'s type."""
+    return jnp.einsum("nkf,nk->nf", _rows_by_pair(y, row_of, w.shape[1]), w,
+                      preferred_element_type=w.dtype)
+
+
+def _weighed_to_tokens_fwd(y, w, pairs, row_of):
+    return _weighed_to_tokens(y, w, pairs, row_of), (y, w, pairs)
+
+
+def _weighed_to_tokens_bwd(res, g):
+    y, w, pairs = res
+    of_token = g[pairs // w.shape[1]]                       # (M, F)
+    of_pair = w.reshape(-1).at[pairs].get(unique_indices=True)
+    dw = jnp.zeros((w.size,), w.dtype).at[pairs].set(
+        jnp.sum(of_token * y.astype(g.dtype), axis=-1),
+        unique_indices=True)
+    return (of_token * of_pair[:, None]).astype(y.dtype), \
+        dw.reshape(w.shape), None, None
+
+
+_weighed_to_tokens.defvjp(_weighed_to_tokens_fwd, _weighed_to_tokens_bwd)
+
+
+#: the rows of one dispatch's FULL tier (tokens x top_k, each an input, a
+#: hidden and an output row) may take this much; more tokens go in blocks.
+#: It sizes the full tier although most dispatches walk a smaller one: a
+#: block whose every pair is held here must still fit
 _DISPATCH_LIVE_BYTES = 512 << 20
+
+#: the row tiers of a dispatch that holds a share of its experts, as
+#: divisors of its tokens x top_k (token, slot) pairs: it walks the first
+#: (smallest) tier that holds the pairs held here, and the whole is last.
+#: One small tier: where 8 of 256 experts are held a block's pairs fill
+#: 37 to 80 % of it, and a middle one (1/4) was compiled into every
+#: switch and never taken (PERF.md section 6, PR 30)
+_ROW_TIERS = (16, 1)
+
+#: the parts of its tokens in which a switch's last tier walks them: in
+#: one part that tier sized the LM step (4.34 GB of temporaries where the
+#: small tier alone needs 3.70), in two 4.06 (PERF.md section 6, PR 30)
+_WHOLE_TIER_PARTS = 2
 
 
 def _grouped_matmul(x, w, sizes):
@@ -395,8 +473,150 @@ def _grouped_matmul(x, w, sizes):
     On the v5e ``jax.lax.ragged_dot`` walks ALL M static rows, tile by
     tile, each with the one or two matrices it needs: the cost follows
     the static row count (not M x G), whatever share of the rows is in a
-    group (measured: PERF.md section 5)."""
+    group, which is why a dispatch hands it the rows of its tier and not
+    its worst case (measured: PERF.md section 5)."""
     return jax.lax.ragged_dot(x, w, sizes.astype(jnp.int32))
+
+
+def _expert_products(weights, xs, sizes, of_row, live, activation, product):
+    """The held experts applied to the rows ``xs`` (M, F), sorted by
+    expert in groups of ``sizes``, with ``product`` as the grouped matrix
+    product (`_grouped_matmul`); ``live`` zeroes the rows behind the last
+    group wherever they enter or leave a grouped product, ``of_row`` is
+    each row's expert (layers with biases only)."""
+    from deeplearning4j_tpu.nn.activations import get_activation
+    act = get_activation(activation)
+    if "Wgate" in weights:
+        mid = act(live(product(xs, weights["Wgate"], sizes))) \
+            * live(product(xs, weights["Wup"], sizes))
+        return live(product(live(mid), weights["Wdown"], sizes))
+    mid = product(xs, weights["W1"], sizes)
+    if of_row is not None:
+        mid = mid + weights["b1"][of_row]
+    ys = product(live(act(live(mid))), weights["W2"], sizes)
+    if of_row is not None:
+        ys = ys + weights["b2"][of_row]
+    return live(ys)
+
+
+def _expert_of_rows(weights, local, e):
+    """Each row's expert among the ``e`` held, from its pair's ``local``
+    expert (a row behind the last group names the last expert and is
+    masked): for the biases of plain experts, None where there are none."""
+    if "Wgate" in weights or "b1" not in weights:
+        return None
+    return jnp.minimum(local, e - 1)
+
+
+def _walk_all(weights, h, w, here, local, order, inverse, sizes, *,
+              activation, product):
+    """A dispatch behind its sort, on every one of its N*k (token, slot)
+    pairs: the last tier, and all a layer that holds every expert runs."""
+    n, k = w.shape
+    e = sizes.shape[0]
+    with jax.named_scope("moe/dispatch"):
+        in_group = (jnp.arange(n * k) < sizes.sum())[:, None]
+        of_row = _expert_of_rows(weights, local[order], e)
+        # the rows behind the last group belong to no expert held
+        # here. A grouped product leaves such rows of its result
+        # UNDEFINED (the TPU's kernel never writes them), forward and
+        # in its transposes, so every tensor of that many rows is
+        # selected to zero where it enters and where it leaves a
+        # product: no undefined row reaches a sum, in either direction
+        live = lambda a: jnp.where(in_group, a, 0).astype(h.dtype)
+        xs = live(_rows_to_experts(h, order, inverse))  # (n*k, F)
+    with jax.named_scope("moe/experts"):
+        ys = _expert_products(weights, xs, sizes, of_row, live, activation,
+                              product)
+    with jax.named_scope("moe/combine"):
+        per_slot = _rows_to_tokens(ys, order, inverse).reshape(n, k, -1)
+        wk = jnp.where(here.reshape(n, k), w, 0)
+        out = jnp.einsum("nkf,nk->nf", per_slot, wk.astype(w.dtype),
+                         preferred_element_type=w.dtype)
+    return out.astype(h.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _switch_remat(branches, index, weights, h, w, *ints):
+    """``jax.lax.switch`` over ``branches(weights, h, w, *ints)`` that
+    keeps no branch's residuals: the backward pass runs the chosen branch
+    again, forward and backward inside ONE branch of a second switch.
+    Differentiated as it stands, a switch returns every branch's
+    residuals from each branch, as zeros from the ones not taken: a small
+    tier would write the full tier's rows (1.1 GB a dispatch at the LM
+    cell's sizes) and the step would hold them all."""
+    return jax.lax.switch(index, branches, weights, h, w, *ints)
+
+
+def _switch_remat_fwd(branches, index, weights, h, w, *ints):
+    return _switch_remat(branches, index, weights, h, w, *ints), \
+        (index, weights, h, w, ints)
+
+
+def _switch_remat_bwd(branches, res, g):
+    index, weights, h, w, ints = res
+    back = lambda branch: lambda g, weights, h, w: jax.vjp(
+        lambda *a: branch(*a, *ints), weights, h, w)[1](g)
+    return (None, *jax.lax.switch(index, [back(b) for b in branches], g,
+                                  weights, h, w), *[None] * len(ints))
+
+
+_switch_remat.defvjp(_switch_remat_fwd, _switch_remat_bwd)
+
+
+# the tiers are jitted, so that the expert layers of a model share one
+# trace of each; the grouped product is an argument of theirs, so that a
+# trace never outlives the `_grouped_matmul` it was made with
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "activation", "product"))
+def _walk(weights, h, w, local, order, sizes, *, rows, activation, product):
+    """A dispatch behind its sort, on the first ``rows`` rows of the
+    expert order, which hold every pair held here (`_dispatch` chose the
+    tier so): the gather, the grouped products and the rows the combine
+    reads are ``rows`` rows."""
+    n, k = w.shape
+    e = sizes.shape[0]
+    with jax.named_scope("moe/dispatch"):
+        pairs = order[:rows]            # the (token, slot) pair of a row
+        row_of = jnp.full((n * k,), rows, jnp.int32).at[pairs].set(
+            jnp.arange(rows, dtype=jnp.int32), unique_indices=True)
+        in_group = (jnp.arange(rows) < sizes.sum())[:, None]
+        of_row = _expert_of_rows(weights, local[pairs], e)
+        live = lambda a: jnp.where(in_group, a, 0).astype(h.dtype)
+        xs = live(_rows_of_pairs(h, pairs, row_of))
+    with jax.named_scope("moe/experts"):
+        ys = _expert_products(weights, xs, sizes, of_row, live, activation,
+                              product)
+    with jax.named_scope("moe/combine"):
+        # a pair behind the last group has a row of zeros (`live`): its
+        # weight reaches no sum and gets no gradient
+        out = _weighed_to_tokens(ys, w, pairs, row_of)
+    return out.astype(h.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("activation", "product"))
+def _walk_whole(weights, h, w, local, order, sizes, *, activation, product):
+    """The last of a layer's tiers: every pair of the dispatch, in
+    `_WHOLE_TIER_PARTS` parts of its tokens, one after the other, each
+    sorted again and walked on all of ITS pairs. A switch reserves the
+    temporaries of its largest branch whichever it takes, so the tier
+    that is there for the worst case may not be what sizes the step."""
+    n, k = w.shape
+    e = sizes.shape[0]
+    parts = next(c for c in range(_WHOLE_TIER_PARTS, 0, -1) if n % c == 0)
+
+    @jax.checkpoint
+    def one(part):
+        h, w, local = part
+        with jax.named_scope("moe/dispatch"):
+            order = jnp.argsort(local, stable=True).astype(jnp.int32)
+            sizes = jnp.bincount(local, length=e + 1)[:e]
+        return _walk(weights, h, w, local, order, sizes, rows=local.size,
+                     activation=activation, product=product)
+
+    return jax.lax.map(one, (h.reshape(parts, -1, h.shape[-1]),
+                             w.reshape(parts, -1, k),
+                             local.reshape(parts, -1))).reshape(h.shape)
 
 
 def _gated_mlp(x, wgate, wup, wdown, activation):
@@ -427,14 +647,23 @@ class MoEFeedForward(LayerConf):
 
     Dispatch has static shapes and drops nothing: the (token, slot) pairs
     are sorted by expert (pairs of experts not held last), the token rows
-    gathered in that order into N*top_k rows — the worst case, every
-    token's every expert held here — and the experts' matrices applied as
-    grouped matrix products over the rows that ARE in a group
-    (`_grouped_matmul`); the rows behind them cost the gather and the
-    combine their bytes and no matrix product. Experts are
+    of the pairs held here gathered in that order, the experts' matrices
+    applied as grouped matrix products over them (`_grouped_matmul`) and
+    the rows summed back to their tokens under the routing weights, in
+    float32. All of it has a static row count, and its cost follows that
+    count, so a layer that holds a share of its experts keeps a ladder of
+    row TIERS (`_ROW_TIERS`: 1/16 and the whole of the dispatch's
+    N*top_k pairs): a dispatch counts the pairs held here, which it has
+    on the device, and walks the smallest tier that holds them
+    (`jax.lax.switch`). The whole, the worst case of every token's every
+    expert held here, is always there to be taken, so no routing drops a
+    pair (a switch walks it in `_WHOLE_TIER_PARTS` parts, because the
+    step reserves the memory of a switch's largest branch whichever runs);
+    a layer that holds ALL its experts has that one tier and no switch.
+    Experts are
     ``act(x W1 + b1) W2 + b2`` or, ``gated``, ``(act(x Wgate) * (x Wup))
     Wdown`` (ReGLU with ``activation="relu"``, SwiGLU with ``"swish"``).
-    Where the worst-case rows of all N tokens (an input, a hidden and an
+    Where the full tier's rows of all N tokens (an input, a hidden and an
     output row each) would pass `_DISPATCH_LIVE_BYTES`, the tokens are
     dispatched in the fewest equal blocks that stay under it, one block
     after another and each rematerialised in the backward pass.
@@ -445,8 +674,11 @@ class MoEFeedForward(LayerConf):
     The layer's state keeps the tokens each of the ``n_experts`` experts
     drew in the last step (``tokens_routed``) and in all steps so far
     (``tokens_routed_total``, uint32: it wraps, so a reader takes
-    differences modulo 2**32); ``train.listeners.ExpertLoadListener``
-    turns them into counters, on every fit path of both containers."""
+    differences modulo 2**32) and, where the layer holds a share of its
+    experts, its dispatches by the tier they walked (``tier_hits``, one
+    count a tier) and the rows those tiers had (``rows_walked_total``),
+    uint32 both; ``train.listeners.ExpertLoadListener`` turns them into
+    counters, on every fit path of both containers."""
     n_out: int = 0
     n_experts: int = 8
     top_k: int = 2
@@ -518,6 +750,9 @@ class MoEFeedForward(LayerConf):
         state = {"tokens_routed": jnp.zeros((self.n_experts,), jnp.int32),
                  "tokens_routed_total": jnp.zeros((self.n_experts,),
                                                   jnp.uint32)}
+        if hi - lo < self.n_experts:
+            state["tier_hits"] = jnp.zeros((len(_ROW_TIERS),), jnp.uint32)
+            state["rows_walked_total"] = jnp.zeros((), jnp.uint32)
         if self.router == "sigmoid":
             state["route_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
         return p, state
@@ -554,12 +789,14 @@ class MoEFeedForward(LayerConf):
         n = h.shape[0]
         blk = n // self._dispatch_blocks(params, h)
         if blk == n:
-            out, routed = self._dispatch(params, h, idx, w)
-            return out.reshape(shape), routed
+            out, counts = self._dispatch(params, h, idx, w)
+            return out.reshape(shape), counts
         weights = {k: v for k, v in params.items()
                    if k != "Wr" and not k.endswith("_s")}
-        one = jax.checkpoint(self._dispatch)
-        out, routed = jax.lax.map(
+        # a dispatch over tiers rematerialises the tier it walked itself
+        one = self._dispatch if len(self._tiers(blk * self.top_k)) > 1 \
+            else jax.checkpoint(self._dispatch)
+        out, counts = jax.lax.map(
             lambda a: one(weights, *a),
             (h.reshape(-1, blk, shape[-1]), idx.reshape(-1, blk, self.top_k),
              w.reshape(-1, blk, self.top_k)))
@@ -567,11 +804,11 @@ class MoEFeedForward(LayerConf):
         # checkpointing keeps this result: its second forward pass does
         # not dispatch again
         out = checkpoint_name(out.reshape(shape), "remat_keep")
-        return out, routed.sum(0)
+        return out, {name: c.sum(0) for name, c in counts.items()}
 
     def _dispatch_blocks(self, params, h):
         """Blocks the (N, F) token rows are dispatched in: the fewest that
-        divide N and keep one block's worst-case rows under
+        divide N and keep the rows of one block's full tier under
         `_DISPATCH_LIVE_BYTES`."""
         n, f = h.shape
         hidden = params["Wdown" if self.gated else "W2"].shape[1]
@@ -579,53 +816,52 @@ class MoEFeedForward(LayerConf):
         most = max(_DISPATCH_LIVE_BYTES // (row * self.top_k), 1)
         return next(g for g in range(-(-n // most), n + 1) if n % g == 0)
 
+    def _tiers(self, pairs):
+        """The rows a dispatch of ``pairs`` (token, slot) pairs may walk,
+        smallest first, the whole last; a layer that holds every expert
+        holds every pair and has the one."""
+        lo, hi = self._held()
+        if hi - lo == self.n_experts:
+            return (pairs,)
+        return tuple(max(pairs // d, 1) for d in _ROW_TIERS)
+
+    @staticmethod
+    def tier_names():
+        """The row tiers as the shares of a dispatch's pairs they are,
+        in the order of the state's ``tier_hits``."""
+        return tuple(f"1/{d}" for d in _ROW_TIERS)
+
     def _dispatch(self, params, h, idx, w):
-        """`experts` for the (N, F) token rows of one dispatch."""
-        from deeplearning4j_tpu.nn.activations import get_activation
+        """`experts` for the (N, F) token rows of one dispatch: the result
+        and what it counted."""
         lo, hi = self._held()
         e = hi - lo
-        k = self.top_k
-        n = h.shape[0]
+        tiers = self._tiers(idx.size)
+        how = dict(activation=self.activation, product=_grouped_matmul)
         with jax.named_scope("moe/dispatch"):
             flat = idx.reshape(-1)
             here = (flat >= lo) & (flat < hi)
             local = jnp.where(here, flat - lo, e)   # not held: behind all
             order = jnp.argsort(local, stable=True).astype(jnp.int32)
-            inverse = jnp.argsort(order).astype(jnp.int32)
+            if len(tiers) == 1:
+                inverse = jnp.argsort(order).astype(jnp.int32)
             sizes = jnp.bincount(local, length=e + 1)[:e]
             routed = jnp.bincount(flat, length=self.n_experts)
-            in_group = (jnp.arange(n * k) < sizes.sum())[:, None]
-            if self.has_bias and not self.gated:
-                of_row = jnp.minimum(local[order], e - 1)
-            # the rows behind the last group belong to no expert held
-            # here. A grouped product leaves such rows of its result
-            # UNDEFINED (the TPU's kernel never writes them), forward and
-            # in its transposes, so every tensor of that many rows is
-            # selected to zero where it enters and where it leaves a
-            # product: no undefined row reaches a sum, in either direction
-            live = lambda a: jnp.where(in_group, a, 0).astype(h.dtype)
-            xs = live(_rows_to_experts(h, order, inverse))  # (n*k, F)
-        with jax.named_scope("moe/experts"):
-            act = get_activation(self.activation)
-            if self.gated:
-                mid = act(live(_grouped_matmul(xs, params["Wgate"], sizes))) \
-                    * live(_grouped_matmul(xs, params["Wup"], sizes))
-            else:
-                mid = _grouped_matmul(xs, params["W1"], sizes)
-                if self.has_bias:
-                    mid = mid + params["b1"][of_row]
-                mid = act(live(mid))
-            ys = _grouped_matmul(
-                live(mid), params["Wdown" if self.gated else "W2"], sizes)
-            if self.has_bias and not self.gated:
-                ys = ys + params["b2"][of_row]
-            ys = live(ys)
-        with jax.named_scope("moe/combine"):
-            per_slot = _rows_to_tokens(ys, order, inverse).reshape(n, k, -1)
-            wk = jnp.where(here.reshape(n, k), w, 0)
-            out = jnp.einsum("nkf,nk->nf", per_slot, wk.astype(w.dtype),
-                             preferred_element_type=w.dtype)
-        return out.astype(h.dtype), routed.astype(jnp.int32)
+        if len(tiers) == 1:
+            out = _walk_all(params, h, w, here, local, order, inverse, sizes,
+                            **how)
+            return out, {"tokens_routed": routed.astype(jnp.int32)}
+        # the pairs held here come first in the expert order, so the
+        # first tier that holds them walks them all: none is dropped
+        tier = jnp.sum(sizes.sum() > jnp.asarray(tiers[:-1]))
+        out = _switch_remat(
+            (*(functools.partial(_walk, rows=m, **how) for m in tiers[:-1]),
+             functools.partial(_walk_whole, **how)),
+            tier, params, h, w, local, order, sizes)
+        return out, {"tokens_routed": routed.astype(jnp.int32),
+                     "tier_hits": (jnp.arange(len(tiers)) == tier)
+                     .astype(jnp.int32),
+                     "rows_walked": jnp.asarray(tiers, jnp.int32)[tier]}
 
     def shared(self, params, h):
         """The shared expert's part: every token, no routing."""
@@ -633,20 +869,26 @@ class MoEFeedForward(LayerConf):
             return _gated_mlp(h, params["Wgate_s"], params["Wup_s"],
                               params["Wdown_s"], self.activation)
 
-    def counted(self, state, routed):
-        """``state`` with the tokens a step routed counted in."""
-        return {**state, "tokens_routed": routed,
-                "tokens_routed_total": state["tokens_routed_total"]
-                + routed.astype(jnp.uint32)}
+    def counted(self, state, counts):
+        """``state`` with what a step's dispatches counted added in."""
+        new = {**state, "tokens_routed": counts["tokens_routed"],
+               "tokens_routed_total": state["tokens_routed_total"]
+               + counts["tokens_routed"].astype(jnp.uint32)}
+        if "tier_hits" in counts:
+            new["tier_hits"] = state["tier_hits"] \
+                + counts["tier_hits"].astype(jnp.uint32)
+            new["rows_walked_total"] = state["rows_walked_total"] \
+                + counts["rows_walked"].astype(jnp.uint32)
+        return new
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        out, routed = self.experts(params, x,
+        out, counts = self.experts(params, x,
                                    *self.route(params, state, x))
         if self.n_shared:
             out = out + self.shared(params, x)
         if mask is not None:
             out = out * mask[..., None].astype(out.dtype)
-        return out, self.counted(state, routed)
+        return out, self.counted(state, counts)
 
 
 @register_layer

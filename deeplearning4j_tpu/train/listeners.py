@@ -10,7 +10,8 @@ Parity with DL4J's TrainingListener/IterationListener framework
 - EvaluativeListener              (periodic held-out evaluation)
 - CheckpointListener              (periodic checkpoints w/ keepLast(n);
                                    checkpoint/CheckpointListener.java:72-144)
-- ExpertLoadListener              (expert layers' routed tokens as counters)
+- ExpertLoadListener              (expert layers' routed tokens and walked
+                                   rows as counters)
 """
 from __future__ import annotations
 
@@ -384,17 +385,23 @@ class ProfilerListener(TrainingListener):
 
 class ExpertLoadListener(TrainingListener):
     """What a model's expert layers (`MoEFeedForward`, alone or as a
-    `TransformerBlock`'s FFN) routed, as counters. The layers count in
-    their own STATE, so every fit path of both containers counts alike;
-    this listener reads the state where ``fit()`` holds a finished one,
-    at the start and the end of an epoch (inside an epoch the pipelined
-    paths have the next chunk in flight, and reading its state would wait
-    for it), and publishes the difference:
+    `TransformerBlock`'s FFN) routed and walked, as counters. The layers
+    count in their own STATE, so every fit path of both containers counts
+    alike; this listener reads the state where ``fit()`` holds a finished
+    one, at the start and the end of an epoch (inside an epoch the
+    pipelined paths have the next chunk in flight, and reading its state
+    would wait for it), and publishes the difference:
     ``moe_tokens_routed_total{layer,held}`` ((token, expert) pairs, by
-    whether the expert is held here) and
+    whether the expert is held here),
     ``moe_expert_load_max_over_mean{layer}`` (the busiest expert's tokens
-    over the mean of all, last step). The state's totals are uint32 and
-    wrap; the difference is taken modulo 2**32."""
+    over the mean of all, last step) and, for a layer that holds a share
+    of its experts, ``moe_dispatch_tier_total{layer,tier}`` (dispatches by
+    the row tier they walked, named by its share of the dispatch's pairs)
+    and ``moe_rows_walked_total{layer}`` (rows those tiers had). The
+    state's totals are uint32 and wrap; the difference is taken modulo
+    2**32."""
+
+    _TOTALS = ("tokens_routed_total", "tier_hits", "rows_walked_total")
 
     def __init__(self):
         self._at_start = {}
@@ -410,11 +417,15 @@ class ExpertLoadListener(TrainingListener):
             if "tokens_routed_total" in state:
                 yield key, layer, state
 
-    def on_epoch_start(self, model, epoch):
+    @classmethod
+    def _totals(cls, state):
         import numpy as np
-        self._at_start = {
-            key: np.asarray(state["tokens_routed_total"], np.int64)
-            for key, _, state in self._layers(model)}
+        return {name: np.asarray(state[name], np.int64)
+                for name in cls._TOTALS if name in state}
+
+    def on_epoch_start(self, model, epoch):
+        self._at_start = {key: self._totals(state)
+                          for key, _, state in self._layers(model)}
 
     def on_epoch_end(self, model, epoch):
         import numpy as np
@@ -427,16 +438,34 @@ class ExpertLoadListener(TrainingListener):
             "moe_expert_load_max_over_mean",
             "tokens of the busiest expert over the mean of all experts, "
             "last step", labels=("layer",))
+        tiers = monitor.counter(
+            "moe_dispatch_tier_total",
+            "dispatches of an expert layer by the row tier they walked "
+            "(its share of the dispatch's token x top_k pairs)",
+            labels=("layer", "tier"))
+        walked = monitor.counter(
+            "moe_rows_walked_total",
+            "rows the dispatches of an expert layer gathered, multiplied "
+            "and summed back: those of the tiers they walked",
+            labels=("layer",))
         for key, layer, state in self._layers(model):
-            total = np.asarray(state["tokens_routed_total"], np.int64)
-            drew = (total - self._at_start.get(key, 0)) % 2 ** 32
+            now = self._totals(state)
+            before = self._at_start.get(key, {})
+            gained = {name: (total - before.get(name, 0)) % 2 ** 32
+                      for name, total in now.items()}
+            drew = gained["tokens_routed_total"]
             lo, hi = layer.experts_held or (0, layer.n_experts)
             held = int(drew[lo:hi].sum())
             routed.inc(held, layer=key, held="yes")
             routed.inc(int(drew.sum()) - held, layer=key, held="no")
             last = np.asarray(state["tokens_routed"], np.float64)
             load.set(float(last.max() / max(last.mean(), 1e-9)), layer=key)
-            self._at_start[key] = total
+            if "tier_hits" in gained:
+                for name, hits in zip(layer.tier_names(),
+                                      gained["tier_hits"]):
+                    tiers.inc(int(hits), layer=key, tier=name)
+                walked.inc(int(gained["rows_walked_total"]), layer=key)
+            self._at_start[key] = now
 
 
 class DivergenceListener(TrainingListener):

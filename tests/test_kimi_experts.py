@@ -1,6 +1,7 @@
 """The expert layer of the hybrid LM: the chip's share, the dispatch, the
 routers and the routing counters; see `_kimi_common.py`."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -99,23 +100,35 @@ def test_no_pair_is_dropped_when_every_token_chooses_held_experts():
                 for e in (0, 1))
     np.testing.assert_allclose(y, dense, atol=1e-5)
     np.testing.assert_array_equal(state["tokens_routed"], [36, 36, 0, 0])
+    # all 72 pairs are held, so the dispatch walked its full tier, and
+    # counted it
+    assert MoEFeedForward.tier_names()[-1] == "1/1"
+    np.testing.assert_array_equal(state["tier_hits"], [0, 1])
+    assert state["tier_hits"].dtype == np.uint32
+    assert int(state["rows_walked_total"]) == 36 * 2
     # the layer has no capacity and no counter of dropped pairs: none can
     assert not any("drop" in k for k in state)
     assert not any("capacity" in f.name
                    for f in dataclasses.fields(MoEFeedForward))
 
 
-def test_undefined_rows_of_a_grouped_product_reach_no_sum(monkeypatch):
+@pytest.mark.parametrize("n_experts,held,tier,rows", [
+    (8, (2, 5), 1, 60), (32, (2, 3), 0, 7)])
+def test_undefined_rows_of_a_grouped_product_reach_no_sum(
+        monkeypatch, n_experts, held, tier, rows):
     """Behind the last group a grouped product's rows are undefined: the
     CPU writes zeros there, the TPU's kernel nothing (whatever the buffer
     held). With NaN in every such row, of the products and of their
     transposes alike, the layer's result and gradients are the same
-    finite numbers."""
+    finite numbers: on the full tier (3 of 8 experts held: the 120 pairs
+    in two parts of 60 rows) and on the small one (1 of 32: 7 rows)."""
     from deeplearning4j_tpu.nn.layers import attention
     real = attention._grouped_matmul
+    poisoned_rows = []
 
     def poison(a, sizes):
         rows = jnp.arange(a.shape[0])[:, None]
+        poisoned_rows.append(a.shape[0])
         return jnp.where(rows < sizes.sum(), a, jnp.nan)
 
     @jax.custom_vjp
@@ -131,19 +144,204 @@ def test_undefined_rows_of_a_grouped_product_reach_no_sum(monkeypatch):
         return poison(dx, sizes), dw, None
 
     poisoned.defvjp(fwd, bwd)
-    ffn = MoEFeedForward(n_out=16, n_experts=8, top_k=3, hidden=8,
+    ffn = MoEFeedForward(n_out=16, n_experts=n_experts, top_k=3, hidden=8,
                          activation="swish", gated=True, has_bias=False,
-                         experts_held=(2, 5), router="sigmoid", n_shared=1)
+                         experts_held=held, router="sigmoid", n_shared=1)
     p, state = ffn.init(jax.random.PRNGKey(0), InputType.recurrent(16, 20))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 20, 16))
     loss = lambda p, x: jnp.sum(ffn.apply(p, state, x)[0] ** 2)
     want = jax.value_and_grad(loss, (0, 1))(p, x)
+    counted = ffn.apply(p, state, x)[1]
+    np.testing.assert_array_equal(counted["tier_hits"], np.arange(2) == tier)
+    assert 0 < counted["tokens_routed"][held[0]:held[1]].sum()
+    # the tiers are jitted with the product as an argument: the trace made
+    # above with the real one is not the poisoned one's
     monkeypatch.setattr(attention, "_grouped_matmul", poisoned)
     got = jax.value_and_grad(loss, (0, 1))(p, x)
+    assert ffn._tiers(120) == (7, 120)
+    assert rows in poisoned_rows
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(a, b, atol=1e-6)
+
+
+# ------------------------------------------------------------------ the tiers
+_TIER_N, _TIER_K = 32, 4          # 128 pairs a dispatch: tiers of 8 and 128
+
+
+@functools.lru_cache(maxsize=None)
+def _tiered_and_whole(router, gated):
+    """A layer that holds 8 of its 16 experts, and two compiled functions
+    of (params, x, idx): the layer's result, counts and gradients under
+    the routing ``idx`` with the router's own weights, through the tiers
+    and through the full tier alone (the parent's dispatch)."""
+    from deeplearning4j_tpu.nn.layers import attention
+    ffn = MoEFeedForward(n_out=16, n_experts=16, top_k=_TIER_K, hidden=8,
+                         activation="swish" if gated else "gelu",
+                         gated=gated, has_bias=not gated,
+                         experts_held=(4, 12), router=router,
+                         routed_scale=1.7)
+    p, state = ffn.init(jax.random.PRNGKey(0),
+                        InputType.recurrent(16, _TIER_N))
+    if not gated:
+        p["b1"] = 0.1 * jax.random.normal(jax.random.PRNGKey(3),
+                                          p["b1"].shape)
+        p["b2"] = 0.1 * jax.random.normal(jax.random.PRNGKey(4),
+                                          p["b2"].shape)
+
+    def run(p, x, idx):
+        def loss(p, x):
+            out, counts = ffn.experts(p, x, idx, ffn.route(p, state, x)[1])
+            return jnp.sum(out * jnp.cos(out)), (out, counts)
+        (_, (out, counts)), grads = jax.value_and_grad(
+            loss, (0, 1), has_aux=True)(p, x)
+        return out, counts, grads
+
+    def whole(p, x, idx):
+        tiers = attention._ROW_TIERS
+        attention._ROW_TIERS = (1,)       # read while tracing
+        try:
+            return run(p, x, idx)
+        finally:
+            attention._ROW_TIERS = tiers
+
+    return ffn, p, jax.jit(run), jax.jit(whole)
+
+
+def _routing_with(live, seed):
+    """(_TIER_N, _TIER_K) expert ids, distinct within a token, of which
+    exactly ``live`` lie among the held experts 4..11."""
+    rng = np.random.default_rng(seed)
+    per_token = np.zeros(_TIER_N, int)
+    for _ in range(live):
+        per_token[rng.choice(np.flatnonzero(per_token < _TIER_K))] += 1
+    held, others = np.arange(4, 12), np.r_[0:4, 12:16]
+    idx = np.stack([rng.permutation(np.r_[
+        rng.choice(held, c, replace=False),
+        rng.choice(others, _TIER_K - c, replace=False)])
+        for c in per_token])
+    assert ((idx >= 4) & (idx < 12)).sum() == live
+    return jnp.asarray(idx, jnp.int32)
+
+
+@pytest.mark.parametrize("live", [0, 1, 7, 8, 9, 63, 64, 65, 128])
+@pytest.mark.parametrize("router,gated", [
+    ("sigmoid", True), ("softmax", True), ("sigmoid", False),
+    ("softmax", False)])
+def test_every_tier_gives_the_full_tiers_result_and_gradients(router, gated,
+                                                              live):
+    """Around the small tier's edge (M - 1, M and M + 1 live pairs of the
+    128), around the edge between the two parts the full tier walks its
+    tokens in, and at both ends, the dispatch walks the smallest tier
+    that holds its pairs, counts it, and gives what the full tier alone
+    gives (the parent's dispatch): the result and the gradients of the
+    inputs, the expert weights and the router."""
+    ffn, p, tiered, whole = _tiered_and_whole(router, gated)
+    assert ffn._tiers(_TIER_N * _TIER_K) == (8, 128)
+    x = jax.random.normal(jax.random.PRNGKey(live), (1, _TIER_N, 16))
+    idx = _routing_with(live, seed=live)
+    out, counts, grads = tiered(p, x, idx)
+    want, base, want_grads = whole(p, x, idx)
+    tier = 0 if live <= 8 else 1
+    np.testing.assert_array_equal(counts["tier_hits"], np.arange(2) == tier)
+    assert int(counts["rows_walked"]) == (8, 128)[tier]
+    assert set(base) == {"tokens_routed"}
+    np.testing.assert_array_equal(counts["tokens_routed"],
+                                  base["tokens_routed"])
+    np.testing.assert_allclose(out, want, atol=2e-6)
+    assert float(jnp.abs(want).max()) > 0.1 or not live
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(grads),
+            jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(a, b, atol=2e-5, err_msg=str(path))
+    assert float(jnp.abs(want_grads[0]["Wr"]).max()) > 1e-3 or not live
+
+
+def _the_parents_apply(ffn, params, state, x):
+    """`MoEFeedForward.apply` of a layer that holds every expert, as the
+    parent of the PR that brought the tiers had it (one dispatch, no
+    shared expert, no mask), statement for statement: what such a layer
+    must still lower to."""
+    from deeplearning4j_tpu.nn.activations import get_activation
+    from deeplearning4j_tpu.nn.layers.attention import (
+        _grouped_matmul, _rows_to_experts, _rows_to_tokens)
+    self = ffn
+    idx, w = self.route(params, state, x)
+    shape = x.shape
+    h = x.reshape(-1, shape[-1])
+    lo, hi = self._held()
+    e = hi - lo
+    k = self.top_k
+    n = h.shape[0]
+    with jax.named_scope("moe/dispatch"):
+        flat = idx.reshape(-1)
+        here = (flat >= lo) & (flat < hi)
+        local = jnp.where(here, flat - lo, e)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        sizes = jnp.bincount(local, length=e + 1)[:e]
+        routed = jnp.bincount(flat, length=self.n_experts)
+        in_group = (jnp.arange(n * k) < sizes.sum())[:, None]
+        if self.has_bias and not self.gated:
+            of_row = jnp.minimum(local[order], e - 1)
+        live = lambda a: jnp.where(in_group, a, 0).astype(h.dtype)
+        xs = live(_rows_to_experts(h, order, inverse))
+    with jax.named_scope("moe/experts"):
+        act = get_activation(self.activation)
+        if self.gated:
+            mid = act(live(_grouped_matmul(xs, params["Wgate"], sizes))) \
+                * live(_grouped_matmul(xs, params["Wup"], sizes))
+        else:
+            mid = _grouped_matmul(xs, params["W1"], sizes)
+            if self.has_bias:
+                mid = mid + params["b1"][of_row]
+            mid = act(live(mid))
+        ys = _grouped_matmul(
+            live(mid), params["Wdown" if self.gated else "W2"], sizes)
+        if self.has_bias and not self.gated:
+            ys = ys + params["b2"][of_row]
+        ys = live(ys)
+    with jax.named_scope("moe/combine"):
+        per_slot = _rows_to_tokens(ys, order, inverse).reshape(n, k, -1)
+        wk = jnp.where(here.reshape(n, k), w, 0)
+        out = jnp.einsum("nkf,nk->nf", per_slot, wk.astype(w.dtype),
+                         preferred_element_type=w.dtype)
+    out, routed = out.astype(h.dtype).reshape(shape), routed.astype(jnp.int32)
+    return out, {**state, "tokens_routed": routed,
+                 "tokens_routed_total": state["tokens_routed_total"]
+                 + routed.astype(jnp.uint32)}
+
+
+@pytest.mark.parametrize("conf", [
+    dict(n_experts=4, top_k=2, mlp_ratio=2),
+    dict(n_experts=6, top_k=3, hidden=8, gated=True, has_bias=False,
+         activation="swish", router="sigmoid", experts_held=(0, 6))])
+def test_a_layer_that_holds_every_expert_lowers_to_the_parents_program(conf):
+    """Such a layer holds every pair of every dispatch: it has one tier,
+    builds no switch, keeps no tier counter and lowers, forward and
+    backward, to the text the parent's layer lowers to."""
+    ffn = MoEFeedForward(n_out=16, **conf)
+    p, state = ffn.init(jax.random.PRNGKey(2), InputType.recurrent(16, 10))
+    assert set(state) - {"route_bias"} == {"tokens_routed",
+                                           "tokens_routed_total"}
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 10, 16))
+
+    def lowered(apply):
+        def program(p, state, x):
+            loss = lambda p, x: (lambda out, new: (jnp.sum(out ** 2), new))(
+                *apply(p, state, x))
+            return jax.value_and_grad(loss, (0, 1), has_aux=True)(p, x)
+        return jax.jit(program).lower(p, state, x).as_text()
+
+    assert lowered(ffn.apply) == lowered(
+        functools.partial(_the_parents_apply, ffn))
+    forward = lambda ffn: jax.jit(ffn.apply).lower(
+        *ffn.init(jax.random.PRNGKey(2), InputType.recurrent(16, 10)),
+        x).as_text()
+    assert "stablehlo.case" not in forward(ffn)
+    assert "stablehlo.case" in forward(
+        dataclasses.replace(ffn, experts_held=(1, 3)))
 
 
 @pytest.mark.parametrize("top_k", [1, 2, 4])
@@ -214,19 +412,40 @@ def test_softmax_router_is_a_softmax_over_the_kept_logits():
 
 
 # --------------------------------------------------------------- the counters
-def _routed(before):
-    """(token, expert) pairs ``moe_tokens_routed_total`` gained since the
-    dump ``before``, by (layer, held)."""
+def _gained(before, family, *labels):
+    """What the counter ``family`` gained since the dump ``before``, by
+    the values of ``labels``."""
     from deeplearning4j_tpu import monitor
     had = {tuple(sorted(s["labels"].items())): s["value"] for s in
-           before.get("moe_tokens_routed_total", {}).get("series", [])}
+           before.get(family, {}).get("series", [])}
     out = {}
-    for s in monitor.dump()["moe_tokens_routed_total"]["series"]:
+    for s in monitor.dump()[family]["series"]:
         key = tuple(sorted(s["labels"].items()))
         gained = s["value"] - had.get(key, 0)
         if gained:
-            out[s["labels"]["layer"], s["labels"]["held"]] = gained
+            out[tuple(s["labels"][name] for name in labels)] = gained
     return out
+
+
+def _routed(before):
+    """(token, expert) pairs ``moe_tokens_routed_total`` gained since the
+    dump ``before``, by (layer, held)."""
+    return _gained(before, "moe_tokens_routed_total", "layer", "held")
+
+
+def _tiers_and_rows(before, state, layer, rows):
+    """The dispatches of ``layer`` since the dump ``before`` by tier, as
+    its state counted and the listener published them, the rows they
+    walked checked against the tiers' ``rows``."""
+    hits = np.asarray(state["tier_hits"], np.int64)
+    assert state["tier_hits"].dtype == np.uint32
+    assert int(state["rows_walked_total"]) == int((hits * rows).sum())
+    tiers = _gained(before, "moe_dispatch_tier_total", "layer", "tier")
+    assert [tiers.get((layer, name), 0)
+            for name in MoEFeedForward.tier_names()] == list(hits)
+    assert _gained(before, "moe_rows_walked_total", "layer")[layer,] \
+        == int(state["rows_walked_total"])
+    return hits
 
 
 @pytest.mark.parametrize("how", [{"scan_steps": 2}, {"scan_steps": 1},
@@ -251,6 +470,11 @@ def test_every_fit_path_counts_every_steps_routing(how):
         total = np.asarray(net.state[layer]["ffn"]["tokens_routed_total"])
         assert total.dtype == np.uint32 and total.sum() == 4 * 256 * 2
         assert got[layer, "yes"] == total[2:6].sum()
+        # 4 batches in 4 dispatches of 64 tokens, 128 pairs each of which
+        # about half are held: every dispatch counts the tier it walked
+        hits = _tiers_and_rows(before, net.state[layer]["ffn"], layer,
+                               (8, 128))
+        assert hits.sum() == 4 * 4 and hits[0] == 0
     # the host's count for layer 2 (rate 0: the weights stay the seed's)
     params = REF.make_params(cfg)
     want = np.zeros(8, np.int64)
@@ -299,6 +523,9 @@ def test_a_graphs_expert_layer_counts_too():
     assert got["moe", "yes"] + got["moe", "no"] == 2 * 3 * 16 * 2
     total = np.asarray(net.state["moe"]["tokens_routed_total"])
     assert got["moe", "yes"] == total[1:3].sum()
+    # one dispatch a step of 48 tokens x 2: tiers of 6 and 96 rows
+    assert _tiers_and_rows(before, net.state["moe"], "moe",
+                           (6, 96)).sum() == 2
 
 
 def test_the_states_total_wraps_and_the_listener_takes_it_modulo():

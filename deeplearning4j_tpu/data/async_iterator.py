@@ -42,7 +42,7 @@ import numpy as np
 
 from deeplearning4j_tpu.util.env import env_flag, env_int
 
-from deeplearning4j_tpu.data.dataset import DataSet
+from deeplearning4j_tpu.data.dataset import DataSet, MultiDataSet
 from deeplearning4j_tpu.data.iterator import DataSetIterator
 
 _SENTINEL = object()
@@ -248,9 +248,31 @@ class AsyncDataSetIterator(DataSetIterator):
         self._source.set_pre_processor(pre_processor)
         return self
 
-    def _stage(self, ds: DataSet) -> DataSet:
+    def _stage(self, ds):
         """Per-batch worker-thread transform: 16-bit host cast, async H2D
-        transfer, then the DataSetCallback seam."""
+        transfer, then the DataSetCallback seam. A `MultiDataSet` (a
+        graph's several inputs, labels and masks) is cast and placed
+        array by array, the same way."""
+        if isinstance(ds, MultiDataSet):
+            parts = [self._place(DataSet(*arrays))
+                     for arrays in itertools.zip_longest(
+                         ds.features, ds.labels, ds.features_masks or (),
+                         ds.labels_masks or ())]
+            pick = lambda name, like: None if like is None else tuple(
+                getattr(part, name) for part in parts[:len(like)])
+            ds = MultiDataSet(
+                pick("features", ds.features), pick("labels", ds.labels),
+                pick("features_mask", ds.features_masks),
+                pick("labels_mask", ds.labels_masks))
+        else:
+            ds = self._place(ds)
+        if self._callback is not None:
+            out = self._callback.call(ds)
+            ds = ds if out is None else out
+        return ds
+
+    def _place(self, ds: DataSet) -> DataSet:
+        """One DataSet's arrays cast on the host and put on the device."""
         if self._cast_dtype is not None:
             ds = DataSet(
                 host_cast(ds.features, self._cast_dtype)
@@ -272,9 +294,6 @@ class AsyncDataSetIterator(DataSetIterator):
                     else jax.device_put(a, dev)
             ds = DataSet(put(ds.features), put(ds.labels),
                          put(ds.features_mask), put(ds.labels_mask))
-        if self._callback is not None:
-            out = self._callback.call(ds)
-            ds = ds if out is None else out
         return ds
 
     def __iter__(self):

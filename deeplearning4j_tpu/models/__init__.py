@@ -24,12 +24,13 @@ from deeplearning4j_tpu.models.zoo import (
     zoo_models,
 )
 from deeplearning4j_tpu.models.transformer import (
-    KimiLinearLM, TransformerLM, TransformerLMMoE,
+    Glm4MoeLiteLM, KimiLinearLM, TransformerLM, TransformerLMMoE,
 )
 
 __all__ = [
     "ZooModel", "LeNet", "SimpleCNN", "AlexNet", "VGG16", "VGG19",
     "ResNet50", "GoogLeNet", "Darknet19", "TinyYOLO", "YOLO2",
     "TextGenerationLSTM", "InceptionResNetV1", "FaceNetNN4Small2", "UNet",
-    "TransformerLM", "TransformerLMMoE", "KimiLinearLM", "model_by_name", "zoo_models",
+    "TransformerLM", "TransformerLMMoE", "KimiLinearLM", "Glm4MoeLiteLM",
+    "model_by_name", "zoo_models",
 ]

@@ -18,6 +18,11 @@ mesh —
 Attention (a chunked gated delta rule) three layers to one of position-free
 latent attention by two layout lists, a dense SwiGLU first layer and then
 sigmoid-routed SwiGLU experts beside a shared expert.
+
+`Glm4MoeLiteLM` is the GLM-4.7-Flash family (``glm4_moe_lite``): rotated
+latent attention with a low-rank query in every layer, the same expert
+layer, and a multi-token-prediction module that shares the embedding and
+the head with the trunk: a `ComputationGraph` with two weighted outputs.
 """
 from __future__ import annotations
 
@@ -220,3 +225,154 @@ class KimiLinearLM(ZooModel):
                                weight_init="normal"))
         b.set_input_type(InputType.recurrent(1, self.seq_length))
         return b.build()
+
+
+@dataclasses.dataclass
+class Glm4MoeLiteLM(ZooModel):
+    """Decoder-only LM of Zhipu's GLM-4.7-Flash family (``model_type``
+    ``glm4_moe_lite``): token embedding -> pre-norm blocks (RMSNorm, no
+    biases) -> RMSNorm -> untied head, sparse cross-entropy over blocks of
+    positions, as a `ComputationGraph` over one input of token ids.
+
+    Every layer attends by `MultiHeadLatentAttention` with a low-rank
+    query (``q_rank``) and rotated positions (``rope_theta``, all
+    ``rope_dim`` dims); the first ``first_k_dense`` layers have a SwiGLU
+    MLP of width ``dense_hidden``, the others the expert layer (sigmoid
+    router over ``n_experts``, ``top_k`` a token, renormalised and scaled
+    by ``routed_scale``, one shared expert; ``experts_held``: the range
+    this chip holds, None for all).
+
+    ``mtp_layers`` (0 or 1, the published ``num_nextn_predict_layers``)
+    adds DeepSeek-V3's multi-token-prediction module, every vertex of it
+    under the scope ``mtp``: for position i, ``W_eh [RMSNorm(Emb(t_{i+1}))
+    ; RMSNorm(h_i)]`` with ``h_i`` the last block's output (before the
+    final norm) and ``Emb`` the TRUNK's embedding (vertex ``mtp_embed``
+    reads the parameters of ``embed``), one more block, a norm, and the
+    TRUNK's head (``mtp_head`` reads ``head``'s) scored against
+    ``t_{i+2}``. The net then has two outputs and `fit()` wants two label
+    arrays with their masks: next tokens (none for a sequence's last
+    position) and next-next tokens (none for the last two); the score is
+    ``L_main + mtp_loss_weight * L_mtp``. With ``mtp_layers=0`` it is the
+    plain trunk with one output.
+
+    Defaults: the published shape cut to widths a CPU test can run;
+    `benchmark/configs/glm-4.7-flash.json` holds the published sizes."""
+    vocab_size: int = 1024
+    seq_length: int = 256
+    n_embd: int = 128
+    n_layers: int = 3
+    n_heads: int = 4
+    q_rank: int = 48
+    kv_rank: int = 32
+    nope_dim: int = 24
+    rope_dim: int = 8
+    v_dim: int = 32
+    rope_theta: float = 1e6
+    first_k_dense: int = 1
+    dense_hidden: int = 512
+    n_experts: int = 16
+    top_k: int = 4
+    expert_hidden: int = 64
+    n_shared: int = 1
+    routed_scale: float = 1.8
+    experts_held: Optional[Tuple[int, int]] = None
+    mtp_layers: int = 1
+    mtp_loss_weight: float = 0.1
+    rms_norm_eps: float = 1e-5
+    learning_rate: float = 1e-4
+    beta1: float = 0.9
+    beta2: float = 0.95
+    epsilon: float = 1e-8
+    weight_decay: float = 0.1
+    compute_dtype: Optional[str] = None
+    gradient_checkpointing: bool = True
+    seed: int = 123
+    block_size: int = 512
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.conf.graph_vertices import (
+            MergeVertex, ShiftTimeSeriesVertex,
+        )
+        from deeplearning4j_tpu.nn.layers.attention import (
+            GatedMLP, LinearProjection,
+        )
+        from deeplearning4j_tpu.nn.layers.linear_attention import (
+            MultiHeadLatentAttention,
+        )
+        if self.mtp_layers not in (0, 1):
+            raise ValueError("mtp_layers: 0 or 1 (the family publishes one "
+                             "multi-token-prediction module)")
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(AdamW(self.learning_rate, beta1=self.beta1,
+                            beta2=self.beta2, epsilon=self.epsilon,
+                            weight_decay=self.weight_decay,
+                            decay_matrices_only=True))
+             .gradient_checkpointing(self.gradient_checkpointing))
+        if self.compute_dtype:
+            b = b.compute_dtype(self.compute_dtype)
+        g = b.graph_builder().add_inputs("ids").set_input_types(
+            InputType.recurrent(1, self.seq_length))
+        mla = MultiHeadLatentAttention(
+            n_out=self.n_embd, n_heads=self.n_heads, nope_dim=self.nope_dim,
+            rope_dim=self.rope_dim, v_dim=self.v_dim, kv_rank=self.kv_rank,
+            q_rank=self.q_rank, rotate=True, rope_theta=self.rope_theta,
+            norm_epsilon=self.rms_norm_eps, block_size=self.block_size,
+            weight_init="normal")
+        dense = GatedMLP(n_out=self.n_embd, hidden=self.dense_hidden,
+                         weight_init="normal")
+        experts = MoEFeedForward(
+            n_out=self.n_embd, n_experts=self.n_experts, top_k=self.top_k,
+            hidden=self.expert_hidden, activation="swish", gated=True,
+            has_bias=False, experts_held=self.experts_held,
+            router="sigmoid", routed_scale=self.routed_scale,
+            n_shared=self.n_shared, weight_init="normal")
+        block = lambda ffn: TransformerBlock(
+            n_out=self.n_embd, n_heads=self.n_heads, norm="rms",
+            norm_epsilon=self.rms_norm_eps, has_bias=False, attn=mla, ffn=ffn)
+        norm = RMSNormLayer(epsilon=self.rms_norm_eps)
+        embed = EmbeddingSequenceLayer(n_out=self.n_embd,
+                                       n_in=self.vocab_size)
+        head = RnnOutputLayer(n_out=self.vocab_size, activation="softmax",
+                              loss="sparse_mcxent", has_bias=False,
+                              weight_init="normal")
+        g.add_layer("embed", embed, "ids")
+        last = "embed"
+        for i in range(self.n_layers):
+            g.add_layer(f"layer{i}",
+                        block(dense if i < self.first_k_dense else experts),
+                        last)
+            last = f"layer{i}"
+        g.add_layer("norm", norm, last)
+        g.add_layer("head", head, "norm")
+        if not self.mtp_layers:
+            return g.set_outputs("head").build()
+        mtp = dict(scope="mtp")
+        g.add_vertex("mtp_ids", ShiftTimeSeriesVertex(steps=1), "ids", **mtp)
+        g.add_layer("mtp_embed", embed, "mtp_ids", params_of="embed", **mtp)
+        g.add_layer("mtp_enorm", norm, "mtp_embed", **mtp)
+        g.add_layer("mtp_hnorm", norm, last, **mtp)
+        g.add_vertex("mtp_merge", MergeVertex(), "mtp_enorm", "mtp_hnorm",
+                     **mtp)
+        g.add_layer("mtp_proj", LinearProjection(
+            n_out=self.n_embd, weight_init="normal"), "mtp_merge", **mtp)
+        g.add_layer("mtp_block", block(experts), "mtp_proj", **mtp)
+        g.add_layer("mtp_norm", norm, "mtp_block", **mtp)
+        g.add_layer("mtp_head", head, "mtp_norm", params_of="head", **mtp)
+        return (g.set_outputs("head", "mtp_head")
+                .set_output_weights(1.0, self.mtp_loss_weight).build())
+
+    @staticmethod
+    def mtp_targets(ids):
+        """The two label arrays and masks `fit()` takes for (B, T) token
+        ids with the module on: ``((next, next-next), (keep, keep2))``,
+        float32 0/1 masks; the last position of a sequence has no next
+        token and the last two no next-next one."""
+        import numpy as np
+        ids = np.asarray(ids)
+        keep = np.ones(ids.shape, np.float32)
+        keep[:, -1:] = 0.0
+        keep2 = np.ones(ids.shape, np.float32)
+        keep2[:, -2:] = 0.0
+        return ((np.roll(ids, -1, axis=1), np.roll(ids, -2, axis=1)),
+                (keep, keep2))

@@ -242,6 +242,26 @@ class ReverseTimeSeriesVertex(GraphVertexConf):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class ShiftTimeSeriesVertex(GraphVertexConf):
+    """A sequence ``steps`` positions ahead of its input: ``y[:, t] =
+    x[:, t + steps]`` on (B, T) ids or (B, T, F), zeros where the input
+    has run out (the last ``steps`` positions, which the consumer's label
+    mask leaves out). What a multi-token-prediction branch embeds: the
+    token after the one the trunk saw."""
+    steps: int = 1
+
+    def output_type(self, *input_types: InputType) -> InputType:
+        return input_types[0]
+
+    def apply(self, *inputs):
+        x = inputs[0]
+        pad = [(0, 0)] * x.ndim
+        pad[1] = (0, self.steps)
+        return jnp.pad(x[:, self.steps:], pad)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class PoolHelperVertex(GraphVertexConf):
     """Strip the first spatial row and column of a CNN activation
     (DL4J nn/conf/graph/PoolHelperVertex.java + impl

@@ -267,9 +267,26 @@ class ListBuilder:
 # ------------------------------------------------------------------- graph
 @dataclasses.dataclass(frozen=True)
 class VertexDef:
-    """One node in the DAG: either a LayerConf or a GraphVertex op."""
+    """One node in the DAG: either a LayerConf or a GraphVertex op.
+    ``params_of`` names another layer vertex whose parameters this one
+    reads instead of holding its own (parameter sharing: a tied embedding,
+    a second head over the first one's matrix): ONE leaf in the net's
+    params and optimizer state, its gradient the sum over every use.
+    ``scope`` is a `jax.named_scope` put on every op of the vertex (and,
+    for an output vertex, of its loss), so that a device trace can tell a
+    branch of the graph apart."""
     vertex: Any                      # LayerConf | GraphVertexConf
     inputs: Tuple[str, ...]
+    params_of: Optional[str] = None
+    scope: Optional[str] = None
+
+    def to_dict(self) -> dict:
+        d = {"vertex": layer_to_dict(self.vertex), "inputs": list(self.inputs)}
+        if self.params_of is not None:
+            d["params_of"] = self.params_of
+        if self.scope is not None:
+            d["scope"] = self.scope
+        return d
 
 
 @dataclasses.dataclass(frozen=True)
@@ -290,6 +307,9 @@ class ComputationGraphConfiguration:
     grad_clip_norm: Optional[float] = None
     grad_clip_value: Optional[float] = None
     gradient_checkpointing: bool = False   # remat per-vertex activations
+    # the weight of each output's loss in the score, in network_outputs'
+    # order; () = every output at 1 (DL4J sums them unweighted)
+    output_weights: Tuple[float, ...] = ()
 
     def topological_order(self) -> List[str]:
         order: List[str] = []
@@ -310,10 +330,8 @@ class ComputationGraphConfiguration:
     def to_dict(self) -> dict:
         return {
             "format": "deeplearning4j_tpu.ComputationGraphConfiguration.v1",
-            "vertices": {
-                name: {"vertex": layer_to_dict(vd.vertex), "inputs": list(vd.inputs)}
-                for name, vd in self.vertices.items()
-            },
+            "vertices": {name: vd.to_dict()
+                         for name, vd in self.vertices.items()},
             "network_inputs": list(self.network_inputs),
             "network_outputs": list(self.network_outputs),
             "input_types": [t.to_dict() for t in self.input_types],
@@ -327,6 +345,8 @@ class ComputationGraphConfiguration:
             "grad_clip_norm": self.grad_clip_norm,
             "grad_clip_value": self.grad_clip_value,
             "gradient_checkpointing": self.gradient_checkpointing,
+            **({"output_weights": list(self.output_weights)}
+               if self.output_weights else {}),
         }
 
     def to_json(self) -> str:
@@ -346,7 +366,9 @@ class ComputationGraphConfiguration:
     def from_dict(d: dict) -> "ComputationGraphConfiguration":
         return ComputationGraphConfiguration(
             vertices={
-                name: VertexDef(layer_from_dict(vd["vertex"]), tuple(vd["inputs"]))
+                name: VertexDef(layer_from_dict(vd["vertex"]),
+                                tuple(vd["inputs"]), vd.get("params_of"),
+                                vd.get("scope"))
                 for name, vd in d["vertices"].items()
             },
             network_inputs=tuple(d["network_inputs"]),
@@ -363,6 +385,7 @@ class ComputationGraphConfiguration:
             grad_clip_norm=d.get("grad_clip_norm"),
             grad_clip_value=d.get("grad_clip_value"),
             gradient_checkpointing=d.get("gradient_checkpointing", False),
+            output_weights=tuple(d.get("output_weights", ())),
         )
 
     @staticmethod
@@ -378,6 +401,7 @@ class GraphBuilder:
         self._vertices: Dict[str, VertexDef] = {}
         self._inputs: Tuple[str, ...] = ()
         self._outputs: Tuple[str, ...] = ()
+        self._output_weights: Tuple[float, ...] = ()
         self._input_types: Tuple[InputType, ...] = ()
         self._backprop_type = "standard"
         self._tbptt_fwd = 20
@@ -391,17 +415,30 @@ class GraphBuilder:
         self._input_types = tuple(types)
         return self
 
-    def add_layer(self, name: str, layer: LayerConf, *inputs: str):
+    def add_layer(self, name: str, layer: LayerConf, *inputs: str,
+                  params_of: Optional[str] = None,
+                  scope: Optional[str] = None):
+        """``params_of``: the layer vertex whose parameters this one reads
+        (it holds none of its own); ``scope``: a `jax.named_scope` on the
+        vertex's ops (`VertexDef`)."""
         self._vertices[name] = VertexDef(
-            _apply_global_defaults(self._parent, layer), tuple(inputs))
+            _apply_global_defaults(self._parent, layer), tuple(inputs),
+            params_of, scope)
         return self
 
-    def add_vertex(self, name: str, vertex, *inputs: str):
-        self._vertices[name] = VertexDef(vertex, tuple(inputs))
+    def add_vertex(self, name: str, vertex, *inputs: str,
+                   scope: Optional[str] = None):
+        self._vertices[name] = VertexDef(vertex, tuple(inputs), None, scope)
         return self
 
     def set_outputs(self, *names: str):
         self._outputs = tuple(names)
+        return self
+
+    def set_output_weights(self, *weights: float):
+        """The weight of each output's loss in the score, in the order of
+        `set_outputs` (default: each at 1)."""
+        self._output_weights = tuple(float(w) for w in weights)
         return self
 
     def backprop_type(self, t: str, fwd_length: int = 20, back_length: int = 20):
@@ -412,6 +449,19 @@ class GraphBuilder:
 
     def build(self) -> ComputationGraphConfiguration:
         p = self._parent
+        if self._output_weights and \
+                len(self._output_weights) != len(self._outputs):
+            raise ValueError(f"{len(self._output_weights)} output weights "
+                             f"for {len(self._outputs)} outputs")
+        for name, vd in self._vertices.items():
+            owner = self._vertices.get(vd.params_of)
+            if vd.params_of is not None and (
+                    owner is None or owner.params_of is not None
+                    or not isinstance(owner.vertex, LayerConf)):
+                raise ValueError(
+                    f"vertex '{name}' shares the parameters of "
+                    f"'{vd.params_of}', which is not a layer vertex "
+                    "holding its own")
         return ComputationGraphConfiguration(
             vertices=dict(self._vertices),
             network_inputs=self._inputs,
@@ -427,4 +477,5 @@ class GraphBuilder:
             grad_clip_norm=p._grad_clip_norm,
             grad_clip_value=p._grad_clip_value,
             gradient_checkpointing=p._gradient_checkpointing,
+            output_weights=self._output_weights,
         )

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import logging
 import time
+import weakref
 from typing import Optional
 
 import jax
@@ -294,17 +295,35 @@ def build_step(net, kind, with_stats=False):
     from deeplearning4j_tpu.nn.regularization import (
         apply_constraints, constraint_map, has_constraints,
     )
+    # the program lives in `net._steps` and traces through the net: it
+    # holds the net weakly, or net and program would keep each other (and
+    # the parameters' device memory) until the collector's next full pass
+    net = weakref.proxy(net)
     tx = net._tx
     layer_map = constraint_map(net)
     constrained = has_constraints(layer_map.values())
     plan = net._plan   # GSPMD plan: sharding constraints in-jit
 
+    # a graph with several outputs returns each output's own loss beside
+    # the score: `loss` is then [score, parts...] (`score_of`)
+    several = len(getattr(net.conf, "network_outputs", ())) > 1
+
     def grads_of(params, state, batch, rng, carries=None):
         inputs, labels, fmasks, lmasks = batch
         def loss_fn(p):
+            if several:
+                score, aux, parts = net._score_parts(
+                    p, state, inputs, labels, fmasks, lmasks, True, rng,
+                    carries)
+                return score, (aux, parts)
             return net._score_fn(p, state, inputs, labels, fmasks, lmasks,
                                  True, rng, carries=carries)
-        return jax.value_and_grad(loss_fn, has_aux=True)(params)
+        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            params)
+        if several:
+            aux, parts = aux
+            loss = jnp.stack([loss, *(p.astype(loss.dtype) for p in parts)])
+        return (loss, aux), grads
 
     def update(params, opt_state, state, grads):
         """The one tail of all three programs."""
@@ -369,7 +388,8 @@ def build_step(net, kind, with_stats=False):
         grads = jax.tree_util.tree_map(lambda g: g / subs.shape[0], gsum)
         new_params, new_opt, state, updates = update(
             params, opt_state, state, grads)
-        return (new_params, new_opt, state, jnp.mean(losses)) + (
+        return (new_params, new_opt, state, jnp.mean(losses, axis=0)
+                if several else jnp.mean(losses)) + (
             (grads, updates) if with_stats else ())
 
     return jax.jit({"step": step, "kstep": kstep, "kaccum": kaccum}[kind],
@@ -419,6 +439,24 @@ def _capturing(net):
             and lst.should_capture(net.iteration_count)]
 
 
+def score_of(net, loss) -> float:
+    """The score in a step's fetched loss. A graph with several outputs
+    returns ``[score, each output's own loss...]`` from its compiled
+    steps (`build_step`), so that one fetch brings them all: the parts
+    go to the gauge ``train_output_loss{output}``, unweighted."""
+    if np.ndim(loss) == 0:
+        return float(loss)
+    from deeplearning4j_tpu import monitor
+    loss = np.asarray(loss)
+    gauge = monitor.gauge(
+        "train_output_loss", "Last training loss of one output of a graph "
+        "with several (unweighted; train_score is their weighted sum)",
+        labels=("output",))
+    for name, part in zip(net.conf.network_outputs, loss[1:]):
+        gauge.set(float(part), output=name)
+    return float(loss[0])
+
+
 def _fit_epoch_per_call(net, batches, rng):
     """One compiled step a batch, with the one budgeted loss fetch a
     step; returns the RNG stream's head."""
@@ -446,8 +484,9 @@ def _fit_epoch_per_call(net, batches, rng):
         goodput.device_wait(loss)
         fetch_start = time.perf_counter()
         monitor.add_span("train/device_wait", sync_start, fetch_start)
-        # graftlint: disable=host-sync-in-hot-path -- the step's ONE budgeted loss fetch (the deliberate per-iteration sync; PERF.md) — bracketed by the train/host_sync span
-        net._score = float(loss)     # the step's one blocking fetch
+        # the step's ONE budgeted loss fetch (the deliberate per-iteration
+        # sync; PERF.md), bracketed by the train/host_sync span
+        net._score = score_of(net, loss)
         step_end = time.perf_counter()
         bs = net._batch_examples(batch)
         monitor.add_span("train/host_sync", fetch_start, step_end)
@@ -507,8 +546,9 @@ def _fit_epoch_scan(net, batches, rng, K):
         _, bs, etl_ms, rec = p
         _observe_chunk(rec, last_sync)
         for loss in arr:
-            # graftlint: disable=host-sync-in-hot-path -- chunk losses are already host-resident (fetch() above IS the deferred chunk sync); this is per-iteration bookkeeping
-            net._score = float(loss)
+            # chunk losses are already host-resident (fetch() above IS the
+            # deferred chunk sync); this is per-iteration bookkeeping
+            net._score = score_of(net, loss)
             _record_iteration(net._score, bs)
             for lst in net.listeners:
                 lst.iteration_done(net, net.iteration_count,
@@ -579,11 +619,11 @@ def _fit_epoch_accum(net, batches, rng, K):
     last_sync = [None]
 
     def fetch(p):
-        return float(p[0])      # the chunk's one blocking fetch
+        return np.asarray(p[0])     # the chunk's one blocking fetch
 
-    def notify(p, score):
+    def notify(p, loss):
         _, bs, etl_ms, capture, grads, updates, rec = p
-        net._score = score
+        net._score = score_of(net, loss)
         _observe_chunk(rec, last_sync)
         _record_iteration(net._score, bs)
         for lst in capture:
@@ -669,8 +709,8 @@ def _fit_tbptt_batch(net, chunks, rng, etl_ms, bs):
         # stop gradient across chunk boundary
         carries = jax.tree_util.tree_map(jax.lax.stop_gradient,
                                          new_carries)
-        # graftlint: disable=host-sync-in-hot-path -- the tbptt chunk's one budgeted loss fetch
-        net._score = float(loss)
+        # the tbptt chunk's one budgeted loss fetch
+        net._score = score_of(net, loss)
         _record_iteration(net._score, bs)
         for lst in net.listeners:
             lst.iteration_done(net, net.iteration_count, net.epoch_count,

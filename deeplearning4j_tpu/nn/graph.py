@@ -12,6 +12,7 @@ across vertices (DL4J walks GraphVertex objects at runtime instead).
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -36,6 +37,12 @@ from deeplearning4j_tpu.nn.updaters import NoOp, build_optimizer
 from deeplearning4j_tpu.util import params as param_util
 
 log = logging.getLogger("deeplearning4j_tpu")
+
+
+def _scoped(scope: Optional[str]):
+    """The `jax.named_scope` a vertex asked for, or nothing."""
+    return contextlib.nullcontext() if scope is None \
+        else jax.named_scope(scope)
 
 
 class ComputationGraph:
@@ -115,6 +122,20 @@ class ComputationGraph:
             need = self._pre_kind[name]
             if need is not None and in_t.kind != need:
                 in_t = preprocessed_type(in_t, need)
+            if vd.params_of is not None:
+                # a sharer holds no parameters: its owner's leaf is the one
+                # leaf (of what a sharer's init makes only the state is kept)
+                init = lambda k, v=vd.vertex, t=in_t: v.init(
+                    k, t, self._param_dtype)
+                shapes = lambda t: jax.tree_util.tree_map(
+                    lambda a: (a.shape, a.dtype), t)
+                if shapes(jax.eval_shape(init, sub)[0]) \
+                        != shapes(params[vd.params_of]):
+                    raise ValueError(
+                        f"vertex '{name}' cannot share the parameters of "
+                        f"'{vd.params_of}': the shapes differ")
+                state[name] = init(sub)[1]
+                continue
             p, s = vd.vertex.init(sub, in_t, self._param_dtype)
             params[name] = p
             state[name] = s
@@ -217,7 +238,12 @@ class ComputationGraph:
         )
         if self._vertex_types is None:
             self._vertex_types = self._resolve_types()
-        params = self._cast_params(params)
+        # under gradient checkpointing a layer casts its own weights
+        # inside its rematerialised region (`_layer_call`)
+        remat = train and self.conf.gradient_checkpointing
+        late_cast = self._cast_params if remat else None
+        if not remat:
+            params = self._cast_params(params)
         acts: Dict[str, Any] = {}
         masks: Dict[str, Any] = {}
         for i, name in enumerate(self.conf.network_inputs):
@@ -232,7 +258,8 @@ class ComputationGraph:
             xs = [acts[i] for i in vd.inputs]
             in_masks = [masks[i] for i in vd.inputs]
             if isinstance(vd.vertex, GraphVertexConf):
-                acts[name] = vd.vertex.apply(*xs)
+                with _scoped(vd.scope):
+                    acts[name] = vd.vertex.apply(*xs)
                 masks[name] = self._vertex_out_mask(
                     vd.vertex, in_masks, xs, self._vertex_types[name])
                 continue
@@ -247,7 +274,9 @@ class ComputationGraph:
             m = in_masks[0] if need == Kind.RNN else None
             if name in out_set:
                 acts["__pre__" + name] = x
-            layer_params = params.get(name, {})
+            layer_params = params.get(vd.params_of or name, {})
+            if remat and getattr(vd.vertex, "weight_noise", None) is not None:
+                layer_params = self._cast_params(layer_params)
             if train and sub_rng is not None and \
                     getattr(vd.vertex, "weight_noise", None) is not None:
                 from deeplearning4j_tpu.nn.regularization import (
@@ -259,19 +288,21 @@ class ComputationGraph:
             # per-vertex jax.checkpoint under gradient_checkpointing:
             # backward recomputes this vertex's activations (HBM for
             # FLOPs); inference forwards are untouched (train only)
-            remat = train and self.conf.gradient_checkpointing
             if carries is not None and _is_stateful_recurrent(vd.vertex):
-                y, carry = _layer_call(
-                    vd.vertex, seq=True, train=train, remat=remat,
-                    params=layer_params, x=x, carry=carries.get(name),
-                    rng=sub_rng, mask=m)
+                with _scoped(vd.scope):
+                    y, carry = _layer_call(
+                        vd.vertex, seq=True, train=train, remat=remat,
+                        params=layer_params, x=x, carry=carries.get(name),
+                        rng=sub_rng, mask=m, cast=late_cast)
                 new_carries[name] = carry
                 new_state[name] = state.get(name, {})
             else:
-                y, s = _layer_call(
-                    vd.vertex, seq=False, train=train, remat=remat,
-                    params=layer_params, x=x, state=state.get(name, {}),
-                    rng=sub_rng, mask=m)
+                with _scoped(vd.scope):
+                    y, s = _layer_call(
+                        vd.vertex, seq=False, train=train, remat=remat,
+                        params=layer_params, x=x,
+                        state=state.get(name, {}), rng=sub_rng, mask=m,
+                        cast=late_cast)
                 new_state[name] = s
             acts[name] = y
             masks[name] = (in_masks[0]
@@ -307,11 +338,24 @@ class ComputationGraph:
     # ------------------------------------------------------------------ fit
     def _score_fn(self, params, state, inputs, labels, fmasks, lmasks, train,
                   rng, carries=None):
+        total, aux, _ = self._score_parts(params, state, inputs, labels,
+                                          fmasks, lmasks, train, rng, carries)
+        return total, aux
+
+    def _score_parts(self, params, state, inputs, labels, fmasks, lmasks,
+                     train, rng, carries=None):
+        """`_score_fn` and, third, each output's own loss (unweighted, in
+        `network_outputs`' order): the score is their sum under the
+        configuration's ``output_weights`` plus the regularization."""
         params_c = self._cast_params(params)
+        # the forward casts the weights itself, a vertex at a time under
+        # gradient checkpointing
         acts, new_state, new_carries, masks = self._forward(
-            params_c, state, inputs, train, rng, fmasks, stash_pre=True,
+            params, state, inputs, train, rng, fmasks, stash_pre=True,
             carries=carries)
+        weights = self.conf.output_weights
         total = jnp.asarray(0.0, jnp.float32)
+        parts = []
         for i, out_name in enumerate(self.conf.network_outputs):
             vd = self.conf.vertices[out_name]
             feat = acts["__pre__" + out_name]
@@ -323,15 +367,19 @@ class ComputationGraph:
                 # mask propagated along THIS output's input path
                 lmask = masks[vd.inputs[0]]
             lab = _as_jnp(labels[i], self._compute_dtype)
-            s = vd.vertex.score(params_c.get(out_name, {}), feat, lab,
-                                train=train, rng=None, mask=lmask)
+            with _scoped(vd.scope):
+                s = vd.vertex.score(
+                    params_c.get(vd.params_of or out_name, {}), feat, lab,
+                    train=train, rng=None, mask=lmask)
             # keep f64 under float64 gradient checking; f32 otherwise
-            total = total + s.astype(jnp.promote_types(jnp.float32, s.dtype))
+            s = s.astype(jnp.promote_types(jnp.float32, s.dtype))
+            parts.append(s)
+            total = total + (s * weights[i] if weights else s)
         for name, p in params.items():
             vd = self.conf.vertices[name]
             if isinstance(vd.vertex, LayerConf):
                 total = total + vd.vertex.regularization_score(p)
-        return total, (new_state, new_carries)
+        return total, (new_state, new_carries), tuple(parts)
 
     def _make_scan_step(self):
         """The scan-of-K compiled step fit() runs (nn/fit_loop.py)."""

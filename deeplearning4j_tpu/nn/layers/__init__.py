@@ -23,7 +23,8 @@ from deeplearning4j_tpu.nn.layers.variational import VariationalAutoencoder
 from deeplearning4j_tpu.nn.layers.samediff import SameDiffLayer, FrozenLayerWrapper
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2OutputLayer
 from deeplearning4j_tpu.nn.layers.attention import (
-    EmbeddingSequenceLayer, GatedMLP, LayerNormLayer, MoEFeedForward,
+    EmbeddingSequenceLayer, GatedMLP, LayerNormLayer, LinearProjection,
+    MoEFeedForward,
     RMSNormLayer, MultiHeadAttention, PositionalEmbeddingLayer,
     TransformerBlock,
 )
@@ -49,7 +50,7 @@ __all__ = [
     "MaskZeroLayer", "VariationalAutoencoder", "SameDiffLayer",
     "FrozenLayerWrapper", "Yolo2OutputLayer",
     "MultiHeadAttention", "TransformerBlock", "MoEFeedForward",
-    "RMSNormLayer", "GatedMLP", "KimiDeltaAttention",
+    "RMSNormLayer", "GatedMLP", "LinearProjection", "KimiDeltaAttention",
     "MultiHeadLatentAttention",
     "LayerNormLayer", "PositionalEmbeddingLayer", "EmbeddingSequenceLayer",
 ]

@@ -161,7 +161,8 @@ def dot_product_attention(q, k, v, *, mask=None, causal=False,
     mask: (B, Tk) 0/1 key-validity mask (DL4J mask semantics).
     q_offset/k_offset: global position offsets (used by ring attention to
     apply causal masking across sequence shards). v's head width may
-    differ from q's and k's (latent attention: 192-wide q.k, 128-wide v);
+    differ from q's and k's (latent attention: 192-wide q.k, 128-wide v,
+    or 256 and 256);
     the scale is that of q's width."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
@@ -450,13 +451,14 @@ _weighed_to_tokens.defvjp(_weighed_to_tokens_fwd, _weighed_to_tokens_bwd)
 #: block whose every pair is held here must still fit
 _DISPATCH_LIVE_BYTES = 512 << 20
 
-#: the row tiers of a dispatch that holds a share of its experts, as
-#: divisors of its tokens x top_k (token, slot) pairs: it walks the first
-#: (smallest) tier that holds the pairs held here, and the whole is last.
-#: One small tier: where 8 of 256 experts are held a block's pairs fill
-#: 37 to 80 % of it, and a middle one (1/4) was compiled into every
-#: switch and never taken (PERF.md section 6, PR 30)
-_ROW_TIERS = (16, 1)
+# The row tiers of a dispatch that holds a share of its experts are the
+# layer's own (`MoEFeedForward._tier_divisors`): ONE small tier sized from
+# the share held, twice the balanced load, and the whole. Where 8 of 256
+# experts are held (divisor 16) a block's pairs fill 37 to 80 % of the
+# small tier, and a middle one (1/4) was compiled into every switch and
+# never taken (PERF.md section 6, PR 30); a layer that holds 8 of 64 would
+# overflow that 1/16 on every dispatch and walk the whole, eight times its
+# live rows, so its small tier is 1/4 (PR 31).
 
 #: the parts of its tokens in which a switch's last tier walks them: in
 #: one part that tier sized the LM step (4.34 GB of temporaries where the
@@ -652,8 +654,10 @@ class MoEFeedForward(LayerConf):
     the rows summed back to their tokens under the routing weights, in
     float32. All of it has a static row count, and its cost follows that
     count, so a layer that holds a share of its experts keeps a ladder of
-    row TIERS (`_ROW_TIERS`: 1/16 and the whole of the dispatch's
-    N*top_k pairs): a dispatch counts the pairs held here, which it has
+    row TIERS (`_tier_divisors`: one small tier of twice the balanced
+    load, ``2 * held / n_experts`` of the dispatch's N*top_k pairs, 1/16
+    where 8 of 256 are held and 1/4 where 8 of 64 are, and the whole): a
+    dispatch counts the pairs held here, which it has
     on the device, and walks the smallest tier that holds them
     (`jax.lax.switch`). The whole, the worst case of every token's every
     expert held here, is always there to be taken, so no routing drops a
@@ -750,8 +754,9 @@ class MoEFeedForward(LayerConf):
         state = {"tokens_routed": jnp.zeros((self.n_experts,), jnp.int32),
                  "tokens_routed_total": jnp.zeros((self.n_experts,),
                                                   jnp.uint32)}
-        if hi - lo < self.n_experts:
-            state["tier_hits"] = jnp.zeros((len(_ROW_TIERS),), jnp.uint32)
+        tiers = len(self._tier_divisors())
+        if tiers > 1:
+            state["tier_hits"] = jnp.zeros((tiers,), jnp.uint32)
             state["rows_walked_total"] = jnp.zeros((), jnp.uint32)
         if self.router == "sigmoid":
             state["route_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
@@ -816,20 +821,25 @@ class MoEFeedForward(LayerConf):
         most = max(_DISPATCH_LIVE_BYTES // (row * self.top_k), 1)
         return next(g for g in range(-(-n // most), n + 1) if n % g == 0)
 
+    def _tier_divisors(self):
+        """The layer's row tiers as divisors of a dispatch's pairs,
+        smallest tier first, the whole (1) last. The small tier is twice
+        the balanced load of the share held: ``n_experts // (2 * held)``
+        (16 for 8 of 256, 4 for 8 of 64); a layer that holds every expert,
+        or so many that twice its load is the whole, has the one tier."""
+        lo, hi = self._held()
+        small = self.n_experts // (2 * (hi - lo))
+        return (small, 1) if small > 1 else (1,)
+
     def _tiers(self, pairs):
         """The rows a dispatch of ``pairs`` (token, slot) pairs may walk,
-        smallest first, the whole last; a layer that holds every expert
-        holds every pair and has the one."""
-        lo, hi = self._held()
-        if hi - lo == self.n_experts:
-            return (pairs,)
-        return tuple(max(pairs // d, 1) for d in _ROW_TIERS)
+        smallest first, the whole last."""
+        return tuple(max(pairs // d, 1) for d in self._tier_divisors())
 
-    @staticmethod
-    def tier_names():
+    def tier_names(self):
         """The row tiers as the shares of a dispatch's pairs they are,
         in the order of the state's ``tier_hits``."""
-        return tuple(f"1/{d}" for d in _ROW_TIERS)
+        return tuple(f"1/{d}" for d in self._tier_divisors())
 
     def _dispatch(self, params, h, idx, w):
         """`experts` for the (N, F) token rows of one dispatch: the result
@@ -919,6 +929,28 @@ class GatedMLP(LayerConf):
         with jax.named_scope("mlp/gated"):
             return _gated_mlp(x, params["Wgate"], params["Wup"],
                               params["Wdown"], self.activation), state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class LinearProjection(LayerConf):
+    """Bias-free linear map of the feature axis, (..., F) -> (..., n_out),
+    at every position of a sequence (what joins two merged streams back
+    to the model's width)."""
+    n_out: int = 0
+    weight_init: str = "xavier"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType(input_type.kind,
+                         tuple(input_type.shape[:-1]) + (self.n_out,))
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        f_in = input_type.features
+        return {"W": get_initializer(self.weight_init)(
+            key, (f_in, self.n_out), f_in, self.n_out, dtype)}, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return x @ params["W"], state
 
 
 @register_layer

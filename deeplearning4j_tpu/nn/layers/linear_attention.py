@@ -26,15 +26,19 @@ head) pairs go through in groups, each rematerialised, so that only one
 group's temporaries are alive.
 
 `MultiHeadLatentAttention` (MLA) is DeepSeek's latent attention in the
-expanded form used for training, with no positional rotation
-(``mla_use_nope``): q of 128 + 64 a head, a 512 + 64 latent whose first
-part is normed and expanded to 128-wide k and v a head, the last 64 shared
-by all heads as the rest of k.
+expanded form used for training: q of ``nope + rope`` a head, projected
+directly or through a normed low-rank latent (``q_rank``), a
+``kv_rank + rope`` latent whose first part is normed and expanded to k and
+v a head, the last ``rope`` dims shared by all heads as the rest of k.
+Those dims are carried as they are (``rotate=False``: Kimi-Linear's
+``mla_use_nope``, 128 + 64 / 128) or rotated by the token's position
+(``rotate=True``: the GLM / DeepSeek families, 192 + 64 / 256).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +48,9 @@ from deeplearning4j_tpu.nn.conf.base import (
     InputType, Kind, LayerConf, register_layer,
 )
 from deeplearning4j_tpu.nn.initializers import get_initializer
-from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
+from deeplearning4j_tpu.nn.layers.attention import (
+    dot_product_attention, rope,
+)
 from deeplearning4j_tpu.ops.kda_chunk import chunk_algebra
 from deeplearning4j_tpu.util.platform import is_tpu_backend
 
@@ -271,22 +277,43 @@ class KimiDeltaAttention(LayerConf):
         return y, state
 
 
+def rope_pairs(x, positions, theta):
+    """Rotary positions on the last axis of x (B, T, H, D), all D dims,
+    the pairs (2j, 2j+1) rotated by ``positions[t] * theta^(-2j/D)`` (the
+    interleaved layout of the GLM / DeepSeek latent attentions). The
+    result holds the rotated pairs' first members in its first half and
+    the second members in its second: one fixed permutation of the dims,
+    the same in q and in k, which no score ``q . k`` can see, and which
+    saves putting the pairs back side by side. So it is `rope` (which
+    pairs dim j with dim j + D/2) on the de-interleaved dims."""
+    return rope(jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1),
+                positions, theta)
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class MultiHeadLatentAttention(LayerConf):
-    """Latent attention (MLA) over (B, T, F), expanded form, causal, with
-    no positions: ``q = Wq x`` split ``nope_dim + rope_dim`` a head;
-    ``[c; k_r] = Wkva x`` (``kv_rank + rope_dim``), ``c <- RMSNorm(c)``,
-    ``[k_nope; v] = Wkvb c`` (``nope_dim + v_dim`` a head), ``k = [k_nope;
-    k_r]`` with ``k_r`` shared by the heads; ``softmax(q k^T / sqrt(nope_dim
-    + rope_dim)) v``; ``Wo``. The ``rope_dim`` part is carried and NOT
-    rotated. On a TPU the fused flash kernel runs it (two head sizes)."""
+    """Latent attention (MLA) over (B, T, F), expanded form, causal:
+    ``q = Wq x`` or, with ``q_rank``, ``q = Wqb RMSNorm(Wqa x)``, split
+    ``nope_dim + rope_dim`` a head; ``[c; k_r] = Wkva x`` (``kv_rank +
+    rope_dim``), ``c <- RMSNorm(c)``, ``[k_nope; v] = Wkvb c`` (``nope_dim
+    + v_dim`` a head), ``k = [k_nope; k_r]`` with ``k_r`` shared by the
+    heads; ``softmax(q k^T / sqrt(nope_dim + rope_dim)) v``; ``Wo``.
+    ``rotate`` False carries the ``rope_dim`` part as it is (no positions
+    at all); True rotates it, in q and in the shared ``k_r``, by the
+    token's position (`rope_pairs`: RoPE at base ``rope_theta`` over ALL
+    ``rope_dim`` dims, the pairs (2j, 2j+1) as the family lays them out).
+    On a TPU the fused flash kernel runs the attention at the layer's
+    head sizes (192/128, 256/256, ...)."""
     n_out: int = 0
     n_heads: int = 8
     nope_dim: int = 128
     rope_dim: int = 64
     v_dim: int = 128
     kv_rank: int = 512
+    q_rank: Optional[int] = None
+    rotate: bool = False
+    rope_theta: float = 10000.0
     norm_epsilon: float = 1e-5
     block_size: int = 512
     weight_init: str = "xavier"
@@ -297,10 +324,15 @@ class MultiHeadLatentAttention(LayerConf):
     def init(self, key, input_type: InputType, dtype=jnp.float32):
         f, h = input_type.features, self.n_heads
         w_init = get_initializer(self.weight_init)
-        ks = jax.random.split(key, 4)
+        ks = list(jax.random.split(key, 4)) + [jax.random.fold_in(key, 4)]
         mat = lambda i, fi, fo: w_init(ks[i], (fi, fo), fi, fo, dtype)
+        qk = h * (self.nope_dim + self.rope_dim)
+        query = {"Wq": mat(0, f, qk)} if self.q_rank is None else {
+            "Wqa": mat(0, f, self.q_rank),
+            "q_norm": jnp.ones((self.q_rank,), dtype),
+            "Wqb": mat(4, self.q_rank, qk)}
         return {
-            "Wq": mat(0, f, h * (self.nope_dim + self.rope_dim)),
+            **query,
             "Wkva": mat(1, f, self.kv_rank + self.rope_dim),
             "kv_norm": jnp.ones((self.kv_rank,), dtype),
             "Wkvb": mat(2, self.kv_rank, h * (self.nope_dim + self.v_dim)),
@@ -311,13 +343,27 @@ class MultiHeadLatentAttention(LayerConf):
         b, t, _ = x.shape
         h = self.n_heads
         with jax.named_scope("mla/proj"):
-            q = (x @ params["Wq"]).reshape(b, t, h, -1)
+            if self.q_rank is None:
+                q = x @ params["Wq"]
+            else:
+                q = _rms(x @ params["Wqa"], params["q_norm"],
+                         self.norm_epsilon).astype(x.dtype) @ params["Wqb"]
+            q = q.reshape(b, t, h, -1)
             ckr = x @ params["Wkva"]
             c = _rms(ckr[..., :self.kv_rank], params["kv_norm"],
                      self.norm_epsilon).astype(x.dtype)
             kv = (c @ params["Wkvb"]).reshape(b, t, h, -1)
-            k_r = jnp.broadcast_to(ckr[:, :, None, self.kv_rank:],
-                                   (b, t, h, self.rope_dim))
+            k_r = ckr[:, :, None, self.kv_rank:]
+        if self.rotate:
+            with jax.named_scope("mla/rope"):
+                at = jnp.arange(t)
+                q = jnp.concatenate(
+                    [q[..., :self.nope_dim],
+                     rope_pairs(q[..., self.nope_dim:], at, self.rope_theta)],
+                    axis=-1)
+                k_r = rope_pairs(k_r, at, self.rope_theta)
+        with jax.named_scope("mla/proj"):
+            k_r = jnp.broadcast_to(k_r, (b, t, h, self.rope_dim))
             k = jnp.concatenate([kv[..., :self.nope_dim], k_r], axis=-1)
             v = kv[..., self.nope_dim:]
         with jax.named_scope("mla/attn"):

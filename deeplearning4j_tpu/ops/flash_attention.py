@@ -11,8 +11,10 @@ registers/VMEM, MXU does the two matmuls back to back).
 
 Semantics match dot_product_attention exactly (tested):
 - (B, T, H, D) layout, f32 accumulation, 1/sqrt(D) scaling; v (and the
-  output) may have another head width than q and k (latent attention:
-  192-wide q.k, 128-wide v);
+  output) may have another head width than q and k, and any widths the
+  blocks fit VMEM at (the latent attentions: 192-wide q.k with 128-wide
+  v, position-free; 256-wide q.k with 256-wide v, rotated: both compile
+  for the v5e at blocks of 512, `tests/test_tpu_lowering.py`);
 - optional causal masking; key blocks wholly above the diagonal are
   neither fetched nor computed, forward and backward;
 - optional (B, Tk) 0/1 key-validity mask, fully-masked query rows emit 0;

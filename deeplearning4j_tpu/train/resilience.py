@@ -787,6 +787,7 @@ class ResilientTrainer:
         """One guarded optimizer step. Returns (status, loss, batch_size)
         with status in {"applied", "skipped"}; raises _Unrecoverable when
         the consecutive-skip threshold trips."""
+        from deeplearning4j_tpu.nn.fit_loop import score_of
         policy = self.policy
         snap = self._driver.snapshot() if policy.guards_steps else None
         attempt = 0
@@ -808,7 +809,7 @@ class ResilientTrainer:
                 fetch_start = time.perf_counter()
                 monitor.add_span("train/device_wait", wait_start,
                                  fetch_start)
-                loss_f = float(loss)
+                loss_f = score_of(self._driver.net, loss)
                 step_end = time.perf_counter()
                 step_secs = step_end - attempt_start
                 monitor.add_span("train/host_sync", fetch_start, step_end)

@@ -182,6 +182,8 @@ def build_memory_report(net, batch_size: int,
             out_t = types[name]
             in_t = types[vd.inputs[0]]
             act = batch_size * out_t.flat_size * dtype_size
+            # a vertex that reads another's parameters (`params_of`)
+            # holds none: a shared leaf is counted once, at its owner
             p_bytes = _tree_bytes(net.params.get(name, {}))
             scratch = _scratch_bytes(vd.vertex, in_t, out_t, batch_size,
                                      dtype_size)

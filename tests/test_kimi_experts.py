@@ -87,7 +87,7 @@ def test_no_pair_is_dropped_when_every_token_chooses_held_experts():
     """A router of zeros ties every score: every token takes experts 0 and
     1, both held, so every one of the N*k rows is in a group (the worst
     case the dispatch is sized for) and the result is the dense sum."""
-    ffn = MoEFeedForward(n_out=16, n_experts=4, top_k=2, hidden=8,
+    ffn = MoEFeedForward(n_out=16, n_experts=8, top_k=2, hidden=8,
                          activation="swish", gated=True, has_bias=False,
                          experts_held=(0, 2), router="sigmoid",
                          routed_scale=2.0)
@@ -99,10 +99,12 @@ def test_no_pair_is_dropped_when_every_token_chooses_held_experts():
                              * (x @ p["Wup"][e])) @ p["Wdown"][e]
                 for e in (0, 1))
     np.testing.assert_allclose(y, dense, atol=1e-5)
-    np.testing.assert_array_equal(state["tokens_routed"], [36, 36, 0, 0])
-    # all 72 pairs are held, so the dispatch walked its full tier, and
+    np.testing.assert_array_equal(state["tokens_routed"],
+                                  [36, 36, 0, 0, 0, 0, 0, 0])
+    # all 72 pairs are held, so the dispatch walked its full tier (the
+    # small one, twice the balanced load of 2 experts in 8, is half), and
     # counted it
-    assert MoEFeedForward.tier_names()[-1] == "1/1"
+    assert ffn.tier_names() == ("1/2", "1/1")
     np.testing.assert_array_equal(state["tier_hits"], [0, 1])
     assert state["tier_hits"].dtype == np.uint32
     assert int(state["rows_walked_total"]) == 36 * 2
@@ -113,15 +115,17 @@ def test_no_pair_is_dropped_when_every_token_chooses_held_experts():
 
 
 @pytest.mark.parametrize("n_experts,held,tier,rows", [
-    (8, (2, 5), 1, 60), (32, (2, 3), 0, 7)])
+    (16, (2, 5), 1, 60), (32, (2, 3), 0, 7)])
 def test_undefined_rows_of_a_grouped_product_reach_no_sum(
         monkeypatch, n_experts, held, tier, rows):
     """Behind the last group a grouped product's rows are undefined: the
     CPU writes zeros there, the TPU's kernel nothing (whatever the buffer
     held). With NaN in every such row, of the products and of their
     transposes alike, the layer's result and gradients are the same
-    finite numbers: on the full tier (3 of 8 experts held: the 120 pairs
-    in two parts of 60 rows) and on the small one (1 of 32: 7 rows)."""
+    finite numbers: on the full tier (3 of 16 experts held and a choice
+    bent towards them, so that the held pairs pass the small tier's 60
+    rows: the 120 pairs in two parts of 60 rows) and on the small one (1
+    of 32: 7 rows)."""
     from deeplearning4j_tpu.nn.layers import attention
     real = attention._grouped_matmul
     poisoned_rows = []
@@ -148,6 +152,9 @@ def test_undefined_rows_of_a_grouped_product_reach_no_sum(
                          activation="swish", gated=True, has_bias=False,
                          experts_held=held, router="sigmoid", n_shared=1)
     p, state = ffn.init(jax.random.PRNGKey(0), InputType.recurrent(16, 20))
+    if tier:        # most tokens choose the held experts: the whole tier
+        state["route_bias"] = state["route_bias"].at[held[0]:held[1]].set(
+            1.0)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 20, 16))
     loss = lambda p, x: jnp.sum(ffn.apply(p, state, x)[0] ** 2)
     want = jax.value_and_grad(loss, (0, 1))(p, x)
@@ -158,7 +165,7 @@ def test_undefined_rows_of_a_grouped_product_reach_no_sum(
     # above with the real one is not the poisoned one's
     monkeypatch.setattr(attention, "_grouped_matmul", poisoned)
     got = jax.value_and_grad(loss, (0, 1))(p, x)
-    assert ffn._tiers(120) == (7, 120)
+    assert ffn._tiers(120) == ((60, 120) if tier else (7, 120))
     assert rows in poisoned_rows
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
@@ -172,12 +179,12 @@ _TIER_N, _TIER_K = 32, 4          # 128 pairs a dispatch: tiers of 8 and 128
 
 @functools.lru_cache(maxsize=None)
 def _tiered_and_whole(router, gated):
-    """A layer that holds 8 of its 16 experts, and two compiled functions
+    """A layer that holds 8 of its 256 experts, and two compiled functions
     of (params, x, idx): the layer's result, counts and gradients under
     the routing ``idx`` with the router's own weights, through the tiers
     and through the full tier alone (the parent's dispatch)."""
     from deeplearning4j_tpu.nn.layers import attention
-    ffn = MoEFeedForward(n_out=16, n_experts=16, top_k=_TIER_K, hidden=8,
+    ffn = MoEFeedForward(n_out=16, n_experts=256, top_k=_TIER_K, hidden=8,
                          activation="swish" if gated else "gelu",
                          gated=gated, has_bias=not gated,
                          experts_held=(4, 12), router=router,
@@ -199,12 +206,12 @@ def _tiered_and_whole(router, gated):
         return out, counts, grads
 
     def whole(p, x, idx):
-        tiers = attention._ROW_TIERS
-        attention._ROW_TIERS = (1,)       # read while tracing
+        divisors = MoEFeedForward._tier_divisors
+        MoEFeedForward._tier_divisors = lambda self: (1,)  # while tracing
         try:
             return run(p, x, idx)
         finally:
-            attention._ROW_TIERS = tiers
+            MoEFeedForward._tier_divisors = divisors
 
     return ffn, p, jax.jit(run), jax.jit(whole)
 
@@ -341,7 +348,7 @@ def test_a_layer_that_holds_every_expert_lowers_to_the_parents_program(conf):
         x).as_text()
     assert "stablehlo.case" not in forward(ffn)
     assert "stablehlo.case" in forward(
-        dataclasses.replace(ffn, experts_held=(1, 3)))
+        dataclasses.replace(ffn, experts_held=(1, 2)))
 
 
 @pytest.mark.parametrize("top_k", [1, 2, 4])
@@ -442,7 +449,7 @@ def _tiers_and_rows(before, state, layer, rows):
     assert int(state["rows_walked_total"]) == int((hits * rows).sum())
     tiers = _gained(before, "moe_dispatch_tier_total", "layer", "tier")
     assert [tiers.get((layer, name), 0)
-            for name in MoEFeedForward.tier_names()] == list(hits)
+            for name in ("1/2", "1/1")] == list(hits)
     assert _gained(before, "moe_rows_walked_total", "layer")[layer,] \
         == int(state["rows_walked_total"])
     return hits
@@ -458,7 +465,9 @@ def test_every_fit_path_counts_every_steps_routing(how):
     routing."""
     from deeplearning4j_tpu import monitor
     from deeplearning4j_tpu.train.listeners import ExpertLoadListener
-    net, cfg = _net(learning_rate=0.0, weight_decay=0.0)
+    # 4 held of 16 routed over: the small tier is half the pairs (4 of 8,
+    # the files' common size, has the whole tier alone)
+    net, cfg = _net(learning_rate=0.0, weight_decay=0.0, router_experts=16)
     net.set_listeners(ExpertLoadListener())
     before = monitor.dump()
     rows = _rows(6, 4)
@@ -471,13 +480,14 @@ def test_every_fit_path_counts_every_steps_routing(how):
         assert total.dtype == np.uint32 and total.sum() == 4 * 256 * 2
         assert got[layer, "yes"] == total[2:6].sum()
         # 4 batches in 4 dispatches of 64 tokens, 128 pairs each of which
-        # about half are held: every dispatch counts the tier it walked
+        # about a quarter are held: every dispatch counts the tier it
+        # walked
         hits = _tiers_and_rows(before, net.state[layer]["ffn"], layer,
-                               (8, 128))
-        assert hits.sum() == 4 * 4 and hits[0] == 0
+                               (64, 128))
+        assert hits.sum() == 4 * 4 and hits[0] > 0
     # the host's count for layer 2 (rate 0: the weights stay the seed's)
     params = REF.make_params(cfg)
-    want = np.zeros(8, np.int64)
+    want = np.zeros(16, np.int64)
     for r, _ in rows:
         x = params["0"]["W"][jnp.asarray(REF.decode_tokens(cfg, r))]
         h = REF.layer(cfg, params["1"], x, KINDS[0])
@@ -486,7 +496,7 @@ def test_every_fit_path_counts_every_steps_routing(how):
             h, p2["ln1"]["gamma"], 1e-5), "highest")
         n = REF._rms(h, p2["ln2"]["gamma"], 1e-5).reshape(-1, 32)
         want += np.bincount(np.asarray(REF.routing(cfg, p2["ffn"], n)[0])
-                            .ravel(), minlength=8)
+                            .ravel(), minlength=16)
     np.testing.assert_array_equal(
         np.asarray(net.state["2"]["ffn"]["tokens_routed_total"]), want)
     # a second epoch publishes its own steps and no more
@@ -508,7 +518,7 @@ def test_a_graphs_expert_layer_counts_too():
                       .updater(Adam(1e-2)))
          .add_inputs("tokens").set_input_types(InputType.recurrent(1, 16)))
     g.add_layer("emb", EmbeddingSequenceLayer(n_in=12, n_out=16), "tokens")
-    g.add_layer("moe", MoEFeedForward(n_out=16, n_experts=4, top_k=2,
+    g.add_layer("moe", MoEFeedForward(n_out=16, n_experts=8, top_k=2,
                                       hidden=8, experts_held=(1, 3)), "emb")
     g.add_layer("head", RnnOutputLayer(n_out=12, activation="softmax",
                                        loss="mcxent"), "moe")
@@ -523,9 +533,9 @@ def test_a_graphs_expert_layer_counts_too():
     assert got["moe", "yes"] + got["moe", "no"] == 2 * 3 * 16 * 2
     total = np.asarray(net.state["moe"]["tokens_routed_total"])
     assert got["moe", "yes"] == total[1:3].sum()
-    # one dispatch a step of 48 tokens x 2: tiers of 6 and 96 rows
+    # one dispatch a step of 48 tokens x 2: tiers of 48 and 96 rows
     assert _tiers_and_rows(before, net.state["moe"], "moe",
-                           (6, 96)).sum() == 2
+                           (48, 96)).sum() == 2
 
 
 def test_the_states_total_wraps_and_the_listener_takes_it_modulo():

@@ -159,10 +159,14 @@ class TestFlashKernelCompiles:
         for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
             assert kernel in hlo
 
+    @pytest.mark.parametrize("heads,d_qk,d_v", [(32, 192, 128),
+                                                (20, 256, 256)])
     @pytest.mark.parametrize("backward", [True, False])
-    def test_two_head_sizes_at_the_lm_cells_widths(self, v5e, backward):
-        # latent attention's expanded form: 32 heads, q.k 192 wide and v
-        # 128, 8,192 positions, block 512
+    def test_two_head_sizes_at_the_lm_cells_widths(self, v5e, backward,
+                                                   heads, d_qk, d_v):
+        # latent attention's expanded form at the two LM cells' widths:
+        # 32 heads of 192-wide q.k and 128-wide v (position-free), 20 of
+        # 256 / 256 (rotated); 8,192 positions, block 512
         from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
         def loss(q, k, v):
@@ -170,8 +174,8 @@ class TestFlashKernelCompiles:
                 q, k, v, causal=True, block_q=512, block_k=512,
                 interpret=False).astype(jnp.float32) ** 2)
 
-        qk = ((1, 8192, 32, 192), jnp.bfloat16)
-        v = ((1, 8192, 32, 128), jnp.bfloat16)
+        qk = ((1, 8192, heads, d_qk), jnp.bfloat16)
+        v = ((1, 8192, heads, d_v), jnp.bfloat16)
         hlo = _compile_v5e(
             jax.grad(loss, argnums=(0, 1, 2)) if backward else loss,
             self._one(v5e), qk, qk, v)

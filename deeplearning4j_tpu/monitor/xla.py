@@ -259,6 +259,13 @@ def _register_families():
                   "PartitionSpecs (GSPMD plan), 0 when replicated/"
                   "single-device",
                   labels=("program", "fingerprint"))
+    metrics.gauge("xla_program_kernel_calls",
+                  "Compiled instructions of the program that call one of "
+                  "the Pallas kernels of ops/ (an instruction is named "
+                  "after its kernel: flash_fwd.133), from the record's "
+                  "op_scopes; kernels the program does not call are not "
+                  "set",
+                  labels=("program", "kernel"))
     metrics.counter("xla_analysis_unavailable_total",
                     "cost/memory analysis probes that degraded (backend "
                     "capability missing, not a lowering bug), by kind",
@@ -413,6 +420,26 @@ def compiled_op_scopes(compiled) -> Dict[str, str]:
     return dict(_OP_NAME.findall(text or ""))
 
 
+#: the Pallas kernels of ops/, by the name their `pallas_call` gives the
+#: compiled instruction (`flash_fwd.133`, `kda_chunk_bwd.37`)
+PALLAS_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                  "kda_chunk_fwd", "kda_chunk_bwd")
+
+
+def kernel_calls(op_scopes: Dict[str, str]) -> Dict[str, int]:
+    """{kernel: instructions of a compiled program that call it}, from the
+    program's instruction names (the keys of `op_scopes`); a kernel the
+    program does not call is left out. What a rematerialised block runs a
+    second time shows here: two `flash_fwd` a `flash_bwd_dq` before the
+    kernel's results were kept (`ops.REMAT_KEEP`), one since."""
+    calls: Dict[str, int] = {}
+    for name in op_scopes:
+        kernel = name.split(".", 1)[0]
+        if kernel in PALLAS_KERNELS:
+            calls[kernel] = calls.get(kernel, 0) + 1
+    return calls
+
+
 def analyze_compiled(compiled):
     """(flops, bytes_accessed, hbm dict) from a jax.stages.Compiled —
     None for whatever the backend cannot answer. The ONE place the XLA
@@ -525,6 +552,10 @@ def capture(name: str, fn, args, domain: str = "train",
                   labels=("program", "fingerprint")).set(
         1.0 if rec.is_sharded else 0.0, program=name,
         fingerprint=fingerprint)
+    for kernel, n in kernel_calls(rec.op_scopes).items():
+        metrics.gauge("xla_program_kernel_calls",
+                      labels=("program", "kernel")).set(
+            n, program=name, kernel=kernel)
     return rec
 
 

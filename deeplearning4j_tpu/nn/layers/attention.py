@@ -37,6 +37,7 @@ from deeplearning4j_tpu.nn.conf.base import (
     InputType, Kind, LayerConf, register_layer,
 )
 from deeplearning4j_tpu.nn.initializers import get_initializer
+from deeplearning4j_tpu.ops import REMAT_KEEP
 from deeplearning4j_tpu.util.platform import is_tpu_backend
 
 # --- context-parallel mode -------------------------------------------------
@@ -808,7 +809,7 @@ class MoEFeedForward(LayerConf):
         # a block rematerialised under the containers' gradient
         # checkpointing keeps this result: its second forward pass does
         # not dispatch again
-        out = checkpoint_name(out.reshape(shape), "remat_keep")
+        out = checkpoint_name(out.reshape(shape), REMAT_KEEP)
         return out, {name: c.sum(0) for name, c in counts.items()}
 
     def _dispatch_blocks(self, params, h):

@@ -51,6 +51,7 @@ from deeplearning4j_tpu.nn.initializers import get_initializer
 from deeplearning4j_tpu.nn.layers.attention import (
     dot_product_attention, rope,
 )
+from deeplearning4j_tpu.ops import REMAT_KEEP
 from deeplearning4j_tpu.ops.kda_chunk import chunk_algebra
 from deeplearning4j_tpu.util.platform import is_tpu_backend
 
@@ -270,7 +271,7 @@ class KimiDeltaAttention(LayerConf):
             # a block rematerialised under the containers' gradient
             # checkpointing keeps the recurrence's result, so that its
             # second forward pass does not run the recurrence again
-            o = checkpoint_name(o, "remat_keep")
+            o = checkpoint_name(o, REMAT_KEEP)
         with jax.named_scope("kda/out"):
             o = _rms(o, params["o_norm"], self.norm_epsilon) * heads(gate)
             y = o.reshape(b, t, h * d).astype(x.dtype) @ params["Wo"]

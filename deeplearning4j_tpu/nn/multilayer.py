@@ -39,6 +39,7 @@ from deeplearning4j_tpu.nn.fit_loop import (
     _as_jnp, _fit_tbptt_batch, _stage_with_affine,
 )
 from deeplearning4j_tpu.nn.updaters import NoOp, build_optimizer
+from deeplearning4j_tpu.ops import REMAT_KEEP
 from deeplearning4j_tpu.util import params as param_util
 
 log = logging.getLogger("deeplearning4j_tpu")
@@ -108,11 +109,12 @@ def _layer_call(layer, *, seq, train, remat, params, x, state=None,
             return _l.apply(cast(lp), st, xx, train=train, rng=rr, mask=mm)
         args = (params, state, x, rng, mask)
     if remat:
-        # nothing is kept but what a layer names "remat_keep" (a result
-        # far dearer to make again than to hold: a recurrence's output)
+        # nothing is kept but what a layer or a kernel names REMAT_KEEP (a
+        # result far dearer to make again than to hold: a recurrence's
+        # output, the flash forward's output and log-sum-exp)
         fn = jax.checkpoint(
             fn, policy=jax.checkpoint_policies.save_only_these_names(
-                "remat_keep"))
+                REMAT_KEEP))
     return fn(*args)
 
 

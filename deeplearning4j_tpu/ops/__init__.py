@@ -7,6 +7,13 @@ attention (`flash_attention`) and Kimi Delta Attention's chunk algebra
 backends the kernels run in interpret mode (tests) or the callers fall
 back to the XLA path.
 """
-from deeplearning4j_tpu.ops.flash_attention import flash_attention
+#: the one name the containers' gradient checkpointing keeps
+#: (`nn/multilayer.py::_layer_call`: `save_only_these_names(REMAT_KEEP)`):
+#: a result far dearer to make again than to hold carries it through
+#: `checkpoint_name`, in a layer or, for a kernel's backward residuals,
+#: here. Defined before the kernels' modules, which import it.
+REMAT_KEEP = "remat_keep"
 
-__all__ = ["flash_attention"]
+from deeplearning4j_tpu.ops.flash_attention import flash_attention  # noqa: E402
+
+__all__ = ["REMAT_KEEP", "flash_attention"]

@@ -21,7 +21,11 @@ Semantics match dot_product_attention exactly (tested):
 - backward pass: true flash backward — two Pallas passes (dq over key
   blocks; dk/dv over query blocks) recomputing the probabilities from
   the saved per-row log-sum-exp, so the score matrix never materializes
-  in either direction; cross-attention shapes (tq != tk) included.
+  in either direction; cross-attention shapes (tq != tk) included. The
+  two residuals that are the forward kernel's own results (output and
+  log-sum-exp) carry the containers' keep-name (`ops.REMAT_KEEP`): a
+  block rematerialised under gradient checkpointing holds them and does
+  not run the forward kernel a second time.
 
 Off-TPU the kernel runs under `interpret=True` (numerically identical,
 slow); on a TPU it compiles or the call raises.
@@ -33,9 +37,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.ops import REMAT_KEEP
 from deeplearning4j_tpu.util.platform import is_tpu_backend
 
 NEG = -1e30
@@ -198,6 +204,13 @@ def _flash(q, k, v, mask, causal, block_q, block_k, interpret):
 def _flash_fwd(q, k, v, mask, causal, block_q, block_k, interpret):
     out, lse = _flash_call(q, k, v, mask, causal, block_q, block_k,
                            interpret)
+    # the two residuals that are the kernel's own results: a block
+    # rematerialised under the containers' gradient checkpointing keeps
+    # them, so that its second forward pass does not run the kernel again
+    # (q, k and v it makes again from the projections). The identity
+    # outside a `jax.checkpoint` and under one with no policy.
+    out = checkpoint_name(out, REMAT_KEEP)
+    lse = checkpoint_name(lse, REMAT_KEEP)
     return (out, lse), (q, k, v, mask, out, lse)
 
 

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
-from deeplearning4j_tpu.ops import flash_attention
+from deeplearning4j_tpu.ops import REMAT_KEEP, flash_attention
 
 
 def _qkv(b=2, t=48, h=4, d=16, seed=0, dtype="float32"):
@@ -280,3 +280,100 @@ def test_flash_block_size_shape_matrix(shape, bq, bk):
         ref = dot_product_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
+
+
+# ---- a rematerialised block keeps the kernel's output and log-sum-exp
+
+
+def _two_layers(causal, with_mask, t, checkpointed, return_lse=False):
+    """loss(q, k, v, mask) of two stacked "layers" around flash_attention,
+    each under the containers' checkpoint (`nn/multilayer.py::_layer_call`)
+    or bare. With return_lse the log-sum-exp carries a cotangent too."""
+    def layer(x, k, v, mask):
+        got = flash_attention(jnp.tanh(x), k, v, causal=causal,
+                              mask=mask if with_mask else None,
+                              block_q=16, block_k=16, return_lse=return_lse)
+        if return_lse:
+            out, lse = got
+            return out * jnp.cos(lse)[..., None]
+        return got
+
+    if checkpointed:
+        layer = jax.checkpoint(
+            layer, policy=jax.checkpoint_policies.save_only_these_names(
+                REMAT_KEEP))
+
+    def loss(q, k, v, mask):
+        return jnp.sum(layer(layer(q, k, v, mask), k, v, mask) ** 2)
+
+    q, k, v = _qkv(t=t, seed=20)
+    mask = np.ones((2, t), np.float32)
+    mask[1, t - 7:] = 0.0
+    return loss, (q, k, v, jnp.asarray(mask))
+
+
+def _kernel_calls(fn, args):
+    """{kernel name: Pallas calls} in the traced fn, every use of a
+    shared sub-jaxpr counted (the printed text shows one only once)."""
+    calls = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                calls[name] = calls.get(name, 0) + 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return calls
+
+
+@pytest.mark.parametrize("return_lse", [False, True])
+@pytest.mark.parametrize("causal,with_mask,t", [
+    (True, False, 48), (False, True, 48), (True, True, 40),
+    (False, False, 40)])   # 40: padded to the block of 16
+def test_checkpointed_block_runs_the_forward_kernel_once(causal, with_mask,
+                                                         t, return_lse):
+    """Under the containers' policy the second forward of a block holds no
+    `flash_fwd`: as many forward calls as layers, as many backward; the
+    gradients are bit for bit those of the bare function."""
+    kept, args = _two_layers(causal, with_mask, t, True, return_lse)
+    bare, _ = _two_layers(causal, with_mask, t, False, return_lse)
+    grad = lambda f: jax.grad(f, argnums=(0, 1, 2))
+    once = {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
+    assert _kernel_calls(grad(kept), args) == once
+    assert _kernel_calls(grad(bare), args) == once
+    for a, b in zip(jax.jit(grad(kept))(*args), jax.jit(grad(bare))(*args)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_checkpoint_that_keeps_nothing_runs_the_forward_twice():
+    """What the name spares: under a checkpoint with no policy it is the
+    identity and each block's backward pass runs `flash_fwd` again."""
+    bare, args = _two_layers(True, False, 48, False)
+    assert _kernel_calls(jax.grad(jax.checkpoint(bare)), args) == {
+        "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
+
+
+@pytest.mark.parametrize("return_lse", [False, True])
+def test_without_a_checkpoint_the_name_adds_no_op(return_lse, monkeypatch):
+    """Outside `jax.checkpoint` the lowered text is what it was before the
+    residuals carried a name: the same text with the name taken away (but
+    for the counter MLIR appends to a private function's symbol)."""
+    import re
+    import sys
+    module = sys.modules["deeplearning4j_tpu.ops.flash_attention"]
+    grad = lambda f: jax.grad(f, argnums=(0, 1, 2))
+
+    def text():
+        loss, args = _two_layers(True, True, 40, False, return_lse)
+        return re.sub(r"@(\w+?)_\d+\b", r"@\1",
+                      jax.jit(grad(loss)).lower(*args).as_text())
+
+    named = text()
+    calls = []
+    monkeypatch.setattr(module, "checkpoint_name",
+                        lambda x, name: calls.append(name) or x)
+    assert text() == named
+    assert calls == [REMAT_KEEP] * 4        # out and lse, two layers

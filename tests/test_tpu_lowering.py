@@ -183,6 +183,51 @@ class TestFlashKernelCompiles:
                        if backward else ("flash_fwd",)):
             assert kernel in hlo
 
+    @pytest.mark.parametrize("hidden,widths", [
+        (2304, dict(n_heads=32, nope_dim=128, rope_dim=64, v_dim=128)),
+        (2048, dict(n_heads=20, nope_dim=192, rope_dim=64, v_dim=256,
+                    q_rank=768, rotate=True))])
+    def test_checkpointed_latent_attention_runs_the_forward_once(
+            self, v5e, hidden, widths, monkeypatch):
+        # the latent attention of each LM cell (32 x 192/128 position-free;
+        # 20 x 256/256 rotated with a low-rank query) inside the
+        # containers' rematerialised layer call: the kernel's output and
+        # log-sum-exp are kept, so the gradient holds ONE flash_fwd
+        import re
+        import sys
+
+        from deeplearning4j_tpu.nn.conf.base import InputType
+        from deeplearning4j_tpu.nn.layers.linear_attention import (
+            MultiHeadLatentAttention,
+        )
+        from deeplearning4j_tpu.nn.multilayer import _layer_call
+        for name in ("ops.flash_attention", "nn.layers.linear_attention"):
+            monkeypatch.setattr(sys.modules["deeplearning4j_tpu." + name],
+                                "is_tpu_backend", lambda: True)
+        layer = MultiHeadLatentAttention(n_out=hidden, kv_rank=512,
+                                         **widths)
+        shapes = jax.eval_shape(
+            lambda key: layer.init(key, InputType.recurrent(hidden, 8192),
+                                   jnp.bfloat16)[0], jax.random.PRNGKey(0))
+
+        def loss(params, x):
+            y, _ = _layer_call(layer, seq=False, train=True, remat=True,
+                               params=params, x=x, state={})
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        one = self._one(v5e)
+        place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one)
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jax.tree_util.tree_map(place, shapes),
+            place(jax.ShapeDtypeStruct((1, 8192, hidden), jnp.bfloat16))
+        ).compile().as_text()
+        kernels = re.findall(
+            r"^\s*(?:ROOT\s+)?%?(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call",
+            hlo, re.M)
+        assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                   "flash_fwd"]
+
     def test_expert_grouped_products_compile_as_ragged_dot(self, v5e):
         # the expert layer's grouped product at the cell's widths (one
         # dispatch block's worst-case rows): XLA's own tiled kernel over

@@ -91,6 +91,43 @@ def test_capture_reads_cost_and_memory_analysis_on_cpu():
                    fingerprint=rec.fingerprint) == rec.hbm_peak_bytes
 
 
+@pytest.mark.parametrize("names,calls", [
+    # a rematerialised block that runs the forward kernel again: two
+    # flash_fwd instructions a backward pair
+    (("flash_fwd.22", "flash_fwd.23", "flash_bwd_dq.11", "flash_bwd_dkv.11",
+      "fusion.7", "pallas_call.300"),
+     {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}),
+    (("kda_chunk_fwd.1", "kda_chunk_fwd.2", "kda_chunk_bwd.37", "flash_fwd",
+      "flash_fwd_like.3", "ragged-dot-none.3"),
+     {"kda_chunk_fwd": 2, "kda_chunk_bwd": 1, "flash_fwd": 1}),
+    (("fusion.1", "copy.2"), {}), ((), {})])
+def test_kernel_calls_counts_instructions_by_their_kernels_name(names, calls):
+    assert xla_ledger.kernel_calls(dict.fromkeys(names, "")) == calls
+
+
+def test_capture_publishes_the_programs_kernel_calls(monkeypatch):
+    """On a TPU a Pallas call's instruction is named after its kernel; here
+    the instruction table is planted."""
+    monkeypatch.setattr(
+        xla_ledger, "compiled_op_scopes", lambda compiled: {
+            "flash_fwd.1": "jit(f)/mla/attn/flash_fwd/pallas_call",
+            "flash_bwd_dq.1": "jit(f)/transpose(mla/attn)/flash_bwd_dq",
+            "flash_bwd_dkv.1": "jit(f)/transpose(mla/attn)/flash_bwd_dkv",
+            "fusion.1": "jit(f)/add"})
+    xla_ledger.enable_ledger()
+    xla_ledger.capture("t/prog", jax.jit(lambda x: x + 1),
+                       (np.ones(3, "float32"),))
+    g = monitor.REGISTRY.collect("xla_program_kernel_calls")
+    assert [g.value(program="t/prog", kernel=k) for k in
+            ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")] == [1, 1, 1]
+    # a program without a kernel sets no series
+    monkeypatch.setattr(xla_ledger, "compiled_op_scopes", lambda c: {})
+    xla_ledger.capture("t/plain", jax.jit(lambda x: x * 2),
+                       (np.ones(3, "float32"),))
+    series = monitor.dump()["xla_program_kernel_calls"]["series"]
+    assert {s["labels"]["program"] for s in series} == {"t/prog"}
+
+
 def test_disabled_ledger_is_a_noop():
     f = jax.jit(lambda x: x + 1)
     assert xla_ledger.capture("t/p", f, (np.ones(3, "float32"),)) is None

@@ -24,7 +24,7 @@ from deeplearning4j_tpu.models.zoo import (
     zoo_models,
 )
 from deeplearning4j_tpu.models.transformer import (
-    Glm4MoeLiteLM, KimiLinearLM, TransformerLM, TransformerLMMoE,
+    Glm4MoeLiteLM, KimiLinearLM, Lfm2MoeLM, TransformerLM, TransformerLMMoE,
 )
 
 __all__ = [
@@ -32,5 +32,6 @@ __all__ = [
     "ResNet50", "GoogLeNet", "Darknet19", "TinyYOLO", "YOLO2",
     "TextGenerationLSTM", "InceptionResNetV1", "FaceNetNN4Small2", "UNet",
     "TransformerLM", "TransformerLMMoE", "KimiLinearLM", "Glm4MoeLiteLM",
+    "Lfm2MoeLM",
     "model_by_name", "zoo_models",
 ]

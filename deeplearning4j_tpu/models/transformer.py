@@ -23,6 +23,13 @@ sigmoid-routed SwiGLU experts beside a shared expert.
 latent attention with a low-rank query in every layer, the same expert
 layer, and a multi-token-prediction module that shares the embedding and
 the head with the trunk: a `ComputationGraph` with two weighted outputs.
+
+`Lfm2MoeLM` is the LFM2 mixture-of-experts family (``lfm2_moe``): gated
+short convolutions three layers to one of grouped-query attention (q/k
+normed, rotated) by the published list of layer types, leading dense SwiGLU
+layers and then sigmoid-routed SwiGLU experts with NO shared expert, the
+head tied to the embedding: a `ComputationGraph` whose head reads the
+embedding's leaf.
 """
 from __future__ import annotations
 
@@ -376,3 +383,115 @@ class Glm4MoeLiteLM(ZooModel):
         keep2[:, -2:] = 0.0
         return ((np.roll(ids, -1, axis=1), np.roll(ids, -2, axis=1)),
                 (keep, keep2))
+
+
+@dataclasses.dataclass
+class Lfm2MoeLM(ZooModel):
+    """Decoder-only LM of Liquid AI's LFM2 mixture-of-experts family
+    (``model_type`` ``lfm2_moe``): token embedding -> pre-norm blocks
+    (RMSNorm, no biases) -> RMSNorm -> a head TIED to the embedding
+    (``logits = h E^T``: vertex ``head`` reads the parameters of
+    ``embed``, one leaf in the params and in the updater's state), sparse
+    cross-entropy over blocks of positions, as a `ComputationGraph` over
+    one input of token ids.
+
+    ``layer_types`` names each layer's operator as the published
+    config.json does: ``"conv"`` is a `GatedShortConv` of ``conv_kernel``
+    taps, ``"full_attention"`` a `MultiHeadAttention` of ``n_heads`` query
+    heads on ``n_kv_heads`` key/value heads, q and k RMS-normed over the
+    head width before the rotation (``rope_theta``, the halves of a head
+    rotated against each other), causal, on the fused kernel where there
+    is a TPU. The first ``num_dense_layers`` layers have a SwiGLU MLP of
+    width ``dense_hidden``, the others the expert layer: a sigmoid router
+    over ``n_experts``, ``top_k`` a token chosen by the scores plus a
+    non-trained bias, weights renormalised and scaled by ``routed_scale``,
+    no shared expert; ``experts_held`` is the range of experts this chip
+    holds (None: all), and a token none of whose experts is held gets
+    exactly zero from the layer.
+
+    Defaults: the published shape cut to widths a CPU test can run;
+    `benchmark/configs/lfm2-24b-a2b.json` holds the published sizes."""
+    vocab_size: int = 1024
+    seq_length: int = 256
+    n_embd: int = 128
+    layer_types: Tuple[str, ...] = ("conv", "full_attention", "conv", "conv",
+                                    "conv")
+    n_heads: int = 8
+    n_kv_heads: int = 2
+    conv_kernel: int = 3
+    rope_theta: float = 1e6
+    num_dense_layers: int = 1
+    dense_hidden: int = 512
+    n_experts: int = 16
+    top_k: int = 4
+    expert_hidden: int = 64
+    routed_scale: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None
+    rms_norm_eps: float = 1e-5
+    learning_rate: float = 1e-4
+    beta1: float = 0.9
+    beta2: float = 0.95
+    epsilon: float = 1e-8
+    weight_decay: float = 0.1
+    compute_dtype: Optional[str] = None
+    gradient_checkpointing: bool = True
+    seed: int = 123
+    block_size: int = 512
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers.attention import (
+            GatedMLP, MultiHeadAttention,
+        )
+        from deeplearning4j_tpu.nn.layers.linear_attention import (
+            GatedShortConv,
+        )
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(AdamW(self.learning_rate, beta1=self.beta1,
+                            beta2=self.beta2, epsilon=self.epsilon,
+                            weight_decay=self.weight_decay,
+                            decay_matrices_only=True))
+             .gradient_checkpointing(self.gradient_checkpointing))
+        if self.compute_dtype:
+            b = b.compute_dtype(self.compute_dtype)
+        g = b.graph_builder().add_inputs("ids").set_input_types(
+            InputType.recurrent(1, self.seq_length))
+        operators = {
+            "conv": GatedShortConv(n_out=self.n_embd,
+                                   conv_kernel=self.conv_kernel,
+                                   weight_init="normal"),
+            "full_attention": MultiHeadAttention(
+                n_out=self.n_embd, n_heads=self.n_heads,
+                n_kv_heads=self.n_kv_heads, causal=True, use_rope=True,
+                rope_base=self.rope_theta, qk_norm=True,
+                norm_epsilon=self.rms_norm_eps, has_bias=False,
+                attention_impl="flash", block_size=self.block_size,
+                weight_init="normal"),
+        }
+        dense = GatedMLP(n_out=self.n_embd, hidden=self.dense_hidden,
+                         weight_init="normal")
+        experts = MoEFeedForward(
+            n_out=self.n_embd, n_experts=self.n_experts, top_k=self.top_k,
+            hidden=self.expert_hidden, activation="swish", gated=True,
+            has_bias=False, experts_held=self.experts_held,
+            router="sigmoid", routed_scale=self.routed_scale, n_shared=0,
+            weight_init="normal")
+        g.add_layer("embed", EmbeddingSequenceLayer(
+            n_out=self.n_embd, n_in=self.vocab_size), "ids")
+        last = "embed"
+        for i, kind in enumerate(self.layer_types):
+            if kind not in operators:
+                raise ValueError(f"layer_types[{i}] = {kind!r}: 'conv' or "
+                                 "'full_attention'")
+            g.add_layer(f"layer{i}", TransformerBlock(
+                n_out=self.n_embd, n_heads=self.n_heads, norm="rms",
+                norm_epsilon=self.rms_norm_eps, has_bias=False,
+                attn=operators[kind],
+                ffn=dense if i < self.num_dense_layers else experts), last)
+            last = f"layer{i}"
+        g.add_layer("norm", RMSNormLayer(epsilon=self.rms_norm_eps), last)
+        g.add_layer("head", RnnOutputLayer(
+            n_out=self.vocab_size, activation="softmax",
+            loss="sparse_mcxent", has_bias=False, tied_embedding=True),
+            "norm", params_of="embed")
+        return g.set_outputs("head").build()

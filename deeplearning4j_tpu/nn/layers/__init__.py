@@ -29,7 +29,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
     TransformerBlock,
 )
 from deeplearning4j_tpu.nn.layers.linear_attention import (
-    KimiDeltaAttention, MultiHeadLatentAttention,
+    GatedShortConv, KimiDeltaAttention, MultiHeadLatentAttention,
 )
 
 __all__ = [
@@ -51,6 +51,7 @@ __all__ = [
     "FrozenLayerWrapper", "Yolo2OutputLayer",
     "MultiHeadAttention", "TransformerBlock", "MoEFeedForward",
     "RMSNormLayer", "GatedMLP", "LinearProjection", "KimiDeltaAttention",
+    "GatedShortConv",
     "MultiHeadLatentAttention",
     "LayerNormLayer", "PositionalEmbeddingLayer", "EmbeddingSequenceLayer",
 ]

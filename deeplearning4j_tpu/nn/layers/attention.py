@@ -201,15 +201,28 @@ class MultiHeadAttention(LayerConf):
     """Multi-head self-attention over (B, T, F).
 
     n_out: model width (must divide by n_heads). causal: autoregressive
-    masking. use_rope: rotary positions (otherwise positions come from an
-    embedding layer upstream). Masks follow DL4J semantics: (B, T) 0/1,
+    masking. use_rope: rotary positions at base ``rope_base``, the halves
+    of a head rotated against each other (otherwise positions come from an
+    embedding layer upstream). ``n_kv_heads`` (None: ``n_heads``) is
+    grouped-query attention: ``Wk`` and ``Wv`` are ``(f_in, n_kv_heads *
+    head_dim)`` and query head ``h`` reads key/value head ``h // (n_heads
+    // n_kv_heads)``; the fused kernel reads k and v at their own head
+    count, every other path repeats them. ``qk_norm`` adds an RMSNorm over
+    the head width with a learned gain of ``head_dim`` each to q
+    (``q_norm``) and to k (``k_norm``), every head alike, BEFORE the
+    rotation (``norm_epsilon``). Masks follow DL4J semantics: (B, T) 0/1,
     masked steps neither attend nor get attended to, and their outputs are
-    zeroed (MaskZeroLayer behavior)."""
+    zeroed (MaskZeroLayer behavior). Scopes: ``mha/proj``, ``mha/norm``,
+    ``mha/rope``, ``mha/attn``."""
     n_out: int = 0
     n_heads: int = 8
     n_in: Optional[int] = None
     causal: bool = False
     use_rope: bool = True
+    n_kv_heads: Optional[int] = None
+    qk_norm: bool = False
+    norm_epsilon: float = 1e-5          # of the q/k norms
+    rope_base: float = 10000.0
     attention_dropout: float = 0.0
     weight_init: str = "xavier"
     has_bias: bool = False
@@ -235,20 +248,31 @@ class MultiHeadAttention(LayerConf):
                 f"rotary embeddings need an even head dim; got "
                 f"{self.n_out // self.n_heads} (n_out={self.n_out}, "
                 f"n_heads={self.n_heads}) — disable use_rope or resize")
+        if self.n_heads % self._kv_heads():
+            raise ValueError(f"n_kv_heads {self.n_kv_heads} does not divide "
+                             f"n_heads {self.n_heads}")
         f_in = self.n_in or input_type.features
+        kv = self._kv_heads() * (self.n_out // self.n_heads)
         w_init = get_initializer(self.weight_init)
         ks = jax.random.split(key, 4)
         p = {
             "Wq": w_init(ks[0], (f_in, self.n_out), f_in, self.n_out, dtype),
-            "Wk": w_init(ks[1], (f_in, self.n_out), f_in, self.n_out, dtype),
-            "Wv": w_init(ks[2], (f_in, self.n_out), f_in, self.n_out, dtype),
+            "Wk": w_init(ks[1], (f_in, kv), f_in, kv, dtype),
+            "Wv": w_init(ks[2], (f_in, kv), f_in, kv, dtype),
             "Wo": w_init(ks[3], (self.n_out, self.n_out), self.n_out,
                          self.n_out, dtype),
         }
         if self.has_bias:
-            for b in ("bq", "bk", "bv", "bo"):
-                p[b] = jnp.zeros((self.n_out,), dtype)
+            for b, n in (("bq", self.n_out), ("bk", kv), ("bv", kv),
+                         ("bo", self.n_out)):
+                p[b] = jnp.zeros((n,), dtype)
+        if self.qk_norm:
+            for g in ("q_norm", "k_norm"):
+                p[g] = jnp.ones((self.n_out // self.n_heads,), dtype)
         return p, {}
+
+    def _kv_heads(self):
+        return self.n_kv_heads or self.n_heads
 
     def _qkv(self, params, x):
         q = x @ params["Wq"]
@@ -256,21 +280,28 @@ class MultiHeadAttention(LayerConf):
         v = x @ params["Wv"]
         if self.has_bias:
             q, k, v = q + params["bq"], k + params["bk"], v + params["bv"]
-        h = self.n_heads
-        return _split_heads(q, h), _split_heads(k, h), _split_heads(v, h)
+        h, hk = self.n_heads, self._kv_heads()
+        return _split_heads(q, h), _split_heads(k, hk), _split_heads(v, hk)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         attn_rng = None
         if rng is not None:
             rng, attn_rng = jax.random.split(rng)
         x = self.maybe_dropout_input(x, train, rng)
-        q, k, v = self._qkv(params, x)
+        with jax.named_scope("mha/proj"):
+            q, k, v = self._qkv(params, x)
+        if self.qk_norm:
+            with jax.named_scope("mha/norm"):
+                norm = RMSNormLayer(epsilon=self.norm_epsilon)
+                q = norm.apply({"gamma": params["q_norm"]}, {}, q)[0]
+                k = norm.apply({"gamma": params["k_norm"]}, {}, k)[0]
         t_loc = x.shape[1]
         offset = _seq_offset(t_loc)
         if self.use_rope:
-            pos = (offset + jnp.arange(t_loc))[None]
-            q = rope(q, pos)
-            k = rope(k, pos)
+            with jax.named_scope("mha/rope"):
+                pos = (offset + jnp.arange(t_loc))[None]
+                q = rope(q, pos, self.rope_base)
+                k = rope(k, pos, self.rope_base)
         drop = self.attention_dropout if train else 0.0
         # fused-kernel eligibility, shared by the context-parallel and
         # single-device dispatches, decided from what the code can see:
@@ -282,6 +313,24 @@ class MultiHeadAttention(LayerConf):
         use_flash = (self.attention_impl in ("flash", "blockwise")
                      and drop == 0.0
                      and is_tpu_backend())
+        with jax.named_scope("mha/attn"):
+            out = self._attend(q, k, v, mask, drop, attn_rng, use_flash)
+        with jax.named_scope("mha/proj"):
+            y = _merge_heads(out) @ params["Wo"]
+            if self.has_bias:
+                y = y + params["bo"]
+        if mask is not None:
+            y = y * mask[..., None].astype(y.dtype)
+        return y, state
+
+    def _attend(self, q, k, v, mask, drop, attn_rng, use_flash):
+        """(B, T, H, D) weighted values from q and the k, v of
+        ``n_kv_heads`` heads, by the path the layer's fields and the
+        platform name."""
+        group = self.n_heads // self._kv_heads()
+        if group > 1 and not (use_flash and _CONTEXT_PARALLEL_AXIS is None):
+            # only the fused kernel reads a key head for its group
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         if _CONTEXT_PARALLEL_AXIS is not None:
             if use_flash:
                 from deeplearning4j_tpu.parallel.ring import (
@@ -332,12 +381,7 @@ class MultiHeadAttention(LayerConf):
             out = dot_product_attention(
                 q, k, v, mask=mask, causal=self.causal,
                 dropout=drop, rng=attn_rng)
-        y = _merge_heads(out) @ params["Wo"]
-        if self.has_bias:
-            y = y + params["bo"]
-        if mask is not None:
-            y = y * mask[..., None].astype(y.dtype)
-        return y, state
+        return out
 
 
 # --- expert dispatch -------------------------------------------------------
@@ -681,9 +725,12 @@ class MoEFeedForward(LayerConf):
     (``tokens_routed_total``, uint32: it wraps, so a reader takes
     differences modulo 2**32) and, where the layer holds a share of its
     experts, its dispatches by the tier they walked (``tier_hits``, one
-    count a tier) and the rows those tiers had (``rows_walked_total``),
-    uint32 both; ``train.listeners.ExpertLoadListener`` turns them into
-    counters, on every fit path of both containers."""
+    count a tier), the rows those tiers had (``rows_walked_total``) and
+    the tokens with at least one of their ``top_k`` experts held here
+    (``tokens_with_held_pair_total``: with no shared expert every other
+    token gets exactly zero from the layer), uint32 all;
+    ``train.listeners.ExpertLoadListener`` turns them into counters, on
+    every fit path of both containers."""
     n_out: int = 0
     n_experts: int = 8
     top_k: int = 2
@@ -759,6 +806,8 @@ class MoEFeedForward(LayerConf):
         if tiers > 1:
             state["tier_hits"] = jnp.zeros((tiers,), jnp.uint32)
             state["rows_walked_total"] = jnp.zeros((), jnp.uint32)
+        if hi - lo < self.n_experts:
+            state["tokens_with_held_pair_total"] = jnp.zeros((), jnp.uint32)
         if self.router == "sigmoid":
             state["route_bias"] = jnp.zeros((self.n_experts,), jnp.float32)
         return p, state
@@ -858,10 +907,16 @@ class MoEFeedForward(LayerConf):
                 inverse = jnp.argsort(order).astype(jnp.int32)
             sizes = jnp.bincount(local, length=e + 1)[:e]
             routed = jnp.bincount(flat, length=self.n_experts)
+            counts = {"tokens_routed": routed.astype(jnp.int32)}
+            if e < self.n_experts:
+                # the tokens this share adds anything to: every other
+                # token's row of the result is exactly zero
+                counts["tokens_with_held_pair"] = jnp.sum(
+                    jnp.any(here.reshape(idx.shape), axis=-1), dtype=jnp.int32)
         if len(tiers) == 1:
             out = _walk_all(params, h, w, here, local, order, inverse, sizes,
                             **how)
-            return out, {"tokens_routed": routed.astype(jnp.int32)}
+            return out, counts
         # the pairs held here come first in the expert order, so the
         # first tier that holds them walks them all: none is dropped
         tier = jnp.sum(sizes.sum() > jnp.asarray(tiers[:-1]))
@@ -869,7 +924,7 @@ class MoEFeedForward(LayerConf):
             (*(functools.partial(_walk, rows=m, **how) for m in tiers[:-1]),
              functools.partial(_walk_whole, **how)),
             tier, params, h, w, local, order, sizes)
-        return out, {"tokens_routed": routed.astype(jnp.int32),
+        return out, {**counts,
                      "tier_hits": (jnp.arange(len(tiers)) == tier)
                      .astype(jnp.int32),
                      "rows_walked": jnp.asarray(tiers, jnp.int32)[tier]}
@@ -890,6 +945,10 @@ class MoEFeedForward(LayerConf):
                 + counts["tier_hits"].astype(jnp.uint32)
             new["rows_walked_total"] = state["rows_walked_total"] \
                 + counts["rows_walked"].astype(jnp.uint32)
+        if "tokens_with_held_pair" in counts:
+            new["tokens_with_held_pair_total"] = \
+                state["tokens_with_held_pair_total"] \
+                + counts["tokens_with_held_pair"].astype(jnp.uint32)
         return new
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):

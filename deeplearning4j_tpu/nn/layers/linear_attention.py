@@ -1,4 +1,5 @@
-"""Linear and latent attention for hybrid LMs (Kimi-Linear's two kinds).
+"""Linear and latent attention for hybrid LMs (Kimi-Linear's two kinds)
+and the gated short convolution of the LFM2 family.
 
 `KimiDeltaAttention` (KDA) is a gated delta rule with a per-channel decay.
 Per head, with state ``S`` (d_k x d_v)::
@@ -33,6 +34,11 @@ v a head, the last ``rope`` dims shared by all heads as the rest of k.
 Those dims are carried as they are (``rotate=False``: Kimi-Linear's
 ``mla_use_nope``, 128 + 64 / 128) or rotated by the token's position
 (``rotate=True``: the GLM / DeepSeek families, 192 + 64 / 256).
+
+`GatedShortConv` is LFM2's operator, three layers to one of grouped-query
+attention there: a depth-wise causal convolution of a few taps
+(`causal_conv`, the helper KDA's q, k and v branches run on) between two
+element-wise gates, with no state beyond the last ``conv_kernel - 1`` rows.
 """
 from __future__ import annotations
 
@@ -276,6 +282,54 @@ class KimiDeltaAttention(LayerConf):
             o = _rms(o, params["o_norm"], self.norm_epsilon) * heads(gate)
             y = o.reshape(b, t, h * d).astype(x.dtype) @ params["Wo"]
         return y, state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class GatedShortConv(LayerConf):
+    """Gated short convolution over (B, T, F), causal by construction
+    (LFM2's operator): ``[B; C; u] = x W_in`` (F -> 3 * n_out, no bias),
+    ``z = B * u``, ``c = causal_conv(z, taps)`` (depth-wise,
+    ``conv_kernel`` taps a channel, no bias, zeros before position 0; the
+    LAST tap meets the current position, as a Conv1d's weight lies),
+    ``y = (C * c) W_out``. No activation. The gates and the taps run in
+    float32 whatever the compute dtype (the three reads and the one
+    write are what they cost). Scopes: ``sconv/proj`` (the two
+    projections), ``sconv/mix`` (gates and taps)."""
+    n_out: int = 0
+    conv_kernel: int = 3
+    weight_init: str = "xavier"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType(Kind.RNN, (input_type.shape[0], self.n_out))
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        f, n = input_type.features, self.n_out
+        w_init = get_initializer(self.weight_init)
+        k_in, k_taps, k_out = jax.random.split(key, 3)
+        bound = self.conv_kernel ** -0.5       # a depth-wise Conv1d's default
+        return {
+            "Win": w_init(k_in, (f, 3 * n), f, 3 * n, dtype),
+            "conv": jax.random.uniform(k_taps, (self.conv_kernel, n), dtype,
+                                       -bound, bound),
+            "Wout": w_init(k_out, (n, n), n, n, dtype),
+        }, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        if mask is not None:
+            raise NotImplementedError(
+                "GatedShortConv takes whole sequences (no mask)")
+        n = self.n_out
+        f32 = jnp.promote_types(jnp.float32, x.dtype)
+        with jax.named_scope("sconv/proj"):
+            bcu = x @ params["Win"]
+        with jax.named_scope("sconv/mix"):
+            b, c, u = (bcu[..., i * n:(i + 1) * n].astype(f32)
+                       for i in range(3))
+            mixed = (c * causal_conv(b * u, params["conv"].astype(f32))
+                     ).astype(x.dtype)
+        with jax.named_scope("sconv/proj"):
+            return mixed @ params["Wout"], state
 
 
 def rope_pairs(x, positions, theta):

@@ -404,13 +404,19 @@ class RnnOutputLayer(LayerConf):
     logits are made in float32, reduced to its summed loss and made again
     in the backward pass, so the logits of all positions never exist at
     once (an LM head over a long sequence). Same number as the whole
-    score up to float32 summation order."""
+    score up to float32 summation order.
+
+    ``tied_embedding`` keeps ``W`` as an embedding keeps its table,
+    ``(n_out, n_in)``, and projects by its transpose: the head of a model
+    that ties the two, a graph vertex reading the embedding's leaf
+    (``add_layer(..., params_of="embed")``)."""
     n_out: int = 0
     n_in: Optional[int] = None
     activation: str = "softmax"
     loss: str = "mcxent"
     weight_init: str = "xavier"
     has_bias: bool = True
+    tied_embedding: bool = False
 
     def output_type(self, input_type: InputType) -> InputType:
         t = input_type.shape[0]
@@ -419,14 +425,16 @@ class RnnOutputLayer(LayerConf):
     def init(self, key, input_type: InputType, dtype=jnp.float32):
         n_in = self.n_in or input_type.features
         w_init = get_initializer(self.weight_init)
-        params = {"W": w_init(key, (n_in, self.n_out), n_in, self.n_out, dtype)}
+        shape = (self.n_out, n_in) if self.tied_embedding \
+            else (n_in, self.n_out)
+        params = {"W": w_init(key, shape, n_in, self.n_out, dtype)}
         if self.has_bias:
             params["b"] = jnp.zeros((self.n_out,), dtype)
         return params, {}
 
     def preout(self, params, x, train=False, rng=None):
         x = self.maybe_dropout_input(x, train, rng)
-        y = x @ params["W"]
+        y = x @ (params["W"].T if self.tied_embedding else params["W"])
         if self.has_bias:
             y = y + params["b"]
         return y
@@ -465,7 +473,8 @@ class RnnOutputLayer(LayerConf):
 
             @jax.checkpoint
             def block_loss(w, b, xb, yb, kb):
-                z = jnp.dot(xb, w, preferred_element_type=acc_t)
+                z = jnp.dot(xb, w.T if self.tied_embedding else w,
+                            preferred_element_type=acc_t)
                 if b is not None:
                     z = z + b.astype(acc_t)
                 nll = jax.nn.logsumexp(z, axis=-1) - jnp.take_along_axis(

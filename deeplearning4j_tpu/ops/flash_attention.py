@@ -14,7 +14,13 @@ Semantics match dot_product_attention exactly (tested):
   output) may have another head width than q and k, and any widths the
   blocks fit VMEM at (the latent attentions: 192-wide q.k with 128-wide
   v, position-free; 256-wide q.k with 256-wide v, rotated: both compile
-  for the v5e at blocks of 512, `tests/test_tpu_lowering.py`);
+  for the v5e at blocks of 512, `tests/test_tpu_lowering.py`); k and v
+  may have FEWER heads than q (grouped-query attention: query head ``h``
+  reads key/value head ``h // group``, ``group = H // H_kv``). The K/V
+  block index maps do the grouping, so k and v stay at their own head
+  count in HBM; in the dk/dv pass one key head's tile accumulates over
+  the ``group`` query heads that read it, inside the kernel (64-wide
+  heads, 32 on 8, compile for the v5e at blocks of 512 too);
 - optional causal masking; key blocks wholly above the diagonal are
   neither fetched nor computed, forward and backward;
 - optional (B, Tk) 0/1 key-validity mask, fully-masked query rows emit 0;
@@ -72,6 +78,28 @@ class _Geometry:
         if not self.causal:
             return kj * 0
         return jnp.minimum((kj * self.bk) // self.bq, self.nq - 1)
+
+
+class _Group:
+    """Grouped key/value heads as integer arithmetic on grid indices:
+    ``group`` query heads read one key/value head. Rows of q are
+    ``b * H + h``, rows of k and v ``b * H_kv + h // group``, which is
+    ``row // group``. In the dk/dv pass the grid's rows are those of k and
+    its third axis runs over (query head of the group, step); ``group``
+    1 leaves every index as it was, so that a call with as many key heads
+    as query heads lowers to the text it had before there were groups."""
+
+    def __init__(self, group, nq):
+        self.n, self.nq = group, nq
+
+    def kv_row(self, q_row):
+        return q_row if self.n == 1 else q_row // self.n
+
+    def q_row(self, kv_row, st):
+        return kv_row if self.n == 1 else kv_row * self.n + st // self.nq
+
+    def step(self, st):
+        return st if self.n == 1 else st % self.nq
 
 
 def _masked_scores(q, k, kmask, qi, kj, *, geom, scale):
@@ -153,6 +181,7 @@ def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
     tk, dv = k.shape[1], v.shape[3]
     scale = 1.0 / float(d) ** 0.5
     geom = _Geometry(causal, block_q, block_k, tq // block_q, tk // block_k)
+    grp = _Group(h // k.shape[2], geom.nq)
     if mask is None:
         mask = jnp.ones((b, tk), jnp.float32)
     # rank-2 operands carry a singleton MIDDLE dim: the Mosaic lowering
@@ -169,9 +198,11 @@ def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d),
-                         lambda bh, qi, kj: (bh, kidx(qi, kj), 0)),
+                         lambda bh, qi, kj: (grp.kv_row(bh), kidx(qi, kj),
+                                             0)),
             pl.BlockSpec((1, block_k, dv),
-                         lambda bh, qi, kj: (bh, kidx(qi, kj), 0)),
+                         lambda bh, qi, kj: (grp.kv_row(bh), kidx(qi, kj),
+                                             0)),
             pl.BlockSpec((1, 1, block_k),
                          lambda bh, qi, kj, _h=h: (bh // _h, 0,
                                                    kidx(qi, kj))),
@@ -254,10 +285,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     mask_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, geom,
-                    scale):
+                    grp, scale):
+    """Grid (B*H_kv, k_blocks, group * q_steps): one key head's tile
+    stays in the scratch while the third axis runs over the query heads
+    of its group and, for each, over the live q blocks."""
     kj = pl.program_id(1)
     step = pl.program_id(2)
-    qi = geom.q_lo(kj) + step
+    qi = geom.q_lo(kj) + grp.step(step)
 
     @pl.when(step == 0)
     def _init():
@@ -291,9 +325,10 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     q, k, v, mask, out, lse = res
     g, g_lse = g                  # cotangents of (out, lse)
     b, tq, h, d = q.shape
-    tk, dv = k.shape[1], v.shape[3]
+    tk, hk, dv = k.shape[1], k.shape[2], v.shape[3]
     scale = 1.0 / float(d) ** 0.5
     geom = _Geometry(causal, block_q, block_k, tq // block_q, tk // block_k)
+    grp = _Group(h // hk, geom.nq)
     # delta_i = rowsum(dO * O) (the softmax-jacobian diagonal term).
     # The LSE output is differentiable too: d lse_i / d s_ij = p_ij, so
     # its cotangent folds in as ds = p * (dp - (delta - g_lse)) — no
@@ -316,7 +351,6 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
 
     common = dict(geom=geom, scale=scale)
     kidx = lambda qi, kj: jnp.minimum(kj, geom.k_hi(qi))
-    qidx = lambda kj, st: jnp.minimum(geom.q_lo(kj) + st, geom.nq - 1)
     q_spec = lambda ix: pl.BlockSpec((1, block_q, d), ix)
     do_spec = lambda ix: pl.BlockSpec((1, block_q, dv), ix)
     row_spec = lambda ix: pl.BlockSpec((1, 1, block_q), ix)
@@ -325,7 +359,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
 
     at_q = lambda bh, qi, kj: (bh, qi, 0)
     at_row = lambda bh, qi, kj: (bh, 0, qi)
-    at_k = lambda bh, qi, kj: (bh, kidx(qi, kj), 0)
+    at_k = lambda bh, qi, kj: (grp.kv_row(bh), kidx(qi, kj), 0)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         grid=(b * h, geom.nq, geom.nk),
@@ -343,22 +377,25 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
         name="flash_bwd_dq",
     )(qh, kh, vh, gh, lse3, dh, m_in)
 
-    at_q = lambda bh, kj, st: (bh, qidx(kj, st), 0)
-    at_row = lambda bh, kj, st: (bh, 0, qidx(kj, st))
+    # rows of k; the third axis: (query head of the group, step)
+    qidx = lambda kj, st: jnp.minimum(geom.q_lo(kj) + grp.step(st),
+                                      geom.nq - 1)
+    at_q = lambda bh, kj, st: (grp.q_row(bh, st), qidx(kj, st), 0)
+    at_row = lambda bh, kj, st: (grp.q_row(bh, st), 0, qidx(kj, st))
     at_k = lambda bh, kj, st: (bh, kj, 0)
     dk, dv_ = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **common),
-        grid=(b * h, geom.nk, geom.nq),
+        functools.partial(_bwd_dkv_kernel, grp=grp, **common),
+        grid=(b * hk, geom.nk, grp.n * geom.nq),
         in_specs=[
             q_spec(at_q), k_spec(at_k), v_spec(at_k), do_spec(at_q),
             row_spec(at_row), row_spec(at_row),
             pl.BlockSpec((1, 1, block_k),
-                         lambda bh, kj, st, _h=h: (bh // _h, 0, kj)),
+                         lambda bh, kj, st, _h=hk: (bh // _h, 0, kj)),
         ],
         out_specs=[k_spec(at_k), v_spec(at_k)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, dv), v.dtype),
+            jax.ShapeDtypeStruct((b * hk, tk, d), k.dtype),
+            jax.ShapeDtypeStruct((b * hk, tk, dv), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, dv), jnp.float32)],
@@ -366,7 +403,8 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
         name="flash_bwd_dkv",
     )(qh, kh, vh, gh, lse3, dh, m_in)
 
-    back = lambda a: a.reshape(b, h, a.shape[1], -1).transpose(0, 2, 1, 3)
+    back = lambda a: a.reshape(b, -1, a.shape[1],
+                               a.shape[2]).transpose(0, 2, 1, 3)
     return back(dq), back(dk), back(dv_), None
 
 
@@ -384,7 +422,9 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
     are mask-excluded; padded query rows are sliced off).
 
     ``v`` (and so the output) may have another head width than ``q`` and
-    ``k``. With ``causal``, key tiles wholly above the diagonal are neither
+    ``k``; ``k`` and ``v`` may have fewer heads than ``q``, a divisor of
+    its count: query head ``h`` reads key/value head ``h // (H // H_kv)``
+    and neither is repeated in HBM. With ``causal``, key tiles wholly above the diagonal are neither
     fetched nor computed, forward and backward.
 
     return_lse=True additionally returns the per-row log-sum-exp
@@ -398,9 +438,10 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
     plain autodiff of the merge arithmetic."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
-    if k.shape[2:] != (h, d) or v.shape[:3] != k.shape[:3]:
+    if k.shape[3] != d or h % k.shape[2] or v.shape[:3] != k.shape[:3]:
         raise ValueError(f"q {q.shape}, k {k.shape}, v {v.shape}: k needs "
-                         "q's heads and head width, v k's length and heads")
+                         "q's head width and a head count that divides "
+                         "q's, v k's length and heads")
     if interpret is None:
         interpret = not is_tpu_backend()
     block_q = min(block_q or 128, max(tq, 1))

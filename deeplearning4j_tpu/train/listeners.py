@@ -397,11 +397,14 @@ class ExpertLoadListener(TrainingListener):
     over the mean of all, last step) and, for a layer that holds a share
     of its experts, ``moe_dispatch_tier_total{layer,tier}`` (dispatches by
     the row tier they walked, named by its share of the dispatch's pairs)
-    and ``moe_rows_walked_total{layer}`` (rows those tiers had). The
-    state's totals are uint32 and wrap; the difference is taken modulo
-    2**32."""
+    and ``moe_rows_walked_total{layer}`` (rows those tiers had) and
+    ``moe_tokens_with_held_pair_total{layer}`` (tokens with at least one
+    of their experts held here; over the tokens routed it is the share of
+    tokens the layer adds anything to). The state's totals are uint32 and
+    wrap; the difference is taken modulo 2**32."""
 
-    _TOTALS = ("tokens_routed_total", "tier_hits", "rows_walked_total")
+    _TOTALS = ("tokens_routed_total", "tier_hits", "rows_walked_total",
+               "tokens_with_held_pair_total")
 
     def __init__(self):
         self._at_start = {}
@@ -448,6 +451,10 @@ class ExpertLoadListener(TrainingListener):
             "rows the dispatches of an expert layer gathered, multiplied "
             "and summed back: those of the tiers they walked",
             labels=("layer",))
+        with_held = monitor.counter(
+            "moe_tokens_with_held_pair_total",
+            "tokens of an expert layer with at least one of their top_k "
+            "experts held here", labels=("layer",))
         for key, layer, state in self._layers(model):
             now = self._totals(state)
             before = self._at_start.get(key, {})
@@ -465,6 +472,9 @@ class ExpertLoadListener(TrainingListener):
                                       gained["tier_hits"]):
                     tiers.inc(int(hits), layer=key, tier=name)
                 walked.inc(int(gained["rows_walked_total"]), layer=key)
+            if "tokens_with_held_pair_total" in gained:
+                with_held.inc(int(gained["tokens_with_held_pair_total"]),
+                              layer=key)
             self._at_start[key] = now
 
 

@@ -377,3 +377,137 @@ def test_without_a_checkpoint_the_name_adds_no_op(return_lse, monkeypatch):
                         lambda x, name: calls.append(name) or x)
     assert text() == named
     assert calls == [REMAT_KEEP] * 4        # out and lse, two layers
+
+
+# ---- grouped key/value heads: query head h reads key head h // group
+
+
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("causal,with_mask", [(True, False), (False, True)])
+def test_grouped_kv_heads_forward_and_the_three_gradients(group, d, causal,
+                                                          with_mask):
+    """The kernels (interpreted) with 8 query heads on 8 // group
+    key/value heads, against the dense path with k and v repeated to
+    every query head: the output and dq, dk, dv (a key head's gradient
+    sums over its group inside the dk/dv kernel), causal and key-masked,
+    at a length that is no multiple of the block."""
+    h, hk, t = 8, 8 // group, 80
+    ks = jax.random.split(jax.random.PRNGKey(group), 4)
+    q = jax.random.normal(ks[0], (2, t, h, d)) * 0.5
+    k = jax.random.normal(ks[1], (2, t, hk, d)) * 0.5
+    v = jax.random.normal(ks[2], (2, t, hk, d))
+    w = jax.random.normal(ks[3], (2, t, h, d))
+    mask = None
+    if with_mask:
+        mask = np.ones((2, t), np.float32)
+        mask[0, 50:] = 0.0
+        mask[1, ::7] = 0.0
+        mask = jnp.asarray(mask)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, mask=mask, block_q=32, block_k=32,
+        interpret=True)
+    dense = lambda q, k, v: dot_product_attention(
+        q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+        causal=causal, mask=mask)
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=3e-6)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    assert got[1].shape == k.shape and got[2].shape == v.shape
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_grouped_kv_heads_must_divide_the_query_heads():
+    q, _, _ = _qkv(h=4)
+    k, v, _ = _qkv(h=3)
+    with pytest.raises(ValueError, match="divides"):
+        flash_attention(q, k, v)
+    k, v, _ = _qkv(h=2, d=8)
+    with pytest.raises(ValueError, match="head width"):
+        flash_attention(q, k, v)
+
+
+def _without_locations(text):
+    """Lowered StableHLO with its locations stripped and every Mosaic
+    kernel body (serialized MLIR bytecode, which carries the Python call
+    stack of each op) decoded and printed without debug info."""
+    import base64
+    import json
+    import re
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    text = re.sub(r"loc\([^)]*\)", "", text)
+    text = re.sub(r"#loc.*\n", "", text)
+
+    def decoded(match):
+        raw = re.sub(r"\\([0-9A-Fa-f]{2})",
+                     lambda h: chr(int(h.group(1), 16)), match.group(1))
+        config = json.loads(raw)
+        body = config.get("custom_call_config", {}).get("body")
+        if body is None:
+            return match.group(0)
+        ctx = jax_mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(body))
+            config["custom_call_config"]["body"] = \
+                module.operation.get_asm(enable_debug_info=False)
+        return "backend_config = " + json.dumps(config, sort_keys=True)
+
+    return re.sub(r'backend_config = "([^"]*)"', decoded, text)
+
+
+def test_as_many_key_heads_as_query_heads_lowers_as_before_the_groups(
+        monkeypatch):
+    """With ``group`` 1 the grouping hands every grid index back as it
+    came (no op traced), so an equal-heads call lowers, for the TPU, to
+    the text of the ungrouped index maps: `_Group` replaced by the
+    parent's expressions (``bh``, ``bh``, ``st``) gives the same text.
+    (Checked against the parent commit itself, kernel bodies decoded and
+    locations stripped, in PERF.md section 6, PR 34.)"""
+    import re
+    import sys
+    from jax import export
+    module = sys.modules["deeplearning4j_tpu.ops.flash_attention"]
+    grp = module._Group(1, 4)
+    marks = [object() for _ in range(2)]
+    assert grp.kv_row(marks[0]) is marks[0]
+    assert grp.q_row(marks[0], marks[1]) is marks[0]
+    assert grp.step(marks[1]) is marks[1]
+
+    def loss(q, k, v, mask):
+        out, lse = flash_attention(q, k, v, mask=mask, causal=True,
+                                   block_q=128, block_k=128,
+                                   interpret=False, return_lse=True)
+        return jnp.sum(out ** 2) + jnp.sum(lse)
+
+    q, k, v = _qkv(t=200, d=64)
+    mask = jnp.ones((2, 200), jnp.float32)
+
+    def text(k=k, v=v):
+        exported = export.export(jax.jit(jax.grad(loss, (0, 1, 2))),
+                                 platforms=["tpu"])(q, k, v, mask)
+        return _without_locations(exported.mlir_module())
+
+    grouped = text()
+    assert grouped.count("tpu_custom_call") >= 3
+
+    class Ungrouped:
+        n = 1
+
+        def __init__(self, group, nq):
+            assert group == 1
+
+        kv_row = staticmethod(lambda row: row)
+        q_row = staticmethod(lambda row, st: row)
+        step = staticmethod(lambda st: st)
+
+    monkeypatch.setattr(module, "_Group", Ungrouped)
+    assert text() == grouped
+    # and a grouped call does trace the grouping
+    monkeypatch.undo()
+    k2, v2, _ = _qkv(t=200, h=2, d=64)
+    assert text(k2, v2) != grouped

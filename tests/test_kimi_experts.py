@@ -253,9 +253,12 @@ def test_every_tier_gives_the_full_tiers_result_and_gradients(router, gated,
     tier = 0 if live <= 8 else 1
     np.testing.assert_array_equal(counts["tier_hits"], np.arange(2) == tier)
     assert int(counts["rows_walked"]) == (8, 128)[tier]
-    assert set(base) == {"tokens_routed"}
-    np.testing.assert_array_equal(counts["tokens_routed"],
-                                  base["tokens_routed"])
+    # one tier counts neither tier nor rows; both count, alike, what was
+    # routed and the tokens with a pair held here
+    assert set(base) == {"tokens_routed", "tokens_with_held_pair"}
+    for name in base:
+        np.testing.assert_array_equal(counts[name], base[name])
+    assert int(base["tokens_with_held_pair"]) <= min(live, _TIER_N)
     np.testing.assert_allclose(out, want, atol=2e-6)
     assert float(jnp.abs(want).max()) > 0.1 or not live
     for (path, a), b in zip(

@@ -183,6 +183,74 @@ class TestFlashKernelCompiles:
                        if backward else ("flash_fwd",)):
             assert kernel in hlo
 
+    @pytest.mark.parametrize("backward", [True, False])
+    def test_grouped_64_wide_heads_at_the_third_lm_cells_widths(
+            self, v5e, backward):
+        # grouped-query attention at the LFM2 cell's widths: 32 query
+        # heads on 8 key/value heads, 64 wide (half the v5e's lanes), 4
+        # sequences of 8,192 positions, block 512; k, v and their
+        # gradients keep 8 heads
+        from deeplearning4j_tpu.ops.flash_attention import flash_attention
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, causal=True, block_q=512, block_k=512,
+                interpret=False).astype(jnp.float32) ** 2)
+
+        q = ((4, 8192, 32, 64), jnp.bfloat16)
+        kv = ((4, 8192, 8, 64), jnp.bfloat16)
+        hlo = _compile_v5e(
+            jax.grad(loss, argnums=(0, 1, 2)) if backward else loss,
+            self._one(v5e), q, kv, kv)
+        for kernel in (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+                       if backward else ("flash_fwd",)):
+            assert kernel in hlo
+        assert "tpu_custom_call" in hlo
+
+    def test_checkpointed_grouped_query_attention_runs_the_forward_once(
+            self, v5e, monkeypatch):
+        # `MultiHeadAttention` as the LFM2 family holds it (32 on 8, q/k
+        # normed, rotated at 1e6, the fused kernel) inside the containers'
+        # rematerialised layer call: ONE flash_fwd in the gradient, and no
+        # k or v of 32 heads anywhere (they are not repeated in HBM)
+        import re
+        import sys
+
+        from deeplearning4j_tpu.nn.conf.base import InputType
+        from deeplearning4j_tpu.nn.layers import MultiHeadAttention
+        from deeplearning4j_tpu.nn.multilayer import _layer_call
+        for name in ("ops.flash_attention", "nn.layers.attention"):
+            monkeypatch.setattr(sys.modules["deeplearning4j_tpu." + name],
+                                "is_tpu_backend", lambda: True)
+        layer = MultiHeadAttention(
+            n_out=2048, n_heads=32, n_kv_heads=8, causal=True, use_rope=True,
+            rope_base=1e6, qk_norm=True, has_bias=False,
+            attention_impl="flash", block_size=512)
+        shapes = jax.eval_shape(
+            lambda key: layer.init(key, InputType.recurrent(2048, 8192),
+                                   jnp.bfloat16)[0], jax.random.PRNGKey(0))
+
+        def loss(params, x):
+            y, _ = _layer_call(layer, seq=False, train=True, remat=True,
+                               params=params, x=x, state={})
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        one = self._one(v5e)
+        place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one)
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jax.tree_util.tree_map(place, shapes),
+            place(jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16))
+        ).compile().as_text()
+        kernels = re.findall(
+            r"^\s*(?:ROOT\s+)?%?(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call",
+            hlo, re.M)
+        assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq",
+                                   "flash_fwd"]
+        calls = [line for line in hlo.splitlines()
+                 if "tpu_custom_call" in line]
+        assert all("bf16[8,8192,64]" in line for line in calls)   # k, v
+
     @pytest.mark.parametrize("hidden,widths", [
         (2304, dict(n_heads=32, nope_dim=128, rope_dim=64, v_dim=128)),
         (2048, dict(n_heads=20, nope_dim=192, rope_dim=64, v_dim=256,

@@ -24,6 +24,23 @@ Semantics match dot_product_attention exactly (tested):
 - optional causal masking; key blocks wholly above the diagonal are
   neither fetched nor computed, forward and backward;
 - optional (B, Tk) 0/1 key-validity mask, fully-masked query rows emit 0;
+- a tile pays for the masking it needs and no more, chosen from what the
+  code can observe (whether a key mask was given, ``causal``, the tile's
+  place against the diagonal) and from nothing a caller sets. Without a
+  key mask the three kernels take NO mask operand (none is built of
+  ones) and lower two bodies: on a tile the diagonal crosses the scores
+  are masked by position, on an interior tile (its last key visible to
+  its first query: 120 of the 136 live tiles at 8,192 positions in
+  blocks of 512) nothing is compared or selected, and nowhere are
+  probabilities zeroed by select: every row has seen key 0 by the end of
+  its first tile, so its running max is finite and ``exp(NEG - m)`` is 0
+  already. With a key mask given (a caller's, or the wrapper's own for
+  padded keys) one body applies it, the diagonal and the zeroing of
+  fully-masked rows on every tile. The gauge ``flash_tile_share{kind}``
+  (docs/OBSERVABILITY.md) says which bodies a traced call takes;
+- the forward's running max and denominator stay in the layout the row
+  reductions give them (``_STAT_LANES``) from tile to tile and are turned
+  into the (block_q,) row of the log-sum-exp once a q block;
 - backward pass: true flash backward — two Pallas passes (dq over key
   blocks; dk/dv over query blocks) recomputing the probabilities from
   the saved per-row log-sum-exp, so the score matrix never materializes
@@ -79,6 +96,24 @@ class _Geometry:
             return kj * 0
         return jnp.minimum((kj * self.bk) // self.bq, self.nq - 1)
 
+    def interior(self, qi, kj):
+        """A causal call's tile whose last key is visible to its first
+        query: no score of it is masked by the diagonal. (A call that is
+        not causal has no other tiles.)"""
+        return (kj + 1) * self.bk - 1 <= qi * self.bq
+
+    def tile_counts(self):
+        """(interior, diagonal) live tiles of one (batch, head) row, in
+        Python integers (where a call is traced, not inside a kernel)."""
+        if not self.causal:
+            return self.nq * self.nk, 0
+        live = interior = 0
+        for qi in range(self.nq):
+            row = min(((qi + 1) * self.bq - 1) // self.bk, self.nk - 1) + 1
+            live += row
+            interior += sum(self.interior(qi, kj) for kj in range(row))
+        return interior, live - interior
+
 
 class _Group:
     """Grouped key/value heads as integer arithmetic on grid indices:
@@ -102,15 +137,19 @@ class _Group:
         return st if self.n == 1 else st % self.nq
 
 
-def _masked_scores(q, k, kmask, qi, kj, *, geom, scale):
+def _masked_scores(q, k, kmask, qi, kj, *, geom, scale, diagonal):
     """Scaled masked scores for one (q block, k block) tile — the ONE
     copy of the masking semantics, shared by the forward kernel and the
     backward recomputation. Operands keep their dtype (bf16 operands run
-    the MXU at its bf16 rate); the product accumulates in float32."""
+    the MXU at its bf16 rate); the product accumulates in float32. Where
+    nothing is to mask nothing is done: no select without a key mask
+    (``kmask`` None), no positions and no select on a tile the diagonal
+    does not cross (``diagonal`` False)."""
     s = scale * jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    s = jnp.where(kmask[None, :] > 0, s, NEG)
-    if geom.causal:
+    if kmask is not None:
+        s = jnp.where(kmask[None, :] > 0, s, NEG)
+    if diagonal:
         qpos = qi * geom.bq + jax.lax.broadcasted_iota(
             jnp.int32, (geom.bq, geom.bk), 0)
         kpos = kj * geom.bk + jax.lax.broadcasted_iota(
@@ -119,12 +158,54 @@ def _masked_scores(q, k, kmask, qi, kj, *, geom, scale):
     return s
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr,
-                 l_scr, acc_scr, *, geom, scale: float):
+def _on_live_tile(live, qi, kj, *, geom, masked, body):
+    """Run ``body(diagonal)`` on a live tile, traced once for each kind of
+    tile the call has. Without a key mask a causal call has two: a tile
+    the diagonal crosses is masked by position, an interior one not at
+    all. A key mask is applied on every tile, as the diagonal is then."""
+    if masked or not geom.causal:
+        pl.when(live)(lambda: body(geom.causal))
+        return
+    interior = geom.interior(qi, kj)
+    pl.when(jnp.logical_and(live, interior))(lambda: body(False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(interior)))(
+        lambda: body(True))
+
+
+def _key_mask(mask_ref):
+    """The (block_k,) key mask of the tile, from what a kernel's operands
+    hold between its inputs and its outputs: one block, or nothing."""
+    return mask_ref[0][0, 0] if mask_ref else None
+
+
+#: the forward kernel's running max and denominator are (block_q,
+#: _STAT_LANES) scratch, a row's value in every lane: the layout a row
+#: reduction that keeps its dimension broadcasts to, and the one the score
+#: tile is read against. On the chip at blocks of 512 (PERF.md section 5,
+#: PR 35): 1.3-1.65 us a tile less than (block_q,) scratch, which is turned
+#: from a value a sublane to a value a lane and back on every tile; a
+#: (block_q, 1) scratch is 0.26-0.75 us a tile slower than this one
+_STAT_LANES = 128
+
+
+def _lanes(x, n):
+    """A row statistic (rows, _STAT_LANES) against a tile n lanes wide."""
+    w = x.shape[1]
+    if n <= w:
+        return x if n == w else x[:, :n]
+    x = jnp.tile(x, (1, -(-n // w)))
+    return x if n % w == 0 else x[:, :n]
+
+
+def _attn_kernel(q_ref, k_ref, v_ref, *rest, geom, scale: float):
     """Grid (B*H, q_blocks, k_blocks), k innermost: each step folds ONE
     (block_k, D) K/V tile into the running (m, l, acc) scratch — only one
     K and one V tile are VMEM-resident at a time, so sequence length is
-    not bounded by VMEM."""
+    not bounded by VMEM. ``rest`` begins with the key mask's block where
+    the call has a key mask. The running max and denominator keep the
+    layout the row reductions give them, a value a row; they become the
+    (block_q,) row of ``lse`` once a q block, in ``_finish``."""
+    *mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -134,39 +215,65 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, m_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def _step(diagonal):
+        v = v_ref[0]
+        s = _masked_scores(q_ref[0], k_ref[0], _key_mask(mask_ref), qi, kj,
+                           geom=geom, scale=scale, diagonal=diagonal)
+        m = m_scr[...]
+        m_new = jnp.maximum(m, s.max(-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+        alpha = jnp.exp(m - m_new)
+        if mask_ref:
+            # exp(NEG - NEG) == 1 for all-masked rows: zero those terms.
+            # Without a key mask every row has seen key 0 by the end of
+            # its first tile: m is finite and exp(NEG - m) is 0 already
+            p = jnp.where(s > NEG / 2, p, 0.0)
+            alpha = jnp.where(m > NEG / 2, alpha, 0.0)
+        m_scr[...] = m_new
+        l_scr[...] = l_scr[...] * alpha + p.sum(-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, v.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
     # tiles wholly above the diagonal are neither fetched (the index map
     # repeats the last live tile) nor computed
-    @pl.when(kj <= geom.k_hi(qi))
-    def _step():
-        v = v_ref[0]
-        s = _masked_scores(q_ref[0], k_ref[0], mask_ref[0, 0], qi, kj,
-                           geom=geom, scale=scale)
-        m = m_scr[...]
-        m_new = jnp.maximum(m, s.max(-1))
-        # exp(NEG - NEG) == 1 for all-masked rows: zero those terms
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(s > NEG / 2, p, 0.0)
-        alpha = jnp.exp(m - m_new)
-        alpha = jnp.where(m > NEG / 2, alpha, 0.0)
-        m_scr[...] = m_new
-        l_scr[...] = l_scr[...] * alpha + p.sum(-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _on_live_tile(kj <= geom.k_hi(qi), qi, kj, geom=geom,
+                  masked=bool(mask_ref), body=_step)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
         m = m_scr[...]
         l = l_scr[...]
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, None]
-        # broadcast the f32 row max, THEN compare: Mosaic has no layout
-        # for the (block_q,) -> (block_q, 1) reshape of an i1 vector
-        out = jnp.where(m[:, None] <= NEG / 2, 0.0, out)
+        acc = acc_scr[...]
+        if mask_ref:
+            l = jnp.maximum(l, 1e-30)       # a fully-masked row's is 0
+        out = acc / _lanes(l, acc.shape[1])
+        # log-sum-exp per q row, the backward residual
+        lse = m + jnp.log(l)
+        if mask_ref:
+            # a fully-masked row emits 0, and +NEG -> +inf for its lse so
+            # that exp(s - lse) vanishes there in the bwd
+            out = jnp.where(_lanes(m, acc.shape[1]) <= NEG / 2, 0.0, out)
+            lse = jnp.where(m <= NEG / 2, -NEG, lse)
         o_ref[0] = out.astype(o_ref.dtype)
-        # log-sum-exp per q row, the backward residual; +NEG-> +inf for
-        # fully-masked rows so exp(s - lse) vanishes there in the bwd
-        lse = m + jnp.log(jnp.maximum(l, 1e-30))
-        lse_ref[0, 0] = jnp.where(m <= NEG / 2, -NEG, lse)
+        # the one turn from a value a row to the (block_q,) row of lanes
+        lse_ref[0, 0] = lse.max(-1)
+
+
+def _mask_operand(mask, block_k, index):
+    """([operand], [its BlockSpec]) of a key mask, and nothing twice where
+    the call has none: no array of ones is built, no block fetched. A
+    rank-2 operand carries a singleton MIDDLE dim: the Mosaic lowering
+    requires the last TWO block dims to divide (8, 128) or equal the
+    array dims, so a (1, block) block on a (b, t) array is rejected
+    (second-to-last = 1 != b); as (b, 1, t) with (1, 1, block) blocks the
+    trailing pair is (1==1, block%128==0) — valid, same bytes."""
+    if mask is None:
+        return [], []
+    b, tk = mask.shape
+    return ([mask.astype(jnp.float32).reshape(b, 1, tk)],
+            [pl.BlockSpec((1, 1, block_k), index)])
 
 
 def _head_rows(x):
@@ -182,15 +289,9 @@ def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
     scale = 1.0 / float(d) ** 0.5
     geom = _Geometry(causal, block_q, block_k, tq // block_q, tk // block_k)
     grp = _Group(h // k.shape[2], geom.nq)
-    if mask is None:
-        mask = jnp.ones((b, tk), jnp.float32)
-    # rank-2 operands carry a singleton MIDDLE dim: the Mosaic lowering
-    # requires the last TWO block dims to divide (8, 128) or equal the
-    # array dims, so a (1, block) block on a (b, t) array is rejected
-    # (second-to-last = 1 != b); as (b, 1, t) with (1, 1, block) blocks
-    # the trailing pair is (1==1, block%128==0) — valid, same bytes
-    mask = mask.astype(jnp.float32).reshape(b, 1, tk)
     kidx = lambda qi, kj: jnp.minimum(kj, geom.k_hi(qi))
+    masks, mask_specs = _mask_operand(
+        mask, block_k, lambda bh, qi, kj: (bh // h, 0, kidx(qi, kj)))
 
     out, lse = pl.pallas_call(
         functools.partial(_attn_kernel, geom=geom, scale=scale),
@@ -203,9 +304,7 @@ def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
             pl.BlockSpec((1, block_k, dv),
                          lambda bh, qi, kj: (grp.kv_row(bh), kidx(qi, kj),
                                              0)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bh, qi, kj, _h=h: (bh // _h, 0,
-                                                   kidx(qi, kj))),
+            *mask_specs,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda bh, qi, kj: (bh, qi, 0)),
@@ -216,13 +315,13 @@ def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
-    )(_head_rows(q), _head_rows(k), _head_rows(v), mask)
+    )(_head_rows(q), _head_rows(k), _head_rows(v), *masks)
     return (out.reshape(b, h, tq, dv).transpose(0, 2, 1, 3),
             lse.reshape(b * h, tq))
 
@@ -245,12 +344,15 @@ def _flash_fwd(q, k, v, mask, causal, block_q, block_k, interpret):
     return (out, lse), (q, k, v, mask, out, lse)
 
 
-def _bwd_scores(q, k, kmask, lse_row, qi, kj, *, geom, scale):
+def _bwd_scores(q, k, kmask, lse_row, qi, kj, *, geom, scale, diagonal):
     """Recompute the softmax probabilities p = exp(s - lse) for one
-    (q block, k block) tile via the shared masked-scores helper."""
-    s = _masked_scores(q, k, kmask, qi, kj, geom=geom, scale=scale)
+    (q block, k block) tile via the shared masked-scores helper. Without
+    a key mask every row's lse is finite, so a score the diagonal masked
+    gives exp(NEG - lse) == 0 by itself."""
+    s = _masked_scores(q, k, kmask, qi, kj, geom=geom, scale=scale,
+                       diagonal=diagonal)
     p = jnp.exp(s - lse_row[:, None])
-    return jnp.where(s > NEG / 2, p, 0.0)
+    return p if kmask is None else jnp.where(s > NEG / 2, p, 0.0)
 
 
 def _bwd_ds(p, do, v, delta_row):
@@ -259,8 +361,9 @@ def _bwd_ds(p, do, v, delta_row):
     return p * (dp - delta_row[:, None])
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   mask_ref, dq_ref, dq_scr, *, geom, scale):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                   geom, scale):
+    *mask_ref, dq_ref, dq_scr = rest      # the key mask's block, if any
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -268,27 +371,29 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(kj <= geom.k_hi(qi))
-    def _step():
+    def _step(diagonal):
         k = k_ref[0]
-        p = _bwd_scores(q_ref[0], k, mask_ref[0, 0], lse_ref[0, 0], qi, kj,
-                        geom=geom, scale=scale)
+        p = _bwd_scores(q_ref[0], k, _key_mask(mask_ref), lse_ref[0, 0], qi,
+                        kj, geom=geom, scale=scale, diagonal=diagonal)
         ds = _bwd_ds(p, do_ref[0], v_ref[0], delta_ref[0, 0])
         dq_scr[...] += scale * jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _on_live_tile(kj <= geom.k_hi(qi), qi, kj, geom=geom,
+                  masked=bool(mask_ref), body=_step)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    mask_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, geom,
-                    grp, scale):
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                    geom, grp, scale):
     """Grid (B*H_kv, k_blocks, group * q_steps): one key head's tile
     stays in the scratch while the third axis runs over the query heads
     of its group and, for each, over the live q blocks."""
+    *mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
     kj = pl.program_id(1)
     step = pl.program_id(2)
     qi = geom.q_lo(kj) + grp.step(step)
@@ -298,11 +403,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(qi <= geom.nq - 1)
-    def _step():
+    def _step(diagonal):
         q, do = q_ref[0], do_ref[0]
-        p = _bwd_scores(q, k_ref[0], mask_ref[0, 0], lse_ref[0, 0], qi, kj,
-                        geom=geom, scale=scale)
+        p = _bwd_scores(q, k_ref[0], _key_mask(mask_ref), lse_ref[0, 0], qi,
+                        kj, geom=geom, scale=scale, diagonal=diagonal)
         dv_scr[...] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -310,6 +414,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[...] += scale * jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _on_live_tile(qi <= geom.nq - 1, qi, kj, geom=geom,
+                  masked=bool(mask_ref), body=_step)
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
@@ -342,12 +449,10 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     gh = _head_rows(g.astype(q.dtype))
     qh, kh, vh = _head_rows(q), _head_rows(k), _head_rows(v)
     # singleton middle dims on the rank-2 operands (lse/delta/mask) — see
-    # the forward call: (1, 1, block) trailing pairs satisfy the Mosaic
+    # `_mask_operand`: (1, 1, block) trailing pairs satisfy the Mosaic
     # (8, 128)-or-equal block constraint where (1, block) cannot
     dh = delta.transpose(0, 2, 1).reshape(b * h, 1, tq)
     lse3 = lse.reshape(b * h, 1, tq)
-    m_in = (jnp.ones((b, tk), jnp.float32) if mask is None
-            else mask.astype(jnp.float32)).reshape(b, 1, tk)
 
     common = dict(geom=geom, scale=scale)
     kidx = lambda qi, kj: jnp.minimum(kj, geom.k_hi(qi))
@@ -360,22 +465,21 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     at_q = lambda bh, qi, kj: (bh, qi, 0)
     at_row = lambda bh, qi, kj: (bh, 0, qi)
     at_k = lambda bh, qi, kj: (grp.kv_row(bh), kidx(qi, kj), 0)
+    masks, mask_specs = _mask_operand(
+        mask, block_k, lambda bh, qi, kj: (bh // h, 0, kidx(qi, kj)))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
         grid=(b * h, geom.nq, geom.nk),
         in_specs=[
             q_spec(at_q), k_spec(at_k), v_spec(at_k), do_spec(at_q),
-            row_spec(at_row), row_spec(at_row),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bh, qi, kj, _h=h: (bh // _h, 0,
-                                                   kidx(qi, kj))),
+            row_spec(at_row), row_spec(at_row), *mask_specs,
         ],
         out_specs=q_spec(at_q),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
-    )(qh, kh, vh, gh, lse3, dh, m_in)
+    )(qh, kh, vh, gh, lse3, dh, *masks)
 
     # rows of k; the third axis: (query head of the group, step)
     qidx = lambda kj, st: jnp.minimum(geom.q_lo(kj) + grp.step(st),
@@ -383,14 +487,14 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     at_q = lambda bh, kj, st: (grp.q_row(bh, st), qidx(kj, st), 0)
     at_row = lambda bh, kj, st: (grp.q_row(bh, st), 0, qidx(kj, st))
     at_k = lambda bh, kj, st: (bh, kj, 0)
+    masks, mask_specs = _mask_operand(
+        mask, block_k, lambda bh, kj, st: (bh // hk, 0, kj))
     dk, dv_ = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, grp=grp, **common),
         grid=(b * hk, geom.nk, grp.n * geom.nq),
         in_specs=[
             q_spec(at_q), k_spec(at_k), v_spec(at_k), do_spec(at_q),
-            row_spec(at_row), row_spec(at_row),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bh, kj, st, _h=hk: (bh // _h, 0, kj)),
+            row_spec(at_row), row_spec(at_row), *mask_specs,
         ],
         out_specs=[k_spec(at_k), v_spec(at_k)],
         out_shape=[
@@ -401,7 +505,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
                         pltpu.VMEM((block_k, dv), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(qh, kh, vh, gh, lse3, dh, m_in)
+    )(qh, kh, vh, gh, lse3, dh, *masks)
 
     back = lambda a: a.reshape(b, -1, a.shape[1],
                                a.shape[2]).transpose(0, 2, 1, 3)
@@ -409,6 +513,27 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _publish_tile_shares(geom, masked):
+    """The gauge ``flash_tile_share{kind}``: shares of the call's live
+    tiles by the body the kernels run on them, set where the call is
+    traced (the last call traced is the one that shows)."""
+    from deeplearning4j_tpu.monitor import metrics
+    interior, diagonal = geom.tile_counts()
+    live = interior + diagonal
+    counts = {"interior": 0 if masked else interior,
+              "diagonal": 0 if masked else diagonal,
+              "key_masked": live if masked else 0}
+    gauge = metrics.gauge(
+        "flash_tile_share",
+        "Shares (%) of the live (q block, k block) tiles of the flash "
+        "attention call traced last, by the body its kernels run on them: "
+        "interior (nothing masked), diagonal (masked by position), "
+        "key_masked (the call has a key mask: applied on every tile)",
+        labels=("kind",))
+    for kind, n in counts.items():
+        gauge.set(100.0 * n / live, kind=kind)
 
 
 def flash_attention(q, k, v, *, mask=None, causal: bool = False,
@@ -456,6 +581,9 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
         v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
         if mask is not None:
             mask = jnp.pad(mask, ((0, 0), (0, pk)))
+    _publish_tile_shares(
+        _Geometry(causal, block_q, block_k, q.shape[1] // block_q,
+                  k.shape[1] // block_k), masked=mask is not None)
     out, lse = _flash(q, k, v, mask, causal, block_q, block_k, interpret)
     if not return_lse:
         return out[:, :tq]

@@ -312,20 +312,25 @@ def _two_layers(causal, with_mask, t, checkpointed, return_lse=False):
     return loss, (q, k, v, jnp.asarray(mask))
 
 
-def _kernel_calls(fn, args):
-    """{kernel name: Pallas calls} in the traced fn, every use of a
-    shared sub-jaxpr counted (the printed text shows one only once)."""
-    calls = {}
-
+def _eqns(fn, args):
+    """Every equation of the traced fn, those of its sub-jaxprs too (each
+    use of a shared one: the printed text shows it only once)."""
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                name = eqn.params["name"]
-                calls[name] = calls.get(name, 0) + 1
+            yield eqn
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
+                yield from walk(sub)
 
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def _kernel_calls(fn, args):
+    """{kernel name: Pallas calls} in the traced fn."""
+    calls = {}
+    for eqn in _eqns(fn, args):
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            calls[name] = calls.get(name, 0) + 1
     return calls
 
 
@@ -511,3 +516,218 @@ def test_as_many_key_heads_as_query_heads_lowers_as_before_the_groups(
     monkeypatch.undo()
     k2, v2, _ = _qkv(t=200, h=2, d=64)
     assert text(k2, v2) != grouped
+
+
+# ---- a tile pays for what it needs: interior / diagonal / key-masked bodies
+
+
+def _kernel_operands(fn, args):
+    """{kernel name: number of operands of its Pallas call} in the traced
+    fn, and the shapes of every array the trace builds from nothing (a
+    broadcast of a scalar)."""
+    operands, built = {}, []
+    for eqn in _eqns(fn, args):
+        if eqn.primitive.name == "pallas_call":
+            operands[eqn.params["name"]] = len(eqn.invars)
+        if eqn.primitive.name == "broadcast_in_dim" and not any(
+                getattr(v.aval, "shape", ()) for v in eqn.invars):
+            built.append(eqn.outvars[0].aval.shape)
+    return operands, built
+
+
+def _tile_case(tq, tk, h, hk, d=16, dv=16, seed=30):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (2, tq, h, d)) * 0.5
+    k = jax.random.normal(ks[1], (2, tk, hk, d)) * 0.5
+    v = jax.random.normal(ks[2], (2, tk, hk, dv))
+    w = jax.random.normal(ks[3], (2, tq, h, dv))
+    return q, k, v, w
+
+
+def _key_mask(kind, tk):
+    """None; ones; a caller's mask whose batch row 1 has no valid key."""
+    if kind is None:
+        return None
+    mask = np.ones((2, tk), np.float32)
+    if kind == "given":
+        mask[0, tk // 2:] = 0.0
+        mask[0, 3] = 0.0
+        mask[1, :] = 0.0
+    return jnp.asarray(mask)
+
+
+# (causal, tq, tk, block_q, block_k, key mask, query heads, key heads):
+# which body each kernel runs on the live tiles
+_TILE_CASES = {
+    "interior-only": (False, 64, 64, 16, 16, None, 4, 4),
+    "interior-only-cross": (False, 32, 64, 16, 32, None, 4, 4),
+    "diagonal-square-blocks": (True, 64, 64, 16, 16, None, 4, 4),
+    "diagonal-wide-q-block": (True, 64, 64, 32, 16, None, 4, 4),
+    "diagonal-wide-k-block": (True, 64, 64, 16, 32, None, 4, 4),
+    "diagonal-more-keys": (True, 32, 64, 16, 16, None, 4, 4),
+    "diagonal-more-queries": (True, 64, 32, 16, 32, None, 4, 4),
+    "key-mask-given-causal": (True, 64, 64, 16, 16, "given", 4, 4),
+    "key-mask-given": (False, 64, 64, 16, 32, "given", 4, 4),
+    "padding-mask-causal": (True, 56, 56, 16, 16, None, 4, 4),
+    "padding-mask-cross": (False, 40, 50, 16, 16, None, 4, 4),
+    "queries-padded-keys-not": (True, 56, 64, 16, 16, None, 4, 4),
+    "grouped-32-on-8": (True, 64, 64, 16, 16, None, 32, 8),
+    "grouped-32-on-8-key-mask": (True, 64, 64, 32, 16, "given", 32, 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TILE_CASES))
+def test_each_tile_body_forward_and_the_three_gradients(case):
+    """Output, log-sum-exp and dq, dk, dv against the dense path for every
+    body the kernels have: interior tiles only (not causal), the diagonal's
+    (causal, at equal and unequal blocks and lengths), a caller's key mask
+    with a fully masked row (zero output, the `lse` sentinel, zero
+    gradients), the wrapper's own padding mask, grouped heads."""
+    causal, tq, tk, bq, bk, kind, h, hk = _TILE_CASES[case]
+    q, k, v, w = _tile_case(tq, tk, h, hk)
+    mask = _key_mask(kind, tk)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, mask=mask, block_q=bq, block_k=bk,
+        return_lse=True)
+    dense = lambda q, k, v: dot_product_attention(
+        q, jnp.repeat(k, h // hk, axis=2), jnp.repeat(v, h // hk, axis=2),
+        causal=causal, mask=mask)
+    out, lse = flash(q, k, v)
+    np.testing.assert_allclose(out, dense(q, k, v), atol=3e-6)
+    # the log-sum-exp against the scores written out
+    s = jnp.einsum("bqhd,bkhd->bqhk", q, jnp.repeat(k, h // hk, axis=2)) \
+        / np.sqrt(q.shape[-1])
+    seen = jnp.ones((2, tq, 1, tk), bool)
+    if causal:
+        seen = seen & (jnp.arange(tq)[:, None] >= jnp.arange(tk))[
+            None, :, None, :]
+    if mask is not None:
+        seen = seen & (mask > 0)[:, None, None, :]
+    want_lse = jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+    want_lse = jnp.where(jnp.isfinite(want_lse), want_lse, -1e30)
+    np.testing.assert_allclose(lse, want_lse, atol=1e-5, rtol=1e-6)
+    if kind == "given":             # batch row 1: no valid key
+        assert np.abs(np.asarray(out)[1]).max() == 0.0
+        assert (np.asarray(lse)[1] == np.float32(-1e30)).all()
+    got = jax.grad(lambda *a: jnp.sum(flash(*a)[0] * w), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("causal,tq,tk,bq,bk,h,hk", [
+    (True, 64, 64, 16, 16, 4, 4), (True, 64, 64, 32, 16, 4, 4),
+    (True, 64, 64, 16, 32, 4, 4), (True, 32, 64, 16, 16, 4, 4),
+    (True, 64, 32, 16, 32, 4, 4), (False, 32, 64, 16, 32, 4, 4),
+    (True, 64, 64, 16, 16, 8, 2)])
+def test_a_key_mask_of_ones_is_no_mask_to_the_last_bit(causal, tq, tk, bq,
+                                                       bk, h, hk):
+    """Without a key mask the kernels select nothing: every row has seen
+    key 0 by the end of its first tile, so its running max is finite from
+    then on and exp(NEG - m) is 0 by itself. Output, log-sum-exp and the
+    three gradients equal those of a mask of ones (today's body on every
+    tile) bit for bit, in float32 in the interpreter, at unequal blocks and
+    unequal lengths too."""
+    q, k, v, w = _tile_case(tq, tk, h, hk, seed=31)
+    ones = jnp.ones((2, tk), jnp.float32)
+
+    def run(mask):
+        def loss(q, k, v):
+            out, lse = flash_attention(q, k, v, causal=causal, mask=mask,
+                                       block_q=bq, block_k=bk,
+                                       return_lse=True)
+            return jnp.sum(out * w) + jnp.sum(jnp.sin(lse)), (out, lse)
+        grads, (out, lse) = jax.jit(
+            jax.grad(loss, (0, 1, 2), has_aux=True))(q, k, v)
+        return (out, lse) + tuple(grads)
+
+    for a, b in zip(run(None), run(ones)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_without_a_key_mask_the_kernels_take_no_mask_operand(causal):
+    """`mask=None` lowers `flash_fwd` on q, k, v and the two backward
+    kernels on q, k, v, dO, lse, delta: no mask operand, and no (B, T) array
+    of ones is built for one. A caller's mask is one operand more."""
+    q, k, v, w = _tile_case(64, 64, 4, 4)
+    loss = lambda mask: lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=causal, mask=mask, block_q=16, block_k=16) * w)
+    grad = lambda mask: jax.grad(loss(mask), (0, 1, 2))
+    operands, built = _kernel_operands(grad(None), (q, k, v))
+    assert operands == {"flash_fwd": 3, "flash_bwd_dq": 6,
+                        "flash_bwd_dkv": 6}
+    assert not [shape for shape in built if shape in ((2, 64), (2, 1, 64))]
+    operands, _ = _kernel_operands(grad(jnp.ones((2, 64))), (q, k, v))
+    assert operands == {"flash_fwd": 4, "flash_bwd_dq": 7,
+                        "flash_bwd_dkv": 7}
+
+
+@pytest.mark.parametrize("causal,tq,tk,bq,bk", [
+    (True, 8192, 8192, 512, 512), (True, 1024, 1024, 256, 128),
+    (True, 1024, 1024, 128, 256), (True, 512, 1024, 128, 128),
+    (True, 1024, 512, 128, 128), (False, 512, 1024, 128, 256)])
+def test_tile_shares_follow_the_geometry(causal, tq, tk, bq, bk):
+    """`_Geometry.tile_counts` (Python integers) counts what the kernels'
+    own predicates say tile by tile, and `flash_attention` publishes the
+    shares as `flash_tile_share{kind}` where it is traced: 120 interior and
+    16 diagonal of 136 live tiles at 8,192 positions in blocks of 512;
+    every live tile `key_masked` for a caller with a key mask."""
+    import sys
+    from deeplearning4j_tpu import monitor
+    module = sys.modules["deeplearning4j_tpu.ops.flash_attention"]
+    geom = module._Geometry(causal, bq, bk, tq // bq, tk // bk)
+    interior = diagonal = 0
+    for qi in range(geom.nq):
+        for kj in range(int(geom.k_hi(np.int32(qi))) + 1):
+            assert qi >= int(geom.q_lo(np.int32(kj)))
+            if not causal or geom.interior(qi, kj):
+                interior += 1
+            else:
+                diagonal += 1
+    assert geom.tile_counts() == (interior, diagonal)
+    if (tq, bq, bk) == (8192, 512, 512):
+        assert (interior, diagonal) == (120, 16)
+
+    def shares(mask):
+        shape = lambda t, *more: jax.ShapeDtypeStruct((1, t, 2, 8) + more,
+                                                      jnp.float32)
+        jax.eval_shape(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, mask=mask, block_q=bq, block_k=bk,
+            interpret=True), shape(tq), shape(tk), shape(tk))
+        series = monitor.dump()["flash_tile_share"]["series"]
+        return {s["labels"]["kind"]: s["value"] for s in series}
+
+    live = interior + diagonal
+    assert shares(None) == {"interior": 100.0 * interior / live,
+                            "diagonal": 100.0 * diagonal / live,
+                            "key_masked": 0.0}
+    assert shares(jnp.ones((1, tk))) == {"interior": 0.0, "diagonal": 0.0,
+                                         "key_masked": 100.0}
+
+
+def test_the_benchmarks_reader_takes_the_interior_share_from_the_dump(
+        monkeypatch):
+    """`benchmark/metrics/flash_interior_tile_share.py` reads the gauge
+    from `monitor.dump()` itself: the interior share where a flash call was
+    traced, None where the program has no such gauge (a parent commit; a
+    run that took the XLA path)."""
+    import importlib.util
+    import os
+    from deeplearning4j_tpu import monitor
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics",
+        "flash_interior_tile_share.py")
+    spec = importlib.util.spec_from_file_location("_flash_share", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    shape = jax.ShapeDtypeStruct((1, 8192, 1, 8), jnp.float32)
+    jax.eval_shape(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=512, block_k=512, interpret=True),
+        shape, shape, shape)
+    assert reader.read({}) == pytest.approx(100.0 * 120 / 136)
+    dump = monitor.dump()
+    dump.pop("flash_tile_share")
+    monkeypatch.setattr(monitor, "dump", lambda: dump)
+    assert reader.read({}) is None

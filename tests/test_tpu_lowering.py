@@ -162,46 +162,55 @@ class TestFlashKernelCompiles:
     @pytest.mark.parametrize("heads,d_qk,d_v", [(32, 192, 128),
                                                 (20, 256, 256)])
     @pytest.mark.parametrize("backward", [True, False])
+    @pytest.mark.parametrize("masked", [False, True])
     def test_two_head_sizes_at_the_lm_cells_widths(self, v5e, backward,
-                                                   heads, d_qk, d_v):
+                                                   heads, d_qk, d_v, masked):
         # latent attention's expanded form at the two LM cells' widths:
         # 32 heads of 192-wide q.k and 128-wide v (position-free), 20 of
-        # 256 / 256 (rotated); 8,192 positions, block 512
+        # 256 / 256 (rotated); 8,192 positions, block 512. Without a key
+        # mask (the cells) the kernels take no mask operand and lower an
+        # interior and a diagonal body; with one, the one body that
+        # applies it on every tile
         from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
-        def loss(q, k, v):
+        def loss(q, k, v, *mask):
             return jnp.sum(flash_attention(
                 q, k, v, causal=True, block_q=512, block_k=512,
+                mask=mask[0] if mask else None,
                 interpret=False).astype(jnp.float32) ** 2)
 
         qk = ((1, 8192, heads, d_qk), jnp.bfloat16)
         v = ((1, 8192, heads, d_v), jnp.bfloat16)
+        mask = [((1, 8192), jnp.float32)] if masked else []
         hlo = _compile_v5e(
             jax.grad(loss, argnums=(0, 1, 2)) if backward else loss,
-            self._one(v5e), qk, qk, v)
+            self._one(v5e), qk, qk, v, *mask)
         for kernel in (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
                        if backward else ("flash_fwd",)):
             assert kernel in hlo
 
     @pytest.mark.parametrize("backward", [True, False])
+    @pytest.mark.parametrize("masked", [False, True])
     def test_grouped_64_wide_heads_at_the_third_lm_cells_widths(
-            self, v5e, backward):
+            self, v5e, backward, masked):
         # grouped-query attention at the LFM2 cell's widths: 32 query
         # heads on 8 key/value heads, 64 wide (half the v5e's lanes), 4
         # sequences of 8,192 positions, block 512; k, v and their
-        # gradients keep 8 heads
+        # gradients keep 8 heads; without a key mask (the cell) and with
         from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
-        def loss(q, k, v):
+        def loss(q, k, v, *mask):
             return jnp.sum(flash_attention(
                 q, k, v, causal=True, block_q=512, block_k=512,
+                mask=mask[0] if mask else None,
                 interpret=False).astype(jnp.float32) ** 2)
 
         q = ((4, 8192, 32, 64), jnp.bfloat16)
         kv = ((4, 8192, 8, 64), jnp.bfloat16)
+        mask = [((4, 8192), jnp.float32)] if masked else []
         hlo = _compile_v5e(
             jax.grad(loss, argnums=(0, 1, 2)) if backward else loss,
-            self._one(v5e), q, kv, kv)
+            self._one(v5e), q, kv, kv, *mask)
         for kernel in (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
                        if backward else ("flash_fwd",)):
             assert kernel in hlo

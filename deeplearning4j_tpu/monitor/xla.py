@@ -44,6 +44,7 @@ from __future__ import annotations
 import hashlib
 import json
 import logging
+import math
 import os
 import re
 import threading
@@ -122,9 +123,16 @@ class ProgramRecord:
         #: (the jax.named_scope path of the op, or of a fusion's root):
         #: a device trace names ops by instruction, this maps them back
         #: to the program's scopes ("moe/experts", "opt/update", ...).
-        #: In memory only, not in the saved ledger.
+        #: As `capture()` fills it, an `OpTable`: a view of the program's
+        #: instruction table, `ops`.
         self.op_scopes: Dict[str, str] = {}
         self.first_captured_unix = time.time()
+
+    @property
+    def ops(self) -> List[dict]:
+        """One row an instruction of the compiled program (fields:
+        `parse_hlo_ops`); empty where the program's text was not parsed."""
+        return getattr(self.op_scopes, "rows", [])
 
     @property
     def total_flops_per_call(self) -> Optional[float]:
@@ -170,6 +178,8 @@ class ProgramRecord:
             "arg_shardings": list(self.arg_shardings),
             "sharded": self.is_sharded,
             "first_captured_unix": round(self.first_captured_unix, 3),
+            # a profile names an instruction; the rows say what it is
+            **({"ops": self.ops} if self.ops else {}),
         }
 
     def brief(self) -> dict:
@@ -405,19 +415,317 @@ def hbm_stats(ma) -> Dict[str, int]:
     }
 
 
-_OP_NAME = re.compile(
-    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*\bop_name="([^"]*)"', re.M)
+# ------------------------------------------------ the compiled-step table
+#: opcodes whose instruction only holds other instructions, whatever it is
+#: called (`lax.cond`'s is `cond.N`): its time is its children's
+CONTAINER_OPCODES = ("while", "conditional", "call")
+#: instructions that do no work on the device: not rows of the table
+_NO_WORK = frozenset(("parameter", "get-tuple-element", "tuple", "constant",
+                      "bitcast"))
+_DTYPE_BYTES = {"pred": 1, "s4": 0.5, "u4": 0.5, "s8": 1, "u8": 1,
+                "s16": 2, "u16": 2, "f16": 2, "bf16": 2, "s32": 4,
+                "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
+                "c64": 8, "c128": 16}
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%([\w.\-]+) \(")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%([\w.\-]+) = ")
+# a TPU shape carries its tiling, `bf16[4,8192]{1,0:T(8,128)(2,1)S(1)}`:
+# dtype and dims are all that is read
+_ARRAY = re.compile(r"\b([a-z][a-z0-9]*)\[([\d,<= ]*)\]")
+_TUPLE_END = re.compile(r"\) ([a-z][\w\-]*)\(")
+_CALLED = re.compile(r"\b(condition|body|to_apply|calls|true_computation|"
+                     r"false_computation)=%([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_WINDOW_FIELD = re.compile(r"(\w+)=([\w\-]+)")
 
 
-def compiled_op_scopes(compiled) -> Dict[str, str]:
-    """{instruction name: op_name metadata} of every instruction of a
-    jax.stages.Compiled that carries one. Empty when the backend gives no
-    text."""
+def _dims(shape: str):
+    """[(dtype, [dims])] of the arrays a shape holds (a tuple: each)."""
+    return [(t, [int(d.strip("<= ")) for d in dims.split(",") if d.strip()])
+            for t, dims in _ARRAY.findall(shape)]
+
+
+def _shape_bytes(shape: str) -> int:
+    return int(sum(
+        _DTYPE_BYTES.get(t, 1 if t.startswith("f8") else 0) * math.prod(dims)
+        for t, dims in _dims(shape)))
+
+
+def _dot_flops(attrs: str, result, lhs) -> int:
+    """2 x multiply-adds of a `dot`: result elements x contracted extent."""
+    m = re.search(r"lhs_contracting_dims=\{([\d,]*)\}", attrs)
+    contracted = m.group(1).split(",") if m and m.group(1) else ()
+    return 2 * math.prod(result) * math.prod(lhs[int(d)] for d in contracted)
+
+
+def _valid_taps(n, k, out, stride, lo, lhs_dilate, rhs_dilate) -> int:
+    """(output position, kernel tap) pairs of one spatial dim that meet an
+    input element: not padding, not a hole of a dilated input. XLA:TPU
+    writes a batched product as a convolution whose window mostly meets
+    holes (`size=8 stride=7 lhs_dilate=8`); counting its taps as products
+    would count the batch twice."""
+    if lo == 0 and lhs_dilate == 1 and (out - 1) * stride \
+            + (k - 1) * rhs_dilate < n:
+        return out * k
+    hit = 0
+    for o in range(out):
+        for t in range(k):
+            at = o * stride - lo + t * rhs_dilate
+            if at >= 0 and at % lhs_dilate == 0 and at // lhs_dilate < n:
+                hit += 1
+    return hit
+
+
+def _conv_flops(attrs: str, result, lhs, rhs) -> int:
+    """2 x multiply-adds of a `convolution` (on a TPU every matrix product
+    is one): the result's elements x the kernel's elements / its output
+    features where no tap falls on padding or a hole, so grouped features
+    count once; where taps do, only those that meet an input element."""
+    m = re.search(r"dim_labels=(\w+)_(\w+)->(\w+)", attrs)
+    if not m:
+        return 0
+    l_lab, r_lab, o_lab = m.groups()
+    window = {}
+    w = re.search(r"window=\{([^}]*)\}", attrs)
+    for key, val in _WINDOW_FIELD.findall(w.group(1) if w else ""):
+        window[key] = val.split("x")
+    group = re.search(r"batch_group_count=(\d+)", attrs)
+    fma = rhs[r_lab.index("i")] * rhs[r_lab.index("o")] \
+        * lhs[l_lab.index("b")] // (int(group.group(1)) if group else 1)
+    spatial = [c for c in l_lab if c.isdigit()]
+    for i, c in enumerate(spatial):
+        field = lambda key, default: window[key][i] if key in window \
+            else default
+        fma *= _valid_taps(
+            lhs[l_lab.index(c)], rhs[r_lab.index(c)], result[o_lab.index(c)],
+            int(field("stride", 1)), int(str(field("pad", "0_0")).split(
+                "_")[0]), int(field("lhs_dilate", 1)),
+            int(field("rhs_dilate", 1)))
+    return 2 * fma
+
+
+def _split_instruction(line: str):
+    """(name, shape, opcode, operand names, attributes) of one line of a
+    computation's text, or None."""
+    m = _INSTRUCTION.match(line)
+    if not m:
+        return None
+    rest = line[m.end():]
+    cut = rest.find(", backend_config=")    # a Pallas call's is its body
+    if cut >= 0:
+        rest = rest[:cut]
+    if rest.startswith("("):                # a tuple's shape
+        end = _TUPLE_END.search(rest)
+        if not end:
+            return None
+        shape, rest = rest[:end.start() + 1], rest[end.start() + 2:]
+    else:
+        shape, _, rest = rest.partition(" ")
+    opcode, _, rest = rest.partition("(")
+    depth, close = 1, len(rest)
+    for i, ch in enumerate(rest):
+        depth += (ch == "(") - (ch == ")")
+        if not depth:
+            close = i
+            break
+    operands = re.findall(r"%([\w.\-]+)", rest[:close]) \
+        if opcode != "constant" else []
+    return m.group(1), shape, opcode, operands, rest[close + 1:]
+
+
+def parse_hlo_ops(text: str) -> List[dict]:
+    """One row an instruction of a compiled module's text, for every
+    computation that executes on the device: the entry, `while` bodies and
+    conditions, a conditional's branches, called computations. A fusion's
+    body belongs to its fusion; the computations that a reduce, a sort or a
+    scatter applies belong to that instruction. Fields of a row:
+
+    - ``name``, ``opcode``, ``kind`` (a fusion's), ``computation``,
+      ``parent``: the instruction that calls its computation (None in the
+      entry); an instruction whose opcode is in `CONTAINER_OPCODES` only
+      holds others;
+    - ``scope``: the raw `op_name` (None where XLA kept none); ``layer``,
+      ``part``: `monitor.scopes.parse` of it, or, for a fusion that XLA
+      left without one, of its body's (the root's, else the first that
+      carries one), or, for any other instruction XLA made without one (a
+      layout copy, the `copy-done` / `slice-done` of a prefetch), of the
+      first op that uses its result and names a part; ``recomputed``:
+      the op is the forward made again inside a `jax.checkpoint` region's
+      backward (`rematted_computation` in its path); ``direction``: ``"backward"`` under a `transpose(`
+      (the op runs in the backward pass: the forward made again too),
+      else ``"forward"``; None without a scope;
+    - ``dot_flops``: 2 x multiply-adds of every `dot` and `convolution`
+      the instruction holds, a fusion those of its body; 0 for custom
+      calls (the Pallas kernels, XLA's grouped products), whose work is
+      counted from the configuration by their own rooflines;
+    - ``bytes_out``, ``bytes_in``: result and operand bytes as the shapes
+      say: an UPPER bound where an op reads a slice of an operand
+      (`dynamic-slice`, `gather`, the stacked operands of a scan).
+
+    Instructions that do nothing on the device (parameters, tuples and
+    their elements, constants, bitcasts) are left out."""
+    from deeplearning4j_tpu.monitor import scopes
+    comps: Dict[str, list] = {}
+    shapes: Dict[str, str] = {}
+    entry = current = None
+    for line in text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m and line.rstrip().endswith("{"):
+                current = comps.setdefault(m.group(2), [])
+                if m.group(1):
+                    entry = m.group(2)
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        got = _split_instruction(line)
+        if got:
+            shapes[got[0]] = got[1]
+            current.append(got)
+
+    flops_of: Dict[str, int] = {}
+
+    def body_flops(comp: str) -> int:
+        """The dots and convolutions of a fusion's body (a body can hold
+        fusions of its own)."""
+        if comp not in flops_of:
+            flops_of[comp] = 0          # a cycle cannot be; be safe
+            flops_of[comp] = sum(own_flops(i) for i in comps.get(comp, ()))
+        return flops_of[comp]
+
+    def own_flops(instr) -> int:
+        _, shape, opcode, operands, attrs = instr
+        if opcode == "fusion":
+            called = _CALLED.search(attrs)
+            return body_flops(called.group(2)) if called else 0
+        if opcode not in ("dot", "convolution") or len(operands) < 2:
+            return 0
+        dims = lambda s: (_dims(s) or [("", [])])[0][1]
+        result = dims(shape)
+        lhs, rhs = (dims(shapes.get(o, "")) for o in operands[:2])
+        try:
+            return _dot_flops(attrs, result, lhs) if opcode == "dot" \
+                else _conv_flops(attrs, result, lhs, rhs)
+        except (ValueError, IndexError):    # a form this does not know
+            return 0
+
+    def own_scope(instr) -> Optional[str]:
+        op_name = re.search(r'op_name="([^"]*)"', instr[4])
+        return op_name.group(1) if op_name else None
+
+    def body_scope(instr, depth=0) -> Optional[str]:
+        """The op_name of a fusion's body: its root's (a fusion's: that
+        one's body's), else the first instruction's that carries one."""
+        called = _CALLED.search(instr[4])
+        body = comps.get(called.group(2), ()) if called else ()
+        for inner in (*body[-1:], *body):
+            found = own_scope(inner) or (
+                body_scope(inner, depth + 1)
+                if inner[2] == "fusion" and depth < 8 else None)
+            if found:
+                return found
+        return None
+
+    users: Dict[str, Dict[str, list]] = {}
+    placed_by: Dict[str, Optional[str]] = {}
+
+    def user_scope(instr, comp: str, depth=0) -> Optional[str]:
+        """For an instruction XLA made without an op_name (a layout copy,
+        the `copy-done` / `slice-done` of a prefetch): the op_name of the
+        first op that uses its result and says what part it is, through
+        other such instructions."""
+        if instr[0] in placed_by:
+            return placed_by[instr[0]]
+        placed_by[instr[0]] = None
+        if comp not in users:
+            users[comp] = {}
+            for other in comps[comp]:
+                for operand in other[3]:
+                    users[comp].setdefault(operand, []).append(other)
+        for user in users[comp].get(instr[0], ()) if depth < 8 else ():
+            named = own_scope(user) or (
+                body_scope(user) if user[2] == "fusion" else None)
+            if named is None:           # XLA's own too: look through it
+                named = user_scope(user, comp, depth + 1)
+            elif scopes.parse(named)[1] is None:
+                continue                # a loop or its tuple: no part
+            if named:
+                placed_by[instr[0]] = named
+                break
+        return placed_by[instr[0]]
+
+    rows: List[dict] = []
+    seen = set()
+
+    def walk(comp: str, parent: Optional[str]):
+        if comp in seen or comp not in comps:
+            return
+        seen.add(comp)
+        for instr in comps[comp]:
+            name, shape, opcode, operands, attrs = instr
+            if opcode in _NO_WORK:
+                continue
+            scope = own_scope(instr)
+            # a fusion XLA left without an op_name is placed by its
+            # body's, any other such instruction by the op it serves
+            placed = scope or (body_scope(instr) if opcode == "fusion"
+                               else None) or user_scope(instr, comp)
+            layer, part = scopes.parse(placed) if placed else (None, None)
+            kind = re.search(r"\bkind=(k\w+)", attrs) \
+                if opcode == "fusion" else None
+            rows.append({
+                "name": name, "opcode": opcode,
+                "kind": kind.group(1) if kind else None,
+                "computation": comp, "parent": parent,
+                "scope": scope, "layer": layer, "part": part,
+                "recomputed": bool(placed)
+                and "rematted_computation" in placed,
+                "direction": None if not placed else
+                "backward" if "transpose(" in placed else "forward",
+                "dot_flops": own_flops(instr),
+                "bytes_out": _shape_bytes(shape),
+                "bytes_in": sum(_shape_bytes(shapes.get(o, ""))
+                                for o in operands)})
+            if opcode in CONTAINER_OPCODES:
+                called = [c for _, c in _CALLED.findall(attrs)]
+                branches = _BRANCHES.search(attrs)
+                if branches:
+                    called += re.findall(r"%([\w.\-]+)", branches.group(1))
+                for c in called:
+                    walk(c, name)
+
+    if entry is not None:
+        walk(entry, None)
+    return rows
+
+
+class OpTable(dict):
+    """``{instruction name: op_name}`` of a compiled program, for the
+    instructions that carry one (what `ProgramRecord.op_scopes` has always
+    been): a view of ``rows``, the table `parse_hlo_ops` made."""
+
+    def __init__(self, rows=()):
+        super().__init__((r["name"], r["scope"]) for r in rows
+                         if r["scope"])
+        self.rows = list(rows)
+
+
+def compiled_op_scopes(compiled) -> "OpTable":
+    """The one parse of a jax.stages.Compiled: its instruction table
+    (`.rows`, see `parse_hlo_ops`) under the map from instruction name to
+    `op_name` that the scope readers join a trace with. Empty when the
+    backend gives no text."""
     try:
         text = compiled.as_text()
     except Exception:  # noqa: BLE001 — no HLO text: no map, readers get None
-        return {}
-    return dict(_OP_NAME.findall(text or ""))
+        return OpTable()
+    return OpTable(parse_hlo_ops(text or ""))
+
+
+def compiled_ops(compiled) -> List[dict]:
+    """The rows of `compiled_op_scopes(compiled)`: to look at a step
+    compiled by hand (chiplessly, say) without the ledger."""
+    return compiled_op_scopes(compiled).rows
 
 
 #: the Pallas kernels of ops/, by the name their `pallas_call` gives the

@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.conf.base import InputType, Kind, register_layer
@@ -48,7 +49,8 @@ class MergeVertex(GraphVertexConf):
         raise ValueError(k)
 
     def apply(self, *inputs):
-        return jnp.concatenate(inputs, axis=-1)
+        with jax.named_scope("merge"):
+            return jnp.concatenate(inputs, axis=-1)
 
 
 @register_layer
@@ -61,27 +63,28 @@ class ElementWiseVertex(GraphVertexConf):
         return input_types[0]
 
     def apply(self, *inputs):
-        op = self.op.lower()
-        if op == "add":
-            out = inputs[0]
-            for x in inputs[1:]:
-                out = out + x
-            return out
-        if op == "subtract":
-            return inputs[0] - inputs[1]
-        if op in ("product", "mul"):
-            out = inputs[0]
-            for x in inputs[1:]:
-                out = out * x
-            return out
-        if op == "max":
-            out = inputs[0]
-            for x in inputs[1:]:
-                out = jnp.maximum(out, x)
-            return out
-        if op in ("average", "avg"):
-            return sum(inputs) / float(len(inputs))
-        raise ValueError(f"Unknown ElementWise op {self.op}")
+        with jax.named_scope("merge"):
+            op = self.op.lower()
+            if op == "add":
+                out = inputs[0]
+                for x in inputs[1:]:
+                    out = out + x
+                return out
+            if op == "subtract":
+                return inputs[0] - inputs[1]
+            if op in ("product", "mul"):
+                out = inputs[0]
+                for x in inputs[1:]:
+                    out = out * x
+                return out
+            if op == "max":
+                out = inputs[0]
+                for x in inputs[1:]:
+                    out = jnp.maximum(out, x)
+                return out
+            if op in ("average", "avg"):
+                return sum(inputs) / float(len(inputs))
+            raise ValueError(f"Unknown ElementWise op {self.op}")
 
 
 @register_layer
@@ -254,10 +257,11 @@ class ShiftTimeSeriesVertex(GraphVertexConf):
         return input_types[0]
 
     def apply(self, *inputs):
-        x = inputs[0]
-        pad = [(0, 0)] * x.ndim
-        pad[1] = (0, self.steps)
-        return jnp.pad(x[:, self.steps:], pad)
+        with jax.named_scope("shift"):
+            x = inputs[0]
+            pad = [(0, 0)] * x.ndim
+            pad[1] = (0, self.steps)
+            return jnp.pad(x[:, self.steps:], pad)
 
 
 @register_layer

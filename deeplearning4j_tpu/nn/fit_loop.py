@@ -820,7 +820,7 @@ def fit(net, data, epochs, scan_steps, accumulate_steps, plan, **source):
 # data stack and unmaps a chunk the moment the frame at its base returns.
 # Tracing and lowering a step go up and down thousands of frames; where a
 # chunk boundary happens to fall inside that recursion, every crossing maps,
-# faults and unmaps 16 KiB (ROADMAP S5a: 100,000-250,000 times a fit() of
+# faults and unmaps 16 KiB (ROADMAP S6, D14: 100,000-250,000 times a fit() of
 # the benchmark's cells, a quarter of `setup_s` on the chip, and how many
 # depends on the byte depth of the Python stack at the call, so on every
 # refactor above it). A frame that asks for 512 KiB gets a 1 MiB chunk of

@@ -23,6 +23,7 @@ import numpy as np
 import optax
 
 from deeplearning4j_tpu.data.dataset import DataSet, MultiDataSet
+from deeplearning4j_tpu.monitor.scopes import layer_scope
 from deeplearning4j_tpu.nn.conf.base import (
     InputType, Kind, LayerConf, preprocess_forward, preprocessed_type,
 )
@@ -40,7 +41,8 @@ log = logging.getLogger("deeplearning4j_tpu")
 
 
 def _scoped(scope: Optional[str]):
-    """The `jax.named_scope` a vertex asked for, or nothing."""
+    """The `jax.named_scope` a vertex asked for, or nothing. It stands
+    OUTSIDE the vertex's own layer scope (`monitor/scopes.py`)."""
     return contextlib.nullcontext() if scope is None \
         else jax.named_scope(scope)
 
@@ -238,12 +240,10 @@ class ComputationGraph:
         )
         if self._vertex_types is None:
             self._vertex_types = self._resolve_types()
-        # under gradient checkpointing a layer casts its own weights
-        # inside its rematerialised region (`_layer_call`)
+        # a layer casts its own weights, under its own scope; under
+        # gradient checkpointing inside its rematerialised region
+        # (`_layer_call`)
         remat = train and self.conf.gradient_checkpointing
-        late_cast = self._cast_params if remat else None
-        if not remat:
-            params = self._cast_params(params)
         acts: Dict[str, Any] = {}
         masks: Dict[str, Any] = {}
         for i, name in enumerate(self.conf.network_inputs):
@@ -258,7 +258,7 @@ class ComputationGraph:
             xs = [acts[i] for i in vd.inputs]
             in_masks = [masks[i] for i in vd.inputs]
             if isinstance(vd.vertex, GraphVertexConf):
-                with _scoped(vd.scope):
+                with _scoped(vd.scope), layer_scope(name):
                     acts[name] = vd.vertex.apply(*xs)
                 masks[name] = self._vertex_out_mask(
                     vd.vertex, in_masks, xs, self._vertex_types[name])
@@ -267,7 +267,9 @@ class ComputationGraph:
             need = self._pre_kind[name]
             src_t = self._input_type_of(vd.inputs[0])
             if need is not None and src_t.kind != need:
-                x = preprocess_forward(src_t, need, x)
+                with _scoped(vd.scope), layer_scope(name), \
+                        jax.named_scope("layout"):
+                    x = preprocess_forward(src_t, need, x)
             sub_rng = None
             if rng is not None:
                 rng, sub_rng = jax.random.split(rng)
@@ -275,8 +277,11 @@ class ComputationGraph:
             if name in out_set:
                 acts["__pre__" + name] = x
             layer_params = params.get(vd.params_of or name, {})
-            if remat and getattr(vd.vertex, "weight_noise", None) is not None:
-                layer_params = self._cast_params(layer_params)
+            if getattr(vd.vertex, "weight_noise", None) is not None:
+                # the noise is drawn in the compute dtype
+                with _scoped(vd.scope), layer_scope(name), \
+                        jax.named_scope("cast"):
+                    layer_params = self._cast_params(layer_params)
             if train and sub_rng is not None and \
                     getattr(vd.vertex, "weight_noise", None) is not None:
                 from deeplearning4j_tpu.nn.regularization import (
@@ -291,18 +296,19 @@ class ComputationGraph:
             if carries is not None and _is_stateful_recurrent(vd.vertex):
                 with _scoped(vd.scope):
                     y, carry = _layer_call(
-                        vd.vertex, seq=True, train=train, remat=remat,
-                        params=layer_params, x=x, carry=carries.get(name),
-                        rng=sub_rng, mask=m, cast=late_cast)
+                        vd.vertex, name=name, seq=True, train=train,
+                        remat=remat, params=layer_params, x=x,
+                        carry=carries.get(name), rng=sub_rng, mask=m,
+                        cast=self._cast_params)
                 new_carries[name] = carry
                 new_state[name] = state.get(name, {})
             else:
                 with _scoped(vd.scope):
                     y, s = _layer_call(
-                        vd.vertex, seq=False, train=train, remat=remat,
-                        params=layer_params, x=x,
+                        vd.vertex, name=name, seq=False, train=train,
+                        remat=remat, params=layer_params, x=x,
                         state=state.get(name, {}), rng=sub_rng, mask=m,
-                        cast=late_cast)
+                        cast=self._cast_params)
                 new_state[name] = s
             acts[name] = y
             masks[name] = (in_masks[0]
@@ -347,9 +353,7 @@ class ComputationGraph:
         """`_score_fn` and, third, each output's own loss (unweighted, in
         `network_outputs`' order): the score is their sum under the
         configuration's ``output_weights`` plus the regularization."""
-        params_c = self._cast_params(params)
-        # the forward casts the weights itself, a vertex at a time under
-        # gradient checkpointing
+        # the forward casts the weights itself, a vertex at a time
         acts, new_state, new_carries, masks = self._forward(
             params, state, inputs, train, rng, fmasks, stash_pre=True,
             carries=carries)
@@ -367,10 +371,12 @@ class ComputationGraph:
                 # mask propagated along THIS output's input path
                 lmask = masks[vd.inputs[0]]
             lab = _as_jnp(labels[i], self._compute_dtype)
-            with _scoped(vd.scope):
-                s = vd.vertex.score(
-                    params_c.get(vd.params_of or out_name, {}), feat, lab,
-                    train=train, rng=None, mask=lmask)
+            with _scoped(vd.scope), layer_scope(out_name):
+                with jax.named_scope("cast"):
+                    head_params = self._cast_params(
+                        params.get(vd.params_of or out_name, {}))
+                s = vd.vertex.score(head_params, feat, lab, train=train,
+                                    rng=None, mask=lmask)
             # keep f64 under float64 gradient checking; f32 otherwise
             s = s.astype(jnp.promote_types(jnp.float32, s.dtype))
             parts.append(s)
@@ -378,7 +384,8 @@ class ComputationGraph:
         for name, p in params.items():
             vd = self.conf.vertices[name]
             if isinstance(vd.vertex, LayerConf):
-                total = total + vd.vertex.regularization_score(p)
+                with layer_scope(name), jax.named_scope("reg"):
+                    total = total + vd.vertex.regularization_score(p)
         return total, (new_state, new_carries), tuple(parts)
 
     def _make_scan_step(self):

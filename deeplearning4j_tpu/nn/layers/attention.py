@@ -89,10 +89,11 @@ class LayerNormLayer(LayerConf):
                 "beta": jnp.zeros((f,), dtype)}, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        y = (x - mean) * jax.lax.rsqrt(var + self.epsilon)
-        return y * params["gamma"] + params["beta"], state
+        with jax.named_scope("norm"):
+            mean = jnp.mean(x, axis=-1, keepdims=True)
+            var = jnp.var(x, axis=-1, keepdims=True)
+            y = (x - mean) * jax.lax.rsqrt(var + self.epsilon)
+            return y * params["gamma"] + params["beta"], state
 
 
 @register_layer
@@ -110,11 +111,18 @@ class RMSNormLayer(LayerConf):
         return {"gamma": jnp.ones((input_type.features,), dtype)}, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        acc_t = jnp.promote_types(jnp.float32, x.dtype)
-        xf = x.astype(acc_t)
-        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + self.epsilon)
-        return (y * params["gamma"].astype(acc_t)).astype(x.dtype), state
+        with jax.named_scope("norm"):
+            return rms_norm(x, params["gamma"], self.epsilon), state
+
+
+def rms_norm(x, gamma, epsilon):
+    """`RMSNormLayer`'s arithmetic under no scope of its own, for a layer
+    that norms inside one of its parts (`mha/norm`)."""
+    acc_t = jnp.promote_types(jnp.float32, x.dtype)
+    xf = x.astype(acc_t)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    y = xf * jax.lax.rsqrt(ms + epsilon)
+    return (y * gamma.astype(acc_t)).astype(x.dtype)
 
 
 def _norm_layer(kind: str, epsilon: Optional[float]):
@@ -292,9 +300,8 @@ class MultiHeadAttention(LayerConf):
             q, k, v = self._qkv(params, x)
         if self.qk_norm:
             with jax.named_scope("mha/norm"):
-                norm = RMSNormLayer(epsilon=self.norm_epsilon)
-                q = norm.apply({"gamma": params["q_norm"]}, {}, q)[0]
-                k = norm.apply({"gamma": params["k_norm"]}, {}, k)[0]
+                q = rms_norm(q, params["q_norm"], self.norm_epsilon)
+                k = rms_norm(k, params["k_norm"], self.norm_epsilon)
         t_loc = x.shape[1]
         offset = _seq_offset(t_loc)
         if self.use_rope:
@@ -851,10 +858,12 @@ class MoEFeedForward(LayerConf):
         # a dispatch over tiers rematerialises the tier it walked itself
         one = self._dispatch if len(self._tiers(blk * self.top_k)) > 1 \
             else jax.checkpoint(self._dispatch)
-        out, counts = jax.lax.map(
-            lambda a: one(weights, *a),
-            (h.reshape(-1, blk, shape[-1]), idx.reshape(-1, blk, self.top_k),
-             w.reshape(-1, blk, self.top_k)))
+        with jax.named_scope("moe/blocks"):
+            out, counts = jax.lax.map(
+                lambda a: one(weights, *a),
+                (h.reshape(-1, blk, shape[-1]),
+                 idx.reshape(-1, blk, self.top_k),
+                 w.reshape(-1, blk, self.top_k)))
         # a block rematerialised under the containers' gradient
         # checkpointing keeps this result: its second forward pass does
         # not dispatch again
@@ -954,11 +963,14 @@ class MoEFeedForward(LayerConf):
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         out, counts = self.experts(params, x,
                                    *self.route(params, state, x))
-        if self.n_shared:
-            out = out + self.shared(params, x)
-        if mask is not None:
-            out = out * mask[..., None].astype(out.dtype)
-        return out, self.counted(state, counts)
+        shared = self.shared(params, x) if self.n_shared else None
+        with jax.named_scope("residual"):
+            if shared is not None:
+                out = out + shared
+            if mask is not None:
+                out = out * mask[..., None].astype(out.dtype)
+        with jax.named_scope("moe/route"):
+            return out, self.counted(state, counts)
 
 
 @register_layer
@@ -1010,7 +1022,8 @@ class LinearProjection(LayerConf):
             key, (f_in, self.n_out), f_in, self.n_out, dtype)}, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        return x @ params["W"], state
+        with jax.named_scope("proj"):
+            return x @ params["W"], state
 
 
 @register_layer
@@ -1092,10 +1105,11 @@ class TransformerBlock(LayerConf):
         h, _ = ln.apply(params["ln1"], {}, x)
         a, _ = attn.apply(params["attn"], {}, h, train=train, rng=r1,
                           mask=mask)
-        if train and self.residual_dropout > 0 and r2 is not None:
-            keep = 1.0 - self.residual_dropout
-            a = a * jax.random.bernoulli(r2, keep, a.shape) / keep
-        x = x + a
+        with jax.named_scope("residual"):
+            if train and self.residual_dropout > 0 and r2 is not None:
+                keep = 1.0 - self.residual_dropout
+                a = a * jax.random.bernoulli(r2, keep, a.shape) / keep
+            x = x + a
         h, _ = ln.apply(params["ln2"], {}, x)
         if self.ffn is not None:
             h, ffn_state = self.ffn.apply(params["ffn"],
@@ -1103,15 +1117,17 @@ class TransformerBlock(LayerConf):
             if ffn_state:
                 state = {**state, "ffn": ffn_state}
         else:
-            h = h @ params["W1"]
-            if self.has_bias:
-                h = h + params["b1"]
-            h = get_activation(self.activation)(h) @ params["W2"]
-            if self.has_bias:
-                h = h + params["b2"]
-        y = x + h
-        if mask is not None:
-            y = y * mask[..., None].astype(y.dtype)
+            with jax.named_scope("dense"):
+                h = h @ params["W1"]
+                if self.has_bias:
+                    h = h + params["b1"]
+                h = get_activation(self.activation)(h) @ params["W2"]
+                if self.has_bias:
+                    h = h + params["b2"]
+        with jax.named_scope("residual"):
+            y = x + h
+            if mask is not None:
+                y = y * mask[..., None].astype(y.dtype)
         return y, state
 
 @register_layer
@@ -1129,25 +1145,26 @@ class PositionalEmbeddingLayer(LayerConf):
                 * 0.02}, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        t = x.shape[1]
-        start = _seq_offset(t)
-        if isinstance(start, int) and start == 0:
-            if t > self.max_length:
-                raise ValueError(
-                    f"sequence length {t} exceeds max_length "
-                    f"{self.max_length}")
-            pos = params["P"][:t]
-        else:    # context-parallel shard: take this shard's slice
-            # the global length is static (shard count x local length);
-            # reject overflow at trace time — dynamic_slice would silently
-            # clamp late shards onto the tail rows
-            global_t = t * jax.lax.psum(1, _CONTEXT_PARALLEL_AXIS)
-            if int(global_t) > self.max_length:
-                raise ValueError(
-                    f"global sequence length {int(global_t)} exceeds "
-                    f"max_length {self.max_length}")
-            pos = jax.lax.dynamic_slice_in_dim(params["P"], start, t)
-        return x + pos[None], state
+        with jax.named_scope("embed"):
+            t = x.shape[1]
+            start = _seq_offset(t)
+            if isinstance(start, int) and start == 0:
+                if t > self.max_length:
+                    raise ValueError(
+                        f"sequence length {t} exceeds max_length "
+                        f"{self.max_length}")
+                pos = params["P"][:t]
+            else:    # context-parallel shard: take this shard's slice
+                # the global length is static (shard count x local length);
+                # reject overflow at trace time — dynamic_slice would silently
+                # clamp late shards onto the tail rows
+                global_t = t * jax.lax.psum(1, _CONTEXT_PARALLEL_AXIS)
+                if int(global_t) > self.max_length:
+                    raise ValueError(
+                        f"global sequence length {int(global_t)} exceeds "
+                        f"max_length {self.max_length}")
+                pos = jax.lax.dynamic_slice_in_dim(params["P"], start, t)
+            return x + pos[None], state
 
 
 @register_layer
@@ -1174,10 +1191,11 @@ class EmbeddingSequenceLayer(LayerConf):
         return {"W": table}, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        if x.ndim == 3:
-            x = x[..., 0]
-        idx = x.astype(jnp.int32)
-        y = jnp.take(params["W"], idx, axis=0)
-        if mask is not None:
-            y = y * mask[..., None].astype(y.dtype)
-        return y, state
+        with jax.named_scope("embed"):
+            if x.ndim == 3:
+                x = x[..., 0]
+            idx = x.astype(jnp.int32)
+            y = jnp.take(params["W"], idx, axis=0)
+            if mask is not None:
+                y = y * mask[..., None].astype(y.dtype)
+            return y, state

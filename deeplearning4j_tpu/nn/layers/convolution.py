@@ -88,22 +88,23 @@ class ConvolutionLayer(LayerConf):
         return params, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        # No preferred_element_type here (or in the other conv variants):
-        # JAX's conv transpose rule rejects the mixed-dtype cotangent it
-        # produces under bf16 compute, and the TPU MXU accumulates bf16
-        # convolutions in f32 regardless — the f32-accumulation invariant
-        # holds without requesting it.
-        x = self.maybe_dropout_input(x, train, rng)
-        y = lax.conv_general_dilated(
-            x, params["W"],
-            window_strides=_pair(self.stride),
-            padding=_padding(self.convolution_mode),
-            rhs_dilation=_pair(self.dilation),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-        if self.has_bias:
-            y = y + params["b"]
-        return get_activation(self.activation)(y), state
+        with jax.named_scope("conv"):
+            # No preferred_element_type here (or in the other conv variants):
+            # JAX's conv transpose rule rejects the mixed-dtype cotangent it
+            # produces under bf16 compute, and the TPU MXU accumulates bf16
+            # convolutions in f32 regardless — the f32-accumulation invariant
+            # holds without requesting it.
+            x = self.maybe_dropout_input(x, train, rng)
+            y = lax.conv_general_dilated(
+                x, params["W"],
+                window_strides=_pair(self.stride),
+                padding=_padding(self.convolution_mode),
+                rhs_dilation=_pair(self.dilation),
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            )
+            if self.has_bias:
+                y = y + params["b"]
+            return get_activation(self.activation)(y), state
 
 
 @register_layer
@@ -124,17 +125,18 @@ class Deconvolution2D(ConvolutionLayer):
         return InputType.convolutional(oh, ow, self.n_out)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        x = self.maybe_dropout_input(x, train, rng)
-        y = lax.conv_transpose(
-            x, params["W"],
-            strides=_pair(self.stride),
-            padding=_padding(self.convolution_mode),
-            rhs_dilation=_pair(self.dilation),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-        if self.has_bias:
-            y = y + params["b"]
-        return get_activation(self.activation)(y), state
+        with jax.named_scope("conv"):
+            x = self.maybe_dropout_input(x, train, rng)
+            y = lax.conv_transpose(
+                x, params["W"],
+                strides=_pair(self.stride),
+                padding=_padding(self.convolution_mode),
+                rhs_dilation=_pair(self.dilation),
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            )
+            if self.has_bias:
+                y = y + params["b"]
+            return get_activation(self.activation)(y), state
 
 
 @register_layer
@@ -172,19 +174,20 @@ class DepthwiseConvolution2D(LayerConf):
         return params, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        x = self.maybe_dropout_input(x, train, rng)
-        c_in = x.shape[-1]
-        y = lax.conv_general_dilated(
-            x, params["W"],
-            window_strides=_pair(self.stride),
-            padding=_padding(self.convolution_mode),
-            rhs_dilation=_pair(self.dilation),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=c_in,
-        )
-        if self.has_bias:
-            y = y + params["b"]
-        return get_activation(self.activation)(y), state
+        with jax.named_scope("conv"):
+            x = self.maybe_dropout_input(x, train, rng)
+            c_in = x.shape[-1]
+            y = lax.conv_general_dilated(
+                x, params["W"],
+                window_strides=_pair(self.stride),
+                padding=_padding(self.convolution_mode),
+                rhs_dilation=_pair(self.dilation),
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                feature_group_count=c_in,
+            )
+            if self.has_bias:
+                y = y + params["b"]
+            return get_activation(self.activation)(y), state
 
 
 @register_layer
@@ -225,22 +228,23 @@ class SeparableConvolution2D(LayerConf):
         return params, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        x = self.maybe_dropout_input(x, train, rng)
-        c_in = x.shape[-1]
-        y = lax.conv_general_dilated(
-            x, params["dW"], window_strides=_pair(self.stride),
-            padding=_padding(self.convolution_mode),
-            rhs_dilation=_pair(self.dilation),
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            feature_group_count=c_in,
-        )
-        y = lax.conv_general_dilated(
-            y, params["pW"], window_strides=(1, 1), padding="VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-        if self.has_bias:
-            y = y + params["b"]
-        return get_activation(self.activation)(y), state
+        with jax.named_scope("conv"):
+            x = self.maybe_dropout_input(x, train, rng)
+            c_in = x.shape[-1]
+            y = lax.conv_general_dilated(
+                x, params["dW"], window_strides=_pair(self.stride),
+                padding=_padding(self.convolution_mode),
+                rhs_dilation=_pair(self.dilation),
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                feature_group_count=c_in,
+            )
+            y = lax.conv_general_dilated(
+                y, params["pW"], window_strides=(1, 1), padding="VALID",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            )
+            if self.has_bias:
+                y = y + params["b"]
+            return get_activation(self.activation)(y), state
 
 
 @register_layer
@@ -267,28 +271,29 @@ class SubsamplingLayer(LayerConf):
         return False
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        kh, kw = _pair(self.kernel)
-        sh, sw = _pair(self.stride)
-        dims = (1, kh, kw, 1)
-        strides = (1, sh, sw, 1)
-        pad = _padding(self.convolution_mode)
-        pt = self.pooling_type.lower()
-        if pt == "max":
-            y = lax.reduce_window(x, -jnp.inf, lax.max, dims, strides, pad)
-        elif pt == "sum":
-            y = lax.reduce_window(x, 0.0, lax.add, dims, strides, pad)
-        elif pt == "avg":
-            s = lax.reduce_window(x, 0.0, lax.add, dims, strides, pad)
-            ones = jnp.ones_like(x)
-            cnt = lax.reduce_window(ones, 0.0, lax.add, dims, strides, pad)
-            y = s / cnt
-        elif pt == "pnorm":
-            p = float(self.pnorm)
-            s = lax.reduce_window(jnp.abs(x) ** p, 0.0, lax.add, dims, strides, pad)
-            y = s ** (1.0 / p)
-        else:
-            raise ValueError(f"Unknown pooling type {self.pooling_type}")
-        return y, state
+        with jax.named_scope("pool"):
+            kh, kw = _pair(self.kernel)
+            sh, sw = _pair(self.stride)
+            dims = (1, kh, kw, 1)
+            strides = (1, sh, sw, 1)
+            pad = _padding(self.convolution_mode)
+            pt = self.pooling_type.lower()
+            if pt == "max":
+                y = lax.reduce_window(x, -jnp.inf, lax.max, dims, strides, pad)
+            elif pt == "sum":
+                y = lax.reduce_window(x, 0.0, lax.add, dims, strides, pad)
+            elif pt == "avg":
+                s = lax.reduce_window(x, 0.0, lax.add, dims, strides, pad)
+                ones = jnp.ones_like(x)
+                cnt = lax.reduce_window(ones, 0.0, lax.add, dims, strides, pad)
+                y = s / cnt
+            elif pt == "pnorm":
+                p = float(self.pnorm)
+                s = lax.reduce_window(jnp.abs(x) ** p, 0.0, lax.add, dims, strides, pad)
+                y = s ** (1.0 / p)
+            else:
+                raise ValueError(f"Unknown pooling type {self.pooling_type}")
+            return y, state
 
 
 @register_layer
@@ -307,39 +312,40 @@ class GlobalPoolingLayer(LayerConf):
         return False
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        if x.ndim == 4:       # (B,H,W,C)
-            axes = (1, 2)
-        elif x.ndim == 3:     # (B,T,F)
-            axes = (1,)
-        else:
-            raise ValueError(f"GlobalPooling expects 3d/4d input, got {x.shape}")
-        pt = self.pooling_type.lower()
-        if mask is not None and x.ndim == 3:
-            m = mask[..., None].astype(x.dtype)
+        with jax.named_scope("pool"):
+            if x.ndim == 4:       # (B,H,W,C)
+                axes = (1, 2)
+            elif x.ndim == 3:     # (B,T,F)
+                axes = (1,)
+            else:
+                raise ValueError(f"GlobalPooling expects 3d/4d input, got {x.shape}")
+            pt = self.pooling_type.lower()
+            if mask is not None and x.ndim == 3:
+                m = mask[..., None].astype(x.dtype)
+                if pt == "max":
+                    y = jnp.max(jnp.where(m > 0, x, -jnp.inf), axis=1)
+                elif pt == "sum":
+                    y = jnp.sum(x * m, axis=1)
+                elif pt == "avg":
+                    y = jnp.sum(x * m, axis=1) / jnp.maximum(jnp.sum(m, axis=1), 1.0)
+                elif pt == "pnorm":
+                    p = float(self.pnorm)
+                    y = jnp.sum((jnp.abs(x) * m) ** p, axis=1) ** (1.0 / p)
+                else:
+                    raise ValueError(self.pooling_type)
+                return y, state
             if pt == "max":
-                y = jnp.max(jnp.where(m > 0, x, -jnp.inf), axis=1)
+                y = jnp.max(x, axis=axes)
             elif pt == "sum":
-                y = jnp.sum(x * m, axis=1)
+                y = jnp.sum(x, axis=axes)
             elif pt == "avg":
-                y = jnp.sum(x * m, axis=1) / jnp.maximum(jnp.sum(m, axis=1), 1.0)
+                y = jnp.mean(x, axis=axes)
             elif pt == "pnorm":
                 p = float(self.pnorm)
-                y = jnp.sum((jnp.abs(x) * m) ** p, axis=1) ** (1.0 / p)
+                y = jnp.sum(jnp.abs(x) ** p, axis=axes) ** (1.0 / p)
             else:
                 raise ValueError(self.pooling_type)
             return y, state
-        if pt == "max":
-            y = jnp.max(x, axis=axes)
-        elif pt == "sum":
-            y = jnp.sum(x, axis=axes)
-        elif pt == "avg":
-            y = jnp.mean(x, axis=axes)
-        elif pt == "pnorm":
-            p = float(self.pnorm)
-            y = jnp.sum(jnp.abs(x) ** p, axis=axes) ** (1.0 / p)
-        else:
-            raise ValueError(self.pooling_type)
-        return y, state
 
 
 @register_layer
@@ -374,8 +380,9 @@ class ZeroPaddingLayer(LayerConf):
         return False
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        t, b, l, r = self.padding
-        return jnp.pad(x, ((0, 0), (t, b), (l, r), (0, 0))), state
+        with jax.named_scope("layout"):
+            t, b, l, r = self.padding
+            return jnp.pad(x, ((0, 0), (t, b), (l, r), (0, 0))), state
 
 
 @register_layer
@@ -411,11 +418,12 @@ class SpaceToDepthLayer(LayerConf):
         return False
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        b, h, w, c = x.shape
-        bs = self.block_size
-        x = x.reshape(b, h // bs, bs, w // bs, bs, c)
-        x = x.transpose(0, 1, 3, 2, 4, 5)
-        return x.reshape(b, h // bs, w // bs, bs * bs * c), state
+        with jax.named_scope("layout"):
+            b, h, w, c = x.shape
+            bs = self.block_size
+            x = x.reshape(b, h // bs, bs, w // bs, bs, c)
+            x = x.transpose(0, 1, 3, 2, 4, 5)
+            return x.reshape(b, h // bs, w // bs, bs * bs * c), state
 
 
 @register_layer
@@ -469,16 +477,17 @@ class Convolution1DLayer(LayerConf):
         return params, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        x = self.maybe_dropout_input(x, train, rng)
-        y = lax.conv_general_dilated(
-            x, params["W"], window_strides=(self.stride,),
-            padding=_padding(self.convolution_mode),
-            rhs_dilation=(self.dilation,),
-            dimension_numbers=("NWC", "WIO", "NWC"),
-        )
-        if self.has_bias:
-            y = y + params["b"]
-        return get_activation(self.activation)(y), state
+        with jax.named_scope("conv"):
+            x = self.maybe_dropout_input(x, train, rng)
+            y = lax.conv_general_dilated(
+                x, params["W"], window_strides=(self.stride,),
+                padding=_padding(self.convolution_mode),
+                rhs_dilation=(self.dilation,),
+                dimension_numbers=("NWC", "WIO", "NWC"),
+            )
+            if self.has_bias:
+                y = y + params["b"]
+            return get_activation(self.activation)(y), state
 
 
 @register_layer
@@ -498,15 +507,16 @@ class Subsampling1DLayer(LayerConf):
         return False
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        dims, strides = (1, self.kernel, 1), (1, self.stride, 1)
-        pad = _padding(self.convolution_mode)
-        if self.pooling_type == "max":
-            y = lax.reduce_window(x, -jnp.inf, lax.max, dims, strides, pad)
-        else:
-            s = lax.reduce_window(x, 0.0, lax.add, dims, strides, pad)
-            cnt = lax.reduce_window(jnp.ones_like(x), 0.0, lax.add, dims, strides, pad)
-            y = s / cnt
-        return y, state
+        with jax.named_scope("pool"):
+            dims, strides = (1, self.kernel, 1), (1, self.stride, 1)
+            pad = _padding(self.convolution_mode)
+            if self.pooling_type == "max":
+                y = lax.reduce_window(x, -jnp.inf, lax.max, dims, strides, pad)
+            else:
+                s = lax.reduce_window(x, 0.0, lax.add, dims, strides, pad)
+                cnt = lax.reduce_window(jnp.ones_like(x), 0.0, lax.add, dims, strides, pad)
+                y = s / cnt
+            return y, state
 
 
 @register_layer

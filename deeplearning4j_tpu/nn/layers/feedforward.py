@@ -48,11 +48,12 @@ class DenseLayer(LayerConf):
         return params, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        x = self.maybe_dropout_input(x, train, rng)
-        y = x @ params["W"]
-        if self.has_bias:
-            y = y + params["b"]
-        return get_activation(self.activation)(y), state
+        with jax.named_scope("dense"):
+            x = self.maybe_dropout_input(x, train, rng)
+            y = x @ params["W"]
+            if self.has_bias:
+                y = y + params["b"]
+            return get_activation(self.activation)(y), state
 
 
 @register_layer
@@ -117,13 +118,14 @@ class EmbeddingLayer(LayerConf):
         return params, {}
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        idx = x.astype(jnp.int32)
-        if idx.ndim == 2 and idx.shape[-1] == 1:
-            idx = idx[..., 0]
-        y = jnp.take(params["W"], idx, axis=0)
-        if self.has_bias:
-            y = y + params["b"]
-        return get_activation(self.activation)(y), state
+        with jax.named_scope("embed"):
+            idx = x.astype(jnp.int32)
+            if idx.ndim == 2 and idx.shape[-1] == 1:
+                idx = idx[..., 0]
+            y = jnp.take(params["W"], idx, axis=0)
+            if self.has_bias:
+                y = y + params["b"]
+            return get_activation(self.activation)(y), state
 
 
 @register_layer
@@ -141,10 +143,11 @@ class ActivationLayer(LayerConf):
         return False
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        fn = get_activation(self.activation)
-        if self.alpha is not None:
-            return fn(x, self.alpha), state
-        return fn(x), state
+        with jax.named_scope("act"):
+            fn = get_activation(self.activation)
+            if self.alpha is not None:
+                return fn(x, self.alpha), state
+            return fn(x), state
 
 
 @register_layer
@@ -198,11 +201,13 @@ class OutputLayer(LayerConf):
         return y
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        return get_activation(self.activation)(self.preout(params, x, train, rng)), state
+        with jax.named_scope("dense"):
+            return get_activation(self.activation)(self.preout(params, x, train, rng)), state
 
     def score(self, params, x, labels, *, train=False, rng=None, mask=None):
-        z = self.preout(params, x, train, rng)
-        return get_loss(self.loss)(labels, z, self.activation, mask=mask)
+        with jax.named_scope("head/loss"):
+            z = self.preout(params, x, train, rng)
+            return get_loss(self.loss)(labels, z, self.activation, mask=mask)
 
 
 @register_layer

@@ -429,7 +429,8 @@ class MultiHeadLatentAttention(LayerConf):
                                       block_k=self.block_size)
             else:
                 out = dot_product_attention(q, k, v, mask=mask, causal=True)
-        y = out.reshape(b, t, h * self.v_dim) @ params["Wo"]
-        if mask is not None:
-            y = y * mask[..., None].astype(y.dtype)
+        with jax.named_scope("mla/out"):
+            y = out.reshape(b, t, h * self.v_dim) @ params["Wo"]
+            if mask is not None:
+                y = y * mask[..., None].astype(y.dtype)
         return y, state

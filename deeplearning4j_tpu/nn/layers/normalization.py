@@ -80,24 +80,25 @@ class BatchNormalization(LayerConf):
         return params, state
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        axes = tuple(range(x.ndim - 1))    # all but channel/feature dim
-        stat_t = jnp.promote_types(jnp.float32, x.dtype)
-        if train:
-            mean, var = _batch_moments(x, axes, stat_t)
-            new_state = {
-                "mean": self.decay * state["mean"] + (1.0 - self.decay) * mean,
-                "var": self.decay * state["var"] + (1.0 - self.decay) * var,
-            }
-        else:
-            mean, var = state["mean"], state["var"]
-            new_state = state
-        inv = lax.rsqrt(var + self.epsilon)
-        y = (x - mean.astype(x.dtype)) * inv.astype(x.dtype)
-        if not self.lock_gamma_beta:
-            y = y * params["gamma"] + params["beta"]
-        else:
-            y = y * self.gamma_init + self.beta_init
-        return y, new_state
+        with jax.named_scope("bn"):
+            axes = tuple(range(x.ndim - 1))    # all but channel/feature dim
+            stat_t = jnp.promote_types(jnp.float32, x.dtype)
+            if train:
+                mean, var = _batch_moments(x, axes, stat_t)
+                new_state = {
+                    "mean": self.decay * state["mean"] + (1.0 - self.decay) * mean,
+                    "var": self.decay * state["var"] + (1.0 - self.decay) * var,
+                }
+            else:
+                mean, var = state["mean"], state["var"]
+                new_state = state
+            inv = lax.rsqrt(var + self.epsilon)
+            y = (x - mean.astype(x.dtype)) * inv.astype(x.dtype)
+            if not self.lock_gamma_beta:
+                y = y * params["gamma"] + params["beta"]
+            else:
+                y = y * self.gamma_init + self.beta_init
+            return y, new_state
 
 
 @register_layer

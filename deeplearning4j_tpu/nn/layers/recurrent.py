@@ -440,7 +440,8 @@ class RnnOutputLayer(LayerConf):
         return y
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        return get_activation(self.activation)(self.preout(params, x, train, rng)), state
+        with jax.named_scope("dense"):
+            return get_activation(self.activation)(self.preout(params, x, train, rng)), state
 
     def score(self, params, x, labels, *, train=False, rng=None, mask=None):
         if self.loss == "sparse_mcxent" and self.activation == "softmax":
@@ -452,8 +453,9 @@ class RnnOutputLayer(LayerConf):
                 return self._blocked_score(
                     params, x, labels, train, rng, mask,
                     1 << (most.bit_length() - 1))
-        z = self.preout(params, x, train, rng)
-        return get_loss(self.loss)(labels, z, self.activation, mask=mask)
+        with jax.named_scope("head/loss"):
+            z = self.preout(params, x, train, rng)
+            return get_loss(self.loss)(labels, z, self.activation, mask=mask)
 
     def _blocked_score(self, params, x, labels, train, rng, mask, blk):
         with jax.named_scope("head/loss"):

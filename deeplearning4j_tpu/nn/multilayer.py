@@ -33,6 +33,7 @@ from deeplearning4j_tpu.data.iterator import ArrayDataSetIterator, DataSetIterat
 from deeplearning4j_tpu.nn.conf.base import (
     InputType, Kind, LayerConf, preprocess_forward, preprocessed_type,
 )
+from deeplearning4j_tpu.monitor.scopes import layer_scope
 from deeplearning4j_tpu.nn import fit_loop
 from deeplearning4j_tpu.nn.conf.network import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.fit_loop import (
@@ -89,7 +90,7 @@ def _required_kind(layer: LayerConf) -> Optional[Kind]:
 
 
 def _layer_call(layer, *, seq, train, remat, params, x, state=None,
-                carry=None, rng=None, mask=None, cast=None):
+                carry=None, rng=None, mask=None, cast=None, name=None):
     """Invoke layer.apply (seq=False) or layer.apply_seq (seq=True), with
     jax.checkpoint rematerialization when remat is on: every traced value
     (params/state/carry/input/rng/mask) is a checkpoint ARGUMENT, only the
@@ -97,16 +98,26 @@ def _layer_call(layer, *, seq, train, remat, params, x, state=None,
     params in the compute dtype) is applied INSIDE the rematerialised
     region, so that the cast copy of a layer's weights lives only while
     the layer runs and is not held from the forward pass to the backward.
-    Shared by both containers so the two forward passes can't drift."""
-    cast = cast or (lambda lp: lp)
+    The whole call stands under the layer's own scope (``name``: its index
+    or its vertex; None: no scope), OUTSIDE the checkpoint, so that the
+    forward pass, the forward made again and the backward pass all name
+    their layer (`monitor/scopes.py`). Shared by both containers so the
+    two forward passes can't drift."""
+    def weights(lp):
+        if cast is None:
+            return lp
+        with jax.named_scope("cast"):
+            return cast(lp)
+
     if seq:
         def fn(lp, xx, cc, rr, mm, _l=layer):
-            return _l.apply_seq(cast(lp), xx, cc, train=train, rng=rr,
+            return _l.apply_seq(weights(lp), xx, cc, train=train, rng=rr,
                                 mask=mm)
         args = (params, x, carry, rng, mask)
     else:
         def fn(lp, st, xx, rr, mm, _l=layer):
-            return _l.apply(cast(lp), st, xx, train=train, rng=rr, mask=mm)
+            return _l.apply(weights(lp), st, xx, train=train, rng=rr,
+                            mask=mm)
         args = (params, state, x, rng, mask)
     if remat:
         # nothing is kept but what a layer or a kernel names REMAT_KEEP (a
@@ -115,7 +126,10 @@ def _layer_call(layer, *, seq, train, remat, params, x, state=None,
         fn = jax.checkpoint(
             fn, policy=jax.checkpoint_policies.save_only_these_names(
                 REMAT_KEEP))
-    return fn(*args)
+    if name is None:
+        return fn(*args)
+    with layer_scope(name):
+        return fn(*args)
 
 
 def _masked_eval_pair(labels, preds, labels_mask):
@@ -317,12 +331,10 @@ class MultiLayerNetwork:
         activation, new_state, new_carries)."""
         if self._input_types is None:
             self._input_types = self._resolve_types()
-        # under gradient checkpointing a layer casts its own weights
-        # inside its rematerialised region (`_layer_call`)
+        # a layer casts its own weights, under its own scope; under
+        # gradient checkpointing inside its rematerialised region
+        # (`_layer_call`)
         remat = train and self.conf.gradient_checkpointing
-        late_cast = self._cast_params if remat else None
-        if not remat:
-            params = self._cast_params(params)
         x = _as_jnp(x, self._compute_dtype)
         cur_type = self.conf.input_type
         n = len(self.layers) if upto is None else upto
@@ -331,17 +343,20 @@ class MultiLayerNetwork:
         acts = []
         for i, layer in enumerate(self.layers[:n]):
             need = _required_kind(layer)
+            key = str(i)
             if need is not None and cur_type.kind != need:
-                x = preprocess_forward(cur_type, need, x)
+                with layer_scope(key), jax.named_scope("layout"):
+                    x = preprocess_forward(cur_type, need, x)
                 cur_type = preprocessed_type(cur_type, need)
             sub_rng = None
             if rng is not None:
                 rng, sub_rng = jax.random.split(rng)
             mask = fmask if cur_type.kind == Kind.RNN else None
-            key = str(i)
             layer_params = params[key]
-            if remat and layer.weight_noise is not None:
-                layer_params = self._cast_params(layer_params)
+            if layer.weight_noise is not None:
+                # the noise is drawn in the compute dtype
+                with layer_scope(key), jax.named_scope("cast"):
+                    layer_params = self._cast_params(layer_params)
             if train and sub_rng is not None and layer.weight_noise is not None:
                 from deeplearning4j_tpu.nn.regularization import (
                     apply_weight_noise,
@@ -355,16 +370,16 @@ class MultiLayerNetwork:
             # forward pays for a backward, so inference is untouched.
             if carries is not None and _is_stateful_recurrent(layer):
                 y, carry = _layer_call(
-                    layer, seq=True, train=train, remat=remat,
+                    layer, name=key, seq=True, train=train, remat=remat,
                     params=layer_params, x=x, carry=carries.get(key),
-                    rng=sub_rng, mask=mask, cast=late_cast)
+                    rng=sub_rng, mask=mask, cast=self._cast_params)
                 new_carries[key] = carry
                 new_state[key] = state[key]
             else:
                 y, s = _layer_call(
-                    layer, seq=False, train=train, remat=remat,
+                    layer, name=key, seq=False, train=train, remat=remat,
                     params=layer_params, x=x, state=state[key],
-                    rng=sub_rng, mask=mask, cast=late_cast)
+                    rng=sub_rng, mask=mask, cast=self._cast_params)
                 new_state[key] = s
             x = y
             cur_type = layer.output_type(cur_type)
@@ -374,7 +389,8 @@ class MultiLayerNetwork:
             head = self.layers[upto]
             need = _required_kind(head)
             if need is not None and cur_type.kind != need:
-                x = preprocess_forward(cur_type, need, x)
+                with layer_scope(upto), jax.named_scope("layout"):
+                    x = preprocess_forward(cur_type, need, x)
         return (acts if collect else x), new_state, new_carries
 
     def _score_fn(self, params, state, x, y, fmask, lmask, train, rng,
@@ -384,21 +400,24 @@ class MultiLayerNetwork:
         if not self.layers or not hasattr(self.layers[-1], "score"):
             raise ValueError("Last layer must be an output/loss layer with a "
                              "score() method to compute training loss")
-        params_c = self._cast_params(params)
         # forward up to (but excluding) the output layer; it casts the
-        # weights itself, a layer at a time under gradient checkpointing
-        head = self.layers[-1]
+        # weights itself, a layer at a time
+        head, last = self.layers[-1], str(len(self.layers) - 1)
         feat, new_state, new_carries = self._forward(
             params, state, x, train, rng, fmask, carries,
             upto=len(self.layers) - 1)
         out_mask = lmask if lmask is not None else (
             fmask if _required_kind(head) == Kind.RNN else None)
-        loss = head.score(params_c[str(len(self.layers) - 1)], feat,
-                          _as_jnp(y, self._compute_dtype), train=train,
-                          rng=None, mask=out_mask)
+        with layer_scope(last):
+            with jax.named_scope("cast"):
+                head_params = self._cast_params(params[last])
+            loss = head.score(head_params, feat,
+                              _as_jnp(y, self._compute_dtype), train=train,
+                              rng=None, mask=out_mask)
         reg = jnp.asarray(0.0, jnp.float32)
         for i, layer in enumerate(self.layers):
-            reg = reg + layer.regularization_score(params[str(i)])
+            with layer_scope(i), jax.named_scope("reg"):
+                reg = reg + layer.regularization_score(params[str(i)])
         # score accumulates in f32 (bf16 compute) but must stay f64 under
         # float64 gradient checking — don't down-cast a wider loss
         score_dtype = jnp.promote_types(jnp.float32, loss.dtype)

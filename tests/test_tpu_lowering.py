@@ -380,6 +380,125 @@ class TestFlashKernelCompiles:
         assert "flash_fwd" in hlo and "collective-permute" in hlo
 
 
+class TestCompiledStepTable:
+    """`monitor/xla.py::parse_hlo_ops` on programs as the chip's compiler
+    prints them: tiled shapes, products written as convolutions inside
+    fusions, a `lax.cond` whose instruction is called `cond.N`, custom
+    calls (`tests/test_step_scopes.py` holds the grammar on hand-written
+    text and on the CPU's programs)."""
+
+    @staticmethod
+    def _rows(v5e, fn, *avals):
+        from jax.sharding import SingleDeviceSharding
+
+        from deeplearning4j_tpu.monitor import xla
+        one = SingleDeviceSharding(v5e.devices[0])
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+                for shape, dtype in avals]
+        compiled = jax.jit(fn).lower(*args).compile()
+        return xla.compiled_ops(compiled), compiled.as_text()
+
+    def test_a_scan_of_checkpointed_layers_a_cond_and_a_grouped_conv(
+            self, v5e):
+        from deeplearning4j_tpu.monitor import scopes, xla
+
+        def layer(w, x):
+            with jax.named_scope("mlp/gated"):
+                return jnp.tanh(x @ w)
+
+        def f(ws, x, img, k, flag):
+            def body(c, w):
+                with scopes.layer_scope("blk"):
+                    return jax.checkpoint(layer)(w, c), None
+            y, _ = jax.lax.scan(body, x, ws)
+            with scopes.layer_scope("stem"), jax.named_scope("conv"):
+                z = jax.lax.conv_general_dilated(
+                    img, k, (1, 1), "VALID",
+                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                    feature_group_count=4)
+            with scopes.layer_scope("top"), jax.named_scope("merge"):
+                y = jax.lax.cond(flag, lambda a: a @ a.T,
+                                 lambda a: (a @ a.T) * 2, y)
+            return jnp.sum(y.astype(jnp.float32)) \
+                + jnp.sum(z.astype(jnp.float32))
+
+        bf = jnp.bfloat16
+        rows, text = self._rows(
+            v5e, jax.grad(f, argnums=(0, 3)),
+            ((3, 256, 256), bf), ((128, 256), bf), ((2, 18, 18, 32), bf),
+            ((3, 3, 8, 64), bf), ((), jnp.bool_))
+        by = {r["name"]: r for r in rows}
+        assert "T(8,128)" in text and " dot(" not in text
+        # the conditional is a container although XLA calls it `cond.N`
+        conds = [r for r in rows if r["opcode"] == "conditional"]
+        assert conds and all(r["name"].startswith("cond")
+                             for r in conds)
+        assert all(r["opcode"] in xla.CONTAINER_OPCODES for r in conds)
+        whiles = [r for r in rows if r["opcode"] == "while"]
+        assert len(whiles) == 2                   # forward, backward
+        inside = lambda parent: [r for r in rows if r["parent"] == parent]
+        # a branch's product names its conditional; (128x256)@(256x128)
+        branch = [r for c in conds for r in inside(c["name"])
+                  if r["dot_flops"]]
+        assert branch and all(r["dot_flops"] % (2 * 128 * 128 * 256) == 0
+                              and r["layer"] == "top"
+                              and r["part"] == "merge" for r in branch)
+        # a scan body's product names its while, sums its fusion's body:
+        # (128x256)@(256x256) forward; again, marked, and transposed
+        # twice in the backward scan
+        one = 2 * 128 * 256 * 256
+        fwd = [r for r in inside(whiles[0]["name"]) if r["dot_flops"]]
+        bwd = [r for r in inside(whiles[1]["name"]) if r["dot_flops"]]
+        if any(r["direction"] == "backward" for r in fwd):
+            fwd, bwd = bwd, fwd
+        assert [r["dot_flops"] for r in fwd] == [one]
+        assert fwd[0]["opcode"] == "fusion" and not fwd[0]["recomputed"]
+        assert (fwd[0]["layer"], fwd[0]["part"]) == ("blk", "mlp/gated")
+        assert sum(r["dot_flops"] for r in bwd) == 3 * one
+        assert any(r["recomputed"] for r in inside(bwd[0]["parent"]))
+        assert all(r["direction"] == "backward" and r["layer"] == "blk"
+                   for r in bwd)
+        # the grouped convolution's gradient of its kernel, exact against
+        # the shapes: 2 x (3x3x8x64 results) x (2x16x16 positions)
+        stem = [r for r in rows if r["layer"] == "stem" and r["dot_flops"]]
+        assert sum(r["dot_flops"] for r in stem) \
+            == 2 * (3 * 3 * 8 * 64) * (2 * 16 * 16)
+        # tiled shapes give their bytes
+        assert fwd[0]["bytes_out"] == 128 * 256 * 2
+        assert all(by[r["parent"]]["opcode"] in xla.CONTAINER_OPCODES
+                   for r in rows if r["parent"])
+
+    def test_a_pallas_call_and_a_grouped_product_hold_no_dot(self, v5e):
+        from deeplearning4j_tpu.monitor import scopes, xla
+        from deeplearning4j_tpu.nn.layers.attention import _grouped_matmul
+        from deeplearning4j_tpu.ops.flash_attention import flash_attention
+
+        def f(q, x, w, sizes):
+            with scopes.layer_scope("blk"):
+                with jax.named_scope("mha/attn"):
+                    o = flash_attention(q, q, q, causal=True,
+                                        interpret=False)
+                with jax.named_scope("moe/experts"):
+                    y = _grouped_matmul(x, w, sizes)
+            return o, y
+
+        bf = jnp.bfloat16
+        rows, _ = self._rows(
+            v5e, f, ((1, 1024, 4, 128), bf), ((1024, 256), bf),
+            ((4, 256, 512), bf), ((4,), jnp.int32))
+        calls = [r for r in rows if r["opcode"] == "custom-call"
+                 and r["name"].split(".")[0] in ("flash_fwd",
+                                                 "ragged-dot-none")]
+        assert {r["name"].split(".")[0] for r in calls} \
+            == {"flash_fwd", "ragged-dot-none"}
+        assert all(r["dot_flops"] == 0 for r in calls)
+        flash = next(r for r in calls if r["name"].startswith("flash"))
+        assert (flash["layer"], flash["part"]) == ("blk", "mha/attn")
+        # the kernel-call view counts the same instruction
+        table = xla.OpTable(rows)
+        assert xla.kernel_calls(table) == {"flash_fwd": 1}
+
+
 class TestFlagshipLowering:
     def test_graft_entry_forward_lowers_for_tpu(self):
         # the driver compile-checks entry() on whatever chip it has;

@@ -43,8 +43,8 @@ LAYER = "layer:"
 PART_SCOPES = (
     ("kda/proj", "Kimi Delta Attention's projections and the layout "
                  "change to (sequence, head) pairs"),
-    ("kda/scan", "its convolutions, decay, chunk kernels and the scan "
-                 "over chunks"),
+    ("kda/scan", "its convolutions, decay and the chunked recurrence (two "
+                 "kernels that hand the state over; off the TPU a scan)"),
     ("kda/out", "its per-head norm, output gate and output projection"),
     ("mla/proj", "latent attention's projections, latent norms and "
                  "concatenations"),

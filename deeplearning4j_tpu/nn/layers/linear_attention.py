@@ -17,14 +17,16 @@ the chunk receives::
     U = U0 - W S;  O = (q exp(g)) S + Aqk U
     S' = Diag(exp(g_C)) S + (k exp(g_C - g))^T U          the hand-over
 
-Everything but the last two lines is independent of ``S``: a map over the
-(pair, chunk) tiles, `ops/kda_chunk.py` (one tile function; on a TPU at
-widths of 128 one Pallas kernel forward and one backward with the tile in
-VMEM, elsewhere the same function vmapped under XLA). No exponent it
-takes is positive, whatever the decay. A `lax.scan` over the chunks
-carries ``S`` in float32; its backward pass is autodiff. The (sequence,
-head) pairs go through in groups, each rematerialised, so that only one
-group's temporaries are alive.
+Everything but the last two lines is independent of ``S``. Both halves
+are `ops/kda_chunk.py` (two tile functions written once): on a TPU at
+widths of 128 one Pallas kernel forward and one backward that walk a
+pair's chunks in order with ``S`` (float32) in VMEM, so that neither the
+five intermediates nor the chunks' states' hand-over touch HBM; elsewhere
+the same functions under XLA, the first a map over the (pair, chunk)
+tiles, the second a `lax.scan` over the chunks, with autodiff. No
+exponent is ever positive, whatever the decay. The (sequence, head) pairs
+go through in groups, each rematerialised, so that only one group's
+temporaries are alive.
 
 `MultiHeadLatentAttention` (MLA) is DeepSeek's latent attention in the
 expanded form used for training: q of ``nope + rope`` a head, projected
@@ -58,7 +60,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
     dot_product_attention, rope,
 )
 from deeplearning4j_tpu.ops import REMAT_KEEP
-from deeplearning4j_tpu.ops.kda_chunk import chunk_algebra
+from deeplearning4j_tpu.ops.kda_chunk import chunk_scan
 from deeplearning4j_tpu.util.platform import is_tpu_backend
 
 
@@ -84,28 +86,8 @@ def _kda_core(q, k, v, log_a, beta, s0, *, mm):
     (M, N, C, d_k), v (M, N, C, d_v), beta (M, N, C, 1), all float32, in N
     chunks of C positions; s0 (M, d_k, d_v). Returns (o (M, N, C, d_v),
     the final state)."""
-    f32 = q.dtype
     g = jnp.cumsum(log_a, axis=2)                        # (M,N,C,dk)
-    w, u0, q_in, k_out, a_qk = chunk_algebra(q, k, v, g, beta, mm=mm)
-    g_end = g[:, :, -1:, :]                              # (M,N,1,dk)
-
-    def dot(x, y, spec):
-        return jnp.einsum(spec, x.astype(mm), y.astype(mm),
-                          preferred_element_type=f32)
-
-    # ---- the hand-over between chunks
-    def step(s, xs):
-        w_n, u0_n, q_n, aqk_n, k_n, end_n = xs
-        u = u0_n - dot(w_n, s, "mck,mkv->mcv")
-        o = dot(q_n, s, "mck,mkv->mcv") + dot(aqk_n, u, "mci,miv->mcv")
-        s = jnp.swapaxes(jnp.exp(end_n), -1, -2) * s + dot(
-            k_n, u, "mck,mcv->mkv")
-        return s, o
-
-    front = lambda x: jnp.moveaxis(x, 1, 0)
-    s, o = jax.lax.scan(step, s0, tuple(
-        front(x) for x in (w, u0, q_in, a_qk, k_out, g_end)))
-    return jnp.moveaxis(o, 0, 1), s
+    return chunk_scan(q, k, v, g, beta, s0, mm=mm)
 
 
 #: the temporaries of the (sequence, head) pairs that go through the

@@ -1,38 +1,50 @@
-"""Kimi Delta Attention's chunk algebra as one Pallas kernel a tile.
+"""Kimi Delta Attention's chunked recurrence as one Pallas kernel a pass.
 
-The part of the chunked gated delta rule that does not depend on the
-carried state (`nn/layers/linear_attention.py`, module docstring): for one
-(sequence, head) pair and one chunk of C positions, with ``g`` the running
-sum of ``log a`` inside the chunk::
+The chunked gated delta rule (`nn/layers/linear_attention.py`, module
+docstring) for one (sequence, head) pair and one chunk of C positions, with
+``g`` the running sum of ``log a`` inside the chunk and ``S`` the state the
+chunk receives::
 
     A[t,i]   = b_t sum_c k_tc k_ic exp(g_tc - g_ic)        (i <  t)
     Aqk[t,i] =     sum_c q_tc k_ic exp(g_tc - g_ic)        (i <= t)
     (I + A) [W | U0] = [b k exp(g) | b v]
     q_in = q exp(g);  k_out = k exp(g_C - g)
+    U = U0 - W S;  O = q_in S + Aqk U
+    S' = Diag(exp(g_C)) S + k_out^T U                      the hand-over
 
-`chunk_tile` is that algebra for ONE tile in plain `jnp`, written once.
-No exponent is ever positive: a pair inside a block of 16 positions takes
-``exp(g_t - g_i)`` directly where i <= t, column by column; a pair across
-blocks splits it at ``r``, g at the end of the block before t's, into the
-two operands ``x_t exp(g_t - r)`` and ``k_i exp(r - g_i)`` of one matrix
-product a row block. The solve is a substitution, fused with the loop that
-makes A's columns: inside the block on the diagonal column by column on
-the VPU (row j of the solution is final once columns 0..j-1 have been
-taken off it), the blocks left of it by ONE float32 product a row block at
-`Precision.HIGHEST` (the MXU's default for float32 is not float32); A is
-never assembled and nothing is inverted. ``g``, every decay, every sum
-over channels, the solve and ``U0`` are float32; only the cross-block
-products take their operands in the ``mm`` dtype; ``W``, ``q_in``,
-``k_out`` and ``Aqk``, which the scan over chunks reads only as operands
-of such products, leave in it.
+`chunk_tile` is the part that ``S`` does not enter, for ONE tile in plain
+`jnp`, written once. No exponent is ever positive: a pair inside a block
+of 16 positions takes ``exp(g_t - g_i)`` directly where i <= t, column by
+column; a pair across blocks splits it at ``r``, g at the end of the block
+before t's, into the two operands ``x_t exp(g_t - r)`` and
+``k_i exp(r - g_i)`` of one matrix product a row block. The solve is a
+substitution, fused with the loop that makes A's columns: inside the block
+on the diagonal column by column on the VPU (row j of the solution is
+final once columns 0..j-1 have been taken off it), the blocks left of it
+by ONE float32 product a row block at `Precision.HIGHEST` (the MXU's
+default for float32 is not float32); A is never assembled and nothing is
+inverted. ``g``, every decay, every sum over channels, the solve and
+``U0`` are float32; only the cross-block products take their operands in
+the ``mm`` dtype; ``W``, ``q_in``, ``k_out`` and ``Aqk``, which the
+hand-over reads only as operands of such products, leave in it.
+`hand_over` is the last two lines for one tile (``S`` and ``U`` float32,
+the four products' operands in the ``mm`` dtype), `chunk_step` the two
+composed: ``(q, k, v, g, beta, S) -> (O, S')``.
 
-Two executors of the same function (`chunk_algebra`): on a TPU, where the
-shapes fit the tiling (key and value widths multiples of 128, the chunk a
-multiple of 16), the kernel `kda_chunk_fwd` runs it on tiles held in VMEM,
-several chunks a grid step, and `kda_chunk_bwd` runs its `jax.vjp` on the
-tiles and their cotangents, making the tile's forward again in VMEM: the
-residuals are the inputs and nothing else. Elsewhere it runs vmapped over
-the tiles under XLA with plain autodiff.
+Two executors of the same two functions (`chunk_scan`). On a TPU, where
+the shapes fit the tiling (key and value widths multiples of 128, the
+chunk a multiple of 16), the kernel `kda_chunk_fwd` walks a pair's chunks
+in order along a sequential axis of its grid, several chunks a grid step,
+with ``S`` in VMEM scratch from the first chunk to the last: `chunk_step`
+a tile, ``O`` and the final state written, nothing else (``W``, ``U0``,
+``q_in``, ``k_out``, ``Aqk`` and ``U`` never reach HBM); as the forward
+rule of the `custom_vjp` it also writes the state each chunk RECEIVED.
+`kda_chunk_bwd` walks the same grid from the last chunk to the first with
+the state's cotangent in scratch and runs `jax.vjp` of `chunk_step` at the
+tile and its received state, making the tile's forward again in VMEM: the
+residuals are the inputs and those states. Elsewhere `chunk_tile` runs
+vmapped over the tiles and `hand_over` vmapped over the pairs under a
+`lax.scan` over the chunks, with plain autodiff.
 """
 from __future__ import annotations
 
@@ -48,15 +60,27 @@ from deeplearning4j_tpu.util.platform import is_tpu_backend
 NEG = -1e30
 #: the positions of a block whose pairs take their decay pair by pair
 BLOCK = 16
+#: x @ y
+_NN = (((1,), (0,)), ((), ()))
 #: contract the last axis of both operands: x @ y^T
 _NT = (((1,), (1,)), ((), ()))
+#: contract the first axis of both operands: x^T @ y
+_TN = (((0,), (0,)), ((), ()))
 
 
 def _exact(x, y):
     """x @ y as a float32 product that stays float32 on the MXU."""
-    return jax.lax.dot_general(x, y, (((1,), (0,)), ((), ())),
+    return jax.lax.dot_general(x, y, _NN,
                                precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
+
+
+def _column(x):
+    """A row (1, n) as a column (n, 1): the diagonal of an (n, n) matrix
+    that holds the row."""
+    n = x.shape[1]
+    at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, (n, n), axis)
+    return jnp.sum(jnp.where(at(0) == at(1), x, 0.0), axis=1, keepdims=True)
 
 
 def chunk_tile(q, k, v, g, beta, *, mm):
@@ -72,9 +96,7 @@ def chunk_tile(q, k, v, g, beta, *, mm):
     # x under `top` rows of zeros
     below = lambda x, top: jnp.concatenate(
         [jnp.zeros((top,) + x.shape[1:], f32), x], axis=0) if top else x
-    # beta as a column: the diagonal of a (C, C) matrix that holds the row
-    beta = jnp.sum(jnp.where(row((c, c)) == lane((c, c)), beta, 0.0),
-                   axis=1, keepdims=True)
+    beta = _column(beta)
     decay = jnp.exp(g)
     rhs = jnp.concatenate([beta * k * decay, beta * v], axis=1)
     sol, qk = [], []
@@ -123,36 +145,111 @@ def chunk_tile(q, k, v, g, beta, *, mm):
             k_out.astype(mm), qk.astype(mm))
 
 
-def _each_chunk(refs, one):
-    """``one(i)`` for every chunk i of a grid step's block."""
+def hand_over(s, w, u0, q_in, k_out, a_qk, g_end, *, mm):
+    """The hand-over of the module docstring for one tile: the state the
+    chunk receives ``s (d_k, d_v)`` float32, `chunk_tile`'s five results
+    and ``g_end (1, d_k)``, g at the chunk's last position -> ``o (C, d_v),
+    s'``, both float32. ``s`` and ``U`` are float32; the four products
+    take their operands in the ``mm`` dtype and accumulate in float32."""
+    f32 = s.dtype
+
+    def dot(x, y, dims=_NN):
+        return jax.lax.dot_general(x.astype(mm), y.astype(mm), dims,
+                                   preferred_element_type=f32)
+
+    u = u0 - dot(w, s)
+    o = dot(q_in, s) + dot(a_qk, u)
+    return o, _column(jnp.exp(g_end)) * s + dot(k_out, u, _TN)
+
+
+def chunk_step(q, k, v, g, beta, s, *, mm):
+    """One chunk of one pair, whole: `chunk_tile` of the tile, then
+    `hand_over` of the state ``s`` it receives -> ``o, s'``."""
+    tile = chunk_tile(q, k, v, g, beta, mm=mm)
+    return hand_over(s, *tile, g[-1:], mm=mm)
+
+
+def _each_chunk(ref, one, by=1, reverse=False):
+    """``one(i)`` for every chunk i of a grid step's block, in order (or
+    from the last to the first), ``by`` to a turn of the loop where that
+    divides the count."""
+    n = ref.shape[1]
+    by = by if n % by == 0 else 1
+
     def body(i, carry):
-        one(i)
+        for at in range(by):
+            at = by * i + at
+            one(n - 1 - at if reverse else at)
         return carry
 
-    jax.lax.fori_loop(0, refs[0].shape[1], body, 0)
+    jax.lax.fori_loop(0, n // by, body, 0)
 
 
-def _fwd_kernel(*refs, mm):
-    ins, outs = refs[:5], refs[5:]
+def _first_and_last():
+    """Whether this is a pair's first grid step, and whether its last."""
+    j = pl.program_id(1)
+    return j == 0, j == pl.num_programs(1) - 1
+
+
+def _fwd_kernel(*refs, mm, keep):
+    """A grid step's chunks of one pair in order, the state in ``s_ref``
+    (VMEM scratch) from chunk to chunk and from grid step to grid step.
+    ``keep``: also write the state each chunk received."""
+    ins, s0_ref, o_ref, end_ref = refs[:5], refs[5], refs[6], refs[7]
+    states_ref, s_ref = refs[8] if keep else None, refs[-1]
+
+    first, last = _first_and_last()
+
+    @pl.when(first)
+    def _():
+        s_ref[...] = s0_ref[0]
 
     def one(i):
-        tile = chunk_tile(*(r[0, i] for r in ins), mm=mm)
-        for ref, x in zip(outs, tile):
-            ref[0, i] = x
+        s = s_ref[...]
+        if keep:
+            states_ref[0, i] = s
+        o_ref[0, i], s_ref[...] = chunk_step(*(r[0, i] for r in ins), s,
+                                            mm=mm)
 
-    _each_chunk(refs, one)
+    # two chunks a turn: the second's algebra, which no state enters, runs
+    # beside the first's hand-over (on the v5e 1.19 -> 1.05 ms per 1,024
+    # tiles; eight a turn hide the hand-over whole, 0.96, and take five
+    # times as long to trace; the backward kernel gains 4 % from two a
+    # turn and takes twice as long to trace: one)
+    _each_chunk(o_ref, one, by=2)
+
+    @pl.when(last)
+    def _():
+        end_ref[0] = s_ref[...]
 
 
 def _bwd_kernel(*refs, mm):
-    ins, cots, grads = refs[:5], refs[5:10], refs[10:]
+    """The grid and a step's chunks walked from the last to the first,
+    the state's cotangent in ``ds_ref`` (VMEM scratch): `jax.vjp` of
+    `chunk_step` at the tile and the state it received, on the chunk's
+    ``do`` and the cotangent of the state it handed on."""
+    ins, do_ref, dend_ref = refs[:6], refs[6], refs[7]
+    grads, ds0_ref, ds_ref = refs[8:13], refs[13], refs[14]
+
+    first, last = _first_and_last()
+
+    @pl.when(first)
+    def _():
+        ds_ref[...] = dend_ref[0]
 
     def one(i):
-        _, pull = jax.vjp(functools.partial(chunk_tile, mm=mm),
+        _, pull = jax.vjp(functools.partial(chunk_step, mm=mm),
                           *(r[0, i] for r in ins))
-        for ref, x in zip(grads, pull(tuple(r[0, i] for r in cots))):
+        *tile, ds = pull((do_ref[0, i], ds_ref[...]))
+        for ref, x in zip(grads, tile):
             ref[0, i] = x
+        ds_ref[...] = ds
 
-    _each_chunk(refs, one)
+    _each_chunk(do_ref, one, reverse=True)
+
+    @pl.when(last)
+    def _():
+        ds0_ref[0] = ds_ref[...]
 
 
 #: chunks a grid step (the most that divide the sequence's chunks), so that
@@ -161,54 +258,68 @@ def _bwd_kernel(*refs, mm):
 _CHUNKS_A_STEP = 8
 
 
-def _over_tiles(kernel, name, arrays, out, mm, interpret):
-    """`kernel` over the tiles of ``arrays`` ((M, N, C, .) each) into
-    arrays of the shapes and dtypes ``out``: grid (M, N / chunks a step),
-    every operand and result cut the same way."""
+def _over_chunks(kernel, name, arrays, out, reverse, interpret):
+    """`kernel` over the pairs (parallel) and, one after another, the
+    chunks of a pair (from the last where ``reverse``): grid (M, N / chunks
+    a step). An operand or result of four axes ((M, N, ., .)) is cut by
+    pair and chunk, one of three ((M, d_k, d_v): a state or its cotangent)
+    by pair; the kernel's scratch is one state."""
     m, n = arrays[0].shape[:2]
     step = max(s for s in range(1, _CHUNKS_A_STEP + 1) if n % s == 0)
-    spec = lambda a: pl.BlockSpec((1, step) + a.shape[2:],
-                                  lambda i, j: (i, j, 0, 0))
+    last = n // step - 1
+    chunk = lambda i, j: (i, last - j if reverse else j, 0, 0)
+
+    def spec(a):
+        if len(a.shape) == 3:
+            return pl.BlockSpec((1,) + a.shape[1:], lambda i, j: (i, 0, 0))
+        return pl.BlockSpec((1, step) + a.shape[2:], chunk)
+
+    state = next(a for a in arrays if len(a.shape) == 3)
     return tuple(pl.pallas_call(
-        functools.partial(kernel, mm=mm),
+        kernel,
         grid=(m, n // step),
         in_specs=[spec(a) for a in arrays],
         out_specs=[spec(a) for a in out],
         out_shape=out,
+        scratch_shapes=[pltpu.VMEM(state.shape[1:], state.dtype)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=name,
     )(*arrays))
 
 
 # jitted, so that the layers of a model share one trace of each kernel
-@functools.partial(jax.jit, static_argnums=(5, 6))
-def _forward(q, k, v, g, beta, mm, interpret):
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _forward(q, k, v, g, beta, s0, mm, interpret, keep):
     like = jax.ShapeDtypeStruct
-    return _over_tiles(
-        _fwd_kernel, "kda_chunk_fwd", (q, k, v, g, beta),
-        [like(k.shape, mm), like(v.shape, v.dtype), like(q.shape, mm),
-         like(k.shape, mm), like(k.shape[:3] + k.shape[2:3], mm)],
-        mm, interpret)
+    states = [like(k.shape[:2] + s0.shape[1:], s0.dtype)] if keep else []
+    return _over_chunks(
+        functools.partial(_fwd_kernel, mm=mm, keep=keep), "kda_chunk_fwd",
+        (q, k, v, g, beta, s0),
+        [like(v.shape, v.dtype), like(s0.shape, s0.dtype)] + states,
+        False, interpret)
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
 def _backward(res, cots, mm, interpret):
-    return _over_tiles(
-        _bwd_kernel, "kda_chunk_bwd", res + cots,
-        [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in res], mm, interpret)
+    return _over_chunks(
+        functools.partial(_bwd_kernel, mm=mm), "kda_chunk_bwd", res + cots,
+        [jax.ShapeDtypeStruct(a.shape, a.dtype)
+         for a in res[:5] + cots[1:]], True, interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _chunk_kernels(q, k, v, g, beta, mm, interpret):
-    """`chunk_tile` over (M, N, C, .) tiles by the two kernels; beta
-    (M, N, 1, C)."""
-    return _forward(q, k, v, g, beta, mm, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _chunk_kernels(q, k, v, g, beta, s0, mm, interpret):
+    """`chunk_step` over the chunks of (M, N, C, .) tiles from the states
+    s0 (M, d_k, d_v) by the two kernels; beta (M, N, 1, C). Returns
+    (o (M, N, C, d_v), the final states)."""
+    return _forward(q, k, v, g, beta, s0, mm, interpret, False)
 
 
-def _chunk_kernels_fwd(q, k, v, g, beta, mm, interpret):
-    return _forward(q, k, v, g, beta, mm, interpret), (q, k, v, g, beta)
+def _chunk_kernels_fwd(q, k, v, g, beta, s0, mm, interpret):
+    o, end, states = _forward(q, k, v, g, beta, s0, mm, interpret, True)
+    return (o, end), (q, k, v, g, beta, states)
 
 
 def _chunk_kernels_bwd(mm, interpret, res, cots):
@@ -218,16 +329,33 @@ def _chunk_kernels_bwd(mm, interpret, res, cots):
 _chunk_kernels.defvjp(_chunk_kernels_fwd, _chunk_kernels_bwd)
 
 
-def chunk_algebra(q, k, v, g, beta, *, mm):
-    """`chunk_tile` over every tile: q, k, g (M, N, C, d_k), v (M, N, C,
-    d_v), beta (M, N, C, 1), all float32 -> ``w, u0, q_in, k_out, a_qk``
-    with the same leading axes. By the Pallas kernels on a TPU where the
-    shapes fit its tiling, vmapped under XLA elsewhere."""
+def _chunk_scan(q, k, v, g, beta, s0, mm):
+    """`_chunk_kernels` under XLA with plain autodiff: `chunk_tile` vmapped
+    over every tile, then a `lax.scan` over the chunks that carries the
+    pairs' states through `hand_over`."""
+    tiles = jax.vmap(jax.vmap(functools.partial(chunk_tile, mm=mm)))(
+        q, k, v, g, beta)
+    pairs = jax.vmap(functools.partial(hand_over, mm=mm))
+
+    def step(s, xs):
+        o, s = pairs(s, *xs)
+        return s, o
+
+    s, o = jax.lax.scan(step, s0, tuple(
+        jnp.moveaxis(x, 1, 0) for x in tiles + (g[:, :, -1:],)))
+    return jnp.moveaxis(o, 0, 1), s
+
+
+def chunk_scan(q, k, v, g, beta, s0, *, mm):
+    """The chunked recurrence from the running sums on: q, k, g (M, N, C,
+    d_k), v (M, N, C, d_v), beta (M, N, C, 1), s0 (M, d_k, d_v), all
+    float32 -> ``(o (M, N, C, d_v), the final states)``. By the Pallas
+    kernels on a TPU where the shapes fit its tiling, as XLA ops
+    elsewhere."""
     c, dk, dv = k.shape[2], k.shape[3], v.shape[3]
     if c & (c - 1):
         raise ValueError(f"chunk {c} is not a power of two")
     beta = jnp.swapaxes(beta, 2, 3)                      # (M, N, 1, C)
     if is_tpu_backend() and not (dk % 128 or dv % 128 or c % BLOCK):
-        return _chunk_kernels(q, k, v, g, beta, mm, False)
-    return jax.vmap(jax.vmap(functools.partial(chunk_tile, mm=mm)))(
-        q, k, v, g, beta)
+        return _chunk_kernels(q, k, v, g, beta, s0, mm, False)
+    return _chunk_scan(q, k, v, g, beta, s0, mm)

@@ -127,6 +127,25 @@ def _compile_v5e(fn, sharding, *avals):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _loop_trips(hlo):
+    """The bound each `while` of a compiled module counts to: the constant
+    its condition computation (or the fusion that one calls) compares
+    with. The TPU's text carries no trip count."""
+    import re
+
+    def body(name):
+        return re.search(r"^%?" + re.escape(name) + r" \([^\n]*\{\n(.*?)^\}",
+                         hlo, re.M | re.S).group(1)
+
+    trips = []
+    for cond in re.findall(r" while\(.*?condition=%?([\w.\-]+)", hlo):
+        text = body(cond)
+        text += "".join(body(c)
+                        for c in re.findall(r"calls=%?([\w.\-]+)", text))
+        trips += [int(n) for n in re.findall(r"constant\((\d+)\)", text)]
+    return trips
+
+
 class TestFlashKernelCompiles:
     """forward, dq and dk/dv through Mosaic's own passes, at the smoke's
     shapes and the layer-default block 512."""
@@ -331,8 +350,11 @@ class TestFlashKernelCompiles:
                                                          monkeypatch):
         # one group of the cell's (sequence, head) pairs: 8 pairs, 8,192
         # positions in chunks of 64, d_k = d_v = 128, bf16 products: the
-        # chunk algebra is the two Pallas kernels, the scan over chunks a
-        # loop. At a width the tiling does not take (8), neither kernel.
+        # two Pallas kernels hand the state over themselves, so there is
+        # no loop over the 128 chunks and no stack of states written a
+        # turn. At a width the tiling does not take (8), neither kernel
+        # and the `lax.scan` over the chunks.
+        import re
         from deeplearning4j_tpu.nn.layers.linear_attention import kda_chunked
         from deeplearning4j_tpu.ops import kda_chunk
         monkeypatch.setattr(kda_chunk, "is_tpu_backend", lambda: True)
@@ -346,10 +368,12 @@ class TestFlashKernelCompiles:
         hlo = _compile_v5e(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
                            self._one(v5e), wide, wide, wide, wide,
                            ((1, t, 8), jnp.float32))
-        assert "while" in hlo
         assert ("tpu_custom_call" in hlo) == kernels
         for kernel in ("kda_chunk_fwd", "kda_chunk_bwd"):
             assert (kernel in hlo) == kernels
+        assert (t // 64 in _loop_trips(hlo)) == (not kernels)
+        assert not re.search(
+            r"f32\[128,8,128,128\]\S* dynamic-update-slice", hlo)
 
     def test_masked_padded_f32_with_lse(self, v5e):
         # t=200: the pad path; masked non-causal with the lse output and

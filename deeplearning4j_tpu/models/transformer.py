@@ -30,6 +30,14 @@ normed, rotated) by the published list of layer types, leading dense SwiGLU
 layers and then sigmoid-routed SwiGLU experts with NO shared expert, the
 head tied to the embedding: a `ComputationGraph` whose head reads the
 embedding's leaf.
+
+`KeyeVL2LM` is the language model of Kwai-Keye's Keye-VL-2.0 family
+(``KeyeVL2``; the vision tower is NOT here): grouped-query attention whose
+heads are wider than the stream's share (``head_dim``), q/k-normed, rotated
+with the frequencies shared out among three rows of positions, SPARSE: a
+learned indexer picks the keys each query attends and is trained by its own
+loss; softmax-routed SwiGLU experts with no shared one in every layer; an
+untied head.
 """
 from __future__ import annotations
 
@@ -494,4 +502,110 @@ class Lfm2MoeLM(ZooModel):
             n_out=self.vocab_size, activation="softmax",
             loss="sparse_mcxent", has_bias=False, tied_embedding=True),
             "norm", params_of="embed")
+        return g.set_outputs("head").build()
+
+
+@dataclasses.dataclass
+class KeyeVL2LM(ZooModel):
+    """Decoder-only LANGUAGE MODEL of Kwai-Keye's Keye-VL-2.0 family
+    (``model_type`` ``KeyeVL2``; the vision tower and image input are not
+    part of it: text ids in, the three rows of rotary positions coincide):
+    token embedding -> pre-norm blocks (RMSNorm, no biases) -> RMSNorm ->
+    an UNTIED head, sparse cross-entropy over blocks of positions, as a
+    `ComputationGraph` over one input of token ids.
+
+    Every layer: `MultiHeadAttention` of ``n_heads`` query heads on
+    ``n_kv_heads`` key/value heads, ``head_dim`` wide whatever the stream's
+    width, q and k RMS-normed over the head width before the rotation
+    (``rope_theta``; ``mrope_section`` shares the ``head_dim / 2``
+    frequencies out among the time, height and width rows), causal and
+    SPARSE: a `LightningIndexer` of ``indexer_heads`` heads of
+    ``indexer_head_dim`` on one key head scores the keys of every query,
+    the ``topk`` highest are attended (exactly those), and the indexer is
+    trained by ``indexer_loss_coef`` times its Kullback-Leibler loss
+    through `attach_auxiliary_loss` (the score ``fit()`` reports stays the
+    cross-entropy). Then the expert layer: a softmax router over
+    ``n_experts``, the ``top_k`` largest kept and renormalised, SwiGLU
+    experts of ``expert_hidden``, NO shared expert; ``experts_held`` is
+    the range of experts this chip holds (None: all), and a token none of
+    whose experts is held gets exactly zero from the layer.
+
+    Defaults: the published shape cut to widths a CPU test can run;
+    `benchmark/configs/keye-vl-2.0-30b-a3b.json` holds the published
+    sizes."""
+    vocab_size: int = 1024
+    seq_length: int = 256
+    n_embd: int = 128
+    n_layers: int = 2
+    n_heads: int = 8
+    n_kv_heads: int = 2
+    head_dim: int = 32
+    rope_theta: float = 1e7
+    mrope_section: Tuple[int, ...] = (4, 6, 6)
+    indexer_heads: int = 4
+    indexer_head_dim: int = 16
+    topk: int = 64
+    indexer_loss_coef: float = 1.0
+    n_experts: int = 16
+    top_k: int = 4
+    expert_hidden: int = 64
+    experts_held: Optional[Tuple[int, int]] = None
+    rms_norm_eps: float = 1e-6
+    learning_rate: float = 1e-4
+    beta1: float = 0.9
+    beta2: float = 0.95
+    epsilon: float = 1e-8
+    weight_decay: float = 0.1
+    compute_dtype: Optional[str] = None
+    gradient_checkpointing: bool = True
+    seed: int = 123
+    block_size: int = 512
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers.attention import (
+            LightningIndexer, MultiHeadAttention,
+        )
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(AdamW(self.learning_rate, beta1=self.beta1,
+                            beta2=self.beta2, epsilon=self.epsilon,
+                            weight_decay=self.weight_decay,
+                            decay_matrices_only=True))
+             .gradient_checkpointing(self.gradient_checkpointing))
+        if self.compute_dtype:
+            b = b.compute_dtype(self.compute_dtype)
+        g = b.graph_builder().add_inputs("ids").set_input_types(
+            InputType.recurrent(1, self.seq_length))
+        attention = MultiHeadAttention(
+            n_out=self.n_embd, n_heads=self.n_heads,
+            n_kv_heads=self.n_kv_heads, head_dim=self.head_dim, causal=True,
+            use_rope=True, rope_base=self.rope_theta,
+            rope_sections=tuple(self.mrope_section), qk_norm=True,
+            norm_epsilon=self.rms_norm_eps, has_bias=False,
+            attention_impl="flash", block_size=self.block_size,
+            weight_init="normal",
+            indexer=LightningIndexer(
+                n_heads=self.indexer_heads, head_dim=self.indexer_head_dim,
+                topk=self.topk, rope_base=self.rope_theta,
+                norm_epsilon=self.rms_norm_eps,
+                loss_coef=self.indexer_loss_coef, weight_init="normal"))
+        experts = MoEFeedForward(
+            n_out=self.n_embd, n_experts=self.n_experts, top_k=self.top_k,
+            hidden=self.expert_hidden, activation="swish", gated=True,
+            has_bias=False, experts_held=self.experts_held,
+            router="softmax", n_shared=0, weight_init="normal")
+        g.add_layer("embed", EmbeddingSequenceLayer(
+            n_out=self.n_embd, n_in=self.vocab_size), "ids")
+        last = "embed"
+        for i in range(self.n_layers):
+            g.add_layer(f"layer{i}", TransformerBlock(
+                n_out=self.n_embd, n_heads=self.n_heads, norm="rms",
+                norm_epsilon=self.rms_norm_eps, has_bias=False,
+                attn=attention, ffn=experts), last)
+            last = f"layer{i}"
+        g.add_layer("norm", RMSNormLayer(epsilon=self.rms_norm_eps), last)
+        g.add_layer("head", RnnOutputLayer(
+            n_out=self.vocab_size, activation="softmax",
+            loss="sparse_mcxent", has_bias=False, weight_init="normal"),
+            "norm")
         return g.set_outputs("head").build()

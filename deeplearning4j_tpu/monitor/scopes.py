@@ -58,6 +58,16 @@ PART_SCOPES = (
     ("mha/rope", "its rotation of q and k"),
     ("mha/attn", "its attention kernels and the layout changes around "
                  "them"),
+    ("dsa/index/proj", "a sparse attention's indexer: its three "
+                       "projections, the key's norm, the rotation"),
+    ("dsa/index", "the indexer's scores of every causal (query, key) "
+                  "pair, made for the selection"),
+    ("dsa/select", "the exact selection of each query's keys from its "
+                   "scores, and the counts of the pairs kept"),
+    ("dsa/attn", "attention over the kept keys, forward and backward "
+                 "(the indexer's loss's backward rides its kernels)"),
+    ("dsa/kl", "the indexer's loss: its divergence from the attention's "
+               "mean probabilities, and the loss's way into the step"),
     ("sconv/proj", "the gated short convolution's two projections"),
     ("sconv/mix", "its gates and depth-wise causal taps"),
     ("moe/route", "router scores, top-k and the kept experts' weights"),
@@ -112,13 +122,15 @@ def _parts(path):
     """The entries of `PART_SCOPES` along a path, in its order."""
     i = 0
     while i < len(path):
-        if tuple(path[i:i + 2]) in _PARTS:
-            yield "/".join(path[i:i + 2])
-            i += 2
-            continue
-        if (path[i],) in _PARTS:
-            yield path[i]
-        i += 1
+        for n in (3, 2):                  # the longest spelling first
+            if tuple(path[i:i + n]) in _PARTS:
+                yield "/".join(path[i:i + n])
+                i += n
+                break
+        else:
+            if (path[i],) in _PARTS:
+                yield path[i]
+            i += 1
 
 
 def parse(op_name: str) -> Tuple[Optional[str], Optional[str]]:

@@ -23,8 +23,8 @@ from deeplearning4j_tpu.nn.layers.variational import VariationalAutoencoder
 from deeplearning4j_tpu.nn.layers.samediff import SameDiffLayer, FrozenLayerWrapper
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2OutputLayer
 from deeplearning4j_tpu.nn.layers.attention import (
-    EmbeddingSequenceLayer, GatedMLP, LayerNormLayer, LinearProjection,
-    MoEFeedForward,
+    EmbeddingSequenceLayer, GatedMLP, LayerNormLayer, LightningIndexer,
+    LinearProjection, MoEFeedForward, attach_auxiliary_loss,
     RMSNormLayer, MultiHeadAttention, PositionalEmbeddingLayer,
     TransformerBlock,
 )
@@ -50,6 +50,7 @@ __all__ = [
     "MaskZeroLayer", "VariationalAutoencoder", "SameDiffLayer",
     "FrozenLayerWrapper", "Yolo2OutputLayer",
     "MultiHeadAttention", "TransformerBlock", "MoEFeedForward",
+    "LightningIndexer", "attach_auxiliary_loss",
     "RMSNormLayer", "GatedMLP", "LinearProjection", "KimiDeltaAttention",
     "GatedShortConv",
     "MultiHeadLatentAttention",

@@ -18,7 +18,11 @@ foundation the sequence-parallel / ring-attention machinery
   (token, slot) pairs by expert into grouped matrix products over the
   held ones (cost follows the row tier that holds the pairs routed here,
   not the expert count and not the worst case), drops nothing, and adds
-  a shared expert where the model has one. The linear
+  a shared expert where the model has one;
+- a layer may add a loss of its own to the step (`attach_auxiliary_loss`):
+  `MultiHeadAttention(indexer=)`, the learned sparse attention, trains its
+  `LightningIndexer` that way while the containers' score stays the
+  output layers'. The linear
   and latent attentions of the hybrid LMs live beside this file in
   `linear_attention.py` and ride `TransformerBlock` through its ``attn``
   field.
@@ -144,15 +148,29 @@ def _merge_heads(x):
     return x.reshape(b, t, h * d)
 
 
-def rope(x, positions, base: float = 10000.0):
-    """Rotary position embedding on (B, T, H, D)."""
+def rope(x, positions, base: float = 10000.0, sections=None):
+    """Rotary position embedding on (B, T, H, D): dim ``j`` of the first
+    half turned against dim ``j + D/2`` by ``positions * base^(-j/(D/2))``.
+    ``sections`` (their sum ``D/2``) shares the frequencies out among
+    several ROWS of positions, ``positions`` then (rows, B?, T): frequency
+    ``j`` turns by the row whose section holds ``j`` (a multimodal LM's
+    time, height and width rows, sections 16/24/24 of 64; on text the rows
+    coincide and the result is that of one row)."""
     d = x.shape[-1]
     half = d // 2
     # trig in >= f32 (f64 under float64 gradient checking — a hard f32 cast
     # here corrupts the finite-difference oracle)
     acc_t = jnp.promote_types(jnp.float32, x.dtype)
     freqs = base ** (-jnp.arange(0, half, dtype=acc_t) / half)
-    angles = positions[..., None].astype(acc_t) * freqs   # (B?, T, half)
+    if sections is not None:
+        if sum(sections) != half or len(sections) != positions.shape[0]:
+            raise ValueError(f"sections {tuple(sections)} have to add up to "
+                             f"{half} and name {positions.shape[0]} rows")
+        row_of = [r for r, n in enumerate(sections) for _ in range(n)]
+        positions = jnp.moveaxis(positions[jnp.asarray(row_of)], 0, -1)
+        angles = positions.astype(acc_t) * freqs          # (B?, T, half)
+    else:
+        angles = positions[..., None].astype(acc_t) * freqs
     while angles.ndim < x.ndim:
         angles = angles[..., None, :] if angles.ndim == x.ndim - 1 \
             else angles[None]
@@ -203,6 +221,113 @@ def dot_product_attention(q, k, v, *, mask=None, causal=False,
     return out
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def attach_auxiliary_loss(y, loss, coef: float):
+    """How a layer adds a loss of its own to the step: the identity on
+    ``y`` whose backward pass hands the scalar ``loss`` a cotangent of
+    ``coef``, so that the step's gradient is that of ``score + coef *
+    loss`` while the score the containers report and ``fit()`` prints
+    stays the output layers' (the way the sparse-expert families' modelling
+    code attaches an auxiliary loss to a hidden state). No container code
+    is involved: it works on every fit path of both containers and under
+    gradient checkpointing. The cotangent is ``coef`` whatever the score's
+    own scale: a caller that scales the score (loss scaling) scales
+    ``coef`` likewise. The layer keeps the loss's VALUE in its state for a
+    listener to publish."""
+    return y
+
+
+def _attach_fwd(y, loss, coef):
+    return y, loss
+
+
+def _attach_bwd(coef, loss, g):
+    return g, jnp.full_like(loss, coef)
+
+
+attach_auxiliary_loss.defvjp(_attach_fwd, _attach_bwd)
+
+
+def _add_u64(total, counts):
+    """``total`` ((2,) uint32: low word, high word) plus the sum of
+    ``counts`` ((B,) uint32, fewer than 65,536 of them), with the carries:
+    a layer's state counts in uint32 words and a step of long sequences
+    passes 2**32 pairs in a few steps."""
+    counts = counts.astype(jnp.uint32)
+    lo16, hi16 = jnp.sum(counts & 0xFFFF), jnp.sum(counts >> 16)
+    shifted = hi16 << 16
+    add_lo = shifted + lo16
+    add_hi = (hi16 >> 16) + (add_lo < shifted).astype(jnp.uint32)
+    lo = total[0] + add_lo
+    hi = total[1] + add_hi + (lo < total[0]).astype(jnp.uint32)
+    return jnp.stack([lo, hi])
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class LightningIndexer(LayerConf):
+    """The learned indexer of a sparse attention (`MultiHeadAttention(
+    indexer=)`; DeepSeek-V3.2's "lightning indexer"): ``n_heads`` small
+    query heads of ``head_dim`` on ONE key head, the key LayerNormed
+    (gain and bias of ``head_dim``), both rotated over all ``head_dim``
+    dims at ``rope_base``, and a weight a query and head,
+
+        I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]),
+        w = x Ww * n_heads^-1/2 * head_dim^-1/2;
+
+    a query attends the ``topk`` keys ``s <= t`` with the largest ``I``.
+    It reads the DETACHED input of the attention and is trained by
+    ``loss_coef`` times the Kullback-Leibler divergence of its softmax
+    over the kept keys from the attention's probabilities averaged over
+    the query heads (`ops/dsa_attention.py`), and by nothing else; the
+    attention's own weights get nothing from that loss."""
+    n_heads: int = 16
+    head_dim: int = 64
+    topk: int = 2048
+    rope_base: float = 10000.0
+    norm_epsilon: float = 1e-6
+    loss_coef: float = 1.0
+    weight_init: str = "xavier"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        f_in, wide = input_type.features, self.n_heads * self.head_dim
+        w_init = get_initializer(self.weight_init)
+        ks = jax.random.split(key, 3)
+        return {
+            "Wq": w_init(ks[0], (f_in, wide), f_in, wide, dtype),
+            "Wk": w_init(ks[1], (f_in, self.head_dim), f_in, self.head_dim,
+                         dtype),
+            "Ww": w_init(ks[2], (f_in, self.n_heads), f_in, self.n_heads,
+                         dtype),
+            "k_gamma": jnp.ones((self.head_dim,), dtype),
+            "k_beta": jnp.zeros((self.head_dim,), dtype),
+        }, {}
+
+    def project(self, params, x, positions):
+        """x (B, T, F) -> qI (B, T, n_heads, head_dim), kI (B, T,
+        head_dim), both in x's dtype, and w (B, T, n_heads) float32."""
+        acc_t = jnp.promote_types(jnp.float32, x.dtype)
+        qi = _split_heads(x @ params["Wq"], self.n_heads)
+        ki = (x @ params["Wk"]).astype(acc_t)
+        mean = jnp.mean(ki, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
+        ki = (ki - mean) * jax.lax.rsqrt(var + self.norm_epsilon) \
+            * params["k_gamma"].astype(acc_t) + params["k_beta"].astype(acc_t)
+        qi = rope(qi, positions, self.rope_base)
+        ki = rope(ki.astype(x.dtype)[:, :, None, :], positions,
+                  self.rope_base)[:, :, 0, :]
+        w = jnp.dot(x, params["Ww"], preferred_element_type=acc_t) \
+            * (self.n_heads ** -0.5 * self.head_dim ** -0.5)
+        return qi, ki, w
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        raise TypeError("LightningIndexer is a part of MultiHeadAttention("
+                        "indexer=), not a layer of its own")
+
+
 @register_layer
 @dataclasses.dataclass(frozen=True)
 class MultiHeadAttention(LayerConf):
@@ -220,11 +345,29 @@ class MultiHeadAttention(LayerConf):
     (``q_norm``) and to k (``k_norm``), every head alike, BEFORE the
     rotation (``norm_epsilon``). Masks follow DL4J semantics: (B, T) 0/1,
     masked steps neither attend nor get attended to, and their outputs are
-    zeroed (MaskZeroLayer behavior). Scopes: ``mha/proj``, ``mha/norm``,
-    ``mha/rope``, ``mha/attn``."""
+    zeroed (MaskZeroLayer behavior). ``head_dim`` (None: ``n_out //
+    n_heads``) is the head width where the heads together are not the
+    stream's width: ``Wq`` is ``(f_in, n_heads * head_dim)``, ``Wo``
+    ``(n_heads * head_dim, n_out)``. ``rope_sections`` shares the rotary
+    frequencies out among three rows of positions (`rope`; on text, which
+    is all this layer is given, the rows coincide). ``indexer`` (a
+    `LightningIndexer`) makes the attention SPARSE: causal, every query
+    head attends only the ``indexer.topk`` keys the indexer scores highest
+    for the query (exact; `ops/dsa_attention.py`), and the indexer is
+    trained by its own loss through `attach_auxiliary_loss`. Such a layer
+    keeps in its state the pairs it kept and the causal pairs it chose
+    among (``pairs_selected_total``, ``pairs_causal_total``: two uint32
+    words each, low then high) and its last ``indexer_kl``;
+    ``train.listeners.ExpertLoadListener`` publishes them. Scopes:
+    ``mha/proj``, ``mha/norm``, ``mha/rope``, ``mha/attn``; with an
+    indexer ``dsa/index/proj``, ``dsa/index``, ``dsa/select``,
+    ``dsa/attn``, ``dsa/kl`` in ``mha/attn``'s place."""
     n_out: int = 0
     n_heads: int = 8
     n_in: Optional[int] = None
+    head_dim: Optional[int] = None
+    rope_sections: Optional[Tuple[int, ...]] = None
+    indexer: Optional[LayerConf] = None
     causal: bool = False
     use_rope: bool = True
     n_kv_heads: Optional[int] = None
@@ -248,39 +391,54 @@ class MultiHeadAttention(LayerConf):
         return InputType(Kind.RNN, (t, self.n_out))
 
     def init(self, key, input_type: InputType, dtype=jnp.float32):
-        if self.n_out % self.n_heads:
+        if self.head_dim is None and self.n_out % self.n_heads:
             raise ValueError(f"n_out {self.n_out} not divisible by "
                              f"n_heads {self.n_heads}")
-        if self.use_rope and (self.n_out // self.n_heads) % 2:
+        d = self._head_dim()
+        if self.use_rope and d % 2:
             raise ValueError(
                 f"rotary embeddings need an even head dim; got "
-                f"{self.n_out // self.n_heads} (n_out={self.n_out}, "
+                f"{d} (n_out={self.n_out}, "
                 f"n_heads={self.n_heads}) — disable use_rope or resize")
         if self.n_heads % self._kv_heads():
             raise ValueError(f"n_kv_heads {self.n_kv_heads} does not divide "
                              f"n_heads {self.n_heads}")
+        if self.indexer is not None and not (
+                self.causal and self.attention_dropout == 0.0):
+            raise ValueError("an indexer needs a causal attention without "
+                             "attention dropout")
         f_in = self.n_in or input_type.features
-        kv = self._kv_heads() * (self.n_out // self.n_heads)
+        wide = self.n_heads * d
+        kv = self._kv_heads() * d
         w_init = get_initializer(self.weight_init)
         ks = jax.random.split(key, 4)
         p = {
-            "Wq": w_init(ks[0], (f_in, self.n_out), f_in, self.n_out, dtype),
+            "Wq": w_init(ks[0], (f_in, wide), f_in, wide, dtype),
             "Wk": w_init(ks[1], (f_in, kv), f_in, kv, dtype),
             "Wv": w_init(ks[2], (f_in, kv), f_in, kv, dtype),
-            "Wo": w_init(ks[3], (self.n_out, self.n_out), self.n_out,
-                         self.n_out, dtype),
+            "Wo": w_init(ks[3], (wide, self.n_out), wide, self.n_out, dtype),
         }
         if self.has_bias:
-            for b, n in (("bq", self.n_out), ("bk", kv), ("bv", kv),
+            for b, n in (("bq", wide), ("bk", kv), ("bv", kv),
                          ("bo", self.n_out)):
                 p[b] = jnp.zeros((n,), dtype)
         if self.qk_norm:
             for g in ("q_norm", "k_norm"):
-                p[g] = jnp.ones((self.n_out // self.n_heads,), dtype)
-        return p, {}
+                p[g] = jnp.ones((d,), dtype)
+        if self.indexer is None:
+            return p, {}
+        p["indexer"], _ = self.indexer.init(
+            jax.random.fold_in(key, 4), InputType(Kind.RNN, (
+                input_type.shape[0], f_in)), dtype)
+        words = jnp.zeros((2,), jnp.uint32)
+        return p, {"pairs_selected_total": words, "pairs_causal_total": words,
+                   "indexer_kl": jnp.zeros((), jnp.float32)}
 
     def _kv_heads(self):
         return self.n_kv_heads or self.n_heads
+
+    def _head_dim(self):
+        return self.head_dim or self.n_out // self.n_heads
 
     def _qkv(self, params, x):
         q = x @ params["Wq"]
@@ -307,8 +465,14 @@ class MultiHeadAttention(LayerConf):
         if self.use_rope:
             with jax.named_scope("mha/rope"):
                 pos = (offset + jnp.arange(t_loc))[None]
-                q = rope(q, pos, self.rope_base)
-                k = rope(k, pos, self.rope_base)
+                rows = pos if self.rope_sections is None else \
+                    jnp.broadcast_to(pos, (len(self.rope_sections),)
+                                     + pos.shape)
+                q = rope(q, rows, self.rope_base, self.rope_sections)
+                k = rope(k, rows, self.rope_base, self.rope_sections)
+        if self.indexer is not None:
+            out, state = self._sparse(params, state, x, q, k, v, mask)
+            return self._project_out(params, out, mask), state
         drop = self.attention_dropout if train else 0.0
         # fused-kernel eligibility, shared by the context-parallel and
         # single-device dispatches, decided from what the code can see:
@@ -322,13 +486,49 @@ class MultiHeadAttention(LayerConf):
                      and is_tpu_backend())
         with jax.named_scope("mha/attn"):
             out = self._attend(q, k, v, mask, drop, attn_rng, use_flash)
+        return self._project_out(params, out, mask), state
+
+    def _project_out(self, params, out, mask):
         with jax.named_scope("mha/proj"):
             y = _merge_heads(out) @ params["Wo"]
             if self.has_bias:
                 y = y + params["bo"]
         if mask is not None:
             y = y * mask[..., None].astype(y.dtype)
-        return y, state
+        return y
+
+    def _sparse(self, params, state, x, q, k, v, mask):
+        """``apply``'s attention with an indexer: its projections on the
+        detached input, the sparse attention with the indexer's loss
+        attached to its result, the counts into the state."""
+        from deeplearning4j_tpu.ops.dsa_attention import (
+            pairs_causal, sparse_attention,
+        )
+        if mask is not None or _CONTEXT_PARALLEL_AXIS is not None:
+            raise NotImplementedError(
+                "sparse attention takes whole unpadded sequences on one "
+                "device: no key mask and no sequence parallelism yet")
+        b, t = x.shape[:2]
+        with jax.named_scope("dsa/index/proj"):
+            qi, ki, wi = self.indexer.project(
+                params["indexer"], jax.lax.stop_gradient(x),
+                jnp.arange(t)[None])
+        out, kl, kept = sparse_attention(
+            q, k, v, qi, ki, wi, topk=self.indexer.topk,
+            block_k=self.block_size)
+        with jax.named_scope("dsa/kl"):
+            loss = jnp.mean(kl)
+            out = attach_auxiliary_loss(out, loss, self.indexer.loss_coef)
+        with jax.named_scope("dsa/select"):
+            state = {
+                "pairs_selected_total": _add_u64(
+                    state["pairs_selected_total"],
+                    jnp.sum(kept, axis=1, dtype=jnp.uint32)),
+                "pairs_causal_total": _add_u64(
+                    state["pairs_causal_total"],
+                    jnp.full((b,), pairs_causal(t), jnp.uint32)),
+                "indexer_kl": jax.lax.stop_gradient(loss)}
+        return out, state
 
     def _attend(self, q, k, v, mask, drop, attn_rng, use_flash):
         """(B, T, H, D) weighted values from q and the k, v of
@@ -1079,12 +1279,14 @@ class TransformerBlock(LayerConf):
         ln, attn = self._sub()
         ks = jax.random.split(key, 4)
         ln_p, _ = ln.init(ks[0], input_type, dtype)
-        attn_p, _ = attn.init(ks[1], input_type, dtype)
+        attn_p, attn_state = attn.init(ks[1], input_type, dtype)
         p = {"ln1": ln_p, "attn": attn_p,
              "ln2": ln.init(ks[0], input_type, dtype)[0]}
+        # an attention that counts (a sparse one) keeps its state here
+        state = {"attn": attn_state} if attn_state else {}
         if self.ffn is not None:
             p["ffn"], ffn_state = self.ffn.init(ks[2], input_type, dtype)
-            return p, ({"ffn": ffn_state} if ffn_state else {})
+            return p, ({**state, "ffn": ffn_state} if ffn_state else state)
         hidden = self.mlp_ratio * self.n_out
         w_init = get_initializer(self.weight_init)
         p["W1"] = w_init(ks[2], (self.n_out, hidden), self.n_out, hidden,
@@ -1094,7 +1296,7 @@ class TransformerBlock(LayerConf):
         if self.has_bias:
             p["b1"] = jnp.zeros((hidden,), dtype)
             p["b2"] = jnp.zeros((self.n_out,), dtype)
-        return p, {}
+        return p, state
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         from deeplearning4j_tpu.nn.activations import get_activation
@@ -1103,8 +1305,10 @@ class TransformerBlock(LayerConf):
         if rng is not None:
             rng, r1, r2 = jax.random.split(rng, 3)
         h, _ = ln.apply(params["ln1"], {}, x)
-        a, _ = attn.apply(params["attn"], {}, h, train=train, rng=r1,
-                          mask=mask)
+        a, attn_state = attn.apply(params["attn"], state.get("attn", {}), h,
+                                   train=train, rng=r1, mask=mask)
+        if attn_state:
+            state = {**state, "attn": attn_state}
         with jax.named_scope("residual"):
             if train and self.residual_dropout > 0 and r2 is not None:
                 keep = 1.0 - self.residual_dropout

@@ -2,10 +2,12 @@
 
 The XLA lowerings in nn/ are the default compute path; this package holds
 the Pallas kernels that beat them where fusion matters most: flash
-attention (`flash_attention`) and Kimi Delta Attention's chunk algebra
-(`kda_chunk`, reached through `nn/layers/linear_attention.py`). On non-TPU
-backends the kernels run in interpret mode (tests) or the callers fall
-back to the XLA path.
+attention (`flash_attention`), Kimi Delta Attention's chunk algebra
+(`kda_chunk`, reached through `nn/layers/linear_attention.py`) and learned
+sparse attention (`dsa_attention`: an indexer's exact top-k of keys a query
+and attention over them, reached through `MultiHeadAttention(indexer=)`).
+On non-TPU backends the kernels run in interpret mode (tests) or the
+callers fall back to the XLA path.
 """
 #: the one name the containers' gradient checkpointing keeps
 #: (`nn/multilayer.py::_layer_call`: `save_only_these_names(REMAT_KEEP)`):

@@ -401,13 +401,24 @@ class ExpertLoadListener(TrainingListener):
     ``moe_tokens_with_held_pair_total{layer}`` (tokens with at least one
     of their experts held here; over the tokens routed it is the share of
     tokens the layer adds anything to). The state's totals are uint32 and
-    wrap; the difference is taken modulo 2**32."""
+    wrap; the difference is taken modulo 2**32.
+
+    A sparse attention (`MultiHeadAttention(indexer=)`, alone or as a
+    block's attention) counts in its state too, in two uint32 words a
+    total (a step of long sequences passes 2**32 pairs within a few
+    steps): ``dsa_pairs_selected_total{layer}`` (the (query, key) pairs its
+    selection kept), ``dsa_pairs_causal_total{layer}`` (the pairs it chose
+    among: an exact top-k keeps ``sum_t min(t + 1, topk)`` of ``T (T + 1)
+    / 2`` a sequence, and a selection that lets a tie in or drops a key
+    shows here) and the gauge ``dsa_indexer_kl{layer}`` (the indexer's
+    loss at the last step)."""
 
     _TOTALS = ("tokens_routed_total", "tier_hits", "rows_walked_total",
                "tokens_with_held_pair_total")
 
     def __init__(self):
         self._at_start = {}
+        self._pairs_at_start = {}
 
     @staticmethod
     def _layers(model):
@@ -426,13 +437,62 @@ class ExpertLoadListener(TrainingListener):
         return {name: np.asarray(state[name], np.int64)
                 for name in cls._TOTALS if name in state}
 
+    _PAIRS = ("pairs_selected_total", "pairs_causal_total")
+
+    @classmethod
+    def _sparse_layers(cls, model):
+        """(state key, state) of every attention that selects its keys."""
+        from deeplearning4j_tpu.nn.regularization import constraint_map
+        for key, layer in constraint_map(model).items():
+            state = model.state.get(key) or {}
+            if getattr(layer, "attn", None) is not None:
+                state = state.get("attn", {})
+            if cls._PAIRS[0] in state:
+                yield key, state
+
+    @classmethod
+    def _pairs(cls, state):
+        import numpy as np
+        words = {name: [int(w) for w in np.asarray(state[name], np.uint32)]
+                 for name in cls._PAIRS}
+        return {name: lo + (hi << 32) for name, (lo, hi) in words.items()}
+
     def on_epoch_start(self, model, epoch):
         self._at_start = {key: self._totals(state)
                           for key, _, state in self._layers(model)}
+        self._pairs_at_start = {key: self._pairs(state)
+                                for key, state in self._sparse_layers(model)}
+
+    def _publish_sparse(self, model):
+        import numpy as np
+        from deeplearning4j_tpu import monitor
+        counters = {
+            "pairs_selected_total": monitor.counter(
+                "dsa_pairs_selected_total",
+                "(query, key) pairs a sparse attention's selection kept",
+                labels=("layer",)),
+            "pairs_causal_total": monitor.counter(
+                "dsa_pairs_causal_total",
+                "(query, key <= query) pairs a sparse attention's selection "
+                "chose among", labels=("layer",))}
+        kl = monitor.gauge(
+            "dsa_indexer_kl",
+            "a sparse attention's indexer loss (Kullback-Leibler divergence "
+            "from the attention's mean probabilities over the kept keys), "
+            "last step", labels=("layer",))
+        for key, state in self._sparse_layers(model):
+            now = self._pairs(state)
+            before = self._pairs_at_start.get(key, {})
+            for name, counter in counters.items():
+                counter.inc((now[name] - before.get(name, 0)) % 2 ** 64,
+                            layer=key)
+            kl.set(float(np.asarray(state["indexer_kl"])), layer=key)
+            self._pairs_at_start[key] = now
 
     def on_epoch_end(self, model, epoch):
         import numpy as np
         from deeplearning4j_tpu import monitor
+        self._publish_sparse(model)
         routed = monitor.counter(
             "moe_tokens_routed_total",
             "(token, expert) pairs routed by an expert layer, by whether "

@@ -375,6 +375,38 @@ class TestFlashKernelCompiles:
         assert not re.search(
             r"f32\[128,8,128,128\]\S* dynamic-update-slice", hlo)
 
+    @pytest.mark.parametrize("backward", [False, True],
+                             ids=["forward", "gradient"])
+    def test_sparse_attention_kernels_at_32k(self, v5e, backward):
+        """The six kernels of the learned sparse attention at the fourth
+        LM cell's shapes: one sequence of 32,768, 32 query heads of 128 on
+        4, an indexer of 16 heads of 64, 2,048 keys a query, q blocks of
+        512 with every head in VMEM. The gradient runs each forward
+        kernel but the loss's (its value is not asked for) and the two
+        backward ones."""
+        from deeplearning4j_tpu.ops import dsa_attention as D
+        t, bf = 32768, jnp.bfloat16
+        assert D.kernel_blocks(t, 512, 32, 128) == (512, 512)
+
+        def loss(*a):
+            out, kl, kept = D.sparse_attention(
+                *a, topk=2048, block_k=512, kernels=True, interpret=False)
+            return jnp.sum(out.astype(jnp.float32)) + jnp.mean(kl), kept
+
+        fn = jax.grad(loss, argnums=tuple(range(6)), has_aux=True) \
+            if backward else loss
+        hlo = _compile_v5e(
+            fn, self._one(v5e), ((1, t, 32, 128), bf), ((1, t, 4, 128), bf),
+            ((1, t, 4, 128), bf), ((1, t, 16, 64), bf), ((1, t, 64), bf),
+            ((1, t, 16), jnp.float32))
+        assert "tpu_custom_call" in hlo
+        want = {"dsa_index", "dsa_select", "dsa_attn_fwd"} | (
+            {"dsa_attn_bwd_dq", "dsa_attn_bwd_dkv"} if backward
+            else {"dsa_kl_fwd"})
+        import re
+        assert set(re.findall(r"dsa_(?:index|select|attn_fwd|kl_fwd|"
+                              r"attn_bwd_dq|attn_bwd_dkv)", hlo)) == want
+
     def test_masked_padded_f32_with_lse(self, v5e):
         # t=200: the pad path; masked non-causal with the lse output and
         # its cotangent, in f32

@@ -27,7 +27,7 @@ last key admitted AT that value; key ``s`` is kept iff ``I > tau`` or
 ``I == tau and s <= cut`` (`keep_mask`). That is `lax.top_k`'s set to the
 key, ties included, at 8 bytes a query.
 
-On a TPU six Pallas kernels do the work, none holding a score matrix in
+On a TPU five Pallas kernels do the work, none holding a score matrix in
 HBM beyond 2,048 query rows of the indexer's:
 
 - ``dsa_index``: the indexer's scores by (q block, k block) tile, written
@@ -43,10 +43,11 @@ HBM beyond 2,048 query rows of the indexer's:
   that the tile's mask is made once for them all; output, log-sum-exp and
   the count of kept pairs a query;
 - ``dsa_kl_fwd``: ``kl`` a query from the saved log-sum-exps;
-- ``dsa_attn_bwd_dq`` / ``dsa_attn_bwd_dkv``: the flash backward under the
-  same mask, and, from the probabilities they make again anyway, the
-  gradient of ``kl`` into the indexer's ``qI``, ``w`` (first kernel) and
-  ``kI`` (second).
+- ``dsa_attn_bwd``: the flash backward under the same mask in ONE walk
+  over the causal tiles (a tile's mask, probabilities and ``ds`` made
+  once for dq, dk and dv), and, from the probabilities it makes again
+  anyway, the gradient of ``kl`` into the indexer's ``qI``, ``w`` and
+  ``kI``; the key side's sums gather in float32 buffers in HBM.
 
 What the backward needs again and is dear to make (output, log-sum-exp,
 ``tau``, ``cut``, the indexer's log-sum-exp) carries `ops.REMAT_KEEP`.
@@ -334,102 +335,142 @@ def _index_grad(s, kept, p, lsei_ref, gkl_ref):
     return gkl_ref[0] * (pi - p)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
-                   ki_ref, wi_ref, tau_ref, cut_ref, lsei_ref, gkl_ref,
-                   dq_ref, dqi_ref, dwi_ref, dq_scr, dqi_scr, dwi_scr, *,
-                   geom, scale, heads, group):
-    qi, kj = pl.program_id(1), pl.program_id(2)
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
+                ki_ref, wi_ref, tau_ref, cut_ref, lsei_ref, gkl_ref, dq_ref,
+                dqi_ref, dwi_ref, dk_hbm, dv_hbm, dki_hbm, dq_scr, dqi_scr,
+                dwi_scr, dk_scr, dv_scr, dki_scr, sem, *, geom, scale, heads,
+                group):
+    """Grid (B, nq, nk), k innermost, every axis sequential: a tile's
+    mask, probabilities and ``ds`` are made ONCE and feed all six
+    gradients. dq, dqi and dwi of the q block stay in VMEM scratch over
+    its row of tiles. dk, dv and dki of a k block gather over the q
+    blocks, the OUTER axis: they are float32 buffers in HBM (``dk_hbm``
+    (B, Hk, T, D), ``dv_hbm``, ``dki_hbm`` (B, T, Di padded to whole
+    lanes: a copy moves whole lane tiles), never BlockSpec-pipelined),
+    which a tile finds in one of two VMEM slots (``kj % 2``; zeros at
+    the k block's FIRST q block, so the buffers need no zeroing), adds to
+    in the order the q blocks come (ascending, float32: the sums of a
+    k-major walk bit for bit) and copies back.
+
+    The read-after-write on those buffers is ordered by explicit copies
+    that are WAITED on, not by the grid's pipeline: tile ``kj`` asks for
+    tile ``kj + 1``'s sums once its heads are done (after waiting for
+    the copy back of tile ``kj - 1``, whose slot they take; a row's first
+    tile asks for its own) and waits for its own before its first sum;
+    the row's last step waits for every copy back still in flight. So
+    when a q block's first tile starts, nothing an earlier q block wrote
+    is still on its way (q block 0 has ONE live tile: k block 0 is
+    written at step (0, 0) and read at the very next, (1, 0)), and within
+    a row no two tiles share a k block. The copy in runs under the
+    indexer's gradient and the next tile's mask, the copy back under the
+    next tile (on the v5e, alone at the Keye cell's shapes: 216.2 ms with
+    the copies, 215.7 without; 222.8 asked for at the tile's own start)."""
+    b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    k_hi = geom.k_hi(qi)
+
+    def copies(j, back):
+        """The three copies of k block ``j``'s sums, HBM -> its VMEM slot
+        or ``back``."""
+        slot, rows = j % 2, pl.ds(j * geom.bk, geom.bk)
+        pairs = ((dk_hbm.at[b, :, rows], dk_scr.at[slot]),
+                 (dv_hbm.at[b, :, rows], dv_scr.at[slot]),
+                 (dki_hbm.at[b, rows], dki_scr.at[slot]))
+        return [pltpu.make_async_copy(*(pair[::-1] if back else pair),
+                                      sem.at[int(back), slot, n])
+                for n, pair in enumerate(pairs)]
+
+    def wait_back(j):
+        for copy in copies(j, True):
+            copy.wait()
+
+    def summed_before(j):
+        """Whether an earlier q block saw k block ``j``'s keys."""
+        return qi != geom.q_lo(j)
+
+    def fetch(j):
+        """k block ``j``'s sums so far into its slot: zeros at the first
+        q block that sees its keys, a copy from HBM after that."""
+        @pl.when(jnp.logical_not(summed_before(j)))
+        def _():
+            for scr in (dk_scr, dv_scr, dki_scr):
+                scr[j % 2] = jnp.zeros(scr.shape[1:], scr.dtype)
+
+        @pl.when(summed_before(j))
+        def _():
+            for copy in copies(j, False):
+                copy.start()
 
     @pl.when(kj == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
         dqi_scr[...] = jnp.zeros_like(dqi_scr)
         dwi_scr[...] = jnp.zeros_like(dwi_scr)
+        fetch(kj)
 
-    @pl.when(kj <= geom.k_hi(qi))
+    @pl.when(kj <= k_hi)
     def _tile():
+        slot = kj % 2
         s, kept = _tile_mask(qi_ref, ki_ref, wi_ref, tau_ref, cut_ref,
                              qi * geom.bq, kj * geom.bk)
 
-        def each(h, g, p):
-            k = k_ref[0, g]
-            dp = jax.lax.dot_general(do_ref[0, h], v_ref[0, g],
-                                     (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, h, 0][:, None])
-            dq_scr[h] += scale * jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-        p = _mean_probs(q_ref, k_ref, lse_ref, kept, scale, heads, group,
-                        each)
-        di = _index_grad(s, kept, p, lsei_ref, gkl_ref)
-        ki, wi = ki_ref[0], wi_ref[0]
-        for j in range(qi_ref.shape[1]):
-            a = _index_heads(qi_ref[0], ki, j)
-            dwi_scr[j] += _fold(di * jnp.maximum(a, 0.0))
-            g = jnp.where(a > 0, di * wi[:, j:j + 1], 0.0)
-            dqi_scr[j] += jax.lax.dot_general(
-                g.astype(ki.dtype), ki, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-    @pl.when(kj == pl.num_programs(2) - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
-        dqi_ref[0] = dqi_scr[...].astype(dqi_ref.dtype)
-        for j in range(dwi_scr.shape[0]):
-            dwi_ref[0, j, 0] = dwi_scr[j].sum(-1)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
-                    ki_ref, wi_ref, tau_ref, cut_ref, lsei_ref, gkl_ref,
-                    dk_ref, dv_ref, dki_ref, dk_scr, dv_scr, dki_scr, *,
-                    geom, scale, heads, group):
-    """Grid (B, nk, nq), q innermost from the first q block that sees the
-    k block."""
-    kj, st = pl.program_id(1), pl.program_id(2)
-    qi = geom.q_lo(kj) + st
-
-    @pl.when(st == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-        dki_scr[...] = jnp.zeros_like(dki_scr)
-
-    @pl.when(qi <= geom.nq - 1)
-    def _tile():
-        s, kept = _tile_mask(qi_ref, ki_ref, wi_ref, tau_ref, cut_ref,
-                             qi * geom.bq, kj * geom.bk)
+        @pl.when(summed_before(kj))
+        def _():
+            for copy in copies(kj, False):
+                copy.wait()
 
         def each(h, g, p):
-            q, do = q_ref[0, h], do_ref[0, h]
-            dv_scr[g] += jax.lax.dot_general(
+            q, k, do = q_ref[0, h], k_ref[0, g], do_ref[0, h]
+            dv_scr[slot, g] += jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             dp = jax.lax.dot_general(do, v_ref[0, g],
                                      (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[0, h, 0][:, None])
-            dk_scr[g] += scale * jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds = (p * (dp - delta_ref[0, h, 0][:, None])).astype(k.dtype)
+            dq_scr[h] += scale * jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_scr[slot, g] += scale * jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
         p = _mean_probs(q_ref, k_ref, lse_ref, kept, scale, heads, group,
                         each)
+
+        @pl.when(kj < k_hi)
+        def _():
+            @pl.when(kj >= 1)
+            def _():
+                wait_back(kj - 1)
+
+            fetch(kj + 1)
+
         di = _index_grad(s, kept, p, lsei_ref, gkl_ref)
         ki, wi = ki_ref[0], wi_ref[0]
         for j in range(qi_ref.shape[1]):
             a = _index_heads(qi_ref[0], ki, j)
-            g = jnp.where(a > 0, di * wi[:, j:j + 1], 0.0)
-            dki_scr[...] += jax.lax.dot_general(
-                g.astype(ki.dtype), qi_ref[0, j], (((0,), (0,)), ((), ())),
+            dwi_scr[j] += _fold(di * jnp.maximum(a, 0.0))
+            g = jnp.where(a > 0, di * wi[:, j:j + 1], 0.0).astype(ki.dtype)
+            dqi_scr[j] += jax.lax.dot_general(
+                g, ki, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            dki_scr[slot, :, :ki.shape[1]] += jax.lax.dot_general(
+                g, qi_ref[0, j], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        for copy in copies(kj, True):
+            copy.start()
 
-    @pl.when(st == pl.num_programs(2) - 1)
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
-        dki_ref[0] = dki_scr[...].astype(dki_ref.dtype)
+        @pl.when(k_hi >= 1)
+        def _():
+            wait_back(k_hi - 1)
+
+        wait_back(k_hi)
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dqi_ref[0] = dqi_scr[...].astype(dqi_ref.dtype)
+        for j in range(dwi_scr.shape[0]):
+            dwi_ref[0, j, 0] = dwi_scr[j].sum(-1)
 
 
 # ------------------------------------------------------- calling the kernels
@@ -513,32 +554,25 @@ def _select(qih, ki, wi, topk, bq, bk, interpret):
 
 
 class _Specs:
-    """The BlockSpecs of the kernels whose grid is (B, q blocks, k blocks)
-    (``q_major``) or (B, k blocks, q steps)."""
+    """The BlockSpecs of the kernels whose grid is (B, q blocks, k blocks):
+    a step above the diagonal repeats the row's last live k block (no new
+    DMA)."""
 
-    def __init__(self, geom, q_major, h, hk, hi, d, dv, di):
+    def __init__(self, geom, h, hk, hi, d, dv, di):
         bq, bk = geom.bq, geom.bk
-        if q_major:
-            qx = lambda b, i, j: i
-            kx = lambda b, i, j: jnp.minimum(j, geom.k_hi(i))
-        else:
-            qx = lambda b, j, st: jnp.minimum(geom.q_lo(j) + st,
-                                              geom.nq - 1)
-            kx = lambda b, j, st: j
-        at_q = lambda *g: (g[0], 0, qx(*g), 0)
-        at_k = lambda *g: (g[0], 0, kx(*g), 0)
+        kx = lambda i, j: jnp.minimum(j, geom.k_hi(i))
+        at_q = lambda b, i, j: (b, 0, i, 0)
+        at_k = lambda b, i, j: (b, 0, kx(i, j), 0)
         self.q = pl.BlockSpec((1, h, bq, d), at_q)
         self.o = pl.BlockSpec((1, h, bq, dv), at_q)
         self.k = pl.BlockSpec((1, hk, bk, d), at_k)
         self.v = pl.BlockSpec((1, hk, bk, dv), at_k)
-        self.row = pl.BlockSpec((1, h, 1, bq),
-                                lambda *g: (g[0], 0, 0, qx(*g)))
+        self.row = pl.BlockSpec((1, h, 1, bq), lambda b, i, j: (b, 0, 0, i))
         self.qi = pl.BlockSpec((1, hi, bq, di), at_q)
-        self.ki = pl.BlockSpec((1, bk, di), lambda *g: (g[0], kx(*g), 0))
-        self.wi = pl.BlockSpec((1, bq, hi), lambda *g: (g[0], qx(*g), 0))
-        self.dwi = pl.BlockSpec((1, hi, 1, bq),
-                                lambda *g: (g[0], 0, 0, qx(*g)))
-        self.col = pl.BlockSpec((1, bq, 1), lambda *g: (g[0], qx(*g), 0))
+        self.ki = pl.BlockSpec((1, bk, di), lambda b, i, j: (b, kx(i, j), 0))
+        self.wi = pl.BlockSpec((1, bq, hi), lambda b, i, j: (b, i, 0))
+        self.dwi = pl.BlockSpec((1, hi, 1, bq), lambda b, i, j: (b, 0, 0, i))
+        self.col = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
 
 
 def _setup(qh, kh, vh, qih, bq, bk):
@@ -557,7 +591,7 @@ def _forward_kernels(qh, kh, vh, qih, ki, wi, topk, bq, bk, interpret):
     b, h, t, d = qh.shape
     geom, common, dims = _setup(qh, kh, vh, qih, bq, bk)
     dv = dims[4]
-    sp = _Specs(geom, True, *dims)
+    sp = _Specs(geom, *dims)
     tau, cut, lsei = _select(qih, ki, wi, topk, bq, bk, interpret)
     f32 = jnp.float32
     with jax.named_scope("dsa/attn"):
@@ -591,50 +625,52 @@ def _forward_kernels(qh, kh, vh, qih, ki, wi, topk, bq, bk, interpret):
     return out, lse, tau, cut, lsei, kl, kept
 
 
+def _rounded(x, dtype):
+    """Float32 ``x`` in ``dtype``, the rounding KEPT: a plain cast XLA may
+    skip where the consumer widens again (`xla_allow_excess_precision`;
+    the rotation's backward does), and the step's numbers are to be those
+    of a kernel that wrote ``dtype`` itself."""
+    info = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(x, info.nexp, info.nmant).astype(dtype)
+
+
 def _backward_kernels(res, g_out, g_kl, bq, bk, interpret):
     qh, kh, vh, qih, ki, wi, out, lse, tau, cut, lsei = res
     b, h, t, d = qh.shape
     geom, common, dims = _setup(qh, kh, vh, qih, bq, bk)
     _, hk, hi, _, dv, di = dims
     f32 = jnp.float32
+    sp = _Specs(geom, *dims)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    dip = -(-di // _LANES) * _LANES
     with jax.named_scope("dsa/attn"):
         delta = jnp.sum(g_out.astype(f32) * out.astype(f32),
                         axis=-1)[:, :, None, :]           # (B, H, 1, T)
-        do = g_out.astype(qh.dtype)
-        gkl = g_kl.astype(f32)
-        ins = (qh, kh, vh, do, lse, delta, qih, ki, wi, tau, cut, lsei, gkl)
-        sp = _Specs(geom, True, *dims)
-        specs = [sp.q, sp.k, sp.v, sp.o, sp.row, sp.row, sp.qi, sp.ki,
-                 sp.wi, sp.col, sp.col, sp.col, sp.col]
-        dq, dqi, dwi = pl.pallas_call(
-            functools.partial(_bwd_dq_kernel, **common),
+        dq, dqi, dwi, dk, dv_, dki = pl.pallas_call(
+            functools.partial(_bwd_kernel, **common),
             grid=(b, geom.nq, geom.nk),
-            in_specs=specs, out_specs=[sp.q, sp.qi, sp.dwi],
+            in_specs=[sp.q, sp.k, sp.v, sp.o, sp.row, sp.row, sp.qi, sp.ki,
+                      sp.wi, sp.col, sp.col, sp.col, sp.col],
+            out_specs=[sp.q, sp.qi, sp.dwi, hbm, hbm, hbm],
             out_shape=[jax.ShapeDtypeStruct(qh.shape, qh.dtype),
                        jax.ShapeDtypeStruct(qih.shape, qih.dtype),
-                       jax.ShapeDtypeStruct((b, hi, 1, t), f32)],
+                       jax.ShapeDtypeStruct((b, hi, 1, t), f32),
+                       jax.ShapeDtypeStruct(kh.shape, f32),
+                       jax.ShapeDtypeStruct(vh.shape, f32),
+                       jax.ShapeDtypeStruct((b, t, dip), f32)],
             scratch_shapes=[pltpu.VMEM((h, bq, d), f32),
                             pltpu.VMEM((hi, bq, di), f32),
-                            pltpu.VMEM((hi, bq, _LANES), f32)],
-            compiler_params=_params("parallel", "parallel", "arbitrary"),
-            interpret=interpret, name="dsa_attn_bwd_dq",
-        )(*ins)
-        sp = _Specs(geom, False, *dims)
-        specs = [sp.q, sp.k, sp.v, sp.o, sp.row, sp.row, sp.qi, sp.ki,
-                 sp.wi, sp.col, sp.col, sp.col, sp.col]
-        dk, dv_, dki = pl.pallas_call(
-            functools.partial(_bwd_dkv_kernel, **common),
-            grid=(b, geom.nk, geom.nq),
-            in_specs=specs, out_specs=[sp.k, sp.v, sp.ki],
-            out_shape=[jax.ShapeDtypeStruct(kh.shape, kh.dtype),
-                       jax.ShapeDtypeStruct(vh.shape, vh.dtype),
-                       jax.ShapeDtypeStruct(ki.shape, ki.dtype)],
-            scratch_shapes=[pltpu.VMEM((hk, bk, d), f32),
-                            pltpu.VMEM((hk, bk, dv), f32),
-                            pltpu.VMEM((bk, di), f32)],
-            compiler_params=_params("parallel", "parallel", "arbitrary"),
-            interpret=interpret, name="dsa_attn_bwd_dkv",
-        )(*ins)
+                            pltpu.VMEM((hi, bq, _LANES), f32),
+                            pltpu.VMEM((2, hk, bk, d), f32),
+                            pltpu.VMEM((2, hk, bk, dv), f32),
+                            pltpu.VMEM((2, bk, dip), f32),
+                            pltpu.SemaphoreType.DMA((2, 2, 3))],
+            compiler_params=_params("arbitrary", "arbitrary", "arbitrary"),
+            interpret=interpret, name="dsa_attn_bwd",
+        )(qh, kh, vh, g_out.astype(qh.dtype), lse, delta, qih, ki, wi, tau,
+          cut, lsei, g_kl.astype(f32))
+        dk, dv_, dki = (_rounded(a, like.dtype) for a, like in (
+            (dk, kh), (dv_, vh), (dki[..., :di], ki)))
     return dq, dk, dv_, dqi, dki, dwi[:, :, 0, :].transpose(0, 2, 1)
 
 

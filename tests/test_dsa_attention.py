@@ -136,9 +136,14 @@ def test_the_indexers_loss_and_its_gradient_are_the_plain_formulas():
         assert not np.any(np.asarray(g))
 
 
+def _loss_of(call, w):
+    return lambda *a: (lambda r: jnp.sum(r[0] * w) + 3.0 * jnp.mean(r[1]))(
+        call(*a))
+
+
 @pytest.mark.parametrize("ties", [False, True])
 def test_the_kernels_are_the_xla_path(ties):
-    """The six Pallas kernels, interpreted, against the XLA path at 128-
+    """The five Pallas kernels, interpreted, against the XLA path at 128-
     wide heads in a group of 4: output, the loss a query, the pairs kept a
     query (planted ties included: every indexer weight alike and the
     products rounded coarse), and all six gradients."""
@@ -163,13 +168,71 @@ def test_the_kernels_are_the_xla_path(ties):
                     & (jnp.arange(256)[None] <= jnp.arange(256)[:, None])
                     ).sum()) > 50       # ties the selection had to break
     w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
-    loss = lambda f: lambda *a: (lambda r: jnp.sum(r[0] * w)
-                                 + 3.0 * jnp.mean(r[1]))(f(*a))
-    for g, r in zip(jax.grad(loss(call(True)), range(6))(q, k, v, qi, ki, wi),
-                    jax.grad(loss(call(False)), range(6))(q, k, v, qi, ki,
-                                                          wi)):
+    for g, r in zip(
+            jax.grad(_loss_of(call(True), w), range(6))(q, k, v, qi, ki, wi),
+            jax.grad(_loss_of(call(False), w), range(6))(q, k, v, qi, ki,
+                                                         wi)):
         scale = max(float(jnp.abs(r).max()), 1e-6)
         assert float(jnp.abs(g - r).max()) <= 2e-5 * scale
+
+
+@pytest.mark.parametrize("t,b,bq", [(512, 2, 128), (640, 1, 128),
+                                    (512, 1, 64)],
+                         ids=["four_q_blocks", "five_q_blocks",
+                              "two_q_blocks_a_k_block"])
+def test_the_one_backward_kernel_is_the_xla_path(t, b, bq, monkeypatch):
+    """`dsa_attn_bwd`, interpreted, at four and at five q blocks with bq =
+    bk = 128 (q block 0 has one live tile, so k block 0 is written at grid
+    step (0, 0) and read at the very next, (1, 0); an even and an odd
+    count of tiles in a row's last slot), with two q blocks a k block (a
+    VMEM budget that holds 64 rows), and at a group of 8 query heads on
+    one key head: all six gradients against the XLA path."""
+    args = _inputs(t=t, h=8, hk=1, d=128, hi=2, di=64, b=b, seed=5)
+    monkeypatch.setattr(D, "_Q_BLOCK_BYTES", bq * 8 * 128 * 16)
+    assert D.kernel_blocks(t, 128, 8, 128) == (bq, 128)
+    call = lambda kernels: lambda *a: D.sparse_attention(
+        *a, topk=48, block_k=128, kernels=kernels, interpret=True)
+    w = jnp.cos(jnp.arange(args[0].size, dtype=jnp.float32)).reshape(
+        args[0].shape)
+    got = jax.grad(_loss_of(call(True), w), range(6))(*args)
+    want = jax.grad(_loss_of(call(False), w), range(6))(*args)
+    for g, r in zip(got, want):
+        scale = float(jnp.abs(r).max())
+        assert scale > 1e-4
+        assert float(jnp.abs(g - r).max()) <= 2e-5 * scale
+
+
+def test_the_backward_kernels_copies_are_waited_for():
+    """The key side's sums travel between HBM and VMEM by the kernel's own
+    copies. Under the TPU interpreter a copy lands only when it is WAITED
+    for and every read and write is checked against the others' clocks: a
+    k block read back before its copy out was waited for is a race (taking
+    the waits out of the row's last step and into the next row's first
+    shows as one). None here, and the plain interpreter's gradients bit
+    for bit."""
+    from jax._src.pallas.mosaic.interpret import (
+        interpret_pallas_call as mosaic,
+    )
+    from jax.experimental.pallas import tpu as pltpu
+    q, k, v, qi, ki, wi = _inputs(t=512, h=8, hk=1, d=128, hi=2, di=64,
+                                  b=1, seed=6)
+    heads_first = [D._heads_first(a) for a in (q, k, v, qi)]
+    out, lse, tau, cut, lsei, kl, _ = D._forward_kernels(
+        *heads_first, ki, wi, 48, 128, 128, True)
+    res = (*heads_first, ki, wi, out, lse, tau, cut, lsei)
+    g_out = jnp.cos(jnp.arange(out.size, dtype=jnp.float32)).reshape(
+        out.shape)
+    g_kl = jnp.full(kl.shape, 3.0 / 512)
+    plain = D._backward_kernels(res, g_out, g_kl, 128, 128, True)
+    pltpu.reset_tpu_interpret_mode_state()
+    checked = D._backward_kernels(
+        res, g_out, g_kl, 128, 128,
+        pltpu.InterpretParams(dma_execution_mode="on_wait",
+                              detect_races=True))
+    assert not mosaic.races.races_found
+    for a, b in zip(checked, plain):
+        assert np.abs(np.asarray(b)).max() > 0
+        np.testing.assert_array_equal(a, b)
 
 
 def test_the_kernels_need_tiles_that_divide_the_length():

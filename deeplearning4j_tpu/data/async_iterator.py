@@ -244,7 +244,9 @@ class AsyncDataSetIterator(DataSetIterator):
         return self._source.batch_size()
 
     def set_pre_processor(self, pre_processor):
-        # DL4J AsyncDataSetIterator delegates to the backing iterator
+        # DL4J AsyncDataSetIterator delegates to the backing iterator:
+        # the source routes every batch, a `MultiDataSet` like a
+        # `DataSet`, through it as the prefetch thread pulls it
         self._source.set_pre_processor(pre_processor)
         return self
 

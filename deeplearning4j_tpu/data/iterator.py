@@ -12,6 +12,7 @@ from typing import Iterator, List, Optional
 import numpy as np
 
 from deeplearning4j_tpu.data.dataset import DataSet
+from deeplearning4j_tpu.data.normalization import DataSetPreProcessor
 
 
 class DataSetIterator:
@@ -24,7 +25,10 @@ class DataSetIterator:
     pre_processor = None
 
     def reset(self):
-        pass
+        """Start over. A source that overrides this calls it too: the
+        iterator that holds a pre-processor resets it with itself."""
+        if isinstance(self.pre_processor, DataSetPreProcessor):
+            self.pre_processor.reset()
 
     def __iter__(self) -> Iterator[DataSet]:
         raise NotImplementedError
@@ -64,6 +68,7 @@ class ArrayDataSetIterator(DataSetIterator):
         return self._batch
 
     def reset(self):
+        super().reset()
         self._epoch += 1
 
     def __iter__(self):

@@ -31,6 +31,10 @@ class DataSetPreProcessor:
     def preprocess(self, ds: DataSet) -> DataSet:
         raise NotImplementedError
 
+    def reset(self):
+        """The iterator it is attached to was reset: a pre-processor that
+        counts what it has seen counts from here again."""
+
     def device_affine(self):
         """(shift, scale) float32 arrays such that
         `features.astype(f32) * scale + shift` reproduces this

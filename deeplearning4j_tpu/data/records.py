@@ -212,6 +212,7 @@ class RecordReaderDataSetIterator(DataSetIterator):
         return self._batch
 
     def reset(self):
+        super().reset()
         self.reader.reset()
         if self._mp_pipe:               # False = disabled after failure
             self._mp_pipe.reset()
@@ -386,6 +387,7 @@ class SequenceRecordReaderDataSetIterator(DataSetIterator):
         return self._batch
 
     def reset(self):
+        super().reset()
         self.reader.reset()
         if self.labels_reader is not None:
             self.labels_reader.reset()
@@ -483,6 +485,7 @@ class RecordReaderMultiDataSetIterator(DataSetIterator):
         return self._batch
 
     def reset(self):
+        super().reset()
         for r in self.readers.values():
             r.reset()
 

@@ -424,6 +424,7 @@ class EpochPositionMixin:
         self._sought = False
 
     def reset(self):
+        super().reset()
         self._epoch += 1
         self._pos = 0
         self._sought = False

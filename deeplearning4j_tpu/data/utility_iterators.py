@@ -54,6 +54,7 @@ class EarlyTerminationDataSetIterator(DataSetIterator):
             yield self._pp(ds)
 
     def reset(self):
+        super().reset()
         self.source.reset()
 
 
@@ -72,6 +73,7 @@ class MultipleEpochsIterator(DataSetIterator):
             self.source.reset()
 
     def reset(self):
+        super().reset()
         self.source.reset()
 
 
@@ -97,6 +99,7 @@ class _SplitView(DataSetIterator):
             self.parent.source.reset()
 
     def reset(self):
+        super().reset()
         self.parent.source.reset()
 
 
@@ -147,9 +150,6 @@ class SamplingDataSetIterator(DataSetIterator):
                 else np.asarray(self.dataset.labels_mask)[sel]))
         self._epoch += 1
 
-    def reset(self):
-        pass
-
 
 class IteratorDataSetIterator(DataSetIterator):
     """Wraps any (re-iterable) python iterable of DataSets
@@ -160,9 +160,6 @@ class IteratorDataSetIterator(DataSetIterator):
 
     def __iter__(self) -> Iterator[DataSet]:
         return (self._pp(ds) for ds in self._items)
-
-    def reset(self):
-        pass
 
 
 class AsyncMultiDataSetIterator:
@@ -192,6 +189,7 @@ class ReconstructionDataSetIterator(DataSetIterator):
         self.source = source
 
     def reset(self):
+        super().reset()
         self.source.reset()
 
     def batch_size(self):
@@ -215,6 +213,7 @@ class AsyncShieldDataSetIterator(DataSetIterator):
         self.source = source
 
     def reset(self):
+        super().reset()
         self.source.reset()
 
     def batch_size(self):
@@ -272,6 +271,7 @@ class MultiDataSetWrapperIterator(DataSetIterator):
         self.source = source
 
     def reset(self):
+        super().reset()
         if hasattr(self.source, "reset"):
             self.source.reset()
 
@@ -322,6 +322,7 @@ class AbstractDataSetIterator(DataSetIterator):
         return self._batch
 
     def reset(self):
+        super().reset()
         if hasattr(self._iterable, "reset"):
             self._iterable.reset()
 
@@ -369,9 +370,6 @@ class ListDataSetIterator(DataSetIterator):
 
     def batch_size(self):
         return self._batch
-
-    def reset(self):
-        pass
 
     def __iter__(self):
         def cat(arrs):
@@ -455,6 +453,7 @@ class WorkspacesShieldDataSetIterator(DataSetIterator):
         return self._source.batch_size()
 
     def reset(self):
+        super().reset()
         self._source.reset()
 
     def __iter__(self):
@@ -477,9 +476,6 @@ class MovingWindowBaseDataSetIterator(DataSetIterator):
 
     def batch_size(self):
         return self._window
-
-    def reset(self):
-        pass
 
     def __iter__(self):
         n = self._ds.num_examples()
@@ -528,9 +524,6 @@ class FileSplitDataSetIterator(DataSetIterator):
 
     def batch_size(self):
         return None
-
-    def reset(self):
-        pass
 
     def __iter__(self):
         for path in self._files:
@@ -610,6 +603,7 @@ class JointParallelDataSetIterator(DataSetIterator):
         return self._sources[0].batch_size()
 
     def reset(self):
+        super().reset()
         for s in self._sources:
             s.reset()
 

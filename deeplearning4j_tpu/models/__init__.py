@@ -24,8 +24,8 @@ from deeplearning4j_tpu.models.zoo import (
     zoo_models,
 )
 from deeplearning4j_tpu.models.transformer import (
-    Glm4MoeLiteLM, KeyeVL2LM, KimiLinearLM, Lfm2MoeLM, TransformerLM,
-    TransformerLMMoE,
+    Glm4MoeLiteLM, KeyeVL2LM, KimiLinearLM, Lfm2MoeLM, SdarMoeLM,
+    TransformerLM, TransformerLMMoE,
 )
 
 __all__ = [
@@ -34,6 +34,6 @@ __all__ = [
     "TextGenerationLSTM", "InceptionResNetV1", "FaceNetNN4Small2", "UNet",
     "TransformerLM", "TransformerLMMoE", "KimiLinearLM", "Glm4MoeLiteLM",
     "Lfm2MoeLM",
-    "KeyeVL2LM",
+    "KeyeVL2LM", "SdarMoeLM",
     "model_by_name", "zoo_models",
 ]

@@ -609,3 +609,107 @@ class KeyeVL2LM(ZooModel):
             loss="sparse_mcxent", has_bias=False, weight_init="normal"),
             "norm")
         return g.set_outputs("head").build()
+
+
+@dataclasses.dataclass
+class SdarMoeLM(ZooModel):
+    """Block-diffusion LM of JetLM's SDAR family (``model_type``
+    ``sdar_moe``) in its TRAINING form, as a `ComputationGraph` over one
+    input: the stream ``[noisy copy ; clean copy]`` of ``2 *
+    seq_length`` token ids that `data.denoise.BlockDiffusionPreProcessor`
+    makes of ``seq_length`` ids. Token embedding -> pre-norm blocks
+    (RMSNorm, no biases) -> the first ``seq_length`` rows (the noisy
+    half: `TimeSliceVertex`; the head over the clean half would be thrown
+    away) -> RMSNorm (a norm of rows: the same numbers as norming before
+    the slice) -> an UNTIED head whose score is the denoising loss: row i
+    of the noisy half predicts token i ITSELF (no shift), the
+    cross-entropy at a masked position weighted by ``1 / t`` of its
+    block's noise level, summed and divided by the count of positions
+    (`RnnOutputLayer(weighted=True)`), over blocks of positions.
+
+    Every layer: `MultiHeadAttention` of ``n_heads`` query heads on
+    ``n_kv_heads`` key/value heads, ``head_dim`` wide whatever the
+    stream's width, q and k RMS-normed over the head width before the
+    rotation (``rope_theta``; positions restart at the clean half), DENSE
+    under the block-diffusion rule at ``block_length``: a noisy block sees
+    itself both ways and the clean copies of the blocks before it, the
+    clean copy is block-causal
+    (`nn/layers/attention.py::block_diffusion_visible`; on a TPU the
+    flash kernels walk only the tiles the rule leaves visible). Then the
+    expert layer `KeyeVL2LM` has: a softmax router over ``n_experts``,
+    the ``top_k`` largest kept and renormalised, SwiGLU experts of
+    ``expert_hidden``, NO shared expert; ``experts_held`` is the range of
+    experts this chip holds (None: all), and a token none of whose
+    experts is held gets exactly zero from the layer.
+
+    Generation (a block denoised over several passes against a cache of
+    clean blocks) is not part of it. Defaults: the published shape cut to
+    widths a CPU test can run; `benchmark/configs/sdar-30b-a3b-chat.json`
+    holds the published sizes."""
+    vocab_size: int = 1024
+    seq_length: int = 128
+    block_length: int = 4
+    n_embd: int = 128
+    n_layers: int = 2
+    n_heads: int = 8
+    n_kv_heads: int = 2
+    head_dim: int = 32
+    rope_theta: float = 1e6
+    n_experts: int = 16
+    top_k: int = 4
+    expert_hidden: int = 64
+    experts_held: Optional[Tuple[int, int]] = None
+    rms_norm_eps: float = 1e-6
+    learning_rate: float = 1e-4
+    beta1: float = 0.9
+    beta2: float = 0.95
+    epsilon: float = 1e-8
+    weight_decay: float = 0.1
+    compute_dtype: Optional[str] = None
+    gradient_checkpointing: bool = True
+    seed: int = 123
+    block_size: int = 512
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.conf.graph_vertices import TimeSliceVertex
+        from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(AdamW(self.learning_rate, beta1=self.beta1,
+                            beta2=self.beta2, epsilon=self.epsilon,
+                            weight_decay=self.weight_decay,
+                            decay_matrices_only=True))
+             .gradient_checkpointing(self.gradient_checkpointing))
+        if self.compute_dtype:
+            b = b.compute_dtype(self.compute_dtype)
+        g = b.graph_builder().add_inputs("ids").set_input_types(
+            InputType.recurrent(1, 2 * self.seq_length))
+        attention = MultiHeadAttention(
+            n_out=self.n_embd, n_heads=self.n_heads,
+            n_kv_heads=self.n_kv_heads, head_dim=self.head_dim,
+            block_diffusion=self.block_length, use_rope=True,
+            rope_base=self.rope_theta, qk_norm=True,
+            norm_epsilon=self.rms_norm_eps, has_bias=False,
+            attention_impl="flash", block_size=self.block_size,
+            weight_init="normal")
+        experts = MoEFeedForward(
+            n_out=self.n_embd, n_experts=self.n_experts, top_k=self.top_k,
+            hidden=self.expert_hidden, activation="swish", gated=True,
+            has_bias=False, experts_held=self.experts_held,
+            router="softmax", n_shared=0, weight_init="normal")
+        g.add_layer("embed", EmbeddingSequenceLayer(
+            n_out=self.n_embd, n_in=self.vocab_size), "ids")
+        last = "embed"
+        for i in range(self.n_layers):
+            g.add_layer(f"layer{i}", TransformerBlock(
+                n_out=self.n_embd, n_heads=self.n_heads, norm="rms",
+                norm_epsilon=self.rms_norm_eps, has_bias=False,
+                attn=attention, ffn=experts), last)
+            last = f"layer{i}"
+        g.add_vertex("noisy", TimeSliceVertex(steps=self.seq_length), last)
+        g.add_layer("norm", RMSNormLayer(epsilon=self.rms_norm_eps), "noisy")
+        g.add_layer("head", RnnOutputLayer(
+            n_out=self.vocab_size, activation="softmax",
+            loss="sparse_mcxent", has_bias=False, weight_init="normal",
+            weighted=True), "norm")
+        return g.set_outputs("head").build()

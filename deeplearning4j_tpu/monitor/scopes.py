@@ -101,8 +101,8 @@ PART_SCOPES = (
     ("merge", "a vertex that joins inputs: concatenate, add and the "
               "other element-wise joins"),
     ("shift", "a time-series shift vertex"),
-    ("layout", "padding, space-to-depth and the reshapes between layer "
-               "kinds"),
+    ("layout", "padding, space-to-depth, the reshapes between layer "
+               "kinds and a time-slice vertex"),
     ("reg", "the l1/l2 penalty on a layer's weights"),
 )
 

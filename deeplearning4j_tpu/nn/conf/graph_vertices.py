@@ -266,6 +266,27 @@ class ShiftTimeSeriesVertex(GraphVertexConf):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class TimeSliceVertex(GraphVertexConf):
+    """The first ``steps`` time steps of a sequence: (B, T, F) ->
+    (B, steps, F) (ids (B, T) likewise), the mask with it. What hands a
+    head the half of a stream it scores: the first L of a block-diffusion
+    LM's ``[noisy ; clean]`` stream of 2L rows, so that the head over the
+    clean half, which nothing reads, is never made."""
+    steps: int = 0
+
+    def output_type(self, *input_types: InputType) -> InputType:
+        t = input_types[0]
+        if t.kind != Kind.RNN or not 0 < self.steps <= t.shape[0]:
+            raise ValueError(f"TimeSliceVertex [0, {self.steps}) of {t}")
+        return InputType(Kind.RNN, (self.steps,) + tuple(t.shape[1:]))
+
+    def apply(self, *inputs):
+        with jax.named_scope("layout"):
+            return jax.lax.slice_in_dim(inputs[0], 0, self.steps, axis=1)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class PoolHelperVertex(GraphVertexConf):
     """Strip the first spatial row and column of a CNN activation
     (DL4J nn/conf/graph/PoolHelperVertex.java + impl

@@ -213,6 +213,9 @@ class ComputationGraph:
                 return None
             n = m.shape[0] // vertex.stack_size
             return m[vertex.from_idx * n:(vertex.from_idx + 1) * n]
+        if vname == "TimeSliceVertex":
+            m = in_masks[0]
+            return None if m is None else m[:, :vertex.steps]
         return next((m for m in in_masks if m is not None), None)
 
     def _forward(self, params, state, inputs: Sequence, train, rng,

@@ -221,6 +221,57 @@ def dot_product_attention(q, k, v, *, mask=None, causal=False,
     return out
 
 
+def block_diffusion_visible(rows, cols, length: int, block: int):
+    """The visibility rule of block-diffusion training over a stream of
+    ``2 * length`` rows ``[noisy copy ; clean copy]``: bool (rows, cols)
+    from absolute row and column indices. With ``noisy(i) = i < length``,
+    ``pos(i) = i mod length`` and ``blk(i) = pos(i) // block``, row i sees
+    row j iff both are noisy and ``blk(j) == blk(i)`` (a noisy block sees
+    itself, both directions), or i is noisy, j clean and ``blk(j) <
+    blk(i)`` (and the clean copies of the blocks before it), or both are
+    clean and ``blk(j) <= blk(i)`` (block-causal); a clean row never sees
+    a noisy one (BD3-LMs' three mask parts)."""
+    qn, kn = (rows < length)[:, None], (cols < length)[None, :]
+    qb = ((rows % length) // block)[:, None]
+    kb = ((cols % length) // block)[None, :]
+    return (qn & kn & (kb == qb)) | (qn & ~kn & (kb < qb)) \
+        | (~qn & ~kn & (kb <= qb))
+
+
+def block_diffusion_attention(q, k, v, *, block: int,
+                              block_size: Optional[int] = None):
+    """Softmax attention on (B, 2L, H, D) under `block_diffusion_visible`
+    in plain XLA: the path of the CPU, the tests and a TPU layer whose
+    implementation is "dense". ``block_size`` queries at a time (None: all
+    at once) against every key, each block's scores made again in the
+    backward pass, so that no (2L)^2 array outlives a block; every row
+    sees a key (a noisy row itself, a clean row key 0 of its half)."""
+    b, t, h, d = q.shape
+    acc_t = jnp.promote_types(jnp.float32, q.dtype)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(d, acc_t))
+    bs = t if block_size is None else min(block_size, t)
+    if t % bs:
+        raise ValueError(f"stream {t} not divisible by block {bs}")
+    cols = jnp.arange(t)
+
+    @jax.checkpoint
+    def one(qb, start):
+        s = jnp.einsum("bqhd,bkhd->bhqk", qb, k,
+                       preferred_element_type=acc_t) * scale
+        seen = block_diffusion_visible(start + jnp.arange(bs), cols, t // 2,
+                                       block)
+        w = jax.nn.softmax(jnp.where(seen[None, None], s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", w.astype(v.dtype), v)
+
+    if bs == t:
+        return one(q, 0)
+    out = jax.lax.map(
+        lambda a: one(*a),
+        (jnp.moveaxis(q.reshape(b, t // bs, bs, h, d), 1, 0),
+         jnp.arange(0, t, bs)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, d)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def attach_auxiliary_loss(y, loss, coef: float):
     """How a layer adds a loss of its own to the step: the identity on
@@ -358,7 +409,15 @@ class MultiHeadAttention(LayerConf):
     keeps in its state the pairs it kept and the causal pairs it chose
     among (``pairs_selected_total``, ``pairs_causal_total``: two uint32
     words each, low then high) and its last ``indexer_kl``;
-    ``train.listeners.ExpertLoadListener`` publishes them. Scopes:
+    ``train.listeners.ExpertLoadListener`` publishes them.
+    ``block_diffusion`` (a block length) is the visibility rule of
+    block-diffusion training in ``causal``'s place
+    (`block_diffusion_visible`): the input is the stream ``[noisy copy ;
+    clean copy]`` of ``2 L`` steps, rotary positions restart at the clean
+    half (``pos(i) = i mod L``), the fused kernel walks only the tiles
+    the rule leaves visible and the XLA paths compute the same rule. It
+    is refused, by name, together with ``causal``, an indexer, attention
+    dropout, a key mask or a context-parallel axis. Scopes:
     ``mha/proj``, ``mha/norm``, ``mha/rope``, ``mha/attn``; with an
     indexer ``dsa/index/proj``, ``dsa/index``, ``dsa/select``,
     ``dsa/attn``, ``dsa/kl`` in ``mha/attn``'s place."""
@@ -369,6 +428,7 @@ class MultiHeadAttention(LayerConf):
     rope_sections: Optional[Tuple[int, ...]] = None
     indexer: Optional[LayerConf] = None
     causal: bool = False
+    block_diffusion: Optional[int] = None
     use_rope: bool = True
     n_kv_heads: Optional[int] = None
     qk_norm: bool = False
@@ -407,6 +467,8 @@ class MultiHeadAttention(LayerConf):
                 self.causal and self.attention_dropout == 0.0):
             raise ValueError("an indexer needs a causal attention without "
                              "attention dropout")
+        if self.block_diffusion is not None:
+            self._check_block_diffusion(input_type.shape[0])
         f_in = self.n_in or input_type.features
         wide = self.n_heads * d
         kv = self._kv_heads() * d
@@ -437,6 +499,27 @@ class MultiHeadAttention(LayerConf):
     def _kv_heads(self):
         return self.n_kv_heads or self.n_heads
 
+    def _check_block_diffusion(self, t, mask=None):
+        """Refuse by name what the block-diffusion rule is not combined
+        with, where the layer is initialised and where it is applied."""
+        for what, given in (
+                ("an indexer", self.indexer is not None),
+                ("causal", self.causal),
+                ("attention dropout", self.attention_dropout != 0.0),
+                ("a key mask", mask is not None),
+                ("a context-parallel axis",
+                 _CONTEXT_PARALLEL_AXIS is not None)):
+            if given:
+                raise ValueError(
+                    f"block_diffusion attention does not take {what}: the "
+                    "rule is a visibility of its own over whole unpadded "
+                    "[noisy ; clean] streams on one device")
+        if self.block_diffusion < 1 or (t is not None and (
+                t % 2 or (t // 2) % self.block_diffusion)):
+            raise ValueError(
+                f"block_diffusion {self.block_diffusion} needs a stream of "
+                f"twice a whole number of blocks, not {t} steps")
+
     def _head_dim(self):
         return self.head_dim or self.n_out // self.n_heads
 
@@ -461,10 +544,14 @@ class MultiHeadAttention(LayerConf):
                 q = rms_norm(q, params["q_norm"], self.norm_epsilon)
                 k = rms_norm(k, params["k_norm"], self.norm_epsilon)
         t_loc = x.shape[1]
+        if self.block_diffusion is not None:
+            self._check_block_diffusion(t_loc, mask)
         offset = _seq_offset(t_loc)
         if self.use_rope:
             with jax.named_scope("mha/rope"):
                 pos = (offset + jnp.arange(t_loc))[None]
+                if self.block_diffusion is not None:
+                    pos = pos % (t_loc // 2)   # both halves count 0..L-1
                 rows = pos if self.rope_sections is None else \
                     jnp.broadcast_to(pos, (len(self.rope_sections),)
                                      + pos.shape)
@@ -538,6 +625,16 @@ class MultiHeadAttention(LayerConf):
         if group > 1 and not (use_flash and _CONTEXT_PARALLEL_AXIS is None):
             # only the fused kernel reads a key head for its group
             k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+        if self.block_diffusion is not None:
+            if use_flash:
+                from deeplearning4j_tpu.ops import flash_attention
+                return flash_attention(
+                    q, k, v, block_diffusion=self.block_diffusion,
+                    block_q=self.block_size, block_k=self.block_size)
+            return block_diffusion_attention(
+                q, k, v, block=self.block_diffusion,
+                block_size=None if self.attention_impl == "dense"
+                else self.block_size)
         if _CONTEXT_PARALLEL_AXIS is not None:
             if use_flash:
                 from deeplearning4j_tpu.parallel.ring import (
@@ -704,13 +801,13 @@ _weighed_to_tokens.defvjp(_weighed_to_tokens_fwd, _weighed_to_tokens_bwd)
 _DISPATCH_LIVE_BYTES = 512 << 20
 
 # The row tiers of a dispatch that holds a share of its experts are the
-# layer's own (`MoEFeedForward._tier_divisors`): ONE small tier sized from
-# the share held, twice the balanced load, and the whole. Where 8 of 256
-# experts are held (divisor 16) a block's pairs fill 37 to 80 % of the
-# small tier, and a middle one (1/4) was compiled into every switch and
-# never taken (PERF.md section 6, PR 30); a layer that holds 8 of 64 would
-# overflow that 1/16 on every dispatch and walk the whole, eight times its
-# live rows, so its small tier is 1/4 (PR 31).
+# layer's own (`MoEFeedForward._tier_divisors`): a small tier sized from
+# the share held, twice the balanced load, and every doubling of it up to
+# the whole. A layer that holds 8 of 64 would overflow a 1/16 on every
+# dispatch, so its small tier is 1/4 (PR 31); a dispatch that tips over a
+# tier walks twice its rows, not the whole's: where the loads are skewed a
+# handful of a step's dispatches tip over, another handful every step
+# (PERF.md section 6, PR 40).
 
 #: the parts of its tokens in which a switch's last tier walks them: in
 #: one part that tier sized the LM step (4.34 GB of temporaries where the
@@ -906,19 +1003,20 @@ class MoEFeedForward(LayerConf):
     the rows summed back to their tokens under the routing weights, in
     float32. All of it has a static row count, and its cost follows that
     count, so a layer that holds a share of its experts keeps a ladder of
-    row TIERS (`_tier_divisors`: one small tier of twice the balanced
+    row TIERS (`_tier_divisors`: a small tier of twice the balanced
     load, ``2 * held / n_experts`` of the dispatch's N*top_k pairs, 1/16
-    where 8 of 256 are held and 1/4 where 8 of 64 are, and the whole): a
-    dispatch counts the pairs held here, which it has
-    on the device, and walks the smallest tier that holds them
-    (`jax.lax.switch`). The whole, the worst case of every token's every
-    expert held here, is always there to be taken, so no routing drops a
-    pair (a switch walks it in `_WHOLE_TIER_PARTS` parts, because the
-    step reserves the memory of a switch's largest branch whichever runs);
-    a layer that holds ALL its experts has that one tier and no switch.
-    Experts are
-    ``act(x W1 + b1) W2 + b2`` or, ``gated``, ``(act(x Wgate) * (x Wup))
-    Wdown`` (ReGLU with ``activation="relu"``, SwiGLU with ``"swish"``).
+    where 8 of 256 are held and 1/4 where 8 of 64 are, and every doubling
+    of it up to the whole: 1/4, 1/2, 1/1): a dispatch counts the pairs
+    held here, which it has on the device, and walks the smallest tier
+    that holds them (`jax.lax.switch`), at most twice the rows it needs
+    once it is over the small tier. The whole, the worst case of every
+    token's every expert held here, is always there to be taken, so no
+    routing drops a pair (a switch walks it in `_WHOLE_TIER_PARTS` parts,
+    because the step reserves the memory of a switch's largest branch
+    whichever runs); a layer that holds ALL its experts has that one tier
+    and no switch. Experts are ``act(x W1 + b1) W2 + b2`` or, ``gated``,
+    ``(act(x Wgate) * (x Wup)) Wdown`` (ReGLU with ``activation="relu"``,
+    SwiGLU with ``"swish"``).
     Where the full tier's rows of all N tokens (an input, a hidden and an
     output row each) would pass `_DISPATCH_LIVE_BYTES`, the tokens are
     dispatched in the fewest equal blocks that stay under it, one block
@@ -1084,11 +1182,13 @@ class MoEFeedForward(LayerConf):
         """The layer's row tiers as divisors of a dispatch's pairs,
         smallest tier first, the whole (1) last. The small tier is twice
         the balanced load of the share held: ``n_experts // (2 * held)``
-        (16 for 8 of 256, 4 for 8 of 64); a layer that holds every expert,
-        or so many that twice its load is the whole, has the one tier."""
+        (16 for 8 of 256, 4 for 8 of 64), and every halving of that
+        divisor down to the whole is a tier (4, 2, 1); a layer that holds
+        every expert, or so many that twice its load is the whole, has
+        the one tier."""
         lo, hi = self._held()
-        small = self.n_experts // (2 * (hi - lo))
-        return (small, 1) if small > 1 else (1,)
+        small = max(self.n_experts // (2 * (hi - lo)), 1)
+        return tuple(small >> i for i in range(small.bit_length()))
 
     def _tiers(self, pairs):
         """The rows a dispatch of ``pairs`` (token, slot) pairs may walk,

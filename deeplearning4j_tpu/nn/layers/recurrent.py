@@ -409,7 +409,14 @@ class RnnOutputLayer(LayerConf):
     ``tied_embedding`` keeps ``W`` as an embedding keeps its table,
     ``(n_out, n_in)``, and projects by its transpose: the head of a model
     that ties the two, a graph vertex reading the embedding's leaf
-    (``add_layer(..., params_of="embed")``)."""
+    (``add_layer(..., params_of="embed")``).
+
+    ``weighted`` reads the label mask as per-position loss WEIGHTS (any
+    float32, 0 where a position has no target) and normalises by the
+    COUNT of positions, not by the mask's sum: ``score = sum_i w_i loss_i
+    / positions``, whole and blocked alike (a denoising loss weighted by
+    ``1 / t``: `data.denoise.BlockDiffusionPreProcessor` makes the
+    weights). Without a label mask every weight is 1."""
     n_out: int = 0
     n_in: Optional[int] = None
     activation: str = "softmax"
@@ -417,6 +424,7 @@ class RnnOutputLayer(LayerConf):
     weight_init: str = "xavier"
     has_bias: bool = True
     tied_embedding: bool = False
+    weighted: bool = False
 
     def output_type(self, input_type: InputType) -> InputType:
         t = input_type.shape[0]
@@ -455,7 +463,14 @@ class RnnOutputLayer(LayerConf):
                     1 << (most.bit_length() - 1))
         with jax.named_scope("head/loss"):
             z = self.preout(params, x, train, rng)
-            return get_loss(self.loss)(labels, z, self.activation, mask=mask)
+            score = get_loss(self.loss)(labels, z, self.activation,
+                                        mask=mask)
+            if self.weighted and mask is not None:
+                # the loss divided by the weights' sum (at least 1): undo
+                # that, divide by the positions
+                total = jnp.maximum(jnp.sum(mask.astype(score.dtype)), 1.0)
+                score = score * total / (z.size // z.shape[-1])
+            return score
 
     def _blocked_score(self, params, x, labels, train, rng, mask, blk):
         with jax.named_scope("head/loss"):
@@ -491,6 +506,8 @@ class RnnOutputLayer(LayerConf):
                 body, jnp.zeros((), acc_t),
                 (x.reshape(-1, blk, f), labels.reshape(-1, blk),
                  keep.reshape(-1, blk)))
+            if self.weighted:
+                return total / n
             return total / jnp.maximum(jnp.sum(keep), 1.0).astype(acc_t)
 
 

@@ -23,6 +23,13 @@ Semantics match dot_product_attention exactly (tested):
   heads, 32 on 8, compile for the v5e at blocks of 512 too);
 - optional causal masking; key blocks wholly above the diagonal are
   neither fetched nor computed, forward and backward;
+- or, in its place, the visibility rule of block-diffusion training over a
+  stream ``[noisy copy ; clean copy]`` (``block_diffusion``): ONE
+  description of a rule (`_Geometry`: which tiles are empty, interior or
+  edge, the step -> tile maps of the q-side and k-side passes, the in-tile
+  predicate) serves both, and the three kernels and `_masked_scores` are
+  the same; a q tile's live k tiles are then two runs, not one from 0, and
+  the maps are part of the rule (`_BlockDiffusion`);
 - optional (B, Tk) 0/1 key-validity mask, fully-masked query rows emit 0;
 - a tile pays for the masking it needs and no more, chosen from what the
   code can observe (whether a key mask was given, ``causal``, the tile's
@@ -60,6 +67,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -71,20 +79,39 @@ NEG = -1e30
 
 
 class _Geometry:
-    """Which (q block, k block) tiles hold a visible score, as integer
-    arithmetic on traced block indices: in a BlockSpec index map (so that
-    a tile above the causal diagonal is never fetched) and inside a kernel
-    (so that it is never computed).
+    """The visibility rule of a call as integer arithmetic on tiles: which
+    (q block, k block) tiles hold a visible score and in what order a pass
+    walks them, on traced block indices: in a BlockSpec index map (so that
+    an empty tile is never fetched) and inside a kernel (so that it is
+    never computed). ONE description serves every rule; a rule says
 
-    Query i sees key j iff ``j <= i`` (causal). For q block ``qi`` the
-    live k blocks are ``0 .. k_hi(qi)``; for k block ``kj`` the live q
-    blocks are ``q_lo(kj) .. nq - 1``. The third grid axis counts STEPS
-    from the low end of the live range; a step past the high end repeats
-    the last live block index (no new DMA) and computes nothing."""
+    - for the q-side passes (forward, dq), which k tile a q tile's step
+      ``st`` of the third grid axis reads (`k_tile`), whether that step is
+      live (`k_live`) and what the index map fetches (`k_index`: a dead
+      step repeats the last live tile, no new DMA); ``k_steps`` is the
+      axis' length;
+    - the same for the k-side pass (dk/dv): `q_tile`, `q_live`, `q_index`,
+      ``q_steps``;
+    - whether a live tile is interior (every score visible: nothing is
+      compared or selected) or an edge (`interior`), ``edges`` saying
+      whether the rule has edge tiles at all, and the predicate on
+      absolute rows and columns an edge tile is masked by (`visible`);
+    - in Python integers, where a call is traced: how many tiles the
+      passes walk by kind (`tile_counts`) and how many tiles hold a
+      visible pair at all (`tiles_with_a_pair`, counted tile by tile from
+      the predicate's corners, not from the walk).
+
+    This class is the rule of a call that is ``causal`` (query i sees key
+    j iff ``j <= i``) or has none. For q block ``qi`` the live k blocks
+    are ``0 .. k_hi(qi)``; for k block ``kj`` the live q blocks are
+    ``q_lo(kj) .. nq - 1``; a step IS the tile's distance from the low
+    end of the run."""
 
     def __init__(self, causal, block_q, block_k, nq, nk):
         self.causal = causal
         self.bq, self.bk, self.nq, self.nk = block_q, block_k, nq, nk
+        self.edges = bool(causal)
+        self.k_steps, self.q_steps = nk, nq
 
     def k_hi(self, qi):
         if not self.causal:
@@ -96,15 +123,46 @@ class _Geometry:
             return kj * 0
         return jnp.minimum((kj * self.bk) // self.bq, self.nq - 1)
 
+    # the q-side passes: step -> k tile
+    def k_tile(self, qi, st):
+        return st
+
+    def k_live(self, qi, st, kj):
+        return kj <= self.k_hi(qi)
+
+    def k_index(self, qi, st):
+        return jnp.minimum(st, self.k_hi(qi))
+
+    # the k-side pass: step -> q tile; the third axis runs over (query
+    # head of the group, step), which ``grp`` takes apart
+    def q_tile(self, kj, st, grp):
+        return self.q_lo(kj) + grp.step(st)
+
+    def q_live(self, kj, st, grp, qi):
+        return qi <= self.nq - 1
+
+    def q_index(self, kj, st, grp):
+        return jnp.minimum(self.q_lo(kj) + grp.step(st), self.nq - 1)
+
     def interior(self, qi, kj):
         """A causal call's tile whose last key is visible to its first
         query: no score of it is masked by the diagonal. (A call that is
         not causal has no other tiles.)"""
         return (kj + 1) * self.bk - 1 <= qi * self.bq
 
+    def visible(self, qi, kj):
+        """(block_q, block_k) bool of an edge tile: the rule on absolute
+        rows and columns."""
+        qpos = qi * self.bq + jax.lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 0)
+        kpos = kj * self.bk + jax.lax.broadcasted_iota(
+            jnp.int32, (self.bq, self.bk), 1)
+        return qpos >= kpos
+
     def tile_counts(self):
-        """(interior, diagonal) live tiles of one (batch, head) row, in
-        Python integers (where a call is traced, not inside a kernel)."""
+        """(interior, edge) tiles the q-side pass walks for one (batch,
+        head) row, in Python integers (where a call is traced, not inside
+        a kernel)."""
         if not self.causal:
             return self.nq * self.nk, 0
         live = interior = 0
@@ -113,6 +171,181 @@ class _Geometry:
             live += row
             interior += sum(self.interior(qi, kj) for kj in range(row))
         return interior, live - interior
+
+    def tiles_with_a_pair(self):
+        """Tiles of one (batch, head) row in which some query sees some
+        key, counted tile by tile: what a walk cannot go below."""
+        if not self.causal:
+            return self.nq * self.nk
+        return sum(kj * self.bk <= (qi + 1) * self.bq - 1
+                   for qi in range(self.nq) for kj in range(self.nk))
+
+
+class _BlockDiffusion(_Geometry):
+    """The rule of block-diffusion training over a stream of ``2 L`` rows
+    ``[noisy copy ; clean copy]`` (``noisy(i) = i < L``, ``pos(i) = i mod
+    L``, ``blk(i) = pos(i) // block``): row i sees row j iff
+
+    - both are noisy and ``blk(j) == blk(i)`` (a noisy block sees itself,
+      both directions), or
+    - i is noisy, j clean and ``blk(j) < blk(i)`` (and the clean copies of
+      the blocks before it), or
+    - both are clean and ``blk(j) <= blk(i)`` (block-causal);
+
+    a clean row never sees a noisy one. ``L`` is a whole number of q
+    blocks and of k blocks, so a tile lies in one quadrant. A q tile's
+    live k tiles are NOT one run from 0: a noisy q tile reads the noisy
+    tiles that hold its own blocks and then the clean tiles from the
+    clean half's first up to the last block before its last row's; a clean
+    q tile reads the clean tiles up to its last row's block. A clean k
+    tile is read by two runs of q tiles (the noisy rows of later blocks,
+    the clean rows from its first block on), a noisy one by the noisy q
+    tiles of its own blocks. The step -> tile maps below walk exactly
+    those tiles; the own tiles come first, so that every row has seen a
+    key by the end of its first tile when the blocks are equal. At L =
+    8,192, blocks of 512 and ``block`` 4 a (sequence, head) walks 288
+    tiles of 1,024: 240 interior, 48 edge (16 of them the noisy half's
+    diagonal), in 17 steps a q tile and 32 a k tile."""
+
+    def __init__(self, block, block_q, block_k, nq, nk):
+        super().__init__(False, block_q, block_k, nq, nk)
+        self.block, self.edges = int(block), True
+        self.hq, self.hk = nq // 2, nk // 2          # tiles a half
+        self.length = self.hq * block_q
+        # Python integers: the grid's third axes are static
+        self.k_steps = int(max(sum(self._k_runs(qi, np)[1::2])
+                               for qi in range(nq)))
+        self.q_steps = int(max(sum(self._q_runs(kj, np)[1::2])
+                               for kj in range(nk)))
+
+    def _blocks(self, tile, half, size, xp):
+        """(the tile is in the noisy half, the blocks of its first and of
+        its last position)."""
+        noisy = tile < half
+        p0 = (tile - xp.where(noisy, 0, half)) * size
+        return noisy, p0 // self.block, (p0 + size - 1) // self.block
+
+    def _k_runs(self, qi, xp):
+        """(first tile, tiles) of the q tile's own run of noisy k tiles
+        and of its run of clean ones: integers with ``xp`` numpy, traced
+        with ``xp`` jax.numpy."""
+        b, last = self.block, self.length - 1
+        noisy, b0, b1 = self._blocks(qi, self.hq, self.bq, xp)
+        own = (b0 * b) // self.bk
+        n_own = xp.where(
+            noisy, xp.minimum(b1 * b + b - 1, last) // self.bk - own + 1, 0)
+        n_clean = xp.where(
+            noisy, (b1 * b + self.bk - 1) // self.bk,
+            xp.minimum(b1 * b + b - 1, last) // self.bk + 1)
+        return own, n_own, self.hk, n_clean
+
+    def _q_runs(self, kj, xp):
+        """(first tile, tiles) of the two runs of q tiles that read the k
+        tile: of a noisy one the noisy q tiles of its blocks and nothing;
+        of a clean one the noisy q tiles from the block after its first
+        on, and the clean q tiles from its first block on."""
+        b, last = self.block, self.length - 1
+        noisy, c0, c1 = self._blocks(kj, self.hk, self.bk, xp)
+        own = (c0 * b) // self.bq
+        first = xp.where(noisy, own, ((c0 + 1) * b) // self.bq)
+        n_first = xp.where(
+            noisy, xp.minimum(c1 * b + b - 1, last) // self.bq - own + 1,
+            xp.maximum(self.hq - first, 0))
+        return (first, n_first, self.hq + own,
+                xp.where(noisy, 0, self.hq - own))
+
+    @staticmethod
+    def _walk(runs, st, xp):
+        """(the tile of step ``st`` of two runs walked one after the
+        other, the step is live)."""
+        first, n_first, second, n_second = runs
+        tile = xp.where(st < n_first, first + st, second + st - n_first)
+        return tile, st < n_first + n_second
+
+    def k_tile(self, qi, st):
+        return self._walk(self._k_runs(qi, jnp), st, jnp)[0]
+
+    def k_live(self, qi, st, kj):
+        return self._walk(self._k_runs(qi, jnp), st, jnp)[1]
+
+    def k_index(self, qi, st):
+        runs = self._k_runs(qi, jnp)
+        return self._walk(runs, jnp.minimum(st, runs[1] + runs[3] - 1),
+                          jnp)[0]
+
+    def q_tile(self, kj, st, grp):
+        return self._walk(self._q_runs(kj, jnp), grp.step(st), jnp)[0]
+
+    def q_live(self, kj, st, grp, qi):
+        return self._walk(self._q_runs(kj, jnp), grp.step(st), jnp)[1]
+
+    def q_index(self, kj, st, grp):
+        runs = self._q_runs(kj, jnp)
+        return self._walk(
+            runs, jnp.minimum(grp.step(st), runs[1] + runs[3] - 1), jnp)[0]
+
+    def interior(self, qi, kj, xp=jnp):
+        """Every query of the tile sees every key of it (the tile is
+        live, so it is not a clean q tile on a noisy k tile)."""
+        q_noisy, b0, b1 = self._blocks(qi, self.hq, self.bq, xp)
+        k_noisy, c0, c1 = self._blocks(kj, self.hk, self.bk, xp)
+        one_block = xp.logical_and(xp.logical_and(b0 == b1, c0 == c1),
+                                   b0 == c0)
+        return xp.where(k_noisy, one_block,
+                        c1 <= b0 - xp.where(q_noisy, 1, 0))
+
+    def visible(self, qi, kj):
+        shape = (self.bq, self.bk)
+        q_noisy, k_noisy = qi < self.hq, kj < self.hk
+        row = (qi - jnp.where(q_noisy, 0, self.hq)) * self.bq \
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        col = (kj - jnp.where(k_noisy, 0, self.hk)) * self.bk \
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        # positions are not negative: the truncating quotient is the floor
+        rb = jax.lax.div(row, jnp.full_like(row, self.block))
+        cb = jax.lax.div(col, jnp.full_like(col, self.block))
+        # noisy on noisy: the row's own block; noisy on clean: the blocks
+        # before it; clean on clean: up to its own
+        # (the quadrant as 0/1 integers: no vector is selected by a scalar)
+        own = jnp.where(k_noisy, 1, 0)
+        before = jnp.where(q_noisy, 1, 0) * (1 - own)
+        return jnp.logical_and(cb <= rb - before,
+                               cb >= rb * own + (own - 1))
+
+    def tile_counts(self):
+        interior = live = 0
+        for qi in range(self.nq):
+            runs = self._k_runs(qi, np)
+            for st in range(int(runs[1] + runs[3])):
+                live += 1
+                interior += bool(self.interior(
+                    qi, self._walk(runs, st, np)[0], np))
+        return interior, live - interior
+
+    def tiles_with_a_pair(self):
+        """From the three clauses at a tile's corner blocks: noisy on
+        noisy, the blocks overlap; noisy on clean, the tile's first block
+        lies before the q tile's last; clean on clean, not after it."""
+        n = 0
+        for qi in range(self.nq):
+            q_noisy, b0, b1 = self._blocks(qi, self.hq, self.bq, np)
+            for kj in range(self.nk):
+                k_noisy, c0, c1 = self._blocks(kj, self.hk, self.bk, np)
+                if q_noisy and k_noisy:
+                    n += bool(c0 <= b1 and b0 <= c1)
+                elif q_noisy:
+                    n += bool(c0 < b1)
+                elif not k_noisy:
+                    n += bool(c0 <= b1)
+        return n
+
+
+def _geometry(rule, block_q, block_k, nq, nk):
+    """The geometry of a call's rule: ``False`` (none), ``True`` (causal)
+    or ``("block_diffusion", block)``."""
+    if isinstance(rule, tuple):
+        return _BlockDiffusion(rule[1], block_q, block_k, nq, nk)
+    return _Geometry(rule, block_q, block_k, nq, nk)
 
 
 class _Group:
@@ -125,7 +358,7 @@ class _Group:
     as query heads lowers to the text it had before there were groups."""
 
     def __init__(self, group, nq):
-        self.n, self.nq = group, nq
+        self.n, self.nq = group, nq      # nq: the k-side pass' steps a head
 
     def kv_row(self, q_row):
         return q_row if self.n == 1 else q_row // self.n
@@ -140,31 +373,29 @@ class _Group:
 def _masked_scores(q, k, kmask, qi, kj, *, geom, scale, diagonal):
     """Scaled masked scores for one (q block, k block) tile — the ONE
     copy of the masking semantics, shared by the forward kernel and the
-    backward recomputation. Operands keep their dtype (bf16 operands run
-    the MXU at its bf16 rate); the product accumulates in float32. Where
-    nothing is to mask nothing is done: no select without a key mask
-    (``kmask`` None), no positions and no select on a tile the diagonal
-    does not cross (``diagonal`` False)."""
+    backward recomputation, whatever the call's rule. Operands keep their
+    dtype (bf16 operands run the MXU at its bf16 rate); the product
+    accumulates in float32. Where nothing is to mask nothing is done: no
+    select without a key mask (``kmask`` None), no positions and no
+    select on a tile that is no edge of the rule (``diagonal`` False: the
+    causal diagonal does not cross it, every block of it is visible)."""
     s = scale * jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     if kmask is not None:
         s = jnp.where(kmask[None, :] > 0, s, NEG)
     if diagonal:
-        qpos = qi * geom.bq + jax.lax.broadcasted_iota(
-            jnp.int32, (geom.bq, geom.bk), 0)
-        kpos = kj * geom.bk + jax.lax.broadcasted_iota(
-            jnp.int32, (geom.bq, geom.bk), 1)
-        s = jnp.where(qpos >= kpos, s, NEG)
+        s = jnp.where(geom.visible(qi, kj), s, NEG)
     return s
 
 
 def _on_live_tile(live, qi, kj, *, geom, masked, body):
     """Run ``body(diagonal)`` on a live tile, traced once for each kind of
-    tile the call has. Without a key mask a causal call has two: a tile
-    the diagonal crosses is masked by position, an interior one not at
-    all. A key mask is applied on every tile, as the diagonal is then."""
-    if masked or not geom.causal:
-        pl.when(live)(lambda: body(geom.causal))
+    tile the call has. Without a key mask a call under a rule has two: an
+    edge tile (the causal diagonal crosses it) is masked by position, an
+    interior one not at all. A key mask is applied on every tile, as the
+    rule is then."""
+    if masked or not geom.edges:
+        pl.when(live)(lambda: body(geom.edges))
         return
     interior = geom.interior(qi, kj)
     pl.when(jnp.logical_and(live, interior))(lambda: body(False))
@@ -207,9 +438,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, *rest, geom, scale: float):
     (block_q,) row of ``lse`` once a q block, in ``_finish``."""
     *mask_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = pl.program_id(2)
+    kj = geom.k_tile(qi, step)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -236,12 +468,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, *rest, geom, scale: float):
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    # tiles wholly above the diagonal are neither fetched (the index map
-    # repeats the last live tile) nor computed
-    _on_live_tile(kj <= geom.k_hi(qi), qi, kj, geom=geom,
+    # empty tiles (wholly above the causal diagonal) are neither fetched
+    # (the index map repeats the last live tile) nor computed
+    _on_live_tile(geom.k_live(qi, step, kj), qi, kj, geom=geom,
                   masked=bool(mask_ref), body=_step)
 
-    @pl.when(kj == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         m = m_scr[...]
         l = l_scr[...]
@@ -282,20 +514,20 @@ def _head_rows(x):
     return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
 
-def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
+def _flash_call(q, k, v, mask, rule, block_q: int, block_k: int,
                 interpret: bool):
     b, tq, h, d = q.shape
     tk, dv = k.shape[1], v.shape[3]
     scale = 1.0 / float(d) ** 0.5
-    geom = _Geometry(causal, block_q, block_k, tq // block_q, tk // block_k)
-    grp = _Group(h // k.shape[2], geom.nq)
-    kidx = lambda qi, kj: jnp.minimum(kj, geom.k_hi(qi))
+    geom = _geometry(rule, block_q, block_k, tq // block_q, tk // block_k)
+    grp = _Group(h // k.shape[2], geom.q_steps)
+    kidx = geom.k_index
     masks, mask_specs = _mask_operand(
         mask, block_k, lambda bh, qi, kj: (bh // h, 0, kidx(qi, kj)))
 
     out, lse = pl.pallas_call(
         functools.partial(_attn_kernel, geom=geom, scale=scale),
-        grid=(b * h, geom.nq, geom.nk),
+        grid=(b * h, geom.nq, geom.k_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d),
@@ -327,12 +559,12 @@ def _flash_call(q, k, v, mask, causal: bool, block_q: int, block_k: int,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, mask, causal, block_q, block_k, interpret):
-    return _flash_call(q, k, v, mask, causal, block_q, block_k, interpret)
+def _flash(q, k, v, mask, rule, block_q, block_k, interpret):
+    return _flash_call(q, k, v, mask, rule, block_q, block_k, interpret)
 
 
-def _flash_fwd(q, k, v, mask, causal, block_q, block_k, interpret):
-    out, lse = _flash_call(q, k, v, mask, causal, block_q, block_k,
+def _flash_fwd(q, k, v, mask, rule, block_q, block_k, interpret):
+    out, lse = _flash_call(q, k, v, mask, rule, block_q, block_k,
                            interpret)
     # the two residuals that are the kernel's own results: a block
     # rematerialised under the containers' gradient checkpointing keeps
@@ -365,9 +597,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                    geom, scale):
     *mask_ref, dq_ref, dq_scr = rest      # the key mask's block, if any
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = pl.program_id(2)
+    kj = geom.k_tile(qi, step)
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
@@ -380,10 +613,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _on_live_tile(kj <= geom.k_hi(qi), qi, kj, geom=geom,
+    _on_live_tile(geom.k_live(qi, step, kj), qi, kj, geom=geom,
                   masked=bool(mask_ref), body=_step)
 
-    @pl.when(kj == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -396,7 +629,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     *mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
     kj = pl.program_id(1)
     step = pl.program_id(2)
-    qi = geom.q_lo(kj) + grp.step(step)
+    qi = geom.q_tile(kj, step, grp)
 
     @pl.when(step == 0)
     def _init():
@@ -415,7 +648,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _on_live_tile(qi <= geom.nq - 1, qi, kj, geom=geom,
+    _on_live_tile(geom.q_live(kj, step, grp, qi), qi, kj, geom=geom,
                   masked=bool(mask_ref), body=_step)
 
     @pl.when(step == pl.num_programs(2) - 1)
@@ -424,18 +657,19 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(rule, block_q, block_k, interpret, res, g):
     """True flash backward: two Pallas passes (dq over k blocks; dk/dv
     over q blocks) recomputing p from the saved LSE — the score matrix
     never materializes, matching the forward's memory shape, and a tile
-    above the causal diagonal is skipped as in the forward."""
+    the rule leaves empty (above the causal diagonal) is skipped as in
+    the forward."""
     q, k, v, mask, out, lse = res
     g, g_lse = g                  # cotangents of (out, lse)
     b, tq, h, d = q.shape
     tk, hk, dv = k.shape[1], k.shape[2], v.shape[3]
     scale = 1.0 / float(d) ** 0.5
-    geom = _Geometry(causal, block_q, block_k, tq // block_q, tk // block_k)
-    grp = _Group(h // hk, geom.nq)
+    geom = _geometry(rule, block_q, block_k, tq // block_q, tk // block_k)
+    grp = _Group(h // hk, geom.q_steps)
     # delta_i = rowsum(dO * O) (the softmax-jacobian diagonal term).
     # The LSE output is differentiable too: d lse_i / d s_ij = p_ij, so
     # its cotangent folds in as ds = p * (dp - (delta - g_lse)) — no
@@ -455,7 +689,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     lse3 = lse.reshape(b * h, 1, tq)
 
     common = dict(geom=geom, scale=scale)
-    kidx = lambda qi, kj: jnp.minimum(kj, geom.k_hi(qi))
+    kidx = geom.k_index
     q_spec = lambda ix: pl.BlockSpec((1, block_q, d), ix)
     do_spec = lambda ix: pl.BlockSpec((1, block_q, dv), ix)
     row_spec = lambda ix: pl.BlockSpec((1, 1, block_q), ix)
@@ -469,7 +703,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
         mask, block_k, lambda bh, qi, kj: (bh // h, 0, kidx(qi, kj)))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
-        grid=(b * h, geom.nq, geom.nk),
+        grid=(b * h, geom.nq, geom.k_steps),
         in_specs=[
             q_spec(at_q), k_spec(at_k), v_spec(at_k), do_spec(at_q),
             row_spec(at_row), row_spec(at_row), *mask_specs,
@@ -482,8 +716,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
     )(qh, kh, vh, gh, lse3, dh, *masks)
 
     # rows of k; the third axis: (query head of the group, step)
-    qidx = lambda kj, st: jnp.minimum(geom.q_lo(kj) + grp.step(st),
-                                      geom.nq - 1)
+    qidx = lambda kj, st: geom.q_index(kj, st, grp)
     at_q = lambda bh, kj, st: (grp.q_row(bh, st), qidx(kj, st), 0)
     at_row = lambda bh, kj, st: (grp.q_row(bh, st), 0, qidx(kj, st))
     at_k = lambda bh, kj, st: (bh, kj, 0)
@@ -491,7 +724,7 @@ def _flash_bwd(causal, block_q, block_k, interpret, res, g):
         mask, block_k, lambda bh, kj, st: (bh // hk, 0, kj))
     dk, dv_ = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, grp=grp, **common),
-        grid=(b * hk, geom.nk, grp.n * geom.nq),
+        grid=(b * hk, geom.nk, grp.n * geom.q_steps),
         in_specs=[
             q_spec(at_q), k_spec(at_k), v_spec(at_k), do_spec(at_q),
             row_spec(at_row), row_spec(at_row), *mask_specs,
@@ -516,12 +749,20 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _publish_tile_shares(geom, masked):
-    """The gauge ``flash_tile_share{kind}``: shares of the call's live
-    tiles by the body the kernels run on them, set where the call is
-    traced (the last call traced is the one that shows)."""
+    """The gauges ``flash_tile_share{kind}``, shares of the tiles the
+    call's kernels walk by the body they run on them, and
+    ``flash_tiles_walked_over_live``, those tiles over the tiles in which
+    the rule lets some query see some key; set where the call is traced
+    (the last call traced is the one that shows)."""
     from deeplearning4j_tpu.monitor import metrics
     interior, diagonal = geom.tile_counts()
     live = interior + diagonal
+    metrics.gauge(
+        "flash_tiles_walked_over_live",
+        "Tiles the flash attention call traced last fetches and computes "
+        "over the (q block, k block) tiles that hold a visible pair under "
+        "its rule: 1.0 when no empty tile is walked").set(
+            live / geom.tiles_with_a_pair())
     counts = {"interior": 0 if masked else interior,
               "diagonal": 0 if masked else diagonal,
               "key_masked": live if masked else 0}
@@ -529,14 +770,16 @@ def _publish_tile_shares(geom, masked):
         "flash_tile_share",
         "Shares (%) of the live (q block, k block) tiles of the flash "
         "attention call traced last, by the body its kernels run on them: "
-        "interior (nothing masked), diagonal (masked by position), "
-        "key_masked (the call has a key mask: applied on every tile)",
+        "interior (nothing masked), diagonal (an edge tile of the rule: "
+        "masked by position), key_masked (the call has a key mask: applied "
+        "on every tile)",
         labels=("kind",))
     for kind, n in counts.items():
         gauge.set(100.0 * n / live, kind=kind)
 
 
 def flash_attention(q, k, v, *, mask=None, causal: bool = False,
+                    block_diffusion: Optional[int] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None,
@@ -551,6 +794,17 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
     its count: query head ``h`` reads key/value head ``h // (H // H_kv)``
     and neither is repeated in HBM. With ``causal``, key tiles wholly above the diagonal are neither
     fetched nor computed, forward and backward.
+
+    ``block_diffusion`` (a block length) is the other visibility rule,
+    that of block-diffusion training (`_BlockDiffusion`): q, k and v are
+    the stream ``[noisy copy ; clean copy]`` of ``2 L`` rows, a noisy
+    block sees itself both ways and the clean copies of the blocks before
+    it, the clean copy is block-causal and never sees a noisy row. Only
+    the tiles that hold a visible pair are fetched and computed, only the
+    edge tiles among them masked. ``L`` has to be a whole number of
+    blocks of the kernels (``block_q``, ``block_k``: each at most ``L``)
+    and of ``block_diffusion``; the rule takes no key mask and is not
+    ``causal`` besides.
 
     return_lse=True additionally returns the per-row log-sum-exp
     ((B, T, H), the softmax normalizer in log space) so partial results
@@ -569,6 +823,21 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
                          "q's, v k's length and heads")
     if interpret is None:
         interpret = not is_tpu_backend()
+    rule = bool(causal)
+    if block_diffusion is not None:
+        half = tq // 2
+        block_q, block_k = min(block_q or 128, half), min(block_k or 128,
+                                                          half)
+        if causal or mask is not None or tq != tk or tq % 2 or \
+                block_diffusion < 1 or half % block_diffusion or \
+                half % block_q or half % block_k:
+            raise ValueError(
+                f"block_diffusion {block_diffusion} over q {q.shape}, k "
+                f"{k.shape} in blocks of {block_q} x {block_k}: the rule "
+                "takes a stream of twice a whole number of its blocks and "
+                "of the kernels' blocks as q and as k, no key mask and no "
+                "causal rule besides")
+        rule = ("block_diffusion", int(block_diffusion))
     block_q = min(block_q or 128, max(tq, 1))
     block_k = min(block_k or 128, max(tk, 1))
     pq = (-tq) % block_q
@@ -582,9 +851,9 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
         if mask is not None:
             mask = jnp.pad(mask, ((0, 0), (0, pk)))
     _publish_tile_shares(
-        _Geometry(causal, block_q, block_k, q.shape[1] // block_q,
+        _geometry(rule, block_q, block_k, q.shape[1] // block_q,
                   k.shape[1] // block_k), masked=mask is not None)
-    out, lse = _flash(q, k, v, mask, causal, block_q, block_k, interpret)
+    out, lse = _flash(q, k, v, mask, rule, block_q, block_k, interpret)
     if not return_lse:
         return out[:, :tq]
     b, _, h, d = q.shape

@@ -47,7 +47,7 @@ _SEQ_CROSSING = {"LSTM", "GravesLSTM", "SimpleRnn", "GRU", "Bidirectional",
                  # per-shard last-step / flip / length-broadcast are all
                  # silently wrong on a local sequence chunk
                  "LastTimeStepVertex", "ReverseTimeSeriesVertex",
-                 "DuplicateToTimeSeriesVertex"}
+                 "DuplicateToTimeSeriesVertex", "TimeSliceVertex"}
 
 
 class ContextParallelTrainer:

@@ -68,10 +68,11 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
 
 
 @pytest.mark.parametrize("n_experts,held,divisors,names", [
-    (256, (0, 8), (16, 1), ("1/16", "1/1")),     # the Kimi cell's layer
-    (64, (0, 8), (4, 1), ("1/4", "1/1")),        # the GLM cell's
-    (64, (8, 16), (4, 1), ("1/4", "1/1")),       # whichever eight
-    (32, (0, 4), (4, 1), ("1/4", "1/1")),        # the GLM rehearsal's
+    (256, (0, 8), (16, 8, 4, 2, 1),              # the Kimi cell's layer
+     ("1/16", "1/8", "1/4", "1/2", "1/1")),
+    (64, (0, 8), (4, 2, 1), ("1/4", "1/2", "1/1")),     # the GLM cell's
+    (64, (8, 16), (4, 2, 1), ("1/4", "1/2", "1/1")),    # whichever eight
+    (32, (0, 4), (4, 2, 1), ("1/4", "1/2", "1/1")),     # GLM's rehearsal
     (16, (2, 6), (2, 1), ("1/2", "1/1")),        # these tests' model
     (64, (0, 32), (1,), ("1/1",)),               # half: twice that is all
     (64, (0, 40), (1,), ("1/1",)),
@@ -80,9 +81,9 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
 def test_the_small_tier_follows_from_the_share_held(n_experts, held,
                                                     divisors, names):
     """Twice the balanced load of the share: ``n_experts // (2 * held)`` as
-    the divisor of a dispatch's pairs; a layer whose doubled share is the
-    whole, or that holds every expert, has the one tier, no switch and no
-    tier counter."""
+    the divisor of a dispatch's pairs, and every halving of it down to
+    the whole; a layer whose doubled share is the whole, or that holds
+    every expert, has the one tier, no switch and no tier counter."""
     ffn = MoEFeedForward(n_out=16, n_experts=n_experts, top_k=4, hidden=8,
                          activation="swish", gated=True, has_bias=False,
                          experts_held=held, router="sigmoid")
@@ -91,7 +92,7 @@ def test_the_small_tier_follows_from_the_share_held(n_experts, held,
     assert ffn._tiers(4096) == tuple(4096 // d for d in divisors)
     _, state = ffn.init(jax.random.PRNGKey(0), InputType.recurrent(16, 8))
     if len(divisors) > 1:
-        assert state["tier_hits"].shape == (2,)
+        assert state["tier_hits"].shape == (len(divisors),)
     else:
         assert "tier_hits" not in state and "rows_walked_total" not in state
 
@@ -125,9 +126,9 @@ def test_eight_of_sixty_four_walk_a_quarter_and_drop_no_pair(live):
     assert int(new["tokens_routed"].sum()) == 64 * 4
     if live == "all":
         assert held == 256
-        np.testing.assert_array_equal(new["tier_hits"], [0, 1])
+        np.testing.assert_array_equal(new["tier_hits"], [0, 0, 1])
         assert int(new["rows_walked_total"]) == 256
     else:
         assert 0 < held <= 64
-        np.testing.assert_array_equal(new["tier_hits"], [1, 0])
+        np.testing.assert_array_equal(new["tier_hits"], [1, 0, 0])
         assert int(new["rows_walked_total"]) == 64

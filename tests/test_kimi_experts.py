@@ -159,13 +159,15 @@ def test_undefined_rows_of_a_grouped_product_reach_no_sum(
     loss = lambda p, x: jnp.sum(ffn.apply(p, state, x)[0] ** 2)
     want = jax.value_and_grad(loss, (0, 1))(p, x)
     counted = ffn.apply(p, state, x)[1]
-    np.testing.assert_array_equal(counted["tier_hits"], np.arange(2) == tier)
+    np.testing.assert_array_equal(
+        counted["tier_hits"], np.arange(len(ffn._tier_divisors())) == tier)
     assert 0 < counted["tokens_routed"][held[0]:held[1]].sum()
     # the tiers are jitted with the product as an argument: the trace made
     # above with the real one is not the poisoned one's
     monkeypatch.setattr(attention, "_grouped_matmul", poisoned)
     got = jax.value_and_grad(loss, (0, 1))(p, x)
-    assert ffn._tiers(120) == ((60, 120) if tier else (7, 120))
+    assert ffn._tiers(120) == ((60, 120) if tier
+                               else (7, 15, 30, 60, 120))
     assert rows in poisoned_rows
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
@@ -174,7 +176,8 @@ def test_undefined_rows_of_a_grouped_product_reach_no_sum(
 
 
 # ------------------------------------------------------------------ the tiers
-_TIER_N, _TIER_K = 32, 4          # 128 pairs a dispatch: tiers of 8 and 128
+_TIER_N, _TIER_K = 32, 4          # 128 pairs a dispatch: tiers 8, 16 .. 128
+_TIERS = (8, 16, 32, 64, 128)
 
 
 @functools.lru_cache(maxsize=None)
@@ -245,14 +248,14 @@ def test_every_tier_gives_the_full_tiers_result_and_gradients(router, gated,
     gives (the parent's dispatch): the result and the gradients of the
     inputs, the expert weights and the router."""
     ffn, p, tiered, whole = _tiered_and_whole(router, gated)
-    assert ffn._tiers(_TIER_N * _TIER_K) == (8, 128)
+    assert ffn._tiers(_TIER_N * _TIER_K) == _TIERS
     x = jax.random.normal(jax.random.PRNGKey(live), (1, _TIER_N, 16))
     idx = _routing_with(live, seed=live)
     out, counts, grads = tiered(p, x, idx)
     want, base, want_grads = whole(p, x, idx)
-    tier = 0 if live <= 8 else 1
-    np.testing.assert_array_equal(counts["tier_hits"], np.arange(2) == tier)
-    assert int(counts["rows_walked"]) == (8, 128)[tier]
+    tier = sum(live > rows for rows in _TIERS[:-1])
+    np.testing.assert_array_equal(counts["tier_hits"], np.arange(5) == tier)
+    assert int(counts["rows_walked"]) == _TIERS[tier]
     # one tier counts neither tier nor rows; both count, alike, what was
     # routed and the tokens with a pair held here
     assert set(base) == {"tokens_routed", "tokens_with_held_pair"}

@@ -235,6 +235,31 @@ class TestFlashKernelCompiles:
             assert kernel in hlo
         assert "tpu_custom_call" in hlo
 
+    @pytest.mark.parametrize("backward", [True, False])
+    def test_the_block_diffusion_rule_at_the_sdar_cells_widths(
+            self, v5e, backward):
+        # the rule of block-diffusion training at the SDAR cell's widths:
+        # 32 query heads on 4 key/value heads, 128 wide, 2 sequences whose
+        # stream [noisy ; clean] is 16,384 rows, block 512, diffusion
+        # blocks of 4 (the in-tile predicate divides positions by it on
+        # the vector unit); the index maps walk two runs of tiles
+        from deeplearning4j_tpu.ops.flash_attention import flash_attention
+
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, block_diffusion=4, block_q=512, block_k=512,
+                interpret=False).astype(jnp.float32) ** 2)
+
+        q = ((2, 16384, 32, 128), jnp.bfloat16)
+        kv = ((2, 16384, 4, 128), jnp.bfloat16)
+        hlo = _compile_v5e(
+            jax.grad(loss, argnums=(0, 1, 2)) if backward else loss,
+            self._one(v5e), q, kv, kv)
+        for kernel in (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+                       if backward else ("flash_fwd",)):
+            assert kernel in hlo
+        assert "tpu_custom_call" in hlo
+
     def test_checkpointed_grouped_query_attention_runs_the_forward_once(
             self, v5e, monkeypatch):
         # `MultiHeadAttention` as the LFM2 family holds it (32 on 8, q/k

@@ -401,9 +401,10 @@ def phase_kernel(sz, rehearse=False):
                    if k in hlo]
         say(phase, f"compiled train step: {calls} tpu_custom_call(s), "
                    f"kernels {kernels}")
-        _check(calls == 3 * lm["n_layers"] and len(kernels) == 3,
-               "the compiled LM step does not hold forward, dq and dk/dv "
-               "Pallas calls for every layer")
+        _check(calls == 2 * lm["n_layers"]
+               and kernels == ["flash_fwd", "flash_bwd_dq"],
+               "the compiled LM step does not hold a forward and ONE fused "
+               "backward Pallas call (named flash_bwd_dq) for every layer")
     return {"device": info, "ran": True,
             "compile_s": round(first - again, 1),
             "run_s": round(parity_s + again, 2)}

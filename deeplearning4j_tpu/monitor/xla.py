@@ -740,7 +740,9 @@ def kernel_calls(op_scopes: Dict[str, str]) -> Dict[str, int]:
     program's instruction names (the keys of `op_scopes`); a kernel the
     program does not call is left out. What a rematerialised block runs a
     second time shows here: two `flash_fwd` a `flash_bwd_dq` before the
-    kernel's results were kept (`ops.REMAT_KEEP`), one since."""
+    kernel's results were kept (`ops.REMAT_KEEP`), one since. (The fused
+    flash backward keeps the name `flash_bwd_dq`; a `flash_bwd_dkv`
+    beside it says the call took the pair of passes.)"""
     calls: Dict[str, int] = {}
     for name in op_scopes:
         kernel = name.split(".", 1)[0]

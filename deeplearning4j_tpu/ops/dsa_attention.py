@@ -69,7 +69,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops import REMAT_KEEP
 from deeplearning4j_tpu.ops.flash_attention import (
-    NEG, _Geometry, _STAT_LANES, _lanes,
+    NEG, _Geometry, _STAT_LANES, _VMEM_BYTES, _lanes,
 )
 from deeplearning4j_tpu.util.platform import is_tpu_backend
 
@@ -86,8 +86,6 @@ _Q_BLOCK_BYTES = 40 * 2 ** 20
 _SELECT_ROWS = 64
 #: query rows whose indexer scores are in HBM at once (T keys each, int32)
 _INDEX_ROWS = 2048
-#: the kernels' VMEM limit (the v5e has 128 MiB)
-_VMEM_BYTES = 100 * 2 ** 20
 
 
 # ------------------------------------------------------- the tile functions

@@ -18,7 +18,7 @@ Semantics match dot_product_attention exactly (tested):
   may have FEWER heads than q (grouped-query attention: query head ``h``
   reads key/value head ``h // group``, ``group = H // H_kv``). The K/V
   block index maps do the grouping, so k and v stay at their own head
-  count in HBM; in the dk/dv pass one key head's tile accumulates over
+  count in HBM; in the backward one key head's dk and dv accumulate over
   the ``group`` query heads that read it, inside the kernel (64-wide
   heads, 32 on 8, compile for the v5e at blocks of 512 too);
 - optional causal masking; key blocks wholly above the diagonal are
@@ -27,15 +27,15 @@ Semantics match dot_product_attention exactly (tested):
   stream ``[noisy copy ; clean copy]`` (``block_diffusion``): ONE
   description of a rule (`_Geometry`: which tiles are empty, interior or
   edge, the step -> tile maps of the q-side and k-side passes, the in-tile
-  predicate) serves both, and the three kernels and `_masked_scores` are
-  the same; a q tile's live k tiles are then two runs, not one from 0, and
+  predicate) serves both, and the kernels and `_masked_scores` are the
+  same; a q tile's live k tiles are then two runs, not one from 0, and
   the maps are part of the rule (`_BlockDiffusion`);
 - optional (B, Tk) 0/1 key-validity mask, fully-masked query rows emit 0;
 - a tile pays for the masking it needs and no more, chosen from what the
   code can observe (whether a key mask was given, ``causal``, the tile's
   place against the diagonal) and from nothing a caller sets. Without a
-  key mask the three kernels take NO mask operand (none is built of
-  ones) and lower two bodies: on a tile the diagonal crosses the scores
+  key mask the kernels take NO mask operand (none is built of ones) and
+  lower two bodies: on a tile the diagonal crosses the scores
   are masked by position, on an interior tile (its last key visible to
   its first query: 120 of the 136 live tiles at 8,192 positions in
   blocks of 512) nothing is compared or selected, and nowhere are
@@ -48,14 +48,26 @@ Semantics match dot_product_attention exactly (tested):
 - the forward's running max and denominator stay in the layout the row
   reductions give them (``_STAT_LANES``) from tile to tile and are turned
   into the (block_q,) row of the log-sum-exp once a q block;
-- backward pass: true flash backward — two Pallas passes (dq over key
-  blocks; dk/dv over query blocks) recomputing the probabilities from
-  the saved per-row log-sum-exp, so the score matrix never materializes
-  in either direction; cross-attention shapes (tq != tk) included. The
-  two residuals that are the forward kernel's own results (output and
-  log-sum-exp) carry the containers' keep-name (`ops.REMAT_KEEP`): a
-  block rematerialised under gradient checkpointing holds them and does
-  not run the forward kernel a second time.
+- backward pass: true flash backward — the probabilities are recomputed
+  from the saved per-row log-sum-exp, so the score matrix never
+  materializes in either direction; cross-attention shapes (tq != tk)
+  included. ONE Pallas kernel (`_bwd_kernel`, named ``flash_bwd_dq``: the
+  forward's walk, k innermost) makes a tile's scores, probabilities,
+  ``dp`` and ``ds`` a single time and takes dv, dq and dk from them: five
+  products a tile. dq of a q block stays in VMEM over its row of tiles;
+  dk and dv, which gather over the q blocks, are float32 sums of the
+  WHOLE key head in VMEM (16 MiB at 16,384 keys of 128 + 128), cast and
+  written once a key head, so the backward holds no buffer in HBM but its
+  three results, and every sum is made in the order of the pair below,
+  bit for bit. A call whose sums do not fit (`_RESIDENT_SUM_BYTES`,
+  reckoned from its shapes) takes two passes instead, each of which makes
+  the tile again (``flash_bwd_dq`` over key blocks; ``flash_bwd_dkv`` over
+  query blocks): seven products a tile. The gauge ``flash_bwd_kernels``
+  says which a traced call takes. The two residuals that are the forward
+  kernel's own results (output and log-sum-exp) carry the containers'
+  keep-name (`ops.REMAT_KEEP`): a block rematerialised under gradient
+  checkpointing holds them and does not run the forward kernel a second
+  time.
 
 Off-TPU the kernel runs under `interpret=True` (numerically identical,
 slow); on a TPU it compiles or the call raises.
@@ -85,13 +97,13 @@ class _Geometry:
     an empty tile is never fetched) and inside a kernel (so that it is
     never computed). ONE description serves every rule; a rule says
 
-    - for the q-side passes (forward, dq), which k tile a q tile's step
-      ``st`` of the third grid axis reads (`k_tile`), whether that step is
-      live (`k_live`) and what the index map fetches (`k_index`: a dead
-      step repeats the last live tile, no new DMA); ``k_steps`` is the
-      axis' length;
-    - the same for the k-side pass (dk/dv): `q_tile`, `q_live`, `q_index`,
-      ``q_steps``;
+    - for the q-side passes (forward, the fused backward, the pair's dq),
+      which k tile a q tile's step ``st`` of the innermost grid axis reads
+      (`k_tile`), whether that step is live (`k_live`) and what the index
+      map fetches (`k_index`: a dead step repeats the last live tile, no
+      new DMA); ``k_steps`` is the axis' length;
+    - the same for the k-side pass (the pair's dk/dv): `q_tile`, `q_live`,
+      `q_index`, ``q_steps``;
     - whether a live tile is interior (every score visible: nothing is
       compared or selected) or an edge (`interior`), ``edges`` saying
       whether the rule has edge tiles at all, and the predicate on
@@ -352,16 +364,21 @@ class _Group:
     """Grouped key/value heads as integer arithmetic on grid indices:
     ``group`` query heads read one key/value head. Rows of q are
     ``b * H + h``, rows of k and v ``b * H_kv + h // group``, which is
-    ``row // group``. In the dk/dv pass the grid's rows are those of k and
-    its third axis runs over (query head of the group, step); ``group``
-    1 leaves every index as it was, so that a call with as many key heads
-    as query heads lowers to the text it had before there were groups."""
+    ``row // group``. In the pair's dk/dv pass the grid's rows are those of
+    k and its third axis runs over (query head of the group, step); in
+    the fused backward the rows are those of k too and the query head of
+    the group is a grid axis of its own (`head_row`). ``group`` 1 leaves every
+    index as it was, so that a call with as many key heads as query heads
+    lowers to the text it had before there were groups."""
 
     def __init__(self, group, nq):
         self.n, self.nq = group, nq      # nq: the k-side pass' steps a head
 
     def kv_row(self, q_row):
         return q_row if self.n == 1 else q_row // self.n
+
+    def head_row(self, kv_row, head):
+        return kv_row if self.n == 1 else kv_row * self.n + head
 
     def q_row(self, kv_row, st):
         return kv_row if self.n == 1 else kv_row * self.n + st // self.nq
@@ -593,8 +610,74 @@ def _bwd_ds(p, do, v, delta_row):
     return p * (dp - delta_row[:, None])
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                geom, scale):
+    """The backward in ONE walk. Grid (B*H_kv, group, q_blocks, k_steps), k
+    innermost (the forward's walk, the query heads of a group an outer
+    axis): a tile's scores, probabilities, ``dp`` and ``ds`` are made once
+    and feed dv, dq and dk. dq of the q block stays in its scratch over the
+    row of tiles. dk and dv gather over the q blocks and the group's heads,
+    the OUTER axes: the float32 sums of the WHOLE key head are VMEM scratch
+    ``(k_blocks, block_k, d)`` and ``(k_blocks, block_k, dv)``, zeroed at
+    the key head's first step and cast once into the output blocks, which
+    the key head alone indexes, at its last. A k block's contributions
+    arrive in the order (query head of the group, q block ascending), the
+    k-major pass' order: the three gradients are that pair's bit for bit."""
+    *mask_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr = rest
+    head, qi, step = (pl.program_id(n) for n in (1, 2, 3))
+    heads, steps = pl.num_programs(1), pl.num_programs(3)
+    kj = geom.k_tile(qi, step)
+    q_block = head * geom.nq + qi           # of the key head's walk
+
+    def over_k_blocks(fn):
+        jax.lax.fori_loop(0, geom.nk, lambda j, _: fn(j), None)
+
+    @pl.when(step == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+        @pl.when(q_block == 0)
+        def _():
+            def zero(j):
+                dk_scr[j] = jnp.zeros(dk_scr.shape[1:], dk_scr.dtype)
+                dv_scr[j] = jnp.zeros(dv_scr.shape[1:], dv_scr.dtype)
+
+            over_k_blocks(zero)
+
+    def _step(diagonal):
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        p = _bwd_scores(q, k, _key_mask(mask_ref), lse_ref[0, 0], qi, kj,
+                        geom=geom, scale=scale, diagonal=diagonal)
+        dv_scr[kj] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = _bwd_ds(p, do, v_ref[0], delta_ref[0, 0]).astype(k.dtype)
+        dq_scr[...] += scale * jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_scr[kj] += scale * jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _on_live_tile(geom.k_live(qi, step, kj), qi, kj, geom=geom,
+                  masked=bool(mask_ref), body=_step)
+
+    @pl.when(step == steps - 1)
+    def _finish():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+        @pl.when(q_block == heads * geom.nq - 1)
+        def _():
+            def cast(j):
+                dk_ref[0, j] = dk_scr[j].astype(dk_ref.dtype)
+                dv_ref[0, j] = dv_scr[j].astype(dv_ref.dtype)
+
+            over_k_blocks(cast)
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                    geom, scale):
+    """The pair's q-side pass: `_bwd_kernel` without the key side."""
     *mask_ref, dq_ref, dq_scr = rest      # the key mask's block, if any
     qi = pl.program_id(1)
     step = pl.program_id(2)
@@ -623,9 +706,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     geom, grp, scale):
-    """Grid (B*H_kv, k_blocks, group * q_steps): one key head's tile
-    stays in the scratch while the third axis runs over the query heads
-    of its group and, for each, over the live q blocks."""
+    """The pair's k-side pass. Grid (B*H_kv, k_blocks, group * q_steps):
+    one key head's tile stays in the scratch while the third axis runs over
+    the query heads of its group and, for each, over the live q blocks."""
     *mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
     kj = pl.program_id(1)
     step = pl.program_id(2)
@@ -657,12 +740,39 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+#: the kernels' VMEM limit where one asks for more than the compiler's
+#: default scope (the v5e has 128 MiB)
+_VMEM_BYTES = 100 * 2 ** 20
+#: the fused backward holds the float32 sums of ONE key head's dk and dv
+#: and their double-buffered output blocks in VMEM: ``T_k (d + dv) (4 + 2
+#: itemsize)`` bytes, the widths in whole lanes. A call takes it where
+#: that is at most this, which leaves 36 MiB of `_VMEM_BYTES` to the
+#: tiles (at blocks of 512 and 256 wide the compiler counts 5 MiB:
+#: double-buffered operands, the (512, 512) float32 score tile and its
+#: like); past it a call keeps the pair of kernels, whose sums are a
+#: block long. In bfloat16 the budget admits 32,768 keys at 128 + 128
+#: wide (SDAR's 16,384 take 32 MiB; LFM2's 8,192 at 64 + 64, a lane tile
+#: each, 16), 21,845 at 192 + 128 (Kimi's 8,192: 24 MiB), 16,384 at 256 +
+#: 256 (GLM's 8,192: 32 MiB)
+_RESIDENT_SUM_BYTES = 64 * 2 ** 20
+
+
+def _backward_is_fused(tk, d, dv, dtype):
+    """Whether a call's backward is the one fused kernel: its key-side
+    sums fit `_RESIDENT_SUM_BYTES`. From the call's shapes alone."""
+    lanes = sum(-(-width // _STAT_LANES) * _STAT_LANES for width in (d, dv))
+    return tk * lanes * (4 + 2 * jnp.dtype(dtype).itemsize) \
+        <= _RESIDENT_SUM_BYTES
+
+
 def _flash_bwd(rule, block_q, block_k, interpret, res, g):
-    """True flash backward: two Pallas passes (dq over k blocks; dk/dv
-    over q blocks) recomputing p from the saved LSE — the score matrix
-    never materializes, matching the forward's memory shape, and a tile
-    the rule leaves empty (above the causal diagonal) is skipped as in
-    the forward."""
+    """True flash backward: p is recomputed from the saved LSE, so the
+    score matrix never materializes, matching the forward's memory shape,
+    and a tile the rule leaves empty (above the causal diagonal) is
+    skipped as in the forward. ONE Pallas kernel walks the live tiles once
+    for dq, dk and dv (`_bwd_kernel`) where one key head's float32 sums
+    fit VMEM (`_backward_is_fused`); a longer call takes two passes that
+    each make the tile again (dq over k blocks; dk/dv over q blocks)."""
     q, k, v, mask, out, lse = res
     g, g_lse = g                  # cotangents of (out, lse)
     b, tq, h, d = q.shape
@@ -687,6 +797,7 @@ def _flash_bwd(rule, block_q, block_k, interpret, res, g):
     # (8, 128)-or-equal block constraint where (1, block) cannot
     dh = delta.transpose(0, 2, 1).reshape(b * h, 1, tq)
     lse3 = lse.reshape(b * h, 1, tq)
+    operands = (qh, kh, vh, gh, lse3, dh)
 
     common = dict(geom=geom, scale=scale)
     kidx = geom.k_index
@@ -695,6 +806,49 @@ def _flash_bwd(rule, block_q, block_k, interpret, res, g):
     row_spec = lambda ix: pl.BlockSpec((1, 1, block_q), ix)
     k_spec = lambda ix: pl.BlockSpec((1, block_k, d), ix)
     v_spec = lambda ix: pl.BlockSpec((1, block_k, dv), ix)
+    back = lambda a, t: a.reshape(b, -1, t,
+                                  a.shape[-1]).transpose(0, 2, 1, 3)
+
+    if _backward_is_fused(tk, d, dv, k.dtype):
+        # rows of k; the query heads of a group are the second axis
+        at_q = lambda bh, hd, qi, st: (grp.head_row(bh, hd), qi, 0)
+        at_row = lambda bh, hd, qi, st: (grp.head_row(bh, hd), 0, qi)
+        at_k = lambda bh, hd, qi, st: (bh, kidx(qi, st), 0)
+        masks, mask_specs = _mask_operand(
+            mask, block_k, lambda bh, hd, qi, st: (bh // hk, 0,
+                                                   kidx(qi, st)))
+        # a key head's whole gradient is ONE output block, by k blocks
+        head_spec = lambda width: pl.BlockSpec(
+            (1, geom.nk, block_k, width),
+            lambda bh, hd, qi, st: (bh, 0, 0, 0))
+        # the name is the q-side pass' (it is the kernel that makes dq;
+        # fused, it makes dk and dv as well): the benchmark's
+        # `attn_fwd_runs_per_bwd` counts the backward's runs by it
+        dq, dk, dv_ = pl.pallas_call(
+            functools.partial(_bwd_kernel, **common),
+            grid=(b * hk, grp.n, geom.nq, geom.k_steps),
+            in_specs=[
+                q_spec(at_q), k_spec(at_k), v_spec(at_k), do_spec(at_q),
+                row_spec(at_row), row_spec(at_row), *mask_specs,
+            ],
+            out_specs=[q_spec(at_q), head_spec(d), head_spec(dv)],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+                jax.ShapeDtypeStruct((b * hk, geom.nk, block_k, d), k.dtype),
+                jax.ShapeDtypeStruct((b * hk, geom.nk, block_k, dv),
+                                     v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((geom.nk, block_k, d), jnp.float32),
+                pltpu.VMEM((geom.nk, block_k, dv), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_BYTES),
+            interpret=interpret,
+            name="flash_bwd_dq",
+        )(*operands, *masks)
+        return back(dq, tq), back(dk, tk), back(dv_, tk), None
 
     at_q = lambda bh, qi, kj: (bh, qi, 0)
     at_row = lambda bh, qi, kj: (bh, 0, qi)
@@ -713,7 +867,7 @@ def _flash_bwd(rule, block_q, block_k, interpret, res, g):
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
-    )(qh, kh, vh, gh, lse3, dh, *masks)
+    )(*operands, *masks)
 
     # rows of k; the third axis: (query head of the group, step)
     qidx = lambda kj, st: geom.q_index(kj, st, grp)
@@ -738,11 +892,8 @@ def _flash_bwd(rule, block_q, block_k, interpret, res, g):
                         pltpu.VMEM((block_k, dv), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(qh, kh, vh, gh, lse3, dh, *masks)
-
-    back = lambda a: a.reshape(b, -1, a.shape[1],
-                               a.shape[2]).transpose(0, 2, 1, 3)
-    return back(dq), back(dk), back(dv_), None
+    )(*operands, *masks)
+    return back(dq, tq), back(dk, tk), back(dv_, tk), None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -776,6 +927,18 @@ def _publish_tile_shares(geom, masked):
         labels=("kind",))
     for kind, n in counts.items():
         gauge.set(100.0 * n / live, kind=kind)
+
+
+def _publish_backward_kernels(fused):
+    """The gauge ``flash_bwd_kernels``, set where the call is traced as
+    the tile shares are."""
+    from deeplearning4j_tpu.monitor import metrics
+    metrics.gauge(
+        "flash_bwd_kernels",
+        "Pallas kernels the backward of the flash attention call traced "
+        "last walks its tiles with: 1 (one fused kernel makes dq, dk and "
+        "dv; one key head's float32 sums fit VMEM) or 2 (the pair: a call "
+        "too long for that)").set(1 if fused else 2)
 
 
 def flash_attention(q, k, v, *, mask=None, causal: bool = False,
@@ -853,6 +1016,8 @@ def flash_attention(q, k, v, *, mask=None, causal: bool = False,
     _publish_tile_shares(
         _geometry(rule, block_q, block_k, q.shape[1] // block_q,
                   k.shape[1] // block_k), masked=mask is not None)
+    _publish_backward_kernels(
+        _backward_is_fused(k.shape[1], d, v.shape[3], k.dtype))
     out, lse = _flash(q, k, v, mask, rule, block_q, block_k, interpret)
     if not return_lse:
         return out[:, :tq]

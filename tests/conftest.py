@@ -138,3 +138,17 @@ def pytest_collection_modifyitems(config, items):
 from deeplearning4j_tpu.util.sentinel import (  # noqa: E402,F401
     pytest_runtest_protocol,
 )
+
+
+@pytest.fixture(params=["fused", "pair"])
+def flash_backward(request, monkeypatch):
+    """Both forms of the flash attention's backward, by name: "fused", the
+    ONE kernel (named ``flash_bwd_dq``) of a call whose key-side sums fit
+    VMEM, and "pair", the two passes a longer call keeps (forced here by a
+    byte budget nothing fits)."""
+    import deeplearning4j_tpu.ops  # noqa: F401  (the module is loaded)
+    if request.param == "pair":
+        monkeypatch.setattr(
+            sys.modules["deeplearning4j_tpu.ops.flash_attention"],
+            "_RESIDENT_SUM_BYTES", 0)
+    return request.param

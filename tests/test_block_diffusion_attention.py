@@ -119,9 +119,10 @@ def _dense(q, k, v, seen):
     (16, 4, 16, 16)])
 def test_the_kernels_forward_and_the_three_gradients(length, block, bq, bk,
                                                      group):
-    """flash_fwd, flash_bwd_dq and flash_bwd_dkv (interpreted) under the
-    rule against plain softmax attention under the brute-force boolean,
-    one, four and eight query heads a key head."""
+    """flash_fwd and the fused backward (interpreted; the pair of passes
+    equals it bit for bit under the rule, tests/test_flash_attention.py)
+    under the rule against plain softmax attention under the brute-force
+    boolean, one, four and eight query heads a key head."""
     q, k, v, w = _case(length, 8, 8 // group)
     seen = jnp.asarray(brute_force_visible(length, block))
     run = lambda q, k, v: flash_attention(
@@ -135,9 +136,12 @@ def test_the_kernels_forward_and_the_three_gradients(length, block, bq, bk,
         np.testing.assert_allclose(a, b, atol=1e-5)
 
 
-def test_the_kernels_take_no_mask_operand_and_no_square_mask_is_built():
-    """Under the rule the three kernels take their six operands and
-    nothing else, and the trace builds no array of 2L x 2L."""
+def test_the_kernels_take_no_mask_operand_and_no_square_mask_is_built(
+        flash_backward):
+    """Under the rule the kernels (the forward and the fused backward, or
+    the pair of passes of a call past the byte budget) take their six
+    operands and nothing else, and the trace builds no array of 2L x 2L."""
+    from test_flash_attention import _backward_kernels
     q, k, v, w = _case(64, 4, 1)
     jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(flash_attention(
         *a, block_diffusion=4, block_q=16, block_k=16) * w), (0, 1, 2)))(
@@ -154,8 +158,8 @@ def test_the_kernels_take_no_mask_operand_and_no_square_mask_is_built():
                 walk(sub)
 
     walk(jaxpr.jaxpr)
-    assert operands == {"flash_fwd": 3, "flash_bwd_dq": 6,
-                        "flash_bwd_dkv": 6}
+    assert operands == {"flash_fwd": 3,
+                        **_backward_kernels(flash_backward, 6)}
     assert not [s for s in shapes if s[-2:] == (128, 128)]
 
 
@@ -350,13 +354,14 @@ class _ParentGeometry(FLASH._Geometry):
 @pytest.mark.parametrize("causal,hk,masked", [
     (True, 4, False), (True, 1, False), (False, 2, True), (True, 4, True)])
 def test_a_causal_call_lowers_as_before_the_rule(causal, hk, masked,
-                                                 monkeypatch):
+                                                 flash_backward, monkeypatch):
     """A call that is causal, or has no rule, lowers for the TPU to the
     text of the parent's expressions (kernel bodies decoded, locations
-    stripped), equal heads and grouped, with a key mask and without: the
-    rule's interface adds no op to them. (Checked against the parent
-    commit itself at the Kimi, GLM and LFM2 cells' shapes in PERF.md
-    section 6, PR 40.) A call under the rule does lower to another text."""
+    stripped), equal heads and grouped, with a key mask and without, its
+    backward the one fused kernel or the pair of passes: the rule's
+    interface adds no op to them. (Checked against the parent commit
+    itself at the Kimi, GLM and LFM2 cells' shapes in PERF.md section 6,
+    PR 40.) A call under the rule does lower to another text."""
     from jax import export
     from test_flash_attention import _without_locations
     q = jnp.zeros((2, 512, 4, 64), jnp.bfloat16)
@@ -374,9 +379,10 @@ def test_a_causal_call_lowers_as_before_the_rule(causal, hk, masked,
         return _without_locations(exported.mlir_module())
 
     now = text(causal=causal)
-    assert now.count("tpu_custom_call") >= 3
-    monkeypatch.setattr(FLASH, "_Geometry", _ParentGeometry)
-    assert text(causal=causal) == now
-    monkeypatch.undo()
+    assert now.count("tpu_custom_call") >= (
+        2 if flash_backward == "fused" else 3)
+    with monkeypatch.context() as patch:
+        patch.setattr(FLASH, "_Geometry", _ParentGeometry)
+        assert text(causal=causal) == now
     if not masked:
         assert text(block_diffusion=4) != now

@@ -312,6 +312,14 @@ def _two_layers(causal, with_mask, t, checkpointed, return_lse=False):
     return loss, (q, k, v, jnp.asarray(mask))
 
 
+def _backward_kernels(form, calls):
+    """{kernel name: calls} of ``calls`` backward passes in ``form``."""
+    kernels = {"flash_bwd_dq": calls}
+    if form == "pair":
+        kernels["flash_bwd_dkv"] = calls
+    return kernels
+
+
 def _eqns(fn, args):
     """Every equation of the traced fn, those of its sub-jaxprs too (each
     use of a shared one: the printed text shows it only once)."""
@@ -339,26 +347,29 @@ def _kernel_calls(fn, args):
     (True, False, 48), (False, True, 48), (True, True, 40),
     (False, False, 40)])   # 40: padded to the block of 16
 def test_checkpointed_block_runs_the_forward_kernel_once(causal, with_mask,
-                                                         t, return_lse):
+                                                         t, return_lse,
+                                                         flash_backward):
     """Under the containers' policy the second forward of a block holds no
-    `flash_fwd`: as many forward calls as layers, as many backward; the
-    gradients are bit for bit those of the bare function."""
+    `flash_fwd`: as many forward calls as layers, as many backward (one
+    fused kernel each, or the pair); the gradients are bit for bit those
+    of the bare function."""
     kept, args = _two_layers(causal, with_mask, t, True, return_lse)
     bare, _ = _two_layers(causal, with_mask, t, False, return_lse)
     grad = lambda f: jax.grad(f, argnums=(0, 1, 2))
-    once = {"flash_fwd": 2, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
+    once = {"flash_fwd": 2, **_backward_kernels(flash_backward, 2)}
     assert _kernel_calls(grad(kept), args) == once
     assert _kernel_calls(grad(bare), args) == once
     for a, b in zip(jax.jit(grad(kept))(*args), jax.jit(grad(bare))(*args)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_a_checkpoint_that_keeps_nothing_runs_the_forward_twice():
+def test_a_checkpoint_that_keeps_nothing_runs_the_forward_twice(
+        flash_backward):
     """What the name spares: under a checkpoint with no policy it is the
     identity and each block's backward pass runs `flash_fwd` again."""
     bare, args = _two_layers(True, False, 48, False)
     assert _kernel_calls(jax.grad(jax.checkpoint(bare)), args) == {
-        "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkv": 2}
+        "flash_fwd": 4, **_backward_kernels(flash_backward, 2)}
 
 
 @pytest.mark.parametrize("return_lse", [False, True])
@@ -395,7 +406,7 @@ def test_grouped_kv_heads_forward_and_the_three_gradients(group, d, causal,
     """The kernels (interpreted) with 8 query heads on 8 // group
     key/value heads, against the dense path with k and v repeated to
     every query head: the output and dq, dk, dv (a key head's gradient
-    sums over its group inside the dk/dv kernel), causal and key-masked,
+    sums over its group inside the backward kernel), causal and key-masked,
     at a length that is no multiple of the block."""
     h, hk, t = 8, 8 // group, 80
     ks = jax.random.split(jax.random.PRNGKey(group), 4)
@@ -421,6 +432,119 @@ def test_grouped_kv_heads_forward_and_the_three_gradients(group, d, causal,
     assert got[1].shape == k.shape and got[2].shape == v.shape
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+# ---- the backward in one walk: the fused kernel against the pair of passes
+
+
+def _both_backwards(monkeypatch, loss, args):
+    """(gradients, kernel calls) of ``loss`` with the fused backward and
+    with the pair a call keeps past the byte budget."""
+    import sys
+    module = sys.modules["deeplearning4j_tpu.ops.flash_attention"]
+    got = {}
+    for form, budget in (("fused", module._RESIDENT_SUM_BYTES), ("pair", 0)):
+        monkeypatch.setattr(module, "_RESIDENT_SUM_BYTES", budget)
+        # a function of its own a form: jit keeps its traces by function
+        grad = jax.grad(lambda *a: loss(*a), (0, 1, 2))
+        got[form] = (jax.jit(grad)(*args), _kernel_calls(grad, args))
+    return got
+
+
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("d,dv", [(64, 64), (192, 128)])
+@pytest.mark.parametrize("rule,keys", [
+    ("none", "whole"), ("none", "masked"), ("none", "padded"),
+    ("causal", "whole"), ("causal", "masked"), ("causal", "padded"),
+    ("block_diffusion", "whole")])
+def test_the_fused_backward_equals_the_pair_bit_for_bit(rule, keys, group, d,
+                                                        dv, monkeypatch):
+    """ONE kernel that makes a tile's scores, probabilities, dp and ds once
+    gives the dq, dk and dv of the two passes that each make them again,
+    EXACTLY: dq sums over k tiles in the same order, and a k block's dk
+    and dv over (query head of the group, q block ascending), the k-major
+    pass' order, in float32. Interpreted, in bfloat16 (a cast a sum) and
+    with a cotangent on the log-sum-exp, which reaches both through
+    ``delta``; under every rule, with a caller's key mask, with the
+    wrapper's own for padded keys (and padded query rows), at one, four and
+    eight query heads a key head and at a v narrower than q and k. (The
+    rule of block diffusion takes no key mask and no padding.)"""
+    h, hk = 8, 8 // group
+    t = {"whole": 48, "masked": 48, "padded": 40}[keys]
+    if rule == "block_diffusion":
+        t = 64                      # twice a whole number of q blocks
+    ks = jax.random.split(jax.random.PRNGKey(group + d), 4)
+    bf = lambda x: x.astype(jnp.bfloat16)
+    q = bf(jax.random.normal(ks[0], (2, t, h, d)) * 0.5)
+    k = bf(jax.random.normal(ks[1], (2, t, hk, d)) * 0.5)
+    v = bf(jax.random.normal(ks[2], (2, t, hk, dv)))
+    w = bf(jax.random.normal(ks[3], (2, t, h, dv)))
+    mask = None
+    if keys == "masked":
+        mask = np.ones((2, t), np.float32)
+        mask[0, 30:] = 0.0
+        mask[1, ::5] = 0.0
+        mask = jnp.asarray(mask)
+    how = dict(causal=rule == "causal", mask=mask, block_q=16, block_k=16)
+    if rule == "block_diffusion":
+        how = dict(block_diffusion=8, block_q=16, block_k=8)
+
+    def loss(q, k, v):
+        out, lse = flash_attention(q, k, v, return_lse=True, **how)
+        return jnp.sum((out * w).astype(jnp.float32)) + jnp.sum(jnp.sin(lse))
+
+    got = _both_backwards(monkeypatch, loss, (q, k, v))
+    assert got["fused"][1] == {"flash_fwd": 1, "flash_bwd_dq": 1}
+    assert got["pair"][1] == {"flash_fwd": 1, "flash_bwd_dq": 1,
+                              "flash_bwd_dkv": 1}
+    for a, b in zip(got["fused"][0], got["pair"][0]):
+        assert a.dtype == jnp.bfloat16 and a.shape == b.shape
+        assert float(jnp.abs(a.astype(jnp.float32)).max()) > 0
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("tk,d,dv,dtype,fused", [
+    (16384, 128, 128, "bfloat16", True),     # the SDAR cell's stream
+    (8192, 256, 256, "bfloat16", True),      # GLM
+    (8192, 192, 128, "bfloat16", True),      # Kimi
+    (8192, 64, 64, "bfloat16", True),        # LFM2: a lane tile a width
+    (32768, 128, 128, "bfloat16", True),     # the largest at 128 + 128
+    (32768 + 512, 128, 128, "bfloat16", False),
+    (16384, 256, 256, "bfloat16", True),
+    (16384 + 512, 256, 256, "bfloat16", False),
+    (32768, 128, 128, "float32", False),     # 12 bytes a value, not 8
+    (16384, 128, 128, "float32", True)])
+def test_the_backward_is_fused_where_a_key_heads_sums_fit_the_budget(
+        tk, d, dv, dtype, fused):
+    """Which backward a call takes follows from its shapes alone: the
+    float32 sums of one key head's dk and dv and their double-buffered
+    output blocks, the widths in whole lanes, against 64 MiB."""
+    import sys
+    module = sys.modules["deeplearning4j_tpu.ops.flash_attention"]
+    assert module._RESIDENT_SUM_BYTES == 64 * 2 ** 20 < module._VMEM_BYTES
+    assert module._backward_is_fused(tk, d, dv, jnp.dtype(dtype)) is fused
+
+
+def test_a_call_past_the_byte_budget_takes_the_pair_and_the_gauge_says_so(
+        monkeypatch):
+    """A call whose key-side sums pass the budget (cut here to the bytes of
+    48 keys at a lane tile a width, in float32: 48 * 256 * 12) keeps the
+    two passes; one key more in the budget and it takes the fused kernel.
+    The gauge `flash_bwd_kernels` reads 2 and 1 where the calls are
+    traced."""
+    import sys
+    from deeplearning4j_tpu import monitor
+    module = sys.modules["deeplearning4j_tpu.ops.flash_attention"]
+    monkeypatch.setattr(module, "_RESIDENT_SUM_BYTES", 48 * 256 * 12)
+    for t, form, kernels in ((64, "pair", 2), (48, "fused", 1)):
+        q, k, v = _qkv(t=t, seed=41)
+        grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, block_q=16, block_k=16) ** 2), (0, 1, 2))
+        assert _kernel_calls(grad, (q, k, v)) == {
+            "flash_fwd": 1, **_backward_kernels(form, 1)}
+        series = monitor.dump()["flash_bwd_kernels"]["series"]
+        assert [s["value"] for s in series] == [kernels]
 
 
 def test_grouped_kv_heads_must_divide_the_query_heads():
@@ -466,13 +590,14 @@ def _without_locations(text):
 
 
 def test_as_many_key_heads_as_query_heads_lowers_as_before_the_groups(
-        monkeypatch):
+        monkeypatch, flash_backward):
     """With ``group`` 1 the grouping hands every grid index back as it
     came (no op traced), so an equal-heads call lowers, for the TPU, to
     the text of the ungrouped index maps: `_Group` replaced by the
-    parent's expressions (``bh``, ``bh``, ``st``) gives the same text.
-    (Checked against the parent commit itself, kernel bodies decoded and
-    locations stripped, in PERF.md section 6, PR 34.)"""
+    parent's expressions (``bh``, ``bh``, ``st``) gives the same text,
+    with the fused backward (whose axis of a group's heads is 1 long) and
+    with the pair. (Checked against the parent commit itself, kernel
+    bodies decoded and locations stripped, in PERF.md section 6, PR 34.)"""
     import re
     import sys
     from jax import export
@@ -481,6 +606,7 @@ def test_as_many_key_heads_as_query_heads_lowers_as_before_the_groups(
     marks = [object() for _ in range(2)]
     assert grp.kv_row(marks[0]) is marks[0]
     assert grp.q_row(marks[0], marks[1]) is marks[0]
+    assert grp.head_row(marks[0], marks[1]) is marks[0]
     assert grp.step(marks[1]) is marks[1]
 
     def loss(q, k, v, mask):
@@ -498,7 +624,8 @@ def test_as_many_key_heads_as_query_heads_lowers_as_before_the_groups(
         return _without_locations(exported.mlir_module())
 
     grouped = text()
-    assert grouped.count("tpu_custom_call") >= 3
+    assert grouped.count("tpu_custom_call") >= (
+        2 if flash_backward == "fused" else 3)
 
     class Ungrouped:
         n = 1
@@ -508,12 +635,13 @@ def test_as_many_key_heads_as_query_heads_lowers_as_before_the_groups(
 
         kv_row = staticmethod(lambda row: row)
         q_row = staticmethod(lambda row, st: row)
+        head_row = staticmethod(lambda row, head: row)
         step = staticmethod(lambda st: st)
 
-    monkeypatch.setattr(module, "_Group", Ungrouped)
-    assert text() == grouped
+    with monkeypatch.context() as patch:
+        patch.setattr(module, "_Group", Ungrouped)
+        assert text() == grouped
     # and a grouped call does trace the grouping
-    monkeypatch.undo()
     k2, v2, _ = _qkv(t=200, h=2, d=64)
     assert text(k2, v2) != grouped
 
@@ -647,21 +775,23 @@ def test_a_key_mask_of_ones_is_no_mask_to_the_last_bit(causal, tq, tk, bq,
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_without_a_key_mask_the_kernels_take_no_mask_operand(causal):
-    """`mask=None` lowers `flash_fwd` on q, k, v and the two backward
-    kernels on q, k, v, dO, lse, delta: no mask operand, and no (B, T) array
-    of ones is built for one. A caller's mask is one operand more."""
+def test_without_a_key_mask_the_kernels_take_no_mask_operand(
+        causal, flash_backward):
+    """`mask=None` lowers `flash_fwd` on q, k, v and the backward's kernel
+    (fused) or kernels (the pair) on q, k, v, dO, lse, delta: no mask
+    operand, and no (B, T) array of ones is built for one. A caller's mask
+    is one operand more."""
     q, k, v, w = _tile_case(64, 64, 4, 4)
     loss = lambda mask: lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, causal=causal, mask=mask, block_q=16, block_k=16) * w)
     grad = lambda mask: jax.grad(loss(mask), (0, 1, 2))
     operands, built = _kernel_operands(grad(None), (q, k, v))
-    assert operands == {"flash_fwd": 3, "flash_bwd_dq": 6,
-                        "flash_bwd_dkv": 6}
+    assert operands == {"flash_fwd": 3, **_backward_kernels(flash_backward,
+                                                           6)}
     assert not [shape for shape in built if shape in ((2, 64), (2, 1, 64))]
     operands, _ = _kernel_operands(grad(jnp.ones((2, 64))), (q, k, v))
-    assert operands == {"flash_fwd": 4, "flash_bwd_dq": 7,
-                        "flash_bwd_dkv": 7}
+    assert operands == {"flash_fwd": 4, **_backward_kernels(flash_backward,
+                                                           7)}
 
 
 @pytest.mark.parametrize("causal,tq,tk,bq,bk", [

@@ -55,8 +55,8 @@ class TestFlashKernelLowering:
             q, k, v, mask=m, interpret=False), q, q, q, m)
 
     def test_backward_kernels_with_lse_cotangent(self):
-        # grad through out AND lse covers the dq kernel, the dk/dv
-        # kernel, and the lse-cotangent fold into delta
+        # grad through out AND lse covers the backward kernel (dq, dk and
+        # dv in one walk) and the lse-cotangent fold into delta
         from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
         def loss(q, k, v):
@@ -127,6 +127,42 @@ def _compile_v5e(fn, sharding, *avals):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+#: the kernels a flash program compiles to: the forward alone (False), or
+#: with the backward in one of its two forms (conftest's `flash_backward`)
+_FLASH_KERNELS = {False: ["flash_fwd"], "fused": ["flash_bwd_dq", "flash_fwd"],
+                  "pair": ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]}
+
+
+def _flash_kernels(monkeypatch, backward):
+    """`_FLASH_KERNELS` of a test parametrised over (False, "fused",
+    "pair") itself; the pair is forced as the fixture forces it."""
+    import sys
+
+    import deeplearning4j_tpu.ops  # noqa: F401  (the module is loaded)
+    if backward == "pair":
+        monkeypatch.setattr(
+            sys.modules["deeplearning4j_tpu.ops.flash_attention"],
+            "_RESIDENT_SUM_BYTES", 0)
+    return _FLASH_KERNELS[backward]
+
+
+def _kernels_called(hlo):
+    """The Pallas kernels a compiled module calls, by instruction name."""
+    import re
+    return sorted(re.findall(
+        r"^\s*(?:ROOT\s+)?%?(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call",
+        hlo, re.M))
+
+
+def _holds_flash_kernels(hlo, kernels):
+    """The compiled text names exactly ``kernels`` of the three flash
+    kernels (a bare gradient's instructions are named after their
+    transforms, `transpose_jvp_flash_bwd_dq`, so by substring)."""
+    return "tpu_custom_call" in hlo and all(
+        (name in hlo) == (name in kernels)
+        for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+
+
 def _loop_trips(hlo):
     """The bound each `while` of a compiled module counts to: the constant
     its condition computation (or the fusion that one calls) compares
@@ -147,8 +183,9 @@ def _loop_trips(hlo):
 
 
 class TestFlashKernelCompiles:
-    """forward, dq and dk/dv through Mosaic's own passes, at the smoke's
-    shapes and the layer-default block 512."""
+    """forward and backward (the fused kernel, and the dq and dk/dv pair
+    of a call past its byte budget) through Mosaic's own passes, at the
+    smoke's shapes and the layer-default block 512."""
 
     @staticmethod
     def _one(v5e):
@@ -164,7 +201,7 @@ class TestFlashKernelCompiles:
         assert "tpu_custom_call" in hlo and "flash_fwd" in hlo
 
     @pytest.mark.parametrize("d,block", [(64, 128), (128, 512)])
-    def test_grad_compiles_dq_and_dkv(self, v5e, d, block):
+    def test_grad_compiles_dq_and_dkv(self, v5e, d, block, flash_backward):
         from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
         def loss(q, k, v):
@@ -175,15 +212,15 @@ class TestFlashKernelCompiles:
         qkv = ((4, 2048, 8, d), jnp.bfloat16)
         hlo = _compile_v5e(jax.grad(loss, argnums=(0, 1, 2)),
                            self._one(v5e), qkv, qkv, qkv)
-        for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-            assert kernel in hlo
+        assert _holds_flash_kernels(hlo, _FLASH_KERNELS[flash_backward])
 
     @pytest.mark.parametrize("heads,d_qk,d_v", [(32, 192, 128),
                                                 (20, 256, 256)])
-    @pytest.mark.parametrize("backward", [True, False])
+    @pytest.mark.parametrize("backward", ["fused", "pair", False])
     @pytest.mark.parametrize("masked", [False, True])
     def test_two_head_sizes_at_the_lm_cells_widths(self, v5e, backward,
-                                                   heads, d_qk, d_v, masked):
+                                                   heads, d_qk, d_v, masked,
+                                                   monkeypatch):
         # latent attention's expanded form at the two LM cells' widths:
         # 32 heads of 192-wide q.k and 128-wide v (position-free), 20 of
         # 256 / 256 (rotated); 8,192 positions, block 512. Without a key
@@ -191,6 +228,7 @@ class TestFlashKernelCompiles:
         # interior and a diagonal body; with one, the one body that
         # applies it on every tile
         from deeplearning4j_tpu.ops.flash_attention import flash_attention
+        kernels = _flash_kernels(monkeypatch, backward)
 
         def loss(q, k, v, *mask):
             return jnp.sum(flash_attention(
@@ -204,19 +242,18 @@ class TestFlashKernelCompiles:
         hlo = _compile_v5e(
             jax.grad(loss, argnums=(0, 1, 2)) if backward else loss,
             self._one(v5e), qk, qk, v, *mask)
-        for kernel in (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-                       if backward else ("flash_fwd",)):
-            assert kernel in hlo
+        assert _holds_flash_kernels(hlo, kernels)
 
-    @pytest.mark.parametrize("backward", [True, False])
+    @pytest.mark.parametrize("backward", ["fused", "pair", False])
     @pytest.mark.parametrize("masked", [False, True])
     def test_grouped_64_wide_heads_at_the_third_lm_cells_widths(
-            self, v5e, backward, masked):
+            self, v5e, backward, masked, monkeypatch):
         # grouped-query attention at the LFM2 cell's widths: 32 query
         # heads on 8 key/value heads, 64 wide (half the v5e's lanes), 4
         # sequences of 8,192 positions, block 512; k, v and their
         # gradients keep 8 heads; without a key mask (the cell) and with
         from deeplearning4j_tpu.ops.flash_attention import flash_attention
+        kernels = _flash_kernels(monkeypatch, backward)
 
         def loss(q, k, v, *mask):
             return jnp.sum(flash_attention(
@@ -230,20 +267,18 @@ class TestFlashKernelCompiles:
         hlo = _compile_v5e(
             jax.grad(loss, argnums=(0, 1, 2)) if backward else loss,
             self._one(v5e), q, kv, kv, *mask)
-        for kernel in (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-                       if backward else ("flash_fwd",)):
-            assert kernel in hlo
-        assert "tpu_custom_call" in hlo
+        assert _holds_flash_kernels(hlo, kernels)
 
-    @pytest.mark.parametrize("backward", [True, False])
+    @pytest.mark.parametrize("backward", ["fused", "pair", False])
     def test_the_block_diffusion_rule_at_the_sdar_cells_widths(
-            self, v5e, backward):
+            self, v5e, backward, monkeypatch):
         # the rule of block-diffusion training at the SDAR cell's widths:
         # 32 query heads on 4 key/value heads, 128 wide, 2 sequences whose
         # stream [noisy ; clean] is 16,384 rows, block 512, diffusion
         # blocks of 4 (the in-tile predicate divides positions by it on
         # the vector unit); the index maps walk two runs of tiles
         from deeplearning4j_tpu.ops.flash_attention import flash_attention
+        kernels = _flash_kernels(monkeypatch, backward)
 
         def loss(q, k, v):
             return jnp.sum(flash_attention(
@@ -255,18 +290,14 @@ class TestFlashKernelCompiles:
         hlo = _compile_v5e(
             jax.grad(loss, argnums=(0, 1, 2)) if backward else loss,
             self._one(v5e), q, kv, kv)
-        for kernel in (("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
-                       if backward else ("flash_fwd",)):
-            assert kernel in hlo
-        assert "tpu_custom_call" in hlo
+        assert _holds_flash_kernels(hlo, kernels)
 
     def test_checkpointed_grouped_query_attention_runs_the_forward_once(
-            self, v5e, monkeypatch):
+            self, v5e, monkeypatch, flash_backward):
         # `MultiHeadAttention` as the LFM2 family holds it (32 on 8, q/k
         # normed, rotated at 1e6, the fused kernel) inside the containers'
         # rematerialised layer call: ONE flash_fwd in the gradient, and no
         # k or v of 32 heads anywhere (they are not repeated in HBM)
-        import re
         import sys
 
         from deeplearning4j_tpu.nn.conf.base import InputType
@@ -295,11 +326,7 @@ class TestFlashKernelCompiles:
             jax.tree_util.tree_map(place, shapes),
             place(jax.ShapeDtypeStruct((1, 8192, 2048), jnp.bfloat16))
         ).compile().as_text()
-        kernels = re.findall(
-            r"^\s*(?:ROOT\s+)?%?(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call",
-            hlo, re.M)
-        assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq",
-                                   "flash_fwd"]
+        assert _kernels_called(hlo) == _FLASH_KERNELS[flash_backward]
         calls = [line for line in hlo.splitlines()
                  if "tpu_custom_call" in line]
         assert all("bf16[8,8192,64]" in line for line in calls)   # k, v
@@ -309,12 +336,11 @@ class TestFlashKernelCompiles:
         (2048, dict(n_heads=20, nope_dim=192, rope_dim=64, v_dim=256,
                     q_rank=768, rotate=True))])
     def test_checkpointed_latent_attention_runs_the_forward_once(
-            self, v5e, hidden, widths, monkeypatch):
+            self, v5e, hidden, widths, monkeypatch, flash_backward):
         # the latent attention of each LM cell (32 x 192/128 position-free;
         # 20 x 256/256 rotated with a low-rank query) inside the
         # containers' rematerialised layer call: the kernel's output and
         # log-sum-exp are kept, so the gradient holds ONE flash_fwd
-        import re
         import sys
 
         from deeplearning4j_tpu.nn.conf.base import InputType
@@ -343,11 +369,7 @@ class TestFlashKernelCompiles:
             jax.tree_util.tree_map(place, shapes),
             place(jax.ShapeDtypeStruct((1, 8192, hidden), jnp.bfloat16))
         ).compile().as_text()
-        kernels = re.findall(
-            r"^\s*(?:ROOT\s+)?%?(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call",
-            hlo, re.M)
-        assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq",
-                                   "flash_fwd"]
+        assert _kernels_called(hlo) == _FLASH_KERNELS[flash_backward]
 
     def test_expert_grouped_products_compile_as_ragged_dot(self, v5e):
         # the expert layer's grouped product at the cell's widths (one

@@ -93,10 +93,15 @@ def test_capture_reads_cost_and_memory_analysis_on_cpu():
 
 @pytest.mark.parametrize("names,calls", [
     # a rematerialised block that runs the forward kernel again: two
-    # flash_fwd instructions a backward pair
+    # flash_fwd instructions a backward pair (a call too long for the
+    # fused backward)
     (("flash_fwd.22", "flash_fwd.23", "flash_bwd_dq.11", "flash_bwd_dkv.11",
       "fusion.7", "pallas_call.300"),
      {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}),
+    # the cells' steps: the fused backward keeps the name flash_bwd_dq,
+    # once a forward, and no flash_bwd_dkv
+    (("flash_fwd.55", "flash_fwd.56", "flash_bwd_dq.55", "flash_bwd_dq.56",
+      "fusion.9"), {"flash_fwd": 2, "flash_bwd_dq": 2}),
     (("kda_chunk_fwd.1", "kda_chunk_fwd.2", "kda_chunk_bwd.37", "flash_fwd",
       "flash_fwd_like.3", "ragged-dot-none.3"),
      {"kda_chunk_fwd": 2, "kda_chunk_bwd": 1, "flash_fwd": 1}),
@@ -105,21 +110,28 @@ def test_kernel_calls_counts_instructions_by_their_kernels_name(names, calls):
     assert xla_ledger.kernel_calls(dict.fromkeys(names, "")) == calls
 
 
-def test_capture_publishes_the_programs_kernel_calls(monkeypatch):
+@pytest.mark.parametrize("backward", ["fused", "pair"])
+def test_capture_publishes_the_programs_kernel_calls(monkeypatch, backward):
     """On a TPU a Pallas call's instruction is named after its kernel; here
-    the instruction table is planted."""
-    monkeypatch.setattr(
-        xla_ledger, "compiled_op_scopes", lambda compiled: {
-            "flash_fwd.1": "jit(f)/mla/attn/flash_fwd/pallas_call",
-            "flash_bwd_dq.1": "jit(f)/transpose(mla/attn)/flash_bwd_dq",
-            "flash_bwd_dkv.1": "jit(f)/transpose(mla/attn)/flash_bwd_dkv",
-            "fusion.1": "jit(f)/add"})
+    the instruction table is planted: a program whose flash backward is the
+    one fused kernel sets no `flash_bwd_dkv` series, one that took the pair
+    of passes does."""
+    planted = {
+        "flash_fwd.1": "jit(f)/mla/attn/flash_fwd/pallas_call",
+        "flash_bwd_dq.1": "jit(f)/transpose(mla/attn)/flash_bwd_dq",
+        "fusion.1": "jit(f)/add"}
+    if backward == "pair":
+        planted["flash_bwd_dkv.1"] = \
+            "jit(f)/transpose(mla/attn)/flash_bwd_dkv"
+    monkeypatch.setattr(xla_ledger, "compiled_op_scopes",
+                        lambda compiled: planted)
     xla_ledger.enable_ledger()
     xla_ledger.capture("t/prog", jax.jit(lambda x: x + 1),
                        (np.ones(3, "float32"),))
-    g = monitor.REGISTRY.collect("xla_program_kernel_calls")
-    assert [g.value(program="t/prog", kernel=k) for k in
-            ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")] == [1, 1, 1]
+    series = monitor.dump()["xla_program_kernel_calls"]["series"]
+    assert {s["labels"]["kernel"]: s["value"] for s in series} == {
+        "flash_fwd": 1, "flash_bwd_dq": 1,
+        **({"flash_bwd_dkv": 1} if backward == "pair" else {})}
     # a program without a kernel sets no series
     monkeypatch.setattr(xla_ledger, "compiled_op_scopes", lambda c: {})
     xla_ledger.capture("t/plain", jax.jit(lambda x: x * 2),

@@ -49,6 +49,24 @@ HBM beyond 2,048 query rows of the indexer's:
   anyway, the gradient of ``kl`` into the indexer's ``qI``, ``w`` and
   ``kI``; the key side's sums gather in float32 buffers in HBM.
 
+The tile's orientation follows what a kernel's sums contract over.
+``dsa_index``, ``dsa_select`` and ``dsa_attn_fwd`` hold it Q-MAJOR,
+(bq, bk): the forward's statistics are RUNNING ones, made by reductions
+along the lanes that leave them lane-broadcast already, and its one sum
+``p . v`` contracts over the keys, plain as it stands (key-major would
+make it the product with a transposed operand). ``dsa_attn_bwd`` and
+``dsa_kl_fwd`` hold it KEY-MAJOR, (bk, bq), keys down the sublanes and
+queries along the lanes: the statistics they read are SAVED ones, a lane
+vector a head ((B, H, 1, T): ``lse``, ``delta``), which such a tile takes
+as they lie where a q-major one turned each into a column a head and
+tile; and the backward's key-side sums (dv, dk, dki) contract over the
+queries, the tile's lanes: plain products, where a q-major tile gave two
+of them a head a transposed left operand (the query side's dq and dqi
+gather transposed, plain as well: `_bwd_kernel`). The mask of both stays
+THE set ``tau`` was taken from: they make the q-major tile by the same
+`_tile_mask` and turn the finished (scores, kept) once a tile
+(`_key_major`), never the indexer's products in another order.
+
 What the backward needs again and is dear to make (output, log-sum-exp,
 ``tau``, ``cut``, the indexer's log-sum-exp) carries `ops.REMAT_KEEP`.
 
@@ -225,11 +243,12 @@ def _tile_mask(qi_ref, ki_ref, wi_ref, tau_ref, cut_ref, q0, k0):
     return s, keep_mask(s, tau_ref[0], cut_ref[0], qpos, kpos)
 
 
-def _scores(q, k, kept, scale):
-    """Scaled scores of one head's tile, ``-inf`` off the kept keys: a
-    probability made from them is 0 there by itself, also in a row that
-    has met no kept key yet (its running max is the finite `NEG`)."""
-    s = scale * jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+def _scores(a, b, kept, scale):
+    """Scaled scores of one head's tile, ``a . b^T`` (rows of ``a`` down
+    the tile: (q, k) q-major, (k, q) key-major), ``-inf`` off the kept
+    keys: a probability made from them is 0 there by itself, also in a row
+    that has met no kept key yet (its running max is the finite `NEG`)."""
+    s = scale * jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
     return jnp.where(kept, s, -jnp.inf)
 
@@ -287,14 +306,36 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qi_ref, ki_ref, wi_ref, tau_ref,
         cnt_ref[0] = cnt_scr[...].sum(-1, keepdims=True)
 
 
+def _key_major(s, kept):
+    """A q-major tile's (scores, kept) turned KEY-MAJOR, (bk, bq): keys
+    down the sublanes, queries along the lanes. The mask stays the set
+    ``tau`` was taken from by construction: it is `_tile_mask`'s own
+    (`index_tile` on the one tile shape, as in `dsa_index` and
+    `dsa_attn_fwd`), moved and never made again; ONE transpose a tile for
+    all the heads (a score is finite, so ``> -inf`` is the mask; the
+    scores come back 0 off the kept keys)."""
+    s = jnp.where(kept, s, -jnp.inf).T
+    kept = s > -jnp.inf
+    return jnp.where(kept, s, 0.0), kept
+
+
+def _down(x):
+    """(rows, n) -> (1, n): the sum down the sublanes, a lane vector."""
+    return jnp.sum(x, axis=0, keepdims=True)
+
+
 def _mean_probs(q_ref, k_ref, lse_ref, kept, scale, heads, group,
                 each=None):
-    """The tile's probabilities averaged over the query heads, from the
-    saved log-sum-exps; ``each(h, g, p)`` sees every head's on the way."""
+    """The KEY-MAJOR tile's probabilities (bk, bq) averaged over the query
+    heads, from the saved log-sum-exps; ``each(h, g, p)`` sees every
+    head's on the way. ``lse_ref`` (1, H, 1, bq) holds a head's as a lane
+    vector, which a key-major tile reads as it lies (a row handed down
+    the sublanes); a q-major tile had to turn it into a column, a head and
+    tile."""
     def head(h, acc):
         g = h // group
-        p = jnp.exp(_scores(q_ref[0, h], k_ref[0, g], kept, scale)
-                    - lse_ref[0, h, 0][:, None])
+        p = jnp.exp(_scores(k_ref[0, g], q_ref[0, h], kept, scale)
+                    - lse_ref[0, h])
         if each is not None:
             each(h, g, p)
         return acc + p
@@ -307,6 +348,9 @@ def _mean_probs(q_ref, k_ref, lse_ref, kept, scale, heads, group,
 def _kl_kernel(q_ref, k_ref, qi_ref, ki_ref, wi_ref, tau_ref, cut_ref,
                lse_ref, lsei_ref, kl_ref, acc_scr, *, geom, scale, heads,
                group):
+    """Grid (B, nq, nk), k innermost; the tile KEY-MAJOR (`_key_major`):
+    every statistic a query (``lse``, ``lsei`` (1, 1, 1, bq), the sum
+    ``kl`` itself) is a lane vector and stays one."""
     qi, kj = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kj == 0)
@@ -315,40 +359,59 @@ def _kl_kernel(q_ref, k_ref, qi_ref, ki_ref, wi_ref, tau_ref, cut_ref,
 
     @pl.when(kj <= geom.k_hi(qi))
     def _tile():
-        s, kept = _tile_mask(qi_ref, ki_ref, wi_ref, tau_ref, cut_ref,
-                             qi * geom.bq, kj * geom.bk)
+        s, kept = _key_major(*_tile_mask(
+            qi_ref, ki_ref, wi_ref, tau_ref, cut_ref, qi * geom.bq,
+            kj * geom.bk))
         p = _mean_probs(q_ref, k_ref, lse_ref, kept, scale, heads, group)
         # p is 0 off the kept keys: 0 * (finite) there
-        acc_scr[...] += _fold(p * (jnp.log(jnp.maximum(p, 1e-37))
-                                   - (s - lsei_ref[0])))
+        acc_scr[...] += _down(p * (jnp.log(jnp.maximum(p, 1e-37))
+                                   - (s - lsei_ref[0, 0])))
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _finish():
-        kl_ref[0] = acc_scr[...].sum(-1, keepdims=True)
+        kl_ref[0, 0] = acc_scr[...]
 
 
 def _index_grad(s, kept, p, lsei_ref, gkl_ref):
-    """d kl / d scores of the tile, times the cotangent of its rows."""
-    pi = jnp.where(kept, jnp.exp(s - lsei_ref[0]), 0.0)
-    return gkl_ref[0] * (pi - p)
+    """d kl / d scores of the key-major tile, times the cotangent of its
+    queries (both (1, 1, 1, bq))."""
+    pi = jnp.where(kept, jnp.exp(s - lsei_ref[0, 0]), 0.0)
+    return gkl_ref[0, 0] * (pi - p)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
-                ki_ref, wi_ref, tau_ref, cut_ref, lsei_ref, gkl_ref, dq_ref,
-                dqi_ref, dwi_ref, dk_hbm, dv_hbm, dki_hbm, dq_scr, dqi_scr,
-                dwi_scr, dk_scr, dv_scr, dki_scr, sem, *, geom, scale, heads,
-                group):
+                ki_ref, wi_ref, wit_ref, tau_ref, cut_ref, lsei_ref, gkl_ref,
+                dq_ref, dqi_ref, dwi_ref, dk_hbm, dv_hbm, dki_hbm, dq_scr,
+                dqi_scr, dwi_scr, dk_scr, dv_scr, dki_scr, kt_scr, sem, *,
+                geom, scale, heads, group):
     """Grid (B, nq, nk), k innermost, every axis sequential: a tile's
     mask, probabilities and ``ds`` are made ONCE and feed all six
-    gradients. dq, dqi and dwi of the q block stay in VMEM scratch over
+    gradients.
+
+    The tile is KEY-MAJOR, (bk, bq) (`_key_major`): ``p^T`` and ``ds^T``
+    are what the tile IS, so the key side's sums dv += p^T . do, dk +=
+    ds^T . q and dki += g^T . qI_j are plain products (bk rows stream
+    over a latched operand), ``lse`` and ``delta`` (1, H, 1, bq), the
+    indexer's ``lsei``, ``kl``'s cotangent and the head weights
+    ``wit_ref`` (1, Hi, 1, bq) are read along the lanes as they lie, and
+    dwi gathers as the lane vector it leaves as. The query side's sums,
+    which contract over the tile's rows, gather TRANSPOSED, plain too:
+    dq^T (D, bq) += k^T . ds^T and dqi_j^T (Di, bq) += kI^T . g, with
+    ``k^T`` and ``kI^T`` made once a KEY head and tile (``kt_scr``: the
+    head loop picks a key head's by its index) and the sums turned once a
+    q block (`_finish`). So no product of the head loop has a transposed
+    left operand, where a q-major tile had two, and two lane -> sublane
+    turns of the statistics a head and tile beside them (PERF.md section
+    5, PR 42).
+
+    dq, dqi and dwi of the q block stay in VMEM scratch over
     its row of tiles. dk, dv and dki of a k block gather over the q
     blocks, the OUTER axis: they are float32 buffers in HBM (``dk_hbm``
     (B, Hk, T, D), ``dv_hbm``, ``dki_hbm`` (B, T, Di padded to whole
     lanes: a copy moves whole lane tiles), never BlockSpec-pipelined),
     which a tile finds in one of two VMEM slots (``kj % 2``; zeros at
     the k block's FIRST q block, so the buffers need no zeroing), adds to
-    in the order the q blocks come (ascending, float32: the sums of a
-    k-major walk bit for bit) and copies back.
+    in the order the q blocks come (ascending, float32) and copies back.
 
     The read-after-write on those buffers is ordered by explicit copies
     that are WAITED on, not by the grid's pipeline: tile ``kj`` asks for
@@ -361,10 +424,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
     written at step (0, 0) and read at the very next, (1, 0)), and within
     a row no two tiles share a k block. The copy in runs under the
     indexer's gradient and the next tile's mask, the copy back under the
-    next tile (on the v5e, alone at the Keye cell's shapes: 216.2 ms with
-    the copies, 215.7 without; 222.8 asked for at the tile's own start)."""
+    next tile (on the v5e, alone at the Keye cell's shapes, PR 39: 216.2
+    ms with the copies, 215.7 without; 222.8 asked for at the tile's own
+    start)."""
     b, qi, kj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     k_hi = geom.k_hi(qi)
+    plain = (((1,), (0,)), ((), ()))
+    transposed = (((1,), (1,)), ((), ()))     # a . b^T: a key-major tile
 
     def copies(j, back):
         """The three copies of k block ``j``'s sums, HBM -> its VMEM slot
@@ -408,8 +474,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
     @pl.when(kj <= k_hi)
     def _tile():
         slot = kj % 2
-        s, kept = _tile_mask(qi_ref, ki_ref, wi_ref, tau_ref, cut_ref,
-                             qi * geom.bq, kj * geom.bk)
+        s, kept = _key_major(*_tile_mask(
+            qi_ref, ki_ref, wi_ref, tau_ref, cut_ref, qi * geom.bq,
+            kj * geom.bk))
+        for n in range(kt_scr.shape[0]):
+            kt_scr[n] = k_ref[0, n].T
 
         @pl.when(summed_before(kj))
         def _():
@@ -417,20 +486,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
                 copy.wait()
 
         def each(h, g, p):
-            q, k, do = q_ref[0, h], k_ref[0, g], do_ref[0, h]
+            q, do = q_ref[0, h], do_ref[0, h]
             dv_scr[slot, g] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                p.astype(do.dtype), do, plain,
                 preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do, v_ref[0, g],
-                                     (((1,), (1,)), ((), ())),
+            dp = jax.lax.dot_general(v_ref[0, g], do, transposed,
                                      preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta_ref[0, h, 0][:, None])).astype(k.dtype)
+            ds = (p * (dp - delta_ref[0, h])).astype(q.dtype)
             dq_scr[h] += scale * jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                kt_scr[g], ds, plain, preferred_element_type=jnp.float32)
             dk_scr[slot, g] += scale * jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                ds, q, plain, preferred_element_type=jnp.float32)
 
         p = _mean_probs(q_ref, k_ref, lse_ref, kept, scale, heads, group,
                         each)
@@ -444,17 +510,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
             fetch(kj + 1)
 
         di = _index_grad(s, kept, p, lsei_ref, gkl_ref)
-        ki, wi = ki_ref[0], wi_ref[0]
+        ki = ki_ref[0]
+        kit = ki.T
         for j in range(qi_ref.shape[1]):
-            a = _index_heads(qi_ref[0], ki, j)
-            dwi_scr[j] += _fold(di * jnp.maximum(a, 0.0))
-            g = jnp.where(a > 0, di * wi[:, j:j + 1], 0.0).astype(ki.dtype)
+            a = jax.lax.dot_general(ki, qi_ref[0, j], transposed,
+                                    preferred_element_type=jnp.float32)
+            dwi_scr[j] += _down(di * jnp.maximum(a, 0.0))
+            g = jnp.where(a > 0, di * wit_ref[0, j], 0.0).astype(ki.dtype)
             dqi_scr[j] += jax.lax.dot_general(
-                g, ki, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                kit, g, plain, preferred_element_type=jnp.float32)
             dki_scr[slot, :, :ki.shape[1]] += jax.lax.dot_general(
-                g, qi_ref[0, j], (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                g, qi_ref[0, j], plain, preferred_element_type=jnp.float32)
         for copy in copies(kj, True):
             copy.start()
 
@@ -465,10 +531,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref,
             wait_back(k_hi - 1)
 
         wait_back(k_hi)
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
-        dqi_ref[0] = dqi_scr[...].astype(dqi_ref.dtype)
-        for j in range(dwi_scr.shape[0]):
-            dwi_ref[0, j, 0] = dwi_scr[j].sum(-1)
+
+        def head(h, _):
+            dq_ref[0, h] = dq_scr[h].T.astype(dq_ref.dtype)
+            return 0
+
+        jax.lax.fori_loop(0, heads, head, 0)
+        for j in range(dqi_scr.shape[0]):
+            dqi_ref[0, j] = dqi_scr[j].T.astype(dqi_ref.dtype)
+        dwi_ref[0] = dwi_scr[...]
 
 
 # ------------------------------------------------------- calling the kernels
@@ -565,12 +636,21 @@ class _Specs:
         self.o = pl.BlockSpec((1, h, bq, dv), at_q)
         self.k = pl.BlockSpec((1, hk, bk, d), at_k)
         self.v = pl.BlockSpec((1, hk, bk, dv), at_k)
-        self.row = pl.BlockSpec((1, h, 1, bq), lambda b, i, j: (b, 0, 0, i))
         self.qi = pl.BlockSpec((1, hi, bq, di), at_q)
         self.ki = pl.BlockSpec((1, bk, di), lambda b, i, j: (b, kx(i, j), 0))
         self.wi = pl.BlockSpec((1, bq, hi), lambda b, i, j: (b, i, 0))
-        self.dwi = pl.BlockSpec((1, hi, 1, bq), lambda b, i, j: (b, 0, 0, i))
         self.col = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+        # a value a query along the LANES, (B, n, 1, T): one a query head
+        # (lse, delta), one an indexer head (wi for the key-major tile,
+        # dwi), one (lsei, kl and its cotangent)
+        rows = lambda n: pl.BlockSpec((1, n, 1, bq),
+                                      lambda b, i, j: (b, 0, 0, i))
+        self.row, self.irow, self.one = rows(h), rows(hi), rows(1)
+
+
+def _rows(a):
+    """A value a query (B, T, n) as lane vectors, (B, n, 1, T)."""
+    return a.transpose(0, 2, 1)[:, :, None, :]
 
 
 def _setup(qh, kh, vh, qih, bq, bk):
@@ -613,13 +693,13 @@ def _forward_kernels(qh, kh, vh, qih, ki, wi, topk, bq, bk, interpret):
             functools.partial(_kl_kernel, **common),
             grid=(b, geom.nq, geom.nk),
             in_specs=[sp.q, sp.k, sp.qi, sp.ki, sp.wi, sp.col, sp.col,
-                      sp.row, sp.col],
-            out_specs=sp.col,
-            out_shape=jax.ShapeDtypeStruct((b, t, 1), f32),
-            scratch_shapes=[pltpu.VMEM((bq, _LANES), f32)],
+                      sp.row, sp.one],
+            out_specs=sp.one,
+            out_shape=jax.ShapeDtypeStruct((b, 1, 1, t), f32),
+            scratch_shapes=[pltpu.VMEM((1, bq), f32)],
             compiler_params=_params("parallel", "parallel", "arbitrary"),
             interpret=interpret, name="dsa_kl_fwd",
-        )(qh, kh, qih, ki, wi, tau, cut, lse, lsei)
+        )(qh, kh, qih, ki, wi, tau, cut, lse, _rows(lsei)).reshape(b, t, 1)
     return out, lse, tau, cut, lsei, kl, kept
 
 
@@ -648,25 +728,26 @@ def _backward_kernels(res, g_out, g_kl, bq, bk, interpret):
             functools.partial(_bwd_kernel, **common),
             grid=(b, geom.nq, geom.nk),
             in_specs=[sp.q, sp.k, sp.v, sp.o, sp.row, sp.row, sp.qi, sp.ki,
-                      sp.wi, sp.col, sp.col, sp.col, sp.col],
-            out_specs=[sp.q, sp.qi, sp.dwi, hbm, hbm, hbm],
+                      sp.wi, sp.irow, sp.col, sp.col, sp.one, sp.one],
+            out_specs=[sp.q, sp.qi, sp.irow, hbm, hbm, hbm],
             out_shape=[jax.ShapeDtypeStruct(qh.shape, qh.dtype),
                        jax.ShapeDtypeStruct(qih.shape, qih.dtype),
                        jax.ShapeDtypeStruct((b, hi, 1, t), f32),
                        jax.ShapeDtypeStruct(kh.shape, f32),
                        jax.ShapeDtypeStruct(vh.shape, f32),
                        jax.ShapeDtypeStruct((b, t, dip), f32)],
-            scratch_shapes=[pltpu.VMEM((h, bq, d), f32),
-                            pltpu.VMEM((hi, bq, di), f32),
-                            pltpu.VMEM((hi, bq, _LANES), f32),
+            scratch_shapes=[pltpu.VMEM((h, d, bq), f32),
+                            pltpu.VMEM((hi, di, bq), f32),
+                            pltpu.VMEM((hi, 1, bq), f32),
                             pltpu.VMEM((2, hk, bk, d), f32),
                             pltpu.VMEM((2, hk, bk, dv), f32),
                             pltpu.VMEM((2, bk, dip), f32),
+                            pltpu.VMEM((hk, d, bk), kh.dtype),
                             pltpu.SemaphoreType.DMA((2, 2, 3))],
             compiler_params=_params("arbitrary", "arbitrary", "arbitrary"),
             interpret=interpret, name="dsa_attn_bwd",
-        )(qh, kh, vh, g_out.astype(qh.dtype), lse, delta, qih, ki, wi, tau,
-          cut, lsei, g_kl.astype(f32))
+        )(qh, kh, vh, g_out.astype(qh.dtype), lse, delta, qih, ki, wi,
+          _rows(wi), tau, cut, _rows(lsei), _rows(g_kl.astype(f32)))
         dk, dv_, dki = (_rounded(a, like.dtype) for a, like in (
             (dk, kh), (dv_, vh), (dki[..., :di], ki)))
     return dq, dk, dv_, dqi, dki, dwi[:, :, 0, :].transpose(0, 2, 1)

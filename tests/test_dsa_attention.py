@@ -176,22 +176,42 @@ def test_the_kernels_are_the_xla_path(ties):
         assert float(jnp.abs(g - r).max()) <= 2e-5 * scale
 
 
-@pytest.mark.parametrize("t,b,bq", [(512, 2, 128), (640, 1, 128),
-                                    (512, 1, 64)],
-                         ids=["four_q_blocks", "five_q_blocks",
-                              "two_q_blocks_a_k_block"])
-def test_the_one_backward_kernel_is_the_xla_path(t, b, bq, monkeypatch):
+@pytest.mark.parametrize("t,b,bq,ties", [
+    (512, 2, 128, False), (640, 1, 128, False), (512, 1, 64, False),
+    (640, 1, 128, True)],
+    ids=["four_q_blocks", "five_q_blocks", "two_q_blocks_a_k_block",
+         "planted_ties"])
+def test_the_one_backward_kernel_is_the_xla_path(t, b, bq, ties,
+                                                 monkeypatch):
     """`dsa_attn_bwd`, interpreted, at four and at five q blocks with bq =
     bk = 128 (q block 0 has one live tile, so k block 0 is written at grid
     step (0, 0) and read at the very next, (1, 0); an even and an odd
     count of tiles in a row's last slot), with two q blocks a k block (a
-    VMEM budget that holds 64 rows), and at a group of 8 query heads on
-    one key head: all six gradients against the XLA path."""
+    VMEM budget that holds 64 rows: a tile of 128 keys down and 64
+    queries along, which a swapped axis does not survive), and at a group
+    of 8 query heads on one key head: all six gradients against the XLA
+    path. Once more over five q blocks with PLANTED TIES at the
+    threshold (the key-major tiles of the backward and of the loss must
+    keep the very keys the q-major selection kept): there the pairs kept
+    and `kl` a query from `dsa_kl_fwd` too."""
     args = _inputs(t=t, h=8, hk=1, d=128, hi=2, di=64, b=b, seed=5)
+    if ties:
+        args = (*args[:3], jnp.round(args[3]), jnp.round(args[4]),
+                jnp.full_like(args[5], 0.25))
     monkeypatch.setattr(D, "_Q_BLOCK_BYTES", bq * 8 * 128 * 16)
     assert D.kernel_blocks(t, 128, 8, 128) == (bq, 128)
     call = lambda kernels: lambda *a: D.sparse_attention(
         *a, topk=48, block_k=128, kernels=kernels, interpret=True)
+    if ties:
+        scores = D.index_scores(*args[3:])[0]
+        kept = _topk_mask(scores, 48)
+        edge = jnp.sort(jnp.where(kept, scores, jnp.inf), axis=-1)[:, :1]
+        seen = jnp.arange(t)[None] <= jnp.arange(t)[:, None]
+        assert int(((scores == edge) & ~kept & seen).sum()) > 50
+        got, want = call(True)(*args), call(False)(*args)
+        np.testing.assert_array_equal(got[2], want[2])
+        np.testing.assert_allclose(got[1], want[1], atol=5e-6)
+        assert float(jnp.abs(want[1]).max()) > 1e-2
     w = jnp.cos(jnp.arange(args[0].size, dtype=jnp.float32)).reshape(
         args[0].shape)
     got = jax.grad(_loss_of(call(True), w), range(6))(*args)
@@ -200,6 +220,75 @@ def test_the_one_backward_kernel_is_the_xla_path(t, b, bq, monkeypatch):
         scale = float(jnp.abs(r).max())
         assert scale > 1e-4
         assert float(jnp.abs(g - r).max()) <= 2e-5 * scale
+
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for sub in value if isinstance(value, (list, tuple)) else [value]:
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr``, those of its nested ones included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _equations(sub)
+
+
+def test_the_key_major_kernels_head_loops_turn_nothing_they_need_not():
+    """What `dsa_attn_bwd` and `dsa_kl_fwd` do a QUERY HEAD and tile,
+    read off their jaxprs (no chip): the tile is (keys, queries), NO
+    product of the head loop contracts axis 0 of its left operand (the
+    key side's two sums are plain, and dq gathers transposed from a `k^T`
+    made once a tile; a q-major tile had two such products a head), and
+    no vector that lies along the lanes (a saved statistic: `lse`,
+    `delta`) is turned down the sublanes (the `[:, None]` of a q-major
+    tile)."""
+    heads, bq, bk = 8, 64, 256
+    q, k, v, qi, ki, wi = _inputs(t=512, h=heads, hk=2, d=128, hi=2, di=64,
+                                  b=1)
+    heads_first = [D._heads_first(a) for a in (q, k, v, qi)]
+    fwd = lambda *a: D._forward_kernels(*a, 32, bq, bk, True)
+    out, lse, tau, cut, lsei, kl, _ = jax.eval_shape(fwd, *heads_first, ki,
+                                                     wi)
+    res = (*heads_first, ki, wi, out, lse, tau, cut, lsei)
+    bwd = lambda res, g, gk: D._backward_kernels(res, g, gk, bq, bk, True)
+    kernels = {
+        eqn.params["name"]: eqn.params["jaxpr"]
+        for traced in (jax.make_jaxpr(fwd)(*heads_first, ki, wi),
+                       jax.make_jaxpr(bwd)(res, out, kl))
+        for eqn in _equations(traced.jaxpr)
+        if eqn.primitive.name == "pallas_call"}
+    assert set(kernels) == {"dsa_index", "dsa_select", "dsa_attn_fwd",
+                            "dsa_kl_fwd", "dsa_attn_bwd"}
+    for name, products in (("dsa_attn_bwd", 5), ("dsa_kl_fwd", 1)):
+        # the loops over the query heads: the tile's, which holds the
+        # products, and (the backward) the one that writes dq out
+        loops = [list(_equations(eqn.params["jaxpr"].jaxpr))
+                 for eqn in _equations(kernels[name])
+                 if eqn.primitive.name == "scan"
+                 and eqn.params["length"] == heads]
+        body, = [loop for loop in loops if any(
+            eqn.primitive.name == "dot_general" for eqn in loop)]
+        dots = [eqn for eqn in body if eqn.primitive.name == "dot_general"]
+        assert len(dots) == products, name
+        tiles = {eqn.outvars[0].aval.shape for eqn in dots}
+        assert (bk, bq) in tiles and (bq, bk) not in tiles, name
+        assert not [eqn for eqn in dots
+                    if eqn.params["dimension_numbers"][0][0] == (0,)], name
+        for eqn in body:
+            if eqn.primitive.name not in ("broadcast_in_dim", "reshape"):
+                continue
+            src, dst = eqn.invars[0].aval.shape, eqn.outvars[0].aval.shape
+            if not src or src[-1] == 1:
+                continue                       # a scalar, or a column
+            at = eqn.params["broadcast_dimensions"][-1] \
+                if eqn.primitive.name == "broadcast_in_dim" \
+                else (len(dst) - 1 if dst[-1] == src[-1] else None)
+            assert at == len(dst) - 1, (name, eqn)
 
 
 def test_the_backward_kernels_copies_are_waited_for():

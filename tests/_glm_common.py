@@ -1,8 +1,8 @@
 """What the three files of tests of the GLM-4.7-Flash family share
 (`test_glm_moe_attention.py`, `test_glm_moe_experts.py`,
 `test_glm_moe_model.py`: one file a worker under `--dist loadfile`): the
-published keys at test widths and the seeded rows; the byte budgets, the
-host rows and the closeness helper are `_kimi_common.py`'s.
+published keys at test widths and the family's record; the byte budgets,
+the host rows and the checks' bodies are `_lm_common.py`'s.
 
 The zoo model (`Glm4MoeLiteLM`: rotated latent attention with a low-rank
 query in every layer, a dense SwiGLU layer then sigmoid-routed SwiGLU
@@ -15,6 +15,8 @@ The reference (`benchmark/references/glm-4.7-flash.py`) imports nothing of
 the program; weights are the reference's seeded ones.
 """
 from benchmark.lib.manifest import load_module
+
+from _lm_common import Family, score_is_the_loss
 
 REF = load_module("references", "glm-4.7-flash")
 SYSTEM = load_module("systems", "dl4j_fit_glm_moe_lite")
@@ -44,17 +46,24 @@ CFG = {
     "compute_dtype": None, "gradient_checkpointing": True,
 }
 T = REF.seq_length(CFG)        # 128
-STAGES = ("embed", "layer0", "layer1", "layer2", "mtp", "head")
 
 
-def _net(cfg=CFG, **over):
-    cfg = {**cfg, **over}
-    return SYSTEM.build(cfg, REF.make_params(cfg)), cfg
-
-
-def _batch(cfg, rows):
+def _example(ref, cfg, rows):
     """(ids, the label arrays, their masks) of one host batch."""
-    ids = REF.decode_tokens(cfg, rows)
-    labels, keep = REF.targets(ids)
+    ids = ref.decode_tokens(cfg, rows)
+    labels, keep = ref.targets(ids)
     n = 1 + cfg["num_nextn_predict_layers"]
     return ids, labels[:n], keep[:n]
+
+
+#: two weighted outputs: the labels and masks are tuples already
+FAMILY = Family(
+    ref=REF, system=SYSTEM, cfg=CFG,
+    stages=("embed", "layer0", "layer1", "layer2", "mtp", "head"),
+    ref_loss=score_is_the_loss(
+        lambda cfg, params, example: REF.loss_fn(cfg, params, example[0])),
+    example_of=_example,
+    operands=lambda example: ((example[0],), example[1], example[2]),
+    scopes=("mla/proj", "mla/rope", "mla/attn", "moe/route", "moe/dispatch",
+            "moe/experts", "moe/shared", "moe/combine", "mlp/gated",
+            "head/loss", "opt/update", "mtp"))

@@ -1,7 +1,8 @@
 """What the files of tests of the Keye-VL-2.0 language model share
 (`test_keye_model.py`, `test_dsa_attention.py`: one file a worker under
-`--dist loadfile`): the published keys at test widths; the byte budgets,
-the host rows and the closeness helper are `_kimi_common.py`'s.
+`--dist loadfile`): the published keys at test widths and the family's
+record; the byte budgets, the host rows and the checks' bodies are
+`_lm_common.py`'s.
 
 The zoo model (`KeyeVL2LM`: grouped-query attention whose heads are wider
 than the stream's share, q/k-normed, rotated by three rows of positions,
@@ -14,6 +15,8 @@ The reference (`benchmark/references/keye-vl-2.0-30b-a3b.py`) imports
 nothing of the program; weights are the reference's seeded ones.
 """
 from benchmark.lib.manifest import load_module
+
+from _lm_common import Family
 
 REF = load_module("references", "keye-vl-2.0-30b-a3b")
 SYSTEM = load_module("systems", "dl4j_fit_keye_vl2")
@@ -47,16 +50,16 @@ CFG = {
     "compute_dtype": None, "gradient_checkpointing": True,
 }
 T = REF.seq_length(CFG)        # 128
-STAGES = ("embed", "layer0", "layer1", "layer2", "indexer", "head")
 
-
-def _net(cfg=CFG, **over):
-    cfg = {**cfg, **over}
-    return SYSTEM.build(cfg, REF.make_params(cfg)), cfg
-
-
-def _batch(cfg, rows):
-    """(ids, next-token labels, their mask) of one host batch."""
-    ids = REF.decode_tokens(cfg, rows)
-    nxt, keep = REF.targets(ids)
-    return ids, nxt, keep
+#: ONE sequence a batch (the cell's), which the reference takes alone; its
+#: `loss_fn` gives (CE + the indexers' losses, (CE, those losses by layer));
+#: the bf16 program picks other keys where scores lie within rounding of
+#: the threshold (5 %), and a fault may show in the indexers' moment alone
+FAMILY = Family(
+    ref=REF, system=SYSTEM, cfg=CFG,
+    stages=("embed", "layer0", "layer1", "layer2", "indexer", "head"),
+    ref_loss=lambda cfg, params, example: REF.loss_fn(cfg, params,
+                                                      example[0][0]),
+    ref_logits=lambda cfg, params, example: REF.logits(
+        cfg, params, example[0][0])[None],
+    sequences=1, bf16_stage_gap=5e-2, fault_gap=1e-3, fault_by_stage=True)

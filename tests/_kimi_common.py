@@ -1,7 +1,8 @@
 """What the three files of tests of the hybrid LM share
 (`test_kimi_attention.py`, `test_kimi_experts.py`, `test_kimi_model.py`:
 one file a worker under `--dist loadfile`, ROADMAP D12): the published
-keys at test widths, the byte budgets, the seeded rows.
+keys at test widths and the family's record; the byte budgets, the seeded
+rows and the checks' bodies are `_lm_common.py`'s.
 
 The hybrid LM (`KimiLinearLM`: Kimi Delta Attention three layers to one
 of position-free latent attention, a dense SwiGLU layer then sigmoid-routed
@@ -12,10 +13,9 @@ CPU in float32, and the pieces it is made of.
 The reference (`benchmark/references/kimi-linear-48b-a3b.py`) imports
 nothing of the program; weights are the reference's seeded ones.
 """
-import numpy as np
-import pytest
-
 from benchmark.lib.manifest import load_module
+
+from _lm_common import Family, score_is_the_loss
 
 REF = load_module("references", "kimi-linear-48b-a3b")
 SYSTEM = load_module("systems", "dl4j_fit_kimi_linear")
@@ -48,46 +48,17 @@ CFG = {
 T = REF.seq_length(CFG)        # 128
 KINDS = REF.layer_kinds(CFG)
 
-
-@pytest.fixture(autouse=True)
-def _budgets_at_the_tests_sizes(monkeypatch):
-    """The layers work out from their shapes how much goes through at
-    once; at the tests' sizes everything would. The budgets are cut so
-    that the whole model (2 x 128 tokens, 8 (sequence, head) pairs) takes
-    the paths the cell's sizes take: 2 groups of pairs, 4 dispatches of 64
-    tokens, loss blocks of 64 positions."""
-    from deeplearning4j_tpu.nn.layers import (
-        attention, linear_attention, recurrent,
-    )
-    monkeypatch.setattr(linear_attention, "_SCAN_LIVE_BYTES",
-                        4 * 20 * 128 * 16 * 4)
-    monkeypatch.setattr(attention, "_DISPATCH_LIVE_BYTES",
-                        64 * 2 * (2 * 32 + 2 * 24) * 4)
-    monkeypatch.setattr(recurrent, "_LOSS_LIVE_BYTES", 64 * 96 * 8)
-
-
-def _rows(seed, n, batch=2):
-    rng = np.random.default_rng(seed)
-    return [(np.frombuffer(rng.bytes(batch * 8 * 8 * 4), np.uint8).reshape(
-        batch, 8, 8, 4), np.zeros((batch, 1), np.float32))
-        for _ in range(n)]
-
-
-def _net(cfg=CFG, **over):
-    cfg = {**cfg, **over}
-    return SYSTEM.build(cfg, REF.make_params(cfg)), cfg
-
-
-def _batch(cfg, rows):
-    ids = REF.decode_tokens(cfg, rows)
-    nxt, keep = REF.targets(ids)
-    return ids, nxt, keep
-
-
-def _close(got, want, tol):
-    got, want = np.asarray(got), np.asarray(want)
-    assert np.abs(got - want).max() <= tol * max(np.abs(want).max(), 1e-30)
-
+#: a `MultiLayerNetwork`: ids, labels and mask as they are
+FAMILY = Family(
+    ref=REF, system=SYSTEM, cfg=CFG,
+    stages=("embed", "layer1", "layer2", "layer3", "layer4", "layer5",
+            "head"),
+    ref_loss=score_is_the_loss(
+        lambda cfg, params, example: REF.loss_fn(cfg, params, example[0])),
+    operands=lambda example: example,
+    scopes=("kda/proj", "kda/scan", "kda/out", "mla/proj", "mla/attn",
+            "moe/route", "moe/dispatch", "moe/experts", "moe/shared",
+            "moe/combine", "head/loss", "opt/update"))
 
 
 def _layer_params(i):

@@ -1,7 +1,8 @@
 """What the files of tests of the LFM2 mixture-of-experts family share
 (`test_lfm2_layers.py`, `test_lfm2_model.py`: one file a worker under
-`--dist loadfile`): the published keys at test widths; the byte budgets,
-the host rows and the closeness helper are `_kimi_common.py`'s.
+`--dist loadfile`): the published keys at test widths and the family's
+record; the byte budgets, the host rows and the checks' bodies are
+`_lm_common.py`'s.
 
 The zoo model (`Lfm2MoeLM`: gated short convolutions three to one with
 q/k-normed, rotated grouped-query attention, a dense SwiGLU layer then
@@ -14,6 +15,8 @@ The reference (`benchmark/references/lfm2-24b-a2b.py`) imports nothing of
 the program; weights are the reference's seeded ones.
 """
 from benchmark.lib.manifest import load_module
+
+from _lm_common import Family, score_is_the_loss
 
 REF = load_module("references", "lfm2-24b-a2b")
 SYSTEM = load_module("systems", "dl4j_fit_lfm2_moe")
@@ -46,16 +49,14 @@ CFG = {
 }
 T = REF.seq_length(CFG)        # 128
 KINDS = REF.layer_kinds(CFG)
-STAGES = ("layer0", "layer1", "layer2", "layer3", "layer4", "head")
 
-
-def _net(cfg=CFG, **over):
-    cfg = {**cfg, **over}
-    return SYSTEM.build(cfg, REF.make_params(cfg)), cfg
-
-
-def _batch(cfg, rows):
-    """(ids, next-token labels, their mask) of one host batch."""
-    ids = REF.decode_tokens(cfg, rows)
-    nxt, keep = REF.targets(ids)
-    return ids, nxt, keep
+FAMILY = Family(
+    ref=REF, system=SYSTEM, cfg=CFG,
+    stages=("layer0", "layer1", "layer2", "layer3", "layer4", "head"),
+    ref_loss=score_is_the_loss(
+        lambda cfg, params, example: REF.loss_fn(cfg, params, example[0])),
+    ref_logits=lambda cfg, params, example: REF.logits(cfg, params,
+                                                       example[0]),
+    scopes=("sconv/proj", "sconv/mix", "mha/proj", "mha/norm", "mha/rope",
+            "mha/attn", "moe/route", "moe/dispatch", "moe/experts",
+            "moe/combine", "mlp/gated", "head/loss", "opt/update"))

@@ -1,8 +1,9 @@
 """What the files of tests of the block-diffusion LM share
 (`test_sdar_model.py`, `test_block_diffusion_attention.py`,
 `test_denoise.py`: one file a worker under `--dist loadfile`): the
-published keys at test widths and the brute-force visibility; the byte
-budgets, the host rows and the closeness helper are `_kimi_common.py`'s.
+published keys at test widths, the family's record and the brute-force
+visibility; the byte budgets, the host rows and the checks' bodies are
+`_lm_common.py`'s.
 
 The zoo model (`SdarMoeLM`: grouped-query attention whose heads are wider
 than the stream's share, q/k-normed, rotated at positions that restart at
@@ -20,6 +21,8 @@ copy of the rule.
 import numpy as np
 
 from benchmark.lib.manifest import load_module
+
+from _lm_common import Family, score_is_the_loss
 
 REF = load_module("references", "sdar-30b-a3b-chat")
 SYSTEM = load_module("systems", "dl4j_fit_sdar_moe")
@@ -48,12 +51,31 @@ CFG = {
     "compute_dtype": None, "gradient_checkpointing": True,
 }
 L = REF.seq_length(CFG)        # 128
-STAGES = ("embed", "layer0", "layer1", "layer2", "head")
 
 
-def _net(cfg=CFG, **over):
-    cfg = {**cfg, **over}
-    return SYSTEM.build(cfg, REF.make_params(cfg)), cfg
+def _example(ref, cfg, rows, seed=5, first=0):
+    """(stream, targets, weights) of one host batch, the reference's."""
+    return ref.targets(cfg, ref.decode_tokens(cfg, rows), seed, first)
+
+
+def _mean_loss(cfg, params, example):
+    """The reference takes a sequence; a batch's loss is their mean."""
+    stream, y, w = example
+    return sum(REF.loss_fn(cfg, params, *one) for one in zip(stream, y, w)) \
+        / len(stream)
+
+
+#: an example is (stream, targets, weights); the reference's logits are the
+#: first sequence's; bfloat16 on ONE sequence, to 5 %; a fault may show in a
+#: stage's moment alone; an expert layer takes the stream's 2L rows
+FAMILY = Family(
+    ref=REF, system=SYSTEM, cfg=CFG,
+    stages=("embed", "layer0", "layer1", "layer2", "head"),
+    ref_loss=score_is_the_loss(_mean_loss), example_of=_example,
+    ref_logits=lambda cfg, params, example: REF.logits(
+        cfg, params, example[0][0])[None],
+    bf16_sequences=1, bf16_stage_gap=5e-2, fault_gap=1e-3,
+    fault_by_stage=True, layer_rows=2 * L)
 
 
 def brute_force_visible(length, block):

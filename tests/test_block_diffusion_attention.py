@@ -18,6 +18,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
 )
 from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
+from _lm_common import with_gradients as _with_gradients
 from _sdar_common import brute_force_visible
 
 FLASH = sys.modules["deeplearning4j_tpu.ops.flash_attention"]
@@ -127,11 +128,9 @@ def test_the_kernels_forward_and_the_three_gradients(length, block, bq, bk,
     seen = jnp.asarray(brute_force_visible(length, block))
     run = lambda q, k, v: flash_attention(
         q, k, v, block_diffusion=block, block_q=bq, block_k=bk)
-    np.testing.assert_allclose(run(q, k, v), _dense(q, k, v, seen),
-                               atol=2e-6)
-    got = jax.grad(lambda *a: jnp.sum(run(*a) * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(_dense(*a, seen) * w),
-                    (0, 1, 2))(q, k, v)
+    out, got = _with_gradients(run, w, (q, k, v))
+    ref, want = _with_gradients(lambda *a: _dense(*a, seen), w, (q, k, v))
+    np.testing.assert_allclose(out, ref, atol=2e-6)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
@@ -207,11 +206,9 @@ def test_the_xla_path_computes_the_rule(block_size):
     seen = jnp.asarray(brute_force_visible(32, 4))
     run = lambda q, k, v: block_diffusion_attention(
         q, k, v, block=4, block_size=block_size)
-    np.testing.assert_allclose(run(q, k, v), _dense(q, k, v, seen),
-                               atol=2e-6)
-    got = jax.grad(lambda *a: jnp.sum(run(*a) * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(_dense(*a, seen) * w),
-                    (0, 1, 2))(q, k, v)
+    out, got = _with_gradients(run, w, (q, k, v))
+    ref, want = _with_gradients(lambda *a: _dense(*a, seen), w, (q, k, v))
+    np.testing.assert_allclose(out, ref, atol=2e-6)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
@@ -306,10 +303,12 @@ def test_no_leak_through_the_attention(impl, monkeypatch):
     params, state = layer.init(jax.random.PRNGKey(1),
                                InputType.recurrent(32, 64))
     x = jax.random.normal(jax.random.PRNGKey(2), (1, 64, 32))
+    # the block's rows picked by a 0/1 weight: one program for the three
+    grad = jax.jit(jax.grad(lambda x, picked: jnp.sum(jnp.square(
+        layer.apply(params, state, x)[0]) * picked[None, :, None])))
     for blk in (0, 3, 7):
         rows = slice(4 * blk, 4 * blk + 4)
-        g = jax.grad(lambda x: jnp.sum(jnp.square(
-            layer.apply(params, state, x)[0][:, rows])))(x)
+        g = grad(x, jnp.zeros(64).at[rows].set(1.0))
         clean, noisy = np.asarray(g[0, 32:]), np.asarray(g[0, :32])
         assert not np.any(clean[4 * blk:]), (impl, blk)
         assert blk == 0 or np.any(clean[:4 * blk])

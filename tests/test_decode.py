@@ -312,14 +312,18 @@ def test_late_join_streams_before_batch_drains(served_lm):
 
 
 def test_eos_and_temperature_sampling(served_lm):
-    # greedy run to learn the deterministic 3rd token, then use it as eos
+    # greedy run to learn the deterministic tokens, then take for eos the
+    # first one that no earlier token is (the seeded model's greedy tokens
+    # repeat: the third is the first again, and an eos that stood earlier
+    # would end the run there)
     r = served_lm.generate([7, 8, 9], max_new_tokens=6)
     toks = [p for k, p, _ in drain_events(r) if k == "token"]
     assert len(toks) == 6
-    r = served_lm.generate([7, 8, 9], max_new_tokens=6, eos_id=toks[2])
+    at = next(i for i in range(1, 6) if toks[i] not in toks[:i])
+    r = served_lm.generate([7, 8, 9], max_new_tokens=6, eos_id=toks[at])
     evs = drain_events(r)
     assert evs[-1][1]["finish_reason"] == "eos"
-    assert [p for k, p, _ in evs if k == "token"] == toks[:2]
+    assert [p for k, p, _ in evs if k == "token"] == toks[:at]
     # sampled run stays in-vocab and honors the token budget
     r = served_lm.generate([7, 8, 9], max_new_tokens=5, temperature=1.3,
                            top_k=5)

@@ -136,9 +136,16 @@ def test_the_indexers_loss_and_its_gradient_are_the_plain_formulas():
         assert not np.any(np.asarray(g))
 
 
-def _loss_of(call, w):
-    return lambda *a: (lambda r: jnp.sum(r[0] * w) + 3.0 * jnp.mean(r[1]))(
-        call(*a))
+def _with_gradients(call, w, args):
+    """(output, loss a query, pairs kept a query) of ``call`` and the six
+    gradients of a weighting of the first two: one forward and one
+    backward, one compiled program."""
+    def loss(*a):
+        r = call(*a)
+        return jnp.sum(r[0] * w) + 3.0 * jnp.mean(r[1]), r
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        loss, range(6), has_aux=True))(*args)
+    return out, grads
 
 
 @pytest.mark.parametrize("ties", [False, True])
@@ -154,8 +161,10 @@ def test_the_kernels_are_the_xla_path(ties):
         wi = jnp.full_like(wi, 0.25)
     call = lambda kernels: lambda *a: D.sparse_attention(
         *a, topk=32, block_k=128, kernels=kernels, interpret=True)
-    want = call(False)(q, k, v, qi, ki, wi)
-    got = call(True)(q, k, v, qi, ki, wi)
+    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+    args = (q, k, v, qi, ki, wi)
+    got, got_g = _with_gradients(call(True), w, args)
+    want, want_g = _with_gradients(call(False), w, args)
     np.testing.assert_allclose(got[0], want[0], atol=2e-6)
     np.testing.assert_allclose(got[1], want[1], atol=5e-6)
     np.testing.assert_array_equal(got[2], want[2])
@@ -167,11 +176,7 @@ def test_the_kernels_are_the_xla_path(ties):
         assert int(((scores == edge) & ~_topk_mask(scores, 32)
                     & (jnp.arange(256)[None] <= jnp.arange(256)[:, None])
                     ).sum()) > 50       # ties the selection had to break
-    w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
-    for g, r in zip(
-            jax.grad(_loss_of(call(True), w), range(6))(q, k, v, qi, ki, wi),
-            jax.grad(_loss_of(call(False), w), range(6))(q, k, v, qi, ki,
-                                                         wi)):
+    for g, r in zip(got_g, want_g):
         scale = max(float(jnp.abs(r).max()), 1e-6)
         assert float(jnp.abs(g - r).max()) <= 2e-5 * scale
 
@@ -202,21 +207,20 @@ def test_the_one_backward_kernel_is_the_xla_path(t, b, bq, ties,
     assert D.kernel_blocks(t, 128, 8, 128) == (bq, 128)
     call = lambda kernels: lambda *a: D.sparse_attention(
         *a, topk=48, block_k=128, kernels=kernels, interpret=True)
+    w = jnp.cos(jnp.arange(args[0].size, dtype=jnp.float32)).reshape(
+        args[0].shape)
+    got, got_g = _with_gradients(call(True), w, args)
+    want, want_g = _with_gradients(call(False), w, args)
     if ties:
         scores = D.index_scores(*args[3:])[0]
         kept = _topk_mask(scores, 48)
         edge = jnp.sort(jnp.where(kept, scores, jnp.inf), axis=-1)[:, :1]
         seen = jnp.arange(t)[None] <= jnp.arange(t)[:, None]
         assert int(((scores == edge) & ~kept & seen).sum()) > 50
-        got, want = call(True)(*args), call(False)(*args)
         np.testing.assert_array_equal(got[2], want[2])
         np.testing.assert_allclose(got[1], want[1], atol=5e-6)
         assert float(jnp.abs(want[1]).max()) > 1e-2
-    w = jnp.cos(jnp.arange(args[0].size, dtype=jnp.float32)).reshape(
-        args[0].shape)
-    got = jax.grad(_loss_of(call(True), w), range(6))(*args)
-    want = jax.grad(_loss_of(call(False), w), range(6))(*args)
-    for g, r in zip(got, want):
+    for g, r in zip(got_g, want_g):
         scale = float(jnp.abs(r).max())
         assert scale > 1e-4
         assert float(jnp.abs(g - r).max()) <= 2e-5 * scale

@@ -8,6 +8,14 @@ import pytest
 from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
 from deeplearning4j_tpu.ops import REMAT_KEEP, flash_attention
 
+from _lm_common import with_gradients as _with_gradients
+
+
+def _grads(loss, args):
+    """The gradient of ``loss`` in q, k and v, compiled as one program
+    (the dense path takes three times as long op by op)."""
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(*args)
+
 
 def _qkv(b=2, t=48, h=4, d=16, seed=0, dtype="float32"):
     rs = np.random.RandomState(seed)
@@ -68,8 +76,8 @@ def test_flash_gradients_match_dense():
         return jnp.sum(dot_product_attention(q, k, v, mask=mask,
                                              causal=True) ** 2)
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    gf = _grads(loss_flash, (q, k, v))
+    gd = _grads(loss_dense, (q, k, v))
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4)
@@ -97,8 +105,8 @@ def test_mha_flash_impl_matches_dense_and_trains():
         y, _ = layer.apply(p, state, x, mask=mask)
         return jnp.sum(y ** 2)
 
-    gd = jax.grad(loss)(params, dense)
-    gf = jax.grad(loss)(params, flash)
+    gd = jax.jit(jax.grad(loss), static_argnums=1)(params, dense)
+    gf = jax.jit(jax.grad(loss), static_argnums=1)(params, flash)
     for key in params:
         np.testing.assert_allclose(np.asarray(gf[key]), np.asarray(gd[key]),
                                    atol=2e-4, rtol=2e-4, err_msg=key)
@@ -120,8 +128,8 @@ def test_flash_cross_attention_gradients():
     def loss_dense(q, k, v):
         return jnp.sum(dot_product_attention(q, k, v, mask=mask) ** 2)
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    gf = _grads(loss_flash, (q, k, v))
+    gd = _grads(loss_dense, (q, k, v))
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4)
@@ -191,8 +199,8 @@ def test_flash_lse_merge_trains_correctly():
     def loss_dense(q, k, v):
         return jnp.sum(dot_product_attention(q, k, v) ** 2)
 
-    gm = jax.grad(loss_merged, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    gm = _grads(loss_merged, (q, k, v))
+    gd = _grads(loss_dense, (q, k, v))
     for a, b in zip(gm, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-4, rtol=5e-4)
@@ -320,26 +328,40 @@ def _backward_kernels(form, calls):
     return kernels
 
 
+def _walk(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs too (each use of
+    a shared one: the printed text shows it only once)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)
+
+
 def _eqns(fn, args):
-    """Every equation of the traced fn, those of its sub-jaxprs too (each
-    use of a shared one: the printed text shows it only once)."""
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            yield eqn
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from walk(sub)
-
-    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    """Every equation of the traced fn."""
+    return _walk(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
-def _kernel_calls(fn, args):
-    """{kernel name: Pallas calls} in the traced fn."""
+def _pallas_calls(eqns):
+    """{kernel name: Pallas calls} among ``eqns``."""
     calls = {}
-    for eqn in _eqns(fn, args):
+    for eqn in eqns:
         if eqn.primitive.name == "pallas_call":
             name = eqn.params["name"]
             calls[name] = calls.get(name, 0) + 1
     return calls
+
+
+def _kernel_calls(fn, args):
+    """{kernel name: Pallas calls} in the traced fn."""
+    return _pallas_calls(_eqns(fn, args))
+
+
+def _run_and_count(fn, args):
+    """(fn(*args) compiled, its kernel calls) from ONE trace of fn."""
+    traced = jax.jit(fn).trace(*args)
+    return (traced.lower().compile()(*args),
+            _pallas_calls(_walk(traced.jaxpr.jaxpr)))
 
 
 @pytest.mark.parametrize("return_lse", [False, True])
@@ -357,9 +379,10 @@ def test_checkpointed_block_runs_the_forward_kernel_once(causal, with_mask,
     bare, _ = _two_layers(causal, with_mask, t, False, return_lse)
     grad = lambda f: jax.grad(f, argnums=(0, 1, 2))
     once = {"flash_fwd": 2, **_backward_kernels(flash_backward, 2)}
-    assert _kernel_calls(grad(kept), args) == once
-    assert _kernel_calls(grad(bare), args) == once
-    for a, b in zip(jax.jit(grad(kept))(*args), jax.jit(grad(bare))(*args)):
+    (got, got_calls), (want, want_calls) = (
+        _run_and_count(grad(f), args) for f in (kept, bare))
+    assert got_calls == once and want_calls == once
+    for a, b in zip(got, want):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -426,9 +449,9 @@ def test_grouped_kv_heads_forward_and_the_three_gradients(group, d, causal,
     dense = lambda q, k, v: dot_product_attention(
         q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
         causal=causal, mask=mask)
-    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=3e-6)
-    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    out, got = _with_gradients(flash, w, (q, k, v))
+    ref, want = _with_gradients(dense, w, (q, k, v))
+    np.testing.assert_allclose(out, ref, atol=3e-6)
     assert got[1].shape == k.shape and got[2].shape == v.shape
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=2e-5)
@@ -446,8 +469,8 @@ def _both_backwards(monkeypatch, loss, args):
     for form, budget in (("fused", module._RESIDENT_SUM_BYTES), ("pair", 0)):
         monkeypatch.setattr(module, "_RESIDENT_SUM_BYTES", budget)
         # a function of its own a form: jit keeps its traces by function
-        grad = jax.grad(lambda *a: loss(*a), (0, 1, 2))
-        got[form] = (jax.jit(grad)(*args), _kernel_calls(grad, args))
+        got[form] = _run_and_count(
+            jax.grad(lambda *a: loss(*a), (0, 1, 2)), args)
     return got
 
 
@@ -720,8 +743,9 @@ def test_each_tile_body_forward_and_the_three_gradients(case):
     dense = lambda q, k, v: dot_product_attention(
         q, jnp.repeat(k, h // hk, axis=2), jnp.repeat(v, h // hk, axis=2),
         causal=causal, mask=mask)
-    out, lse = flash(q, k, v)
-    np.testing.assert_allclose(out, dense(q, k, v), atol=3e-6)
+    (out, lse), got = _with_gradients(flash, w, (q, k, v))
+    ref, want = _with_gradients(dense, w, (q, k, v))
+    np.testing.assert_allclose(out, ref, atol=3e-6)
     # the log-sum-exp against the scores written out
     s = jnp.einsum("bqhd,bkhd->bqhk", q, jnp.repeat(k, h // hk, axis=2)) \
         / np.sqrt(q.shape[-1])
@@ -737,8 +761,6 @@ def test_each_tile_body_forward_and_the_three_gradients(case):
     if kind == "given":             # batch row 1: no valid key
         assert np.abs(np.asarray(out)[1]).max() == 0.0
         assert (np.asarray(lse)[1] == np.float32(-1e30)).all()
-    got = jax.grad(lambda *a: jnp.sum(flash(*a)[0] * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=2e-5)

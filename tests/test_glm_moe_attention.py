@@ -14,8 +14,8 @@ from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
 from deeplearning4j_tpu.nn.layers.linear_attention import _rms, rope_pairs
 
 from _glm_common import CFG, REF, T
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
-    _budgets_at_the_tests_sizes, _close,
+from _lm_common import (  # noqa: F401 (the autouse fixture)
+    _budgets_at_the_tests_sizes, _close, with_gradients,
 )
 
 
@@ -59,9 +59,9 @@ def test_mla_with_a_low_rank_query_and_rotation_is_the_references():
     w = jax.random.normal(jax.random.PRNGKey(2), (2, T, 32))
     run = lambda p, x: mla.apply(p, state, x)[0]
     ref = lambda p, x: REF._attention(CFG, p, x, "highest")
-    _close(run(p, x), ref(p, x), 3e-5)
-    got = jax.grad(lambda p, x: jnp.sum(run(p, x) * w), (0, 1))(p, x)
-    want = jax.grad(lambda p, x: jnp.sum(ref(p, x) * w), (0, 1))(p, x)
+    y, got = with_gradients(run, w, (p, x))
+    y_ref, want = with_gradients(ref, w, (p, x))
+    _close(y, y_ref, 3e-5)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
                             jax.tree_util.tree_leaves(want)):
         assert np.abs(np.asarray(a - b)).max() <= 1e-4 * max(
@@ -70,7 +70,7 @@ def test_mla_with_a_low_rank_query_and_rotation_is_the_references():
     # benchmark plants
     off = dataclasses.replace(mla, rotate=False).apply(p, state, x)[0]
     _close(off, REF._attention(CFG, p, x, "highest", fault="no_rope"), 3e-5)
-    assert float(jnp.abs(off - run(p, x)).max()) > 1e-3
+    assert float(jnp.abs(off - y).max()) > 1e-3
 
 
 def _todays_layer(self, params, x):
@@ -140,8 +140,8 @@ def test_flash_kernel_at_256_wide_heads_forward_and_backward(t, block):
     flash = lambda q, k, v: flash_attention(
         q, k, v, causal=True, block_q=block, block_k=block, interpret=True)
     dense = lambda q, k, v: dot_product_attention(q, k, v, causal=True)
-    _close(flash(q, k, v), dense(q, k, v), 2e-5)
-    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    out, got = with_gradients(flash, w, (q, k, v))
+    ref, want = with_gradients(dense, w, (q, k, v))
+    _close(out, ref, 2e-5)
     for a, b in zip(got, want):
         _close(a, b, 5e-5)

@@ -12,7 +12,7 @@ from deeplearning4j_tpu.nn.layers import (
 )
 
 from _glm_common import CFG, REF, T
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
+from _lm_common import (  # noqa: F401 (the autouse fixture)
     _budgets_at_the_tests_sizes, _close,
 )
 

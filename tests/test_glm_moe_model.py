@@ -13,15 +13,15 @@ from deeplearning4j_tpu.data.dataset import MultiDataSet
 from deeplearning4j_tpu.models import Glm4MoeLiteLM
 from deeplearning4j_tpu.nn.graph import ComputationGraph
 
-from _glm_common import CFG, REF, STAGES, SYSTEM, T, _batch, _net
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
+import _lm_common as lm
+from _glm_common import CFG, FAMILY, REF, SYSTEM, T
+from _lm_common import (  # noqa: F401 (the autouse fixture)
     _budgets_at_the_tests_sizes, _rows,
 )
 
 
-def _score(net, params, ids, labels, keep):
-    return net._score_fn(params, net.state, (ids,), labels, None, keep, True,
-                         jax.random.PRNGKey(0))[0]
+def _score(net, params, *example):
+    return FAMILY.score(net, params, example)
 
 
 # --------------------------------------------- the whole model through fit()
@@ -32,51 +32,18 @@ def test_two_adamw_steps_through_fit_match_the_reference(how):
     `train_steps`: the score (L_main + 0.3 L_mtp), AdamW's first moment by
     stage, and the update, as the benchmark's `correct` compares them; the
     shared embedding and head are updated as ONE leaf each, decayed once."""
-    from benchmark.lib import checks
-    net, cfg = _net()
-    rows = _rows(11, 2)
-    stamps = SYSTEM.stamp_listener()
-    net.set_listeners(stamps)
-    net.fit(SYSTEM.feed(rows), **how)
-    losses = [loss for _, loss in stamps.rows]
-    r_losses, r_m, r_params = REF.train_steps(cfg, REF.make_params(cfg),
-                                              rows)
-    np.testing.assert_allclose(losses, r_losses, rtol=2e-6)
-    init = jax.device_get(REF.make_params(cfg))
-    diff = lambda new: checks.leaf_norms(jax.tree_util.tree_map(
-        lambda a, b: np.asarray(a) - np.asarray(b), new, init))
-    prog = {"losses": losses, "update": diff(net.params),
-            "momentum": checks.leaf_norms(SYSTEM.momentum(net))}
-    ref = {"losses": r_losses, "update": diff(r_params),
-           "momentum": checks.leaf_norms(r_m)}
-    limits = {"loss_gap": 2e-6, "head_momentum_gap": 1e-4,
-              "head_update_gap": 1e-4, "update_norm_gap": 1e-5,
-              "stage_momentum_gap": {s: 1e-4 for s in STAGES}}
-    rows_ = checks.training_rows(prog, ref,
-                                 lambda leaf: REF.stage_of(cfg, leaf), limits)
-    assert len(rows_) == 4 + len(STAGES) and checks.verdict(rows_)
+    prog, ref = lm.two_adamw_steps_match(FAMILY, how)
     # leaf by leaf, the shared ones among them: decayed once, moved once
-    assert checks.worst_leaf_gap(prog["update"], ref["update"]) < 1e-3
     for leaf in ("['embed']['W']", "['head']['W']"):
         assert abs(prog["update"][leaf] - ref["update"][leaf]) \
             < 1e-4 * ref["update"][leaf]
 
 
 def test_loss_and_every_gradient_leaf_match_the_reference():
-    net, cfg = _net()
-    ids, labels, keep = _batch(cfg, _rows(4, 1)[0][0])
-    params = REF.make_params(cfg)
-    got_l, got = jax.value_and_grad(
-        lambda p: _score(net, p, ids, labels, keep))(params)
-    want_l, want = jax.value_and_grad(
-        lambda p: REF.loss_fn(cfg, p, ids))(params)
-    np.testing.assert_allclose(got_l, want_l, rtol=2e-6)
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
-                            jax.tree_util.tree_leaves(want)):
-        scale = max(np.abs(np.asarray(b)).max(), 1e-7)
-        assert np.abs(np.asarray(a - b)).max() <= 2e-4 * scale, \
-            jax.tree_util.keystr(path)
+    lm.every_gradient_leaf_matches(FAMILY)
     # both parts, each against the reference's own
+    net, cfg = FAMILY.reader()
+    (ids, labels, keep), params, *_ = lm.reference_gradient(FAMILY, 2)
     parts = net._score_parts(params, net.state, (ids,), labels, None, keep,
                              True, jax.random.PRNGKey(0))[2]
     np.testing.assert_allclose(parts, REF.losses(cfg, params, ids),
@@ -87,27 +54,16 @@ def test_bfloat16_compute_stays_near_the_reference():
     """bf16 operands over float32 weights, as the cell runs: the score to
     half a percent of the float32 reference's, every stage's gradient
     norm to 3 %."""
-    from benchmark.lib import checks
-    net, cfg = _net(compute_dtype="bfloat16")
-    ids, labels, keep = _batch(cfg, _rows(4, 1)[0][0])
-    params = REF.make_params(cfg)
-    got_l, got = jax.value_and_grad(
-        lambda p: _score(net, p, ids, labels, keep))(params)
-    want_l, want = jax.value_and_grad(
-        lambda p: REF.loss_fn(cfg, p, ids))(params)
-    assert abs(float(got_l) - float(want_l)) < 5e-3 * float(want_l)
-    gaps = checks.stage_gaps(checks.leaf_norms(got), checks.leaf_norms(want),
-                             lambda leaf: REF.stage_of(cfg, leaf))
-    assert set(gaps) == set(STAGES) and max(gaps.values()) < 3e-2, gaps
+    lm.bfloat16_stays_near(FAMILY)
 
 
 def test_without_the_module_the_zoo_model_is_the_plain_trunk():
     """``num_nextn_predict_layers`` 0: one output, the trunk's leaves
     alone, and its loss is the reference's L_main of the same trunk."""
-    net, cfg = _net(num_nextn_predict_layers=0)
+    net, cfg = FAMILY.net(num_nextn_predict_layers=0)
     assert net.conf.network_outputs == ("head",)
     assert not any(k.startswith("mtp_") for k in net.conf.vertices)
-    ids, labels, keep = _batch(cfg, _rows(4, 1)[0][0])
+    ids, labels, keep = FAMILY.example(cfg, _rows(4, 1)[0][0])
     assert len(labels) == 1
     full = REF.make_params(CFG)
     assert set(net.params) == {k for k in full if not k.startswith("mtp_")}
@@ -130,12 +86,8 @@ def test_planted_faults_move_what_correct_compares():
     the score far more than float32 rounding."""
     assert REF.FAULTS == ("half_batch", "no_rope", "mtp_unshifted",
                           "no_renorm")
-    rows = _rows(11, 2)
-    sound = REF.train_steps(CFG, REF.make_params(CFG), rows)
     for fault in REF.FAULTS:
-        bad = REF.train_steps(CFG, REF.make_params(CFG), rows, fault=fault)
-        gap = max(abs(a - b) / abs(b) for a, b in zip(bad[0], sound[0]))
-        assert gap > 1e-4, (fault, gap)
+        lm.a_planted_fault_moves(FAMILY, fault)
 
 
 # ------------------------------------------------------------ the shared leaf
@@ -152,8 +104,8 @@ def _untied(net):
 
 
 def test_a_shared_leaf_is_one_leaf_and_its_gradient_the_sum_of_both_uses():
-    net, cfg = _net()
-    ids, labels, keep = _batch(cfg, _rows(4, 1)[0][0])
+    net, cfg = FAMILY.net()
+    ids, labels, keep = FAMILY.example(cfg, _rows(4, 1)[0][0])
     shares = {name: vd.params_of for name, vd in net.conf.vertices.items()
               if vd.params_of}
     assert shares == {"mtp_embed": "embed", "mtp_head": "head"}
@@ -176,9 +128,10 @@ def test_a_shared_leaf_is_one_leaf_and_its_gradient_the_sum_of_both_uses():
     twin = _untied(net)
     assert twin.num_params() == net.num_params() \
         + net.params["embed"]["W"].size + net.params["head"]["W"].size
-    tied = jax.grad(lambda p: _score(net, p, ids, labels, keep))(net.params)
-    loose = jax.grad(lambda p: _score(twin, p, ids, labels, keep))(
-        twin.params)
+    tied = jax.jit(jax.grad(
+        lambda p: _score(net, p, ids, labels, keep)))(net.params)
+    loose = jax.jit(jax.grad(
+        lambda p: _score(twin, p, ids, labels, keep)))(twin.params)
     for owner, sharer in (("embed", "mtp_embed"), ("head", "mtp_head")):
         both = loose[owner]["W"] + loose[sharer]["W"]
         assert float(jnp.abs(loose[sharer]["W"]).max()) > 1e-6
@@ -227,8 +180,8 @@ def test_a_graph_without_sharing_keeps_its_configuration_as_it_was():
 
 def test_checkpoint_round_trip_saves_a_shared_leaf_once(tmp_path):
     from deeplearning4j_tpu.util.serialization import load_model, save_model
-    net, cfg = _net()
-    ids, labels, keep = _batch(cfg, _rows(7, 1)[0][0])
+    net, cfg = FAMILY.net()
+    ids, labels, keep = FAMILY.example(cfg, _rows(7, 1)[0][0])
     net.fit([MultiDataSet((ids,), labels, None, keep)] * 2, scan_steps=2)
     path = os.path.join(tmp_path, "glm.zip")
     save_model(net, path)
@@ -275,8 +228,8 @@ def test_positions_without_a_target_reach_neither_loss():
     """What stands at the masked positions (the labels there, and the
     token a sequence's last position would have embedded) moves neither
     part of the score; a token that IS a target moves both."""
-    net, cfg = _net()
-    ids, labels, keep = _batch(cfg, _rows(9, 1)[0][0])
+    net, cfg = FAMILY.net()
+    ids, labels, keep = FAMILY.example(cfg, _rows(9, 1)[0][0])
     parts = lambda ids, labels: np.asarray(net._score_parts(
         net.params, net.state, (ids,), labels, None, keep, True,
         jax.random.PRNGKey(0))[2])
@@ -285,7 +238,7 @@ def test_positions_without_a_target_reach_neither_loss():
     np.testing.assert_array_equal(parts(ids, junk), base)
     moved = ids.copy()
     moved[:, -1] = (moved[:, -1] + 1) % cfg["vocab_size"]
-    new_labels = _batch(cfg, _rows(9, 1)[0][0])[1]
+    new_labels = FAMILY.example(cfg, _rows(9, 1)[0][0])[1]
     new_labels = tuple(np.where(
         np.roll(np.arange(T) == T - 1, -(i + 1))[None, :],
         np.roll(moved, -(i + 1), axis=1), lab)
@@ -298,19 +251,9 @@ def test_positions_without_a_target_reach_neither_loss():
 
 
 # ---------------------------------------------------------- counters, ledger
-SCOPES = ("mla/proj", "mla/rope", "mla/attn", "moe/route", "moe/dispatch",
-          "moe/experts", "moe/shared", "moe/combine", "mlp/gated",
-          "head/loss", "opt/update", "mtp")
-
-
 def test_the_adapter_reads_the_counters_the_losses_and_the_steps_scopes():
     from deeplearning4j_tpu import monitor
-    from deeplearning4j_tpu.monitor import xla
-    net, cfg = _net()
-    net.set_listeners(SYSTEM.stamp_listener())
-    xla.enable_ledger()
-    try:
-        net.fit(SYSTEM.feed(_rows(6, 4)), scan_steps=2)
+    with lm.fitted_under_the_ledger(FAMILY) as net:
         dump = monitor.dump()
         layers = {"layer1", "layer2", "mtp_block"}
         load = dump["moe_expert_load_max_over_mean"]["series"]
@@ -330,34 +273,29 @@ def test_the_adapter_reads_the_counters_the_losses_and_the_steps_scopes():
                  for s in dump["train_output_loss"]["series"]}
         assert set(parts) == {"head", "mtp_head"}
         np.testing.assert_allclose(
-            parts["head"] + cfg["mtp_loss_weight"] * parts["mtp_head"],
+            parts["head"] + CFG["mtp_loss_weight"] * parts["mtp_head"],
             net.score(), rtol=1e-6)
-        scopes = SYSTEM.op_scopes()
-        seen = {m for m in SCOPES if any(m in s for s in scopes.values())}
-        assert seen == set(SCOPES), set(SCOPES) - seen
         # the module's inner ops keep their inner scopes under its own,
         # forward and backward; the trunk's carry no "mtp"
-        inner = {m for m in SCOPES[:-1] for s in scopes.values()
+        scopes = SYSTEM.op_scopes()
+        inner = {m for m in FAMILY.scopes[:-1] for s in scopes.values()
                  if "mtp" in s and m in s}
         assert inner >= {"mla/attn", "mla/rope", "moe/experts", "head/loss"}
         assert any("transpose" in s and "mtp" in s for s in scopes.values())
         assert any("mla/attn" in s and "mtp" not in s
                    for s in scopes.values())
-    finally:
-        xla.disable_ledger()
-        xla.clear_ledger()
 
 
 def test_the_per_call_paths_report_both_losses_too():
     from deeplearning4j_tpu import monitor
-    net, cfg = _net()
-    ids, labels, keep = _batch(cfg, _rows(3, 1)[0][0])
+    net, cfg = FAMILY.net()
+    ids, labels, keep = FAMILY.example(cfg, _rows(3, 1)[0][0])
     mds = MultiDataSet((ids,), labels, None, keep)
     want = np.asarray(net._score_parts(
         net.params, net.state, (ids,), labels, None, keep, True,
         jax.random.PRNGKey(0))[2])
     for how in ({"scan_steps": 1}, {"accumulate_steps": 2}):
-        net, _ = _net()
+        net, _ = FAMILY.net()
         net.fit([mds, mds], **how)
         parts = {s["labels"]["output"]: s["value"] for s in
                  monitor.dump()["train_output_loss"]["series"]}

@@ -8,6 +8,8 @@ functions against the definitions they are arrangements of.
 widths, the XLA executor) to the token recurrence;
 `tests/test_tpu_lowering.py` compiles the kernels for the v5e.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,10 +18,19 @@ import pytest
 from deeplearning4j_tpu.nn.layers import linear_attention
 from deeplearning4j_tpu.ops import kda_chunk
 
+from _lm_common import jit_unoptimised
+
 D, CHUNK = 128, 64
 
 
-def _tiles(t, decay, pairs=2, seed=0):
+#: the smallest tile with both halves of the tile algebra (two blocks of 16
+#: positions). What a case asks of the COUNT of chunks or of the state
+#: between them it asks at this size: a tile of 64, unrolled column by
+#: column, takes four times as long to compile (ROADMAP D12)
+SMALL = 32
+
+
+def _tiles(t, decay, pairs=2, seed=0, chunk=CHUNK):
     """(M, N, C, .) tiles of ``pairs`` sequences of t positions as the
     layer hands them over: unit q (scaled) and k, g the running sum of
     log a inside a chunk, beta (M, N, 1, C); a tail that does not fill
@@ -27,7 +38,7 @@ def _tiles(t, decay, pairs=2, seed=0):
     state (M, d_k, d_v) for the first chunk to receive."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
-    chunks = lambda a: linear_attention._chunked(a, CHUNK)
+    chunks = lambda a: linear_attention._chunked(a, chunk)
     q = unit(jax.random.normal(ks[0], (pairs, t, D))) * D ** -0.5
     k = unit(jax.random.normal(ks[1], (pairs, t, D)))
     v = jax.random.normal(ks[2], (pairs, t, D))
@@ -45,15 +56,21 @@ def _kernels(mm):
     return lambda *a: kernels(*a, mm, True)
 
 
-def _with_gradients(fn, args):
-    """fn's outputs (o and the final state) and the gradient of a fixed
-    weighting of both in every input, the first state among them."""
-    out = fn(*args)
-    ws = [jax.random.normal(jax.random.PRNGKey(20 + i), x.shape)
-          for i, x in enumerate(out)]
-    loss = lambda *a: sum(jnp.sum(x.astype(jnp.float32) * w)
-                          for x, w in zip(fn(*a), ws))
-    return out, jax.grad(loss, argnums=tuple(range(len(args))))(*args)
+@functools.lru_cache(maxsize=None)
+def _with_gradients(mm, kernels):
+    """args -> the outputs (o and the final state) of the kernels (or of
+    the scan) and the gradient of a fixed weighting of both in every input,
+    the first state among them: one forward and one backward, compiled as
+    one program, and traced once for every case of the same shapes."""
+    fn = _kernels(mm) if kernels \
+        else lambda *a: kda_chunk._chunk_scan(*a, mm)
+
+    def run(*args):
+        out, pull = jax.vjp(fn, *args)
+        return out, pull(tuple(
+            jax.random.normal(jax.random.PRNGKey(20 + i), x.shape, x.dtype)
+            for i, x in enumerate(out)))
+    return jit_unoptimised(run)
 
 
 def _same(got, want, tol, what):
@@ -65,9 +82,8 @@ def _same(got, want, tol, what):
 
 
 def _kernels_against_the_scan(args, mm):
-    out, grads = _with_gradients(_kernels(mm), args)
-    out_ref, grads_ref = _with_gradients(
-        lambda *a: kda_chunk._chunk_scan(*a, mm), args)
+    out, grads = _with_gradients(mm, True)(*args)
+    out_ref, grads_ref = _with_gradients(mm, False)(*args)
     assert [x.dtype for x in out] == [jnp.float32, jnp.float32]
     for name, a, b in zip(("o", "the final state"), out, out_ref):
         # one rounding to the products' dtype apart at the most
@@ -99,14 +115,17 @@ def test_kernels_take_any_count_of_chunks_and_a_padded_tail(t):
     """3, 7, 11 and 16 chunks (a grid step takes the most chunks up to 8
     that divide the count: 3, 7, 1, 8, the forward kernel an even count
     two to a turn of its loop; the state crosses chunks of one grid step
-    and grid steps), the last one padded by the layer."""
-    _kernels_against_the_scan(_tiles(t, 1.0, pairs=1), jnp.bfloat16)
+    and grid steps), the last one padded by the layer: half of ``t``
+    positions in chunks of `SMALL`, the same counts and the same tails."""
+    args = _tiles(t // 2, 1.0, pairs=1, chunk=SMALL)
+    assert args[0].shape[1] == -(-t // CHUNK)
+    _kernels_against_the_scan(args, jnp.bfloat16)
 
 
 def test_forward_rule_keeps_the_state_each_chunk_received():
     """What the backward kernel reads beside the inputs: chunk n's state
     is the final state of the first n chunks."""
-    args = _tiles(3 * CHUNK, 1.0, pairs=1)
+    args = _tiles(3 * SMALL, 1.0, pairs=1, chunk=SMALL)
     o, end, states = kda_chunk._forward(*args, jnp.bfloat16, True, True)
     assert states.shape == (1, 3, D, D)
     upto = lambda n: kda_chunk._forward(
@@ -128,18 +147,18 @@ def test_two_calls_with_the_state_handed_over_are_one_call(monkeypatch):
     monkeypatch.setattr(kda_chunk, "_chunk_kernels",
                         lambda *a: interpreted(*a[:6]))
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
-    t, cut, h = 3 * CHUNK, CHUNK, 2
+    t, cut, h = 3 * SMALL, SMALL, 2
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
     args = (unit(jax.random.normal(ks[0], (1, t, h, D))) * D ** -0.5,
             unit(jax.random.normal(ks[1], (1, t, h, D))),
             jax.random.normal(ks[2], (1, t, h, D)),
             -jnp.exp(jax.random.normal(ks[3], (1, t, h, D)) - 1),
             jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, h))))
-    o, s = linear_attention.kda_chunked(*args, chunk=CHUNK)
+    o, s = linear_attention.kda_chunked(*args, chunk=SMALL)
     o1, s1 = linear_attention.kda_chunked(*(x[:, :cut] for x in args),
-                                          chunk=CHUNK)
+                                          chunk=SMALL)
     o2, s2 = linear_attention.kda_chunked(*(x[:, cut:] for x in args),
-                                          chunk=CHUNK, initial_state=s1)
+                                          chunk=SMALL, initial_state=s1)
     assert float(jnp.abs(s1).max()) > 0
     _same(jnp.concatenate([o1, o2], axis=1), o, 1e-6, "o")
     _same(s2, s, 1e-6, "the final state")
@@ -204,8 +223,11 @@ def test_the_executor_follows_platform_and_shapes(monkeypatch, on_tpu, dk,
     monkeypatch.setattr(kda_chunk, "is_tpu_backend", lambda: on_tpu)
     monkeypatch.setattr(kda_chunk, "_chunk_kernels", kernel_path)
     x = jnp.zeros((1, 2, chunk, dk))
-    o, s = kda_chunk.chunk_scan(
-        x, x, jnp.zeros((1, 2, chunk, dv)), x, jnp.zeros((1, 2, chunk, 1)),
-        jnp.zeros((1, dk, dv)), mm=jnp.float32)
+    # traced, not run: the choice is made from the shapes
+    o, s = jax.eval_shape(functools.partial(kda_chunk.chunk_scan,
+                                            mm=jnp.float32),
+                          x, x, jnp.zeros((1, 2, chunk, dv)), x,
+                          jnp.zeros((1, 2, chunk, 1)),
+                          jnp.zeros((1, dk, dv)))
     assert called == ([False] if kernels else [])
     assert o.shape == (1, 2, chunk, dv) and s.shape == (1, dk, dv)

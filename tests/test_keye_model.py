@@ -9,25 +9,13 @@ import pytest
 
 from deeplearning4j_tpu import monitor
 from deeplearning4j_tpu.models import KeyeVL2LM
-from deeplearning4j_tpu.nn.conf.base import InputType
-from deeplearning4j_tpu.nn.layers import MoEFeedForward
 from deeplearning4j_tpu.ops.dsa_attention import pairs_causal, pairs_selected
 
-from _keye_common import CFG, REF, STAGES, SYSTEM, T, _batch, _net
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
+import _lm_common as lm
+from _keye_common import CFG, FAMILY, REF, SYSTEM, T
+from _lm_common import (  # noqa: F401 (the autouse fixture)
     _budgets_at_the_tests_sizes, _close, _rows,
 )
-
-
-def _score(net, params, ids, nxt, keep, state=None):
-    return net._score_fn(params, net.state if state is None else state,
-                         (ids,), (nxt,), None, (keep,), True,
-                         jax.random.PRNGKey(0))
-
-
-def _one(rows):
-    """The harness's rows of ONE sequence (the cell's batch)."""
-    return [(r[:1], y[:1]) for r, y in rows]
 
 
 # --------------------------------------------- the whole model through fit()
@@ -35,7 +23,7 @@ def test_the_model_is_the_language_model_alone():
     """Embedding, ``num_hidden_layers`` blocks that are all alike (sparse
     attention + experts), a final norm and an UNTIED head; the indexer's
     leaves sit under the attention's; no vision tower."""
-    net, _ = _net()
+    net, _ = FAMILY.net()
     assert net.conf.network_outputs == ("head",)
     assert set(net.params) == {"embed", "norm", "head"} | {
         f"layer{i}" for i in range(3)}
@@ -61,30 +49,7 @@ def test_two_adamw_steps_through_fit_match_the_reference(how):
     against the reference's `train_steps`: the score (the cross-entropy
     alone), AdamW's first moment by stage, the indexers' among them, and
     the update, as the benchmark's `correct` compares them."""
-    from benchmark.lib import checks
-    net, cfg = _net()
-    rows = _one(_rows(11, 2))
-    stamps = SYSTEM.stamp_listener()
-    net.set_listeners(stamps)
-    net.fit(SYSTEM.feed(rows), **how)
-    losses = [loss for _, loss in stamps.rows]
-    r_losses, r_m, r_params = REF.train_steps(cfg, REF.make_params(cfg),
-                                              rows)
-    np.testing.assert_allclose(losses, r_losses, rtol=2e-6)
-    init = jax.device_get(REF.make_params(cfg))
-    diff = lambda new: checks.leaf_norms(jax.tree_util.tree_map(
-        lambda a, b: np.asarray(a) - np.asarray(b), new, init))
-    prog = {"losses": losses, "update": diff(net.params),
-            "momentum": checks.leaf_norms(SYSTEM.momentum(net))}
-    ref = {"losses": r_losses, "update": diff(r_params),
-           "momentum": checks.leaf_norms(r_m)}
-    limits = {"loss_gap": 2e-6, "head_momentum_gap": 1e-4,
-              "head_update_gap": 1e-4, "update_norm_gap": 1e-5,
-              "stage_momentum_gap": {s: 1e-4 for s in STAGES}}
-    rows_ = checks.training_rows(prog, ref,
-                                 lambda leaf: REF.stage_of(cfg, leaf), limits)
-    assert len(rows_) == 4 + len(STAGES) and checks.verdict(rows_)
-    assert checks.worst_leaf_gap(prog["update"], ref["update"]) < 1e-3
+    lm.two_adamw_steps_match(FAMILY, how)
 
 
 def test_logits_losses_and_every_gradient_leaf_match_the_reference():
@@ -93,26 +58,11 @@ def test_logits_losses_and_every_gradient_leaf_match_the_reference():
     and EVERY leaf of the step's gradient element by element, which is
     that of CE + the sum of the layers' indexer losses while the score is
     CE."""
-    net, cfg = _net()
-    ids, nxt, keep = _batch(cfg, _rows(4, 1)[0][0][:1])
-    params = REF.make_params(cfg)
-    want_logits = REF.logits(cfg, params, ids[0])
-    np.testing.assert_allclose(net.output(ids)[0],
-                               jax.nn.softmax(want_logits, axis=-1),
-                               atol=2e-6)
-    (got_l, (state, _)), got = jax.value_and_grad(
-        lambda p: _score(net, p, ids, nxt, keep), has_aux=True)(params)
-    (_, (want_ce, want_kl)), want = jax.value_and_grad(
-        lambda p: REF.loss_fn(cfg, p, ids[0]), has_aux=True)(params)
-    np.testing.assert_allclose(got_l, want_ce, rtol=2e-6)
+    lm.logits_match(FAMILY)
+    state, want_kl = lm.every_gradient_leaf_matches(FAMILY)
     got_kl = [state[f"layer{i}"]["attn"]["indexer_kl"] for i in range(3)]
     np.testing.assert_allclose(got_kl, want_kl, rtol=2e-5)
     assert float(min(want_kl)) > 1e-3
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
-                            jax.tree_util.tree_leaves(want)):
-        scale = max(np.abs(np.asarray(b)).max(), 1e-7)
-        assert np.abs(np.asarray(a - b)).max() <= 2e-4 * scale, \
-            jax.tree_util.keystr(path)
 
 
 def test_the_score_is_the_cross_entropy_and_the_gradient_has_both_losses():
@@ -120,12 +70,12 @@ def test_the_score_is_the_cross_entropy_and_the_gradient_has_both_losses():
     score is the same to the bit, the main weights' gradients too (they
     get nothing from that loss) and the indexers' gradients are exactly
     zero (they get nothing from the cross-entropy)."""
-    net, cfg = _net()
-    off, _ = _net(indexer_loss_coef=0.0)
-    ids, nxt, keep = _batch(cfg, _rows(4, 1)[0][0][:1])
+    net, cfg = FAMILY.net()
+    off, _ = FAMILY.net(indexer_loss_coef=0.0)
+    ids, nxt, keep = FAMILY.example(cfg, _rows(4, 1)[0][0][:1])
     params = REF.make_params(cfg)
-    grad = lambda n: jax.value_and_grad(
-        lambda p: _score(n, p, ids, nxt, keep)[0])(params)
+    grad = lambda n: jax.jit(jax.value_and_grad(
+        lambda p: FAMILY.score(n, p, (ids, nxt, keep))))(params)
     (l_on, g_on), (l_off, g_off) = grad(net), grad(off)
     assert float(l_on) == float(l_off)
     for i in range(3):
@@ -146,8 +96,8 @@ def test_fit_reports_the_cross_entropy_and_publishes_the_counts():
     trains the indexers too; `ExpertLoadListener` publishes the pairs the
     selections kept and chose among, exactly sum_t min(t + 1, topk) of
     T (T + 1) / 2 a sequence and layer, and the last indexer loss."""
-    net, cfg = _net()
-    rows = _one(_rows(12, 2))
+    net, cfg = FAMILY.net()
+    rows = FAMILY.rows(12, 2)
     stamps = SYSTEM.stamp_listener()
     net.set_listeners(stamps)
     before = SYSTEM.sparse_pairs() or (0.0, 0.0)
@@ -178,18 +128,7 @@ def test_bfloat16_compute_stays_near_the_reference():
     half a percent of the float32 reference's, every stage's gradient
     norm to 5 % (the bf16 program picks other keys where scores lie within
     rounding of the threshold)."""
-    from benchmark.lib import checks
-    net, cfg = _net(compute_dtype="bfloat16")
-    ids, nxt, keep = _batch(cfg, _rows(4, 1)[0][0][:1])
-    params = REF.make_params(cfg)
-    got_l, got = jax.value_and_grad(
-        lambda p: _score(net, p, ids, nxt, keep)[0])(params)
-    (_, (want_l, _)), want = jax.value_and_grad(
-        lambda p: REF.loss_fn(cfg, p, ids[0]), has_aux=True)(params)
-    assert abs(float(got_l) - float(want_l)) < 5e-3 * float(want_l)
-    gaps = checks.stage_gaps(checks.leaf_norms(got), checks.leaf_norms(want),
-                             lambda leaf: REF.stage_of(cfg, leaf))
-    assert set(gaps) == set(STAGES) and max(gaps.values()) < 5e-2, gaps
+    lm.bfloat16_stays_near(FAMILY)
 
 
 @pytest.mark.parametrize("fault", REF.FAULTS)
@@ -198,18 +137,10 @@ def test_a_planted_fault_moves_what_correct_compares(fault):
     moves the indexers' first moment or the losses far more than float32
     rounding; with the indexer's loss left out the indexers' moment is
     zero (the stage reads 1)."""
-    from benchmark.lib import checks
     assert REF.FAULTS == ("no_relu", "no_head_weights", "half_topk",
                           "sees_next", "no_indexer_loss", "kl_head0",
                           "kv_head_mod", "no_renorm")
-    rows = _one(_rows(11, 2))
-    sound = REF.train_steps(CFG, REF.make_params(CFG), rows)
-    bad = REF.train_steps(CFG, REF.make_params(CFG), rows, fault=fault)
-    loss = max(abs(a - b) / abs(b) for a, b in zip(bad[0], sound[0]))
-    stage = checks.stage_gaps(checks.leaf_norms(bad[1]),
-                              checks.leaf_norms(sound[1]),
-                              lambda leaf: REF.stage_of(CFG, leaf))
-    assert max(loss, *stage.values()) > 1e-3, (fault, loss, stage)
+    stage = lm.a_planted_fault_moves(FAMILY, fault)
     if fault == "no_indexer_loss":
         assert stage["indexer"] == 1.0
 
@@ -264,7 +195,8 @@ def test_the_references_blocks_and_key_prefixes_change_no_number(
         def f(p, x):
             a, kl = fn(CFG, p, x, fault=fault)
             return jnp.sum(a * ct) + kl, (a, kl)
-        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(p, x)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))(
+            p, x)
 
     (_, (a, kl)), got = both(REF.attention)
     (_, (a0, kl0)), want = both(_attention_at_once)
@@ -278,41 +210,4 @@ def test_the_references_blocks_and_key_prefixes_change_no_number(
 
 # ------------------------------------------------- the shares of a layer
 def test_the_eight_shares_add_up_and_an_unheld_token_gets_exactly_zero():
-    """`y = alike + sum over the chips of (what each chip's experts add)`:
-    with a softmax router and no shared expert the eight shares' partial
-    results of one expert layer add up to the uncut reference's whole
-    layer, and a token none of whose experts a chip holds gets exactly
-    zero from that chip."""
-    cfg = {**CFG, "experts_held": [0, 16], "num_experts": 16}
-    whole = REF.make_params(cfg)["layer1"]
-    x = jax.random.normal(jax.random.PRNGKey(5), (T, 32))
-    want, _ = REF.layer(cfg, whole, x)
-    eps = cfg["rms_norm_eps"]
-    a, _ = REF.attention(cfg, whole["attn"],
-                         REF._rms(x, whole["ln1"]["gamma"], eps))
-    alike = x + a
-    normed = REF._rms(alike, whole["ln2"]["gamma"], eps)
-    total = np.zeros((T, 32), np.float32)
-    for lo in range(0, 16, 2):
-        ffn = MoEFeedForward(
-            n_out=32, n_experts=16, top_k=2, hidden=24, activation="swish",
-            gated=True, has_bias=False, experts_held=(lo, lo + 2),
-            router="softmax", n_shared=0, weight_init="normal")
-        p = {"Wr": whole["ffn"]["Wr"],
-             **{k: whole["ffn"][k][lo:lo + 2]
-                for k in ("Wgate", "Wup", "Wdown")}}
-        _, state = ffn.init(jax.random.PRNGKey(0),
-                            InputType.recurrent(32, T))
-        out, new = ffn.apply(p, state, normed[None])
-        out = np.asarray(out[0])
-        # one share alone is the reference told to hold the same experts
-        share = REF.experts({**cfg, "experts_held": [lo, lo + 2],
-                             "num_experts": 2}, p, normed)
-        _close(out, share, 2e-5)
-        idx, _ = REF.routing(cfg, whole["ffn"], normed)
-        unheld = ~np.any((np.asarray(idx) >= lo)
-                         & (np.asarray(idx) < lo + 2), axis=-1)
-        assert unheld.any() and not np.any(out[unheld])
-        assert int(new["tokens_with_held_pair_total"]) == int((~unheld).sum())
-        total += out
-    _close(alike + total, want, 2e-5)
+    lm.the_eight_shares_add_up(FAMILY)

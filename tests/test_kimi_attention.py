@@ -1,5 +1,7 @@
 """The attention layers of the hybrid LM and their kernels (KDA, MLA, the
 flash kernel at two head sizes); see `_kimi_common.py`."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,8 +14,10 @@ from deeplearning4j_tpu.nn.layers import (
 from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
 from deeplearning4j_tpu.nn.layers.linear_attention import kda_chunked
 
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
-    CFG, REF, T, _budgets_at_the_tests_sizes, _close, _layer_params,
+from _kimi_common import CFG, REF, T, _layer_params
+from _lm_common import (  # noqa: F401 (the autouse fixture)
+    _budgets_at_the_tests_sizes, _close, jit_unoptimised as _jit,
+    with_gradients,
 )
 
 
@@ -34,25 +38,34 @@ def test_kda_chunked_is_the_token_recurrence(t, chunk, monkeypatch):
     """Output, final state and the gradient of every input, at sequence
     lengths that are and are not a multiple of the chunk."""
     args = _kda_inputs(t)
-    o, s = kda_chunked(*args, chunk=chunk)
-    o_ref, s_ref = REF.kda_recurrence(*args, segment=16)
+    w = jax.random.normal(jax.random.PRNGKey(7), args[2].shape)
+
+    def with_gradients(fn):
+        """(o, s) and the gradient of a weighting of both in every input:
+        one forward and one backward, one compiled program."""
+        def loss(*a):
+            o, s = fn(*a)
+            return jnp.sum(o * w) + jnp.sum(s * s), (o, s)
+        (_, out), grads = _jit(jax.value_and_grad(
+            loss, (0, 1, 2, 3, 4), has_aux=True))(*args)
+        return out, grads
+
+    chunked = lambda *a: kda_chunked(*a, chunk=chunk)
+    (o, s), got = with_gradients(chunked)
+    (o_ref, s_ref), want = with_gradients(
+        lambda *a: REF.kda_recurrence(*a, segment=16))
     _close(o, o_ref, 2e-5)
     _close(s, s_ref, 2e-5)
+    for a, b in zip(got, want):
+        _close(a, b, 5e-5)
     # the (sequence, head) pairs in 3 groups, one after another: the same
+    # (a function of its own: jit keeps its traces by function, and the
+    # budget is read when the layer is traced)
     from deeplearning4j_tpu.nn.layers import linear_attention
     monkeypatch.setattr(linear_attention, "_SCAN_LIVE_BYTES",
                         2 * 40 * t * 8 * 4)
-    for got, want in zip(kda_chunked(*args, chunk=chunk), (o, s)):
+    for got, want in zip(_jit(lambda *a: chunked(*a))(*args), (o, s)):
         _close(got, want, 1e-6)
-    w = jax.random.normal(jax.random.PRNGKey(7), o.shape)
-    loss = lambda fn: lambda *a: (lambda o, s: jnp.sum(o * w)
-                                  + jnp.sum(s * s))(*fn(*a))
-    got = jax.grad(loss(lambda *a: kda_chunked(*a, chunk=chunk)),
-                   (0, 1, 2, 3, 4))(*args)
-    want = jax.grad(loss(lambda *a: REF.kda_recurrence(*a, segment=16)),
-                    (0, 1, 2, 3, 4))(*args)
-    for a, b in zip(got, want):
-        _close(a, b, 5e-5)
 
 
 def test_kda_chunked_takes_no_positive_exponent():
@@ -60,24 +73,27 @@ def test_kda_chunked_takes_no_positive_exponent():
     <= 0, so nothing overflows and the numbers are the recurrence's."""
     args = _kda_inputs(64, decay=300.0)
     assert float(args[3].min()) < -200
-    o, s = kda_chunked(*args, chunk=32)
-    o_ref, s_ref = REF.kda_recurrence(*args, segment=16)
+    (_, (o, s)), g = _jit(jax.value_and_grad(
+        lambda *a: (lambda o, s: (jnp.sum(o), (o, s)))(
+            *kda_chunked(*a, chunk=32)),
+        (0, 1, 2, 3, 4), has_aux=True))(*args)
+    o_ref, s_ref = jax.jit(functools.partial(REF.kda_recurrence,
+                                             segment=16))(*args)
     assert np.isfinite(np.asarray(o)).all()
     _close(o, o_ref, 2e-5)
     _close(s, s_ref, 2e-5)
-    g = jax.grad(lambda *a: jnp.sum(kda_chunked(*a, chunk=32)[0]),
-                 (0, 1, 2, 3, 4))(*args)
     assert all(np.isfinite(np.asarray(x)).all() for x in g)
 
 
 def test_kda_chunked_hands_a_state_over():
     """Two calls, the second given the first's state, are one call."""
     args = _kda_inputs(96)
-    o, s = kda_chunked(*args, chunk=32)
+    chunked = _jit(functools.partial(kda_chunked, chunk=32))
+    o, s = chunked(*args)
     head = [a[:, :40] for a in args]
     tail = [a[:, 40:] for a in args]
-    o1, s1 = kda_chunked(*head, chunk=32)
-    o2, s2 = kda_chunked(*tail, chunk=32, initial_state=s1)
+    o1, s1 = chunked(*head)
+    o2, s2 = chunked(*tail, initial_state=s1)
     _close(jnp.concatenate([o1, o2], axis=1), o, 2e-5)
     _close(s2, s, 2e-5)
 
@@ -95,10 +111,11 @@ def test_kda_layer_and_every_parameters_gradient(t):
         {k: v.shape for k, v in p.items()}
     x = jax.random.normal(jax.random.PRNGKey(1), (2, t, 32))
     w = jax.random.normal(jax.random.PRNGKey(2), (2, t, 32))
-    prog = lambda p, x: jnp.sum(kda.apply(p, {}, x)[0] * w)
-    ref = lambda p, x: jnp.sum(REF._kda(CFG, p, x, "highest") * w)
-    _close(kda.apply(p, {}, x)[0], REF._kda(CFG, p, x, "highest"), 2e-5)
-    got, want = jax.grad(prog, (0, 1))(p, x), jax.grad(ref, (0, 1))(p, x)
+    y, got = with_gradients(lambda p, x: kda.apply(p, {}, x)[0], w, (p, x),
+                            _jit)
+    y_ref, want = with_gradients(
+        lambda p, x: REF._kda(CFG, p, x, "highest"), w, (p, x), _jit)
+    _close(y, y_ref, 2e-5)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
                             jax.tree_util.tree_leaves(want)):
         assert np.abs(np.asarray(a - b)).max() <= 1e-4 * max(
@@ -130,11 +147,10 @@ def test_mla_layer_and_every_parameters_gradient():
         {k: v.shape for k, v in p.items()}
     x = jax.random.normal(jax.random.PRNGKey(1), (2, T, 32))
     w = jax.random.normal(jax.random.PRNGKey(2), (2, T, 32))
-    _close(mla.apply(p, {}, x)[0], REF._mla(CFG, p, x, "highest"), 2e-5)
-    got = jax.grad(lambda p, x: jnp.sum(mla.apply(p, {}, x)[0] * w),
-                   (0, 1))(p, x)
-    want = jax.grad(lambda p, x: jnp.sum(REF._mla(CFG, p, x, "highest") * w),
-                    (0, 1))(p, x)
+    y, got = with_gradients(lambda p, x: mla.apply(p, {}, x)[0], w, (p, x))
+    y_ref, want = with_gradients(
+        lambda p, x: REF._mla(CFG, p, x, "highest"), w, (p, x))
+    _close(y, y_ref, 2e-5)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         _close(a, b, 1e-4)
@@ -153,10 +169,10 @@ def test_flash_kernel_at_two_head_sizes_forward_and_backward(t, block):
     flash = lambda q, k, v: flash_attention(
         q, k, v, causal=True, block_q=block, block_k=block, interpret=True)
     dense = lambda q, k, v: dot_product_attention(q, k, v, causal=True)
-    assert flash(q, k, v).shape == (1, t, 2, 128)
-    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-5)
-    got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
+    out, got = with_gradients(flash, w, (q, k, v))
+    ref, want = with_gradients(dense, w, (q, k, v))
+    assert out.shape == (1, t, 2, 128)
+    np.testing.assert_allclose(out, ref, atol=2e-5)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=1e-4)
     with pytest.raises(ValueError, match="head width"):

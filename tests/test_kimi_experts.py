@@ -14,9 +14,9 @@ from deeplearning4j_tpu.nn.layers import (
     RnnOutputLayer, TransformerBlock,
 )
 
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
-    CFG, KINDS, REF, SYSTEM, T, _budgets_at_the_tests_sizes, _close,
-    _layer_params, _net, _rows,
+from _kimi_common import CFG, FAMILY, KINDS, REF, SYSTEM, T, _layer_params
+from _lm_common import (  # noqa: F401 (the autouse fixture)
+    _budgets_at_the_tests_sizes, _close, _rows, jit_unoptimised,
 )
 
 
@@ -57,7 +57,9 @@ def test_the_four_shares_add_up_to_the_uncut_layer(layer, attn_kind):
         blk = _expert_block(lo, hi, attn_kind)
         _, state = blk.init(jax.random.PRNGKey(0),
                             InputType.recurrent(32, T))
-        return blk.apply(p, state, x)[0]
+        # compiled, a share a program (op by op KDA's unrolled tile is
+        # thousands of dispatches)
+        return jit_unoptimised(lambda p, x: blk.apply(p, state, x)[0])(p, x)
 
     alike = run(0, 2, zero_down=True)          # no routed expert adds
     shares = [run(lo, lo + 2) for lo in (0, 2, 4, 6)]
@@ -473,7 +475,10 @@ def test_every_fit_path_counts_every_steps_routing(how):
     from deeplearning4j_tpu.train.listeners import ExpertLoadListener
     # 4 held of 16 routed over: the small tier is half the pairs (4 of 8,
     # the files' common size, has the whole tier alone)
-    net, cfg = _net(learning_rate=0.0, weight_decay=0.0, router_experts=16)
+    # (KDA chunks of 16, one block a tile: the step compiles in two thirds
+    # of the time, and the tile is `test_kimi_attention.py`'s to hold)
+    net, cfg = FAMILY.net(learning_rate=0.0, weight_decay=0.0, router_experts=16,
+                    kda_chunk=16)
     net.set_listeners(ExpertLoadListener())
     before = monitor.dump()
     rows = _rows(6, 4)
@@ -547,8 +552,12 @@ def test_a_graphs_expert_layer_counts_too():
 def test_the_states_total_wraps_and_the_listener_takes_it_modulo():
     from deeplearning4j_tpu import monitor
     from deeplearning4j_tpu.train.listeners import ExpertLoadListener
-    net, cfg = _net()
-    near = jnp.full((8,), 2 ** 32 - 5, jnp.uint32)
+    # the net of the per-call case above, so that its step is compiled once
+    # for both (the second finds it in the compile cache)
+    net, cfg = FAMILY.net(learning_rate=0.0, weight_decay=0.0, router_experts=16,
+                    kda_chunk=16)
+    near = jax.device_put(jnp.full((16,), 2 ** 32 - 5, jnp.uint32),
+                          jax.devices()[0])
     for layer in ("2", "3", "4", "5"):
         net.state[layer]["ffn"]["tokens_routed_total"] = near
     net.set_listeners(ExpertLoadListener())

@@ -17,49 +17,21 @@ from deeplearning4j_tpu.nn.layers import (
     RnnOutputLayer, TransformerBlock,
 )
 
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
-    CFG, REF, SYSTEM, T, _batch, _budgets_at_the_tests_sizes, _net, _rows,
+import _lm_common as lm
+from _kimi_common import CFG, FAMILY, REF, SYSTEM, T
+from _lm_common import (  # noqa: F401 (the autouse fixture)
+    _budgets_at_the_tests_sizes, _rows,
 )
 
 
 # --------------------------------------------- the whole model through fit()
-def _follow(net, cfg, rows, how):
-    stamps = SYSTEM.stamp_listener()
-    net.set_listeners(stamps)
-    net.fit(SYSTEM.feed(rows), **how)
-    return [loss for _, loss in stamps.rows]
-
-
 @pytest.mark.parametrize("how", [{"scan_steps": 2}, {"scan_steps": 1}])
 def test_two_adamw_steps_through_fit_match_the_reference(how):
     """The cut model, two optimizer steps through `fit()` (scan-of-2 and
     per-call alike) against the reference's `train_steps`: the losses,
     AdamW's first moment by stage, and the norm of the update, as the
     benchmark's `correct` compares them."""
-    from benchmark.lib import checks
-    net, cfg = _net()
-    rows = _rows(11, 2)
-    losses = _follow(net, cfg, rows, how)
-    r_losses, r_m, r_params = REF.train_steps(cfg, REF.make_params(cfg),
-                                              rows)
-    np.testing.assert_allclose(losses, r_losses, rtol=2e-6)
-    init = jax.device_get(REF.make_params(cfg))
-    diff = lambda new: checks.leaf_norms(jax.tree_util.tree_map(
-        lambda a, b: np.asarray(a) - np.asarray(b), new, init))
-    prog = {"losses": losses, "update": diff(net.params),
-            "momentum": checks.leaf_norms(SYSTEM.momentum(net))}
-    ref = {"losses": r_losses, "update": diff(r_params),
-           "momentum": checks.leaf_norms(r_m)}
-    limits = {"loss_gap": 2e-6, "head_momentum_gap": 1e-4,
-              "head_update_gap": 1e-4, "update_norm_gap": 1e-5,
-              "stage_momentum_gap": {s: 1e-4 for s in (
-                  "embed", "layer1", "layer2", "layer3", "layer4", "layer5",
-                  "head")}}
-    rows_ = checks.training_rows(prog, ref,
-                                 lambda leaf: REF.stage_of(cfg, leaf), limits)
-    assert len(rows_) == 11 and checks.verdict(rows_)
-    # gains, per-head scalars and biases are not decayed; matrices are
-    assert checks.worst_leaf_gap(prog["update"], ref["update"]) < 1e-3
+    lm.two_adamw_steps_match(FAMILY, how)
 
 
 def test_weight_decay_is_on_matrices_only():
@@ -76,24 +48,7 @@ def test_weight_decay_is_on_matrices_only():
 
 
 def test_loss_and_every_gradient_leaf_match_the_reference():
-    net, cfg = _net()
-    ids, nxt, keep = _batch(cfg, _rows(4, 1)[0][0])
-    params = REF.make_params(cfg)
-
-    def program(p):
-        return net._score_fn(p, net.state, ids, nxt, None, keep, True,
-                             jax.random.PRNGKey(0))[0]
-
-    got_l, got = jax.value_and_grad(program)(params)
-    want_l, want = jax.value_and_grad(
-        lambda p: REF.loss_fn(cfg, p, ids))(params)
-    np.testing.assert_allclose(got_l, want_l, rtol=2e-6)
-    flat_w = jax.tree_util.tree_leaves(want)
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
-                            flat_w):
-        scale = max(np.abs(np.asarray(b)).max(), 1e-7)
-        assert np.abs(np.asarray(a - b)).max() <= 2e-4 * scale, \
-            jax.tree_util.keystr(path)
+    lm.every_gradient_leaf_matches(FAMILY)
 
 
 def test_planted_faults_move_what_correct_compares():
@@ -101,12 +56,8 @@ def test_planted_faults_move_what_correct_compares():
     half a batch, a KDA layer without its decay, a router without the
     renormalisation. Each moves the loss or a stage's first moment by
     far more than float32 rounding."""
-    rows = _rows(11, 2)
-    sound = REF.train_steps(CFG, REF.make_params(CFG), rows)
     for fault in ("half_batch", "kda_no_decay", "router_no_renorm"):
-        bad = REF.train_steps(CFG, REF.make_params(CFG), rows, fault=fault)
-        gap = max(abs(a - b) / abs(b) for a, b in zip(bad[0], sound[0]))
-        assert gap > 1e-4, (fault, gap)
+        lm.a_planted_fault_moves(FAMILY, fault)
 
 
 # -------------------------------------------------- what stays as it was
@@ -220,38 +171,22 @@ def test_all_weights_come_from_weights_seed_and_none_from_the_runs():
 
 
 # ---------------------------------------------------------- counters, ledger
-SCOPES = ("kda/proj", "kda/scan", "kda/out", "mla/proj", "mla/attn",
-          "moe/route", "moe/dispatch", "moe/experts", "moe/shared",
-          "moe/combine", "head/loss", "opt/update")
-
-
 def test_the_adapter_reads_the_counters_and_the_steps_scopes():
     from deeplearning4j_tpu import monitor
-    from deeplearning4j_tpu.monitor import xla
-    net, cfg = _net()
-    net.set_listeners(SYSTEM.stamp_listener())
-    xla.enable_ledger()
-    try:
-        net.fit(SYSTEM.feed(_rows(6, 4)), scan_steps=2)
+    with lm.fitted_under_the_ledger(FAMILY):
         load = monitor.dump()["moe_expert_load_max_over_mean"]["series"]
         assert {s["labels"]["layer"] for s in load} >= {"2", "3", "4", "5"}
         assert all(1.0 <= s["value"] <= 8.0 for s in load)
         rows = SYSTEM.expert_rows_per_step()
         assert set(rows) >= {"2", "3", "4", "5"} \
             and SYSTEM.expert_load_max_over_mean()
-        scopes = SYSTEM.op_scopes()
-        seen = {m for m in SCOPES if any(m in s for s in scopes.values())}
-        assert seen == set(SCOPES), set(SCOPES) - seen
-    finally:
-        xla.disable_ledger()
-        xla.clear_ledger()
 
 
 # ----------------------------------------------------- checkpoints, serving
 def test_zoo_model_checkpoint_round_trip(tmp_path):
     from deeplearning4j_tpu.util.serialization import load_model, save_model
-    net, cfg = _net()
-    ids, nxt, keep = _batch(cfg, _rows(7, 1)[0][0])
+    net, cfg = FAMILY.net()
+    ids, nxt, keep = FAMILY.example(cfg, _rows(7, 1)[0][0])
     net.fit(ExistingDataSetIterator([DataSet(ids, nxt, None, keep)] * 2),
             scan_steps=2)
     path = os.path.join(tmp_path, "lm.zip")
@@ -278,7 +213,7 @@ def test_decode_engine_refuses_the_block_by_name(layers, what):
     from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
     from deeplearning4j_tpu.serving.decode import DecodeConfig, DecodeEngine
     from deeplearning4j_tpu.serving.registry import ModelLoadError
-    whole, _ = _net()
+    whole, _ = FAMILY.reader()
     b = NeuralNetConfiguration.Builder().list()
     b.layer(EmbeddingSequenceLayer(n_out=32, n_in=96))
     for i in layers:

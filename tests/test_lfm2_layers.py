@@ -21,8 +21,8 @@ from deeplearning4j_tpu.nn.layers.attention import (
 from deeplearning4j_tpu.nn.layers.linear_attention import causal_conv
 
 from _lfm2_common import CFG, KINDS, REF, T
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
-    _budgets_at_the_tests_sizes, _close,
+from _lm_common import (  # noqa: F401 (the autouse fixture)
+    _budgets_at_the_tests_sizes, _close, with_gradients,
 )
 
 _PER_EXPERT = ("Wgate", "Wup", "Wdown")
@@ -135,21 +135,21 @@ def test_grouped_query_attention_is_the_references():
     w = jax.random.normal(jax.random.PRNGKey(2), (2, T, 32))
     run = lambda p, x: layer.apply(p, state, x)[0]
     ref = lambda p, x, fault=None: REF.attention(CFG, p, x, fault=fault)
-    _close(run(p, x), ref(p, x), 3e-5)
-    got = jax.grad(lambda p, x: jnp.sum(run(p, x) * w), (0, 1))(p, x)
-    want = jax.grad(lambda p, x: jnp.sum(ref(p, x) * w), (0, 1))(p, x)
+    y, got = with_gradients(run, w, (p, x))
+    y_ref, want = with_gradients(ref, w, (p, x))
+    _close(y, y_ref, 3e-5)
     _leaves_close(got, want, 1e-4)
     for fault, twin in (("no_rope", dict(use_rope=False)),
                         ("no_qk_norm", dict(qk_norm=False))):
         off = dataclasses.replace(layer, **twin).apply(p, state, x)[0]
         _close(off, ref(p, x, fault), 3e-5)
-        assert float(jnp.abs(off - run(p, x)).max()) > 1e-3, fault
-    assert float(jnp.abs(ref(p, x, "kv_head_mod") - run(p, x)).max()) > 1e-3
+        assert float(jnp.abs(off - y).max()) > 1e-3, fault
+    assert float(jnp.abs(ref(p, x, "kv_head_mod") - y).max()) > 1e-3
     # every path of the layer groups alike (off the TPU: the XLA paths,
     # k and v repeated; the kernel itself: tests/test_flash_attention.py)
     for impl in ("dense", "blockwise"):
         _close(dataclasses.replace(layer, attention_impl=impl).apply(
-            p, state, x)[0], run(p, x), 2e-5)
+            p, state, x)[0], y, 2e-5)
     masked = jnp.ones((2, T)).at[1, T - 9:].set(0.0)
     y = layer.apply(p, state, x, mask=masked)[0]
     assert float(jnp.abs(y[1, T - 9:]).max()) == 0.0

@@ -11,15 +11,15 @@ import pytest
 from deeplearning4j_tpu.data.dataset import MultiDataSet
 from deeplearning4j_tpu.models import Lfm2MoeLM
 
-from _lfm2_common import CFG, KINDS, REF, STAGES, SYSTEM, T, _batch, _net
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
+import _lm_common as lm
+from _lfm2_common import CFG, FAMILY, KINDS, REF, SYSTEM, T
+from _lm_common import (  # noqa: F401 (the autouse fixture)
     _budgets_at_the_tests_sizes, _rows,
 )
 
 
-def _score(net, params, ids, nxt, keep):
-    return net._score_fn(params, net.state, (ids,), (nxt,), None, (keep,),
-                         True, jax.random.PRNGKey(0))[0]
+def _score(net, params, *example):
+    return FAMILY.score(net, params, example)
 
 
 # --------------------------------------------- the whole model through fit()
@@ -30,7 +30,7 @@ def test_the_cut_is_published_layers_one_to_five():
     assert KINDS == [("conv", "dense"), ("full_attention", "experts"),
                      ("conv", "experts"), ("conv", "experts"),
                      ("conv", "experts")]
-    net, _ = _net()
+    net, _ = FAMILY.net()
     assert net.conf.network_outputs == ("head",)
     assert net.conf.vertices["head"].params_of == "embed"
     assert "head" not in net.params
@@ -47,72 +47,23 @@ def test_two_adamw_steps_through_fit_match_the_reference(how):
     AdamW's first moment by stage, and the update, as the benchmark's
     `correct` compares them; the tied matrix is updated as ONE leaf,
     decayed once."""
-    from benchmark.lib import checks
-    net, cfg = _net()
-    rows = _rows(11, 2)
-    stamps = SYSTEM.stamp_listener()
-    net.set_listeners(stamps)
-    net.fit(SYSTEM.feed(rows), **how)
-    losses = [loss for _, loss in stamps.rows]
-    r_losses, r_m, r_params = REF.train_steps(cfg, REF.make_params(cfg),
-                                              rows)
-    np.testing.assert_allclose(losses, r_losses, rtol=2e-6)
-    init = jax.device_get(REF.make_params(cfg))
-    diff = lambda new: checks.leaf_norms(jax.tree_util.tree_map(
-        lambda a, b: np.asarray(a) - np.asarray(b), new, init))
-    prog = {"losses": losses, "update": diff(net.params),
-            "momentum": checks.leaf_norms(SYSTEM.momentum(net))}
-    ref = {"losses": r_losses, "update": diff(r_params),
-           "momentum": checks.leaf_norms(r_m)}
-    limits = {"loss_gap": 2e-6, "head_momentum_gap": 1e-4,
-              "head_update_gap": 1e-4, "update_norm_gap": 1e-5,
-              "stage_momentum_gap": {s: 1e-4 for s in STAGES}}
-    rows_ = checks.training_rows(prog, ref,
-                                 lambda leaf: REF.stage_of(cfg, leaf), limits)
-    assert len(rows_) == 4 + len(STAGES) and checks.verdict(rows_)
-    assert checks.worst_leaf_gap(prog["update"], ref["update"]) < 1e-3
+    prog, ref = lm.two_adamw_steps_match(FAMILY, how)
     leaf = "['embed']['W']"
-    assert REF.stage_of(cfg, leaf) == "head"
+    assert REF.stage_of(CFG, leaf) == "head"
     assert abs(prog["update"][leaf] - ref["update"][leaf]) \
         < 1e-4 * ref["update"][leaf]
 
 
 def test_logits_loss_and_every_gradient_leaf_match_the_reference():
-    net, cfg = _net()
-    ids, nxt, keep = _batch(cfg, _rows(4, 1)[0][0])
-    params = REF.make_params(cfg)
-    want_logits = REF.logits(cfg, params, ids)
-    np.testing.assert_allclose(net.output(ids),
-                               jax.nn.softmax(want_logits, axis=-1),
-                               atol=2e-6)
-    got_l, got = jax.value_and_grad(
-        lambda p: _score(net, p, ids, nxt, keep))(params)
-    want_l, want = jax.value_and_grad(
-        lambda p: REF.loss_fn(cfg, p, ids))(params)
-    np.testing.assert_allclose(got_l, want_l, rtol=2e-6)
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
-                            jax.tree_util.tree_leaves(want)):
-        scale = max(np.abs(np.asarray(b)).max(), 1e-7)
-        assert np.abs(np.asarray(a - b)).max() <= 2e-4 * scale, \
-            jax.tree_util.keystr(path)
+    lm.logits_match(FAMILY)
+    lm.every_gradient_leaf_matches(FAMILY)
 
 
 def test_bfloat16_compute_stays_near_the_reference():
     """bf16 operands over float32 weights, as the cell runs: the score to
     half a percent of the float32 reference's, every stage's gradient
     norm to 3 %."""
-    from benchmark.lib import checks
-    net, cfg = _net(compute_dtype="bfloat16")
-    ids, nxt, keep = _batch(cfg, _rows(4, 1)[0][0])
-    params = REF.make_params(cfg)
-    got_l, got = jax.value_and_grad(
-        lambda p: _score(net, p, ids, nxt, keep))(params)
-    want_l, want = jax.value_and_grad(
-        lambda p: REF.loss_fn(cfg, p, ids))(params)
-    assert abs(float(got_l) - float(want_l)) < 5e-3 * float(want_l)
-    gaps = checks.stage_gaps(checks.leaf_norms(got), checks.leaf_norms(want),
-                             lambda leaf: REF.stage_of(cfg, leaf))
-    assert set(gaps) == set(STAGES) and max(gaps.values()) < 3e-2, gaps
+    lm.bfloat16_stays_near(FAMILY)
 
 
 @pytest.mark.parametrize("fault", REF.FAULTS)
@@ -123,11 +74,7 @@ def test_a_planted_fault_moves_what_correct_compares(fault):
     the score far more than float32 rounding."""
     assert REF.FAULTS == ("half_batch", "kv_head_mod", "taps_reversed",
                           "no_qk_norm", "no_rope", "no_renorm")
-    rows = _rows(11, 2)
-    sound = REF.train_steps(CFG, REF.make_params(CFG), rows)
-    bad = REF.train_steps(CFG, REF.make_params(CFG), rows, fault=fault)
-    gap = max(abs(a - b) / abs(b) for a, b in zip(bad[0], sound[0]))
-    assert gap > 1e-4, (fault, gap)
+    lm.a_planted_fault_moves(FAMILY, fault)
 
 
 def test_the_reference_takes_a_batch_one_sequence_at_a_time():
@@ -137,7 +84,8 @@ def test_the_reference_takes_a_batch_one_sequence_at_a_time():
     losses, m, _ = REF.train_steps(CFG, REF.make_params(CFG), rows)
     ids = REF.decode_tokens(CFG, rows[0][0])
     params = REF.make_params(CFG)
-    loss, g = jax.value_and_grad(lambda p: REF.loss_fn(CFG, p, ids))(params)
+    loss, g = jax.jit(jax.value_and_grad(
+        lambda p: REF.loss_fn(CFG, p, ids)))(params)
     np.testing.assert_allclose(losses[0], loss, rtol=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(m),
                     jax.tree_util.tree_leaves(g)):
@@ -148,8 +96,8 @@ def test_the_reference_takes_a_batch_one_sequence_at_a_time():
 
 # ------------------------------------------------------------ the tied leaf
 def test_the_tied_matrix_is_one_leaf_with_the_sum_of_both_gradients():
-    net, cfg = _net()
-    ids, nxt, keep = _batch(cfg, _rows(4, 1)[0][0])
+    net, cfg = FAMILY.net()
+    ids, nxt, keep = FAMILY.example(cfg, _rows(4, 1)[0][0])
     mu = SYSTEM.momentum(net)
     assert jax.tree_util.tree_structure(mu) \
         == jax.tree_util.tree_structure(net.params)
@@ -159,7 +107,8 @@ def test_the_tied_matrix_is_one_leaf_with_the_sum_of_both_gradients():
     report = {r.name: r for r in net.memory_report(
         2, with_compiled=False).layers}
     assert report["head"].params_bytes == 0
-    g = jax.grad(lambda p: _score(net, p, ids, nxt, keep))(net.params)
+    g = jax.jit(jax.grad(lambda p: _score(net, p, ids, nxt, keep)))(
+        net.params)
     # the embedding's use alone: the head reads a copy that gets no gradient
     frozen = jax.lax.stop_gradient(net.params["embed"]["W"])
     x = REF.hidden(cfg, net.params, ids).reshape(-1, 32)
@@ -178,8 +127,8 @@ def test_the_tied_matrix_is_one_leaf_with_the_sum_of_both_gradients():
 
 def test_checkpoint_round_trip_keeps_the_tied_leaf_once(tmp_path):
     from deeplearning4j_tpu.util.serialization import load_model, save_model
-    net, cfg = _net()
-    ids, nxt, keep = _batch(cfg, _rows(7, 1)[0][0])
+    net, cfg = FAMILY.net()
+    ids, nxt, keep = FAMILY.example(cfg, _rows(7, 1)[0][0])
     net.fit([MultiDataSet((ids,), (nxt,), None, (keep,))] * 2, scan_steps=2)
     path = os.path.join(tmp_path, "lfm2.zip")
     save_model(net, path)
@@ -201,21 +150,11 @@ def test_checkpoint_round_trip_keeps_the_tied_leaf_once(tmp_path):
 
 
 # ---------------------------------------------------------- counters, ledger
-SCOPES = ("sconv/proj", "sconv/mix", "mha/proj", "mha/norm", "mha/rope",
-          "mha/attn", "moe/route", "moe/dispatch", "moe/experts",
-          "moe/combine", "mlp/gated", "head/loss", "opt/update")
-
-
 def test_the_adapter_reads_the_counters_and_the_steps_scopes():
     from deeplearning4j_tpu import monitor
-    from deeplearning4j_tpu.monitor import xla
-    net, cfg = _net()
-    net.set_listeners(SYSTEM.stamp_listener())
-    xla.enable_ledger()
-    try:
-        before = {s["labels"]["layer"]: s["value"] for s in monitor.dump().get(
-            "moe_tokens_with_held_pair_total", {}).get("series", [])}
-        net.fit(SYSTEM.feed(_rows(6, 4)), scan_steps=2)
+    before = {s["labels"]["layer"]: s["value"] for s in monitor.dump().get(
+        "moe_tokens_with_held_pair_total", {}).get("series", [])}
+    with lm.fitted_under_the_ledger(FAMILY) as net:
         dump = monitor.dump()
         layers = {"layer1", "layer2", "layer3", "layer4"}
         rows = SYSTEM.expert_rows_per_step()
@@ -232,13 +171,8 @@ def test_the_adapter_reads_the_counters_and_the_steps_scopes():
                 net.state[layer]["ffn"]["tokens_with_held_pair_total"])
         share = SYSTEM.tokens_with_held_pair_share()
         assert 0.2 < share < 0.8
-        scopes = SYSTEM.op_scopes()
-        seen = {m for m in SCOPES if any(m in s for s in scopes.values())}
-        assert seen == set(SCOPES), set(SCOPES) - seen
-        assert not any("moe/shared" in s for s in scopes.values())
-    finally:
-        xla.disable_ledger()
-        xla.clear_ledger()
+        assert not any("moe/shared" in s
+                       for s in SYSTEM.op_scopes().values())
 
 
 def test_the_counts_the_readers_need_come_from_the_configuration():

@@ -11,20 +11,11 @@ from deeplearning4j_tpu.models import SdarMoeLM
 from deeplearning4j_tpu.nn.conf.base import InputType
 from deeplearning4j_tpu.nn.layers import MoEFeedForward
 
-from _kimi_common import (  # noqa: F401 (the autouse fixture)
-    _budgets_at_the_tests_sizes, _close, _rows,
+import _lm_common as lm
+from _lm_common import (  # noqa: F401 (the autouse fixture)
+    _budgets_at_the_tests_sizes, _rows,
 )
-from _sdar_common import CFG, L, REF, STAGES, SYSTEM, _net
-
-
-def _example(cfg, rows, seed=5, first=0):
-    """(stream, targets, weights) of one host batch, the reference's."""
-    return REF.targets(cfg, REF.decode_tokens(cfg, rows), seed, first)
-
-
-def _score(net, params, stream, y, w):
-    return net._score_fn(params, net.state, (stream,), (y,), None, (w,),
-                         True, jax.random.PRNGKey(0))
+from _sdar_common import CFG, FAMILY, L, REF
 
 
 # --------------------------------------------- the whole model through fit()
@@ -32,7 +23,7 @@ def test_the_model_is_the_training_form_alone():
     """Embedding, ``num_hidden_layers`` blocks that are all alike (dense
     attention under the rule + experts), the slice to the noisy half, a
     final norm and an UNTIED weighted head over a stream of 2L ids."""
-    net, _ = _net()
+    net, _ = FAMILY.net()
     assert net.conf.network_outputs == ("head",)
     assert set(net.params) == {"embed", "norm", "head"} | {
         f"layer{i}" for i in range(3)}
@@ -61,63 +52,22 @@ def test_two_adamw_steps_through_fit_match_the_reference(how):
     the reference's `train_steps` with its own copy of the noise: the
     score (the weighted denoising loss), AdamW's first moment by stage
     and the update, as the benchmark's `correct` compares them."""
-    from benchmark.lib import checks
-    net, cfg = _net()
-    rows = _rows(11, 2)
-    stamps = SYSTEM.stamp_listener()
-    net.set_listeners(stamps)
-    net.fit(SYSTEM.feed(rows), **how)
-    losses = [loss for _, loss in stamps.rows]
-    r_losses, r_m, r_params = REF.train_steps(cfg, REF.make_params(cfg),
-                                              rows)
-    np.testing.assert_allclose(losses, r_losses, rtol=2e-6)
-    init = jax.device_get(REF.make_params(cfg))
-    diff = lambda new: checks.leaf_norms(jax.tree_util.tree_map(
-        lambda a, b: np.asarray(a) - np.asarray(b), new, init))
-    prog = {"losses": losses, "update": diff(net.params),
-            "momentum": checks.leaf_norms(SYSTEM.momentum(net))}
-    ref = {"losses": r_losses, "update": diff(r_params),
-           "momentum": checks.leaf_norms(r_m)}
-    limits = {"loss_gap": 2e-6, "head_momentum_gap": 1e-4,
-              "head_update_gap": 1e-4, "update_norm_gap": 1e-5,
-              "stage_momentum_gap": {s: 1e-4 for s in STAGES}}
-    rows_ = checks.training_rows(prog, ref,
-                                 lambda leaf: REF.stage_of(cfg, leaf), limits)
-    assert len(rows_) == 4 + len(STAGES) and checks.verdict(rows_)
-    assert checks.worst_leaf_gap(prog["update"], ref["update"]) < 1e-3
+    lm.two_adamw_steps_match(FAMILY, how)
 
 
 def test_logits_loss_and_every_gradient_leaf_match_the_reference():
     """Float32 on both sides: the noisy half's logits, the weighted loss
     of a batch of two sequences and EVERY leaf of its gradient element by
     element."""
-    net, cfg = _net()
-    stream, y, w = _example(cfg, _rows(4, 1)[0][0])
-    params = REF.make_params(cfg)
-    want_logits = REF.logits(cfg, params, stream[0])
-    got_probs = net.output(stream)
-    assert got_probs.shape == (2, L, 96)
-    np.testing.assert_allclose(got_probs[0],
-                               jax.nn.softmax(want_logits, axis=-1),
-                               atol=2e-6)
-    got_l, got = jax.value_and_grad(
-        lambda p: _score(net, p, stream, y, w)[0])(params)
-    want_l, want = jax.value_and_grad(lambda p: sum(
-        REF.loss_fn(cfg, p, stream[i], y[i], w[i]) for i in range(2)) / 2)(
-            params)
-    np.testing.assert_allclose(got_l, want_l, rtol=2e-6)
-    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
-                            jax.tree_util.tree_leaves(want)):
-        scale = max(np.abs(np.asarray(b)).max(), 1e-7)
-        assert np.abs(np.asarray(a - b)).max() <= 2e-4 * scale, \
-            jax.tree_util.keystr(path)
+    assert lm.logits_match(FAMILY).shape == (2, L, 96)
+    lm.every_gradient_leaf_matches(FAMILY)
 
 
 def test_the_loss_is_the_weighted_sum_over_the_count_of_tokens():
     """``(1 / (N L)) sum_seq sum_masked CE / t``: written out from the
     reference's logits and the weights."""
-    net, cfg = _net()
-    stream, y, w = _example(cfg, _rows(6, 1)[0][0])
+    net, cfg = FAMILY.net()
+    stream, y, w = FAMILY.example(cfg, _rows(6, 1)[0][0])
     params = REF.make_params(cfg)
     total = 0.0
     for i in range(2):
@@ -125,7 +75,7 @@ def test_the_loss_is_the_weighted_sum_over_the_count_of_tokens():
         z = z - z.max(-1, keepdims=True)
         nll = np.log(np.exp(z).sum(-1)) - z[np.arange(L), y[i]]
         total += float(np.sum(w[i] * nll))
-    np.testing.assert_allclose(_score(net, params, stream, y, w)[0],
+    np.testing.assert_allclose(FAMILY.score(net, params, (stream, y, w)),
                                total / (2 * L), rtol=2e-6)
     assert 0.3 < float((w > 0).mean()) < 0.7
 
@@ -134,18 +84,7 @@ def test_bfloat16_compute_stays_near_the_reference():
     """bf16 operands over float32 weights, as the cell runs: the score to
     half a percent of the float32 reference's, every stage's gradient norm
     to 5 %."""
-    from benchmark.lib import checks
-    net, cfg = _net(compute_dtype="bfloat16")
-    stream, y, w = _example(cfg, _rows(4, 1)[0][0][:1])
-    params = REF.make_params(cfg)
-    got_l, got = jax.value_and_grad(
-        lambda p: _score(net, p, stream, y, w)[0])(params)
-    want_l, want = jax.value_and_grad(
-        lambda p: REF.loss_fn(cfg, p, stream[0], y[0], w[0]))(params)
-    assert abs(float(got_l) - float(want_l)) < 5e-3 * float(want_l)
-    gaps = checks.stage_gaps(checks.leaf_norms(got), checks.leaf_norms(want),
-                             lambda leaf: REF.stage_of(cfg, leaf))
-    assert set(gaps) == set(STAGES) and max(gaps.values()) < 5e-2, gaps
+    lm.bfloat16_stays_near(FAMILY)
 
 
 @pytest.mark.parametrize("fault", REF.FAULTS)
@@ -153,19 +92,11 @@ def test_a_planted_fault_moves_what_correct_compares(fault):
     """The eight faults the limits have to catch, at the test's sizes:
     each moves a loss or a stage's first moment far more than float32
     rounding."""
-    from benchmark.lib import checks
     assert REF.FAULTS == (
         "plain_causal", "sees_own_clean_block", "noisy_sees_noisy_past",
         "positions_run_on", "no_weight", "mean_over_masked",
         "loss_on_clean_half", "kv_head_mod")
-    rows = _rows(11, 2)
-    sound = REF.train_steps(CFG, REF.make_params(CFG), rows)
-    bad = REF.train_steps(CFG, REF.make_params(CFG), rows, fault=fault)
-    loss = max(abs(a - b) / abs(b) for a, b in zip(bad[0], sound[0]))
-    stage = checks.stage_gaps(checks.leaf_norms(bad[1]),
-                              checks.leaf_norms(sound[1]),
-                              lambda leaf: REF.stage_of(CFG, leaf))
-    assert max(loss, *stage.values()) > 1e-3, (fault, loss, stage)
+    lm.a_planted_fault_moves(FAMILY, fault)
 
 
 def test_no_leak_through_the_model():
@@ -174,8 +105,8 @@ def test_no_leak_through_the_model():
     with the loss on one block's rows alone, the embedded clean rows of
     that block and of every later one get nothing, the earlier ones
     something."""
-    net, cfg = _net()
-    stream, y, w = _example(cfg, _rows(4, 1)[0][0][:1])
+    net, cfg = FAMILY.net()
+    stream, y, w = FAMILY.example(cfg, _rows(4, 1)[0][0][:1])
     params = REF.make_params(cfg)
     blk = 9
     only = np.zeros_like(w)
@@ -186,12 +117,12 @@ def test_no_leak_through_the_model():
     stream[0, L:] = np.arange(L) % 90
 
     def loss(table):
-        return _score(net, {**params, "embed": {"W": table}},
-                      stream, y, only)[0]
+        return FAMILY.score(net, {**params, "embed": {"W": table}},
+                            (stream, y, only))
 
     # rows of the table only the clean half reads
     noisy_ids = set(stream[0, :L].tolist())
-    g = np.asarray(jax.grad(loss)(params["embed"]["W"]))
+    g = np.asarray(jax.jit(jax.grad(loss))(params["embed"]["W"]))
     for pos in range(L):
         tok = int(stream[0, L + pos])
         if tok in noisy_ids or pos >= 90:
@@ -204,44 +135,7 @@ def test_no_leak_through_the_model():
 
 # ------------------------------------------------- the shares of a layer
 def test_the_eight_shares_add_up_and_an_unheld_token_gets_exactly_zero():
-    """`y = alike + sum over the chips of (what each chip's experts add)`:
-    with a softmax router and no shared expert the eight shares' partial
-    results of one expert layer add up to the uncut reference's whole
-    layer over the stream's 2L rows, and a row none of whose experts a
-    chip holds gets exactly zero from that chip."""
-    cfg = {**CFG, "experts_held": [0, 16], "num_experts": 16}
-    whole = REF.make_params(cfg)["layer1"]
-    t = 2 * L
-    x = jax.random.normal(jax.random.PRNGKey(5), (t, 32))
-    want = REF.layer(cfg, whole, x)
-    eps = cfg["rms_norm_eps"]
-    alike = x + REF.attention(cfg, whole["attn"],
-                              REF._rms(x, whole["ln1"]["gamma"], eps))
-    normed = REF._rms(alike, whole["ln2"]["gamma"], eps)
-    total = np.zeros((t, 32), np.float32)
-    for lo in range(0, 16, 2):
-        ffn = MoEFeedForward(
-            n_out=32, n_experts=16, top_k=2, hidden=24, activation="swish",
-            gated=True, has_bias=False, experts_held=(lo, lo + 2),
-            router="softmax", n_shared=0, weight_init="normal")
-        p = {"Wr": whole["ffn"]["Wr"],
-             **{k: whole["ffn"][k][lo:lo + 2]
-                for k in ("Wgate", "Wup", "Wdown")}}
-        _, state = ffn.init(jax.random.PRNGKey(0),
-                            InputType.recurrent(32, t))
-        out, new = ffn.apply(p, state, normed[None])
-        out = np.asarray(out[0])
-        # one share alone is the reference told to hold the same experts
-        share = REF.experts({**cfg, "experts_held": [lo, lo + 2],
-                             "num_experts": 2}, p, normed)
-        _close(out, share, 2e-5)
-        idx, _ = REF.routing(cfg, whole["ffn"], normed)
-        unheld = ~np.any((np.asarray(idx) >= lo)
-                         & (np.asarray(idx) < lo + 2), axis=-1)
-        assert unheld.any() and not np.any(out[unheld])
-        assert int(new["tokens_with_held_pair_total"]) == int((~unheld).sum())
-        total += out
-    _close(alike + total, want, 2e-5)
+    lm.the_eight_shares_add_up(FAMILY)
 
 
 # ------------------------------------------------- the ladder of row tiers

@@ -24,7 +24,8 @@ from deeplearning4j_tpu.models.zoo import (
     zoo_models,
 )
 from deeplearning4j_tpu.models.transformer import (
-    Glm4MoeLiteLM, KeyeVL2LM, KimiLinearLM, Lfm2MoeLM, SdarMoeLM,
+    Glm4MoeLiteLM, KeyeVL2LM, KimiLinearLM, Lfm2MoeLM, NemotronHLM,
+    SdarMoeLM,
     TransformerLM, TransformerLMMoE,
 )
 
@@ -34,6 +35,6 @@ __all__ = [
     "TextGenerationLSTM", "InceptionResNetV1", "FaceNetNN4Small2", "UNet",
     "TransformerLM", "TransformerLMMoE", "KimiLinearLM", "Glm4MoeLiteLM",
     "Lfm2MoeLM",
-    "KeyeVL2LM", "SdarMoeLM",
+    "KeyeVL2LM", "SdarMoeLM", "NemotronHLM",
     "model_by_name", "zoo_models",
 ]

@@ -38,6 +38,12 @@ with the frequencies shared out among three rows of positions, SPARSE: a
 learned indexer picks the keys each query attends and is trained by its own
 loss; softmax-routed SwiGLU experts with no shared one in every layer; an
 untied head.
+
+`NemotronHLM` is NVIDIA's Nemotron-H family (``nemotron_h``): every block
+ONE mixer behind a pre-norm, named by a pattern string: Mamba-2 state-space
+layers, position-free grouped-query attention, and expert layers whose
+routed ReLU^2 experts live in a latent of the stream beside a ReLU^2 shared
+expert on it; an untied head.
 """
 from __future__ import annotations
 
@@ -712,4 +718,127 @@ class SdarMoeLM(ZooModel):
             n_out=self.vocab_size, activation="softmax",
             loss="sparse_mcxent", has_bias=False, weight_init="normal",
             weighted=True), "norm")
+        return g.set_outputs("head").build()
+
+
+@dataclasses.dataclass
+class NemotronHLM(ZooModel):
+    """Decoder-only LM of NVIDIA's Nemotron-H family (``model_type``
+    ``nemotron_h``): token embedding -> blocks that are ONE mixer behind a
+    pre-norm each (`MixerBlock`: ``y = x + mixer(RMSNorm(x))``, no biases
+    but the convolution's) -> RMSNorm -> an UNTIED head, sparse
+    cross-entropy over blocks of positions, as a `ComputationGraph` over
+    one input of token ids.
+
+    ``pattern`` names each block's mixer as the published
+    ``hybrid_override_pattern`` does: ``M`` a `Mamba2Mixer`
+    (``mamba_heads`` heads of ``mamba_head_dim`` in ``mamba_groups`` groups
+    of state ``state_dim``, taps of ``conv_kernel`` with a bias, chunks of
+    ``chunk``), ``*`` a `MultiHeadAttention` of ``n_heads`` query heads on
+    ``n_kv_heads`` key/value heads of ``head_dim``, causal, with NO
+    rotation and no q/k norm (the state-space layers carry the order), on
+    the fused kernel where there is a TPU, ``E`` the expert layer: a
+    sigmoid router over ``n_experts`` on the stream, ``top_k`` a token
+    chosen by the scores plus a non-trained bias, weights renormalised and
+    scaled by ``routed_scale``; un-gated ReLU^2 experts of
+    ``expert_hidden`` inside a latent of ``latent`` (``x W_down`` in,
+    their weighted sum through ``W_up`` out); ONE un-gated ReLU^2 shared
+    expert of ``shared_hidden`` on the stream. ``experts_held`` is the
+    range of experts this chip holds (None: all). A tensor-parallel slice
+    of a Mamba-2 or attention layer is that layer with the slice's heads
+    and groups: the sizes say it, no option does.
+
+    The family's multi-token-prediction module is not built. Defaults: the
+    published shape cut to widths a CPU test can run;
+    `benchmark/configs/nemotron-3-super-120b-a12b.json` holds the
+    published sizes."""
+    vocab_size: int = 1024
+    seq_length: int = 256
+    n_embd: int = 128
+    pattern: str = "*EMEME"
+    mamba_heads: int = 4
+    mamba_head_dim: int = 16
+    mamba_groups: int = 1
+    state_dim: int = 32
+    conv_kernel: int = 4
+    chunk: int = 128
+    n_heads: int = 4
+    n_kv_heads: int = 1
+    head_dim: int = 32
+    n_experts: int = 16
+    top_k: int = 4
+    expert_hidden: int = 64
+    latent: int = 64
+    shared_hidden: int = 256
+    routed_scale: float = 1.0
+    experts_held: Optional[Tuple[int, int]] = None
+    dt_min: float = 1e-3
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+    rms_norm_eps: float = 1e-5
+    learning_rate: float = 1e-4
+    beta1: float = 0.9
+    beta2: float = 0.95
+    epsilon: float = 1e-8
+    weight_decay: float = 0.1
+    compute_dtype: Optional[str] = None
+    gradient_checkpointing: bool = True
+    seed: int = 123
+    block_size: int = 512
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.layers.attention import (
+            MixerBlock, MultiHeadAttention,
+        )
+        from deeplearning4j_tpu.nn.layers.linear_attention import Mamba2Mixer
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(AdamW(self.learning_rate, beta1=self.beta1,
+                            beta2=self.beta2, epsilon=self.epsilon,
+                            weight_decay=self.weight_decay,
+                            decay_matrices_only=True))
+             .gradient_checkpointing(self.gradient_checkpointing))
+        if self.compute_dtype:
+            b = b.compute_dtype(self.compute_dtype)
+        g = b.graph_builder().add_inputs("ids").set_input_types(
+            InputType.recurrent(1, self.seq_length))
+        mixers = {
+            "M": Mamba2Mixer(
+                n_out=self.n_embd, n_heads=self.mamba_heads,
+                head_dim=self.mamba_head_dim, n_groups=self.mamba_groups,
+                state_dim=self.state_dim, conv_kernel=self.conv_kernel,
+                chunk=self.chunk, norm_epsilon=self.rms_norm_eps,
+                dt_min=self.dt_min, dt_max=self.dt_max,
+                dt_floor=self.dt_floor, weight_init="normal"),
+            "*": MultiHeadAttention(
+                n_out=self.n_embd, n_heads=self.n_heads,
+                n_kv_heads=self.n_kv_heads, head_dim=self.head_dim,
+                causal=True, use_rope=False, has_bias=False,
+                attention_impl="flash", block_size=self.block_size,
+                weight_init="normal"),
+            "E": MoEFeedForward(
+                n_out=self.n_embd, n_experts=self.n_experts,
+                top_k=self.top_k, hidden=self.expert_hidden,
+                activation="relu2", gated=False, has_bias=False,
+                experts_held=self.experts_held, router="sigmoid",
+                routed_scale=self.routed_scale, n_shared=1,
+                shared_hidden=self.shared_hidden, latent=self.latent,
+                weight_init="normal"),
+        }
+        g.add_layer("embed", EmbeddingSequenceLayer(
+            n_out=self.n_embd, n_in=self.vocab_size), "ids")
+        last = "embed"
+        for i, kind in enumerate(self.pattern):
+            if kind not in mixers:
+                raise ValueError(f"pattern[{i}] = {kind!r}: 'M' (Mamba-2), "
+                                 "'*' (attention) or 'E' (experts)")
+            g.add_layer(f"layer{i}", MixerBlock(
+                n_out=self.n_embd, mixer=mixers[kind], norm="rms",
+                norm_epsilon=self.rms_norm_eps), last)
+            last = f"layer{i}"
+        g.add_layer("norm", RMSNormLayer(epsilon=self.rms_norm_eps), last)
+        g.add_layer("head", RnnOutputLayer(
+            n_out=self.vocab_size, activation="softmax",
+            loss="sparse_mcxent", has_bias=False, weight_init="normal"),
+            "norm")
         return g.set_outputs("head").build()

@@ -70,7 +70,17 @@ PART_SCOPES = (
                "mean probabilities, and the loss's way into the step"),
     ("sconv/proj", "the gated short convolution's two projections"),
     ("sconv/mix", "its gates and depth-wise causal taps"),
+    ("ssd/proj", "a Mamba-2 mixer's input projection to [z ; x' ; B ; C ; "
+                 "dt]"),
+    ("ssd/conv", "its depth-wise causal taps, their bias and SiLU"),
+    ("ssd/scan", "its step size, decay and the chunked state-space "
+                 "recurrence (two kernels that hand the state over; off "
+                 "the TPU a scan), the D skip"),
+    ("ssd/out", "its gated group norm and output projection"),
     ("moe/route", "router scores, top-k and the kept experts' weights"),
+    ("moe/latent", "the two projections of an expert layer whose routed "
+                   "experts live in a latent: stream -> latent, latent -> "
+                   "stream"),
     ("moe/dispatch", "the sort of (token, slot) pairs and the gather of "
                      "token rows"),
     ("moe/experts", "the grouped matrix products and the activation "

@@ -732,7 +732,8 @@ def compiled_ops(compiled) -> List[dict]:
 #: compiled instruction (`flash_fwd.133`, `kda_chunk_bwd.37`)
 PALLAS_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                   "kda_chunk_fwd", "kda_chunk_bwd", "dsa_index",
-                  "dsa_select", "dsa_attn_fwd", "dsa_kl_fwd", "dsa_attn_bwd")
+                  "dsa_select", "dsa_attn_fwd", "dsa_kl_fwd", "dsa_attn_bwd",
+                  "ssd_chunk_fwd", "ssd_chunk_bwd")
 
 
 def kernel_calls(op_scopes: Dict[str, str]) -> Dict[str, int]:

@@ -22,6 +22,11 @@ def relu(x):
     return jax.nn.relu(x)
 
 
+def relu2(x):
+    """``relu(x)^2`` (the Nemotron-H family's ``mlp_hidden_act``)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def relu6(x):
     return jnp.clip(x, 0.0, 6.0)
 
@@ -100,6 +105,7 @@ ACTIVATIONS = {
     "identity": identity,
     "linear": identity,
     "relu": relu,
+    "relu2": relu2,
     "relu6": relu6,
     "leakyrelu": leakyrelu,
     "elu": elu,

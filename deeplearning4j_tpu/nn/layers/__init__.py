@@ -24,12 +24,13 @@ from deeplearning4j_tpu.nn.layers.samediff import SameDiffLayer, FrozenLayerWrap
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2OutputLayer
 from deeplearning4j_tpu.nn.layers.attention import (
     EmbeddingSequenceLayer, GatedMLP, LayerNormLayer, LightningIndexer,
-    LinearProjection, MoEFeedForward, attach_auxiliary_loss,
+    LinearProjection, MixerBlock, MoEFeedForward, attach_auxiliary_loss,
     RMSNormLayer, MultiHeadAttention, PositionalEmbeddingLayer,
     TransformerBlock,
 )
 from deeplearning4j_tpu.nn.layers.linear_attention import (
-    GatedShortConv, KimiDeltaAttention, MultiHeadLatentAttention,
+    GatedShortConv, KimiDeltaAttention, Mamba2Mixer,
+    MultiHeadLatentAttention,
 )
 
 __all__ = [
@@ -52,7 +53,7 @@ __all__ = [
     "MultiHeadAttention", "TransformerBlock", "MoEFeedForward",
     "LightningIndexer", "attach_auxiliary_loss",
     "RMSNormLayer", "GatedMLP", "LinearProjection", "KimiDeltaAttention",
-    "GatedShortConv",
+    "GatedShortConv", "Mamba2Mixer", "MixerBlock",
     "MultiHeadLatentAttention",
     "LayerNormLayer", "PositionalEmbeddingLayer", "EmbeddingSequenceLayer",
 ]

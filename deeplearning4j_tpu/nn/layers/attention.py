@@ -1021,9 +1021,19 @@ class MoEFeedForward(LayerConf):
     output row each) would pass `_DISPATCH_LIVE_BYTES`, the tokens are
     dispatched in the fewest equal blocks that stay under it, one block
     after another and each rematerialised in the backward pass.
-    ``n_shared > 0`` adds ONE gated expert of width ``n_shared * hidden``
-    that every token passes (scope ``moe/shared``); a chip that holds a
-    share of the experts holds the shared expert whole.
+    ``n_shared > 0`` adds ONE expert of the experts' own kind (gated
+    beside gated experts: ``Wgate_s``, ``Wup_s``, ``Wdown_s``; plain and
+    bias-free beside plain ones: ``W1_s``, ``W2_s``) that every token
+    passes (scope ``moe/shared``), of width ``shared_hidden`` (None:
+    ``n_shared * hidden``); a chip that holds a share of the experts holds
+    the shared expert whole. ``latent`` (a width) puts the ROUTED experts
+    in a latent of the stream: the router and the shared expert read the
+    stream, the held experts read ``x Wl_down`` (F -> latent) and work at
+    the latent's width, and their weighted sum goes through ``Wl_up``
+    (latent -> F) (scope ``moe/latent``: the two projections, whole on
+    every chip). What is not built is refused by name at `init`: a shared
+    expert beside experts with biases, a latent with biases or with the
+    softmax router.
 
     The layer's state keeps the tokens each of the ``n_experts`` experts
     drew in the last step (``tokens_routed``) and in all steps so far
@@ -1047,7 +1057,9 @@ class MoEFeedForward(LayerConf):
     experts_held: Optional[Tuple[int, int]] = None
     router: str = "softmax"             # | "sigmoid"
     routed_scale: float = 1.0           # sigmoid router only
-    n_shared: int = 0                   # gated layers only
+    n_shared: int = 0                   # one expert every token passes
+    shared_hidden: Optional[int] = None  # its width (None: n_shared*hidden)
+    latent: Optional[int] = None        # the routed experts' width (None: F)
     weight_init: str = "xavier"
 
     def output_type(self, input_type: InputType) -> InputType:
@@ -1070,12 +1082,19 @@ class MoEFeedForward(LayerConf):
         if self.router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}: 'softmax' "
                              "or 'sigmoid'")
-        if self.n_shared and not self.gated:
-            raise ValueError("a shared expert needs gated=True")
+        if self.n_shared and self.has_bias:
+            raise ValueError("a shared expert beside experts with biases is "
+                             "not built: has_bias=False")
+        if self.latent and (self.has_bias or self.router != "sigmoid"):
+            raise ValueError("experts in a latent are built bias-free "
+                             "behind the sigmoid router only: not with "
+                             f"has_bias={self.has_bias}, "
+                             f"router={self.router!r}")
         hidden = self.hidden or self.mlp_ratio * self.n_out
         w_init = get_initializer(self.weight_init)
         ks = jax.random.split(key, 3)
         lo, hi = self._held()
+        stream, f_in = f_in, self.latent or f_in   # the experts' own width
 
         def ew(k, shape, fi, fo):
             # one key per expert of the WHOLE layer: a share holds the
@@ -1084,26 +1103,34 @@ class MoEFeedForward(LayerConf):
             return jnp.stack([w_init(keys[i], shape, fi, fo, dtype)
                               for i in range(lo, hi)])
 
-        p = {"Wr": w_init(ks[0], (f_in, self.n_experts), f_in,
+        p = {"Wr": w_init(ks[0], (stream, self.n_experts), stream,
                           self.n_experts, dtype)}
         if self.gated:
             p["Wgate"] = ew(ks[1], (f_in, hidden), f_in, hidden)
             p["Wup"] = ew(jax.random.fold_in(ks[1], 1), (f_in, hidden), f_in,
                           hidden)
-            p["Wdown"] = ew(ks[2], (hidden, self.n_out), hidden, self.n_out)
+            p["Wdown"] = ew(ks[2], (hidden, f_in), hidden, f_in)
         else:
             p["W1"] = ew(ks[1], (f_in, hidden), f_in, hidden)
-            p["W2"] = ew(ks[2], (hidden, self.n_out), hidden, self.n_out)
+            p["W2"] = ew(ks[2], (hidden, f_in), hidden, f_in)
         if self.has_bias:
             p["b1"] = jnp.zeros((hi - lo, hidden), dtype)
             p["b2"] = jnp.zeros((hi - lo, self.n_out), dtype)
+        if self.latent:
+            kd, ku = jax.random.split(jax.random.fold_in(key, 11))
+            p["Wl_down"] = w_init(kd, (stream, f_in), stream, f_in, dtype)
+            p["Wl_up"] = w_init(ku, (f_in, stream), f_in, stream, dtype)
         if self.n_shared:
-            wide = self.n_shared * hidden
+            wide = self.shared_hidden or self.n_shared * hidden
             kg, ku, kd = jax.random.split(jax.random.fold_in(key, 7), 3)
-            p["Wgate_s"] = w_init(kg, (f_in, wide), f_in, wide, dtype)
-            p["Wup_s"] = w_init(ku, (f_in, wide), f_in, wide, dtype)
-            p["Wdown_s"] = w_init(kd, (wide, self.n_out), wide, self.n_out,
-                                  dtype)
+            mat = lambda k, fi, fo: w_init(k, (fi, fo), fi, fo, dtype)
+            if self.gated:
+                p["Wgate_s"] = mat(kg, stream, wide)
+                p["Wup_s"] = mat(ku, stream, wide)
+                p["Wdown_s"] = mat(kd, wide, stream)
+            else:
+                p["W1_s"] = mat(kg, stream, wide)
+                p["W2_s"] = mat(kd, wide, stream)
         state = {"tokens_routed": jnp.zeros((self.n_experts,), jnp.int32),
                  "tokens_routed_total": jnp.zeros((self.n_experts,),
                                                   jnp.uint32)}
@@ -1152,7 +1179,8 @@ class MoEFeedForward(LayerConf):
             out, counts = self._dispatch(params, h, idx, w)
             return out.reshape(shape), counts
         weights = {k: v for k, v in params.items()
-                   if k != "Wr" and not k.endswith("_s")}
+                   if k != "Wr" and not k.endswith("_s")
+                   and not k.startswith("Wl_")}
         # a dispatch over tiers rematerialises the tier it walked itself
         one = self._dispatch if len(self._tiers(blk * self.top_k)) > 1 \
             else jax.checkpoint(self._dispatch)
@@ -1240,9 +1268,13 @@ class MoEFeedForward(LayerConf):
 
     def shared(self, params, h):
         """The shared expert's part: every token, no routing."""
+        from deeplearning4j_tpu.nn.activations import get_activation
         with jax.named_scope("moe/shared"):
-            return _gated_mlp(h, params["Wgate_s"], params["Wup_s"],
-                              params["Wdown_s"], self.activation)
+            if self.gated:
+                return _gated_mlp(h, params["Wgate_s"], params["Wup_s"],
+                                  params["Wdown_s"], self.activation)
+            return get_activation(self.activation)(h @ params["W1_s"]) \
+                @ params["W2_s"]
 
     def counted(self, state, counts):
         """``state`` with what a step's dispatches counted added in."""
@@ -1261,8 +1293,15 @@ class MoEFeedForward(LayerConf):
         return new
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        out, counts = self.experts(params, x,
-                                   *self.route(params, state, x))
+        routing = self.route(params, state, x)
+        if self.latent:
+            with jax.named_scope("moe/latent"):
+                rows = (x @ params["Wl_down"]).reshape(-1, self.latent)
+            out, counts = self.experts(params, rows, *routing)
+            with jax.named_scope("moe/latent"):
+                out = (out @ params["Wl_up"]).reshape(x.shape)
+        else:
+            out, counts = self.experts(params, x, *routing)
         shared = self.shared(params, x) if self.n_shared else None
         with jax.named_scope("residual"):
             if shared is not None:
@@ -1433,6 +1472,48 @@ class TransformerBlock(LayerConf):
             if mask is not None:
                 y = y * mask[..., None].astype(y.dtype)
         return y, state
+
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class MixerBlock(LayerConf):
+    """A block that is ONE mixer behind a pre-norm: ``y = x +
+    mixer(norm(x))`` (the Nemotron-H family's block, whose pattern string
+    names each block's mixer: a `linear_attention.Mamba2Mixer`, a
+    `MultiHeadAttention` or a `MoEFeedForward`; `TransformerBlock` is an
+    attention AND a feed-forward). ``norm``: "layer" or "rms". The
+    mixer's state (an expert layer's counters) is the block's own."""
+    n_out: int = 0
+    mixer: Optional[LayerConf] = None
+    norm: str = "rms"
+    norm_epsilon: Optional[float] = None
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType(Kind.RNN, (input_type.shape[0], self.n_out))
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        if self.mixer is None or input_type.features != self.n_out:
+            raise ValueError(
+                f"MixerBlock needs a mixer and input width == n_out "
+                f"({input_type.features} != {self.n_out})")
+        k_norm, k_mixer = jax.random.split(key)
+        ln, _ = _norm_layer(self.norm, self.norm_epsilon).init(
+            k_norm, input_type, dtype)
+        mixer, state = self.mixer.init(k_mixer, input_type, dtype)
+        return {"ln": ln, "mixer": mixer}, state
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        h, _ = _norm_layer(self.norm, self.norm_epsilon).apply(
+            params["ln"], {}, x)
+        h, state = self.mixer.apply(params["mixer"], state, h, train=train,
+                                    rng=rng, mask=mask)
+        with jax.named_scope("residual"):
+            y = x + h
+            if mask is not None:
+                y = y * mask[..., None].astype(y.dtype)
+        return y, state
+
 
 @register_layer
 @dataclasses.dataclass(frozen=True)

@@ -37,6 +37,13 @@ Those dims are carried as they are (``rotate=False``: Kimi-Linear's
 ``mla_use_nope``, 128 + 64 / 128) or rotated by the token's position
 (``rotate=True``: the GLM / DeepSeek families, 192 + 64 / 256).
 
+`Mamba2Mixer` is the Nemotron-H family's state-space layer (Mamba-2): a
+scalar decay a head over a state of ``head_dim x state_dim``, the heads of
+a group sharing their ``B`` and ``C``, in chunks (`ops/ssd_chunk.py`: on a
+TPU two kernels that hand the state over in VMEM, elsewhere the same chunk
+function under a `lax.scan`), behind depth-wise causal taps with a bias and
+in front of a gated group norm.
+
 `GatedShortConv` is LFM2's operator, three layers to one of grouped-query
 attention there: a depth-wise causal convolution of a few taps
 (`causal_conv`, the helper KDA's q, k and v branches run on) between two
@@ -61,6 +68,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
 )
 from deeplearning4j_tpu.ops import REMAT_KEEP
 from deeplearning4j_tpu.ops.kda_chunk import chunk_scan
+from deeplearning4j_tpu.ops.ssd_chunk import ssd_chunked
 from deeplearning4j_tpu.util.platform import is_tpu_backend
 
 
@@ -312,6 +320,117 @@ class GatedShortConv(LayerConf):
                      ).astype(x.dtype)
         with jax.named_scope("sconv/proj"):
             return mixed @ params["Wout"], state
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class Mamba2Mixer(LayerConf):
+    """Mamba-2 over (B, T, F), causal by construction (the Nemotron-H
+    family's ``M`` mixer). With ``d_inner = n_heads * head_dim`` and
+    ``n_groups`` groups of state ``state_dim``: ``[z ; u ; dt] = x W_in``
+    (z of d_inner, u = [x' ; B ; C] of d_inner + 2 * n_groups * state_dim,
+    dt of n_heads; no bias); ``u <- silu(causal_conv(u, taps) + conv_b)``
+    (depth-wise, ``conv_kernel`` taps, zeros before position 0, the LAST
+    tap meets the current position); ``D_t = softplus(dt_t + dt_bias)``,
+    ``a_t = exp(D_t A)`` with ``A = -exp(A_log)`` one scalar a head; head
+    h of group ``h // (n_heads / n_groups)`` keeps a state of ``head_dim x
+    state_dim``: ``S_t = a_t S_{t-1} + D_t x'_t B_t^T``, ``y_t = S_t C_t +
+    D_h x'_t`` (`ops.ssd_chunk.ssd_chunked`, chunks of ``chunk``); ``o =
+    RMSNorm_group(y * silu(z)) * gamma``, the mean of squares over each
+    GROUP's ``d_inner / n_groups`` channels (the gate before the norm);
+    ``o W_out``. The taps, the step size, the decay and the norm run in
+    float32 whatever the compute dtype; the products (the two projections
+    and the chunk algebra's four) take their operands in it. A
+    tensor-parallel slice of the layer IS a smaller layer (fewer heads and
+    groups): there is no option for a share held. Scopes: ``ssd/proj``,
+    ``ssd/conv``, ``ssd/scan``, ``ssd/out``."""
+    n_out: int = 0
+    n_heads: int = 8
+    head_dim: int = 64
+    n_groups: int = 1
+    state_dim: int = 128
+    conv_kernel: int = 4
+    chunk: int = 128
+    norm_epsilon: float = 1e-5
+    dt_min: float = 1e-3
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+    weight_init: str = "xavier"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return InputType(Kind.RNN, (input_type.shape[0], self.n_out))
+
+    def _widths(self):
+        """(d_inner, the convolved width d_inner + 2 * groups * state)."""
+        inner = self.n_heads * self.head_dim
+        return inner, inner + 2 * self.n_groups * self.state_dim
+
+    def init(self, key, input_type: InputType, dtype=jnp.float32):
+        if self.n_heads % self.n_groups:
+            raise ValueError(f"n_groups {self.n_groups} does not divide "
+                             f"n_heads {self.n_heads}")
+        f, h = input_type.features, self.n_heads
+        inner, conv = self._widths()
+        w_init = get_initializer(self.weight_init)
+        k_in, k_taps, k_dt, k_a, k_out = jax.random.split(key, 5)
+        # the family's convention: a depth-wise Conv1d's default taps
+        # U(-K^-1/2, K^-1/2) and a zero bias, dt log-uniform in [dt_min,
+        # dt_max] floored at dt_floor with dt_bias its inverse softplus, A
+        # uniform in [1, 16], D = 1
+        bound = self.conv_kernel ** -0.5
+        dt = jnp.maximum(jnp.exp(
+            jax.random.uniform(k_dt, (h,), jnp.float32)
+            * (jnp.log(self.dt_max) - jnp.log(self.dt_min))
+            + jnp.log(self.dt_min)), self.dt_floor)
+        return {
+            "Win": w_init(k_in, (f, inner + conv + h), f, inner + conv + h,
+                          dtype),
+            "conv": jax.random.uniform(k_taps, (self.conv_kernel, conv),
+                                       dtype, -bound, bound),
+            "conv_b": jnp.zeros((conv,), dtype),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "A_log": jnp.log(jax.random.uniform(
+                k_a, (h,), jnp.float32, 1.0, 16.0)).astype(dtype),
+            "D": jnp.ones((h,), dtype),
+            "norm": jnp.ones((inner,), dtype),
+            "Wout": w_init(k_out, (inner, self.n_out), inner, self.n_out,
+                           dtype),
+        }, {}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        if mask is not None:
+            raise NotImplementedError(
+                "Mamba2Mixer takes whole sequences (no mask)")
+        b, t, _ = x.shape
+        h, p, g, n = (self.n_heads, self.head_dim, self.n_groups,
+                      self.state_dim)
+        inner, conv = self._widths()
+        f32 = jnp.promote_types(jnp.float32, x.dtype)
+        with jax.named_scope("ssd/proj"):
+            zud = x @ params["Win"]
+        with jax.named_scope("ssd/conv"):
+            u = jax.nn.silu(
+                causal_conv(zud[..., inner:inner + conv].astype(f32),
+                            params["conv"].astype(f32))
+                + params["conv_b"].astype(f32))
+        with jax.named_scope("ssd/scan"):
+            step = jax.nn.softplus(zud[..., inner + conv:].astype(f32)
+                                   + params["dt_bias"].astype(f32))
+            xs = u[..., :inner].reshape(b, t, h, p)
+            bc = u[..., inner:].reshape(b, t, 2, g, n)
+            y, _ = ssd_chunked(
+                xs, step, -jnp.exp(params["A_log"].astype(f32)), bc[:, :, 0],
+                bc[:, :, 1], chunk=self.chunk, mm_dtype=x.dtype)
+            y = y + params["D"].astype(f32)[:, None] * xs
+        with jax.named_scope("ssd/out"):
+            gated = (y.reshape(b, t, inner)
+                     * jax.nn.silu(zud[..., :inner].astype(f32)))
+            of_group = gated.reshape(b, t, g, inner // g)
+            o = (of_group * jax.lax.rsqrt(
+                jnp.mean(of_group * of_group, axis=-1, keepdims=True)
+                + self.norm_epsilon)).reshape(b, t, inner) \
+                * params["norm"].astype(f32)
+            return o.astype(x.dtype) @ params["Wout"], state
 
 
 def rope_pairs(x, positions, theta):

@@ -384,8 +384,9 @@ class ProfilerListener(TrainingListener):
 
 
 class ExpertLoadListener(TrainingListener):
-    """What a model's expert layers (`MoEFeedForward`, alone or as a
-    `TransformerBlock`'s FFN) routed and walked, as counters. The layers
+    """What a model's expert layers (`MoEFeedForward`, alone, as a
+    `TransformerBlock`'s FFN or as a `MixerBlock`'s mixer) routed and
+    walked, as counters. The layers
     count in their own STATE, so every fit path of both containers counts
     alike; this listener reads the state where ``fit()`` holds a finished
     one, at the start and the end of an epoch (inside an epoch the
@@ -428,6 +429,8 @@ class ExpertLoadListener(TrainingListener):
             state = model.state.get(key) or {}
             if getattr(layer, "ffn", None) is not None:
                 layer, state = layer.ffn, state.get("ffn", {})
+            elif getattr(layer, "mixer", None) is not None:
+                layer = layer.mixer      # a MixerBlock's state is its mixer's
             if "tokens_routed_total" in state:
                 yield key, layer, state
 

@@ -476,6 +476,21 @@ def _mln_lm():
     return net, (ids, ids, None, jnp.ones((2, 2, T), jnp.float32))
 
 
+def _mixer_lm():
+    """The zoo's `NemotronHLM` at tiny widths: blocks of ONE mixer
+    (attention, experts in a latent with half of them held: one row tier,
+    as `_mln_lm`'s share, whose walk is scoped whole; Mamba-2)."""
+    from deeplearning4j_tpu.models import NemotronHLM
+    net = NemotronHLM(
+        vocab_size=V, seq_length=T, n_embd=F, pattern="*EM", mamba_heads=2,
+        mamba_head_dim=8, state_dim=8, chunk=8, n_heads=2, n_kv_heads=1,
+        head_dim=8, n_experts=8, top_k=2, expert_hidden=16, latent=8,
+        shared_hidden=32, experts_held=(0, 4), block_size=16,
+        compute_dtype="bfloat16").init()
+    ids = jnp.zeros((2, 2, T), jnp.int32)
+    return net, ((ids,), (ids,), None, (jnp.ones((2, 2, T), jnp.float32),))
+
+
 def _conv_graph():
     from deeplearning4j_tpu.nn.conf.base import InputType
     from deeplearning4j_tpu.nn.conf.graph_vertices import ElementWiseVertex
@@ -528,7 +543,12 @@ _PLUMBING = {"kstep", "while", "body", "cond", "closed_call", "checkpoint",
                "opt/update"}, {"0", "1", "2", "3", "4"}),
     (_conv_graph, {"cast", "conv", "bn", "pool", "head/loss", "opt/update"},
      {"c1", "bn1", "pool", "c2", "avg", "out"}),
-], ids=["graph_lm", "multilayer_lm", "conv_graph"])
+    (_mixer_lm, {"cast", "embed", "norm", "residual", "mha/proj", "mha/attn",
+                 "ssd/proj", "ssd/conv", "ssd/scan", "ssd/out", "moe/route",
+                 "moe/latent", "moe/dispatch", "moe/experts", "moe/shared",
+                 "moe/combine", "head/loss", "opt/update"},
+     {"embed", "layer0", "layer1", "layer2", "norm", "head"}),
+], ids=["graph_lm", "multilayer_lm", "conv_graph", "mixer_lm"])
 def test_every_op_of_the_scan_step_has_a_layer_and_a_part(build, parts,
                                                           layers):
     net, operands = build()

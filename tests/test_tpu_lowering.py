@@ -427,6 +427,79 @@ class TestFlashKernelCompiles:
         assert not re.search(
             r"f32\[128,8,128,128\]\S* dynamic-update-slice", hlo)
 
+    @pytest.mark.parametrize("n,chunk,kernels", [(128, 128, True),
+                                                 (32, 32, False)])
+    def test_ssd_chunked_scan_compiles_with_its_backward(self, v5e, n, chunk,
+                                                         kernels,
+                                                         monkeypatch):
+        # the Nemotron cell's Mamba-2 slice: 2 sequences of 8,192
+        # positions in chunks of 128, 16 heads of 64 on ONE group of state
+        # 128, bf16 products: the two Pallas kernels hand the heads' states
+        # over themselves, so there is no loop over the 64 chunks. At a
+        # state width the tiling does not take (32), neither kernel and the
+        # `lax.scan` over the chunks.
+        from deeplearning4j_tpu.ops import ssd_chunk
+        monkeypatch.setattr(ssd_chunk, "is_tpu_backend", lambda: True)
+        t = 8192 if kernels else 512
+
+        def loss(x, dt, a, b, c):
+            y, s = ssd_chunk.ssd_chunked(x, dt, a, b, c, chunk=chunk,
+                                         mm_dtype=jnp.bfloat16)
+            return jnp.sum(y ** 2) + jnp.sum(s ** 2)
+
+        f32 = jnp.float32
+        hlo = _compile_v5e(
+            jax.grad(loss, argnums=(0, 1, 2, 3, 4)), self._one(v5e),
+            ((2, t, 16, 64), f32), ((2, t, 16), f32), ((16,), f32),
+            ((2, t, 1, n), f32), ((2, t, 1, n), f32))
+        assert ("tpu_custom_call" in hlo) == kernels
+        for kernel in ("ssd_chunk_fwd", "ssd_chunk_bwd"):
+            assert (kernel in hlo) == kernels
+        assert (t // chunk in _loop_trips(hlo)) == (not kernels)
+
+    def test_a_checkpointed_mamba2_block_at_the_nemotron_cells_widths(
+            self, v5e, monkeypatch):
+        # a block of the seventh configuration's step as the containers
+        # run it: `MixerBlock(Mamba2Mixer)` at 4,096 wide, 16 heads of 64 on
+        # one group of state 128, taps of 4, 2 x 8,192 positions in bf16,
+        # inside the rematerialised layer call: the forward kernel in the
+        # forward pass and once more in the forward made again (where it
+        # writes the states every chunk received), ONE backward kernel
+        import sys
+
+        from deeplearning4j_tpu.nn.conf.base import InputType
+        from deeplearning4j_tpu.nn.layers import Mamba2Mixer, MixerBlock
+        from deeplearning4j_tpu.nn.multilayer import _layer_call
+        import deeplearning4j_tpu.ops.ssd_chunk  # noqa: F401
+        monkeypatch.setattr(
+            sys.modules["deeplearning4j_tpu.ops.ssd_chunk"],
+            "is_tpu_backend", lambda: True)
+        layer = MixerBlock(n_out=4096, mixer=Mamba2Mixer(
+            n_out=4096, n_heads=16, head_dim=64, n_groups=1, state_dim=128,
+            conv_kernel=4, chunk=128))
+        shapes = jax.eval_shape(
+            lambda key: layer.init(key, InputType.recurrent(4096, 8192),
+                                   jnp.bfloat16)[0], jax.random.PRNGKey(0))
+
+        def loss(params, x):
+            y, _ = _layer_call(layer, seq=False, train=True, remat=True,
+                               params=params, x=x, state={})
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        one = self._one(v5e)
+        place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one)
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jax.tree_util.tree_map(place, shapes),
+            place(jax.ShapeDtypeStruct((2, 8192, 4096), jnp.bfloat16))
+        ).compile().as_text()
+        assert _kernels_called(hlo) == ["ssd_chunk_bwd", "ssd_chunk_fwd",
+                                        "ssd_chunk_fwd"]
+        calls = [line for line in hlo.splitlines()
+                 if "tpu_custom_call" in line]
+        # x' at its 16 heads of 64 in bf16, a chunk a tile
+        assert all("bf16[2,64,16,128,64]" in line for line in calls)
+
     @pytest.mark.parametrize("backward", [False, True],
                              ids=["forward", "gradient"])
     def test_sparse_attention_kernels_at_32k(self, v5e, backward):

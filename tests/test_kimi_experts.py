@@ -273,11 +273,52 @@ def test_every_tier_gives_the_full_tiers_result_and_gradients(router, gated,
     assert float(jnp.abs(want_grads[0]["Wr"]).max()) > 1e-3 or not live
 
 
+@pytest.mark.parametrize("n_experts,held,top_k,routing", [
+    (n_experts, held, top_k, routing)
+    for n_experts, held in [(64, (5, 6)), (64, (8, 16)), (64, (3, 19)),
+                            (24, (0, 24)), (40, (0, 40))]
+    for top_k in (1, 4, 22)
+    for routing in ("mixed", "mixed, whole blocks", "no pair held",
+                    "every pair held", "one expert draws everything")
+    # a layer that holds every expert holds every pair
+    if held != (0, n_experts) or routing != "no pair held"])
+def test_the_counts_are_bincounts(n_experts, held, top_k, routing):
+    """The groups' sizes and the routed counts the dispatch takes from
+    the product of the keys' one-hots are what `jnp.bincount` gave,
+    exactly: 1, 8 and 16 experts held of 64, all 24 of 24 and all 40 of
+    40 (41 buckets: a second digit), keys that fill whole blocks of 512
+    and keys that do not."""
+    from deeplearning4j_tpu.nn.layers.attention import _counts
+    lo, hi = held
+    e = hi - lo
+    n = 512 if routing == "mixed, whole blocks" else 75
+    pairs = n * top_k
+    rng = np.random.default_rng(n_experts + 7 * e + top_k)
+    others = np.r_[0:lo, hi:n_experts]
+    flat = {
+        "mixed": lambda: rng.integers(0, n_experts, pairs),
+        "mixed, whole blocks": lambda: np.where(
+            rng.random(pairs) < 0.3, rng.integers(lo, hi, pairs),
+            rng.integers(0, n_experts, pairs)),
+        "no pair held": lambda: rng.choice(others, pairs),
+        "every pair held": lambda: rng.integers(lo, hi, pairs),
+        "one expert draws everything": lambda: np.full(pairs, hi - 1),
+    }[routing]()
+    flat = jnp.asarray(flat, jnp.int32)
+    local = jnp.where((flat >= lo) & (flat < hi), flat - lo, e)
+    counts = jax.jit(_counts, static_argnums=1)
+    for keys, buckets in ((local, e + 1), (flat, n_experts)):
+        got = counts(keys, buckets)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(got, jnp.bincount(keys, length=buckets))
+
+
 def _the_parents_apply(ffn, params, state, x):
     """`MoEFeedForward.apply` of a layer that holds every expert, as the
     parent of the PR that brought the tiers had it (one dispatch, no
-    shared expert, no mask), statement for statement: what such a layer
-    must still lower to."""
+    shared expert, no mask), statement for statement, with the
+    `jnp.bincount`s the dispatch took its counts from: the independent
+    reference the dispatch is held to, bit for bit."""
     from deeplearning4j_tpu.nn.activations import get_activation
     from deeplearning4j_tpu.nn.layers.attention import (
         _grouped_matmul, _rows_to_experts, _rows_to_tokens)
@@ -332,31 +373,40 @@ def _the_parents_apply(ffn, params, state, x):
     dict(n_experts=4, top_k=2, mlp_ratio=2),
     dict(n_experts=6, top_k=3, hidden=8, gated=True, has_bias=False,
          activation="swish", router="sigmoid", experts_held=(0, 6))])
-def test_a_layer_that_holds_every_expert_lowers_to_the_parents_program(conf):
+def test_a_layer_that_holds_every_expert_gives_the_parents_results(conf):
     """Such a layer holds every pair of every dispatch: it has one tier,
-    builds no switch, keeps no tier counter and lowers, forward and
-    backward, to the text the parent's layer lowers to."""
+    builds no switch, keeps no tier counter and gives, forward and
+    backward, what the parent's layer gave, bit for bit: the counts are
+    the `jnp.bincount`s', so every tensor behind them is the same
+    tensor, with no scatter-add to make them."""
     ffn = MoEFeedForward(n_out=16, **conf)
     p, state = ffn.init(jax.random.PRNGKey(2), InputType.recurrent(16, 10))
     assert set(state) - {"route_bias"} == {"tokens_routed",
                                            "tokens_routed_total"}
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 10, 16))
 
-    def lowered(apply):
-        def program(p, state, x):
-            loss = lambda p, x: (lambda out, new: (jnp.sum(out ** 2), new))(
-                *apply(p, state, x))
+    def program(apply):
+        def run(p, state, x):
+            loss = lambda p, x: (lambda out, new: (
+                jnp.sum(out ** 2), (out, new)))(*apply(p, state, x))
             return jax.value_and_grad(loss, (0, 1), has_aux=True)(p, x)
-        return jax.jit(program).lower(p, state, x).as_text()
+        return jax.jit(run)
 
-    assert lowered(ffn.apply) == lowered(
-        functools.partial(_the_parents_apply, ffn))
-    forward = lambda ffn: jax.jit(ffn.apply).lower(
-        *ffn.init(jax.random.PRNGKey(2), InputType.recurrent(16, 10)),
+    got = program(ffn.apply)(p, state, x)
+    want = program(functools.partial(_the_parents_apply, ffn))(p, state, x)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want), strict=True):
+        assert a.dtype == b.dtype, path
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+    assert float(jnp.abs(want[1][0]["Wr"]).max()) > 1e-3
+    text = jax.jit(ffn.apply).lower(p, state, x).as_text()
+    assert "stablehlo.scatter" not in text and "stablehlo.case" not in text
+    assert "stablehlo.scatter" in jax.jit(functools.partial(
+        _the_parents_apply, ffn)).lower(p, state, x).as_text()
+    shared = dataclasses.replace(ffn, experts_held=(1, 2))
+    assert "stablehlo.case" in jax.jit(shared.apply).lower(
+        *shared.init(jax.random.PRNGKey(2), InputType.recurrent(16, 10)),
         x).as_text()
-    assert "stablehlo.case" not in forward(ffn)
-    assert "stablehlo.case" in forward(
-        dataclasses.replace(ffn, experts_held=(1, 2)))
 
 
 @pytest.mark.parametrize("top_k", [1, 2, 4])

@@ -31,6 +31,39 @@ hand-over reads only as operands of such products, leave in it.
 the four products' operands in the ``mm`` dtype), `chunk_step` the two
 composed: ``(q, k, v, g, beta, S) -> (O, S')``.
 
+`chunk_step_bwd` is `chunk_step`'s pull-back for one tile, written by hand
+beside them: ``(q, k, v, g, beta, S, dO, dS') -> (dq, dk, dv, dg, dbeta,
+dS)``. It makes the tile's forward again, with `chunk_tile` keeping
+``[W | U0]``, A without its beta and each cross-block product's two
+operands, and then takes three parts in this order. (1) The hand-over's
+pull-back, eight products, each the transpose of one of the forward's
+four: the operand in the ``mm`` dtype as the forward had it, the
+cotangent and the sum float32, and what comes out, the cotangent OF an
+``mm`` operand, rounded to ``mm`` (what autodiff does; the MXU's default
+for a float32 operand is one bf16 pass)::
+
+    dU = Aqk^T dO + k_out dS';  dAqk = dO U^T  (i <= t);  dq_in = dO S^T
+    dk_out = U dS'^T;  dW = -dU S^T
+    dS = q_in^T dO + Diag(exp(g_C)) dS' - W^T dU
+
+and g_C's share ``exp(g_C) (.) rowsum(S (.) dS')``. (2) The solve's
+pull-back, ONE transposed substitution ``(I + A)^T Y = [dW | dU]``, the
+forward's arrangement mirrored: the blocks of rows from the last to the
+first, the blocks right of the diagonal by one float32 product a block at
+`Precision.HIGHEST`, inside the block on the diagonal row by row on the
+VPU (row j is final once the rows after it have been taken off it);
+nothing inverted. Then ``d rhs = Y`` and ``dA = -Y [W | U0]^T`` (i < t),
+one float32 product. (3) The decayed products' pull-back: ``x_t exp(g_t -
+g_i) k_i`` pulls back to two more such products over the SAME two
+operands, across blocks the kept ones (products in the ``mm`` dtype),
+inside a block column by column as the forward; its gradient in ``g`` is
+``x (.) dx`` of the row side less ``k (.) dk`` of the column side, so no
+exponential is differentiated and no exponent is positive here either (a
+pair without decay, a position with itself or ``k_out``'s last row, stays
+out of that difference: it would leave a rounding where nothing is).
+``dbeta`` comes from ``rhs`` and A's rows. Every decay, every sum over
+channels and the substitution are float32, as in the forward.
+
 Two executors of the same two functions (`chunk_scan`). On a TPU, where
 the shapes fit the tiling (key and value widths multiples of 128, the
 chunk a multiple of 16), the kernel `kda_chunk_fwd` walks a pair's chunks
@@ -40,11 +73,11 @@ a tile, ``O`` and the final state written, nothing else (``W``, ``U0``,
 ``q_in``, ``k_out``, ``Aqk`` and ``U`` never reach HBM); as the forward
 rule of the `custom_vjp` it also writes the state each chunk RECEIVED.
 `kda_chunk_bwd` walks the same grid from the last chunk to the first with
-the state's cotangent in scratch and runs `jax.vjp` of `chunk_step` at the
-tile and its received state, making the tile's forward again in VMEM: the
-residuals are the inputs and those states. Elsewhere `chunk_tile` runs
-vmapped over the tiles and `hand_over` vmapped over the pairs under a
-`lax.scan` over the chunks, with plain autodiff.
+the state's cotangent in scratch and runs `chunk_step_bwd` at the tile and
+its received state, the tile's forward made again in VMEM: the residuals
+are the inputs and those states. Elsewhere `chunk_tile` runs vmapped over
+the tiles and `hand_over` vmapped over the pairs under a `lax.scan` over
+the chunks, with plain autodiff: the tests' reference for both kernels.
 """
 from __future__ import annotations
 
@@ -68,38 +101,58 @@ _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
 
 
-def _exact(x, y):
+def _exact(x, y, dims=_NN):
     """x @ y as a float32 product that stays float32 on the MXU."""
-    return jax.lax.dot_general(x, y, _NN,
+    return jax.lax.dot_general(x, y, dims,
                                precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
+
+
+def _rows(shape):
+    """Each element's row."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+
+
+def _lanes(shape):
+    """Each element's column."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
 
 def _column(x):
     """A row (1, n) as a column (n, 1): the diagonal of an (n, n) matrix
     that holds the row."""
     n = x.shape[1]
-    at = lambda axis: jax.lax.broadcasted_iota(jnp.int32, (n, n), axis)
-    return jnp.sum(jnp.where(at(0) == at(1), x, 0.0), axis=1, keepdims=True)
+    return jnp.sum(jnp.where(_rows((n, n)) == _lanes((n, n)), x, 0.0),
+                   axis=1, keepdims=True)
 
 
-def chunk_tile(q, k, v, g, beta, *, mm):
+def _row(x):
+    """A column (n, 1) as a row (1, n): the diagonal of an (n, n) matrix
+    that holds the column."""
+    n = x.shape[0]
+    return jnp.sum(jnp.where(_rows((n, n)) == _lanes((n, n)), x, 0.0),
+                   axis=0, keepdims=True)
+
+
+def chunk_tile(q, k, v, g, beta, *, mm, keep=False):
     """The chunk algebra of the module docstring for one tile: q, k, g
     (C, d_k) and v (C, d_v) float32, beta (1, C) float32, C a power of two
     -> ``w (C, d_k), u0 (C, d_v), q_in, k_out (C, d_k), a_qk (C, C)``;
-    u0 float32, the others in the ``mm`` dtype."""
+    u0 float32, the others in the ``mm`` dtype. With ``keep``, those five
+    and what `chunk_step_bwd` reads besides: ``sol`` = [W | U0] and ``kk``
+    = A without its beta (C, C), float32, and ``blocks``, for each block
+    of rows after the first its cross-block product's two operands with
+    the decays that made them."""
     c, dk = k.shape
     f32 = g.dtype
     b = min(c, BLOCK)
-    row = lambda shape: jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    lane = lambda shape: jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     # x under `top` rows of zeros
     below = lambda x, top: jnp.concatenate(
         [jnp.zeros((top,) + x.shape[1:], f32), x], axis=0) if top else x
     beta = _column(beta)
     decay = jnp.exp(g)
     rhs = jnp.concatenate([beta * k * decay, beta * v], axis=1)
-    sol, qk = [], []
+    sol, qk, kk, blocks = [], [], [], []
     for lo in range(0, c, b):
         k_p, q_p, g_p = k[lo:lo + b], q[lo:lo + b], g[lo:lo + b]
         beta_p, x_p = beta[lo:lo + b], rhs[lo:lo + b]
@@ -109,40 +162,51 @@ def chunk_tile(q, k, v, g, beta, *, mm):
             ref = g[lo - 1:lo]
             later = jnp.exp(g_p - ref)
             rows = jnp.concatenate([k_p * later, q_p * later], axis=0)
-            cols = k[:lo] * jnp.exp(ref - g[:lo])
-            cross = jax.lax.dot_general(rows.astype(mm), cols.astype(mm),
-                                        _NT, preferred_element_type=f32)
+            cols, earlier = k[:lo], jnp.exp(ref - g[:lo])
+            cols = cols * earlier
+            rows, cols = rows.astype(mm), cols.astype(mm)
+            cross = jax.lax.dot_general(rows, cols, _NT,
+                                        preferred_element_type=f32)
             x_p = x_p - _exact(beta_p * cross[:b],
                                jnp.concatenate(sol, axis=0))
-            qk_p = jnp.concatenate(
-                [cross[b:], jnp.zeros((b, c - lo), f32)], axis=1)
+            wide = lambda x: jnp.concatenate(
+                [x, jnp.zeros((b, c - lo), f32)], axis=1)
+            qk_p = wide(cross[b:])
+            kk_p = wide(cross[:b]) if keep else None
+            blocks.append((rows, cols, later, earlier))
         else:
-            qk_p = jnp.zeros((b, c), f32)
+            qk_p = kk_p = jnp.zeros((b, c), f32)
         for j in range(b):
             # column lo + j of the block on the diagonal, rows j and later
             # (from the group of 8 rows that holds row j on)
             top = j - j % 8
             k_s, q_s, g_s = k_p[top:], q_p[top:], g_p[top:]
-            seen = row((b - top, dk)) >= j - top
+            seen = _rows((b - top, dk)) >= j - top
             col = jnp.exp(jnp.where(seen, g_s - g_p[j:j + 1], NEG)) \
                 * k_p[j:j + 1]
             qk_p = jnp.where(
-                lane((b, c)) == lo + j,
+                _lanes((b, c)) == lo + j,
                 below(jnp.sum(q_s * col, axis=1, keepdims=True), top), qk_p)
             if j < b - 1:
                 # row j of the solution is final: take A's column j
                 # times it off the rows below
-                a_col = jnp.where(
-                    row((b - top, 1)) > j - top,
-                    beta_p[top:] * jnp.sum(k_s * col, axis=1, keepdims=True),
-                    0.0)
+                under, beta_s = _rows((b - top, 1)) > j - top, beta_p[top:]
+                kk_col = jnp.sum(k_s * col, axis=1, keepdims=True)
+                a_col = jnp.where(under, beta_s * kk_col, 0.0)
                 x_p = x_p - below(a_col * x_p[j:j + 1], top)
+                if keep:
+                    kk_p = jnp.where(
+                        _lanes((b, c)) == lo + j,
+                        below(jnp.where(under, kk_col, 0.0), top), kk_p)
         sol.append(x_p)
         qk.append(qk_p)
+        kk.append(kk_p)
     sol, qk = jnp.concatenate(sol, axis=0), jnp.concatenate(qk, axis=0)
+    kk = jnp.concatenate(kk, axis=0) if keep else None
     k_out = k * jnp.exp(g[c - 1:c] - g)
-    return (sol[:, :dk].astype(mm), sol[:, dk:], (q * decay).astype(mm),
+    tile = (sol[:, :dk].astype(mm), sol[:, dk:], (q * decay).astype(mm),
             k_out.astype(mm), qk.astype(mm))
+    return (tile, sol, kk, blocks) if keep else tile
 
 
 def hand_over(s, w, u0, q_in, k_out, a_qk, g_end, *, mm):
@@ -167,6 +231,124 @@ def chunk_step(q, k, v, g, beta, s, *, mm):
     `hand_over` of the state ``s`` it receives -> ``o, s'``."""
     tile = chunk_tile(q, k, v, g, beta, mm=mm)
     return hand_over(s, *tile, g[-1:], mm=mm)
+
+
+def chunk_step_bwd(q, k, v, g, beta, s, do, ds_next, *, mm):
+    """The pull-back of `chunk_step` at one tile and the state ``s`` it
+    received, on the cotangents ``do (C, d_v)`` of its output and
+    ``ds_next (d_k, d_v)`` of the state it handed on -> ``dq, dk, dv, dg,
+    dbeta (1, C), ds``, all float32: the forward made again, then the
+    module docstring's three parts."""
+    c, dk = k.shape
+    f32 = g.dtype
+    b = min(c, BLOCK)
+
+    # a forward product's transpose: its operand in the mm dtype as it
+    # was, the cotangent float32; what comes out is the cotangent OF an
+    # operand in the mm dtype and is rounded to it, as autodiff rounds it
+    def dot(x, y, dims=_NN):
+        return jax.lax.dot_general(
+            x, y, dims, preferred_element_type=f32).astype(mm).astype(f32)
+
+    # x with `change` of its rows lo to hi in their place
+    def on_rows(x, lo, hi, change):
+        parts = [x[:lo], change(x[lo:hi]), x[hi:]]
+        return jnp.concatenate([a for a in parts if a.shape[0]], axis=0)
+
+    (w, u0, q_in, k_out, a_qk), sol, kk, blocks = chunk_tile(
+        q, k, v, g, beta, mm=mm, keep=True)
+    s_mm = s.astype(mm)
+    u = u0 - jax.lax.dot_general(w, s_mm, _NN, preferred_element_type=f32)
+    u_mm = u.astype(mm)
+    beta = _column(beta)
+    decay, at_end = jnp.exp(g), jnp.exp(g[c - 1:c])
+    before = _lanes((c, c)) < _rows((c, c))
+
+    # 1. the hand-over
+    du = dot(a_qk, do, _TN) + dot(k_out, ds_next)
+    d_aqk = dot(do, u_mm, _NT)
+    dq_in, dw = dot(do, s_mm, _NT), dot(-du, s_mm, _NT)
+    dk_out = dot(u_mm, ds_next, _NT) * jnp.exp(g[c - 1:c] - g)
+    ds = _column(at_end) * ds_next + dot(q_in, do, _TN) + dot(w, -du, _TN)
+    dg_end = at_end * _row(jnp.sum(s * ds_next, axis=1, keepdims=True))
+
+    # 2. the solve: (I + A)^T Y = [dW | dU0], from the last block up
+    a_t = (beta * kk).T
+    y = []
+    for lo in reversed(range(0, c, b)):
+        x = jnp.concatenate([dw[lo:lo + b], du[lo:lo + b]], axis=1)
+        if y:
+            x = x - _exact(a_t[lo:lo + b, lo + b:], jnp.concatenate(y, axis=0))
+        for j in reversed(range(1, b)):
+            # row j of the solution is final: take A's row j times it off
+            # the rows above (to the end of the group of 8 rows that
+            # holds row j - 1)
+            rows = min(b, j + -j % 8)
+            step = a_t[lo:lo + rows, lo + j:lo + j + 1] * x[j:j + 1]
+            x = on_rows(x, 0, rows, lambda x: x - step)
+        y.insert(0, x)
+    y = jnp.concatenate(y, axis=0)
+    y_k, y_v = y[:, :dk], y[:, dk:]
+    d_a = jnp.where(before, -_exact(y, sol, _NT), 0.0)
+    d_kk = beta * d_a
+    dk_rhs = y_k * decay
+    dbeta = jnp.sum(dk_rhs * k, axis=1, keepdims=True) \
+        + jnp.sum(y_v * v, axis=1, keepdims=True) \
+        + jnp.sum(d_a * kk, axis=1, keepdims=True)
+
+    # 3. the decayed products x_t exp(g_t - g_i) k_i: dq_row and dk_row
+    # of their row side (x = q in Aqk, x = k in A), dk_col of their column
+    # side. A position's pair with itself has no decay: it is left out of
+    # the three (so of dg, where it would be one rounding of q k dAqk in
+    # place of nothing) and added last
+    d_self = jnp.sum(jnp.where(_lanes((c, c)) == _rows((c, c)), d_aqk, 0.0),
+                     axis=1, keepdims=True)
+    d_aqk = jnp.where(before, d_aqk, 0.0)
+    dq_row, dk_row, dk_col = [], [], []
+    for lo, block in zip(range(0, c, b), [None] + blocks):
+        k_p, q_p, g_p = k[lo:lo + b], q[lo:lo + b], g[lo:lo + b]
+        d_aqk_p, d_kk_p = d_aqk[lo:lo + b], d_kk[lo:lo + b]
+        dq_p = dk_p = dc_p = jnp.zeros((b, dk), f32)
+        if lo:
+            rows, cols, later, earlier = block
+            d_cross = jnp.concatenate([d_kk_p[:, :lo], d_aqk_p[:, :lo]],
+                                      axis=0)
+            d_rows = dot(d_cross, cols)
+            dk_p, dq_p = d_rows[:b] * later, d_rows[b:] * later
+            d_cols = dot(d_cross, rows, _TN) * earlier
+            dk_col = [x + d_cols[at:at + b]
+                      for at, x in zip(range(0, lo, b), dk_col)]
+        for j in range(b - 1):
+            # column lo + j of the block on the diagonal, rows after j
+            # (from the group of 8 rows that holds row j + 1 on)
+            top = j + 1 - (j + 1) % 8
+            k_s, q_s, g_s = k_p[top:], q_p[top:], g_p[top:]
+            after = _rows((b - top, dk)) > j - top
+            e = jnp.exp(jnp.where(after, g_s - g_p[j:j + 1], NEG))
+            col = e * k_p[j:j + 1]
+            at = slice(lo + j, lo + j + 1)
+            d_qk, d_k = d_aqk_p[top:, at], d_kk_p[top:, at]
+            dq_p = on_rows(dq_p, top, b, lambda x: x + d_qk * col)
+            dk_p = on_rows(dk_p, top, b, lambda x: x + d_k * col)
+            d_col = jnp.sum((d_qk * q_s + d_k * k_s) * e, axis=0,
+                            keepdims=True)
+            dc_p = jnp.where(_rows((b, dk)) == j, d_col, dc_p)
+        dq_row.append(dq_p)
+        dk_row.append(dk_p)
+        dk_col.append(dc_p)
+    dq = dq_in * decay + jnp.concatenate(dq_row, axis=0)
+    dk_row = beta * dk_rhs + jnp.concatenate(dk_row, axis=0)
+    # k_out's last row has no decay either
+    last = _rows((c, dk)) == c - 1
+    dk_last = jnp.where(last, dk_out, 0.0)
+    dk_out = dk_out - dk_last
+    dk_col = jnp.concatenate(dk_col, axis=0) + dk_out
+    # g's gradient: no exponential is differentiated
+    dg = q * dq + k * (dk_row - dk_col)
+    dg_end = dg_end + jnp.sum(k * dk_out, axis=0, keepdims=True)
+    dg = jnp.where(last, dg + dg_end, dg)
+    return (dq + d_self * k, dk_row + dk_col + dk_last + d_self * q,
+            beta * y_v, dg, _row(dbeta), ds)
 
 
 def _each_chunk(ref, one, by=1, reverse=False):
@@ -214,8 +396,8 @@ def _fwd_kernel(*refs, mm, keep):
     # two chunks a turn: the second's algebra, which no state enters, runs
     # beside the first's hand-over (on the v5e 1.19 -> 1.05 ms per 1,024
     # tiles; eight a turn hide the hand-over whole, 0.96, and take five
-    # times as long to trace; the backward kernel gains 4 % from two a
-    # turn and takes twice as long to trace: one)
+    # times as long to trace; the backward kernel LOSES 8 % with two a
+    # turn, 3.01 against 2.78 ms: one)
     _each_chunk(o_ref, one, by=2)
 
     @pl.when(last)
@@ -225,9 +407,9 @@ def _fwd_kernel(*refs, mm, keep):
 
 def _bwd_kernel(*refs, mm):
     """The grid and a step's chunks walked from the last to the first,
-    the state's cotangent in ``ds_ref`` (VMEM scratch): `jax.vjp` of
-    `chunk_step` at the tile and the state it received, on the chunk's
-    ``do`` and the cotangent of the state it handed on."""
+    the state's cotangent in ``ds_ref`` (VMEM scratch): `chunk_step_bwd`
+    at the tile and the state it received, on the chunk's ``do`` and the
+    cotangent of the state it handed on."""
     ins, do_ref, dend_ref = refs[:6], refs[6], refs[7]
     grads, ds0_ref, ds_ref = refs[8:13], refs[13], refs[14]
 
@@ -238,9 +420,8 @@ def _bwd_kernel(*refs, mm):
         ds_ref[...] = dend_ref[0]
 
     def one(i):
-        _, pull = jax.vjp(functools.partial(chunk_step, mm=mm),
-                          *(r[0, i] for r in ins))
-        *tile, ds = pull((do_ref[0, i], ds_ref[...]))
+        *tile, ds = chunk_step_bwd(*(r[0, i] for r in ins), do_ref[0, i],
+                                   ds_ref[...], mm=mm)
         for ref, x in zip(grads, tile):
             ref[0, i] = x
         ds_ref[...] = ds

@@ -26,7 +26,7 @@ from deeplearning4j_tpu.models.zoo import (
 from deeplearning4j_tpu.models.transformer import (
     Glm4MoeLiteLM, KeyeVL2LM, KimiLinearLM, Lfm2MoeLM, NemotronHLM,
     SdarMoeLM,
-    TransformerLM, TransformerLMMoE,
+    TransformerLM, TransformerLMMoE, Xing4LM,
 )
 
 __all__ = [
@@ -35,6 +35,6 @@ __all__ = [
     "TextGenerationLSTM", "InceptionResNetV1", "FaceNetNN4Small2", "UNet",
     "TransformerLM", "TransformerLMMoE", "KimiLinearLM", "Glm4MoeLiteLM",
     "Lfm2MoeLM",
-    "KeyeVL2LM", "SdarMoeLM", "NemotronHLM",
+    "KeyeVL2LM", "SdarMoeLM", "NemotronHLM", "Xing4LM",
     "model_by_name", "zoo_models",
 ]

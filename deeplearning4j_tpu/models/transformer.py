@@ -44,6 +44,12 @@ ONE mixer behind a pre-norm, named by a pattern string: Mamba-2 state-space
 layers, position-free grouped-query attention, and expert layers whose
 routed ReLU^2 experts live in a latent of the stream beside a ReLU^2 shared
 expert on it; an untied head.
+
+`Xing4LM` is XingChen's Xing4.0 family (``xing4_0``): the residual path is
+``n_streams`` streams wide (manifold-constrained hyper-connections:
+`HyperConnectedBlock` between `StreamsInVertex` and `StreamsOutVertex`), around
+YaRN-rotated latent attention with a low-rank query and the
+sigmoid-routed SwiGLU experts beside a shared one; an untied head.
 """
 from __future__ import annotations
 
@@ -837,6 +843,141 @@ class NemotronHLM(ZooModel):
                 norm_epsilon=self.rms_norm_eps), last)
             last = f"layer{i}"
         g.add_layer("norm", RMSNormLayer(epsilon=self.rms_norm_eps), last)
+        g.add_layer("head", RnnOutputLayer(
+            n_out=self.vocab_size, activation="softmax",
+            loss="sparse_mcxent", has_bias=False, weight_init="normal"),
+            "norm")
+        return g.set_outputs("head").build()
+
+
+@dataclasses.dataclass
+class Xing4LM(ZooModel):
+    """Decoder-only LM of XingChen's Xing4.0 family (``model_type``
+    ``xing4_0``): token embedding -> ``n_streams`` copies of it
+    (`StreamsInVertex`) -> `HyperConnectedBlock`s, each two sub-layers that read
+    and write the streams through learned, input-dependent mappings
+    (manifold-constrained hyper-connections: ``sinkhorn_iters`` Sinkhorn
+    steps a sub-layer with ``hc_eps`` in the sums, the 4 x 4 logits
+    clamped to ``res_clamp``) -> the streams' sum (`StreamsOutVertex`) ->
+    RMSNorm -> an UNTIED head, sparse cross-entropy over blocks of
+    positions, as a `ComputationGraph` over one input of token ids.
+
+    Every layer attends by `MultiHeadLatentAttention` with a low-rank
+    query (``q_rank``), rotated by YaRN's frequencies and temperature
+    (``rope_factor`` over ``rope_original_max_position``, ``rope_beta_fast``
+    / ``_slow``, ``rope_mscale`` / ``_all_dim``; ``rope_factor`` 1 or less:
+    the plain rotation); the first ``first_k_dense`` layers have a SwiGLU
+    MLP of width ``dense_hidden``, the others the expert layer (sigmoid
+    router over ``n_experts``, ``top_k`` a token, renormalised and scaled
+    by ``routed_scale``, ``n_shared`` shared experts; ``experts_held``: the
+    range this chip holds, None for all). A tensor-parallel slice of the
+    attention is the layer at the slice's ``n_heads``: the low-rank
+    projections and their norms are whole on every chip, ``Wqb``, ``Wkvb``
+    and ``Wo`` hold the slice's heads.
+
+    The family's multi-token-prediction module is not built. Defaults: the
+    published shape cut to widths a CPU test can run;
+    `benchmark/configs/xing4.0-29b-a4b.json` holds the published sizes."""
+    vocab_size: int = 1024
+    seq_length: int = 256
+    n_embd: int = 128
+    n_layers: int = 3
+    n_streams: int = 4
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    n_heads: int = 4
+    q_rank: int = 48
+    kv_rank: int = 32
+    nope_dim: int = 16
+    rope_dim: int = 8
+    v_dim: int = 16
+    rope_theta: float = 10000.0
+    rope_factor: float = 64.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    first_k_dense: int = 1
+    dense_hidden: int = 512
+    n_experts: int = 16
+    top_k: int = 4
+    expert_hidden: int = 64
+    n_shared: int = 1
+    routed_scale: float = 2.0
+    experts_held: Optional[Tuple[int, int]] = None
+    rms_norm_eps: float = 1e-6
+    learning_rate: float = 1e-4
+    beta1: float = 0.9
+    beta2: float = 0.95
+    epsilon: float = 1e-8
+    weight_decay: float = 0.1
+    compute_dtype: Optional[str] = None
+    gradient_checkpointing: bool = True
+    seed: int = 123
+    block_size: int = 512
+
+    def conf(self):
+        from deeplearning4j_tpu.nn.conf.graph_vertices import (
+            StreamsInVertex, StreamsOutVertex,
+        )
+        from deeplearning4j_tpu.nn.layers.attention import GatedMLP
+        from deeplearning4j_tpu.nn.layers.hyper_connection import (
+            HyperConnectedBlock,
+        )
+        from deeplearning4j_tpu.nn.layers.linear_attention import (
+            MultiHeadLatentAttention,
+        )
+        b = (NeuralNetConfiguration.Builder()
+             .seed(self.seed)
+             .updater(AdamW(self.learning_rate, beta1=self.beta1,
+                            beta2=self.beta2, epsilon=self.epsilon,
+                            weight_decay=self.weight_decay,
+                            decay_matrices_only=True))
+             .gradient_checkpointing(self.gradient_checkpointing))
+        if self.compute_dtype:
+            b = b.compute_dtype(self.compute_dtype)
+        g = b.graph_builder().add_inputs("ids").set_input_types(
+            InputType.recurrent(1, self.seq_length))
+        yarn = self.rope_factor > 1
+        mla = MultiHeadLatentAttention(
+            n_out=self.n_embd, n_heads=self.n_heads, nope_dim=self.nope_dim,
+            rope_dim=self.rope_dim, v_dim=self.v_dim, kv_rank=self.kv_rank,
+            q_rank=self.q_rank, rotate=True, rope_theta=self.rope_theta,
+            norm_epsilon=self.rms_norm_eps, block_size=self.block_size,
+            weight_init="normal", rope_scaling="yarn" if yarn else None,
+            rope_factor=self.rope_factor,
+            rope_original_max_position=self.rope_original_max_position,
+            rope_beta_fast=self.rope_beta_fast,
+            rope_beta_slow=self.rope_beta_slow,
+            rope_mscale=self.rope_mscale,
+            rope_mscale_all_dim=self.rope_mscale_all_dim)
+        dense = GatedMLP(n_out=self.n_embd, hidden=self.dense_hidden,
+                         weight_init="normal")
+        experts = MoEFeedForward(
+            n_out=self.n_embd, n_experts=self.n_experts, top_k=self.top_k,
+            hidden=self.expert_hidden, activation="swish", gated=True,
+            has_bias=False, experts_held=self.experts_held,
+            router="sigmoid", routed_scale=self.routed_scale,
+            n_shared=self.n_shared, weight_init="normal")
+        block = lambda ffn: HyperConnectedBlock(
+            n_out=self.n_embd, n_streams=self.n_streams, attn=mla, ffn=ffn,
+            norm="rms", norm_epsilon=self.rms_norm_eps,
+            sinkhorn_iters=self.sinkhorn_iters, hc_eps=self.hc_eps,
+            res_clamp=tuple(self.res_clamp))
+        g.add_layer("embed", EmbeddingSequenceLayer(
+            n_out=self.n_embd, n_in=self.vocab_size), "ids")
+        g.add_vertex("streams", StreamsInVertex(n_streams=self.n_streams),
+                     "embed")
+        last = "streams"
+        for i in range(self.n_layers):
+            g.add_layer(f"layer{i}",
+                        block(dense if i < self.first_k_dense else experts),
+                        last)
+            last = f"layer{i}"
+        g.add_vertex("sum", StreamsOutVertex(n_streams=self.n_streams), last)
+        g.add_layer("norm", RMSNormLayer(epsilon=self.rms_norm_eps), "sum")
         g.add_layer("head", RnnOutputLayer(
             n_out=self.vocab_size, activation="softmax",
             loss="sparse_mcxent", has_bias=False, weight_init="normal"),

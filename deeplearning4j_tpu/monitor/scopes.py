@@ -92,6 +92,16 @@ PART_SCOPES = (
                    "slices and stacks, the weights' copies, the sums of "
                    "their gradients"),
     ("mlp/gated", "a dense gated MLP"),
+    ("mhc/pre", "the reading side of a multi-stream residual's sub-layer: "
+                "the RMS of the streams' row, the product with the "
+                "mappings' columns, the weighted sum the sub-layer reads"),
+    ("mhc/sinkhorn", "its three mappings from the columns: two sigmoids, "
+                     "the clamped exponential and the Sinkhorn steps; the "
+                     "block's two gauges"),
+    ("mhc/post", "the writing side: the streams mixed by H_res plus the "
+                 "sub-layer's output weighed by H_post"),
+    ("mhc/io", "the ends of a multi-stream residual: one stream copied to "
+               "several, several summed to one"),
     ("head/loss", "an output layer's product with its matrix and the "
                   "loss over it"),
     ("opt/update", "the updater's transform and the new parameters"),

@@ -733,7 +733,8 @@ def compiled_ops(compiled) -> List[dict]:
 PALLAS_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                   "kda_chunk_fwd", "kda_chunk_bwd", "dsa_index",
                   "dsa_select", "dsa_attn_fwd", "dsa_kl_fwd", "dsa_attn_bwd",
-                  "ssd_chunk_fwd", "ssd_chunk_bwd")
+                  "ssd_chunk_fwd", "ssd_chunk_bwd", "mhc_pre_fwd",
+                  "mhc_post_fwd", "mhc_post_bwd", "mhc_pre_bwd")
 
 
 def kernel_calls(op_scopes: Dict[str, str]) -> Dict[str, int]:

@@ -287,6 +287,49 @@ class TimeSliceVertex(GraphVertexConf):
 
 @register_layer
 @dataclasses.dataclass(frozen=True)
+class StreamsInVertex(GraphVertexConf):
+    """One residual stream made ``n_streams``: (B, T, C) -> (B, T,
+    n_streams x C), every stream a copy (hyper-connections' way in, behind
+    the embedding; `nn/layers/hyper_connection.py`)."""
+    n_streams: int = 4
+
+    def output_type(self, *input_types: InputType) -> InputType:
+        t = input_types[0]
+        if t.kind != Kind.RNN:
+            raise ValueError(f"StreamsInVertex expects a sequence, got {t}")
+        return InputType(Kind.RNN, (t.shape[0], self.n_streams * t.shape[1]))
+
+    def apply(self, *inputs):
+        with jax.named_scope("mhc/io"):
+            return jnp.tile(inputs[0], (1, 1, self.n_streams))
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
+class StreamsOutVertex(GraphVertexConf):
+    """``n_streams`` residual streams made one: (B, T, n_streams x C) ->
+    (B, T, C), their sum accumulated in float32 (hyper-connections' way
+    out, before the final norm)."""
+    n_streams: int = 4
+
+    def output_type(self, *input_types: InputType) -> InputType:
+        t = input_types[0]
+        if t.kind != Kind.RNN or t.shape[1] % self.n_streams:
+            raise ValueError(f"StreamsOutVertex: {t} is not "
+                             f"{self.n_streams} streams of a sequence")
+        return InputType(Kind.RNN, (t.shape[0], t.shape[1] // self.n_streams))
+
+    def apply(self, *inputs):
+        with jax.named_scope("mhc/io"):
+            x = inputs[0]
+            b, t, _ = x.shape
+            y = jnp.sum(x.reshape(b, t, self.n_streams, -1), axis=2,
+                        dtype=jnp.promote_types(jnp.float32, x.dtype))
+            return y.astype(x.dtype)
+
+
+@register_layer
+@dataclasses.dataclass(frozen=True)
 class PoolHelperVertex(GraphVertexConf):
     """Strip the first spatial row and column of a CNN activation
     (DL4J nn/conf/graph/PoolHelperVertex.java + impl

@@ -32,6 +32,9 @@ from deeplearning4j_tpu.nn.layers.linear_attention import (
     GatedShortConv, KimiDeltaAttention, Mamba2Mixer,
     MultiHeadLatentAttention,
 )
+from deeplearning4j_tpu.nn.layers.hyper_connection import (
+    HyperConnectedBlock,
+)
 
 __all__ = [
     "DenseLayer", "EmbeddingLayer", "ActivationLayer", "DropoutLayer",
@@ -55,5 +58,6 @@ __all__ = [
     "RMSNormLayer", "GatedMLP", "LinearProjection", "KimiDeltaAttention",
     "GatedShortConv", "Mamba2Mixer", "MixerBlock",
     "MultiHeadLatentAttention",
+    "HyperConnectedBlock",
     "LayerNormLayer", "PositionalEmbeddingLayer", "EmbeddingSequenceLayer",
 ]

@@ -433,7 +433,7 @@ class Mamba2Mixer(LayerConf):
             return o.astype(x.dtype) @ params["Wout"], state
 
 
-def rope_pairs(x, positions, theta):
+def rope_pairs(x, positions, theta, freqs=None, amplitude=1.0):
     """Rotary positions on the last axis of x (B, T, H, D), all D dims,
     the pairs (2j, 2j+1) rotated by ``positions[t] * theta^(-2j/D)`` (the
     interleaved layout of the GLM / DeepSeek latent attentions). The
@@ -441,9 +441,52 @@ def rope_pairs(x, positions, theta):
     the second members in its second: one fixed permutation of the dims,
     the same in q and in k, which no score ``q . k`` can see, and which
     saves putting the pairs back side by side. So it is `rope` (which
-    pairs dim j with dim j + D/2) on the de-interleaved dims."""
-    return rope(jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1),
-                positions, theta)
+    pairs dim j with dim j + D/2) on the de-interleaved dims. ``freqs``
+    (D/2 of them, `yarn_frequencies`) turns pair j by ``positions[t] *
+    freqs[j]`` instead, cos and sin times ``amplitude``."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    if freqs is None:
+        return rope(x, positions, theta)
+    half = x.shape[-1] // 2
+    acc_t = jnp.promote_types(jnp.float32, x.dtype)
+    angles = positions[:, None].astype(acc_t) * jnp.asarray(freqs, acc_t)
+    cos = (amplitude * jnp.cos(angles))[None, :, None, :]
+    sin = (amplitude * jnp.sin(angles))[None, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def yarn_frequencies(dim, theta, *, factor, original_max_position,
+                     beta_fast=32.0, beta_slow=1.0, mscale=1.0,
+                     mscale_all_dim=0.0):
+    """YaRN's rotation as the DeepSeek family's code has it: ``(the dim / 2
+    frequencies, the factor on cos and sin, the factor on the softmax
+    scale)``. With ``f_j = theta^(-2j/dim)`` and ``where(b) = dim ln(
+    original_max_position / (2 pi b)) / (2 ln theta)`` (the pair that turns
+    ``b`` times over the original length), ``low = floor(where(beta_fast))``
+    and ``high = ceil(where(beta_slow))`` (within 0 .. dim - 1), pair j
+    turns by ``f_j / factor * ramp_j + f_j (1 - ramp_j)``, ``ramp_j =
+    clip((j - low) / (high - low), 0, 1)``: the fast pairs as they were,
+    the slow ones stretched by ``factor``. ``m(s) = 0.1 s ln factor + 1``
+    (1 where ``factor <= 1`` or ``s`` is 0); cos and sin are times
+    ``m(mscale) / m(mscale_all_dim)`` and the softmax scale times
+    ``m(mscale_all_dim)^2``."""
+    import math
+
+    import numpy as np
+    plain = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    where = lambda turns: dim * math.log(
+        original_max_position / (turns * 2 * math.pi)) \
+        / (2 * math.log(float(theta)))
+    low = max(math.floor(where(beta_fast)), 0)
+    high = min(math.ceil(where(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    m = lambda s: 0.1 * s * math.log(factor) + 1.0 if factor > 1 else 1.0
+    freqs = plain / factor * ramp + plain * (1.0 - ramp)
+    return (tuple(float(f) for f in freqs), m(mscale) / m(mscale_all_dim),
+            m(mscale_all_dim) ** 2)
 
 
 @register_layer
@@ -457,10 +500,19 @@ class MultiHeadLatentAttention(LayerConf):
     heads; ``softmax(q k^T / sqrt(nope_dim + rope_dim)) v``; ``Wo``.
     ``rotate`` False carries the ``rope_dim`` part as it is (no positions
     at all); True rotates it, in q and in the shared ``k_r``, by the
-    token's position (`rope_pairs`: RoPE at base ``rope_theta`` over ALL
-    ``rope_dim`` dims, the pairs (2j, 2j+1) as the family lays them out).
-    On a TPU the fused flash kernel runs the attention at the layer's
-    head sizes (192/128, 256/256, ...)."""
+    token's position over ALL ``rope_dim`` dims, the pairs (2j, 2j+1) as
+    the family lays them out (`rope_pairs`). Which frequencies and which
+    temperature: with ``rope_scaling`` None, pair j turns by ``position *
+    rope_theta^(-2j/rope_dim)`` and the softmax scale is ``(nope_dim +
+    rope_dim)^-1/2``; with ``rope_scaling`` "yarn" (the only type there
+    is: any other name is refused) the frequencies are YaRN's blend of
+    those and of those over ``rope_factor`` (`yarn_frequencies`, from
+    ``rope_original_max_position``, ``rope_beta_fast`` / ``_slow``), cos
+    and sin are times ``m(rope_mscale) / m(rope_mscale_all_dim)`` and the
+    softmax scale times ``m(rope_mscale_all_dim)^2``, ``m(s) = 0.1 s ln
+    rope_factor + 1`` (q carries that factor into the kernels, which
+    scale by the head width alone). On a TPU the fused flash kernel runs
+    the attention at the layer's head sizes (192/128, 256/256, ...)."""
     n_out: int = 0
     n_heads: int = 8
     nope_dim: int = 128
@@ -473,11 +525,35 @@ class MultiHeadLatentAttention(LayerConf):
     norm_epsilon: float = 1e-5
     block_size: int = 512
     weight_init: str = "xavier"
+    rope_scaling: Optional[str] = None
+    rope_factor: float = 1.0
+    rope_original_max_position: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     def output_type(self, input_type: InputType) -> InputType:
         return InputType(Kind.RNN, (input_type.shape[0], self.n_out))
 
+    def _rotation(self):
+        """(frequencies or None for the plain ones, the factor on cos and
+        sin, the factor on the softmax scale)."""
+        if self.rope_scaling is None:
+            return None, 1.0, 1.0
+        if self.rope_scaling != "yarn" or not self.rotate:
+            raise ValueError(
+                f"rope_scaling {self.rope_scaling!r}: the one type there is "
+                "is \"yarn\", on a layer that rotates (rotate=True)")
+        return yarn_frequencies(
+            self.rope_dim, self.rope_theta, factor=self.rope_factor,
+            original_max_position=self.rope_original_max_position,
+            beta_fast=self.rope_beta_fast, beta_slow=self.rope_beta_slow,
+            mscale=self.rope_mscale,
+            mscale_all_dim=self.rope_mscale_all_dim)
+
     def init(self, key, input_type: InputType, dtype=jnp.float32):
+        self._rotation()                 # an unknown type is refused here
         f, h = input_type.features, self.n_heads
         w_init = get_initializer(self.weight_init)
         ks = list(jax.random.split(key, 4)) + [jax.random.fold_in(key, 4)]
@@ -511,13 +587,17 @@ class MultiHeadLatentAttention(LayerConf):
             kv = (c @ params["Wkvb"]).reshape(b, t, h, -1)
             k_r = ckr[:, :, None, self.kv_rank:]
         if self.rotate:
+            freqs, amplitude, temperature = self._rotation()
             with jax.named_scope("mla/rope"):
                 at = jnp.arange(t)
                 q = jnp.concatenate(
                     [q[..., :self.nope_dim],
-                     rope_pairs(q[..., self.nope_dim:], at, self.rope_theta)],
-                    axis=-1)
-                k_r = rope_pairs(k_r, at, self.rope_theta)
+                     rope_pairs(q[..., self.nope_dim:], at, self.rope_theta,
+                                freqs, amplitude)], axis=-1)
+                if temperature != 1.0:
+                    # the kernels scale the scores by the head width alone
+                    q = (q * temperature).astype(q.dtype)
+                k_r = rope_pairs(k_r, at, self.rope_theta, freqs, amplitude)
         with jax.named_scope("mla/proj"):
             k_r = jnp.broadcast_to(k_r, (b, t, h, self.rope_dim))
             k = jnp.concatenate([kv[..., :self.nope_dim], k_r], axis=-1)

@@ -412,7 +412,14 @@ class ExpertLoadListener(TrainingListener):
     among: an exact top-k keeps ``sum_t min(t + 1, topk)`` of ``T (T + 1)
     / 2`` a sequence, and a selection that lets a tie in or drops a key
     shows here) and the gauge ``dsa_indexer_kl{layer}`` (the indexer's
-    loss at the last step)."""
+    loss at the last step).
+
+    A block on several residual streams (`HyperConnectedBlock`) keeps two
+    gauges of its last step in its state: ``mhc_res_gap{layer}`` (how far
+    a row or column sum of its stream-to-stream mapping is from 1 after
+    the Sinkhorn steps, the largest over tokens and sub-layers) and
+    ``mhc_pre_entropy{layer}`` (the mean entropy, nats, of the weights a
+    sub-layer reads the streams with)."""
 
     _TOTALS = ("tokens_routed_total", "tier_hits", "rows_walked_total",
                "tokens_with_held_pair_total")
@@ -492,10 +499,33 @@ class ExpertLoadListener(TrainingListener):
             kl.set(float(np.asarray(state["indexer_kl"])), layer=key)
             self._pairs_at_start[key] = now
 
+    @staticmethod
+    def _publish_streams(model):
+        import numpy as np
+        from deeplearning4j_tpu import monitor
+        gauges = {
+            "res_gap": monitor.gauge(
+                "mhc_res_gap",
+                "largest |row sum - 1| or |column sum - 1| of a "
+                "multi-stream block's stream-to-stream mapping after its "
+                "Sinkhorn steps, over tokens and sub-layers, last step",
+                labels=("layer",)),
+            "pre_entropy": monitor.gauge(
+                "mhc_pre_entropy",
+                "mean entropy (nats) of the weights a multi-stream block's "
+                "sub-layers read the streams with, over their sum, last "
+                "step", labels=("layer",))}
+        for key, state in (model.state or {}).items():
+            if isinstance(state, dict) and "mhc" in state:
+                for name, gauge in gauges.items():
+                    gauge.set(float(np.asarray(state["mhc"][name])),
+                              layer=key)
+
     def on_epoch_end(self, model, epoch):
         import numpy as np
         from deeplearning4j_tpu import monitor
         self._publish_sparse(model)
+        self._publish_streams(model)
         routed = monitor.counter(
             "moe_tokens_routed_total",
             "(token, expert) pairs routed by an expert layer, by whether "

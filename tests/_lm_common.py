@@ -132,6 +132,11 @@ class Family:
     scopes: tuple = ()
     #: rows one expert layer of the reference takes (the eight shares)
     layer_rows: int = 128
+    #: what two float32 steps may differ by in the update's norm, and the
+    #: leaves whose gradient is rounding noise by construction (Adam divides
+    #: noise by its own size: their update is not compared leaf by leaf)
+    update_norm_gap: float = 1e-5
+    noise_leaves: tuple = ()
 
     def net(self, **over):
         """(a net of its own, its configuration): for a check that fits.
@@ -221,13 +226,17 @@ def two_adamw_steps_match(family, how):
     want = {"losses": r_losses, "update": diff(r_params),
             "momentum": checks.leaf_norms(r_m)}
     limits = {"loss_gap": 2e-6, "head_momentum_gap": 1e-4,
-              "head_update_gap": 1e-4, "update_norm_gap": 1e-5,
+              "head_update_gap": 1e-4,
+              "update_norm_gap": family.update_norm_gap,
               "stage_momentum_gap": {s: 1e-4 for s in family.stages}}
     rows = checks.training_rows(prog, want,
                                 lambda leaf: ref.stage_of(cfg, leaf), limits)
     assert len(rows) == 4 + len(family.stages) and checks.verdict(rows)
     # gains, per-head scalars and biases are not decayed; matrices are
-    assert checks.worst_leaf_gap(prog["update"], want["update"]) < 1e-3
+    sound = lambda rows: {k: v for k, v in rows.items()
+                          if k not in family.noise_leaves}
+    assert checks.worst_leaf_gap(sound(prog["update"]),
+                                 sound(want["update"])) < 1e-3
     return prog, want
 
 

@@ -491,6 +491,21 @@ def _mixer_lm():
     return net, ((ids,), (ids,), None, (jnp.ones((2, 2, T), jnp.float32),))
 
 
+def _streams_lm():
+    """The zoo's `Xing4LM` at tiny widths: blocks on four residual streams
+    (a dense one, one with experts half held) between the two ends, latent
+    attention under YaRN."""
+    from deeplearning4j_tpu.models import Xing4LM
+    net = Xing4LM(
+        vocab_size=V, seq_length=T, n_embd=F, n_layers=2, n_heads=2,
+        q_rank=16, kv_rank=16, nope_dim=8, rope_dim=8, v_dim=8,
+        rope_original_max_position=16, dense_hidden=64, n_experts=8,
+        top_k=2, expert_hidden=16, experts_held=(0, 4), block_size=16,
+        compute_dtype="bfloat16").init()
+    ids = jnp.zeros((2, 2, T), jnp.int32)
+    return net, ((ids,), (ids,), None, (jnp.ones((2, 2, T), jnp.float32),))
+
+
 def _conv_graph():
     from deeplearning4j_tpu.nn.conf.base import InputType
     from deeplearning4j_tpu.nn.conf.graph_vertices import ElementWiseVertex
@@ -548,7 +563,14 @@ _PLUMBING = {"kstep", "while", "body", "cond", "closed_call", "checkpoint",
                  "moe/latent", "moe/dispatch", "moe/experts", "moe/shared",
                  "moe/combine", "head/loss", "opt/update"},
      {"embed", "layer0", "layer1", "layer2", "norm", "head"}),
-], ids=["graph_lm", "multilayer_lm", "conv_graph", "mixer_lm"])
+    (_streams_lm, {"cast", "embed", "norm", "mhc/pre", "mhc/sinkhorn",
+                   "mhc/post", "mhc/io", "mla/proj", "mla/rope", "mla/attn",
+                   "mla/out", "mlp/gated", "moe/route", "moe/dispatch",
+                   "moe/experts", "moe/shared", "moe/combine", "head/loss",
+                   "opt/update"},
+     {"embed", "streams", "layer0", "layer1", "sum", "norm", "head"}),
+], ids=["graph_lm", "multilayer_lm", "conv_graph", "mixer_lm",
+        "streams_lm"])
 def test_every_op_of_the_scan_step_has_a_layer_and_a_part(build, parts,
                                                           layers):
     net, operands = build()

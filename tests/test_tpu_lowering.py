@@ -500,6 +500,60 @@ class TestFlashKernelCompiles:
         # x' at its 16 heads of 64 in bf16, a chunk a tile
         assert all("bf16[2,64,16,128,64]" in line for line in calls)
 
+    def test_a_checkpointed_block_on_four_streams_at_the_xing_cells_widths(
+            self, v5e, monkeypatch):
+        # a block of the eighth configuration's step as the containers run
+        # it: `HyperConnectedBlock` on 4 streams of 3,584 around the latent
+        # attention's 4 held heads of 128 + 64 / 128 under YaRN and the
+        # dense MLP of 9,216, 1 x 8,192 positions in bf16, inside the
+        # rematerialised layer call: ONE forward flash kernel (its output
+        # is kept); the mixing's four kernels a sub-layer, the forward ones
+        # twice (the mappings are made again) but the last writing side,
+        # whose result the backward does not read
+        import sys
+
+        from deeplearning4j_tpu.nn.conf.base import InputType
+        from deeplearning4j_tpu.nn.layers import (
+            GatedMLP, HyperConnectedBlock, MultiHeadLatentAttention,
+        )
+        from deeplearning4j_tpu.nn.multilayer import _layer_call
+        import deeplearning4j_tpu.ops.mhc_mix  # noqa: F401
+        for name in ("ops.flash_attention", "ops.mhc_mix",
+                     "nn.layers.linear_attention"):
+            monkeypatch.setattr(sys.modules["deeplearning4j_tpu." + name],
+                                "is_tpu_backend", lambda: True)
+        layer = HyperConnectedBlock(
+            n_out=3584, n_streams=4, norm_epsilon=1e-6,
+            attn=MultiHeadLatentAttention(
+                n_out=3584, n_heads=4, nope_dim=128, rope_dim=64, v_dim=128,
+                kv_rank=512, q_rank=768, rotate=True, norm_epsilon=1e-6,
+                rope_scaling="yarn", rope_factor=64.0,
+                rope_original_max_position=4096, rope_mscale_all_dim=1.0),
+            ffn=GatedMLP(n_out=3584, hidden=9216))
+        shapes = jax.eval_shape(
+            lambda key: layer.init(key, InputType.recurrent(14336, 8192),
+                                   jnp.bfloat16), jax.random.PRNGKey(0))
+
+        def loss(params, state, x):
+            y, new = _layer_call(layer, seq=False, train=True, remat=True,
+                                 params=params, x=x, state=state)
+            return jnp.sum(y.astype(jnp.float32) ** 2), new
+
+        one = self._one(v5e)
+        place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one)
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 2), has_aux=True)).lower(
+            *jax.tree_util.tree_map(place, shapes),
+            place(jax.ShapeDtypeStruct((1, 8192, 14336), jnp.bfloat16))
+        ).compile()
+        hlo = compiled.as_text()
+        assert _kernels_called(hlo) == sorted(
+            ["flash_bwd_dq", "flash_fwd"] + 4 * ["mhc_pre_fwd"]
+            + 3 * ["mhc_post_fwd"] + 2 * ["mhc_pre_bwd", "mhc_post_bwd"])
+        # the streams at the layer's input, after its first sub-layer and
+        # their cotangents: a few copies of 235 MB, not tens
+        assert compiled.memory_analysis().temp_size_in_bytes < 4.0e9
+
     @pytest.mark.parametrize("backward", [False, True],
                              ids=["forward", "gradient"])
     def test_sparse_attention_kernels_at_32k(self, v5e, backward):
@@ -723,3 +777,38 @@ class TestFlagshipLowering:
 
         _export_tpu(step, net.params, net.opt_state, net.state, x, y,
                     jax.random.PRNGKey(0), expect_pallas=False)
+
+
+#: sha256 (first 16 hex digits) of the lowered StableHLO of each LM cell's
+#: scan-of-2 step at its rehearsal sizes off the TPU, as the parent of the
+#: PR that brought `HyperConnectedBlock` and YaRN lowers it: neither may
+#: move a step that uses neither. A PR that means to change one of these
+#: steps changes its line.
+_LM_STEP_HASHES = {
+    "kimi-linear-fit-8k-1chip": "7a5c7ed3b698bf07",
+    "glm-4.7-flash-fit-8k-1chip": "748664be0295c4aa",
+    "lfm2-24b-a2b-fit-8k-1chip": "4b70746bc0b0f908",
+    "keye-vl-2.0-fit-32k-1chip": "21d08edfddba6a60",
+    "sdar-30b-a3b-fit-8k-1chip": "7c6090cbc661a897",
+    "nemotron-3-super-fit-8k-1chip": "51a48b79ffec2778",
+}
+
+
+@pytest.mark.parametrize("workload", sorted(_LM_STEP_HASHES))
+def test_an_lm_cells_scan_step_lowers_as_its_parent_did(workload):
+    import hashlib
+
+    from benchmark.lib import manifest, train_cell
+    cell = manifest.Cell(manifest.load_manifest(), workload).rehearsal()
+    cfg, traffic = cell.config, cell.traffic
+    ref = manifest.load_module("references", cell.config_name)
+    system = manifest.load_module("systems", cfg["system"])
+    net = system.build(cfg, ref.make_params(cfg, 1))
+    pool = train_cell.make_batches(1, 2, int(traffic["batch"]), cfg)
+    staged = net._stage_stacked(list(system.feed(pool, None))[:2])
+    text = net._make_scan_step().lower(
+        net.params, net.opt_state, net.state, *staged,
+        jnp.zeros((2, 2), jnp.uint32)).as_text()
+    assert "loc(" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _LM_STEP_HASHES[workload]
